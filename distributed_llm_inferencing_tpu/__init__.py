@@ -22,10 +22,7 @@ import os as _os
 
 if _os.environ.get("DLI_PLATFORM"):
     # Select the JAX backend per process (e.g. DLI_PLATFORM=cpu for a
-    # control-plane process that must not claim a TPU). Done via jax.config
-    # rather than JAX_PLATFORMS because environments that preload jax at
-    # interpreter start (sitecustomize TPU plugins) read the env var too
-    # early for user code to set it.
+    # control-plane process that must not claim a TPU).
     import jax as _jax
 
     _jax.config.update("jax_platforms", _os.environ["DLI_PLATFORM"])

@@ -26,9 +26,10 @@ def main(argv=None):
     ap.add_argument("--platform", dest="global_platform", default=None,
                     help="force the jax platform for ANY subcommand "
                          "(tpu|cpu); also honored via DLI_PLATFORM. "
-                         "Unset: worker/generate probe the TPU and degrade "
-                         "to cpu if it is unavailable; convert runs on cpu "
-                         "(host-side weight transform needs no chip)")
+                         "Unset: worker/generate take JAX's default and "
+                         "exit non-zero if that is the cpu; convert runs "
+                         "on cpu (host-side weight transform needs no "
+                         "chip)")
     sub = ap.add_subparsers(dest="cmd", required=True)
 
     w = sub.add_parser("worker", help="run a worker agent (data plane)")
@@ -123,19 +124,20 @@ def main(argv=None):
 
     args = ap.parse_args(argv)
 
-    # Platform policy (utils/platform.py): explicit request wins; jax-using
-    # commands otherwise probe the accelerator hang-proof and degrade to
-    # cpu — a dead/held TPU chip must never hang or crash the CLI
-    # (round-1 failure mode: BENCH_r01 rc=1, convert-subprocess hang).
+    # Platform policy (utils/platform.py): an explicit request wins;
+    # worker and generate otherwise take JAX's default and refuse to run
+    # when that is the cpu (JAX's own fallback when it finds no chip).
     from distributed_llm_inferencing_tpu.utils.platform import (
-        ensure_backend, force_platform)
+        check_backend, force_platform, pin_platform)
     requested = (getattr(args, "platform", None) or args.global_platform
                  or os.environ.get("DLI_PLATFORM") or None)
     if args.cmd in ("worker", "generate"):
-        info = ensure_backend(requested)
-        if info["degraded"]:
-            print("warning: TPU backend unavailable, running on cpu",
-                  file=sys.stderr)
+        asked = pin_platform(requested)
+        # a multi-host worker joins jax.distributed first (backend init
+        # must not precede it) and checks after the join
+        if not (args.cmd == "worker"
+                and (args.coordinator or args.latejoin)):
+            check_backend(asked)
     elif args.cmd == "convert":
         force_platform(requested or "cpu")
     elif requested:
@@ -156,6 +158,7 @@ def main(argv=None):
             else:
                 pid, n = init_multihost(args.coordinator,
                                         args.num_processes, args.process_id)
+                check_backend(asked)
             agent = WorkerAgent()
             if pid == 0:
                 followers = [f for f in (args.followers or "").split(",") if f]

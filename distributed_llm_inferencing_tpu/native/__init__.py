@@ -7,9 +7,11 @@ owns, with ref-counted radix prefix sharing — is C++
 (native/src/block_pool.cc), compiled on first use with g++ and bound through
 a minimal C ABI (no pybind11 in this image).
 
-``BlockPool`` is the Python facade. If the shared library cannot be built
-(no compiler), a pure-Python fallback with identical semantics keeps the
-framework functional; ``BlockPool.is_native`` reports which one is live.
+``BlockPool`` is the Python facade. A library that cannot be built or
+loaded is an error (``NativeBuildError``), not a quiet change of
+allocator: the pure-Python pool with identical semantics exists only as
+the differential oracle tests ask for (``force_python=True``);
+``BlockPool.is_native`` reports which one is live.
 """
 
 from __future__ import annotations
@@ -21,6 +23,7 @@ import re
 import subprocess
 import tempfile
 import threading
+import time
 from typing import List, Optional, Sequence, Tuple
 
 log = logging.getLogger("dli.native")
@@ -30,7 +33,13 @@ _SRC = os.path.join(_HERE, "src", "block_pool.cc")
 _LIB = os.path.join(_HERE, "libdli_native.so")
 _build_lock = threading.Lock()
 _lib = None
-_lib_failed = False
+# seconds g++ took in THIS process; None when an up-to-date library was
+# found on disk (chip_smoke.py reports it: a clean checkout must build)
+build_seconds: Optional[float] = None
+
+
+class NativeBuildError(RuntimeError):
+    """The C++ block pool could not be compiled or loaded."""
 
 
 def configured_threads() -> int:
@@ -50,57 +59,52 @@ def configured_threads() -> int:
     return os.cpu_count() or 1
 
 
-def _build() -> Optional[str]:
-    """Compile the shared library if missing or stale. Returns path or None.
+def _build() -> str:
+    """Compile the shared library if missing or stale; return its path.
 
     The compile lands in a temp file and is os.rename()d into place so a
     concurrent process (master + worker on one host) never dlopens a
     half-written library.
     """
-    try:
-        if (os.path.exists(_LIB)
-                and os.path.getmtime(_LIB) >= os.path.getmtime(_SRC)):
-            return _LIB
-        fd, tmp = tempfile.mkstemp(suffix=".so", dir=_HERE)
-        os.close(fd)
-        try:
-            subprocess.run(
-                ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", _SRC,
-                 "-o", tmp],
-                check=True, capture_output=True, timeout=120)
-            os.rename(tmp, _LIB)  # atomic on POSIX
-        finally:
-            if os.path.exists(tmp):
-                os.unlink(tmp)
+    global build_seconds
+    if (os.path.exists(_LIB)
+            and os.path.getmtime(_LIB) >= os.path.getmtime(_SRC)):
         return _LIB
+    t0 = time.monotonic()
+    fd, tmp = tempfile.mkstemp(suffix=".so", dir=_HERE)
+    os.close(fd)
+    try:
+        subprocess.run(
+            ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", _SRC,
+             "-o", tmp],
+            check=True, capture_output=True, timeout=120)
+        os.rename(tmp, _LIB)  # atomic on POSIX
     except subprocess.CalledProcessError as e:
-        log.warning("native block_pool build failed; using Python fallback:\n%s",
-                    e.stderr.decode(errors="replace")[-2000:])
-        return None
-    except Exception as e:
-        log.warning("native block_pool unavailable (%s); using Python "
-                    "fallback", e)
-        return None
+        raise NativeBuildError(
+            "native block_pool build failed:\n"
+            + e.stderr.decode(errors="replace")[-2000:]) from e
+    except (OSError, subprocess.TimeoutExpired) as e:
+        raise NativeBuildError(
+            f"native block_pool build failed: {e!r}") from e
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+    build_seconds = time.monotonic() - t0
+    return _LIB
 
 
 def _load():
-    global _lib, _lib_failed
-    if _lib is not None or _lib_failed:
+    global _lib
+    if _lib is not None:
         return _lib
     with _build_lock:
-        if _lib is not None or _lib_failed:
+        if _lib is not None:
             return _lib
         path = _build()
-        if path is None:
-            _lib_failed = True
-            return None
         try:
             lib = ctypes.CDLL(path)
         except OSError as e:
-            log.warning("failed to load %s (%s); using Python fallback",
-                        path, e)
-            _lib_failed = True
-            return None
+            raise NativeBuildError(f"failed to load {path}: {e}") from e
         i32p = ctypes.POINTER(ctypes.c_int32)
         lib.dli_pool_create.restype = ctypes.c_void_p
         lib.dli_pool_create.argtypes = [ctypes.c_int32, ctypes.c_int32]

@@ -3,7 +3,7 @@
 XLA-CPU cannot read int8 weights inside a dot: its lowering materializes
 the dequantized f32 array first, so an int8-quantized model streams
 f32-sized bytes per decode step and the quantization buys nothing on the
-degraded/fallback platform. This wraps ``native/src/qgemv.cc`` — a C++
+CPU platform. This wraps ``native/src/qgemv.cc`` — a C++
 kernel that streams the weights int8 and dequantizes in registers — as a
 jit-compatible ``jax.ffi`` call, the CPU sibling of the Pallas int4
 fused-unpack kernel (ops/pallas/quant_matmul.py) on the TPU side.
@@ -39,6 +39,8 @@ import subprocess
 import tempfile
 import threading
 
+from jax import ffi
+
 log = logging.getLogger("dli.cpu_gemv")
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
@@ -64,22 +66,6 @@ _lock = threading.Lock()
 _state = {"ready": False, "failed": False}
 
 
-def _ffi_mod():
-    """The FFI module wherever this jax puts it: ``jax.ffi`` (>= 0.4.38)
-    or ``jax.extend.ffi`` (0.4.3x — the callable-returning ``ffi_call``
-    form exists in both). Without this shim the whole native path is
-    silently dead on 0.4.3x installs — ``available()`` False, every int8
-    matmul on the XLA dequant fallback — which is exactly what the bench
-    host was doing."""
-    try:
-        import jax.ffi as m
-        if hasattr(m, "ffi_call"):
-            return m
-    except ImportError:
-        pass
-    from jax.extend import ffi as m
-    return m
-
 # the kernel keeps per-row accumulators for up to this many activation
 # rows while a weight row is hot in L1; larger M is compute-bound and
 # belongs on the XLA dequant matmul (see MAX_FAST_M use in callers)
@@ -87,7 +73,6 @@ MAX_FAST_M = 4
 
 
 def _build():
-    ffi = _ffi_mod()
     tsan = tsan_requested()
     lib_path = _LIB_TSAN if tsan else _LIB
     if (os.path.exists(lib_path)
@@ -132,7 +117,6 @@ def _ensure():
         if _state["ready"] or _state["failed"]:
             return _state["ready"]
         try:
-            ffi = _ffi_mod()
             lib = ctypes.CDLL(_build())
             ffi.register_ffi_target(
                 _TARGET, ffi.pycapsule(lib.QGemvI8), platform="cpu")
@@ -148,7 +132,7 @@ def _ensure():
             _state["ready"] = True
             log.info("cpu gemv kernels ready (threads=%d)",
                      lib.DliGemvGetThreads())
-        except Exception as e:  # missing g++ / headers / old jax: fall back
+        except Exception as e:  # missing g++ / headers: fall back
             log.warning("cpu int8 gemv unavailable (%s); int8 matmuls use "
                         "the XLA dequant path on cpu", e)
             _state["failed"] = True
@@ -204,7 +188,7 @@ def qgemv_i8(x, wt, scale):
     import jax.numpy as jnp
     m, _ = x.shape
     n = wt.shape[0]
-    call = _ffi_mod().ffi_call(
+    call = ffi.ffi_call(
         _TARGET, jax.ShapeDtypeStruct((m, n), jnp.float32))
     return call(x.astype(jnp.float32), wt, scale.astype(jnp.float32))
 
@@ -217,6 +201,6 @@ def gemv_w(x, wt):
     m, _ = x.shape
     n = wt.shape[0]
     target = "dli_gemv_bf16" if wt.dtype == jnp.bfloat16 else "dli_gemv_f32"
-    call = _ffi_mod().ffi_call(
+    call = ffi.ffi_call(
         target, jax.ShapeDtypeStruct((m, n), jnp.float32))
     return call(x.astype(jnp.float32), wt)

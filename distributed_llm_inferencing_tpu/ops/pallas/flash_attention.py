@@ -55,6 +55,7 @@ def _prefill_kernel(*refs, block_q: int, block_kv: int, scale: float,
     else:
         q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = refs
     i, j = pl.program_id(2), pl.program_id(3)
+    head = pl.program_id(1)
     nkv = pl.num_programs(3)
 
     @pl.when(j == 0)
@@ -84,7 +85,7 @@ def _prefill_kernel(*refs, block_q: int, block_kv: int, scale: float,
             # xla formulation ops/attention.py attend): this head's slope
             # arrives as an SMEM scalar, rel = kv - q is never positive
             # at attended positions
-            s += sl_ref[0, 0] * (kv_pos - q_pos).astype(jnp.float32)
+            s += sl_ref[head, 0] * (kv_pos - q_pos).astype(jnp.float32)
         mask = kv_pos <= q_pos
         if sliding_window is not None:
             mask &= (q_pos - kv_pos) < sliding_window
@@ -158,8 +159,9 @@ def flash_attention(
     ]
     args = (qt, kt, vt)
     if alibi is not None:
-        in_specs = [pl.BlockSpec((1, 1), lambda b, h, i, j: (h, 0),
-                                 memory_space=pltpu.SMEM)] + in_specs
+        # whole [H, 1] table in SMEM, indexed by the head's program id
+        # (Mosaic refuses a (1, 1) block of it: rows must tile by 8)
+        in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)] + in_specs
         args = (alibi.astype(jnp.float32).reshape(H, 1),) + args
 
     out = pl.pallas_call(
@@ -191,6 +193,7 @@ def _decode_kernel(*refs, block_kv: int, scale: float,
     else:
         len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = refs
     j = pl.program_id(2)
+    kv_head = pl.program_id(1)
     nkv = pl.num_programs(2)
 
     @pl.when(j == 0)
@@ -199,7 +202,7 @@ def _decode_kernel(*refs, block_kv: int, scale: float,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    length = len_ref[0, 0]              # valid kv slots: [0, length)
+    length = len_ref[pl.program_id(0), 0]   # valid kv slots: [0, length)
     kv_start = j * block_kv
 
     # Tiles entirely past the sequence skip their FLOPs (their DMA is the
@@ -219,7 +222,7 @@ def _decode_kernel(*refs, block_kv: int, scale: float,
             # per-group-head slopes from SMEM (G scalar reads, G static;
             # G == 1 for the MHA ALiBi families BLOOM/Falcon-RW/MPT);
             # query position == length - 1, so rel = kv - (length-1)
-            sl = jnp.stack([sl_ref[0, g] for g in range(G)])[:, None]
+            sl = jnp.stack([sl_ref[kv_head, g] for g in range(G)])[:, None]
             s += sl * (kv_pos - (length - 1)).astype(jnp.float32)
         mask = kv_pos < length          # causal: q position == length - 1
         if sliding_window is not None:
@@ -284,16 +287,16 @@ def flash_decode(
         sliding_window=sliding_window, alibi=alibi is not None)
 
     in_specs = [
-        pl.BlockSpec((1, 1), lambda b, h, j: (b, 0),
-                     memory_space=pltpu.SMEM),
+        # whole [B, 1] lengths (and [Hkv, G] slopes) in SMEM, indexed by
+        # program id: Mosaic refuses a 1-row block of them (rows tile by 8)
+        pl.BlockSpec(memory_space=pltpu.SMEM),
         pl.BlockSpec((1, 1, G, hd), lambda b, h, j: (b, h, 0, 0)),
         pl.BlockSpec((1, 1, bkv, hd), lambda b, h, j: (b, h, j, 0)),
         pl.BlockSpec((1, 1, bkv, hd), lambda b, h, j: (b, h, j, 0)),
     ]
     args = (len2d, qt, kt, vt)
     if alibi is not None:
-        in_specs = [pl.BlockSpec((1, G), lambda b, h, j: (h, 0),
-                                 memory_space=pltpu.SMEM)] + in_specs
+        in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)] + in_specs
         args = (alibi.astype(jnp.float32).reshape(Hkv, G),) + args
 
     out = pl.pallas_call(

@@ -20,11 +20,13 @@ are pure loss — this kernel chains all three in ONE ``pallas_call``:
 - the last block normalizes and writes the [g, hd] context — q never
   touches HBM.
 
-CPU runs the kernel in interpret mode for correctness (the parity suite
-diffs it against the unfused XLA path, tests/test_pallas_parity.py);
-TPU compiles it via Mosaic. Wired behind ``DLI_FUSED_DECODE``
+The parity suite runs the kernel in interpret mode on the CPU and diffs
+it against the unfused XLA path (tests/test_pallas_parity.py); TPU
+compiles it via Mosaic. Wired behind ``DLI_FUSED_DECODE``
 (models/transformer.py paged_decode_step), with the unfused path as the
-always-available differential oracle.
+always-available differential oracle. Interpret mode is never fallen
+into: ``DLI_FUSED_DECODE=interpret`` (a test's request) is the only way
+the serving path interprets the kernel.
 """
 
 from __future__ import annotations
@@ -43,10 +45,18 @@ NEG_INF = -1e30
 
 def enabled() -> bool:
     """``DLI_FUSED_DECODE=1`` opts the serving decode step into the fused
-    kernel (off by default: on CPU the kernel runs interpreted — exact
-    but slow — so the unfused XLA formulation stays the default oracle;
-    on TPU flip it on after the parity suite clears)."""
+    kernel (off by default; the unfused XLA formulation stays the
+    oracle). ``interpret`` also enables it, interpreted — see
+    ``interpret_requested``."""
     return os.environ.get("DLI_FUSED_DECODE", "0") not in ("0", "false", "")
+
+
+def interpret_requested() -> bool:
+    """``DLI_FUSED_DECODE=interpret``: run the kernel in pallas interpret
+    mode on any backend (CPU parity tests of the serving wiring), the
+    same contract as ``DLI_INT4_PALLAS=interpret``. Anything else
+    compiles via Mosaic, and fails where Mosaic is not there."""
+    return os.environ.get("DLI_FUSED_DECODE") == "interpret"
 
 
 def eligible(cfg, quantized_cache: bool) -> bool:
@@ -54,10 +64,11 @@ def eligible(cfg, quantized_cache: bool) -> bool:
     (models/transformer.py paged_decode_step dispatches the kernel,
     paged_decode_chunk flips to the stepwise formulation that reaches
     it) — a single definition so the two can never drift apart and
-    silently strand the kernel behind a side-buffer chunk."""
-    import jax
-    return (enabled() and not quantized_cache
-            and jax.device_count() == 1 and supported(cfg))
+    silently strand the kernel behind a side-buffer chunk. How many
+    devices the program spans is not asked here: the batcher knows its
+    mesh and refuses the kernel for a multi-device load at construction
+    (the kernel is a single-program kernel with no partitioning rule)."""
+    return enabled() and not quantized_cache and supported(cfg)
 
 
 def supported(cfg, q_leaf=None) -> bool:
@@ -118,7 +129,7 @@ def _fused_kernel(bt_ref, len_ref, x_ref, w_ref, s_ref, cos_ref, sin_ref,
     def _project():
         # dequant-GEMV: x [1, D] against this kv-head's [D, g*hd] weight
         # tile, read in its stored form and dequantized in VMEM
-        x = x_ref[:].astype(jnp.float32)                  # [1, D]
+        x = x_ref[0].astype(jnp.float32)                  # [1, D]
         if w_form == "int4":
             # split-half biased-nibble packing (ops/quant.py pack_int4):
             # byte row i holds din rows i (low nibble) and i + din/2
@@ -141,8 +152,8 @@ def _fused_kernel(bt_ref, len_ref, x_ref, w_ref, s_ref, cos_ref, sin_ref,
                         preferred_element_type=jnp.float32)
         q = q.reshape(g, hd)
         if rope:
-            cos = cos_ref[0].astype(jnp.float32)          # [hd]
-            sin = sin_ref[0].astype(jnp.float32)
+            cos = cos_ref[0, 0].astype(jnp.float32)       # [hd]
+            sin = sin_ref[0, 0].astype(jnp.float32)
             half_rot = jnp.concatenate(
                 [-q[:, hd // 2:], q[:, : hd // 2]], axis=-1)
             q = q * cos[None, :] + half_rot * sin[None, :]
@@ -246,11 +257,14 @@ def fused_decode_step(
         num_scalar_prefetch=2,          # block_tables, context_lens
         grid=(r, hkv, mb),
         in_specs=[
-            pl.BlockSpec((1, d), lambda ri, hi, j, bt, lens: (ri, 0)),
+            # per-slot rows ride as [R, 1, n] so the block's last two
+            # dims equal the array's (Mosaic refuses a 1-row block of an
+            # [R, n] array: rows must tile by 8)
+            pl.BlockSpec((1, 1, d), lambda ri, hi, j, bt, lens: (ri, 0, 0)),
             pl.BlockSpec((wr, ghd), lambda ri, hi, j, bt, lens: (0, hi)),
             pl.BlockSpec((1, ghd), lambda ri, hi, j, bt, lens: (0, hi)),
-            pl.BlockSpec((1, hd), lambda ri, hi, j, bt, lens: (ri, 0)),
-            pl.BlockSpec((1, hd), lambda ri, hi, j, bt, lens: (ri, 0)),
+            pl.BlockSpec((1, 1, hd), lambda ri, hi, j, bt, lens: (ri, 0, 0)),
+            pl.BlockSpec((1, 1, hd), lambda ri, hi, j, bt, lens: (ri, 0, 0)),
             pl.BlockSpec((1, 1, bs, hd),
                          lambda ri, hi, j, bt, lens: (bt[ri, j], hi, 0, 0)),
             pl.BlockSpec((1, 1, bs, hd),
@@ -272,6 +286,6 @@ def fused_decode_step(
         out_shape=jax.ShapeDtypeStruct((r, hkv, g, hd), x.dtype),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
-      x, w, s, rope_cos.astype(jnp.float32), rope_sin.astype(jnp.float32),
-      kt, vt)
+      x[:, None], w, s, rope_cos.astype(jnp.float32)[:, None],
+      rope_sin.astype(jnp.float32)[:, None], kt, vt)
     return out.reshape(r, h, hd)

@@ -42,9 +42,12 @@ from jax.experimental import pallas as pl
 # fallback in ops/quant.py handles it.
 MAX_PALLAS_ROWS = 32
 
-# VMEM budget for one packed weight tile (leaves room for x, out, and
-# double-buffering in the ~16 MB of VMEM)
-_TILE_BYTES_BUDGET = 4 * 1024 * 1024
+# Budget for one packed weight tile. What Mosaic allocates is about six
+# times the tile: two DMA buffers of it plus the int32 widening and the
+# two unpacked nibble planes (compiled for a v5e at din=14336: a 3.7 MB
+# tile needed 21 MB of scoped VMEM against the 16 MB limit), so the tile
+# stays under 2.5 MB.
+_TILE_BYTES_BUDGET = 5 * 512 * 1024
 
 
 def _biased_kernel(x_ref, w_ref, s_ref, o_ref):
@@ -155,23 +158,6 @@ from jax.sharding import NamedSharding  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
 
-def _def_partition(prim, **kwargs):
-    """``def_partition`` across jax versions: the shardy factor kwargs
-    (``sharding_rule``/``need_replication_factors``/``reduction_factors``)
-    only exist on newer jax — on 0.4.3x installs (this container) passing
-    them was an import-time TypeError that silently killed the ENTIRE
-    int4 pallas path (every caller fell back to the XLA unpack). Strip
-    them when unsupported: the GSPMD callbacks carry the full
-    partitioning semantics either way."""
-    try:
-        prim.def_partition(**kwargs)
-    except TypeError:
-        for k in ("sharding_rule", "need_replication_factors",
-                  "reduction_factors"):
-            kwargs.pop(k, None)
-        prim.def_partition(**kwargs)
-
-
 def _spec_of(shape_with_sharding):
     sh = getattr(shape_with_sharding, "sharding", None)
     spec = getattr(sh, "spec", None)
@@ -210,8 +196,7 @@ def _q4_matmul_p(x, p4, scale, interpret):
     return _q4_pallas(x, p4, scale, interpret)
 
 
-_def_partition(
-    _q4_matmul_p,
+_q4_matmul_p.def_partition(
     partition=_q4_partition,
     infer_sharding_from_operands=_q4_infer,
     sharding_rule="m k, h n, n -> m n",
@@ -283,8 +268,7 @@ def _q4_matmul_row_p(x, p4, scale, interpret, chunks):
     return ((x.astype(jnp.float32) @ w) * scale[None, :]).astype(x.dtype)
 
 
-_def_partition(
-    _q4_matmul_row_p,
+_q4_matmul_row_p.def_partition(
     partition=_q4_row_partition,
     infer_sharding_from_operands=_q4_row_infer,
     sharding_rule="m k, h n, n -> m n",

@@ -1,7 +1,7 @@
 """Pipeline-parallel serving programs over the paged KV cache.
 
-This closes the one serving gap pipeline parallelism had (VERDICT round-3
-ask #2): models too big for one slice's tp×ep could only be served
+This closes the one serving gap pipeline parallelism had (a round-3
+review ask): models too big for one slice's tp×ep could only be served
 through ``engine.generate`` — no continuous batching, no paged cache, no
 prefix reuse on exactly the models that need serving throughput most
 (BASELINE.md config 5; the reference's own shard-across-machines

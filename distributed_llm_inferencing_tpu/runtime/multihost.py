@@ -780,6 +780,9 @@ def reinit_multihost(coordinator: str, timeout_s: float = 120.0):
     import gc
 
     import jax
+    # Private surface, checked against the installed jax 0.9.0: detaching
+    # a live job has no public API. A jax that moved any of it fails
+    # here, by name, before anything is torn down.
     from jax._src import distributed as jdist
     from jax.extend import backend as jex_backend
 
@@ -787,6 +790,14 @@ def reinit_multihost(coordinator: str, timeout_s: float = 120.0):
         raise RuntimeError("host has no distributed identity "
                            "(init_multihost/configure_multihost not called)")
     gs = jdist.global_state
+    missing = [a for a in ("client", "service", "preemption_sync_manager",
+                           "process_id") if not hasattr(gs, a)]
+    if missing or not hasattr(jex_backend, "clear_backends"):
+        raise RuntimeError(
+            f"jax {jax.__version__} no longer has what elastic rejoin "
+            f"detaches (jax._src.distributed.global_state{missing}, "
+            "jax.extend.backend.clear_backends); reinit_multihost needs "
+            "repair for this jax")
     if gs.client is not None or gs.service is not None:
         log.warning("abandoning the previous jax.distributed job "
                     "(graceful shutdown cannot complete with a dead peer)")

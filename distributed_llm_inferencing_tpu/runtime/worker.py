@@ -22,6 +22,7 @@ from typing import Dict, Optional
 
 import jax
 
+from distributed_llm_inferencing_tpu import native
 from distributed_llm_inferencing_tpu.models.registry import get_config
 from distributed_llm_inferencing_tpu.ops.sampling import SamplingParams
 from distributed_llm_inferencing_tpu.parallel.mesh import MeshSpec
@@ -303,6 +304,10 @@ class WorkerAgent:
             "arena_occupancy": occ,
             "resources": {"cpu": cpu, "memory": mem, "devices": devices,
                           "device": jax.default_backend()},
+            "compile_cache": _compile_cache_state(),
+            # seconds g++ took to build the block pool in this process
+            # (None: an up-to-date library was already on disk)
+            "native_build_s": native.build_seconds,
             "loaded_models": loaded,
             "metrics": self.metrics.snapshot(),
         }
@@ -1597,12 +1602,26 @@ class WorkerAgent:
         return self.service.serve(host, port, background=background)
 
 
+def _compile_cache_state() -> dict:
+    """Where this process's persistent compile cache lives and how many
+    entries it holds (utils/platform.enable_compilation_cache)."""
+    d = jax.config.jax_compilation_cache_dir
+    try:
+        n = sum(1 for f in os.listdir(d) if f.endswith("-cache")) if d else 0
+    except OSError:
+        n = 0       # not created yet: nothing compiled so far
+    return {"dir": d, "entries": n}
+
+
 def main(argv=None):
     import argparse
     ap = argparse.ArgumentParser(description="TPU worker agent")
     ap.add_argument("--host", default="0.0.0.0")
     ap.add_argument("--port", type=int, default=8100)
     args = ap.parse_args(argv)
+    from distributed_llm_inferencing_tpu.utils.platform import (
+        ensure_backend)
+    ensure_backend()
     WorkerAgent().serve(args.host, args.port)
 
 

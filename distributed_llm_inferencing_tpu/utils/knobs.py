@@ -70,7 +70,8 @@ _P = "distributed_llm_inferencing_tpu"
 KNOBS = (
     # ---- platform / model loading ------------------------------------
     Knob("DLI_PLATFORM", "unset", "enum",
-         "Force the JAX platform (`cpu`/`tpu`); unset lets JAX pick.",
+         "Force the JAX platform (`cpu`/`tpu`); unset takes JAX's "
+         "default, and worker/generate/bench refuse a default of cpu.",
          f"{_P}/__init__.py"),
     Knob("DLI_ATTENTION", "auto", "enum",
          "Attention implementation override (`pallas`/`xla`/`auto`) — "
@@ -78,9 +79,11 @@ KNOBS = (
     Knob("DLI_INT4_PALLAS", "auto", "enum",
          "Int4 fused-unpack Pallas matmul: `1` force, `0` disable, "
          "`auto` = on where supported.", f"{_P}/ops/pallas/quant_matmul.py"),
-    Knob("DLI_FUSED_DECODE", "0", "bool",
+    Knob("DLI_FUSED_DECODE", "0", "enum",
          "Fused dequant-GEMV -> RoPE -> paged-attention decode step "
-         "(one pallas_call per layer).", f"{_P}/ops/pallas/fused_decode.py"),
+         "(one pallas_call per layer; one-device loads only). "
+         "`interpret` runs it in pallas interpret mode (tests).",
+         f"{_P}/ops/pallas/fused_decode.py"),
     Knob("DLI_MLA_LATENT", "1", "bool",
          "MLA latent-KV decode layout on eligible meshes; `0` pins the "
          "materialized layout.", f"{_P}/runtime/engine.py"),
@@ -96,9 +99,6 @@ KNOBS = (
     Knob("DLI_MODEL_CACHE", "~/.cache/dli_models", "path",
          "Where opted-in hub downloads land (share via mounted volume "
          "across workers).", f"{_P}/models/convert.py"),
-    Knob("DLI_COMPILATION_CACHE_DIR", "<tmp>/dli-jax-cache", "path",
-         "Persistent XLA compilation cache shared by probe, bench reps "
-         "and restarted workers.", f"{_P}/utils/platform.py"),
     Knob("DLI_NATIVE_THREADS", "all cores", "int",
          "Row-pool thread count for the native GEMV/GEMM kernels; "
          "bitwise-identical output at any setting.",
@@ -416,12 +416,6 @@ KNOBS = (
     # ---- bench harness ------------------------------------------------
     Knob("DLI_BENCH_BUDGET_S", "2400", "float",
          "Wall-clock budget for one bench invocation.", "bench.py"),
-    Knob("DLI_BENCH_STALL_S", "900", "float",
-         "Bench watchdog: a rep with no progress for this long is "
-         "killed and retried.", "bench.py"),
-    Knob("DLI_BENCH_PROBE_WINDOW_S", "300", "float",
-         "Backend-probe timeout window before the bench falls back.",
-         "bench.py"),
     Knob("DLI_BENCH_PLAN_MIN_X", "1.15", "float",
          "Planner A/B gate: minimum planner-chosen vs naive-uniform "
          "goodput ratio on the heterogeneous fleet.", "bench.py"),

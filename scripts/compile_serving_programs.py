@@ -1,0 +1,116 @@
+#!/usr/bin/env python3
+"""Compile the batcher's whole admit and decode-chunk programs for a
+described TPU — no chip, no arrays — before spending a chip call.
+
+    JAX_PLATFORMS=cpu python scripts/compile_serving_programs.py [tp ...]
+
+For each tensor-parallel width given (default: 1 and 4) the real jitted
+programs of ``runtime/batcher.py`` are lowered for ``v5e:2x2`` at
+chip_smoke.py's serving shape (mistral-7b int8, 32 layers; ``LAYERS=2``
+compiles as long: the stack is one scan), with shapes from
+``jax.eval_shape`` and shardings from ``parallel/sharding.py``. Prints
+what ``compiled.memory_analysis()`` says each device must hold and the
+collectives in the program text. What the chip's compiler refuses, it
+refuses here. A compile that passes is not a chip run: this gives bytes,
+never a time. It loads libtpu, so run it while no test run needs
+``tests/test_tpu_compile.py``.
+"""
+
+import os
+import sys
+import time
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+from jax.experimental import topologies  # noqa: E402
+from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
+
+from distributed_llm_inferencing_tpu.models.params import init_params  # noqa: E402
+from distributed_llm_inferencing_tpu.models.registry import get_config  # noqa: E402
+from distributed_llm_inferencing_tpu.ops.paged_kvcache import init_paged_cache  # noqa: E402
+from distributed_llm_inferencing_tpu.parallel import sharding as shd  # noqa: E402
+from distributed_llm_inferencing_tpu.parallel.mesh import MeshSpec, create_mesh  # noqa: E402
+from distributed_llm_inferencing_tpu.runtime.batcher import (  # noqa: E402
+    ContinuousBatcher, _backend)
+
+MODEL, QUANT = "mistral-7b", "int8"
+SLOTS, BLOCK, BLOCKS, MAX_SEQ = 8, 16, 1024, 2048    # chip_smoke.py LOAD
+ADMIT = ((512, 0, 1), (512, 32, 1), (32, 0, 2))      # (tail, prefix blocks, wave)
+DECODE = (32, 1)                                     # chunk sizes
+GIB = 2.0 ** 30
+
+
+def report(name, lowered, t0):
+    compiled = lowered.compile()
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    print(f"{name}: {time.time() - t0:.1f}s to compile; per device "
+          f"arguments {mem.argument_size_in_bytes / GIB:.2f} GiB, "
+          f"transient {mem.temp_size_in_bytes / GIB:.2f} GiB, "
+          f"output {mem.output_size_in_bytes / GIB:.2f} GiB "
+          f"(aliased {mem.alias_size_in_bytes / GIB:.2f}); "
+          f"all-reduce {text.count('all-reduce(')}, "
+          f"all-gather {text.count('all-gather(')}, "
+          f"pallas calls {text.count('tpu_custom_call')}", flush=True)
+
+
+def main(widths):
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    layers = int(os.environ.get("LAYERS", 0))
+    for tp in widths:
+        spec = MeshSpec(tp=tp)
+        mesh = create_mesh(spec, topo.devices)
+        cfg = get_config(MODEL).replace(quant=QUANT)
+        if layers:
+            cfg = cfg.replace(num_layers=layers)
+        # what ContinuousBatcher.__init__ pins
+        cfg = cfg.replace(attn_backend=_backend(cfg, spec.num_devices),
+                          tp_row_sharded=tp > 1, mla_latent_cache=False)
+        print(f"--- {MODEL} {QUANT}, {cfg.num_layers} layers, tp={tp}, "
+              f"attn_backend={cfg.attn_backend}", flush=True)
+
+        def described(tree, specs):
+            return jax.tree.map(
+                lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                                   sharding=sh),
+                tree, shd.named(mesh, specs))
+
+        params = described(
+            jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))),
+            shd.param_specs(cfg, spec))
+        paged = described(
+            jax.eval_shape(lambda: init_paged_cache(cfg, BLOCKS + 1, BLOCK)),
+            shd.paged_cache_specs(cfg, spec))
+        replicated = NamedSharding(mesh, P())
+
+        def arr(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=replicated)
+
+        # the batcher's program builders need only these attributes; its
+        # constructor would place real parameters on jax.devices()
+        b = object.__new__(ContinuousBatcher)
+        b.cfg, b.block_size, b.mesh_spec, b.mesh = cfg, BLOCK, spec, mesh
+        b._dummy, b._prefill_fns, b._decode_fns = 0, {}, {}
+        mb = MAX_SEQ // BLOCK
+        with mesh:
+            for t, pb, wave in ADMIT:
+                n_ints = wave * (t + t // BLOCK + pb + 6)
+                t0 = time.time()
+                report(f"admit tail={t} prefix_blocks={pb} wave={wave}",
+                       b._admit_jit(t, pb, wave).lower(
+                           params, arr((n_ints,), jnp.int32),
+                           arr((2, wave), jnp.float32), paged), t0)
+            for k in DECODE:
+                t0 = time.time()
+                report(f"decode chunk k={k}",
+                       b._decode_jit(k, SLOTS, mb).lower(
+                           params, arr((SLOTS,), jnp.int32),
+                           arr((SLOTS * (mb + 7),), jnp.int32),
+                           arr((2, SLOTS), jnp.float32), paged), t0)
+
+
+if __name__ == "__main__":
+    main([int(a) for a in sys.argv[1:]] or [1, 4])

@@ -23,8 +23,6 @@ followers = sys.argv[4] if len(sys.argv) > 4 else ""
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"
 sys.path.insert(0, {repo!r})
-import jax
-jax.config.update("jax_platforms", "cpu")
 from distributed_llm_inferencing_tpu.runtime.multihost import (
     LockstepFollower, LockstepLeader, init_multihost)
 from distributed_llm_inferencing_tpu.runtime.worker import WorkerAgent
@@ -304,7 +302,7 @@ def slice2_dist_restartable():
 
 def test_elastic_recovery_reforms_distributed_runtime(
         slice2_dist_restartable):
-    """Round-4 (VERDICT ask #7): elastic recovery on a REAL
+    """Elastic recovery on a REAL
     jax.distributed slice. The tp=2 model's collectives span both
     processes, so serving after the restart is only possible if the
     restarted follower actually rejoined a fresh distributed job AND

@@ -236,7 +236,7 @@ def test_fused_supported_gate():
 def _batch_tokens(monkeypatch, fused: bool, quant=None, spec=False):
     from distributed_llm_inferencing_tpu.runtime.batcher import (
         ContinuousBatcher)
-    monkeypatch.setenv("DLI_FUSED_DECODE", "1" if fused else "0")
+    monkeypatch.setenv("DLI_FUSED_DECODE", "interpret" if fused else "0")
     cfg = get_config("tiny-llama").replace(dtype="float32",
                                            attn_backend="xla")
     params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)

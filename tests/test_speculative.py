@@ -358,8 +358,8 @@ def test_batcher_speculative_matches_plain():
 
 
 def test_batcher_speculative_sampled_accepts_drafts():
-    """do_sample requests must get real accepted-draft speedups (VERDICT
-    round-3 ask #3): a lone sampled request on a highly repetitive prompt
+    """do_sample requests must get real accepted-draft speedups:
+    a lone sampled request on a highly repetitive prompt
     accepts at least one draft token."""
     from distributed_llm_inferencing_tpu.ops.sampling import SamplingParams
     from distributed_llm_inferencing_tpu.runtime.batcher import (

@@ -1,0 +1,153 @@
+"""Mosaic compiles of the Pallas kernels at mistral-7b's real shapes.
+
+Interpret mode (every other Pallas test in the suite) cannot see what the
+TPU compiler refuses: a block off the (8, 128) tiling, more scoped VMEM
+than a kernel may have. libtpu compiles for a chip that is described and
+not attached, so these tests lower each kernel for one device of a
+``v5e:2x2`` topology — shapes only, nothing runs — and fail with whatever
+the chip's compiler would raise.
+
+The topology is described inside a module-scoped fixture (never at
+import time: one process at a time may load libtpu, and every xdist
+worker imports every test file), and every compile happens in the test's
+own process. Keep these tests in this one file.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from distributed_llm_inferencing_tpu.models.registry import get_config
+
+# mistral-7b (models/registry.py): the widths chip_smoke.py serves
+CFG = get_config("mistral-7b")
+H, HKV, HD = CFG.num_heads, CFG.num_kv_heads, CFG.head_dim
+D, I, WINDOW = CFG.hidden_size, CFG.intermediate_size, CFG.sliding_window
+# the smoke's serving shape (chip_smoke.py LOAD): slots, block size, pool
+# blocks (+1 reserved dummy) and block-table width max_seq / block_size
+SLOTS, BS, NB, MB = 8, 16, 1025, 128
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def compile_on_chip(topo):
+    """compile_on_chip(fn, (shape, dtype) pytree...) -> compiled HLO text.
+    Arguments are ShapeDtypeStructs placed on one described device; the
+    persistent compile cache is off around the compile (an entry written
+    for a described chip cannot be read back without one, and warns)."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def struct(leaf):
+        shape, dtype = leaf
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def run(fn, *args):
+        args = [jax.tree.map(struct, a,
+                             is_leaf=lambda x: isinstance(x, tuple))
+                for a in args]
+        cache_was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        try:
+            text = jax.jit(fn).lower(*args).compile().as_text()
+        finally:
+            jax.config.update("jax_enable_compilation_cache", cache_was)
+        assert "tpu_custom_call" in text, "kernel missing from the program"
+        return text
+
+    return run
+
+
+# prefill: the batcher's tail buckets (1..512 blocks of 16 tokens) give
+# power-of-two lengths; a short one, the prefill-chunk length and a long
+# one cover the block-picking branches
+@pytest.mark.parametrize("batch,seq", [(1, 16), (1, 512), (4, 512),
+                                       (1, 2048)])
+def test_flash_attention_compiles(compile_on_chip, batch, seq):
+    from distributed_llm_inferencing_tpu.ops.pallas import flash_attention
+    compile_on_chip(
+        functools.partial(flash_attention, sliding_window=WINDOW),
+        ((batch, seq, H, HD), BF16), ((batch, seq, HKV, HD), BF16),
+        ((batch, seq, HKV, HD), BF16))
+
+
+@pytest.mark.parametrize("cache_len", [2048, 8192])
+def test_flash_decode_compiles(compile_on_chip, cache_len):
+    from distributed_llm_inferencing_tpu.ops.pallas import flash_decode
+    compile_on_chip(
+        functools.partial(flash_decode, sliding_window=WINDOW),
+        ((SLOTS, 1, H, HD), BF16), ((SLOTS, cache_len, HKV, HD), BF16),
+        ((SLOTS, cache_len, HKV, HD), BF16), ((SLOTS,), jnp.int32))
+
+
+def test_flash_kernels_compile_with_alibi(compile_on_chip):
+    """The ALiBi slopes ride SMEM beside the lengths; same tiling rule."""
+    from distributed_llm_inferencing_tpu.ops.attention import alibi_slopes
+    from distributed_llm_inferencing_tpu.ops.pallas import (
+        flash_attention, flash_decode)
+    heads, hd = 32, 64    # falcon-rw-1b's attention shape
+    compile_on_chip(
+        lambda q, k, v: flash_attention(q, k, v, alibi=alibi_slopes(heads)),
+        *[((1, 512, heads, hd), BF16)] * 3)
+    compile_on_chip(
+        lambda q, k, v, n: flash_decode(q, k, v, n,
+                                        alibi=alibi_slopes(heads)),
+        ((SLOTS, 1, heads, hd), BF16), ((SLOTS, 1024, heads, hd), BF16),
+        ((SLOTS, 1024, heads, hd), BF16), ((SLOTS,), jnp.int32))
+
+
+def test_paged_flash_decode_compiles(compile_on_chip):
+    from distributed_llm_inferencing_tpu.ops.pallas.paged_attention import (
+        paged_flash_decode)
+    compile_on_chip(
+        functools.partial(paged_flash_decode, sliding_window=WINDOW),
+        ((SLOTS, 1, H, HD), BF16), ((NB, BS, HKV, HD), BF16),
+        ((NB, BS, HKV, HD), BF16), ((SLOTS, MB), jnp.int32),
+        ((SLOTS,), jnp.int32))
+
+
+@pytest.mark.parametrize("form", ["float", "int8", "int4"])
+def test_fused_decode_step_compiles(compile_on_chip, form):
+    from distributed_llm_inferencing_tpu.ops.pallas.fused_decode import (
+        fused_decode_step)
+    q_leaf = {
+        "float": {"w": ((D, H * HD), BF16)},
+        "int8": {"q": ((D, H * HD), jnp.int8),
+                 "scale": ((H * HD,), jnp.float32)},
+        "int4": {"p4": ((D // 2, H * HD), jnp.uint8),
+                 "scale": ((H * HD,), jnp.float32)},
+    }[form]
+
+    def step(x, leaf, k, v, bt, lens, cos, sin):
+        return fused_decode_step(x, leaf, k, v, bt, lens, rope_cos=cos,
+                                 rope_sin=sin, sliding_window=WINDOW)
+
+    compile_on_chip(
+        step, ((SLOTS, D), BF16), q_leaf, ((NB, BS, HKV, HD), BF16),
+        ((NB, BS, HKV, HD), BF16), ((SLOTS, MB), jnp.int32),
+        ((SLOTS,), jnp.int32), ((SLOTS, HD), jnp.float32),
+        ((SLOTS, HD), jnp.float32))
+
+
+# up/gate (4096 x 14336) and down (14336 x 4096); f32 activations take
+# the sign-extending kernel variant
+@pytest.mark.parametrize("din,dout,act", [(D, I, BF16), (I, D, BF16),
+                                          (I, D, jnp.float32)])
+def test_q4_matmul_compiles(compile_on_chip, din, dout, act):
+    from distributed_llm_inferencing_tpu.ops.pallas.quant_matmul import (
+        q4_matmul)
+    compile_on_chip(
+        q4_matmul, ((SLOTS, din), act), ((din // 2, dout), jnp.uint8),
+        ((dout,), jnp.float32))
