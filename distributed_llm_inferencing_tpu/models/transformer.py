@@ -148,6 +148,7 @@ def _lora_apply(y, x, lp, name, lora_ids):
     return y + lora_ops.gathered_delta(x, lo[name], ids)
 
 
+@jax.named_scope("mlp")
 def _mlp(x, lp, cfg: ModelConfig, lora_ids=None):
     if cfg.gated_mlp:
         h = _act(_lora_apply(_linear(x, lp["gate"]), x, lp, "gate",
@@ -168,6 +169,7 @@ def _ew(operand, p, eq):
     return jnp.einsum(eq, operand, p["w"])
 
 
+@jax.named_scope("moe_route")
 def _moe_gates(x, lp, cfg: ModelConfig):
     """Router probs → weighted top-k gates [..., E].
 
@@ -238,6 +240,7 @@ def _glu_h(gate, up, cfg: ModelConfig):
     return _act(gate, cfg.activation) * up
 
 
+@jax.named_scope("moe_experts")
 def _moe_dense(x, lp, cfg: ModelConfig):
     """Compute every expert for every token, weight by the gate. E/k× the
     FLOPs of a real dispatch, but no permutation/comm beyond the psum the
@@ -256,6 +259,7 @@ def _moe_dense(x, lp, cfg: ModelConfig):
     return out.astype(x.dtype)
 
 
+@jax.named_scope("moe_experts")
 def _moe_capacity(x, lp, cfg: ModelConfig):
     """GShard-style capacity dispatch: each expert processes at most C
     tokens, routed via dispatch/combine einsums (static shapes — XLA turns
@@ -274,20 +278,22 @@ def _moe_capacity(x, lp, cfg: ModelConfig):
     C = max(1, int(cfg.moe_capacity_factor * k * N / E))
 
     gate = _moe_gates(xf, lp, cfg)                          # [N, E] f32
-    gate_vals, gate_idx = jax.lax.top_k(gate, k)            # [N, k]
-    onehot = jax.nn.one_hot(gate_idx, E, dtype=jnp.float32)  # [N, k, E]
-    # position of each (token, choice) within its expert's capacity buffer:
-    # priority by token order, then by choice slot (flatten to [N*k, E])
-    flat = onehot.reshape(N * k, E)
-    pos = (jnp.cumsum(flat, axis=0) * flat - 1.0).reshape(N, k, E)
-    keep = (pos >= 0) & (pos < C)                           # [N, k, E]
-    # combine[n, e, c] = gate weight of token n at expert e, slot c
-    slot = jnp.clip(pos, 0, C - 1).astype(jnp.int32)
-    combine = jnp.einsum(
-        "nke,nkec->nec",
-        onehot * gate_vals[..., None] * keep,
-        jax.nn.one_hot(slot, C, dtype=jnp.float32))
-    dispatch = (combine > 0).astype(x.dtype)                # [N, E, C]
+    with jax.named_scope("moe_route"):
+        gate_vals, gate_idx = jax.lax.top_k(gate, k)        # [N, k]
+        onehot = jax.nn.one_hot(gate_idx, E, dtype=jnp.float32)  # [N, k, E]
+        # position of each (token, choice) within its expert's capacity
+        # buffer: priority by token order, then by choice slot (flatten
+        # to [N*k, E])
+        flat = onehot.reshape(N * k, E)
+        pos = (jnp.cumsum(flat, axis=0) * flat - 1.0).reshape(N, k, E)
+        keep = (pos >= 0) & (pos < C)                       # [N, k, E]
+        # combine[n, e, c] = gate weight of token n at expert e, slot c
+        slot = jnp.clip(pos, 0, C - 1).astype(jnp.int32)
+        combine = jnp.einsum(
+            "nke,nkec->nec",
+            onehot * gate_vals[..., None] * keep,
+            jax.nn.one_hot(slot, C, dtype=jnp.float32))
+        dispatch = (combine > 0).astype(x.dtype)            # [N, E, C]
 
     ex_in = jnp.einsum("nec,nd->ecd", dispatch, xf)         # [E, C, D]
     ex = lp["experts"]
@@ -324,10 +330,11 @@ def _moe(x, lp, cfg: ModelConfig):
     out = (_moe_capacity(x, lp, cfg) if mode == "capacity"
            else _moe_dense(x, lp, cfg))
     if cfg.moe_shared_experts:
-        h = _act(_linear(x, lp["shared_gate"]), cfg.activation) * _linear(
-            x, lp["shared_up"])
-        out = out + _linear(h, lp["shared_down"],
-                            row_sharded=cfg.tp_row_sharded)
+        with jax.named_scope("mlp"):
+            h = _act(_linear(x, lp["shared_gate"]), cfg.activation) \
+                * _linear(x, lp["shared_up"])
+            out = out + _linear(h, lp["shared_down"],
+                                row_sharded=cfg.tp_row_sharded)
     return out
 
 
@@ -417,6 +424,7 @@ def embed(params, cfg: ModelConfig, tokens, q_positions):
     return x
 
 
+@jax.named_scope("lm_head")
 def unembed(params, cfg: ModelConfig, x):
     """Final norm + logits head, f32. Shared with parallel/pipeline.py.
 
@@ -1032,16 +1040,18 @@ def paged_decode_step(params, cfg: ModelConfig, tokens, paged,
 
             if use_fused and fused_decode.supported(seg_cfg, lp["q"]):
                 def fused_q_attend(h, k, v):
-                    nk = write_token(ck, k[:, 0], block_tables,
-                                     context_lens)
-                    nv = write_token(cv, v[:, 0], block_tables,
-                                     context_lens)
-                    attn = fused_decode.fused_decode_step(
-                        h[:, 0], lp["q"], nk, nv, block_tables,
-                        context_lens + 1,
-                        rope_cos=rope_cos, rope_sin=rope_sin,
-                        sliding_window=_layer_window(seg_cfg, lp),
-                        interpret=fused_interpret)
+                    with jax.named_scope("kv_write"):
+                        nk = write_token(ck, k[:, 0], block_tables,
+                                         context_lens)
+                        nv = write_token(cv, v[:, 0], block_tables,
+                                         context_lens)
+                    with jax.named_scope("attention"):
+                        attn = fused_decode.fused_decode_step(
+                            h[:, 0], lp["q"], nk, nv, block_tables,
+                            context_lens + 1,
+                            rope_cos=rope_cos, rope_sin=rope_sin,
+                            sliding_window=_layer_window(seg_cfg, lp),
+                            interpret=fused_interpret)
                     return attn[:, None], (nk, nv)
                 return _block_body(x, lp, seg_cfg, q_pos, None,
                                    fused_q_attend=fused_q_attend)
@@ -1051,12 +1061,17 @@ def paged_decode_step(params, cfg: ModelConfig, tokens, paged,
                     from distributed_llm_inferencing_tpu.ops.kvcache import (
                         quant_kv)
                     cks, cvs = scales
-                    k8, ks = quant_kv(k[:, 0])
-                    v8, vs = quant_kv(v[:, 0])
-                    nk = write_token(ck, k8, block_tables, context_lens)
-                    nv = write_token(cv, v8, block_tables, context_lens)
-                    nks = write_token(cks, ks, block_tables, context_lens)
-                    nvs = write_token(cvs, vs, block_tables, context_lens)
+                    with jax.named_scope("kv_write"):
+                        k8, ks = quant_kv(k[:, 0])
+                        v8, vs = quant_kv(v[:, 0])
+                        nk = write_token(ck, k8, block_tables,
+                                         context_lens)
+                        nv = write_token(cv, v8, block_tables,
+                                         context_lens)
+                        nks = write_token(cks, ks, block_tables,
+                                          context_lens)
+                        nvs = write_token(cvs, vs, block_tables,
+                                          context_lens)
                     attn = paged_attend_decode(
                         q, nk, nv, block_tables, context_lens + 1,
                         sliding_window=_layer_window(seg_cfg, lp),
@@ -1065,8 +1080,11 @@ def paged_decode_step(params, cfg: ModelConfig, tokens, paged,
                         alibi=_alibi(seg_cfg), softcap=seg_cfg.attn_softcap,
                         sinks=_sinks(seg_cfg, lp))
                     return attn, (nk, nv, nks, nvs)
-                nk = write_token(ck, k[:, 0], block_tables, context_lens)
-                nv = write_token(cv, v[:, 0], block_tables, context_lens)
+                with jax.named_scope("kv_write"):
+                    nk = write_token(ck, k[:, 0], block_tables,
+                                     context_lens)
+                    nv = write_token(cv, v[:, 0], block_tables,
+                                     context_lens)
                 attn = paged_attend_decode(
                     q, nk, nv, block_tables, context_lens + 1,
                     sliding_window=_layer_window(seg_cfg, lp),
@@ -1090,6 +1108,37 @@ def paged_decode_step(params, cfg: ModelConfig, tokens, paged,
 # up front (see paged_decode_chunk): under it, one gather per chunk; over
 # it (long contexts), one transient per-layer gather per step.
 _PREGATHER_MAX_BYTES = 256 * 1024 * 1024
+
+
+@jax.named_scope("kv_gather")
+def _pool_pregather(paged, block_tables, shape, dt):
+    """K and V of every slot's whole block table, all layers in one
+    gather each: ``shape`` [L, R, MB*bs, Hkv, hd], dequantized to ``dt``
+    where the pool is int8."""
+    pool_k = paged.k[:, block_tables].reshape(shape)
+    pool_v = paged.v[:, block_tables].reshape(shape)
+    if paged.quantized:
+        from distributed_llm_inferencing_tpu.ops.kvcache import dequant_kv
+        pool_k = dequant_kv(
+            pool_k, paged.k_scale[:, block_tables].reshape(shape[:-1]), dt)
+        pool_v = dequant_kv(
+            pool_v, paged.v_scale[:, block_tables].reshape(shape[:-1]), dt)
+    return pool_k, pool_v
+
+
+@jax.named_scope("kv_gather")
+def _layer_gather(ck, cv, scales, block_tables, dt):
+    """One layer's K and V gathered inside the step (long contexts, where
+    the whole chunk's gather would pass _PREGATHER_MAX_BYTES); ``scales``
+    is the layer's (k_scale, v_scale) for an int8 pool, else empty."""
+    from distributed_llm_inferencing_tpu.ops.paged_kvcache import gather_seq
+    kp, vp = gather_seq(ck, block_tables), gather_seq(cv, block_tables)
+    if scales:
+        from distributed_llm_inferencing_tpu.ops.kvcache import dequant_kv
+        cks, cvs = scales
+        kp = dequant_kv(kp, gather_seq(cks, block_tables), dt)
+        vp = dequant_kv(vp, gather_seq(cvs, block_tables), dt)
+    return kp, vp
 
 
 def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
@@ -1169,17 +1218,9 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
         * cfg.num_kv_heads * cfg.head_dim
     pre = gathered_bytes <= _PREGATHER_MAX_BYTES
     if pre:
-        shape = (L, r, mb * bs, cfg.num_kv_heads, cfg.head_dim)
-        pool_k = paged.k[:, block_tables].reshape(shape)
-        pool_v = paged.v[:, block_tables].reshape(shape)
-        if quantized:
-            from distributed_llm_inferencing_tpu.ops.kvcache import dequant_kv
-            pool_k = dequant_kv(
-                pool_k, paged.k_scale[:, block_tables].reshape(shape[:-1]),
-                dt)
-            pool_v = dequant_kv(
-                pool_v, paged.v_scale[:, block_tables].reshape(shape[:-1]),
-                dt)
+        pool_k, pool_v = _pool_pregather(
+            paged, block_tables,
+            (L, r, mb * bs, cfg.num_kv_heads, cfg.head_dim), dt)
     else:
         pool_k, pool_v = paged.k, paged.v   # gathered per layer in-loop
 
@@ -1194,36 +1235,29 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
 
         def make_layer(seg_cfg):
             def layer(x, layer_in):
-                if pre:
-                    lp, sk, sv, kp, vp = layer_in
-                elif quantized:
-                    from distributed_llm_inferencing_tpu.ops.kvcache import (
-                        dequant_kv)
-                    lp, sk, sv, ck, cv, cks, cvs = layer_in
-                    kp = dequant_kv(gather_seq(ck, block_tables),
-                                    gather_seq(cks, block_tables), dt)
-                    vp = dequant_kv(gather_seq(cv, block_tables),
-                                    gather_seq(cvs, block_tables), dt)
-                else:
-                    lp, sk, sv, ck, cv = layer_in
-                    kp, vp = gather_seq(ck, block_tables), gather_seq(
-                        cv, block_tables)
+                lp, sk, sv, kp, vp, *scales = layer_in
+                if not pre:
+                    kp, vp = _layer_gather(kp, vp, scales, block_tables, dt)
 
                 def attend_write(q, kh, vh):
-                    sk2 = jax.lax.dynamic_update_slice(sk, kh.astype(dt),
-                                                       (0, t, 0, 0))
-                    sv2 = jax.lax.dynamic_update_slice(sv, vh.astype(dt),
-                                                       (0, t, 0, 0))
-                    attn = attend(
-                        q,
-                        jnp.concatenate([kp, sk2], axis=1),
-                        jnp.concatenate([vp, sv2], axis=1),
-                        q_pos,
-                        jnp.concatenate([pool_pos, side_pos], axis=1),
-                        jnp.concatenate([pool_valid, side_valid], axis=1),
-                        sliding_window=_layer_window(seg_cfg, lp),
-                        alibi=_alibi(seg_cfg), softcap=seg_cfg.attn_softcap,
-                        sinks=_sinks(seg_cfg, lp))
+                    with jax.named_scope("kv_write"):
+                        sk2 = jax.lax.dynamic_update_slice(
+                            sk, kh.astype(dt), (0, t, 0, 0))
+                        sv2 = jax.lax.dynamic_update_slice(
+                            sv, vh.astype(dt), (0, t, 0, 0))
+                    with jax.named_scope("attention"):
+                        attn = attend(
+                            q,
+                            jnp.concatenate([kp, sk2], axis=1),
+                            jnp.concatenate([vp, sv2], axis=1),
+                            q_pos,
+                            jnp.concatenate([pool_pos, side_pos], axis=1),
+                            jnp.concatenate([pool_valid, side_valid],
+                                            axis=1),
+                            sliding_window=_layer_window(seg_cfg, lp),
+                            alibi=_alibi(seg_cfg),
+                            softcap=seg_cfg.attn_softcap,
+                            sinks=_sinks(seg_cfg, lp))
                     return attn, (sk2, sv2)
 
                 x, (sk2, sv2) = _block_body(x, lp, seg_cfg, q_pos,
@@ -1238,7 +1272,9 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
         x2, (side_k, side_v) = scan_layer_stack(make_layer, x, params, cfg,
                                                 xs)
         logits = unembed(params, cfg, x2)[:, 0]
-        nxt = sample_batch(logits, seeds, steps0 + t, temps, tks, tps, ds)
+        with jax.named_scope("sample"):
+            nxt = sample_batch(logits, seeds, steps0 + t, temps, tks, tps,
+                               ds)
         is_eos = alive & (eos_ids >= 0) & (nxt == eos_ids)
         emit = alive & ~is_eos
         new_cl = cl + alive.astype(cl.dtype)   # advance iff wrote this step
@@ -1251,25 +1287,26 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
 
     # ONE scatter of the whole chunk's K/V into the pool (never-written
     # steps of dead/inactive slots land in the reserved dummy block)
-    pos = cl0[None, :] + jnp.arange(k, dtype=jnp.int32)[:, None]   # [K, R]
-    blk = jnp.take_along_axis(block_tables,
-                              jnp.swapaxes(pos // bs, 0, 1), axis=1)
-    blk = jnp.where(wrote, jnp.swapaxes(blk, 0, 1), dummy_block)   # [K, R]
-    off = pos % bs
-    if quantized:
-        from distributed_llm_inferencing_tpu.ops.kvcache import quant_kv
-        k8, ks = quant_kv(side_k)
-        v8, vs = quant_kv(side_v)
-        return toks, emits, PagedKVCache(
-            k=paged.k.at[:, blk, off].set(jnp.swapaxes(k8, 1, 2)),
-            v=paged.v.at[:, blk, off].set(jnp.swapaxes(v8, 1, 2)),
-            k_scale=paged.k_scale.at[:, blk, off].set(
-                jnp.swapaxes(ks, 1, 2)),
-            v_scale=paged.v_scale.at[:, blk, off].set(
-                jnp.swapaxes(vs, 1, 2)))
-    new_k = paged.k.at[:, blk, off].set(jnp.swapaxes(side_k, 1, 2))
-    new_v = paged.v.at[:, blk, off].set(jnp.swapaxes(side_v, 1, 2))
-    return toks, emits, PagedKVCache(k=new_k, v=new_v)
+    with jax.named_scope("kv_write"):
+        pos = cl0[None, :] + jnp.arange(k, dtype=jnp.int32)[:, None]  # [K, R]
+        blk = jnp.take_along_axis(block_tables,
+                                  jnp.swapaxes(pos // bs, 0, 1), axis=1)
+        blk = jnp.where(wrote, jnp.swapaxes(blk, 0, 1), dummy_block)  # [K, R]
+        off = pos % bs
+        if quantized:
+            from distributed_llm_inferencing_tpu.ops.kvcache import quant_kv
+            k8, ks = quant_kv(side_k)
+            v8, vs = quant_kv(side_v)
+            return toks, emits, PagedKVCache(
+                k=paged.k.at[:, blk, off].set(jnp.swapaxes(k8, 1, 2)),
+                v=paged.v.at[:, blk, off].set(jnp.swapaxes(v8, 1, 2)),
+                k_scale=paged.k_scale.at[:, blk, off].set(
+                    jnp.swapaxes(ks, 1, 2)),
+                v_scale=paged.v_scale.at[:, blk, off].set(
+                    jnp.swapaxes(vs, 1, 2)))
+        new_k = paged.k.at[:, blk, off].set(jnp.swapaxes(side_k, 1, 2))
+        new_v = paged.v.at[:, blk, off].set(jnp.swapaxes(side_v, 1, 2))
+        return toks, emits, PagedKVCache(k=new_k, v=new_v)
 
 
 def _paged_decode_chunk_stepwise(params, cfg: ModelConfig, k: int, tokens,
@@ -1289,7 +1326,9 @@ def _paged_decode_chunk_stepwise(params, cfg: ModelConfig, k: int, tokens,
         cl_eff = jnp.where(alive, cl, 0)
         logits, paged = paged_decode_step(params, cfg, cur, paged, bt_eff,
                                           cl_eff, lora_ids=lora_ids)
-        nxt = sample_batch(logits, seeds, steps0 + t, temps, tks, tps, ds)
+        with jax.named_scope("sample"):
+            nxt = sample_batch(logits, seeds, steps0 + t, temps, tks, tps,
+                               ds)
         is_eos = alive & (eos_ids >= 0) & (nxt == eos_ids)
         emit = alive & ~is_eos
         new_cl = cl + alive.astype(cl.dtype)
@@ -1391,20 +1430,10 @@ def paged_speculative_chunk(params, cfg: ModelConfig, k: int, gamma: int,
         * cfg.num_kv_heads * cfg.head_dim
     pre = gathered_bytes <= _PREGATHER_MAX_BYTES
     if pre:
-        shape = (L, r, mb * bs, cfg.num_kv_heads, cfg.head_dim)
-        pool_k = paged.k[:, block_tables].reshape(shape)
-        pool_v = paged.v[:, block_tables].reshape(shape)
-        if quantized:
-            from distributed_llm_inferencing_tpu.ops.kvcache import dequant_kv
-            pool_k = dequant_kv(
-                pool_k, paged.k_scale[:, block_tables].reshape(shape[:-1]),
-                dt)
-            pool_v = dequant_kv(
-                pool_v, paged.v_scale[:, block_tables].reshape(shape[:-1]),
-                dt)
+        pool_k, pool_v = _pool_pregather(
+            paged, block_tables,
+            (L, r, mb * bs, cfg.num_kv_heads, cfg.head_dim), dt)
     else:
-        from distributed_llm_inferencing_tpu.ops.paged_kvcache import (
-            gather_seq)
         pool_k, pool_v = paged.k, paged.v   # gathered per layer in-loop
 
     def body(carry, t):
@@ -1422,36 +1451,29 @@ def paged_speculative_chunk(params, cfg: ModelConfig, k: int, gamma: int,
 
         def make_layer(seg_cfg):
             def layer(x, layer_in):
-                if pre:
-                    lp, sk, sv, kp, vp = layer_in
-                elif quantized:
-                    from distributed_llm_inferencing_tpu.ops.kvcache import (
-                        dequant_kv)
-                    lp, sk, sv, ck, cv, cks, cvs = layer_in
-                    kp = dequant_kv(gather_seq(ck, block_tables),
-                                    gather_seq(cks, block_tables), dt)
-                    vp = dequant_kv(gather_seq(cv, block_tables),
-                                    gather_seq(cvs, block_tables), dt)
-                else:
-                    lp, sk, sv, ck, cv = layer_in
-                    kp, vp = gather_seq(ck, block_tables), gather_seq(
-                        cv, block_tables)
+                lp, sk, sv, kp, vp, *scales = layer_in
+                if not pre:
+                    kp, vp = _layer_gather(kp, vp, scales, block_tables, dt)
 
                 def attend_write(q, kh, vh):
-                    sk2 = jax.lax.dynamic_update_slice(sk, kh.astype(dt),
-                                                       (0, t * g1, 0, 0))
-                    sv2 = jax.lax.dynamic_update_slice(sv, vh.astype(dt),
-                                                       (0, t * g1, 0, 0))
-                    attn = attend(
-                        q,
-                        jnp.concatenate([kp, sk2], axis=1),
-                        jnp.concatenate([vp, sv2], axis=1),
-                        qp,
-                        jnp.concatenate([pool_pos, side_pos], axis=1),
-                        jnp.concatenate([pool_valid, side_valid], axis=1),
-                        sliding_window=_layer_window(seg_cfg, lp),
-                        alibi=_alibi(seg_cfg), softcap=seg_cfg.attn_softcap,
-                        sinks=_sinks(seg_cfg, lp))
+                    with jax.named_scope("kv_write"):
+                        sk2 = jax.lax.dynamic_update_slice(
+                            sk, kh.astype(dt), (0, t * g1, 0, 0))
+                        sv2 = jax.lax.dynamic_update_slice(
+                            sv, vh.astype(dt), (0, t * g1, 0, 0))
+                    with jax.named_scope("attention"):
+                        attn = attend(
+                            q,
+                            jnp.concatenate([kp, sk2], axis=1),
+                            jnp.concatenate([vp, sv2], axis=1),
+                            qp,
+                            jnp.concatenate([pool_pos, side_pos], axis=1),
+                            jnp.concatenate([pool_valid, side_valid],
+                                            axis=1),
+                            sliding_window=_layer_window(seg_cfg, lp),
+                            alibi=_alibi(seg_cfg),
+                            softcap=seg_cfg.attn_softcap,
+                            sinks=_sinks(seg_cfg, lp))
                     return attn, (sk2, sv2)
 
                 x, (sk2, sv2) = _block_body(x, lp, seg_cfg, qp,
@@ -1472,9 +1494,10 @@ def paged_speculative_chunk(params, cfg: ModelConfig, k: int, gamma: int,
         # sampled rows run exact leave-one-out rejection against the same
         # warped distribution sample_batch draws from — real speedups for
         # do_sample requests with the target distribution preserved
-        toks_out, n_emit = accept_rejection_batch(
-            logits, drafts, seeds, steps0 + emitted, temps, tks, tps, ds,
-            widths=gammas)
+        with jax.named_scope("sample"):
+            toks_out, n_emit = accept_rejection_batch(
+                logits, drafts, seeds, steps0 + emitted, temps, tks, tps,
+                ds, widths=gammas)
         idx = jnp.arange(g1, dtype=jnp.int32)[None, :]
 
         # eos / budget clamping
@@ -1531,21 +1554,23 @@ def paged_speculative_chunk(params, cfg: ModelConfig, k: int, gamma: int,
             body, carry0, jnp.arange(k, dtype=jnp.int32))
 
     # single pool scatter of the accepted side entries
-    blk = jnp.take_along_axis(block_tables, side_pos // bs, axis=1)  # [R, E]
-    blk = jnp.where(acc_mask, blk, dummy_block)
-    off = side_pos % bs
-    if quantized:
-        from distributed_llm_inferencing_tpu.ops.kvcache import quant_kv
-        k8, ks = quant_kv(side_k)
-        v8, vs = quant_kv(side_v)
-        paged = PagedKVCache(
-            k=paged.k.at[:, blk, off].set(k8),
-            v=paged.v.at[:, blk, off].set(v8),
-            k_scale=paged.k_scale.at[:, blk, off].set(ks),
-            v_scale=paged.v_scale.at[:, blk, off].set(vs))
-    else:
-        paged = PagedKVCache(k=paged.k.at[:, blk, off].set(side_k),
-                             v=paged.v.at[:, blk, off].set(side_v))
+    with jax.named_scope("kv_write"):
+        blk = jnp.take_along_axis(block_tables, side_pos // bs,
+                                  axis=1)                         # [R, E]
+        blk = jnp.where(acc_mask, blk, dummy_block)
+        off = side_pos % bs
+        if quantized:
+            from distributed_llm_inferencing_tpu.ops.kvcache import quant_kv
+            k8, ks = quant_kv(side_k)
+            v8, vs = quant_kv(side_v)
+            paged = PagedKVCache(
+                k=paged.k.at[:, blk, off].set(k8),
+                v=paged.v.at[:, blk, off].set(v8),
+                k_scale=paged.k_scale.at[:, blk, off].set(ks),
+                v_scale=paged.v_scale.at[:, blk, off].set(vs))
+        else:
+            paged = PagedKVCache(k=paged.k.at[:, blk, off].set(side_k),
+                                 v=paged.v.at[:, blk, off].set(side_v))
     return toks, keeps, eos_seen, paged
 
 
@@ -1594,12 +1619,13 @@ def paged_prefill_tail(params, cfg: ModelConfig, tokens, tail_len,
                     from distributed_llm_inferencing_tpu.ops.kvcache import (
                         quant_kv)
                     cks, cvs = scales
-                    k8, ks = quant_kv(k)
-                    v8, vs = quant_kv(v)
-                    nk = write_block_run(ck, k8, tail_blocks)
-                    nv = write_block_run(cv, v8, tail_blocks)
-                    nks = write_block_run(cks, ks, tail_blocks)
-                    nvs = write_block_run(cvs, vs, tail_blocks)
+                    with jax.named_scope("kv_write"):
+                        k8, ks = quant_kv(k)
+                        v8, vs = quant_kv(v)
+                        nk = write_block_run(ck, k8, tail_blocks)
+                        nv = write_block_run(cv, v8, tail_blocks)
+                        nks = write_block_run(cks, ks, tail_blocks)
+                        nvs = write_block_run(cvs, vs, tail_blocks)
                     attn = paged_attend_prefix(
                         q, k, v, nk, nv, prefix_blocks, prefix_len, q_pos,
                         tail_valid,
@@ -1608,8 +1634,9 @@ def paged_prefill_tail(params, cfg: ModelConfig, tokens, tail_len,
                         alibi=_alibi(seg_cfg), softcap=seg_cfg.attn_softcap,
                         sinks=_sinks(seg_cfg, lp))
                     return attn, (nk, nv, nks, nvs)
-                nk = write_block_run(ck, k, tail_blocks)
-                nv = write_block_run(cv, v, tail_blocks)
+                with jax.named_scope("kv_write"):
+                    nk = write_block_run(ck, k, tail_blocks)
+                    nv = write_block_run(cv, v, tail_blocks)
                 attn = paged_attend_prefix(
                     q, k, v, nk, nv, prefix_blocks, prefix_len, q_pos,
                     tail_valid, sliding_window=_layer_window(seg_cfg, lp),

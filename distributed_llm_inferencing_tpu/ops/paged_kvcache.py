@@ -139,25 +139,31 @@ def paged_attend_decode(q, cache_k_layer, cache_v_layer, block_tables,
             and alibi is None and sinks is None:
         from distributed_llm_inferencing_tpu.ops.pallas.paged_attention import (
             paged_flash_decode)
-        return paged_flash_decode(
-            q, cache_k_layer, cache_v_layer, block_tables, context_lens,
-            sliding_window=sliding_window,
-            interpret=(backend == "pallas_interpret"))
+        with jax.named_scope("attention"):
+            return paged_flash_decode(
+                q, cache_k_layer, cache_v_layer, block_tables, context_lens,
+                sliding_window=sliding_window,
+                interpret=(backend == "pallas_interpret"))
     r, mb = block_tables.shape
     bs = cache_k_layer.shape[1]
-    k = gather_seq(cache_k_layer, block_tables)
-    v = gather_seq(cache_v_layer, block_tables)
-    if k_scale_layer is not None:
-        from distributed_llm_inferencing_tpu.ops.kvcache import dequant_kv
-        k = dequant_kv(k, gather_seq(k_scale_layer, block_tables), q.dtype)
-        v = dequant_kv(v, gather_seq(v_scale_layer, block_tables), q.dtype)
+    with jax.named_scope("kv_gather"):
+        k = gather_seq(cache_k_layer, block_tables)
+        v = gather_seq(cache_v_layer, block_tables)
+        if k_scale_layer is not None:
+            from distributed_llm_inferencing_tpu.ops.kvcache import (
+                dequant_kv)
+            k = dequant_kv(k, gather_seq(k_scale_layer, block_tables),
+                           q.dtype)
+            v = dequant_kv(v, gather_seq(v_scale_layer, block_tables),
+                           q.dtype)
     kv_pos = jnp.broadcast_to(jnp.arange(mb * bs, dtype=jnp.int32),
                               (r, mb * bs))
     kv_valid = kv_pos < context_lens[:, None]
     q_pos = (context_lens - 1)[:, None]
-    return attend(q, k, v, q_pos, kv_pos, kv_valid,
-                  sliding_window=sliding_window, alibi=alibi,
-                  softcap=softcap, sinks=sinks)
+    with jax.named_scope("attention"):
+        return attend(q, k, v, q_pos, kv_pos, kv_valid,
+                      sliding_window=sliding_window, alibi=alibi,
+                      softcap=softcap, sinks=sinks)
 
 
 def paged_attend_prefix(q, k_new, v_new, cache_k_layer, cache_v_layer,
@@ -180,14 +186,16 @@ def paged_attend_prefix(q, k_new, v_new, cache_k_layer, cache_v_layer,
     b, t = q.shape[0], q.shape[1]
     bs = cache_k_layer.shape[1]
     pb = prefix_blocks.shape[1]
-    kp = gather_seq(cache_k_layer, prefix_blocks)   # [B, PB*bs, Hkv, hd]
-    vp = gather_seq(cache_v_layer, prefix_blocks)
-    if k_scale_layer is not None:   # int8 pool: dequantize the prefix
-        from distributed_llm_inferencing_tpu.ops.kvcache import dequant_kv
-        kp = dequant_kv(kp, gather_seq(k_scale_layer, prefix_blocks),
-                        q.dtype)
-        vp = dequant_kv(vp, gather_seq(v_scale_layer, prefix_blocks),
-                        q.dtype)
+    with jax.named_scope("kv_gather"):
+        kp = gather_seq(cache_k_layer, prefix_blocks)   # [B, PB*bs, Hkv, hd]
+        vp = gather_seq(cache_v_layer, prefix_blocks)
+        if k_scale_layer is not None:   # int8 pool: dequantize the prefix
+            from distributed_llm_inferencing_tpu.ops.kvcache import (
+                dequant_kv)
+            kp = dequant_kv(kp, gather_seq(k_scale_layer, prefix_blocks),
+                            q.dtype)
+            vp = dequant_kv(vp, gather_seq(v_scale_layer, prefix_blocks),
+                            q.dtype)
     p = pb * bs
     prefix_pos = jnp.broadcast_to(jnp.arange(p, dtype=jnp.int32), (b, p))
     prefix_valid = prefix_pos < prefix_len[:, None]
@@ -196,6 +204,7 @@ def paged_attend_prefix(q, k_new, v_new, cache_k_layer, cache_v_layer,
     v_all = jnp.concatenate([vp, v_new.astype(vp.dtype)], axis=1)
     kv_pos = jnp.concatenate([prefix_pos, q_positions], axis=1)
     kv_valid = jnp.concatenate([prefix_valid, tail_valid], axis=1)
-    return attend(q, k_all, v_all, q_positions, kv_pos, kv_valid,
-                  sliding_window=sliding_window, alibi=alibi,
-                  softcap=softcap, sinks=sinks)
+    with jax.named_scope("attention"):
+        return attend(q, k_all, v_all, q_positions, kv_pos, kv_valid,
+                      sliding_window=sliding_window, alibi=alibi,
+                      softcap=softcap, sinks=sinks)

@@ -62,6 +62,7 @@ from distributed_llm_inferencing_tpu.ops.sampling import (
 from distributed_llm_inferencing_tpu.parallel import sharding as shd
 from distributed_llm_inferencing_tpu.parallel.mesh import (
     MeshSpec, create_mesh, validate_spec)
+from distributed_llm_inferencing_tpu.runtime import events
 from distributed_llm_inferencing_tpu.runtime import kvtier as kvtier_mod
 from distributed_llm_inferencing_tpu.runtime import kvwire as kvwire_mod
 from distributed_llm_inferencing_tpu.runtime import tsdb as tsdb_mod
@@ -96,6 +97,8 @@ class BatchRequest:
     # started dispatching — queue_ms = admitted_at - submitted_at, and
     # queue + prefill + decode sum exactly to the e2e span
     admitted_at: Optional[float] = None
+    # span id of that wave's batcher.admit_wave (joins batcher.queued)
+    _wave_span: Optional[str] = None
     # the finished record (phase ms + resource counts), built once in
     # _observe_finished; the worker attaches it to the response payload
     cost: Optional[dict] = None
@@ -234,6 +237,23 @@ class ContinuousBatcher:
     # remaining budget (budget masks make overshoot steps dead compute)
     # is a win as long as the overshoot stays small.
     CHUNK_OVERSHOOT_MAX = 8
+    # Stall accounting (batcher_stall_{program,host}_ms; fixed, no knob).
+    # A chunk stalled when its wall per pass is over twice the running
+    # mean of earlier chunks of its size: live slots, context and
+    # arrival rate move a pass by a few percent (PERF.md section 5), so
+    # a doubling is the device or its runtime standing still, not load.
+    # What it took over that mean is what was lost, and is counted.
+    STALL_PROGRAM_FACTOR = 2.0
+    # ... judged once this many chunks of that size were seen after the
+    # first, which compiles or reads the program from the cache
+    STALL_MIN_CHUNKS = 4
+    # A busy step's host part (wall outside program calls) is a few
+    # milliseconds for 16 slots; 100 ms is over ten times that, and
+    # two thirds of a decode pass on the v5e: a stream reader sees it.
+    STALL_HOST_BOUND_S = 0.100
+    # Either loss counts from 50 ms on: under it, it is the scheduling
+    # noise of a shared host and moves no end-to-end metric.
+    STALL_FLOOR_S = 0.050
 
     def __init__(self, cfg: ModelConfig, params=None, *,
                  num_blocks: int = 512, block_size: int = 16,
@@ -384,6 +404,12 @@ class ContinuousBatcher:
         # account); registered at 0 so a scrape can't confuse "no
         # migrations yet" with "metric not exported"
         self.metrics.inc("batcher_requests_migrated", 0)
+        # stall counters (milliseconds the scheduler stood still, by
+        # side); alert on dli_batcher_stall_*_ms_total
+        self.metrics.inc("batcher_stall_program_ms", 0)
+        self.metrics.inc("batcher_stall_host_ms", 0)
+        self._pass_mean = {}      # (kind, k) -> [mean wall per pass, n]
+        self._step_program_s = 0.0   # this step's wall inside programs
         if self.spec_wave:
             for name in ("spec_wave_dispatches", "spec_wave_drafted_tokens",
                          "spec_wave_accepted_tokens",
@@ -999,8 +1025,9 @@ class ContinuousBatcher:
                     last, paged = transformer.paged_prefill_tail(
                         p, cfg, toks, tl, tb, pfb, pfl, paged,
                         lora_ids=aids)
-                first = sample_batch(last, seeds, steps, temps, tks, tps,
-                                     ds.astype(bool))
+                with jax.named_scope("sample"):
+                    first = sample_batch(last, seeds, steps, temps, tks,
+                                         tps, ds.astype(bool))
                 return first, paged
 
             fn = jax.jit(admit, donate_argnums=(3,))
@@ -1192,8 +1219,12 @@ class ContinuousBatcher:
         floats = np.stack([np.asarray(a["temps"], np.float32),
                            np.asarray(a["tps"], np.float32)])
         fn = self._decode_jit(int(a["k"]), r, mb, use_lora)
+        # k and slots pair this host call with its device run exactly
+        stats = ({"k": int(a["k"]),
+                  "slots": int(np.count_nonzero(a["budget"]))}
+                 if self.profiler.enabled else {})
         with self.mesh:
-            with self.profiler.phase("dispatch"):
+            with self.profiler.phase("dispatch", **stats):
                 tokens = (tokens_dev if tokens_dev is not None
                           else jnp.asarray(np.asarray(a["tokens"],
                                                       np.int32)))
@@ -1291,6 +1322,38 @@ class ContinuousBatcher:
                     self._wave_params(use_lora), jnp.asarray(ints),
                     jnp.asarray(floats), self.paged)
                 return jax.device_get((toks, keeps, eos_seen))
+
+    def _note_program(self, wall_s: float, kind: str = "", k: int = 0,
+                      slots: int = 0):
+        """Stall accounting for one program call's wall: it is program
+        time of this step, and a decode chunk (``k`` passes) is judged
+        against the running mean of earlier chunks of its kind and
+        size. Admit waves only add their wall: their sizes differ too
+        widely for a mean to say anything."""
+        self._step_program_s += wall_s
+        if not k:
+            return
+        st = self._pass_mean.get((kind, k))
+        if st is None:   # the first of its size compiles, or reads the cache
+            self._pass_mean[(kind, k)] = [0.0, 0]
+            return
+        mean, n = st
+        per_pass = wall_s / k
+        lost = wall_s - mean * k
+        if (n >= self.STALL_MIN_CHUNKS and lost >= self.STALL_FLOOR_S
+                and per_pass > self.STALL_PROGRAM_FACTOR * mean):
+            self._stall("program", lost, k, slots)
+            # a stall is not the norm: it enters the mean at the limit
+            per_pass = self.STALL_PROGRAM_FACTOR * mean
+        st[:] = mean + (per_pass - mean) / min(n + 1, 64), n + 1
+
+    def _stall(self, where: str, lost_s: float, k: int, slots: int):
+        """Count one stall (``where``: program | host) and journal it."""
+        self.metrics.inc(f"batcher_stall_{where}_ms", lost_s * 1e3)
+        log.warning("scheduler stall (%s): %.0f ms lost, k=%d, slots=%d",
+                    where, lost_s * 1e3, k, slots)
+        events.emit("scheduler-stall", where=where,
+                    ms=round(lost_s * 1e3, 1), k=k, slots=slots)
 
     def _wave_params(self, use_lora: bool):
         """The parameter tree a wave's program runs against: the base
@@ -1997,6 +2060,19 @@ class ContinuousBatcher:
         partial per wave: it requeues to the front, and pulling the queue
         past a front request that is mid-prefill would break FIFO order.
         """
+        with self.profiler.phase("admit_prep"):
+            wave = self._collect_wave()
+        if not wave:
+            return
+        groups: dict = {}
+        for m in wave:
+            groups.setdefault((m["t"], m["pb"]), []).append(m)
+        for (t, pb), members in groups.items():
+            self._admit_group(t, pb, members)
+
+    def _collect_wave(self) -> List[dict]:
+        """The host half of _admit_wave: pop queued requests while they
+        fit, each with its radix match and block allocation done."""
         wave: List[dict] = []
         taken: set = set()
         while True:
@@ -2075,19 +2151,50 @@ class ContinuousBatcher:
             prep["slot"] = free[0]
             taken.add(free[0])
             wave.append(prep)
-
-        if not wave:
-            return
-        groups: dict = {}
-        for m in wave:
-            groups.setdefault((m["t"], m["pb"]), []).append(m)
-        for (t, pb), members in groups.items():
-            self._admit_group(t, pb, members)
+        return wave
 
     def _admit_group(self, t: int, pb: int, members: List[dict]):
         """One batched admission program for wave members sharing a
         (tail-bucket, prefix-bucket); rows padded to a power-of-two wave
         size (padding rows write only the reserved dummy block)."""
+        with self.profiler.phase("admit_prep"):
+            b, admit_args = self._pack_admit(t, pb, members)
+        # what the wave costs and whom: real uncached tail tokens, the
+        # tokens the program's shape pays for, and the slots that stand
+        # still while it runs
+        tokens = sum(m["tail_len"] for m in members)
+        active = sum(a is not None for a in self.active)
+        w0 = clock.now()
+        for m in members:
+            # cost ledger: queue phase ends when the FIRST wave carrying
+            # the request starts dispatching (chunked-prefill passes and
+            # preemption re-admissions keep the original stamp)
+            if m["req"].admitted_at is None:
+                m["req"].admitted_at = w0
+        with self.profiler.phase("admit_run", rows=b, tail_bucket=t,
+                                 prefix_bucket=pb, tokens=tokens):
+            if self.program_hook is not None:
+                first = self.program_hook(
+                    "admit", admit_args, lambda: self._run_admit(admit_args))
+            else:
+                first = self._run_admit(admit_args)
+        w1 = clock.now()
+        self.metrics.observe("batcher_admit_wave", w1 - w0)
+        self._note_program(w1 - w0)
+        wave = trace.get_tracer().record(
+            "batcher.admit_wave", w0, w1,
+            attrs={"members": len(members), "rows": b,
+                   "tail_bucket": t, "prefix_bucket": pb,
+                   "tokens": tokens, "padded_tokens": b * t,
+                   "active": active})
+        with self.profiler.phase("admit_post"):
+            for j, m in enumerate(members):
+                if m["req"]._wave_span is None:
+                    m["req"]._wave_span = wave.span_id
+                self._post_admit(m, int(first[j]))
+
+    def _pack_admit(self, t: int, pb: int, members: List[dict]):
+        """(rows, JSON-safe args) of one admission program."""
         bs = self.block_size
         b = self._bucket_wave(len(members))
         if self.mesh_spec.pp > 1:   # wave rows microbatch over pp stages
@@ -2133,26 +2240,7 @@ class ContinuousBatcher:
             # base-only wave compiles/runs the unaugmented program, and
             # lockstep followers replaying the args pick the same one
             admit_args["aids"] = aids.tolist()
-        w0 = clock.now()
-        for m in members:
-            # cost ledger: queue phase ends when the FIRST wave carrying
-            # the request starts dispatching (chunked-prefill passes and
-            # preemption re-admissions keep the original stamp)
-            if m["req"].admitted_at is None:
-                m["req"].admitted_at = w0
-        if self.program_hook is not None:
-            first = self.program_hook("admit", admit_args,
-                                      lambda: self._run_admit(admit_args))
-        else:
-            first = self._run_admit(admit_args)
-        w1 = clock.now()
-        self.metrics.observe("batcher_admit_wave", w1 - w0)
-        trace.get_tracer().record(
-            "batcher.admit_wave", w0, w1,
-            attrs={"members": len(members), "rows": b,
-                   "tail_bucket": t, "prefix_bucket": pb})
-        for j, m in enumerate(members):
-            self._post_admit(m, int(first[j]))
+        return b, admit_args
 
     def _post_admit(self, m: dict, first: int):
         """Register one admitted wave member: release padding blocks, enter
@@ -2379,6 +2467,9 @@ class ContinuousBatcher:
             attrs["error"] = req.error
         g = tr.record("batcher.request", req.submitted_at, end,
                       parent=req.trace_ctx, attrs=attrs)
+        if req.admitted_at is not None:
+            tr.record("batcher.queued", req.submitted_at, req.admitted_at,
+                      parent=g, attrs={"wave": req._wave_span})
         if req.first_token_at is not None:
             tr.record("batcher.ttft", req.submitted_at, req.first_token_at,
                       parent=g)
@@ -2459,6 +2550,7 @@ class ContinuousBatcher:
         t0 = time.perf_counter()
         busy = 0
         work0 = (self._step_count, self._tokens_out)
+        self._step_program_s = 0.0
         prof_rec = self.profiler.step_begin()
         try:
             busy = self._step_inner()
@@ -2488,6 +2580,12 @@ class ContinuousBatcher:
             did_work = bool(busy) or \
                 (self._step_count, self._tokens_out) != work0
             self.profiler.step_end(prof_rec, keep=did_work, active=busy)
+            if did_work:
+                # the step's wall outside its program calls: the host
+                host_over = (time.perf_counter() - t0 - self._step_program_s
+                             - self.STALL_HOST_BOUND_S)
+                if host_over >= self.STALL_FLOOR_S:
+                    self._stall("host", host_over, 0, busy)
 
     def _step_inner(self) -> int:
         # service migration snapshots first: a flagged slot must not
@@ -2614,6 +2712,7 @@ class ContinuousBatcher:
         self._step_count += 1
         w1 = clock.now()
         self.metrics.observe("batcher_decode_chunk", w1 - w0)
+        self._note_program(w1 - w0, "decode", k, len(active))
         trace.get_tracer().record(
             "batcher.decode_chunk", w0, w1,
             attrs={"k": k, "slots": len(active)})
@@ -2715,6 +2814,7 @@ class ContinuousBatcher:
         w1 = clock.now()
         self.metrics.observe("batcher_decode_chunk", (w1 - w0) / 2)
         self.metrics.observe("batcher_decode_chunk", (w1 - w0) / 2)
+        self._note_program(w1 - w0, "overlapped", 2 * k, len(active))
         trace.get_tracer().record(
             "batcher.decode_chunk", w0, w1,
             attrs={"k": 2 * k, "slots": len(active), "overlapped": True})
@@ -2788,6 +2888,7 @@ class ContinuousBatcher:
         self._step_count += 1
         w1 = clock.now()
         self.metrics.observe("batcher_decode_chunk", w1 - w0)
+        self._note_program(w1 - w0, f"spec{gamma}", k_it, len(active))
         trace.get_tracer().record(
             "batcher.spec_chunk", w0, w1,
             attrs={"k": k_it, "gamma": gamma, "slots": len(active)})
@@ -2969,6 +3070,7 @@ class ContinuousBatcher:
         w1 = clock.now()
         m.inc("spec_wave_dispatches")
         m.observe("batcher_decode_chunk", w1 - w0)
+        self._note_program(w1 - w0, f"spec{g_max}", k_it, len(active))
         trace.get_tracer().record(
             "batcher.spec_wave_chunk", w0, w1,
             attrs={"k": k_it, "gamma_max": g_max, "slots": len(active),

@@ -249,6 +249,16 @@ EVENT_TYPES = (
         "the deposed master (to its in-memory ring) as it steps down "
         "— the paused-then-revived-leader trail a postmortem needs.",
         ("term", "observed_term")),
+    # ---- continuous batcher (runtime/batcher.py) -----------------------
+    EventType(
+        "scheduler-stall", "warning",
+        "The batcher's step loop stood still: a decode chunk's wall per "
+        "pass was over twice the running mean of earlier chunks "
+        "(`where` program), or a busy step spent over 100 ms outside "
+        "its program calls (`where` host). `ms` is what was lost (over "
+        "the mean, over the 100 ms), also added to "
+        "`dli_batcher_stall_{program,host}_ms_total`.",
+        ("where", "ms", "k", "slots")),
     # ---- multi-LoRA adapter serving (models/lora.py) ------------------
     EventType(
         "adapter-loaded", "info",

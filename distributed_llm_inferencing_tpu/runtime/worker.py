@@ -134,8 +134,8 @@ class WorkerAgent:
         s.add("POST", "/profile/start", self.profile_start)
         s.add("POST", "/profile/stop", self.profile_stop)
         # decode phase profiler (utils/profiler.py), distinct from the
-        # XLA device profiler above: GET reads per-model summaries +
-        # flamegraph JSON, POST toggles at runtime
+        # XLA device profiler above: GET reads per-model summaries,
+        # POST toggles at runtime
         s.add("GET", "/api/profile", self.api_profile)
         s.add("POST", "/api/profile", self.api_profile_config)
         s.add("GET", "/memory_profile", self.memory_profile)
@@ -339,12 +339,12 @@ class WorkerAgent:
 
     def api_profile(self, body):
         """Decode-profiler readout: per-phase wall attribution of the
-        batcher step loop (summary + d3-flamegraph JSON) per batched
+        batcher step loop (``PhaseProfiler.summary()``) per batched
         model. Zero-cost when the profiler is off — the payload then
         just reports enabled=false."""
         out = {}
         for name, p in self._batcher_profilers():
-            out[name] = {"summary": p.summary(), "flame": p.flame()}
+            out[name] = {"summary": p.summary()}
         return {"status": "success", "profilers": out}
 
     def api_profile_config(self, body):

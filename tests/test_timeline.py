@@ -1,0 +1,315 @@
+"""One scheduler timeline: timestamped step phases, their mirror as
+profiler annotations, the programs' named scopes, the admit wave's span
+attributes and the stall counters (docs/observability.md, "Decode
+profiler")."""
+
+import glob
+import re
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from distributed_llm_inferencing_tpu.models.registry import get_config
+from distributed_llm_inferencing_tpu.ops.sampling import SamplingParams
+from distributed_llm_inferencing_tpu.runtime import events
+from distributed_llm_inferencing_tpu.runtime.batcher import ContinuousBatcher
+from distributed_llm_inferencing_tpu.utils import trace
+from distributed_llm_inferencing_tpu.utils.profiler import (
+    PhaseProfiler, step_phases)
+
+RNG = np.random.default_rng(7)
+GREEDY = SamplingParams.greedy()
+SCOPES = ("kv_gather", "attention", "kv_write", "mlp", "moe_route",
+          "moe_experts", "lm_head", "sample")
+
+
+def batcher(model="tiny-llama", **kw):
+    cfg = get_config(model).replace(dtype="float32", attn_backend="xla")
+    kw = {"num_blocks": 64, "block_size": 8, "slots": 4, "max_seq": 128,
+          **kw}
+    return ContinuousBatcher(cfg, None, seed=0, **kw)
+
+
+def serve(b, lengths, new_tokens=6, max_steps=400):
+    reqs = [b.submit(RNG.integers(3, b.cfg.vocab_size, n).tolist(),
+                     max_new_tokens=new_tokens, sampling=GREEDY)
+            for n in lengths]
+    for _ in range(max_steps):
+        b.step()
+        if all(r.done.is_set() for r in reqs):
+            return reqs
+    raise AssertionError("requests did not finish")
+
+
+# ---- the step's timeline ---------------------------------------------
+
+@pytest.mark.parametrize("sample_every", [1, 3])
+def test_sampled_step_is_an_ordered_timeline(sample_every):
+    b = batcher()
+    b.profiler = PhaseProfiler(enabled=True, sample_every=sample_every)
+    serve(b, [9, 20, 5])
+    samples = b.profiler.samples()
+    assert samples and any(
+        name == "admit_run" for s in samples for name, *_ in s["spans"])
+    old_sums = {}
+    for s in samples:
+        spans = s["spans"]
+        starts = [start for _, start, _, _ in spans]
+        assert starts == sorted(starts)
+        assert all(end >= start for _, start, end, _ in spans)
+        assert s["t"] <= spans[0][1] and \
+            spans[-1][2] <= s["t"] + s["total"] + 1e-4
+        open_ = []          # the brackets still open, outermost first
+        for name, start, end, depth in spans:
+            del open_[depth:]
+            assert len(open_) == depth
+            if depth:       # a nested bracket lies inside its parent
+                _, p_start, p_end = open_[-1]
+                assert p_start <= start and end <= p_end, (name, open_)
+                assert name.startswith("admit_") and open_[0][0] == "admit"
+            open_.append((name, start, end))
+        # what the dict of sums held for this step: every top-level
+        # bracket's wall, and the rest under "other"
+        top = {}
+        for name, start, end, depth in spans:
+            if depth == 0:
+                top[name] = top.get(name, 0.0) + end - start
+        top["other"] = s["total"] - sum(top.values())
+        assert step_phases(s) == pytest.approx(top)
+        for k, v in top.items():
+            old_sums[k] = old_sums.get(k, 0.0) + v
+    summ = b.profiler.summary()
+    assert set(summ["phases"]) == set(old_sums)
+    assert set(summ["phases"]) >= {"admit", "host_prep", "dispatch",
+                                   "device_wait", "emit", "bookkeeping"}
+    for k, v in old_sums.items():
+        assert summ["phases"][k]["s"] == pytest.approx(v, abs=2e-6)
+    assert set(summ["nested"]) == {"admit_prep", "admit_run", "admit_post"}
+    assert not set(summ["nested"]) & set(summ["phases"])
+    assert sum(v["s"] for v in summ["nested"].values()) <= \
+        summ["phases"]["admit"]["s"] + 1e-5
+    ev = b.profiler.chrome_events(pid=1)
+    assert len(ev) == sum(len(s["spans"]) for s in samples)
+    assert [e["ts"] for e in ev[:len(samples[0]["spans"])]] == \
+        [start * 1e6 for _, start, _, _ in samples[0]["spans"]]
+
+
+# ---- the same phases in a profiler trace ------------------------------
+
+def host_events(trace_dir):
+    path = glob.glob(str(trace_dir / "plugins" / "profile" / "*"
+                         / "*.xplane.pb"))[0]
+    data = jax.profiler.ProfileData.from_file(path)
+    out = []
+    for plane in data.planes:
+        if plane.name.startswith("/host:CPU"):
+            for line in plane.lines:
+                for e in line.events:
+                    out.append((e.name, e.start_ns, e.start_ns
+                                + e.duration_ns, dict(e.stats)))
+    return out
+
+
+@pytest.mark.parametrize("enabled", [True, False])
+def test_profiler_trace_holds_the_host_phases(tmp_path, enabled):
+    b = batcher()
+    b.profiler = PhaseProfiler(enabled=enabled)
+    serve(b, [9, 12])                   # compile outside the trace
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        serve(b, [9, 12])
+    finally:
+        jax.profiler.stop_trace()
+    evs = host_events(tmp_path)
+    dli = [e for e in evs if e[0].startswith("dli.")]
+    if not enabled:
+        assert dli == []
+        return
+    names = {e[0] for e in dli}
+    assert names >= {"dli.step", "dli.admit", "dli.admit_prep",
+                     "dli.admit_run", "dli.admit_post", "dli.host_prep",
+                     "dli.dispatch", "dli.device_wait", "dli.emit",
+                     "dli.bookkeeping"}
+    steps = [e for e in dli if e[0] == "dli.step"]
+    assert all("step_num" in e[3] for e in steps)
+    run = next(e for e in dli if e[0] == "dli.admit_run")
+    assert run[3]["rows"] == 2 and run[3]["tail_bucket"] == 16
+    assert run[3]["tokens"] == 9 + 12 and "prefix_bucket" in run[3]
+    dispatch = sorted((e for e in dli if e[0] == "dli.dispatch"),
+                      key=lambda e: e[1])
+    waits = sorted((e for e in dli if e[0] == "dli.device_wait"),
+                   key=lambda e: e[1])
+    assert dispatch and len(dispatch) == len(waits)
+    assert all(e[3]["k"] >= 1 and e[3]["slots"] == 2 for e in dispatch)
+    pairs = [(d[1], w[2]) for d, w in zip(dispatch, waits)]
+    ops = [e for e in evs if e[3].get("hlo_module") == "jit_chunk"]
+    assert ops
+    for _, start, end, _ in ops:    # the device's work, on the host's clock
+        assert any(a <= start and end <= z for a, z in pairs)
+
+
+# ---- names in the programs --------------------------------------------
+
+def lowered(model, program):
+    b = batcher(model)
+    r, mb = b.slots, b.max_blocks
+    paged = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), b.paged)
+
+    def ints(n):
+        return jax.ShapeDtypeStruct((n,), jnp.int32)
+    with b.mesh:
+        if program == "admit":
+            t, pb, w = 64, 1, 2     # 128 tokens: mixtral's capacity path
+            return b._admit_jit(t, pb, w).lower(
+                b.params, ints(w * (t + t // b.block_size + pb + 6)),
+                jax.ShapeDtypeStruct((2, w), jnp.float32), paged)
+        return b._decode_jit(4, r, mb).lower(
+            b.params, ints(r), ints(r * (mb + 7)),
+            jax.ShapeDtypeStruct((2, r), jnp.float32), paged)
+
+
+@pytest.mark.parametrize("model,program", [
+    ("tiny-llama", "admit"), ("tiny-llama", "chunk"),
+    ("tiny-mixtral", "admit"), ("tiny-mixtral", "chunk")])
+def test_programs_carry_the_scope_vocabulary(model, program):
+    text = lowered(model, program).as_text(debug_info=True)
+    seen = {part for name in re.findall(r'loc\("([^"]+)"', text)
+            for part in name.split("/")} & set(SCOPES)
+    moe = {"moe_route", "moe_experts"}
+    want = set(SCOPES) - ({"mlp"} if model == "tiny-mixtral" else moe)
+    assert seen == want
+
+
+# ---- what an admission cost -------------------------------------------
+
+def test_admit_wave_attributes_add_up():
+    tr = trace.get_tracer()
+    b = batcher()
+    warm = serve(b, [30])               # one slot decodes meanwhile ...
+    assert warm[0].error is None
+    t_mark = time.time()
+    before = b.metrics.snapshot()["counters"]["prefill_uncached_tokens"]
+    long_ = b.submit(RNG.integers(3, b.cfg.vocab_size, 20).tolist(),
+                     max_new_tokens=40, sampling=GREEDY)
+    b.step()                            # ... when the next wave arrives
+    reqs = serve(b, [9, 17, 3]) + [long_]
+    while not long_.done.is_set():
+        b.step()
+    after = b.metrics.snapshot()["counters"]["prefill_uncached_tokens"]
+    waves = [s for s in tr.spans() if s.name == "batcher.admit_wave"
+             and s.start >= t_mark]
+    assert len(waves) >= 2
+    for w in waves:
+        a = w.attrs
+        assert 0 < a["tokens"] <= a["padded_tokens"]
+        assert a["padded_tokens"] == a["rows"] * a["tail_bucket"]
+        assert a["members"] <= a["rows"]
+    assert sum(w.attrs["tokens"] for w in waves) == after - before
+    assert waves[0].attrs["active"] == 0
+    assert any(w.attrs["active"] > 0 for w in waves[1:])
+    # each request's queue wait, joined to the wave that ended it
+    by_id = {w.span_id: w for w in waves}
+    queued = [s for s in tr.spans() if s.name == "batcher.queued"
+              and s.start >= t_mark]
+    assert len(queued) == len(reqs)
+    for q in queued:
+        wave = by_id[q.attrs["wave"]]
+        assert q.end == wave.start and q.start <= q.end
+
+
+# ---- stalls -----------------------------------------------------------
+
+@pytest.fixture
+def journal():
+    j = events.EventJournal()
+    events.set_journal(j)
+    yield j
+    events.clear_journal(j)
+
+
+def stall_ms(b):
+    c = b.metrics.snapshot()["counters"]
+    return c["batcher_stall_program_ms"], c["batcher_stall_host_ms"]
+
+
+@pytest.mark.parametrize("where", ["none", "program", "host"])
+def test_stall_counters(journal, where):
+    b = batcher()
+    assert stall_ms(b) == (0, 0)
+    calls = {"decode": 0}
+
+    def hook(kind, payload, run):
+        if kind == "decode":
+            calls["decode"] += 1
+            if where == "program" and calls["decode"] == 12:
+                time.sleep(0.3)         # the device stands still
+        return run()
+    b.program_hook = hook
+
+    def slow_reader(_tok):
+        if where == "host" and calls["decode"] == 12 and "slept" not in calls:
+            calls["slept"] = True
+            time.sleep(0.3)             # the host stands still, once
+    reqs = [b.submit(RNG.integers(3, b.cfg.vocab_size, 9).tolist(),
+                     max_new_tokens=100, sampling=GREEDY, eos_token_id=None,
+                     stream_cb=slow_reader)]
+    # chunks of one size only, so the running mean is of like with like
+    reqs[0].chunk_cap = 4
+    while not reqs[0].done.is_set():
+        b.step()
+    assert calls["decode"] >= 20
+    program, host = stall_ms(b)
+    stalls = [e for e in journal.tail() if e["type"] == "scheduler-stall"]
+    if where == "none":
+        assert (program, host) == (0, 0) and stalls == []
+        return
+    got, other = (program, host) if where == "program" else (host, program)
+    assert 100 <= got <= 400 and other == 0
+    assert [e["data"]["where"] for e in stalls] == [where]
+    assert stalls[0]["data"]["ms"] == pytest.approx(got, abs=0.1)
+    assert stalls[0]["severity"] == "warning"
+
+
+# ---- the operator's reduction (scripts/profile_summary.py) ------------
+
+def test_profile_summary_splits_idle_time_over_host_phases():
+    import importlib.util
+    from pathlib import Path
+    spec = importlib.util.spec_from_file_location(
+        "profile_summary", Path(__file__).resolve().parents[1]
+        / "scripts" / "profile_summary.py")
+    ps = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(ps)
+    assert ps.scope_of("jit(chunk)/while/body/attention/dot_general") \
+        == "attention"
+    assert ps.scope_of("jit(admit)/moe_experts/moe_route/top_k") \
+        == "moe_route"                          # the innermost counts
+    assert ps.scope_of("jit(chunk)/while/body/squeeze") == ps.NO_SCOPE
+    # a while of 10 ms around two ops of 3 ms keeps 4 ms for itself
+    ops = [("w", 0.0, 0.010), ("attention", 0.001, 0.004),
+           ("mlp", 0.005, 0.008)]
+    assert {k[0]: pytest.approx(v) for k, v in ps.self_times(ops)} == \
+        {"w": 0.004, "attention": 0.003, "mlp": 0.003}
+    # the device's clock runs 2 ms behind the host's: two chunk runs of
+    # 100 ms with 6 ms between them, as the host saw them
+    dev = {"modules": [("jit_chunk", 0.000, 0.100),
+                       ("jit_chunk", 0.106, 0.206)],
+           "ops": [("attention", 0.000, 0.100), ("attention", 0.106, 0.206)]}
+    host = [("dli.step", 0.000, 0.1045), ("dli.dispatch", 0.0005, 0.002),
+            ("dli.device_wait", 0.002, 0.103), ("dli.emit", 0.103, 0.104),
+            ("dli.step", 0.1045, 0.212), ("dli.host_prep", 0.105, 0.106),
+            ("dli.dispatch", 0.106, 0.109),
+            ("dli.device_wait", 0.109, 0.209)]
+    offset, low, high = ps.clock_offset(dev, host)
+    assert low <= 0.002 <= high and offset == pytest.approx((low + high) / 2)
+    idle = ps.idle_by_phase(dev, host, 0.002)   # gap: 102..108 ms
+    assert idle == pytest.approx({
+        "device_wait": 0.001, "emit": 0.001, "other": 0.001,
+        "host_prep": 0.001, "dispatch": 0.002})

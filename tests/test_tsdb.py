@@ -207,9 +207,6 @@ def test_profiler_phases_ring_and_sampling():
     # unattributed time is conserved into "other", so fractions sum ~1
     total_frac = sum(v["frac"] for v in summ["phases"].values())
     assert 0.99 <= total_frac <= 1.01, summ
-    flame = p.flame()
-    assert flame["name"] == "batcher.step"
-    assert {c["name"] for c in flame["children"]} >= {"dispatch", "emit"}
     ev = p.chrome_events(pid=1)
     assert ev and all(e["ph"] == "X" for e in ev)
     # runtime toggle clears and disarms
