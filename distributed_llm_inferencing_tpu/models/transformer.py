@@ -1167,15 +1167,18 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
     request's tokens stay a pure function of (params, prompt, seed) —
     bit-identical whether decoded one token or K tokens per dispatch.
 
-    Memory-access structure (the perf-critical part, measured on v5e):
-    dynamic scatters into the block pool cost ~60µs each on TPU, so the
-    naive per-step write (2 per layer per step) burns ~1.5 ms/step.
-    Instead the chunk's fresh K/V accumulates in a small *side buffer*
-    [L, R, K, Hkv, hd] (dynamic_update_slice at step index — cheap), each
-    step's attention reads ``gather(pool) masked < cl0`` concatenated
-    with ``side masked <= t``, and the whole side buffer scatters into
-    the pool in ONE op after the scan. The pool is loop-invariant during
-    the chunk, which is what makes the split exact.
+    Memory-access structure: the chunk's fresh K/V accumulates in a small
+    *side buffer* [L, R, K, Hkv, hd] (dynamic_update_slice at step index)
+    instead of two dynamic scatters into the block pool per layer per
+    step, and the whole side buffer scatters into the pool in ONE op
+    after the scan. Each step's attention takes two KV segments,
+    ``gather(pool) masked < cl0`` and ``side masked <= t``
+    (ops/attention.attend): their scores meet in one softmax and K and V
+    are never concatenated or widened. The pool is loop-invariant during
+    the chunk, which is what makes the split exact. On the chip (PERF.md
+    section 5) the pass is the weights, the per-layer gather of every
+    slot's whole block table (``kv_gather``) and attention's one read of
+    what was gathered, all MB*bs positions whatever the contexts are.
 
     tokens: [R] last emitted token per slot; steps0: [R] tokens emitted so
     far. Returns (toks [K, R] int32, emits [K, R] bool, new paged); the
@@ -1247,13 +1250,8 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
                             sv, vh.astype(dt), (0, t, 0, 0))
                     with jax.named_scope("attention"):
                         attn = attend(
-                            q,
-                            jnp.concatenate([kp, sk2], axis=1),
-                            jnp.concatenate([vp, sv2], axis=1),
-                            q_pos,
-                            jnp.concatenate([pool_pos, side_pos], axis=1),
-                            jnp.concatenate([pool_valid, side_valid],
-                                            axis=1),
+                            q, (kp, sk2), (vp, sv2), q_pos,
+                            (pool_pos, side_pos), (pool_valid, side_valid),
                             sliding_window=_layer_window(seg_cfg, lp),
                             alibi=_alibi(seg_cfg),
                             softcap=seg_cfg.attn_softcap,
@@ -1463,13 +1461,8 @@ def paged_speculative_chunk(params, cfg: ModelConfig, k: int, gamma: int,
                             sv, vh.astype(dt), (0, t * g1, 0, 0))
                     with jax.named_scope("attention"):
                         attn = attend(
-                            q,
-                            jnp.concatenate([kp, sk2], axis=1),
-                            jnp.concatenate([vp, sv2], axis=1),
-                            qp,
-                            jnp.concatenate([pool_pos, side_pos], axis=1),
-                            jnp.concatenate([pool_valid, side_valid],
-                                            axis=1),
+                            q, (kp, sk2), (vp, sv2), qp,
+                            (pool_pos, side_pos), (pool_valid, side_valid),
                             sliding_window=_layer_window(seg_cfg, lp),
                             alibi=_alibi(seg_cfg),
                             softcap=seg_cfg.attn_softcap,

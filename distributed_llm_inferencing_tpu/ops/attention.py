@@ -4,9 +4,17 @@ The reference's attention lives inside vendored HF/torch kernels
 (reference: worker/app.py:297-305 just calls model.generate()). Here there
 are two backends behind one dispatch:
 
-- **xla** (this module): einsum QK^T on the MXU, f32 softmax, einsum PV —
-  written so XLA fuses mask+softmax into the matmuls. Reference
-  implementation and the fallback on non-TPU hosts / multi-device meshes.
+- **xla** (this module): grouped-query einsums with kv heads kept as an
+  axis, f32 scores and softmax, K and V read once in the dtype they are
+  stored in. On a v5e the decode shape compiles to one fusion per dot
+  (K or V streamed through the MXU as the convolution's left operand,
+  scale, mask and the row maximum fused behind q.K) plus one softmax
+  fusion, no temporary (tests/test_tpu_compile.py holds it to that). The
+  head-expanded f32 form it replaced wrote K and V back to HBM as
+  f32[B,S,Hkv,G,hd] and was 87 % of the serving decode pass; PERF.md
+  section 6, PR 25, has the record. This is what the continuous batcher
+  runs, the reference implementation, and the path on non-TPU hosts and
+  multi-device meshes.
 - **pallas** (ops/pallas/flash_attention.py): hand-tiled online-softmax
   kernels for the two hot regimes (prefill flash attention, cached flash
   decode).
@@ -68,10 +76,22 @@ def window_mask(q_pos, kv_pos, sliding_window):
     return in_window
 
 
+def _dot(spec, a, b):
+    """einsum accumulated in f32 with exact products. Two bf16 operands
+    multiply exactly on the MXU at default precision; any wider operand
+    (f32 probabilities, an f32 model) needs HIGHEST, or the MXU rounds
+    it to bf16 first. Operands go in as stored: no caller-side astype,
+    so no K- or V-sized copy in a wider dtype."""
+    both_bf16 = a.dtype == jnp.bfloat16 and b.dtype == jnp.bfloat16
+    return jnp.einsum(
+        spec, a, b, preferred_element_type=jnp.float32,
+        precision=None if both_bf16 else jax.lax.Precision.HIGHEST)
+
+
 def attend(
     q,                   # [B, Sq, H, hd]
-    k,                   # [B, Skv, Hkv, hd]
-    v,                   # [B, Skv, Hkv, hd]
+    k,                   # [B, Skv, Hkv, hd], or a sequence of such segments
+    v,                   # [B, Skv, Hkv, vd], segmented like k
     q_positions,         # [B, Sq] absolute position of each query token
     kv_positions,        # [B, Skv] absolute position of each kv slot
     kv_valid,            # [B, Skv] bool — slot holds a real token
@@ -86,6 +106,7 @@ def attend(
     # scale — its effective q/k carry the (rd + kv_lora_rank)-wide
     # latent, but the scores are mathematically the materialized
     # head_dim attention's (transformer._mla_latent_attn).
+    out_dtype=None,      # None => q.dtype; the accumulator is f32
 ):
     """Causal attention over a (possibly cached, possibly padded) KV set.
 
@@ -96,42 +117,57 @@ def attend(
     scaled scores — position-free K/V make the cache layout identical to
     the RoPE families', so every paged/chunked serving path reuses this
     one formulation.
-    """
-    B, Sq, H, hd = q.shape
-    Hkv = k.shape[2]
-    k = repeat_kv(k, H // Hkv)
-    v = repeat_kv(v, H // Hkv)
 
+    K and V are read once, as stored. Query heads are grouped by the kv
+    head they share (q viewed as [B, Sq, Hkv, G, hd], G = H // Hkv), so
+    kv heads stay an axis of both contractions and nothing K- or V-sized
+    is broadcast, converted or concatenated. A KV set that lives in
+    several buffers (gathered pool + the chunk's side buffer, cached
+    prefix + fresh tail) is passed as sequences ``k, v, kv_positions,
+    kv_valid`` of equal length: each segment's scores are computed
+    against its own buffer, the segments meet only on the score axis for
+    the one softmax, and their ``p @ V`` are summed. Scores, softmax and
+    accumulation are f32; only the order of summation differs from the
+    head-expanded f32 form this replaced (PERF.md section 6, PR 25).
+    """
+    if not isinstance(k, (tuple, list)):
+        k, v, kv_positions, kv_valid = [k], [v], [kv_positions], [kv_valid]
+    B, Sq, H, hd = q.shape
+    Hkv = k[0].shape[2]
+    G = H // Hkv
     if scale is None:
         scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
-    # [B, H, Sq, Skv]
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
-                        k.astype(jnp.float32)) * scale
-    if softcap is not None:   # pre-mask score squash (HF gemma2 order)
-        logits = jnp.tanh(logits / softcap) * softcap
-    if alibi is not None:
-        rel = (kv_positions[:, None, :]
-               - q_positions[:, :, None]).astype(jnp.float32)  # [B,Sq,Skv]
-        logits = logits + alibi[None, :, None, None] * rel[:, None, :, :]
+    qg = q.reshape(B, Sq, Hkv, G, hd)
+    qp = q_positions[:, None, None, :, None]          # [B,1,1,Sq,1]
 
-    causal = kv_positions[:, None, :] <= q_positions[:, :, None]  # [B,Sq,Skv]
-    mask = causal & kv_valid[:, None, :]
-    if sliding_window is not None:
-        mask = mask & window_mask(q_positions[:, :, None],
-                                  kv_positions[:, None, :], sliding_window)
-    logits = jnp.where(mask[:, None, :, :], logits, NEG_INF)
+    def segment_logits(k_seg, pos, valid):            # -> [B,Hkv,G,Sq,S]
+        logits = _dot("bqkgd,bskd->bkgqs", qg, k_seg) * scale
+        if softcap is not None:   # pre-mask score squash (HF gemma2 order)
+            logits = jnp.tanh(logits / softcap) * softcap
+        kp = pos[:, None, None, None, :]              # [B,1,1,1,S]
+        if alibi is not None:
+            logits = logits + (alibi.reshape(Hkv, G)[None, :, :, None, None]
+                               * (kp - qp).astype(jnp.float32))
+        mask = (kp <= qp) & valid[:, None, None, None, :]
+        if sliding_window is not None:
+            mask = mask & window_mask(qp, kp, sliding_window)
+        return jnp.where(mask, logits, NEG_INF)
 
-    if sinks is not None:
-        sink_col = jnp.broadcast_to(
-            sinks.astype(jnp.float32)[None, :, None, None],
-            logits.shape[:-1] + (1,))
-        logits = jnp.concatenate([logits, sink_col], axis=-1)
+    logits = [segment_logits(*seg) for seg in zip(k, kv_positions, kv_valid)]
+    if sinks is not None:         # a score column that carries no value row
+        logits.append(jnp.broadcast_to(
+            sinks.astype(jnp.float32).reshape(Hkv, G)[None, :, :, None, None],
+            (B, Hkv, G, Sq, 1)))
+    logits = jnp.concatenate(logits, axis=-1)
     probs = jnp.exp(logits - jnp.max(logits, axis=-1, keepdims=True))
     probs = probs / jnp.sum(probs, axis=-1, keepdims=True)
-    if sinks is not None:
-        probs = probs[..., :-1]   # the sink carries no value row
-    out = jnp.einsum("bhqk,bkhd->bqhd", probs, v.astype(jnp.float32))
-    return out.astype(q.dtype)
+
+    out, start = 0.0, 0
+    for v_seg in v:
+        stop = start + v_seg.shape[1]
+        out = out + _dot("bkgqs,bskd->bqkgd", probs[..., start:stop], v_seg)
+        start = stop
+    return out.reshape(B, Sq, H, -1).astype(out_dtype or q.dtype)
 
 
 # ----------------------------------------------------------------------
@@ -147,9 +183,10 @@ def resolve_backend(requested: str = "auto", n_devices: int = 1,
     jit program spans one device.
 
     ``op="paged"`` (the continuous batcher's block-table decode): auto
-    resolves to xla — measured on v5e at serving shapes the XLA gather
-    formulation beats the pallas paged kernel ~2x per step (see
-    ops/paged_kvcache.paged_attend_decode). Explicit "pallas" is honored.
+    resolves to xla, the gather formulation of
+    ops/paged_kvcache.paged_attend_decode. No chip run on record compares
+    it with the pallas paged kernel (PERF.md section 7; ROADMAP S4 has
+    the XLA path's cost as the bar). Explicit "pallas" is honored.
     """
     requested = os.environ.get("DLI_ATTENTION", requested)
     if requested in ("xla", "pallas", "pallas_interpret"):

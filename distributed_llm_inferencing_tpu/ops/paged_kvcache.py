@@ -18,9 +18,12 @@ vLLM/PagedAttention:
   ``block_tables[r, p // bs]`` at offset ``p % bs``.
 
 Attention over the paged cache gathers each slot's blocks back into a
-contiguous [R, MB*bs, ...] view (XLA gather rides HBM at full bandwidth;
-a hand-tiled Pallas variant that skips the materialization is
-ops/pallas/paged_attention.py).
+contiguous [R, MB*bs, ...] view, which ``attend`` then reads once as it
+is (grouped-query form, no copy: ops/attention.py). The gather writes that
+view to HBM and covers the whole block table whatever the context, so it
+is a cost of its own beside attention (``kv_gather`` in PERF.md section
+5). A hand-tiled Pallas variant that skips the materialization is
+ops/pallas/paged_attention.py; the batcher does not select it.
 
 The reference framework has no counterpart at any level — its KV cache was
 implicit inside HF ``generate`` (SURVEY.md §2.4).
@@ -124,12 +127,11 @@ def paged_attend_decode(q, cache_k_layer, cache_v_layer, block_tables,
 
     backend "pallas" routes to the block-table-driven kernel
     (ops/pallas/paged_attention.py) which skips the gather
-    materialization below. "auto" resolves to the XLA gather formulation:
-    measured on v5e at serving shapes (R=8, short contexts) the gather
-    path is ~2x faster per step than the current pallas kernel — the
-    gather is a dense contiguous read XLA streams at full HBM bandwidth,
-    while the kernel's per-slot block walk is grid-serialized. Revisit
-    when contexts are long enough that gathering MB*bs dominates.
+    materialization below. "auto" resolves to the XLA gather formulation
+    (ops/attention.resolve_backend); which of the two is faster on a chip
+    at serving shapes has not been measured (PERF.md section 7). The
+    gather copies MB*bs positions per slot whatever ``context_lens``
+    says, and attention then reads all of them.
 
     int8 caches (``k_scale_layer``/``v_scale_layer`` present) always take
     the gather formulation — the dequant fuses into the gather/matmul;
@@ -200,11 +202,9 @@ def paged_attend_prefix(q, k_new, v_new, cache_k_layer, cache_v_layer,
     prefix_pos = jnp.broadcast_to(jnp.arange(p, dtype=jnp.int32), (b, p))
     prefix_valid = prefix_pos < prefix_len[:, None]
 
-    k_all = jnp.concatenate([kp, k_new.astype(kp.dtype)], axis=1)
-    v_all = jnp.concatenate([vp, v_new.astype(vp.dtype)], axis=1)
-    kv_pos = jnp.concatenate([prefix_pos, q_positions], axis=1)
-    kv_valid = jnp.concatenate([prefix_valid, tail_valid], axis=1)
     with jax.named_scope("attention"):
-        return attend(q, k_all, v_all, q_positions, kv_pos, kv_valid,
+        return attend(q, (kp, k_new.astype(kp.dtype)),
+                      (vp, v_new.astype(vp.dtype)), q_positions,
+                      (prefix_pos, q_positions), (prefix_valid, tail_valid),
                       sliding_window=sliding_window, alibi=alibi,
                       softcap=softcap, sinks=sinks)

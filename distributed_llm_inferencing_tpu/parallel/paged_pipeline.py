@@ -172,12 +172,8 @@ def paged_decode_chunk_pp(params, cfg: ModelConfig, k: int, tokens, paged,
                     sv2 = jax.lax.dynamic_update_slice(
                         sv_m, vh.astype(dt), (0, d, 0, 0))
                     attn = attend(
-                        q,
-                        jnp.concatenate([kp, sk2], axis=1),
-                        jnp.concatenate([vp, sv2], axis=1),
-                        q_pos,
-                        jnp.concatenate([pool_pos, side_pos], axis=1),
-                        jnp.concatenate([pool_valid, side_valid], axis=1),
+                        q, (kp, sk2), (vp, sv2), q_pos,
+                        (pool_pos, side_pos), (pool_valid, side_valid),
                         sliding_window=tf._layer_window(cfg, lp),
                         alibi=tf._alibi(cfg), softcap=cfg.attn_softcap)
                     return attn, (sk2, sv2)
@@ -447,12 +443,8 @@ def paged_speculative_chunk_pp(params, cfg: ModelConfig, k: int, gamma: int,
                     sv2 = jax.lax.dynamic_update_slice(
                         sv_m, vh.astype(dt), (0, d * g1, 0, 0))
                     attn = attend(
-                        q,
-                        jnp.concatenate([kp, sk2], axis=1),
-                        jnp.concatenate([vp, sv2], axis=1),
-                        qp,
-                        jnp.concatenate([pool_pos, side_pos_m], axis=1),
-                        jnp.concatenate([pool_valid, side_valid], axis=1),
+                        q, (kp, sk2), (vp, sv2), qp,
+                        (pool_pos, side_pos_m), (pool_valid, side_valid),
                         sliding_window=tf._layer_window(cfg, lp),
                         alibi=tf._alibi(cfg), softcap=cfg.attn_softcap)
                     return attn, (sk2, sv2)

@@ -14,6 +14,8 @@ own process. Keep these tests in this one file.
 """
 
 import functools
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -44,28 +46,32 @@ def topo():
 
 @pytest.fixture(scope="module")
 def compile_on_chip(topo):
-    """compile_on_chip(fn, (shape, dtype) pytree...) -> compiled HLO text.
-    Arguments are ShapeDtypeStructs placed on one described device; the
-    persistent compile cache is off around the compile (an entry written
-    for a described chip cannot be read back without one, and warns)."""
+    """compile_on_chip(fn, (shape, dtype) pytree..., kernel=True) -> the
+    compiled program. Arguments are ShapeDtypeStructs placed on one
+    described device; the persistent compile cache is off around the
+    compile (an entry written for a described chip cannot be read back
+    without one, and warns). ``kernel`` says whether the program has to
+    hold a Pallas kernel (a ``tpu_custom_call``) or is plain XLA."""
     one_chip = SingleDeviceSharding(topo.devices[0])
 
     def struct(leaf):
         shape, dtype = leaf
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    def run(fn, *args):
+    def run(fn, *args, kernel=True):
         args = [jax.tree.map(struct, a,
                              is_leaf=lambda x: isinstance(x, tuple))
                 for a in args]
         cache_was = jax.config.jax_enable_compilation_cache
         jax.config.update("jax_enable_compilation_cache", False)
         try:
-            text = jax.jit(fn).lower(*args).compile().as_text()
+            compiled = jax.jit(fn).lower(*args).compile()
         finally:
             jax.config.update("jax_enable_compilation_cache", cache_was)
-        assert "tpu_custom_call" in text, "kernel missing from the program"
-        return text
+        assert ("tpu_custom_call" in compiled.as_text()) == kernel, (
+            "kernel missing from the program" if kernel
+            else "a kernel in a program that should be plain XLA")
+        return compiled
 
     return run
 
@@ -151,3 +157,38 @@ def test_q4_matmul_compiles(compile_on_chip, din, dout, act):
     compile_on_chip(
         q4_matmul, ((SLOTS, din), act), ((din // 2, dout), jnp.uint8),
         ((dout,), jnp.float32))
+
+
+def test_decode_chunk_attention_copies_no_kv(compile_on_chip):
+    """The XLA decode attention of the benchmark's cells (16 slots, the
+    gathered pool of 2048 positions + the chunk's 8-row side buffer, 32
+    query over 8 kv heads of 128, bf16) reads K and V as stored. Before
+    PR 25 this program broadcast both to f32[16,2056,8,4,128] through HBM
+    (674 MB of temporaries, 87 % of the decode pass on the chip: PERF.md
+    section 6); it fails if anything K-sized comes back in f32."""
+    from distributed_llm_inferencing_tpu.ops.attention import attend
+    slots, pool, side = 16, 2048, 8
+
+    def decode_attention(q, kp, vp, sk, sv, cl, t):
+        pool_pos = jnp.broadcast_to(jnp.arange(pool, dtype=jnp.int32),
+                                    (slots, pool))
+        side_pos = cl[:, None] + jnp.arange(side, dtype=jnp.int32)[None]
+        side_valid = jnp.broadcast_to(
+            jnp.arange(side, dtype=jnp.int32)[None] <= t, (slots, side))
+        return attend(q, (kp, sk), (vp, sv), (cl + t)[:, None],
+                      (pool_pos, side_pos),
+                      (pool_pos < cl[:, None], side_valid),
+                      sliding_window=WINDOW)
+
+    compiled = compile_on_chip(
+        decode_attention, ((slots, 1, H, HD), BF16),
+        ((slots, pool, HKV, HD), BF16), ((slots, pool, HKV, HD), BF16),
+        ((slots, side, HKV, HD), BF16), ((slots, side, HKV, HD), BF16),
+        ((slots,), jnp.int32), ((), jnp.int32), kernel=False)
+    kv_elems = slots * (pool + side) * HKV * HD
+    wide = [m for m in set(re.findall(r"f32\[([\d,]+)\]",
+                                      compiled.as_text()))
+            if math.prod(map(int, m.split(","))) >= kv_elems]
+    assert not wide, f"K- or V-sized f32 arrays in the program: {wide}"
+    assert compiled.memory_analysis().temp_size_in_bytes <= 64 * 2 ** 20
+
