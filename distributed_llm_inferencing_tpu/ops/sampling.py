@@ -5,6 +5,13 @@ do_sample=True, top_p=0.95, top_k=50, temperature=0.8
 (reference: worker/app.py:297-305) — as the defaults of an explicit
 SamplingParams, and implements the pipeline as a jit-friendly pure function
 so it fuses into the decode step instead of running host-side per token.
+
+A row's top-k and nucleus cuts are two scalars (the k-th largest logit, the
+smallest logit of the nucleus). At serving sizes ``nucleus_thresholds`` finds
+both exactly by a search over the logits' bit patterns, a fixed number of
+masked reductions over ``[R, V]`` whatever the distribution's shape, and
+nothing sorts the vocabulary; a small ``[R, V]`` is still sorted
+(``_SORT_BELOW``).
 """
 
 from __future__ import annotations
@@ -37,21 +44,137 @@ def _mask_top_k(logits, k: int):
     return jnp.where(logits < kth, -jnp.inf, logits)
 
 
+# Bits of a threshold decided by one step of the search: a step weighs
+# 2**_SEARCH_BITS - 1 candidate thresholds in one fused pass over [R, V].
+# On a v5e at 64 x 128,256, 2 bits take 0.72 ms a search, 1 bit 1.46 and
+# 4 bits 1.75 (PERF.md, PR 28).
+_SEARCH_BITS = 2
+_KEY_NEG_INF = 0x007FFFFF       # _float_keys(-inf): the least non-NaN key
+
+
+def _float_keys(x):
+    """float32 -> uint32 that orders the same way: the sign bit of a
+    non-negative flipped, every bit of a negative (-0.0 counts as 0.0)."""
+    b = jax.lax.bitcast_convert_type(jnp.where(x == 0, 0.0, x), jnp.uint32)
+    return jnp.where(b >> 31 == 1, ~b, b | jnp.uint32(0x80000000))
+
+
+def _keys_to_float(key):
+    b = jnp.where(key >> 31 == 1, key ^ jnp.uint32(0x80000000), ~key)
+    return jax.lax.bitcast_convert_type(b, jnp.float32)
+
+
+def _largest_key_reaching(scaled, weight, floor, target):
+    """Per row, the largest uint32 key ``t`` such that the ``weight`` of
+    the row's entries ``x`` with ``x >= floor`` and ``key(x) >= t`` sums
+    to at least ``target``. scaled, weight: [R, V] f32, weight
+    non-negative; floor, target: [R] f32.
+
+    The sum is monotone in ``t`` (a fixed-order float sum of non-negative
+    terms, some of them zeroed), so ``t`` is decided from its top bits
+    down: 32 / _SEARCH_BITS steps, each one fused compare-select-reduce
+    over the row for every candidate value of the step's bits, the same
+    count for any data. Candidates are compared as floats
+    (``x >= float(t)``), which orders as the keys do."""
+    fields = jnp.arange(1, 1 << _SEARCH_BITS, dtype=jnp.uint32)
+
+    def step(i, t):
+        lo = (32 - _SEARCH_BITS * (i + 1)).astype(jnp.uint32)
+        cand = t[None, :] | (fields[:, None] << lo)              # [C, R]
+        # keys under key(-inf) are negative NaNs as floats; every entry
+        # lies at or above them
+        cut = _keys_to_float(jnp.maximum(cand, jnp.uint32(_KEY_NEG_INF)))
+        cut = jnp.maximum(cut, floor[None, :])
+        reached = jnp.sum(
+            jnp.where(scaled[None] >= cut[:, :, None], weight[None], 0.0),
+            axis=-1)                                             # [C, R]
+        taken = jnp.sum(reached >= target[None, :], axis=0)
+        return t | (taken.astype(jnp.uint32) << lo)
+
+    return jax.lax.fori_loop(0, 32 // _SEARCH_BITS, step,
+                             jnp.zeros(scaled.shape[:1], jnp.uint32))
+
+
+# Under this many logits a pass, nucleus_thresholds sorts them instead. Not
+# because the sort is cheaper (16 x 32,000: 0.55 ms against the search's
+# 0.1): with no sort in it, mistral-7b's decode chunk compiles 1.2 ms a
+# pass slower on a v5e, because XLA's memory-space assignment then moves
+# the chunk's four side-buffer copies into VMEM and cycles them through HBM
+# in every step of the layer loop (PERF.md section 6, PR 28). Goes when
+# the layer scan carries the side buffers once (ROADMAP S4 (b)).
+_SORT_BELOW = 1 << 20
+
+
+def _thresholds_by_sort(scaled, k, top_ps):
+    sorted_desc = jnp.sort(scaled, axis=-1)[:, ::-1]
+    kth = jnp.take_along_axis(sorted_desc, (k - 1)[:, None], axis=-1)
+    in_top_k = jnp.sum(sorted_desc >= kth, axis=-1, keepdims=True)
+    _, thresh = nucleus_mask_sorted(sorted_desc, in_top_k, top_ps[:, None])
+    return kth[:, 0], thresh[:, 0]
+
+
+def _thresholds_by_search(scaled, k, top_ps):
+    v = scaled.shape[-1]
+    top = jnp.max(scaled, axis=-1)
+    neg_inf = jnp.full_like(top, -jnp.inf)
+    kth = jax.lax.cond(
+        jnp.any(k < v),
+        lambda: _keys_to_float(_largest_key_reaching(
+            scaled, jnp.ones_like(scaled), neg_inf, k.astype(jnp.float32))),
+        lambda: neg_inf)
+    mass = jnp.exp(scaled - top[:, None])
+    total = jnp.sum(jnp.where(scaled >= kth[:, None], mass, 0.0), axis=-1)
+    t = _largest_key_reaching(scaled, mass, kth, top_ps * total)
+    # top_p <= 0 reaches its target at every key: the top token stays
+    t = jnp.clip(t, jnp.uint32(_KEY_NEG_INF), _float_keys(top))
+    return kth, _keys_to_float(t)
+
+
+def nucleus_thresholds(scaled, k, top_ps):
+    """The two scalars a row's top-k ∩ top-p mask needs.
+
+    scaled: [R, V] f32 logits (temperature applied); k: [R] int32 in 1..V
+    (V = top-k off); top_ps: [R] f32. Returns (kth, thresh), both [R] f32:
+    ``kth`` cuts the row to its k largest logits (the k-th largest; the
+    search gives -inf where no row of the batch has k < V: nothing to cut),
+    ``thresh`` is the smallest logit of the nucleus — the least value ``v``
+    of the row such that the probability of the logits strictly above
+    ``v``, renormalised over the top-k set, is below ``top_p`` (HF's
+    TopPLogitsWarper: the token that crosses ``top_p`` is kept). The
+    sampling support is ``scaled >= max(kth, thresh)``. From
+    ``_SORT_BELOW`` logits up both come from ``_largest_key_reaching``, and
+    the row is never sorted; below, from one descending sort.
+
+    Two things to know, in either form:
+
+    1. The mass before a token is a float32 sum: a masked sum in memory
+       order (search) or a cumulative sum in sorted order (sort). Both
+       round the same real number; where it lies within float32 summation
+       error of ``top_p`` the boundary token may fall on either side.
+    2. The top-k set is ``{x >= kth}``: a run of equal logits across the
+       k-th place belongs to it whole (it is what the mask by value keeps),
+       and the nucleus is normalised over that set, i.e. over exactly the
+       tokens that can be drawn. (Until PR 28 the sort normalised over k
+       positions, part of such a run, and still kept the whole run.)
+    """
+    by_sort = scaled.shape[0] * scaled.shape[1] < _SORT_BELOW
+    return (_thresholds_by_sort if by_sort
+            else _thresholds_by_search)(scaled, k, top_ps)
+
+
 def _mask_top_p(logits, p: float):
     """Nucleus filtering: keep the smallest prefix of the sorted distribution
     with cumulative probability >= p (the token crossing the threshold is
-    kept, matching HF's TopPLogitsWarper)."""
+    kept, matching HF's TopPLogitsWarper): the one definition of the
+    nucleus threshold, ``nucleus_thresholds``."""
     if p >= 1.0:
         return logits
-    sorted_logits = jnp.sort(logits, axis=-1)[..., ::-1]
-    probs = jax.nn.softmax(sorted_logits, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    # sorted position i is removed if the cumulative mass *before* it >= p
-    keep_sorted = (cum - probs) < p
-    # threshold logit = smallest kept logit
-    num_keep = jnp.sum(keep_sorted, axis=-1, keepdims=True)  # >= 1
-    thresh = jnp.take_along_axis(sorted_logits, num_keep - 1, axis=-1)
-    return jnp.where(logits < thresh, -jnp.inf, logits)
+    lead = logits.shape[:-1]
+    rows = logits.reshape((-1, logits.shape[-1]))
+    _, thresh = nucleus_thresholds(
+        rows, jnp.full(rows.shape[:1], rows.shape[-1], jnp.int32),
+        jnp.full(rows.shape[:1], p, jnp.float32))
+    return jnp.where(logits < thresh.reshape(lead + (1,)), -jnp.inf, logits)
 
 
 def warp_logits(logits, params: SamplingParams):
@@ -107,10 +230,10 @@ def sample(logits, key, params: SamplingParams,
     return jax.random.categorical(key, logits, axis=-1).astype(jnp.int32)
 
 
-# Static prefix width for sample_batch's fast path. Rows whose top_k fits
-# inside it sample exactly from one lax.top_k — no full-vocab sort, which
-# on TPU (bitonic network over [R, 50k+]) costs more than a whole decode
-# step of a 125M model.
+# Static prefix width for sample_batch's prefix tier. Rows whose top_k fits
+# inside it sample exactly from one lax.top_k and draw over PREFIX_K
+# candidates; every other sampling row takes the full tier, which cuts by
+# nucleus_thresholds' two scalars and draws over the whole vocabulary.
 PREFIX_K = 128
 
 
@@ -149,17 +272,19 @@ def sample_batch(logits, seeds, steps, temps, top_ks, top_ps, do_sample):
     are data, not trace constants — one compiled program covers any mix of
     requests.
 
-    Two tiers, chosen per step by ``lax.cond``:
+    Two tiers, each computed only in a step that has a row for it
+    (``lax.switch``: neither, prefix, full, both):
     - **prefix** (hot): rows with 0 < k <= PREFIX_K (every realistic
       serving config; the reference hardcoded k=50, worker/app.py:301)
       sample from ``lax.top_k(PREFIX_K)``. Exact: the k-masked
       distribution's support lies inside the prefix, so softmax/top-p
       thresholds over the prefix equal the full-vocab computation.
-    - **full** (cold): any sampling row with k == 0 (disabled) or
-      k > PREFIX_K pays the full-vocab descending sort.
-    A row's draw mechanism depends only on its OWN k — covered rows take
-    the prefix draw in both branches — so chunk-mates with exotic configs
-    never change another request's tokens.
+    - **full**: any sampling row with k == 0 (disabled: the OpenAI and
+      vLLM default) or k > PREFIX_K masks the whole vocabulary by the two
+      scalars of ``nucleus_thresholds`` and draws from it.
+    A row's draw mechanism depends only on its OWN k — a covered row takes
+    the prefix draw and an uncovered one the full draw in every branch —
+    so chunk-mates never change another request's tokens.
     """
     logits = logits.astype(jnp.float32)
     r, v = logits.shape
@@ -171,28 +296,28 @@ def sample_batch(logits, seeds, steps, temps, top_ks, top_ps, do_sample):
     keys = jax.vmap(
         lambda s, t: jax.random.fold_in(jax.random.PRNGKey(s), t)
     )(seeds, steps)
-    vals, idx = jax.lax.top_k(scaled, ks)               # [R, KS] descending
-
-    def _nucleus_mask(sorted_vals, width):
-        return nucleus_mask_sorted(sorted_vals, width, top_ps[:, None])
 
     def prefix_draw():
-        m, _ = _nucleus_mask(vals, jnp.minimum(k, ks)[:, None])
+        vals, idx = jax.lax.top_k(scaled, ks)           # [R, KS] descending
+        m, _ = nucleus_mask_sorted(vals, jnp.minimum(k, ks)[:, None],
+                                   top_ps[:, None])
         j = jax.vmap(lambda kk, l: jax.random.categorical(kk, l))(keys, m)
         return jnp.take_along_axis(idx, j[:, None], axis=-1)[:, 0]
 
     def full_draw():
-        sorted_desc = jnp.sort(scaled, axis=-1)[:, ::-1]
-        kth = jnp.take_along_axis(sorted_desc, (k - 1)[:, None], axis=-1)
-        _, thresh = _nucleus_mask(sorted_desc, k[:, None])
-        masked = jnp.where((scaled < kth) | (scaled < thresh), -jnp.inf,
-                           scaled)
+        kth, thresh = nucleus_thresholds(scaled, k, top_ps)
+        masked = jnp.where(scaled < jnp.maximum(kth, thresh)[:, None],
+                           -jnp.inf, scaled)
         return jax.vmap(
             lambda kk, l: jax.random.categorical(kk, l))(keys, masked)
 
-    sampled = jax.lax.cond(
-        jnp.all(covered | ~do_sample),
-        prefix_draw,
-        lambda: jnp.where(covered, prefix_draw(), full_draw()))
+    # each tier is computed only in a pass that has a row for it (the
+    # top_k of 64 x 128,256 costs 3.2 ms on a v5e, the search 1 ms)
+    sampled = jax.lax.switch(
+        jnp.any(do_sample & covered) + 2 * jnp.any(do_sample & ~covered),
+        [lambda: jnp.zeros((r,), jnp.int32),            # greedy rows only
+         prefix_draw,
+         full_draw,
+         lambda: jnp.where(covered, prefix_draw(), full_draw())])
     greedy = jnp.argmax(logits, axis=-1)
     return jnp.where(do_sample, sampled, greedy).astype(jnp.int32)

@@ -58,7 +58,7 @@ from distributed_llm_inferencing_tpu.native import BlockPool
 from distributed_llm_inferencing_tpu.ops import kvblock_quant as kvq
 from distributed_llm_inferencing_tpu.ops.paged_kvcache import init_paged_cache
 from distributed_llm_inferencing_tpu.ops.sampling import (
-    SamplingParams, sample_batch)
+    PREFIX_K, SamplingParams, sample_batch)
 from distributed_llm_inferencing_tpu.parallel import sharding as shd
 from distributed_llm_inferencing_tpu.parallel.mesh import (
     MeshSpec, create_mesh, validate_spec)
@@ -2769,11 +2769,11 @@ class ContinuousBatcher:
         # program outputs, so lockstep followers mirror it in replay()
         self._apply_plain_hist(toks, emits,
                                np.asarray(decode_args["cl"], np.int32))
-        return self._emit_chunk_outputs(active, toks, emits, k,
+        return self._emit_chunk_outputs(active, toks, emits, k, decode_args,
                                         budget=budget)
 
     def _emit_chunk_outputs(self, active, toks, emits, passes: int,
-                            budget=None) -> int:
+                            decode_args: dict, budget=None) -> int:
         """Shared emit/finish/amortization epilogue for [K, R]-shaped
         chunk outputs (plain and overlapped paths; the speculative path's
         outputs are [K, R, G+1] keeps-shaped and handled in place).
@@ -2798,15 +2798,26 @@ class ContinuousBatcher:
                            and cnt < int(budget[i]))  # stopped pre-budget
                 if hit_eos or len(req.tokens) >= req.max_new_tokens:
                     self._finish_slot(i)
-        # amortization: emitted tokens per weight-streaming pass (one
-        # pass per decode iteration) — THE number continuous batching
-        # exists to raise. Gauge for live /metrics, counters for
-        # windowed ratios (bench.py takes per-rep deltas).
+        self._count_passes(decode_args, passes, emitted)
+        return emitted
+
+    def _count_passes(self, decode_args: dict, passes: int,
+                      emitted: int) -> None:
+        """Amortization: emitted tokens per weight-streaming pass (one
+        pass per decode or verify iteration, however wide a draft is) —
+        THE number continuous batching and wave speculation exist to
+        raise. Gauge for live /metrics, counters for windowed ratios
+        (bench.py takes per-rep deltas). ``batcher_sample_full_passes``
+        counts the passes of chunks in which some sampling row has top_k
+        off or beyond PREFIX_K, so that sample_batch's full tier ran:
+        its ratio to ``batcher_weight_passes`` is that tier's share."""
         self.metrics.gauge("decode_tokens_per_weight_pass",
                            emitted / passes if passes else 0.0)
         self.metrics.inc("batcher_weight_passes", passes)
         self.metrics.inc("batcher_tokens_emitted", emitted)
-        return emitted
+        if any(d and not 0 < tk <= PREFIX_K
+               for tk, d in zip(decode_args["tks"], decode_args["ds"])):
+            self.metrics.inc("batcher_sample_full_passes", passes)
 
     def _overlap_eligible(self, active, k: int) -> bool:
         """True when a chunk pair can dispatch back-to-back with no host
@@ -2869,7 +2880,7 @@ class ContinuousBatcher:
 
         toks = np.concatenate([toks_a, toks_b], axis=0)
         emits = np.concatenate([emits_a, emits_b], axis=0)
-        self._emit_chunk_outputs(active, toks, emits, 2 * k)
+        self._emit_chunk_outputs(active, toks, emits, 2 * k, args_a)
         return len([a for a in self.active if a is not None])
 
     def _step_speculative(self, active, decode_args: dict) -> int:
@@ -2949,12 +2960,7 @@ class ContinuousBatcher:
         emitted = sum(cnt for (_, cnt, _, _) in per.values())
         live_iters = sum(live for (_, _, live, _) in per.values())
         accepted = emitted - live_iters
-        # amortization: a verify iteration streams the weights once
-        # however wide the draft is — that width is the whole speedup
-        m.gauge("decode_tokens_per_weight_pass",
-                emitted / k_it if k_it else 0.0)
-        m.inc("batcher_weight_passes", k_it)
-        m.inc("batcher_tokens_emitted", emitted)
+        self._count_passes(decode_args, k_it, emitted)
         if ctl is not None:
             ctl.record("spec", emitted=emitted,
                        elapsed_s=clock.now() - w0,
@@ -3145,14 +3151,7 @@ class ContinuousBatcher:
                 req._spec_ctl.record("plain", emitted=cnt, elapsed_s=dt,
                                      compiled=compiled)
             self._sync_wave_shared(req._spec_ctl)
-        # THE headline metric: emitted tokens per weight-streaming pass.
-        # A verify iteration streams the weights once however wide the
-        # per-slot drafts are — wave speculation exists to push this
-        # past plain batching's 1.0-per-live-slot.
-        m.gauge("decode_tokens_per_weight_pass",
-                emitted / k_it if k_it else 0.0)
-        m.inc("batcher_weight_passes", k_it)
-        m.inc("batcher_tokens_emitted", emitted)
+        self._count_passes(decode_args, k_it, emitted)
         m.inc("spec_wave_drafted_tokens", drafted_total)
         m.inc("spec_wave_accepted_tokens", accepted_total)
         m.inc("spec_wave_plain_rides", len(riding))

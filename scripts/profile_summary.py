@@ -7,7 +7,8 @@ TensorBoard:
 1. The device's time by named scope: the self time of each `XLA Ops`
    event (a `while`'s time less its body's ops) under the innermost scope
    of the vocabulary in its op name (docs/observability.md), per program
-   (`XLA Modules`: `jit_chunk`, `jit_admit`).
+   (`XLA Modules`: `jit_chunk`, `jit_admit`), and each program's largest
+   operations with their scope.
 2. The device's idle time by host phase: every gap between op events,
    split over the `dli.<phase>` annotations of the batcher's step loop
    that it overlaps (innermost bracket first; the profiler must have been
@@ -44,6 +45,7 @@ NO_SCOPE = "(no scope)"
 # own name starts with: `lax.ragged_dot` becomes `ragged-dot-none.N`
 # custom calls (the grouped expert matmuls)
 BY_NAME = {"ragged-dot": "moe_experts"}
+TOP_OPS = 16    # operations listed for each program, largest first
 
 
 def find_xplane(path: str) -> str:
@@ -177,7 +179,8 @@ def all_program_scopes(path: str) -> dict:
 
 def read(path: str) -> dict:
     """{"devices": [{"name", "modules": [(program, start, end)], "ops":
-    [(scope, start, end)]}], "host": [(name, start, end)]}: seconds, the
+    [(scope, start, end, instruction)]}], "host": [(name, start, end)]}:
+    seconds, the
     devices on their plane's clock and `dli.*` annotations on the host's.
     An op's scope is looked up by its instruction's name in the program
     (`jit_chunk(<id>)`) whose run it falls in."""
@@ -207,7 +210,8 @@ def read(path: str) -> dict:
                                   if instruction.startswith(prefix)),
                                  NO_SCOPE)
                 ops.append((scope, start,
-                            (e.start_ns + e.duration_ns) * 1e-9))
+                            (e.start_ns + e.duration_ns) * 1e-9,
+                            instruction))
             devices.append({
                 "name": plane.name,
                 "modules": [(name.split("(", 1)[0], a, z)
@@ -239,18 +243,26 @@ def self_times(spans):
     return out
 
 
-def by_scope(dev) -> dict:
-    """{program: {scope: seconds}} for one device."""
+def by_scope(dev):
+    """({program: {scope: seconds}}, {program: [(instruction, scope,
+    seconds, events)]}: the TOP_OPS operations by self time) for one
+    device."""
     starts = [m[1] for m in dev["modules"]]
-    table = {}
-    for (scope, start, _), self_s in self_times(dev["ops"]):
+    table, ops = {}, {}
+    for (scope, start, _, name), self_s in self_times(dev["ops"]):
         i = bisect.bisect_right(starts, start) - 1
         program = (dev["modules"][i][0]
                    if i >= 0 and start < dev["modules"][i][2]
                    else "(no program)")
         row = table.setdefault(program, {})
         row[scope] = row.get(scope, 0.0) + self_s
-    return table
+        op = ops.setdefault(program, {}).setdefault((name, scope), [0.0, 0])
+        op[0] += self_s
+        op[1] += 1
+    return table, {
+        program: [(name, scope, s, n) for (name, scope), (s, n) in
+                  sorted(row.items(), key=lambda kv: -kv[1][0])[:TOP_OPS]]
+        for program, row in ops.items()}
 
 
 def clock_offset(dev, host):
@@ -291,7 +303,7 @@ def idle_by_phase(dev, host, offset: float) -> dict:
     brackets = [(n[4:], s, e) for n, s, e in host]
     table = {}
     cur = None
-    for _, start, end in dev["ops"]:
+    for _, start, end, *_ in dev["ops"]:      # (scope, start, end[, name])
         if cur is not None and start > cur:
             g0, g1 = cur + offset, start + offset
             inside = [(n, max(s, g0), min(e, g1)) for n, s, e in brackets
@@ -315,11 +327,13 @@ def summarize(path: str) -> dict:
     for dev in events["devices"]:
         ops = dev["ops"]
         offset = clock_offset(dev, events["host"])
+        scopes, largest = by_scope(dev)
         out["devices"].append({
             "name": dev["name"],
             "window_s": max(o[2] for o in ops) - ops[0][1],
             "busy_s": sum(s for _, s in self_times(ops)),
-            "by_scope": by_scope(dev),
+            "by_scope": scopes,
+            "by_op": largest,
             "clock_offset_ms": offset and [x * 1e3 for x in offset],
             "idle_by_phase": idle_by_phase(dev, events["host"],
                                            offset[0] if offset else 0.0)})
@@ -347,6 +361,9 @@ def render(summary: dict) -> str:
             for scope, s in sorted(row.items(), key=lambda kv: -kv[1]):
                 lines.append(f"    {scope:<12} {s:9.4f} s "
                              f"{100 * s / total:6.2f} %")
+            for name, scope, s, n in dev["by_op"].get(program, ()):
+                lines.append(f"      op {name:<40} {scope:<12} {s:9.4f} s "
+                             f"{100 * s / total:6.2f} % in {n} events")
         off = dev["clock_offset_ms"]
         lines.append("idle time by host phase (device clock "
                      + (f"{off[0]:+.3f} ms, between {off[1]:+.3f} and "

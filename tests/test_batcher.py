@@ -534,3 +534,26 @@ def test_overlap_defers_to_eos_and_queue():
     b.queue.pop()
     assert b._overlap_eligible(active, 4)
     run_until_done(b, [r2])
+
+
+@pytest.mark.parametrize("mate,full", [
+    (SamplingParams(top_k=50), False),                  # prefix tier only
+    (SamplingParams.greedy(), False),                   # no draw at all
+    (SamplingParams(top_k=0, top_p=0.9), True),         # top-k off
+    (SamplingParams(top_k=10_000), True),               # beyond PREFIX_K
+])
+def test_sample_full_passes_counts_the_full_tier(mate, full):
+    """batcher_sample_full_passes rises by a chunk's passes when some
+    sampling row of the chunk cannot take sample_batch's prefix tier
+    (top_k off or beyond PREFIX_K), and stays put for a k = 50 fleet:
+    its ratio to batcher_weight_passes is the full tier's share."""
+    b = ContinuousBatcher(CFG, PARAMS, num_blocks=64, block_size=8,
+                          slots=4, max_seq=128)
+    reqs = [b.submit([3, 4, 5], max_new_tokens=12,
+                     sampling=SamplingParams(top_k=50)),
+            b.submit([6, 7, 8, 9], max_new_tokens=12, sampling=mate)]
+    run_until_done(b, reqs)
+    c = b.metrics.snapshot()["counters"]
+    assert c["batcher_weight_passes"] > 0
+    assert c.get("batcher_sample_full_passes", 0) == (
+        c["batcher_weight_passes"] if full else 0)

@@ -3,16 +3,23 @@
 The batcher's sampler runs inside every decode-chunk program; these tests
 pin (a) masking semantics (top-k, nucleus, greedy), (b) branch purity — a
 row's draw never depends on its chunk-mates' configs, the property the
-scheduler's reproducibility contract rests on, and (c) that the prefix
-fast path samples the same *distribution* the full-vocab path does.
+scheduler's reproducibility contract rests on, (c) that the prefix
+fast path samples the same *distribution* the full-vocab path does, and
+(d) the full tier's thresholds (``nucleus_thresholds``: a search over bit
+patterns from ``_SORT_BELOW`` logits up, a sort below) in both forms
+against a float64 oracle, and the search against the sort-based full tier
+it replaced, kept here as ``_sorted_full_draw``.
 """
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
+from distributed_llm_inferencing_tpu.ops import sampling
 from distributed_llm_inferencing_tpu.ops.sampling import (
-    PREFIX_K, sample_batch)
+    PREFIX_K, SamplingParams, nucleus_mask_sorted, sample, sample_batch,
+    warp_logits)
 
 RNG = np.random.default_rng(0)
 
@@ -109,3 +116,207 @@ def test_prefix_path_matches_full_distribution():
     assert counts[k:].sum() == 0          # top-k mask held
     p = np.exp(logits[0, :k]) / np.exp(logits[0, :k]).sum()
     np.testing.assert_allclose(counts[:k] / n, p, atol=0.04)
+
+
+# ---- the full tier: thresholds by search, or by sort when small --------
+
+V = 2000
+ROW_KINDS = ("flat", "peaked", "bf16_ties", "neg_inf")
+MARGIN = 1e-5      # float32 summation error around top_p (docstring, 1.)
+
+
+def _rows(kind, r, v, seed=0):
+    rng = np.random.default_rng([seed, ROW_KINDS.index(kind)])
+    x = rng.normal(size=(r, v))
+    if kind == "flat":          # what random weights give: a wide nucleus
+        x *= 0.3
+    elif kind == "peaked":
+        x *= 4.0
+    elif kind == "bf16_ties":   # a bf16 head's logits: many equal values
+        x = np.asarray(jnp.asarray(x * 2, jnp.bfloat16).astype(jnp.float32))
+    else:                       # banned tokens
+        x[:, ::3] = -np.inf
+    return x.astype(np.float32)
+
+
+def _oracle(x, k, p):
+    """(kept, sure) for one row in float64: the top-k set is {x >= kth},
+    a token is kept iff the mass strictly above its value, over that set,
+    is below p; ``sure`` where that mass is further than MARGIN from p."""
+    x = x.astype(np.float64)
+    v = x.shape[0]
+    order = np.argsort(-x, kind="stable")
+    xs = x[order]
+    kth = xs[(v if k <= 0 else min(k, v)) - 1]
+    in_topk = x >= kth
+    es = np.where(xs >= kth, np.exp(xs - xs[0]), 0.0)
+    cum = np.concatenate([[0.0], np.cumsum(es)])
+    above = cum[np.searchsorted(-xs, -x, side="left")] / cum[-1]
+    return in_topk & (above < p), ~in_topk | (np.abs(above - p) > MARGIN)
+
+
+FORMS = {"search": jax.jit(sampling._thresholds_by_search),
+         "sort": jax.jit(sampling._thresholds_by_sort)}
+
+
+def _kept(x, k, p, form):
+    r, v = x.shape
+    kth, thresh = FORMS[form](
+        jnp.asarray(x), jnp.full((r,), v if k <= 0 else min(k, v), jnp.int32),
+        jnp.full((r,), p, jnp.float32))
+    cut = np.maximum(np.asarray(kth), np.asarray(thresh))
+    return x >= cut[:, None], np.asarray(kth), np.asarray(thresh)
+
+
+@pytest.fixture
+def search_sample(monkeypatch):
+    """sample_batch jitted with the search at any size (the tests' rows
+    are far under _SORT_BELOW, where it would sort)."""
+    monkeypatch.setattr(sampling, "_SORT_BELOW", 0)
+    return jax.jit(sample_batch)
+
+
+@pytest.mark.parametrize("form", list(FORMS))
+@pytest.mark.parametrize("p", [0.1, 0.9, 1.0])
+@pytest.mark.parametrize("k", [0, 1, 129, 500, V])
+@pytest.mark.parametrize("kind", ROW_KINDS)
+def test_kept_set_matches_float64_oracle(kind, k, p, form):
+    x = _rows(kind, 8, V)
+    kept, kth, thresh = _kept(x, k, p, form)
+    for r in range(x.shape[0]):
+        want, sure = _oracle(x[r], k, p)
+        np.testing.assert_array_equal(kept[r][sure], want[sure])
+        # (at p = 1.0 a long tail lies within the margin of 1.0)
+        assert kept[r].any() and (p == 1.0 or sure.mean() > 0.99)
+        # the cut is a value of the row
+        assert max(kth[r], thresh[r]) in x[r]
+
+
+def _sorted_full_draw(logits, seeds, steps, temps, top_ks, top_ps):
+    """sample_batch's full tier as it was before the search: a descending
+    sort of the vocabulary to read two scalars a row."""
+    logits = jnp.asarray(logits, jnp.float32)
+    v = logits.shape[-1]
+    scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
+    k = jnp.where(top_ks <= 0, v, jnp.clip(top_ks, 1, v))
+    keys = jax.vmap(
+        lambda s, t: jax.random.fold_in(jax.random.PRNGKey(s), t)
+    )(seeds, steps)
+    sorted_desc = jnp.sort(scaled, axis=-1)[:, ::-1]
+    kth = jnp.take_along_axis(sorted_desc, (k - 1)[:, None], axis=-1)
+    _, thresh = nucleus_mask_sorted(sorted_desc, k[:, None],
+                                    top_ps[:, None])
+    masked = jnp.where((scaled < kth) | (scaled < thresh), -jnp.inf, scaled)
+    return jax.vmap(
+        lambda kk, l: jax.random.categorical(kk, l))(keys, masked)
+
+
+@pytest.mark.parametrize("block", range(4))
+def test_tokens_equal_the_sorted_form_at_the_cells_settings(block,
+                                                            search_sample):
+    """256 random rows in four blocks of 64 (a decode pass of the kanana
+    cell) at the benchmark's settings, temperature 0.7, top-p 0.9, top-k
+    off: the drawn token is the sort-based form's, bit for bit, in every
+    row the oracle is sure of (a flat row's tokens weigh about 1e-4
+    each, so in a quarter of such rows some token's mass lies within the
+    margin of 0.9; even there the tokens rarely differ)."""
+    r, v = 64, 8192
+    kind = ROW_KINDS[block % 3]
+    x = _rows(kind, r, v, seed=10 + block)
+    seeds = jnp.arange(r, dtype=jnp.int32) + 1000 * block
+    steps = jnp.full((r,), 17 + block, jnp.int32)
+    temps = jnp.full((r,), 0.7, jnp.float32)
+    tks = jnp.zeros((r,), jnp.int32)
+    tps = jnp.full((r,), 0.9, jnp.float32)
+    got = np.asarray(search_sample(jnp.asarray(x), seeds, steps, temps, tks,
+                                   tps, jnp.ones((r,), bool)))
+    want = np.asarray(jax.jit(_sorted_full_draw)(x, seeds, steps, temps,
+                                                 tks, tps))
+    scaled = x / np.float32(0.7)
+    sure = np.array([_oracle(scaled[i], 0, 0.9)[1].all() for i in range(r)])
+    assert sure.mean() > 0.6
+    np.testing.assert_array_equal(got[sure], want[sure])
+    assert (got != want).sum() <= 2
+
+
+def _primitives(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _primitives(sub)
+
+
+@pytest.mark.parametrize("name", ["sample_batch", "sample", "warp_logits"])
+@pytest.mark.parametrize("rows,vocab,sorts", [(64, 128256, False),
+                                              (16, 32000, True)])
+def test_no_path_sorts_a_large_vocabulary(name, rows, vocab, sorts):
+    """At the kanana cell's 64 x 128,256 no sampling path holds a `sort`;
+    at the mistral cells' 16 x 32,000, under _SORT_BELOW, the thresholds
+    still come from one."""
+    x = jax.ShapeDtypeStruct((rows, vocab), jnp.float32)
+    i = jax.ShapeDtypeStruct((rows,), jnp.int32)
+    f = jax.ShapeDtypeStruct((rows,), jnp.float32)
+    sp = SamplingParams(temperature=0.7, top_k=0, top_p=0.9)
+    if name == "sample_batch":
+        jaxpr = jax.make_jaxpr(sample_batch)(
+            x, i, i, f, i, f, jax.ShapeDtypeStruct((rows,), jnp.bool_))
+    elif name == "sample":
+        jaxpr = jax.make_jaxpr(
+            lambda x, key: sample(x, key, sp))(x, jax.random.PRNGKey(0))
+    else:
+        jaxpr = jax.make_jaxpr(lambda x: warp_logits(x, sp))(x)
+    prims = set(_primitives(jaxpr.jaxpr))
+    assert ("sort" in prims) == sorts, prims
+    assert bool(prims & {"while", "scan"}) != sorts   # the search's loop
+
+
+@pytest.mark.parametrize("mate", ["covered", "uncovered", "greedy"])
+def test_full_tier_row_independent_of_chunk_mates(mate, search_sample):
+    """A full-tier row (top-k off) draws the same token whatever its
+    chunk-mate asks for: a prefix-tier row, another full-tier row with
+    its own k and p, or a greedy row."""
+    v = PREFIX_K * 16
+    logits = _rows("flat", 2, v, seed=3)
+    tk, tp, ds = {"covered": (50, 0.95, True),
+                  "uncovered": (PREFIX_K + 7, 0.5, True),
+                  "greedy": (0, 1.0, False)}[mate]
+    def draw(step, temps, tks, tps, ds):
+        return np.asarray(search_sample(
+            jnp.asarray(logits), jnp.asarray([21, 22], jnp.int32),
+            jnp.full((2,), step, jnp.int32), jnp.asarray(temps, jnp.float32),
+            jnp.asarray(tks, jnp.int32), jnp.asarray(tps, jnp.float32),
+            jnp.asarray(ds)))
+
+    for step in range(8):
+        base = draw(step, [0.7, 0.7], [0, 0], [0.9, 0.9], [True, True])
+        mixed = draw(step, [0.7, 1.3], [0, tk], [0.9, tp], [True, ds])
+        assert base[0] == mixed[0], (step, base, mixed)
+
+
+@pytest.mark.parametrize("form", list(FORMS))
+def test_tie_across_the_kth_place_small(form):
+    """Docstring, 2.: a run of equal logits across the k-th place is in
+    the top-k set whole, and the nucleus is normalised over that set.
+    k = 3 over [4, 3, 2, 2, 2, 1, 0]: the set is the first five tokens;
+    the mass above 2 is 74.7 / 96.8 = 0.77 < 0.85, so all five stay
+    (over three sorted positions, as before PR 28, it would be 0.91, and
+    only 4 and 3 would)."""
+    x = np.array([[4, 3, 2, 2, 2, 1, 0]], np.float32)
+    kept, kth, thresh = _kept(x, 3, 0.85, form)
+    assert kth[0] == 2.0 and thresh[0] == 2.0
+    np.testing.assert_array_equal(np.flatnonzero(kept[0]), [0, 1, 2, 3, 4])
+    kept, _, thresh = _kept(x, 3, 0.7, form)  # 54.6 / 96.8 = 0.56 < 0.7
+    assert thresh[0] == 3.0
+    np.testing.assert_array_equal(np.flatnonzero(kept[0]), [0, 1])
+
+
+def test_tie_across_the_kth_place_is_drawn_from():
+    """The same through sample_batch (PREFIX_K < k < V): with k = 130
+    over 129 distinct logits then a run of five equal ones, draws land
+    on the run's every token and nowhere below it."""
+    v = 400
+    logits = np.full((1, v), -5.0, np.float32)
+    logits[0, :129] = np.linspace(1.0, 0.5, 129)
+    logits[0, 129:134] = 0.4
+    out = _draw_many(logits, seed=2, steps=3000, temp=1.0, tk=130, tp=1.0)
+    assert out.max() == 133 and set(range(129, 134)) <= set(out.tolist())

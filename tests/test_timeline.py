@@ -297,6 +297,18 @@ def test_profile_summary_splits_idle_time_over_host_phases():
            ("mlp", 0.005, 0.008)]
     assert {k[0]: pytest.approx(v) for k, v in ps.self_times(ops)} == \
         {"w": 0.004, "attention": 0.003, "mlp": 0.003}
+    # the same by scope and by operation (the instruction's name rides
+    # fourth), largest first
+    named = [(ps.NO_SCOPE, 0.0, 0.010, "while.1"),
+             ("sample", 0.001, 0.004, "sort.154"),
+             ("sample", 0.005, 0.008, "fusion.1878")]
+    scopes, largest = ps.by_scope(
+        {"modules": [("jit_chunk", 0.0, 0.010)], "ops": named})
+    assert scopes == {"jit_chunk": pytest.approx(
+        {ps.NO_SCOPE: 0.004, "sample": 0.006})}
+    assert [(o[0], o[1], o[3]) for o in largest["jit_chunk"]] == [
+        ("while.1", ps.NO_SCOPE, 1), ("sort.154", "sample", 1),
+        ("fusion.1878", "sample", 1)]
     # the device's clock runs 2 ms behind the host's: two chunk runs of
     # 100 ms with 6 ms between them, as the host saw them
     dev = {"modules": [("jit_chunk", 0.000, 0.100),

@@ -265,3 +265,47 @@ def test_expert_dispatch_is_a_grouped_matmul(compile_on_chip, tokens):
     # combine: nowhere near an expert-weights-sized temporary
     assert compiled.memory_analysis().temp_size_in_bytes \
         <= 16 * rows * d + 32 * 2 ** 20
+
+
+def test_decode_chunk_sorts_nothing_vocabulary_sized(compile_on_chip):
+    """A decode chunk of the kanana cell (64 slots over 128,256 logits,
+    the dense layer and one expert layer, the latent pool) samples
+    without sorting the vocabulary: `sample_batch`'s full tier, which
+    every `top_k` 0 row takes, finds its thresholds by a search
+    (`ops/sampling.py: nucleus_thresholds`). Before PR 28 this program
+    held `sort(f32[64,128256])`, a third of the pass on the chip
+    (PERF.md section 6). The expert dispatch's sort of its 384 (token,
+    choice) pairs stays."""
+    from distributed_llm_inferencing_tpu.models import transformer
+    from distributed_llm_inferencing_tpu.models.params import init_params
+    from distributed_llm_inferencing_tpu.ops.paged_kvcache import (
+        PagedKVCache, init_paged_cache)
+    slots, bs, blocks, mb = 64, 16, 640, 160
+    cfg = KANANA.replace(num_layers=2, attn_backend="xla",
+                         mla_latent_cache=True)
+
+    def shapes(tree):
+        return jax.tree.map(lambda s: (s.shape, s.dtype), tree)
+
+    params = shapes(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    pool = shapes(jax.eval_shape(
+        lambda: init_paged_cache(cfg, blocks + 1, bs).k))
+
+    def chunk(params, pool, tokens, bt, ints, floats, ds):
+        cl, seeds, steps, tks, budget, eos = ints
+        return transformer.paged_decode_chunk(
+            params, cfg, 1, tokens, PagedKVCache(k=pool), bt, cl, seeds,
+            steps, floats[0], tks, floats[1], ds, budget, eos, blocks)
+
+    compiled = compile_on_chip(
+        chunk, params, pool, ((slots,), jnp.int32),
+        ((slots, mb), jnp.int32), ((6, slots), jnp.int32),
+        ((2, slots), jnp.float32), ((slots,), jnp.bool_))
+    sorts = re.findall(r"= \(?([^=\n]*?)\)? sort\(", compiled.as_text())
+    assert sorts, "the expert dispatch's sort should be in the program"
+    wide = [s for s in sorts
+            if any(int(d) >= cfg.vocab_size
+                   for dims in re.findall(r"\[([\d,]+)\]", s)
+                   for d in dims.split(","))]
+    assert not wide, f"a sort over the vocabulary: {wide}"
