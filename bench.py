@@ -2373,7 +2373,7 @@ def bench_decode_speed_leg(model, n_requests, new_tokens, prompt_len,
     tput, stats = bench_batched(
         model=model, n_requests=n_requests, new_tokens=new_tokens,
         prompt_len=prompt_len, repeats=repeats, repetitive=True,
-        speculative="ngram" if wave_on else None, spec_wave=wave_on)
+        speculative="ngram" if wave_on else None)
     slots = stats.get("active_slots") or n_requests
     tpwp = stats.get("tokens_per_weight_pass")
     leg = {
@@ -3294,8 +3294,7 @@ def _scenario_main(argv):
 def bench_batched(model=MODEL, quant=None, n_requests=8,
                   new_tokens=NEW_TOKENS, dtype=None, repeats=2,
                   prompt_len=PROMPT_LEN, kv_quant=None,
-                  speculative=None, repetitive=False, stagger_s=None,
-                  spec_wave=None):
+                  speculative=None, repetitive=False, stagger_s=None):
     """Aggregate throughput + TTFT/latency percentiles: n concurrent
     requests through the continuous batcher (the serving path the
     reference fully serialized, reference worker/Dockerfile:47).
@@ -3330,8 +3329,7 @@ def bench_batched(model=MODEL, quant=None, n_requests=8,
     met = Metrics()   # percentiles come from the batcher's own histograms
     b = ContinuousBatcher(cfg, num_blocks=blocks, block_size=16,
                           slots=slots, max_seq=max_seq, seed=0,
-                          speculative=speculative, spec_wave=spec_wave,
-                          metrics=met)
+                          speculative=speculative, metrics=met)
     rng = np.random.default_rng(0)
     # the speculative comparison measures greedy on BOTH arms (greedy is
     # the accelerated mode, and the baseline must match it); repetitive
@@ -3423,30 +3421,21 @@ def bench_batched(model=MODEL, quant=None, n_requests=8,
                 "tokens_per_weight_pass": (
                     round(delta("batcher_tokens_emitted") / passes, 2)
                     if passes else None),
-                "overlapped_dispatches": int(
-                    delta("batcher_overlapped_dispatches")) or None,
             }
             if speculative:
-                sa = b.stats().get("spec_adaptive")
-                if sa:   # adaptive verdict rides the artifact
-                    stats["spec_mode"] = sa["mode"]
-                    stats["spec_gamma"] = sa["gamma"]
-                    stats["spec_fallbacks"] = sa["fallbacks"]
-                else:
-                    # wave mode: controllers live on the requests
-                    # (BatchRequest._spec_ctl) — aggregate the best
-                    # rep's verdicts
-                    ctls = [r._spec_ctl for r in reqs
-                            if r._spec_ctl is not None]
-                    if ctls:
-                        stats["spec_mode"] = (
-                            "spec" if any(c.mode == "spec" for c in ctls)
-                            else "plain")
-                        stats["spec_fallbacks"] = sum(
-                            c.fallbacks for c in ctls)
-                    sw = b.stats().get("spec_wave")
-                    if sw:
-                        stats["spec_wave_dispatches"] = sw["dispatches"]
+                # controllers live on the requests
+                # (BatchRequest._spec_ctl) — aggregate the best rep's
+                # verdicts
+                ctls = [r._spec_ctl for r in reqs
+                        if r._spec_ctl is not None]
+                if ctls:
+                    stats["spec_mode"] = (
+                        "spec" if any(c.mode == "spec" for c in ctls)
+                        else "plain")
+                    stats["spec_fallbacks"] = sum(
+                        c.fallbacks for c in ctls)
+                stats["spec_wave_dispatches"] = \
+                    b.stats()["spec_wave"]["dispatches"]
                 stats["spec_accepted_tokens"] = int(
                     delta("spec_wave_accepted_tokens")) or None
             stats["active_slots"] = slots
@@ -3619,9 +3608,8 @@ def run_all(platform):
     # ---- priority 1: the contract headline -------------------------------
     # On TPU: the framework's native bf16 serving config. On an asked-for
     # CPU platform: the framework's recommended CPU serving config —
-    # int8 weight-only + int8 embed table streamed by the native FFI
-    # GEMV (ops/cpu_gemv.py), f32 activations/accumulate. The reference
-    # stack has no quantized CPU path at all (reference
+    # int8 weight-only + int8 embed table, f32 activations/accumulate.
+    # The reference stack has no quantized CPU path at all (reference
     # worker/app.py:297-305 is stock HF f32 generate); the like-for-like
     # f32 comparison is reported alongside as gpt2_f32_tokens_per_s /
     # vs_baseline_f32 so the cross-precision multiplier can't be
@@ -3631,24 +3619,17 @@ def run_all(platform):
     else:
         ours, pbytes = bench_engine(quant="int8", embed_quant="int8",
                                     dtype="float32")
-        from distributed_llm_inferencing_tpu.ops import cpu_gemv
-        native = cpu_gemv.available()
-        result["cpu_native_gemv"] = native
         result["ours_config"] = (
-            "int8 weight-only + int8 embed "
-            + ("via native CPU GEMV" if native
-               else "on the XLA dequant path (native kernel unavailable)")
-            + " (f32 activations; baseline is the reference's f32 stack — "
-              "see vs_baseline_f32 for same-precision)")
+            "int8 weight-only + int8 embed (f32 activations; baseline is "
+            "the reference's f32 stack — see vs_baseline_f32 for "
+            "same-precision)")
         result["gpt2_int8_tokens_per_s"] = round(ours, 2)
     result["value"] = round(ours, 2)
     util("gpt2_hbm_bw_util", ours, pbytes)
     print(f"ours: {ours:.2f} tok/s [{platform}]", file=sys.stderr)
     _persist(result)
 
-    # ---- priority 1b (cpu): precision ladder -----------------------------
-    # f32 (the like-for-like arm of vs_baseline_f32) and bf16-stored
-    # weights (near-f32 accuracy, half the streamed bytes).
+    # ---- priority 1b (cpu): f32, the like-for-like arm of vs_baseline_f32
     if not on_tpu:
         try:
             f32, _ = bench_engine(dtype="float32")
@@ -3657,17 +3638,6 @@ def run_all(platform):
                   file=sys.stderr)
         except Exception as e:
             print(f"cpu f32 bench skipped: {e!r}", file=sys.stderr)
-        _persist(result)
-        try:
-            os.environ["DLI_CPU_WEIGHT_STORAGE"] = "bf16"
-            try:
-                bw16, _ = bench_engine(dtype="float32")
-            finally:
-                os.environ.pop("DLI_CPU_WEIGHT_STORAGE", None)
-            result["gpt2_bf16w_tokens_per_s"] = round(bw16, 2)
-            print(f"gpt2 bf16-weights: {bw16:.2f} tok/s", file=sys.stderr)
-        except Exception as e:
-            print(f"cpu bf16w bench skipped: {e!r}", file=sys.stderr)
         _persist(result)
 
     # ---- priority 2: batched x8 (the >=3x-engine bar) --------------------

@@ -76,31 +76,6 @@ def _wfull(p, dt):
 
 
 def _linear(x, p, row_sharded: bool = False):
-    if "qT" in p or "wT" in p:
-        # CPU-native transposed layouts (ops/cpu_gemv.py): the engine
-        # repacks leaves to [dout, din] on the unrolled CPU path so
-        # decode streams the stored bytes (f32 / bf16 / int8) through
-        # the FFI GEMV — XLA-CPU's dot leaves ~20% of measured GEMV
-        # bandwidth unused and its int8 lowering materializes the f32
-        # dequant first
-        from distributed_llm_inferencing_tpu.ops import cpu_gemv
-        lead = x.shape[:-1]
-        x2 = x.reshape(-1, x.shape[-1])
-        if "qT" in p:
-            if x2.shape[0] <= cpu_gemv.MAX_FAST_M:
-                y = cpu_gemv.qgemv_i8(x2, p["qT"], p["scale"])
-            else:   # prefill-shaped: compute-bound, XLA's GEMM wins
-                y = (x2.astype(jnp.float32)
-                     @ p["qT"].astype(jnp.float32).T) * p["scale"]
-        else:
-            if x2.shape[0] <= cpu_gemv.MAX_FAST_M:
-                y = cpu_gemv.gemv_w(x2, p["wT"])
-            else:
-                y = x2.astype(jnp.float32) @ p["wT"].astype(jnp.float32).T
-        y = y.reshape(*lead, y.shape[-1])
-        if "b" in p:
-            y = y + p["b"]
-        return y.astype(x.dtype)
     if "p4" in p:   # int4 weight-only: pallas fused-unpack kernel on the
         # decode path, XLA unpack elsewhere (ops/pallas/quant_matmul.py)
         from distributed_llm_inferencing_tpu.ops.pallas.quant_matmul import (
@@ -404,22 +379,8 @@ def unembed(params, cfg: ModelConfig, x):
         x = _linear(x, params["embed"]["project_out"])
     if cfg.tie_word_embeddings:
         table = params["embed"]["tokens"]
-        # The tied head is the single largest per-token read; on a
-        # single-visible-device CPU process with decode-shaped rows the
-        # FFI kernel streams the stored bytes directly (the [V, D] table
-        # IS its transposed layout) — int8 rows with the per-row scale
-        # (a per-output-channel scale here, it commutes out of the dot),
-        # or raw f32/bf16 rows.
-        from distributed_llm_inferencing_tpu.ops import cpu_gemv
-        b, s, d = x.shape
-        if cpu_gemv.usable_for_rows(b * s):
-            x2 = x.reshape(b * s, d)
-            logits = (cpu_gemv.qgemv_i8(x2, table["q8"], table["rscale"])
-                      if isinstance(table, dict)
-                      else cpu_gemv.gemv_w(x2, table))
-            return _head_post(logits.reshape(b, s, -1), cfg
-                              ).astype(jnp.float32)
-        if isinstance(table, dict):   # int8 table (cfg.embed_quant)
+        if isinstance(table, dict):   # int8 table (cfg.embed_quant): the
+            # per-row scale is per output channel here and commutes out
             logits = jnp.einsum("bsd,vd->bsv", x,
                                 table["q8"].astype(x.dtype))
             logits = logits * table["rscale"].astype(x.dtype)
@@ -427,18 +388,12 @@ def unembed(params, cfg: ModelConfig, x):
             logits = jnp.einsum("bsd,vd->bsv", x, table.astype(x.dtype))
     else:
         logits = _linear(x, params["lm_head"])
-    return _head_post(logits, cfg).astype(jnp.float32)
-
-
-def _head_post(logits, cfg: ModelConfig):
-    """Head post-processing: Cohere's constant logit scale and Gemma-2's
-    final softcap, applied wherever logits leave the model (incl. the
-    CPU FFI fast path, which returns early)."""
+    # Cohere's constant logit scale and Gemma-2's final softcap
     if cfg.logit_scale is not None:
         logits = logits * cfg.logit_scale
     if cfg.logit_softcap is not None:
         logits = jnp.tanh(logits / cfg.logit_softcap) * cfg.logit_softcap
-    return logits
+    return logits.astype(jnp.float32)
 
 
 def _qk_normalize(t, p, cfg: ModelConfig):
@@ -486,7 +441,7 @@ def scan_layer_stack(make_body, x, params, cfg: ModelConfig, xs):
     ``(carry, (lp, *per_layer_xs)) -> (carry, per_layer_out)``;
     ``xs`` is a tuple of [L, ...]-stacked per-layer arrays (cache or
     pool planes). Each segment scans its own stacked tree (or, for the
-    engine's CPU-unrolled per-layer buffer lists, loops Python-side);
+    batcher's per-layer lists of MoE layers, loops Python-side);
     per-layer outputs are re-stacked and concatenated back to [L, ...]
     order. Returns (carry, tuple_of_[L,...]_outputs)."""
     seg_outs = []
@@ -494,8 +449,9 @@ def scan_layer_stack(make_body, x, params, cfg: ModelConfig, xs):
         seg_xs = tuple(p[start:start + n] for p in xs)
         body = make_body(seg_cfg)
         if isinstance(layers_seg, (list, tuple)):
-            # unrolled per-layer weight buffers (engine._maybe_unroll_
-            # layers): real per-buffer weights get XLA-CPU's dot kernel
+            # per-layer weight buffers (batcher._unstack_layers): the
+            # grouped expert matmul takes whole buffers, and under a scan
+            # each pass would first copy them out of the stack
             outs = []
             for i, lp in enumerate(layers_seg):
                 x, out = body(x, (lp,) + tuple(p[i] for p in seg_xs))

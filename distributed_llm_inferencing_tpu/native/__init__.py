@@ -19,7 +19,6 @@ from __future__ import annotations
 import ctypes
 import logging
 import os
-import re
 import subprocess
 import tempfile
 import threading
@@ -40,23 +39,6 @@ build_seconds: Optional[float] = None
 
 class NativeBuildError(RuntimeError):
     """The C++ block pool could not be compiled or loaded."""
-
-
-def configured_threads() -> int:
-    """Thread count the native GEMV/GEMM row pool (src/qgemv.cc RowPool)
-    starts with: ``DLI_NATIVE_THREADS`` when set to a positive integer,
-    else every core the host reports. The Python-side mirror of the C++
-    default, so callers (ops/cpu_gemv.py, scripts/check.sh, docs) report
-    one number without re-deriving the parse."""
-    env = os.environ.get("DLI_NATIVE_THREADS", "")
-    # leading-integer parse, NOT int(): the C++ side uses atoi, which
-    # reads "4.5"/"4x" as 4 — the two sides must report one number
-    m = re.match(r"\s*[+-]?\d+", env)
-    if m:
-        v = int(m.group())
-        if v >= 1:
-            return v
-    return os.cpu_count() or 1
 
 
 def _build() -> str:

@@ -27,7 +27,7 @@ tok/s of the speculative vs plain arms, shrinks gamma when drafts miss,
 falls back to plain decode when drafting measurably loses, and re-probes
 periodically so a workload turning repetitive flips it back on. The
 continuous batcher consults it every chunk (runtime/batcher.py
-_step_speculative), which is what makes ``speculative="ngram"`` safe to
+_step_spec_wave), which is what makes ``speculative="ngram"`` safe to
 leave on.
 """
 
@@ -75,7 +75,7 @@ class AdaptiveSpecController:
       either direction.
 
     The batcher owns the measurements (runtime/batcher.py
-    _step_speculative); this object owns the policy, so the engine or a
+    _step_spec_wave); this object owns the policy, so the engine or a
     future tree-drafting tier can reuse it unchanged.
 
     Determinism note: greedy output is mode-invariant, so adaptivity
@@ -362,7 +362,7 @@ def accept_rejection_batch(logits, drafts, seeds, steps, temps, top_ks,
 
     ``widths`` ([R] int32 in [0, G], default G) is the per-row draft
     width for wave-level speculation (runtime/batcher.py
-    _step_speculative): row r considers only its first ``widths[r]``
+    _step_spec_wave): row r considers only its first ``widths[r]``
     drafts; a width-0 row accepts nothing and its stop token is an
     ordinary single-token draw from position 0's distribution — plain
     decode riding the verify pass, with greedy rows emitting exactly

@@ -172,11 +172,10 @@ class BatchRequest:
     _spec_acc: int = 0          # draft tokens accepted beyond 1/iteration
     _spec_rej: int = 0          # draft tokens rejected by verification
     _spec_drafted: int = 0      # draft tokens proposed for this request
-    # wave-level speculation (DLI_SPEC_WAVE): this request's OWN
-    # drafting controller (ops/speculative.py AdaptiveSpecController) —
-    # created lazily at its first speculative chunk, surviving
-    # preemption/re-admission so a request's acceptance history follows
-    # it across slots
+    # wave-level speculation: this request's OWN drafting controller
+    # (ops/speculative.py AdaptiveSpecController) — created lazily at its
+    # first speculative chunk, surviving preemption/re-admission so a
+    # request's acceptance history follows it across slots
     _spec_ctl: Optional[object] = None
 
     def wait(self, timeout: Optional[float] = None) -> List[int]:
@@ -264,8 +263,6 @@ class ContinuousBatcher:
                  decode_chunk_cap: Optional[int] = None,
                  speculative: Optional[str] = None, spec_gamma: int = 4,
                  spec_adaptive: Optional[bool] = None,
-                 spec_wave: Optional[bool] = None,
-                 decode_overlap: Optional[bool] = None,
                  kv_host_mb: Optional[float] = None,
                  kv_digest_chunk: Optional[int] = None,
                  kv_fetcher=None,
@@ -356,20 +353,6 @@ class ContinuousBatcher:
         # bench) caps the chunk so inter-token gaps track real steps.
         self._decode_chunk_cap = (int(decode_chunk_cap)
                                   if decode_chunk_cap else None)
-        # Double-buffered decode dispatch: when the next chunk pair is
-        # provably stop-check-free (no eos, no streaming callback, every
-        # active budget covers BOTH chunks, nothing queued), dispatch
-        # chunk N+1 fed by chunk N's device-resident last tokens and sync
-        # the pair once — chunk N's token transfer overlaps chunk N+1's
-        # compute, halving host round trips on the steady-state decode
-        # path. Single-host only (the lockstep broadcast ships JSON args;
-        # a device-array token feed cannot ride it). DLI_DECODE_OVERLAP=0
-        # opts out for A/B.
-        if decode_overlap is None:
-            decode_overlap = os.environ.get(
-                "DLI_DECODE_OVERLAP", "1") not in ("0", "false")
-        self.decode_overlap = bool(decode_overlap)
-        self._overlapped_dispatches = 0
         # Speculative decoding (models/transformer.py
         # paged_speculative_chunk): on-device prompt-lookup drafts, up to
         # spec_gamma+1 tokens per slot per iteration. Greedy requests get
@@ -391,21 +374,15 @@ class ContinuousBatcher:
             spec_adaptive = os.environ.get(
                 "DLI_SPEC_ADAPTIVE", "1") not in ("0", "false")
         self._spec_adaptive = bool(spec_adaptive)
-        # Wave-level speculation (DLI_SPEC_WAVE, default on): ONE shared
-        # verify pass serves the whole active wave with PER-SLOT draft
-        # widths as data — each request carries its own
-        # AdaptiveSpecController (BatchRequest._spec_ctl), so a
-        # draft-hostile request converges to width 0 and rides the wave's
-        # verify pass as plain decode while its draft-friendly chunk-mates
-        # keep their speedup (no wave-wide fallback cliff). Off: the
-        # pre-wave global controller arbitrates one gamma for the wave.
-        if spec_wave is None:
-            spec_wave = os.environ.get(
-                "DLI_SPEC_WAVE", "1") not in ("0", "false")
-        self.spec_wave = bool(spec_wave) and bool(speculative)
+        # Wave-level speculation: ONE shared verify pass serves the whole
+        # active wave with PER-SLOT draft widths as data — each request
+        # carries its own AdaptiveSpecController (BatchRequest._spec_ctl),
+        # so a draft-hostile request converges to width 0 and rides the
+        # wave's verify pass as plain decode while its draft-friendly
+        # chunk-mates keep their speedup (no wave-wide fallback cliff).
         self._spec_wave_dispatches = 0
-        # Cross-request arbitration state for wave mode: measured spec /
-        # plain tok/s and the probe clocks are HOST+WORKLOAD properties,
+        # Cross-request arbitration state: measured spec / plain tok/s
+        # and the probe clocks are HOST+WORKLOAD properties,
         # not per-request ones — a fresh request's controller seeds from
         # them (and starts in plain mode when the fleet measurements say
         # drafting loses), so short generations inherit the fleet's
@@ -435,22 +412,13 @@ class ContinuousBatcher:
         self.metrics.inc("batcher_stall_host_ms", 0)
         self._pass_mean = {}      # (kind, k) -> [mean wall per pass, n]
         self._step_program_s = 0.0   # this step's wall inside programs
-        if self.spec_wave:
+        if speculative:
             for name in ("spec_wave_dispatches", "spec_wave_drafted_tokens",
                          "spec_wave_accepted_tokens",
                          "spec_wave_plain_rides"):
                 self.metrics.inc(name, 0)
             self.metrics.gauge("spec_wave_drafting_slots", 0.0)
             self.metrics.gauge("spec_wave_gamma_mean", 0.0)
-        self._spec_ctl = None
-        # spec_gamma < 1 is an explicit zero-draft request: no controller
-        # (it would clamp gamma up to 1 and start drafting), the step's
-        # gamma==0 branch runs plain chunks
-        if (speculative and spec_adaptive and self.spec_gamma >= 1
-                and not self.spec_wave):
-            from distributed_llm_inferencing_tpu.ops.speculative import (
-                AdaptiveSpecController)
-            self._spec_ctl = AdaptiveSpecController(self.spec_gamma)
         # device-drafting token history, maintained incrementally (a
         # per-step rebuild would be O(slots * max_seq) host work on the
         # hot path): row i holds slot i's prompt + emitted tokens
@@ -495,7 +463,6 @@ class ContinuousBatcher:
         if cfg.is_moe:
             for name in transformer.MOE_STATS:
                 self.metrics.inc(f"batcher_moe_{name}", 0)
-        self._moe_pending = []    # unsynced chunks' MOE_STATS vectors
         self.block_tables = np.full((slots, self.max_blocks), self._dummy,
                                     np.int32)
         # Host-RAM KV offload tier (runtime/kvtier.py): radix-evicted
@@ -687,7 +654,7 @@ class ContinuousBatcher:
                     f"tokens >= max_new_tokens {req.max_new_tokens} — "
                     "the source should have completed, not migrated")
             spec_state = resume.get("spec")
-            if (spec_state and self.speculative and self.spec_wave
+            if (spec_state and self.speculative
                     and self._spec_adaptive and self.spec_gamma >= 1):
                 from distributed_llm_inferencing_tpu.ops.speculative \
                     import AdaptiveSpecController
@@ -819,12 +786,8 @@ class ContinuousBatcher:
                                    if not isinstance(key[0], str)}),
             "chunked_admissions": self._chunked_admissions,
             "prefill_chunk": self.prefill_chunk,
-            "decode_overlap": self.decode_overlap,
-            "overlapped_dispatches": self._overlapped_dispatches,
             "speculative": self.speculative,
             "spec_accepted_tokens": self._spec_accepted,
-            "spec_adaptive": (self._spec_ctl.stats()
-                              if self._spec_ctl is not None else None),
             "spec_wave": self._spec_wave_stats(),
             "pool": self.pool.stats(),
             # host KV tier + routing advertisement (runtime/kvtier.py):
@@ -846,7 +809,7 @@ class ContinuousBatcher:
         controllers live on the requests (BatchRequest._spec_ctl), so
         the batcher-level summary counts ACTIVE requests' modes/widths —
         the live width mix a scraper sees, not lifetime history."""
-        if not self.spec_wave:
+        if not self.speculative:
             return None
         ctls = [a._spec_ctl for a in self.active
                 if a is not None and a._spec_ctl is not None]
@@ -1073,11 +1036,8 @@ class ContinuousBatcher:
 
     def _decode_jit(self, k: int, r: int, mb: int, use_lora: bool = False):
         """K-token decode chunk (transformer.paged_decode_chunk), one host
-        sync per K tokens for all slots. ``tokens`` rides as its own
-        argument — not packed into ``ints`` — so a double-buffered step
-        can feed chunk N+1 the device-resident last tokens of chunk N
-        without a host round trip (_step_overlapped). ``use_lora``
-        variants append per-slot adapter ids to the ints pack."""
+        sync per K tokens for all slots. ``use_lora`` variants append
+        per-slot adapter ids to the ints pack."""
         fn = self._decode_fns.get((k, r, mb, use_lora))
         if fn is None:
             cfg, dummy = self.cfg, self._dummy
@@ -1159,9 +1119,8 @@ class ContinuousBatcher:
         """AOT-compile (jit.lower().compile()) every decode-chunk program
         this scheduler can dispatch — the plain chunk per DECODE_CHUNKS
         size and, with speculation, each distinct ceil(k/(gamma+1))
-        verify variant (plus the halved-gamma statics the wave-off global
-        controller can request) — and install the compiled executables
-        in the program cache.
+        verify variant — and install the compiled executables in the
+        program cache.
 
         A speculative trajectory's chunk-size sequence is
         acceptance-dependent, so workload warmup cannot cover the
@@ -1191,24 +1150,17 @@ class ContinuousBatcher:
                     n += 1
                 if not (self.speculative and self.spec_gamma >= 1):
                     continue
-                gs = {self.spec_gamma}
-                if not self.spec_wave:
-                    g = self.spec_gamma   # global-controller halvings
-                    while g > 2:
-                        g = max(2, g // 2)
-                        gs.add(g)
-                hh = self._hist.shape[1]
-                for g in gs:
-                    k_it = -(-k // (g + 1))
-                    sfn = self._spec_jit(k_it, g, r, mb, hh)
-                    if hasattr(sfn, "lower"):
-                        ints = jax.ShapeDtypeStruct(
-                            (r * (mb + hh + 9),), jnp.int32)
-                        self._decode_fns[("spec", k_it, g, r, mb, hh,
-                                          False)] = \
-                            sfn.lower(self.params, ints, floats,
-                                      paged_sds).compile()
-                        n += 1
+                g, hh = self.spec_gamma, self._hist.shape[1]
+                k_it = -(-k // (g + 1))
+                sfn = self._spec_jit(k_it, g, r, mb, hh)
+                if hasattr(sfn, "lower"):
+                    ints = jax.ShapeDtypeStruct(
+                        (r * (mb + hh + 9),), jnp.int32)
+                    self._decode_fns[("spec", k_it, g, r, mb, hh,
+                                      False)] = \
+                        sfn.lower(self.params, ints, floats,
+                                  paged_sds).compile()
+                    n += 1
         return n
 
     # ---- program launch (shared by the scheduler and lockstep replay) --
@@ -1241,14 +1193,10 @@ class ContinuousBatcher:
                                    jnp.asarray(floats), self.paged)
             return np.asarray(first)   # ONE host sync per admission wave
 
-    def _run_decode(self, a: dict, tokens_dev=None, sync: bool = True):
-        """Launch one decode chunk's program from a JSON-safe arg dict.
-        Returns (toks [K, R], emits [K, R]) — host arrays when ``sync``
-        (the default: ONE host sync per chunk), device arrays otherwise
-        (the double-buffered step syncs two chunks at once).
-        ``tokens_dev`` overrides ``a["tokens"]`` with a device-resident
-        [R] token vector — chunk N's last sampled tokens feed chunk N+1
-        without ever visiting the host."""
+    def _run_decode(self, a: dict):
+        """Launch one decode chunk's program from a JSON-safe arg dict:
+        pack, dispatch, ONE host sync. Returns host arrays
+        (toks [K, R], emits [K, R])."""
         bt = np.asarray(a["bt"], np.int32)
         r, mb = bt.shape
         use_lora = "aids" in a
@@ -1265,24 +1213,16 @@ class ContinuousBatcher:
                  if self.profiler.enabled else {})
         with self.mesh:
             with self.profiler.phase("dispatch", **stats):
-                tokens = (tokens_dev if tokens_dev is not None
-                          else jnp.asarray(np.asarray(a["tokens"],
-                                                      np.int32)))
                 toks, emits, moe, self.paged = fn(
-                    self._wave_params(use_lora), tokens, jnp.asarray(ints),
-                    jnp.asarray(floats), self.paged)
-            if self.cfg.is_moe:
-                self._moe_pending.append(moe)
-            if not sync:
-                return toks, emits
+                    self._wave_params(use_lora),
+                    jnp.asarray(np.asarray(a["tokens"], np.int32)),
+                    jnp.asarray(ints), jnp.asarray(floats), self.paged)
             with self.profiler.phase("device_wait"):
                 # the expert counters come back with the tokens: one sync
                 toks, emits, moe = jax.device_get(
-                    (toks, emits, self._moe_pending))
-            self._moe_pending = []
-            for vec in moe:
-                for name, n in zip(transformer.MOE_STATS, vec):
-                    self.metrics.inc(f"batcher_moe_{name}", int(n))
+                    (toks, emits, moe if self.cfg.is_moe else ()))
+            for name, n in zip(transformer.MOE_STATS, moe):
+                self.metrics.inc(f"batcher_moe_{name}", int(n))
             return toks, emits
 
     def _hist_deltas(self) -> list:
@@ -1348,8 +1288,7 @@ class ContinuousBatcher:
                 self._hist[r, off:off + len(row)] = row
             hist = self._hist
         r, mb = bt.shape
-        gammas = np.asarray(
-            a.get("gammas") or [int(a["gamma"])] * r, np.int32)
+        gammas = np.asarray(a["gammas"], np.int32)
         use_lora = "aids" in a
         ints = np.concatenate([bt.reshape(-1), hist.reshape(-1)] + [
             np.asarray(a[key], np.int32) for key in
@@ -2730,9 +2669,7 @@ class ContinuousBatcher:
                 # _admit_group); a base-only wave pays zero delta cost
                 decode_args["aids"] = aids.tolist()
         if self.speculative:
-            return self._step_speculative(active, decode_args)
-        if self._overlap_eligible(active, k):
-            return self._step_overlapped(active, decode_args, k)
+            return self._step_spec_wave(active, decode_args)
         self._dispatch_plain_chunk(active, decode_args)
         return len([a for a in self.active if a is not None])
 
@@ -2742,7 +2679,6 @@ class ContinuousBatcher:
         adaptive-speculation fallback/probe path. Returns tokens
         emitted."""
         k = int(decode_args["k"])
-        budget = decode_args["budget"]
         w0 = clock.now()
         if self.program_hook is not None:
             if self._hist is not None:
@@ -2769,17 +2705,14 @@ class ContinuousBatcher:
         # program outputs, so lockstep followers mirror it in replay()
         self._apply_plain_hist(toks, emits,
                                np.asarray(decode_args["cl"], np.int32))
-        return self._emit_chunk_outputs(active, toks, emits, k, decode_args,
-                                        budget=budget)
+        return self._emit_chunk_outputs(active, toks, emits, k, decode_args)
 
     def _emit_chunk_outputs(self, active, toks, emits, passes: int,
-                            decode_args: dict, budget=None) -> int:
-        """Shared emit/finish/amortization epilogue for [K, R]-shaped
-        chunk outputs (plain and overlapped paths; the speculative path's
-        outputs are [K, R, G+1] keeps-shaped and handled in place).
-        ``budget`` enables the stopped-before-budget eos inference —
-        overlapped pairs are provably eos-free and pass None. Returns
-        tokens emitted."""
+                            decode_args: dict) -> int:
+        """Emit/finish/amortization epilogue for a plain chunk's [K, R]
+        outputs (the speculative path's are [K, R, G+1] keeps-shaped:
+        _emit_spec_outputs). Returns tokens emitted."""
+        budget = decode_args["budget"]
         emitted = 0
         with self.profiler.phase("emit"):
             for i in active:
@@ -2794,8 +2727,7 @@ class ContinuousBatcher:
                 emitted += cnt
                 req._weight_passes += passes
                 self.context_lens[i] += cnt
-                hit_eos = (budget is not None
-                           and cnt < int(budget[i]))  # stopped pre-budget
+                hit_eos = cnt < int(budget[i])   # stopped pre-budget
                 if hit_eos or len(req.tokens) >= req.max_new_tokens:
                     self._finish_slot(i)
         self._count_passes(decode_args, passes, emitted)
@@ -2819,169 +2751,14 @@ class ContinuousBatcher:
                for tk, d in zip(decode_args["tks"], decode_args["ds"])):
             self.metrics.inc("batcher_sample_full_passes", passes)
 
-    def _overlap_eligible(self, active, k: int) -> bool:
-        """True when a chunk pair can dispatch back-to-back with no host
-        decision in between: single-host, nothing queued (admission waits
-        a chunk otherwise), and every active slot provably emits exactly
-        ``k`` tokens per chunk twice over — no eos stop-check, no
-        streaming callback wanting tokens at chunk granularity, budget
-        covering both chunks — with growth blocks for 2k pre-allocated."""
-        if not self.decode_overlap or self.program_hook is not None:
-            return False
-        with self._lock:
-            if self.queue:
-                return False
-        for i in active:
-            req = self.active[i]
-            if (req.eos_token_id is not None or req.stream_cb is not None
-                    or req.max_new_tokens - len(req.tokens) < 2 * k):
-                return False
-        # growth extension may fail at the pool/max_blocks edge: the step
-        # then simply runs single-chunk (already-granted blocks stay with
-        # their slots — they back the very next chunk)
-        return all(self._ensure_growth(i, 2 * k) for i in active)
-
-    def _step_overlapped(self, active, args_a: dict, k: int) -> int:
-        """Double-buffered decode: dispatch chunk B fed by chunk A's
-        device-resident last-iteration tokens, then sync the PAIR once —
-        A's device->host token transfer rides under B's compute, and the
-        per-chunk dispatch round trip is paid once per 2k tokens.
-        Eligibility (_overlap_eligible) guarantees A emits exactly k per
-        active slot, so B's context/step offsets advance deterministically
-        host-side without seeing A's tokens."""
-        # _overlap_eligible's 2k growth ran AFTER the step snapshotted the
-        # block tables — refresh, or chunk B scatters into blocks its
-        # table doesn't know (A ignores entries past its write range:
-        # gathers are position-masked below cl0)
-        args_a = dict(args_a, bt=self.block_tables.tolist())
-        cl_b = list(args_a["cl"])
-        st_b = list(args_a["steps"])
-        for i in active:
-            cl_b[i] += k
-            st_b[i] += k
-        args_b = dict(args_a, cl=cl_b, steps=st_b)
-        w0 = clock.now()
-        toks_a, emits_a = self._run_decode(args_a, sync=False)
-        toks_b, emits_b = self._run_decode(args_b, tokens_dev=toks_a[-1],
-                                           sync=False)
-        self._step_count += 2
-        self._overlapped_dispatches += 1
-        self.metrics.inc("batcher_overlapped_dispatches")
-        with self.profiler.phase("device_wait"):
-            toks_a, emits_a, toks_b, emits_b = jax.device_get(
-                (toks_a, emits_a, toks_b, emits_b))  # ONE sync for the pair
-        w1 = clock.now()
-        self.metrics.observe("batcher_decode_chunk", (w1 - w0) / 2)
-        self.metrics.observe("batcher_decode_chunk", (w1 - w0) / 2)
-        self._note_program(w1 - w0, "overlapped", 2 * k, len(active))
-        trace.get_tracer().record(
-            "batcher.decode_chunk", w0, w1,
-            attrs={"k": 2 * k, "slots": len(active), "overlapped": True})
-
-        toks = np.concatenate([toks_a, toks_b], axis=0)
-        emits = np.concatenate([emits_a, emits_b], axis=0)
-        self._emit_chunk_outputs(active, toks, emits, 2 * k, args_a)
-        return len([a for a in self.active if a is not None])
-
-    def _step_speculative(self, active, decode_args: dict) -> int:
-        """Dispatch a speculative chunk instead of a plain decode chunk:
-        ceil(k / (gamma+1)) verify iterations cover the same token budget
-        when drafts miss, and up to (gamma+1)x fewer dispatches when they
-        hit. Block growth was already ensured for k tokens — accepted
-        cache writes never exceed the budget, and rejected scratch
-        entries scatter to the dummy block.
-
-        Wave mode (``spec_wave``, default): per-slot draft widths from
-        per-request controllers, one shared verify pass
-        (_step_spec_wave). Off: this pre-wave path — ONE global
-        controller arbitrates one gamma for the whole wave, and gamma 0
-        runs the entire chunk plain (the wave-wide cliff wave mode
-        exists to remove). Every chunk's (acceptance, emitted, elapsed)
-        feeds back, with fresh-compile dispatches excluded from the
-        throughput EMAs."""
-        if self.spec_wave:
-            return self._step_spec_wave(active, decode_args)
-        ctl = self._spec_ctl
-        gamma = ctl.choose() if ctl is not None else self.spec_gamma
-        m = self.metrics
-        if ctl is not None:
-            m.gauge("spec_mode", 1.0 if gamma else 0.0)
-            m.gauge("spec_gamma_current", float(gamma or ctl.gamma))
-            acc = ctl.acceptance()
-            if acc is not None:
-                m.gauge("spec_acceptance_rate", acc)
-        if gamma == 0:
-            # controller fallback — or spec_gamma=0 with adaptivity off,
-            # where a degenerate zero-draft chunk has nothing to verify:
-            # both run the plain program (ctl may be None in the latter)
-            k = int(decode_args["k"])
-            compiled = (k, self.slots, self.max_blocks,
-                        "aids" in decode_args) not in self._decode_fns
-            w0 = clock.now()
-            emitted = self._dispatch_plain_chunk(active, decode_args)
-            if ctl is not None:
-                ctl.record("plain", emitted=emitted,
-                           elapsed_s=clock.now() - w0, compiled=compiled)
-            return len([a for a in self.active if a is not None])
-
-        g1 = gamma + 1
-        k_it = -(-int(decode_args["k"]) // g1)
-        args = dict(decode_args, k=k_it, gamma=gamma)
-        spec_key = ("spec", k_it, gamma, self.slots, self.max_blocks,
-                    self._hist.shape[1], "aids" in decode_args)
-        compiled = spec_key not in self._decode_fns
-        w0 = clock.now()
-        if self.program_hook is not None:
-            # the lockstep mirror ships JSON: broadcast only per-slot
-            # history deltas (non-empty just after admissions); followers
-            # derive every other append from the replayed program's
-            # outputs, so the broadcast is O(new tokens), never
-            # O(slots * max_seq) per chunk
-            args["hist_delta"] = self._hist_deltas()
-            local = dict(args, hist=self._hist)
-            toks, keeps, eos_seen = self.program_hook(
-                "spec_decode", args, lambda: self._run_spec_decode(local))
-        else:
-            args["hist"] = self._hist
-            toks, keeps, eos_seen = self._run_spec_decode(args)
-        self._step_count += 1
-        w1 = clock.now()
-        self.metrics.observe("batcher_decode_chunk", w1 - w0)
-        self._note_program(w1 - w0, f"spec{gamma}", k_it, len(active))
-        trace.get_tracer().record(
-            "batcher.spec_chunk", w0, w1,
-            attrs={"k": k_it, "gamma": gamma, "slots": len(active)})
-        self._apply_spec_hist(toks, keeps,
-                              np.asarray(decode_args["cl"], np.int32))
-
-        per = self._emit_spec_outputs(
-            active, toks, keeps, eos_seen, k_it,
-            np.full((self.slots,), gamma, np.int32))
-        emitted = sum(cnt for (_, cnt, _, _) in per.values())
-        live_iters = sum(live for (_, _, live, _) in per.values())
-        accepted = emitted - live_iters
-        self._count_passes(decode_args, k_it, emitted)
-        if ctl is not None:
-            ctl.record("spec", emitted=emitted,
-                       elapsed_s=clock.now() - w0,
-                       drafted=gamma * live_iters, accepted=accepted,
-                       compiled=compiled)
-            if ctl.fallbacks:
-                m.gauge("spec_fallbacks", float(ctl.fallbacks))
-        return len([a for a in self.active if a is not None])
-
     def _emit_spec_outputs(self, active, toks, keeps, eos_seen,
                            k_it: int, gammas) -> dict:
-        """Shared emit/accounting epilogue for [K, R, G+1]-shaped
-        speculative outputs — the single definition both arbitration
-        modes use (wave-off passes a uniform width vector), so the most
-        correctness-sensitive bookkeeping in the batcher cannot drift
-        between DLI_SPEC_WAVE settings. Per slot: emit the kept tokens,
-        advance context/ledger counters, finish on the device's
-        cumulative eos flag or an exhausted budget (a slot may
-        legitimately emit fewer than its budget when every draft missed
-        — 1 token/iteration). Returns {slot: (req, cnt, live, drafted)}
-        for the callers' controller feedback."""
+        """Emit/accounting epilogue for [K, R, G+1]-shaped speculative
+        outputs. Per slot: emit the kept tokens, advance context/ledger
+        counters, finish on the device's cumulative eos flag or an
+        exhausted budget (a slot may legitimately emit fewer than its
+        budget when every draft missed — 1 token/iteration). Returns
+        {slot: (req, cnt, live, drafted)} for the controllers' feedback."""
         out = {}
         with self.profiler.phase("emit"):
             for i in active:
@@ -3040,7 +2817,12 @@ class ContinuousBatcher:
     def _step_spec_wave(self, active, decode_args: dict) -> int:
         """Wave-level batched speculation: ONE fused draft+verify program
         serves the whole active wave, with per-slot draft widths riding
-        as data (transformer.paged_speculative_chunk ``gammas``).
+        as data (transformer.paged_speculative_chunk ``gammas``):
+        ceil(k / (gamma+1)) verify iterations cover the same token budget
+        when drafts miss, and up to (gamma+1)x fewer dispatches when they
+        hit. Block growth was already ensured for k tokens — accepted
+        cache writes never exceed the budget, and rejected scratch
+        entries scatter to the dummy block.
 
         Each active request consults its OWN AdaptiveSpecController for
         this chunk's width: 0 means the slot rides the shared verify

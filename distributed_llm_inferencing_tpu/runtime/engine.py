@@ -136,7 +136,6 @@ class InferenceEngine:
             params = maybe_quantize_embed(maybe_quantize(params, cfg), cfg)
         with self.mesh:
             self.params = shd.shard_params(params, self.mesh, cfg, self.mesh_spec)
-        self._maybe_unroll_layers()
 
         self._cache_shardings = shd.named(
             self.mesh, shd.cache_specs(cfg, self.mesh_spec))
@@ -147,115 +146,6 @@ class InferenceEngine:
         # delta pack. One adapter per generate() call.
         self._adapters = {}
         self._adapter_trees = {}
-
-    # Layer-count cap for the CPU unrolled path: past this, the unrolled
-    # program's compile time outweighs the per-step win.
-    UNROLL_MAX_LAYERS = 48
-
-    def _maybe_unroll_layers(self):
-        """On a single-device CPU backend, split the stacked ``[L, ...]``
-        layer params into per-layer trees of separate buffers and let
-        transformer.forward run the stack as an unrolled Python loop.
-
-        XLA-CPU compiles an M<=2 dot whose weight operand is a (scan or
-        static) slice of a stacked array to a scalar kLoop fusion instead
-        of the dot kernel — measured ~7x slower for gpt2 f32 decode. Real
-        per-layer buffers restore the dot kernel and let batch-1 decode
-        stay batch-1 (engine.generate drops its dummy-row workaround).
-        TPU/GPU keep the stacked scan: one traced layer regardless of
-        depth, and the layer axis is what pipeline parallelism shards.
-        """
-        self._layers_unrolled = False
-        flag = os.environ.get("DLI_UNROLL_LAYERS")
-        if flag in ("0", "false"):
-            return
-        # cpu + single-device are HARD gates (a list-of-layers tree has
-        # no stacked [L,...] axis for pp to shard, and the repacked
-        # leaves lower cpu-platform FFI calls); the env flag only lifts
-        # the layer-count compile-time heuristic.
-        if not (jax.default_backend() == "cpu"
-                and self.mesh_spec.num_devices == 1):
-            return
-        if self.cfg.num_layers > self.UNROLL_MAX_LAYERS and flag is None:
-            return
-        self.params = dict(self.params)
-        k = self.cfg.dense_prefix_layers
-        for key, n in (("layers_dense", k),
-                       ("layers", self.cfg.num_layers - k)):
-            if key not in self.params:
-                continue
-            stacked = self.params[key]
-            self.params[key] = [
-                jax.tree.map(lambda a, i=i: a[i], stacked)
-                for i in range(n)]
-        self._layers_unrolled = True
-        self._maybe_repack_cpu()
-
-    def _maybe_repack_cpu(self):
-        """Repack linear leaves into the CPU-native transposed layout so
-        decode streams the stored bytes via the FFI GEMV
-        (ops/cpu_gemv.py): int8 leaves stay int8 (XLA-CPU's lowering
-        materializes the f32 dequant first), f32/bf16 leaves get the
-        kernel's ~20%-higher streaming bandwidth over XLA's dot."""
-        from distributed_llm_inferencing_tpu.ops import cpu_gemv
-        if not cpu_gemv.available():
-            return
-        bf16_storage = os.environ.get(
-            "DLI_CPU_WEIGHT_STORAGE") == "bf16"
-
-        def repack(leaf):
-            if not isinstance(leaf, dict) or not ("q" in leaf
-                                                  or "w" in leaf):
-                return leaf
-            # eager swapaxes materializes a dense row-major [dout, din]
-            # buffer — exactly the contiguous-along-K layout the kernel
-            # streams
-            if "q" in leaf:
-                out = {"qT": jnp.swapaxes(leaf["q"], -2, -1),
-                       "scale": leaf["scale"]}
-            elif leaf["w"].ndim != 2:   # moe expert stacks etc.
-                return leaf
-            elif leaf["w"].dtype == jnp.bfloat16 or bf16_storage:
-                # bf16-stored weights (f32 accumulate in the kernel):
-                # either the model already serves bf16, or the operator
-                # opted into storage truncation on an f32 engine
-                # (DLI_CPU_WEIGHT_STORAGE=bf16) — half the streamed
-                # bytes at near-f32 accuracy
-                out = {"wT": jnp.swapaxes(
-                    leaf["w"].astype(jnp.bfloat16), -2, -1)}
-            elif leaf["w"].dtype == jnp.float32:
-                # f32 via the FFI kernel measures at parity with XLA's
-                # own dot — keep XLA (no repack) for plain f32 leaves
-                return leaf
-            else:
-                out = {"wT": jnp.swapaxes(leaf["w"], -2, -1)}
-            if "b" in leaf:
-                out["b"] = leaf["b"]
-            return out
-
-        # only the big matmul leaves (ops/quant.py's set): the router is
-        # read raw by _moe_route and norms carry no "w"
-        from distributed_llm_inferencing_tpu.ops.quant import _LINEAR_LEAVES
-        # the latent path consumes kv_b_k/kv_b_v through absorbed
-        # einsums (_wfull), not _linear — keep their stored layout
-        skip = ({"kv_b_k", "kv_b_v"} if self.cfg.mla_latent_cache
-                else set())
-        for key in ("layers", "layers_dense"):
-            for lp in self.params.get(key, ()):
-                for name in _LINEAR_LEAVES:
-                    if name in lp and name not in skip:
-                        lp[name] = repack(lp[name])
-        if "lm_head" in self.params:
-            self.params["lm_head"] = repack(self.params["lm_head"])
-        # the tied-head table is the single largest per-token read for
-        # the gpt2 family; under bf16 storage it halves too (embed is a
-        # gather — dequant is a per-row astype; the unembed FFI branch
-        # streams the bf16 rows directly, models/transformer.py unembed)
-        tok = self.params.get("embed", {}).get("tokens")
-        if (bf16_storage and tok is not None and not isinstance(tok, dict)
-                and tok.dtype == jnp.float32):
-            self.params["embed"] = dict(self.params["embed"])
-            self.params["embed"]["tokens"] = tok.astype(jnp.bfloat16)
 
     # ---- compiled step builders -------------------------------------
 
@@ -417,16 +307,11 @@ class InferenceEngine:
                  for t, (a, b) in lp.items()}
                 for lp in ad.layers]
             tree = dict(self.params)
-            if self._layers_unrolled:
-                tree["layers"] = [
-                    dict(lp, lora=jax.tree.map(jnp.asarray, packs[i]))
-                    for i, lp in enumerate(tree["layers"])]
-            else:
-                stacked = {
-                    t: {k: jnp.asarray(np.stack([p[t][k] for p in packs]))
-                        for k in ("a", "b")}
-                    for t in packs[0]}
-                tree["layers"] = dict(tree["layers"], lora=stacked)
+            stacked = {
+                t: {k: jnp.asarray(np.stack([p[t][k] for p in packs]))
+                    for k in ("a", "b")}
+                for t in packs[0]}
+            tree["layers"] = dict(tree["layers"], lora=stacked)
             self._adapter_trees[adapter] = tree
         return tree
 
@@ -485,13 +370,11 @@ class InferenceEngine:
         # pad batch to a dp-divisible size with dummy rows (trimmed below)
         dp = self.mesh_spec.dp
         B = -(-n_real // dp) * dp
-        if B == 1 and jax.default_backend() == "cpu" \
-                and not self._layers_unrolled:
+        if B == 1 and jax.default_backend() == "cpu":
             # XLA-CPU strength-reduces M=1 dots whose weight operand is a
             # scan slice into naive kLoop fusions (~10-20x slower than the
             # dot kernel); a dummy second batch row keeps the real dot.
-            # The unrolled-layer path has real per-layer buffers, so it
-            # decodes at true batch 1. TPU/GPU never take this branch.
+            # TPU/GPU never take this branch.
             B = 2
         prompts = list(prompts) + [[0]] * (B - n_real)
         lens = lens + [1] * (B - n_real)

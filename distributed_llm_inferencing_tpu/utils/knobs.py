@@ -87,53 +87,21 @@ KNOBS = (
     Knob("DLI_MLA_LATENT", "1", "bool",
          "MLA latent-KV decode layout on eligible meshes; `0` pins the "
          "materialized layout.", f"{_P}/runtime/engine.py"),
-    Knob("DLI_UNROLL_LAYERS", "auto", "enum",
-         "CPU engine per-layer weight buffers + unrolled layer loop "
-         "(`1`/`0`/`auto`).", f"{_P}/runtime/engine.py"),
-    Knob("DLI_CPU_WEIGHT_STORAGE", "unset", "enum",
-         "`bf16` stores f32 CPU weights as bf16 — half the streamed "
-         "bytes per decode step.", f"{_P}/runtime/engine.py"),
     Knob("DLI_ALLOW_DOWNLOAD", "unset", "bool",
          "`1` lets workers fetch hub checkpoints for non-local model "
          "names.", f"{_P}/models/convert.py"),
     Knob("DLI_MODEL_CACHE", "~/.cache/dli_models", "path",
          "Where opted-in hub downloads land (share via mounted volume "
          "across workers).", f"{_P}/models/convert.py"),
-    Knob("DLI_NATIVE_THREADS", "all cores", "int",
-         "Row-pool thread count for the native GEMV/GEMM kernels; "
-         "bitwise-identical output at any setting.",
-         f"{_P}/native/__init__.py"),
-    Knob("DLI_NATIVE_TSAN", "0", "bool",
-         "Build the native qgemv kernel with `-fsanitize=thread -g` "
-         "into a separate `libdli_qgemv_tsan.so` (see `scripts/check.sh "
-         "--tsan`). Needs `libtsan` preloaded at run time.",
-         f"{_P}/ops/cpu_gemv.py"),
     Knob("DLI_BUNDLE_TIMEOUT", "30", "float",
          "Seconds per fetch for `scripts/collect_debug_bundle.sh` "
          "(each endpoint is best-effort).",
          "scripts/collect_debug_bundle.sh"),
-    Knob("DLI_TSAN_FAST", "0", "bool",
-         "`scripts/check.sh --tsan` stops after the ctypes RowPool "
-         "hammer, skipping the pytest rerun under the instrumented lib "
-         "(the CI budget mode).", "scripts/check.sh"),
-    Knob("DLI_TSAN_FULL", "0", "bool",
-         "`scripts/check.sh --tsan` stage 2 runs ALL of "
-         "test_gemv_threads under the instrumented lib instead of the "
-         "thread-relevant subset (each XLA compile is minutes-slow "
-         "under TSan — budget accordingly).", "scripts/check.sh"),
     # ---- decode hot path ---------------------------------------------
-    Knob("DLI_DECODE_OVERLAP", "1", "bool",
-         "Double-buffered decode-chunk dispatch when no stop condition "
-         "needs the tokens in between; `0` = sequential stepping.",
-         f"{_P}/runtime/batcher.py"),
     Knob("DLI_SPEC_ADAPTIVE", "1", "bool",
          "Adaptive speculation (acceptance/tok-s-tracked gamma shrink + "
          "plain fallback); `0` pins always-draft.",
          f"{_P}/runtime/engine.py"),
-    Knob("DLI_SPEC_WAVE", "1", "bool",
-         "Wave-level batched speculation with per-slot draft widths; "
-         "`0` = pre-wave global-controller arbitration.",
-         f"{_P}/runtime/batcher.py"),
     # ---- control plane (master) --------------------------------------
     Knob("DLI_DISPATCH_WORKERS", "8", "int",
          "Dispatcher threads pumping the claim -> group -> RPC "

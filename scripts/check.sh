@@ -6,54 +6,6 @@ set -uo pipefail
 
 cd "$(dirname "$0")/.."
 
-# ---- optional mode: bash scripts/check.sh --tsan ----------------------
-# ThreadSanitizer pass over the native RowPool (docs/static_analysis.md
-# "TSan wiring"): builds qgemv.cc with -fsanitize=thread -g into a
-# separate libdli_qgemv_tsan.so, then (1) hammers the pool's every
-# concurrency edge from ctypes — no jax import, seconds — and
-# (2) reruns the full threaded-GEMV suite under the instrumented lib.
-# Known-benign suppressions (uninstrumented python/numpy internals) live
-# in scripts/tsan.supp; finished-python-thread "leaks" are disabled via
-# report_thread_leaks=0 (the RowPool's detached workers are by design).
-if [[ "${1:-}" == "--tsan" ]]; then
-    TSAN_LIB=$(g++ -print-file-name=libtsan.so)
-    if [[ "$TSAN_LIB" != /* || ! -e "$TSAN_LIB" ]]; then
-        echo "FAIL: libtsan.so not found (install gcc's tsan runtime)" >&2
-        exit 1
-    fi
-    TSAN_OPTS="suppressions=$PWD/scripts/tsan.supp exitcode=66"
-    TSAN_OPTS="$TSAN_OPTS report_thread_leaks=0"
-    echo "== tsan build (qgemv.cc -fsanitize=thread -g) =="
-    JAX_PLATFORMS=cpu python scripts/tsan_gemv_driver.py --build-only \
-        || exit 1
-    echo "== tsan stage 1: ctypes RowPool hammer (dispatch x resize) =="
-    env LD_PRELOAD="$TSAN_LIB" TSAN_OPTIONS="$TSAN_OPTS" \
-        python scripts/tsan_gemv_driver.py || exit 1
-    if [[ "${DLI_TSAN_FAST:-}" == "1" ]]; then
-        # CI budget mode: TSan's interception makes anything that jits
-        # brutally slow; the ctypes hammer above already covers every
-        # RowPool concurrency edge, so the bounded tier-1 job stops
-        # here. Run without DLI_TSAN_FAST locally / nightly for the
-        # pytest rerun too.
-        echo "tsan: clean (stage 2 skipped under DLI_TSAN_FAST=1)"
-        exit 0
-    fi
-    echo "== tsan stage 2: threaded-GEMV suite under the instrumented lib =="
-    # Default: the thread-relevant subset (env parse, set_threads
-    # roundtrip, the threaded-dispatch-inside-jit reentrancy test). The
-    # parity sweeps add dozens of XLA compiles whose extra TSan value
-    # over the ctypes hammer is nil but which put the rerun far past a
-    # 30-min budget — DLI_TSAN_FULL=1 runs everything anyway.
-    K='configured or set_threads or inside_jit'
-    [[ "${DLI_TSAN_FULL:-}" == "1" ]] && K=''
-    timeout -k 10 1800 env LD_PRELOAD="$TSAN_LIB" DLI_NATIVE_TSAN=1 \
-        JAX_PLATFORMS=cpu TSAN_OPTIONS="$TSAN_OPTS" \
-        python -m pytest tests/test_gemv_threads.py -q ${K:+-k "$K"} \
-        -p no:cacheprovider -p no:xdist -p no:randomly || exit 1
-    echo "tsan: clean"
-    exit 0
-fi
-
 echo "== compileall =="
 python -m compileall -q distributed_llm_inferencing_tpu tests bench.py \
     benchmarks tools || exit 1
@@ -97,23 +49,9 @@ timeout -k 10 "$VT" env JAX_PLATFORMS=cpu \
     python -m tools.dliverify --mutate stale_term_check --budget "$VB" \
     || exit 1
 
-echo "== native kernels (threaded GEMV/GEMM must build; no silent fallback) =="
-# The decode hot path leans on the -pthread row-pool kernel
-# (native/src/qgemv.cc via ops/cpu_gemv.py). A build regression must fail
-# HERE, loudly — not degrade every int8 matmul to the XLA dequant path.
-JAX_PLATFORMS=cpu python - <<'PY' || exit 1
-from distributed_llm_inferencing_tpu.native import configured_threads
-from distributed_llm_inferencing_tpu.ops import cpu_gemv
-assert cpu_gemv.available(), (
-    "native qgemv failed to build/register -- the threaded decode hot "
-    "path would silently fall back to the XLA dequant matmul")
-print(f"qgemv ready: {cpu_gemv.get_threads()} threads "
-      f"(configured default {configured_threads()})")
-PY
-
-echo "== perf hot-path suites (threaded GEMV + adaptive speculation) =="
+echo "== perf hot-path suite (adaptive speculation) =="
 timeout -k 10 600 env JAX_PLATFORMS=cpu \
-    python -m pytest tests/test_gemv_threads.py tests/test_adaptive_spec.py \
+    python -m pytest tests/test_adaptive_spec.py \
     -q -p no:cacheprovider -p no:xdist -p no:randomly || exit 1
 
 echo "== wave speculation + pallas kernel parity + decode-speed smoke =="
@@ -312,7 +250,6 @@ timeout -k 10 870 env JAX_PLATFORMS=cpu python -m pytest tests/ -q \
     -p no:xdist -p no:randomly \
     --ignore=tests/test_chaos.py --ignore=tests/test_node_lifecycle.py \
     --ignore=tests/test_locks.py \
-    --ignore=tests/test_gemv_threads.py \
     --ignore=tests/test_adaptive_spec.py \
     --ignore=tests/test_spec_wave.py \
     --ignore=tests/test_pallas_parity.py \

@@ -128,14 +128,12 @@ def test_zero_gamma_request_runs_plain_without_controller():
     b = ContinuousBatcher(CFG, PARAMS, num_blocks=64, block_size=8,
                           slots=2, max_seq=96, speculative="ngram",
                           spec_gamma=0)
-    assert b._spec_ctl is None
     r = b.submit([1, 2, 3, 4], max_new_tokens=8,
                  sampling=SamplingParams.greedy())
     _drain(b, [r])
+    assert r._spec_ctl is None
     assert r.tokens == _plain_tokens([[1, 2, 3, 4]], 8)[0]
-    sa = b.stats()
-    assert sa["spec_adaptive"] is None
-    assert sa["spec_accepted_tokens"] == 0   # nothing was ever drafted
+    assert b.stats()["spec_accepted_tokens"] == 0   # nothing was drafted
 
 
 def test_compiled_chunks_excluded_from_throughput():
@@ -171,13 +169,11 @@ def _drain(b, reqs, limit=600):
 
 
 def _spec_batcher():
-    # spec_wave=False: these suites pin the pre-wave GLOBAL-controller
-    # arbitration (one gamma per wave, whole-wave plain fallback), which
-    # stays supported behind DLI_SPEC_WAVE=0; the wave-mode per-request
-    # controllers have their own suite (tests/test_spec_wave.py)
+    # whole workloads against the plain batcher; what a wave of mixed
+    # requests does per slot is tests/test_spec_wave.py's
     b = ContinuousBatcher(CFG, PARAMS, num_blocks=256, block_size=8,
                           slots=4, max_seq=160, speculative="ngram",
-                          spec_gamma=3, spec_wave=False)
+                          spec_gamma=3)
     b.DECODE_CHUNKS = (4, 2, 1)   # many small chunks -> many decisions
     return b
 
@@ -193,9 +189,11 @@ def test_repetitive_workload_keeps_drafting():
     reqs = [b.submit(p, max_new_tokens=64, sampling=SamplingParams.greedy())
             for p in prompts]
     _drain(b, reqs)
-    sa = b.stats()["spec_adaptive"]
-    assert sa["mode"] == "spec", sa
-    assert sa["fallbacks"] == 0
+    for r in reqs:   # every request's own controller rode it out
+        sa = r._spec_ctl.stats()
+        assert sa["mode"] == "spec", sa
+        assert sa["fallbacks"] == 0
+    assert b.stats()["spec_wave"]["dispatches"] > 0
     assert b.stats()["spec_accepted_tokens"] > 0   # drafts actually landed
     assert [r.tokens for r in reqs] == _plain_tokens(prompts, 64)
 
@@ -217,28 +215,17 @@ def test_adversarial_workload_converges_to_plain():
     reqs = [b.submit(p, max_new_tokens=48, sampling=sp, seed=100 + i)
             for i, p in enumerate(prompts)]
     _drain(b, reqs)
-    sa = b.stats()["spec_adaptive"]
-    assert sa["mode"] == "plain", sa
-    assert sa["fallbacks"] >= 1
-    assert sa["plain_chunks"] > 0          # the tail really ran plain
-    assert sa["spec_chunks"] <= 8, sa      # gave up fast, probes bounded
+    for r in reqs:
+        sa = r._spec_ctl.stats()
+        assert sa["mode"] == "plain", sa
+        assert sa["fallbacks"] >= 1
+        assert sa["plain_chunks"] > 0          # the tail really ran plain
+        assert sa["spec_chunks"] <= 8, sa      # gave up fast, probes bounded
+    # every width 0: the step dispatched true plain chunk programs
+    assert b.stats()["chunk_sizes"]
     assert [r.tokens for r in reqs] == _plain_tokens(prompts, 48,
                                                      sampling=sp,
                                                      seed0=100)
-
-
-def test_fixed_gamma_mode_still_available():
-    """spec_adaptive=False pins the always-draft behavior (A/B arm and
-    the pre-existing parity suites)."""
-    b = ContinuousBatcher(CFG, PARAMS, num_blocks=128, block_size=8,
-                          slots=2, max_seq=160, speculative="ngram",
-                          spec_gamma=3, spec_adaptive=False)
-    assert b.stats()["spec_adaptive"] is None
-    base = RNG.integers(0, CFG.vocab_size, 4).tolist()
-    prompt = (base * 8)[:24]
-    r = b.submit(prompt, max_new_tokens=16, sampling=SamplingParams.greedy())
-    _drain(b, [r])
-    assert r.tokens == _plain_tokens([prompt], 16)[0]
 
 
 def test_lockstep_plain_chunks_keep_follower_history_in_sync():
@@ -250,11 +237,8 @@ def test_lockstep_plain_chunks_keep_follower_history_in_sync():
     import json
     mk = lambda: ContinuousBatcher(  # noqa: E731
         CFG, PARAMS, num_blocks=64, block_size=8, slots=2, max_seq=96,
-        seed=0, speculative="ngram", spec_gamma=3, spec_wave=False)
+        seed=0, speculative="ngram", spec_gamma=3)
     leader, follower = mk(), mk()
-    # force the fallback steady state from the start: every chunk until
-    # the first probe runs PLAIN, including the one right after admission
-    leader._spec_ctl.mode = "plain"
     kinds = []
 
     def hook(kind, args, run):
@@ -269,6 +253,12 @@ def test_lockstep_plain_chunks_keep_follower_history_in_sync():
     reqs = [leader.submit(p, max_new_tokens=10,
                           sampling=SamplingParams.greedy(), seed=31 + i)
             for i, p in enumerate(prompts)]
+    # force the all-hostile fallback from the start: every request's own
+    # controller sits in plain mode, so every chunk until the first
+    # probe runs PLAIN, including the one right after admission
+    for r in reqs:
+        r._spec_ctl = AdaptiveSpecController(3)
+        r._spec_ctl.mode = "plain"
     for _ in range(80):
         leader.step()
         if all(r.done.is_set() for r in reqs):

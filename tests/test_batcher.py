@@ -474,68 +474,6 @@ def test_chunked_prefill_progresses_with_all_slots_busy():
     assert hog.wait() and longr.wait()
 
 
-def test_overlapped_decode_matches_sequential():
-    """Double-buffered decode dispatch (decode_overlap): tokens must be
-    bit-identical to the sequential step — the overlap only changes WHEN
-    the host syncs, never what the programs compute — for both greedy
-    and sampled requests, and the overlapped run must actually engage."""
-    prompts = [RNG.integers(0, CFG.vocab_size, 9).tolist()
-               for _ in range(3)]
-
-    def run(overlap, sp, seed0):
-        b = ContinuousBatcher(CFG, PARAMS, num_blocks=128, block_size=8,
-                              slots=4, max_seq=128,
-                              decode_overlap=overlap)
-        b.DECODE_CHUNKS = (8, 4, 2, 1)   # small chunks: budget spans many
-        reqs = [b.submit(p, max_new_tokens=40, sampling=sp,
-                         seed=seed0 + i) for i, p in enumerate(prompts)]
-        run_until_done(b, reqs)
-        for r in reqs:
-            assert r.error is None, r.error
-        return [r.tokens for r in reqs], b.stats()["overlapped_dispatches"]
-
-    for sp in (SamplingParams.greedy(),
-               SamplingParams(temperature=0.8, top_k=20, top_p=0.9)):
-        seq, n_off = run(False, sp, 7)
-        ovl, n_on = run(True, sp, 7)
-        assert seq == ovl
-        assert n_off == 0 and n_on > 0
-
-
-def test_overlap_defers_to_eos_and_queue():
-    """Stop-condition checks win: requests with an eos must never take
-    the overlapped path (the host needs every chunk's tokens to decide),
-    and the output contract is unchanged."""
-    b = ContinuousBatcher(CFG, PARAMS, num_blocks=128, block_size=8,
-                          slots=2, max_seq=128, decode_overlap=True)
-    b.DECODE_CHUNKS = (8, 4, 2, 1)
-    probe = b.submit([1, 2, 3], max_new_tokens=3,
-                     sampling=SamplingParams.greedy())
-    run_until_done(b, [probe])
-    eos = probe.tokens[1]
-    r = b.submit([1, 2, 3], max_new_tokens=40,
-                 sampling=SamplingParams.greedy(), eos_token_id=eos)
-    run_until_done(b, [r])
-    assert eos not in r.tokens and len(r.tokens) < 40
-    assert b.stats()["overlapped_dispatches"] == 0
-
-    # queue deferral: a waiting admission must disable the pair (it
-    # would otherwise wait two chunks instead of one), re-enabling the
-    # moment the queue drains
-    from distributed_llm_inferencing_tpu.runtime.batcher import BatchRequest
-    r2 = b.submit([5, 6, 7], max_new_tokens=40,
-                  sampling=SamplingParams.greedy())
-    b.step()   # admit + first chunk: no eos, no stream, budget >= 2k
-    active = [i for i, a in enumerate(b.active) if a is not None]
-    assert b._overlap_eligible(active, 4)
-    b.queue.append(BatchRequest(prompt=[1], max_new_tokens=4,
-                                sampling=SamplingParams.greedy()))
-    assert not b._overlap_eligible(active, 4)
-    b.queue.pop()
-    assert b._overlap_eligible(active, 4)
-    run_until_done(b, [r2])
-
-
 @pytest.mark.parametrize("mate,full", [
     (SamplingParams(top_k=50), False),                  # prefix tier only
     (SamplingParams.greedy(), False),                   # no draw at all

@@ -210,7 +210,7 @@ def test_jit_impure_time_and_env_caught(tmp_path):
 
         def step(x):
             t0 = time.perf_counter()
-            flag = os.environ.get("DLI_SPEC_WAVE")
+            flag = os.environ.get("DLI_SPEC_ADAPTIVE")
             return x * t0
 
         fn = jax.jit(step)
@@ -887,7 +887,7 @@ def test_real_tree_clean(repo_results, checker):
 
 def test_knob_registry_three_way_parity():
     """Acceptance: code knobs == registry == docs, exactly. "Code"
-    includes shell scripts: a check.sh-only knob (DLI_TSAN_FAST) is a
+    includes shell scripts: a check.sh-only knob (DLI_VERIFY_BUDGET) is a
     knob like any other."""
     from distributed_llm_inferencing_tpu.utils import knobs
     ctx = Ctx.for_repo()

@@ -1317,7 +1317,7 @@ def test_deepseek_v3_mixed_dense_moe_matches_hf():
 
 def test_deepseek_v3_mixed_decode_and_batcher_match_hf_generate():
     """Mixed stack through the real serving paths: greedy decode via the
-    engine (dense cache + CPU layer-unroll eligibility) and via the
+    engine (dense cache) and via the
     paged continuous batcher, both ≡ HF generate."""
     import torch
     import transformers

@@ -84,7 +84,6 @@ def test_int4_engine_tp2_matches_tp1(monkeypatch):
     from distributed_llm_inferencing_tpu.runtime.engine import InferenceEngine
 
     monkeypatch.setenv("DLI_INT4_PALLAS", "interpret")
-    monkeypatch.setenv("DLI_UNROLL_LAYERS", "0")  # exercise the scan path
     torch.manual_seed(0)
     hf = transformers.GPT2LMHeadModel(transformers.GPT2Config(
         vocab_size=96, n_positions=64, n_embd=128, n_layer=2,
@@ -147,7 +146,6 @@ def test_int4_engine_tp2_row_and_col_kernels(monkeypatch):
     from distributed_llm_inferencing_tpu.runtime.engine import InferenceEngine
 
     monkeypatch.setenv("DLI_INT4_PALLAS", "interpret")
-    monkeypatch.setenv("DLI_UNROLL_LAYERS", "0")
     torch.manual_seed(0)
     hf = transformers.GPT2LMHeadModel(transformers.GPT2Config(
         vocab_size=96, n_positions=64, n_embd=128, n_layer=2,

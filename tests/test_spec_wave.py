@@ -62,13 +62,12 @@ def tick_clock():
     clock.set_clock(prev)
 
 
-def _mk(spec_wave, speculative="ngram", slots=4, spec_gamma=3,
+def _mk(speculative="ngram", slots=4, spec_gamma=3,
         spec_adaptive=None, small_chunks=True):
     b = ContinuousBatcher(CFG, PARAMS, num_blocks=256, block_size=8,
                           slots=slots, max_seq=160,
                           speculative=speculative, spec_gamma=spec_gamma,
-                          spec_adaptive=spec_adaptive,
-                          spec_wave=spec_wave)
+                          spec_adaptive=spec_adaptive)
     if small_chunks:
         b.DECODE_CHUNKS = (4, 2, 1)   # many chunks -> many decisions
     return b
@@ -90,25 +89,23 @@ def _run(b, prompts, n=24, sampling=None, seed0=900):
 # ---- bitwise greedy parity (the acceptance bar) -----------------------
 
 
-def test_greedy_bitwise_wave_on_off_and_plain():
-    """Greedy outputs identical across: plain batcher, wave-off
-    speculation, wave-on speculation — mixed repetitive/random prompts
-    so both accepted-heavy and miss-heavy slots are exercised."""
+def test_greedy_bitwise_wave_and_plain():
+    """Greedy outputs identical across the plain batcher and wave
+    speculation — mixed repetitive/random prompts so both
+    accepted-heavy and miss-heavy slots are exercised."""
     prompts = [_repetitive(), RNG.integers(0, 256, 11).tolist(),
                _repetitive(20), RNG.integers(0, 256, 7).tolist()]
     plain, _ = _run(ContinuousBatcher(CFG, PARAMS, num_blocks=256,
                                       block_size=8, slots=4, max_seq=160),
                     prompts)
-    off, _ = _run(_mk(spec_wave=False), prompts)
-    on, _ = _run(_mk(spec_wave=True), prompts)
+    on, _ = _run(_mk(), prompts)
     assert on == plain
-    assert off == plain
 
 
 def test_wave_drafts_actually_accept():
     """On a repetitive workload the wave path must land accepted drafts
     (tokens-per-weight-pass > 1) and count them in the wave metrics."""
-    b = _mk(spec_wave=True)
+    b = _mk()
     prompts = [_repetitive() for _ in range(4)]
     _run(b, prompts, n=32)
     snap = b.metrics.snapshot()["counters"]
@@ -128,13 +125,13 @@ def test_wave_drafts_actually_accept():
 def test_hostile_slot_rides_wave_while_friendly_keeps_drafting(tick_clock):
     """One draft-hostile request (top_k=0 full-vocab sampling: acceptance
     is zero BY DESIGN, ops/speculative.py) shares the wave with three
-    repetitive greedy requests. Pre-wave behavior was a global fallback
-    cliff; wave mode must keep the friendly slots drafting (accepted
-    tokens keep growing) while the hostile request's own controller
+    repetitive greedy requests. The wave must keep the friendly slots
+    drafting, with no wave-wide fallback cliff (accepted tokens keep
+    growing), while the hostile request's own controller
     falls back — and its tokens stay bit-identical to the plain batcher
     (uncovered rows draw the plain chunk's exact sample)."""
     sp_hostile = SamplingParams(temperature=1.0, top_k=0, top_p=1.0)
-    b = _mk(spec_wave=True)
+    b = _mk()
     # its own generator: with the module's RNG the prompts depended on
     # which tests had drawn from it before (alone: draft-friendly; after
     # the not-slow selection of this file: one prompt whose greedy
@@ -178,7 +175,7 @@ def test_all_hostile_wave_falls_back_to_true_plain_chunks():
     sp = SamplingParams(temperature=1.0, top_k=0, top_p=1.0)
     prompts = [RNG.integers(0, CFG.vocab_size, 20).tolist()
                for _ in range(4)]
-    b = _mk(spec_wave=True)
+    b = _mk()
     toks, reqs = _run(b, prompts, n=40, sampling=sp, seed0=300)
     for r in reqs:
         assert r._spec_ctl.mode == "plain", r._spec_ctl.stats()
@@ -189,9 +186,9 @@ def test_all_hostile_wave_falls_back_to_true_plain_chunks():
 
 
 def test_zero_gamma_wave_runs_plain_without_controllers():
-    """spec_gamma=0 under wave mode: an explicit zero-draft request —
+    """spec_gamma=0: an explicit zero-draft request —
     no per-request controllers, plain chunks, plain-identical output."""
-    b = _mk(spec_wave=True, spec_gamma=0, small_chunks=False)
+    b = _mk(spec_gamma=0, small_chunks=False)
     prompts = [[1, 2, 3, 4, 5, 6, 7, 8]]
     toks, reqs = _run(b, prompts, n=8)
     assert reqs[0]._spec_ctl is None
@@ -205,7 +202,7 @@ def test_zero_gamma_wave_runs_plain_without_controllers():
 def test_fixed_width_wave_without_adaptivity():
     """spec_adaptive=False pins every slot at the full static width —
     wave dispatches happen, no controllers exist, greedy parity holds."""
-    b = _mk(spec_wave=True, spec_adaptive=False)
+    b = _mk(spec_adaptive=False)
     prompts = [_repetitive(), _repetitive(20)]
     toks, reqs = _run(b, prompts, n=16)
     for r in reqs:
@@ -221,7 +218,7 @@ def test_fixed_width_wave_without_adaptivity():
 
 
 def test_cost_ledger_attributes_draft_and_verify_tokens():
-    b = _mk(spec_wave=True)
+    b = _mk()
     prompts = [_repetitive() for _ in range(4)]
     _, reqs = _run(b, prompts, n=32)
     for r in reqs:
@@ -241,7 +238,7 @@ def test_cost_ledger_attributes_draft_and_verify_tokens():
 
 
 def test_spec_wave_stats_surface():
-    b = _mk(spec_wave=True)
+    b = _mk()
     reqs = [b.submit(_repetitive(), max_new_tokens=24,
                      sampling=SamplingParams.greedy(), seed=5)]
     for _ in range(3):
@@ -251,7 +248,7 @@ def test_spec_wave_stats_surface():
     assert st["dispatches"] >= 1
     assert st["active_controllers"] >= 1
     _drain(b, reqs)
-    assert _mk(spec_wave=False).stats()["spec_wave"] is None
+    assert _mk(speculative=None).stats()["spec_wave"] is None
 
 
 def test_wave_metrics_reach_tsdb_catalog():
@@ -264,7 +261,7 @@ def test_wave_metrics_reach_tsdb_catalog():
     from distributed_llm_inferencing_tpu.runtime.tsdb import TSDB
     from distributed_llm_inferencing_tpu.utils.metrics import (
         parse_prometheus)
-    b = _mk(spec_wave=True)
+    b = _mk()
     exposition = b.metrics.prometheus()       # pre-decode scrape
     ts = TSDB(window_s=60, step_s=1)
     ts.ingest_prometheus("w0", parse_prometheus(exposition), t=100.0)
@@ -285,7 +282,7 @@ def test_profiler_tags_spec_phases():
     """/api/profile attribution: wave chunks must land their wall time
     in the spec_draft / spec_verify phases, not plain dispatch."""
     from distributed_llm_inferencing_tpu.utils.profiler import PhaseProfiler
-    b = _mk(spec_wave=True)
+    b = _mk()
     b.profiler = PhaseProfiler(enabled=True, sample_every=1)
     _run(b, [_repetitive() for _ in range(2)], n=16)
     phases = b.profiler.summary()["phases"]
@@ -304,7 +301,7 @@ def test_wave_lockstep_broadcast_carries_widths_not_history():
     the leader's drafting history and emits identical programs."""
     mk = lambda: ContinuousBatcher(  # noqa: E731
         CFG, PARAMS, num_blocks=64, block_size=8, slots=2, max_seq=96,
-        seed=0, speculative="ngram", spec_gamma=3, spec_wave=True)
+        seed=0, speculative="ngram", spec_gamma=3)
     leader, follower = mk(), mk()
     spec_payloads = []
 
@@ -357,7 +354,7 @@ def test_wave_eos_and_stream_order():
         pytest.skip("fully degenerate repetition: no usable eos")
     eos = full[cut]
 
-    b = _mk(spec_wave=True, slots=2)
+    b = _mk(slots=2)
     seen = []
     r = b.submit(prompt, max_new_tokens=10,
                  sampling=SamplingParams.greedy(), eos_token_id=eos,
@@ -381,7 +378,7 @@ def test_wave_sampled_distribution_against_noise_floor():
         b = ContinuousBatcher(CFG, PARAMS, num_blocks=256, block_size=8,
                               slots=8, max_seq=64, seed=0,
                               speculative="ngram" if wave else None,
-                              spec_gamma=2, spec_wave=True)
+                              spec_gamma=2)
         reqs = [b.submit(prompt, max_new_tokens=3, sampling=sp,
                          seed=seed0 + s) for s in range(n)]
         _drain(b, reqs)

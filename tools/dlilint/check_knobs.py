@@ -92,7 +92,7 @@ _SHELL_READ_RE = re.compile(r"\$\{?(DLI_[A-Z0-9_]+)")
 
 def collect_shell_reads(paths) -> List[Tuple[str, int, str]]:
     """(path, line, name) for DLI_* expansions in shell scripts —
-    check.sh-only knobs (e.g. DLI_TSAN_FAST) are knobs too and belong
+    check.sh-only knobs (e.g. DLI_VERIFY_BUDGET) are knobs too and belong
     in the registry + docs like any python-read knob."""
     out = []
     for path in paths:
