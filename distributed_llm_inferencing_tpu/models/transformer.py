@@ -1130,6 +1130,65 @@ def _layer_gather(pool, scales, block_tables, dt):
     return got
 
 
+def _layers_scanned(params, cfg: ModelConfig) -> bool:
+    """Whether every segment of the layer stack runs under a lax.scan
+    (scan_layer_stack); the batcher holds MoE layers one by one."""
+    return not any(isinstance(seg, (list, tuple))
+                   for seg, _, _, _ in layer_segments(params, cfg))
+
+
+def _pool_ladder(mb: int, scanned: bool = True):
+    """Static rungs of block counts the decode chunks may stop the pool
+    at: 1, 2, 3, 4, 6 and 8 eighths of the block table's ``mb`` columns,
+    so a rung is at most 1.5 times the one below it from a quarter up
+    (mistral's 128 -> 16/32/48/64/96/128, a toy 6 -> 1/2/3/5/6). A rung
+    is a branch of one lax.switch in the layer body, not a program.
+    Under a layer scan (``scanned``) the branch reads the scan's slice
+    of the pool where it lies. Where layers are held one by one each
+    layer's slice of the stacked pool would be copied out as the
+    branch's operand on every pass, and a switch around the whole chunk
+    costs seconds a program at every start, so there the ladder is the
+    full extent alone: lax.switch inlines its one branch. PERF.md
+    section 6, PR 30, has the chip's numbers for each."""
+    if not scanned:
+        return (mb,)
+    return tuple(sorted({-(-mb * n // 8) for n in (1, 2, 3, 4, 6, 8)}))
+
+
+def _pool_rung(ladder, bs: int, context_lens, live):
+    """Index of the smallest rung whose positions hold every live slot's
+    pool horizon, and that rung's positions. The pool only holds
+    positions < context_lens during a chunk (its own tokens sit in the
+    side buffer), so a position at or past the longest live context has
+    weight exactly zero in every slot: leaving it out is the same
+    mathematics. A slot with no budget does not count, whatever stale
+    length it carries."""
+    need = jnp.max(jnp.where(live, context_lens, 0))
+    extents = jnp.asarray([m * bs for m in ladder], jnp.int32)
+    rung = jnp.sum(need > extents[:-1]).astype(jnp.int32)
+    return rung, extents[rung]
+
+
+def _attend_pool_rung(rung, ladder, pre: bool, planes, scales, block_tables,
+                      dt, pool_pos, pool_valid, attend_pool):
+    """The pool side of a decode chunk's attention, as far as ``rung``
+    says (lax.switch: only the taken branch runs). Branch i takes the
+    first ``ladder[i]`` columns of the block tables -- a slice of the
+    pre-gathered planes when ``pre``, else this layer's gather -- and
+    calls ``attend_pool(planes, positions, valid)``, which brings the
+    side segment: all scores still meet in one softmax."""
+    bs = pool_pos.shape[1] // block_tables.shape[1]
+
+    def branch(mb_i):
+        def run():
+            n = mb_i * bs
+            got = (tuple(p[:, :n] for p in planes) if pre else
+                   _layer_gather(planes, scales, block_tables[:, :mb_i], dt))
+            return attend_pool(got, pool_pos[:, :n], pool_valid[:, :n])
+        return run
+    return jax.lax.switch(rung, [branch(m) for m in ladder])
+
+
 def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
                        block_tables, context_lens, seeds, steps0, temps,
                        tks, tps, ds, budget, eos_ids, dummy_block: int,
@@ -1166,8 +1225,12 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
     are never concatenated or widened. The pool is loop-invariant during
     the chunk, which is what makes the split exact. On the chip (PERF.md
     section 5) the pass is the weights, the per-layer gather of every
-    slot's whole block table (``kv_gather``) and attention's one read of
-    what was gathered, all MB*bs positions whatever the contexts are.
+    slot's block table (``kv_gather``) and attention's one read of what
+    was gathered. Both stop at the rung of ``_pool_ladder`` that holds
+    the longest live context (``_pool_rung``, chosen on the device from
+    ``context_lens`` and ``budget`` before the scan; ``_attend_pool_rung``
+    is a lax.switch inside this one program): positions past it have
+    weight zero in every slot, so the result is the full extent's.
 
     An MLA model's pool is latent (cfg.mla_latent_cache): one plane of
     shared rows, so one side buffer, and attention is the absorbed form
@@ -1176,10 +1239,12 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
 
     tokens: [R] last emitted token per slot; steps0: [R] tokens emitted so
     far. Returns (toks [K, R] int32, emits [K, R] bool, moe int32 [5],
-    new paged); the emitted tokens of slot r are
-    ``toks[:emits[:, r].sum(), r]``, and ``moe`` is the sum of _moe's
+    pool_positions int32, new paged); the emitted tokens of slot r are
+    ``toks[:emits[:, r].sum(), r]``, ``moe`` is the sum of _moe's
     MOE_STATS vectors over the chunk's passes and MoE layers, slots no
-    longer alive counted as idle rows (zeros for a dense model).
+    longer alive counted as idle rows (zeros for a dense model), and
+    ``pool_positions`` is the pool extent each slot was gathered and
+    attended over on every pass of this chunk.
     """
     from distributed_llm_inferencing_tpu.ops.attention import attend
     from distributed_llm_inferencing_tpu.ops.paged_kvcache import (
@@ -1210,6 +1275,8 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
     pool_pos = jnp.broadcast_to(jnp.arange(mb * bs, dtype=jnp.int32),
                                 (r, mb * bs))
     pool_valid = pool_pos < cl0[:, None]
+    ladder = _pool_ladder(mb, _layers_scanned(params, cfg))
+    rung, pool_positions = _pool_rung(ladder, bs, cl0, budget > 0)
     side_pos = cl0[:, None] + jnp.arange(k, dtype=jnp.int32)[None, :]
     side0 = (jnp.zeros((L, r, k, cfg.cache_kv_heads, cfg.cache_head_dim),
                        dt),) * n_planes
@@ -1236,9 +1303,6 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
             def layer(x, layer_in):
                 lp, *rest = layer_in
                 sd, pl = rest[:n_planes], rest[n_planes:]
-                if not pre:
-                    pl = _layer_gather(pl[:n_planes], pl[n_planes:],
-                                       block_tables, dt)
 
                 def write_side(*new):
                     with jax.named_scope("kv_write"):
@@ -1248,12 +1312,16 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
                             for s_, n_ in zip(sd, new))
 
                 def attend_side(q, sd2, **kw):
-                    with jax.named_scope("attention"):
-                        # a latent pool's rows stand for K and for V
-                        return attend(
-                            q, (pl[0], sd2[0]), (pl[-1], sd2[-1]), q_pos,
-                            (pool_pos, side_pos), (pool_valid, side_valid),
-                            **kw)
+                    def attend_pool(got, pos, valid):
+                        with jax.named_scope("attention"):
+                            # a latent pool's rows stand for K and for V
+                            return attend(
+                                q, (got[0], sd2[0]), (got[-1], sd2[-1]),
+                                q_pos, (pos, side_pos), (valid, side_valid),
+                                **kw)
+                    return _attend_pool_rung(
+                        rung, ladder, pre, pl[:n_planes], pl[n_planes:],
+                        block_tables, dt, pool_pos, pool_valid, attend_pool)
 
                 tail = dict(valid=alive[:, None], moe_stats=True)
                 if latent:
@@ -1316,7 +1384,7 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
             k8, ks = quant_kv(side[0])
             v8, vs = quant_kv(side[1])
             side = (k8, v8, ks, vs)
-        return toks, emits, moe, PagedKVCache(*(
+        return toks, emits, moe, pool_positions, PagedKVCache(*(
             plane.at[:, blk, off].set(jnp.swapaxes(sd, 1, 2))
             for plane, sd in zip(paged.planes(), side)))
 
@@ -1350,8 +1418,10 @@ def _paged_decode_chunk_stepwise(params, cfg: ModelConfig, k: int, tokens,
     (_, paged, _, _), (toks, emits) = jax.lax.scan(
         body, (tokens, paged, context_lens, budget > 0),
         jnp.arange(k, dtype=jnp.int32))
-    # this path counts no expert loads (MOE_STATS stays zero)
-    return toks, emits, jnp.zeros((len(MOE_STATS),), jnp.int32), paged
+    # this path counts no expert loads (MOE_STATS stays zero) and the
+    # paged kernels walk each slot's whole block table
+    return (toks, emits, jnp.zeros((len(MOE_STATS),), jnp.int32),
+            jnp.int32(block_tables.shape[1] * paged.block_size), paged)
 
 
 def paged_speculative_chunk(params, cfg: ModelConfig, k: int, gamma: int,
@@ -1436,6 +1506,8 @@ def paged_speculative_chunk(params, cfg: ModelConfig, k: int, gamma: int,
     pool_pos = jnp.broadcast_to(jnp.arange(mb * bs, dtype=jnp.int32),
                                 (r, mb * bs))
     pool_valid = pool_pos < cl0[:, None]
+    ladder = _pool_ladder(mb, _layers_scanned(params, cfg))
+    rung, _ = _pool_rung(ladder, bs, cl0, budget > 0)
     side0 = jnp.zeros((L, r, E, cfg.num_kv_heads, cfg.head_dim), dt)
     entry_step = jnp.arange(E, dtype=jnp.int32) // g1               # [E]
 
@@ -1463,9 +1535,6 @@ def paged_speculative_chunk(params, cfg: ModelConfig, k: int, gamma: int,
         def make_layer(seg_cfg):
             def layer(x, layer_in):
                 lp, sk, sv, kp, vp, *scales = layer_in
-                if not pre:
-                    kp, vp = _layer_gather((kp, vp), scales, block_tables,
-                                           dt)
 
                 def attend_write(q, kh, vh):
                     with jax.named_scope("kv_write"):
@@ -1473,14 +1542,19 @@ def paged_speculative_chunk(params, cfg: ModelConfig, k: int, gamma: int,
                             sk, kh.astype(dt), (0, t * g1, 0, 0))
                         sv2 = jax.lax.dynamic_update_slice(
                             sv, vh.astype(dt), (0, t * g1, 0, 0))
-                    with jax.named_scope("attention"):
-                        attn = attend(
-                            q, (kp, sk2), (vp, sv2), qp,
-                            (pool_pos, side_pos), (pool_valid, side_valid),
-                            sliding_window=_layer_window(seg_cfg, lp),
-                            alibi=_alibi(seg_cfg),
-                            softcap=seg_cfg.attn_softcap,
-                            sinks=_sinks(seg_cfg, lp))
+
+                    def attend_pool(got, pos, valid):
+                        with jax.named_scope("attention"):
+                            return attend(
+                                q, (got[0], sk2), (got[1], sv2), qp,
+                                (pos, side_pos), (valid, side_valid),
+                                sliding_window=_layer_window(seg_cfg, lp),
+                                alibi=_alibi(seg_cfg),
+                                softcap=seg_cfg.attn_softcap,
+                                sinks=_sinks(seg_cfg, lp))
+                    attn = _attend_pool_rung(
+                        rung, ladder, pre, (kp, vp), scales, block_tables,
+                        dt, pool_pos, pool_valid, attend_pool)
                     return attn, (sk2, sv2)
 
                 x, (sk2, sv2) = _block_body(x, lp, seg_cfg, qp,

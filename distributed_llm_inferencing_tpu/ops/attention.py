@@ -184,10 +184,13 @@ def resolve_backend(requested: str = "auto", n_devices: int = 1,
     jit program spans one device.
 
     ``op="paged"`` (the continuous batcher's block-table decode): auto
-    resolves to xla, the gather formulation of
-    ops/paged_kvcache.paged_attend_decode. No chip run on record compares
-    it with the pallas paged kernel (PERF.md section 7; ROADMAP S4 has
-    the XLA path's cost as the bar). Explicit "pallas" is honored.
+    resolves to xla, the gather formulation (the decode chunks of
+    models/transformer.py, which gather and read the pool as far as the
+    rung of _pool_ladder that holds the longest live context, and
+    ops/paged_kvcache.paged_attend_decode). No chip run on record
+    compares it with the pallas paged kernel (PERF.md section 7; ROADMAP
+    S4 (c): what the rungs leave of the XLA path's cost is the bar).
+    Explicit "pallas" is honored.
     """
     requested = os.environ.get("DLI_ATTENTION", requested)
     if requested in ("xla", "pallas", "pallas_interpret"):

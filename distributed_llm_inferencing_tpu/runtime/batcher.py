@@ -410,6 +410,7 @@ class ContinuousBatcher:
         # side); alert on dli_batcher_stall_*_ms_total
         self.metrics.inc("batcher_stall_program_ms", 0)
         self.metrics.inc("batcher_stall_host_ms", 0)
+        self._pool_positions = 0  # the last decode chunk's (_run_decode)
         self._pass_mean = {}      # (kind, k) -> [mean wall per pass, n]
         self._step_program_s = 0.0   # this step's wall inside programs
         if speculative:
@@ -1060,9 +1061,11 @@ class ContinuousBatcher:
                         p, cfg, k, tokens, paged, bt, cl, seeds, steps0,
                         temps, tks, tps, ds.astype(bool), budget, eos_ids,
                         dummy, mesh=mesh)
-                    # the pipelined chunk counts no expert loads
+                    # the pipelined chunk counts no expert loads and
+                    # attends every slot's whole block table
                     return toks, emits, jnp.zeros(
-                        (len(transformer.MOE_STATS),), jnp.int32), paged
+                        (len(transformer.MOE_STATS),), jnp.int32), \
+                        jnp.int32(mb * paged.block_size), paged
                 return transformer.paged_decode_chunk(
                     p, cfg, k, tokens, paged, bt, cl, seeds, steps0, temps,
                     tks, tps, ds.astype(bool), budget, eos_ids, dummy,
@@ -1196,7 +1199,12 @@ class ContinuousBatcher:
     def _run_decode(self, a: dict):
         """Launch one decode chunk's program from a JSON-safe arg dict:
         pack, dispatch, ONE host sync. Returns host arrays
-        (toks [K, R], emits [K, R])."""
+        (toks [K, R], emits [K, R]). The pool positions each slot was
+        attended over on each of the chunk's passes (the rung the program
+        chose, transformer._pool_rung) come back with them, into
+        ``_pool_positions`` for the chunk's span and, a pass, into
+        ``batcher_decode_pool_positions``: its ratio to
+        ``batcher_weight_passes`` is the mean extent."""
         bt = np.asarray(a["bt"], np.int32)
         r, mb = bt.shape
         use_lora = "aids" in a
@@ -1213,16 +1221,20 @@ class ContinuousBatcher:
                  if self.profiler.enabled else {})
         with self.mesh:
             with self.profiler.phase("dispatch", **stats):
-                toks, emits, moe, self.paged = fn(
+                toks, emits, moe, pool_positions, self.paged = fn(
                     self._wave_params(use_lora),
                     jnp.asarray(np.asarray(a["tokens"], np.int32)),
                     jnp.asarray(ints), jnp.asarray(floats), self.paged)
             with self.profiler.phase("device_wait"):
-                # the expert counters come back with the tokens: one sync
-                toks, emits, moe = jax.device_get(
-                    (toks, emits, moe if self.cfg.is_moe else ()))
+                # the counters come back with the tokens: one sync
+                toks, emits, moe, pool_positions = jax.device_get(
+                    (toks, emits, moe if self.cfg.is_moe else (),
+                     pool_positions))
             for name, n in zip(transformer.MOE_STATS, moe):
                 self.metrics.inc(f"batcher_moe_{name}", int(n))
+            self._pool_positions = int(pool_positions)
+            self.metrics.inc("batcher_decode_pool_positions",
+                             self._pool_positions * int(a["k"]))
             return toks, emits
 
     def _hist_deltas(self) -> list:
@@ -2699,7 +2711,8 @@ class ContinuousBatcher:
         trace.get_tracer().record(
             "batcher.decode_chunk", w0, w1,
             attrs={"k": k, "slots": len(active),
-                   "kv_bytes_per_token": self.paged.bytes_per_token})
+                   "kv_bytes_per_token": self.paged.bytes_per_token,
+                   "pool_positions": self._pool_positions})
         # drafting history stays current even when the adaptive controller
         # runs plain chunks in a speculative batcher — pure function of
         # program outputs, so lockstep followers mirror it in replay()

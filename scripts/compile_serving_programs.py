@@ -59,7 +59,8 @@ def report(name, lowered, t0):
     mem, text = compiled.memory_analysis(), compiled.as_text()
     print(f"{name}: {time.time() - t0:.1f}s to compile; per device "
           f"arguments {mem.argument_size_in_bytes / GIB:.2f} GiB, "
-          f"transient {mem.temp_size_in_bytes / GIB:.2f} GiB, "
+          f"transient {mem.temp_size_in_bytes / GIB:.2f} GiB "
+          f"({mem.temp_size_in_bytes} B), "
           f"output {mem.output_size_in_bytes / GIB:.2f} GiB "
           f"(aliased {mem.alias_size_in_bytes / GIB:.2f}); "
           f"all-reduce {text.count('all-reduce(')}, "

@@ -495,3 +495,74 @@ def test_sample_full_passes_counts_the_full_tier(mate, full):
     assert c["batcher_weight_passes"] > 0
     assert c.get("batcher_sample_full_passes", 0) == (
         c["batcher_weight_passes"] if full else 0)
+
+
+def _rung_batcher():
+    # 16 blocks of 8 a slot: rungs of 16, 32, 48, 64, 96, 128 positions
+    return ContinuousBatcher(CFG, PARAMS, num_blocks=64, block_size=8,
+                             slots=2, max_seq=128, decode_chunk_cap=8)
+
+
+RUNG_PROMPTS = [RNG.integers(0, CFG.vocab_size, 25).tolist(),
+                RNG.integers(0, CFG.vocab_size, 6).tolist()]
+
+
+def _serve_across_a_rung(b):
+    """Two requests, one greedy and one on the cells' sampling; the first
+    one's context passes 32 positions mid-generation. Returns what each
+    streamed."""
+    streamed = [[], []]
+    samplings = [SamplingParams.greedy(),
+                 SamplingParams(temperature=0.7, top_k=0, top_p=0.9)]
+    reqs = [b.submit(p, max_new_tokens=24, sampling=sp, seed=11,
+                     stream_cb=streamed[i].append)
+            for i, (p, sp) in enumerate(zip(RUNG_PROMPTS, samplings))]
+    run_until_done(b, reqs)
+    assert [r.wait() for r in reqs] == streamed
+    return streamed
+
+
+def test_context_crossing_a_rung_streams_the_full_extents_tokens(
+        monkeypatch):
+    """A request whose context passes a rung of the pool ladder (32 of
+    128 positions) mid-generation: the rung is chosen inside the decode
+    program, so
+    nothing compiles after warm_decode_programs() and the program keys
+    are the ones warmed; the tokens are those of the ladder patched to
+    the full extent alone, and the dense engine's."""
+    from distributed_llm_inferencing_tpu.models import transformer
+    b = _rung_batcher()
+    assert b.warm_decode_programs() == len(b.decode_chunks)
+    keys = set(b._decode_fns)
+    got = _serve_across_a_rung(b)
+    assert set(b._decode_fns) == keys
+    # AOT executables: a shape they were not compiled for would raise
+    assert not any(hasattr(fn, "lower") for fn in b._decode_fns.values())
+    assert got[0] == dense_greedy(RUNG_PROMPTS[0], 24)
+
+    monkeypatch.setattr(transformer, "_pool_ladder",
+                        lambda mb, scanned=True: (mb,))
+    full = _rung_batcher()
+    assert _serve_across_a_rung(full) == got
+    c = full.metrics.snapshot()["counters"]
+    assert c["batcher_decode_pool_positions"] \
+        == 128 * c["batcher_weight_passes"]
+
+
+def test_decode_pool_positions_counts_each_chunks_rung():
+    """batcher_decode_pool_positions rises by the rung's positions a pass
+    of every chunk, and the chunk's span says which rung it took: its
+    ratio to batcher_weight_passes is the mean extent a pass read."""
+    from distributed_llm_inferencing_tpu.utils import trace
+    b = _rung_batcher()
+    seen = {s.span_id for s in trace.get_tracer().spans()}
+    _serve_across_a_rung(b)
+    chunks = [s.attrs for s in trace.get_tracer().spans()
+              if s.name == "batcher.decode_chunk"
+              and s.span_id not in seen]
+    rungs = [a["pool_positions"] for a in chunks]
+    assert rungs == sorted(rungs) and set(rungs) == {32, 48}, rungs
+    c = b.metrics.snapshot()["counters"]
+    assert c["batcher_decode_pool_positions"] == sum(
+        a["pool_positions"] * a["k"] for a in chunks)
+    assert c["batcher_weight_passes"] == sum(a["k"] for a in chunks)

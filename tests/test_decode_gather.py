@@ -9,6 +9,13 @@ the benchmark sits above it (mistral-7b: 4.0 GiB, kanana-2-30b-a3b:
 inputs through both (the cap patched to 0 for the in-loop one) and hold
 them equal: tokens and ``emits`` exactly, the pool at the tolerance
 ``tests/test_paged.py`` holds paged against dense to.
+
+Both forms stop the pool at the rung of ``transformer._pool_ladder`` that
+holds the longest live context (``_pool_rung``, a ``lax.switch`` inside
+the program). The rung cases run each rung, forced by the contexts,
+against the same program with the ladder patched to ``(mb,)``, the full
+extent: the same tokens, the same ``emits``, the same pool. Layers held
+one by one (the batcher's MoE layout) have the full extent alone.
 """
 
 import functools
@@ -79,6 +86,9 @@ CASES = {
         None, None),
     "lora": lambda: (_llama(), _lora, np.asarray([0, 1, 2, 0], np.int32)),
 }
+# the same configuration with its layers left stacked, under lax.scan as
+# the engine runs them: the pool ladder engages only there
+CASES["mla-latent-scanned"] = CASES["mla-latent"]
 
 
 def _random_pool(cfg):
@@ -105,7 +115,7 @@ def _setup(case):
     params = init_params(cfg, jax.random.PRNGKey(0))
     if edit is not None:
         params = edit(params, cfg)
-    if cfg.is_moe:
+    if cfg.is_moe and not case.endswith("-scanned"):
         # the batcher's layout for MoE layers: a list, run unrolled
         from distributed_llm_inferencing_tpu.runtime.batcher import (
             _unstack_layers)
@@ -131,60 +141,85 @@ STEPS0 = np.asarray([0, 3, 1, 7], np.int32)
 
 
 def _decode_chunk(case, k):
+    """The plain chunk as a function of (inputs, eos ids, sampling rows),
+    and what makes its inputs from the slots' contexts and budgets."""
     cfg, params, pool, bt, lora_ids = _setup(case)
     tokens = np.asarray([0, 5, 9, 17], np.int32)
     # slot 3 runs out of budget inside an 8-pass chunk
     budget = np.minimum(k, np.asarray([0, 8, 8, 5])).astype(np.int32)
 
-    def chunk(eos_ids, temps, tks, tps, ds):
+    def inputs(context):
+        return np.asarray(context, np.int32), budget
+
+    def chunk(inp, eos_ids, temps, tks, tps, ds):
+        context, budget = inp
         return transformer.paged_decode_chunk(
-            params, cfg, k, tokens, pool, bt, CONTEXT, SEEDS, STEPS0,
+            params, cfg, k, tokens, pool, bt, context, SEEDS, STEPS0,
             temps, tks, tps, ds, budget, eos_ids, DUMMY, lora_ids=lora_ids)
-    return chunk, budget
+    return chunk, inputs, budget
 
 
 def _spec_chunk(case, k):
     cfg, params, pool, bt, _ = _setup(case)
-    rng = np.random.default_rng(5)
-    # a repeating history, so that prompt lookup has something to draft
-    hist = np.zeros((R, MB * BS + 1), np.int32)
-    for r in range(R):
-        base = rng.integers(0, cfg.vocab_size, 4)
-        hist[r, :CONTEXT[r] + 1] = np.resize(base, CONTEXT[r] + 1)
-    tokens = hist[np.arange(R), CONTEXT]
     budget = np.asarray([0, 12, 12, 5], np.int32)
     gammas = np.asarray([GAMMA, GAMMA, 1, 0], np.int32)   # a width mix
 
-    def chunk(eos_ids, temps, tks, tps, ds):
+    def inputs(context):
+        rng = np.random.default_rng(5)
+        # a repeating history, so that prompt lookup has something to draft
+        hist = np.zeros((R, MB * BS + 1), np.int32)
+        for r in range(R):
+            base = rng.integers(0, cfg.vocab_size, 4)
+            hist[r, :context[r] + 1] = np.resize(base, context[r] + 1)
+        return (np.asarray(context, np.int32), budget,
+                hist[np.arange(R), context], hist)
+
+    def chunk(inp, eos_ids, temps, tks, tps, ds):
+        context, budget, tokens, hist = inp
         return transformer.paged_speculative_chunk(
-            params, cfg, k, GAMMA, tokens, hist, pool, bt, CONTEXT, SEEDS,
+            params, cfg, k, GAMMA, tokens, hist, pool, bt, context, SEEDS,
             STEPS0, temps, tks, tps, ds, budget, eos_ids, DUMMY,
             gammas=gammas)
-    return chunk, budget
+    return chunk, inputs, budget
 
 
 @functools.lru_cache(maxsize=None)
-def _forms(make, case, k):
+def _forms(make, case, k, full_extent=False):
     """One chunk program as toy widths trace it (pre-gathered) and with
     the cap patched to 0 (the in-loop gather): each a jit of a function
     of its own (jit keys its traces on the function, so two jits of one
     function would share the first trace), traced here by a first call.
-    eos ids and sampling rows are arguments, so the greedy and the
-    sampled case of one (case, k) share the two compiles."""
-    chunk, budget = make(case, k)
-    rows0 = (NO_EOS,) + _sampling_rows(False)
+    Contexts, budgets, eos ids and sampling rows are arguments, so every
+    rung and the greedy and the sampled case of one (case, k) share the
+    two compiles. ``full_extent`` traces both with the ladder patched to
+    its top rung alone: the program that reads the whole block table."""
+    chunk, inputs, budget = make(case, k)
+    args0 = (inputs(CONTEXT), NO_EOS) + _sampling_rows(False)
+    ladder = ((lambda mb, scanned=True: (mb,)) if full_extent
+              else transformer._pool_ladder)
     with mock.patch.object(transformer, "_layer_gather",
-                           side_effect=transformer._layer_gather) as spy:
+                           side_effect=transformer._layer_gather) as spy, \
+            mock.patch.object(transformer, "_pool_ladder", ladder):
         pre_fn = jax.jit(lambda *a: chunk(*a))
-        pre_fn(*rows0)
+        pre_fn(*args0)
         assert spy.call_count == 0, \
             "toy widths were expected under the pre-gather cap"
         with mock.patch.object(transformer, "_PREGATHER_MAX_BYTES", 0):
             loop_fn = jax.jit(lambda *a: chunk(*a))
-            loop_fn(*rows0)
+            loop_fn(*args0)
         assert spy.call_count > 0, \
             "the patched cap did not select the in-loop gather"
-    return pre_fn, loop_fn, budget
+    return pre_fn, loop_fn, inputs, budget
+
+
+def _assert_pools_close(pool_a, pool_b, skip_dummy=False):
+    """tests/test_paged.py's tolerance; ``skip_dummy`` leaves out the
+    reserved block, where the rows of slots that are not alive land."""
+    first = 1 if skip_dummy else 0
+    for pa, pb in zip(pool_a.planes(), pool_b.planes()):
+        np.testing.assert_allclose(
+            np.asarray(pa[:, first:], np.float32),
+            np.asarray(pb[:, first:], np.float32), rtol=2e-4, atol=2e-4)
 
 
 def _run_both(make, case, k, rows, eos_of):
@@ -192,17 +227,15 @@ def _run_both(make, case, k, rows, eos_of):
     pool exactly, the pool at tests/test_paged.py's tolerance. Slot 2's
     eos is ``eos_of(tokens)`` of a run without any: a token it really
     emits. Returns the pre-gathered form's outputs and the budgets."""
-    pre_fn, loop_fn, budget = _forms(make, case, k)
-    probe = jax.device_get(pre_fn(NO_EOS, *rows))
+    pre_fn, loop_fn, inputs, budget = _forms(make, case, k)
+    inp = inputs(CONTEXT)
+    probe = jax.device_get(pre_fn(inp, NO_EOS, *rows))
     eos = np.asarray([-1, -1, eos_of(probe[0]), -1], np.int32)
     (*pre, pool_a), (*loop, pool_b) = jax.device_get(
-        (pre_fn(eos, *rows), loop_fn(eos, *rows)))
+        (pre_fn(inp, eos, *rows), loop_fn(inp, eos, *rows)))
     for x, y in zip(pre, loop):
         np.testing.assert_array_equal(x, y)
-    for pa, pb in zip(pool_a.planes(), pool_b.planes()):
-        np.testing.assert_allclose(
-            np.asarray(pa, np.float32), np.asarray(pb, np.float32),
-            rtol=2e-4, atol=2e-4)
+    _assert_pools_close(pool_a, pool_b)
     return pre, budget
 
 
@@ -211,9 +244,12 @@ def _run_both(make, case, k, rows, eos_of):
 @pytest.mark.parametrize("k", [1, 8])
 @pytest.mark.parametrize("case", list(CASES))
 def test_decode_chunk_in_loop_gather_equals_pregathered(case, k, sampled):
-    (toks, emits, moe), budget = _run_both(
+    (toks, emits, moe, positions), budget = _run_both(
         _decode_chunk, case, k, _sampling_rows(sampled),
         lambda toks: toks[k // 2, 2])     # dies mid-chunk
+    # the rung that holds CONTEXT's 30; layers held one by one read it all
+    unrolled = isinstance(_setup(case)[1]["layers"], list)
+    assert positions == (MB * BS if unrolled else 40)
     assert not emits[:, 0].any()                      # dead from the start
     assert emits[:, 1].sum() == budget[1]
     assert emits[:, 2].sum() < budget[2]              # died of eos
@@ -231,6 +267,147 @@ def test_speculative_chunk_in_loop_gather_equals_pregathered(case, sampled):
         lambda toks: toks[1, 2, 0])
     assert not keeps[:, 0].any()
     assert eos_seen[-1, 2] and not eos_seen[-1, 1]
+
+
+# -- the rungs -----------------------------------------------------------
+
+@pytest.mark.parametrize("mb, want", [
+    (128, (16, 32, 48, 64, 96, 128)),   # mistral-7b's cells: 256..2048
+    (160, (20, 40, 60, 80, 120, 160)),  # kanana's width, were it scanned
+    (MB, (1, 2, 3, 5, 6)), (5, (1, 2, 3, 4, 5)), (3, (1, 2, 3)),
+    (2, (1, 2)), (1, (1,)),
+])
+def test_pool_ladder(mb, want):
+    assert transformer._pool_ladder(mb) == want
+    # layers held one by one: the full extent alone, whatever its size
+    assert transformer._pool_ladder(mb, False) == (mb,)
+
+
+def test_layers_scanned_is_false_where_layers_are_held_one_by_one():
+    cfg = get_config("tiny-llama")
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    assert transformer._layers_scanned(params, cfg)
+    held = dict(params, layers=[
+        jax.tree.map(lambda a: a[i], params["layers"])
+        for i in range(cfg.num_layers)])
+    assert not transformer._layers_scanned(held, cfg)
+
+
+@pytest.mark.parametrize("need, want", [
+    (0, 8), (1, 8), (8, 8),       # a context at a rung's edge stays on it
+    (9, 16), (16, 16),
+    (17, 24), (24, 24),
+    (25, 40), (40, 40),
+    (41, 48), (48, 48),
+])
+def test_pool_rung_is_the_smallest_that_holds_the_longest_live_context(
+        need, want):
+    ladder = transformer._pool_ladder(MB)
+    # the slot with no budget carries a stale, longer context
+    context = np.asarray([47, need, 3, 0], np.int32)
+    live = np.asarray([False, True, True, True])
+    rung, positions = jax.jit(
+        lambda c, a: transformer._pool_rung(ladder, BS, c, a))(context, live)
+    assert int(positions) == want == ladder[int(rung)] * BS
+
+
+# positions a rung holds -> contexts that force it (slot 0 has no budget;
+# the longest sits at the rung's edge, or one past the rung below)
+RUNG_CONTEXTS = {
+    8: [0, 5, 8, 3],
+    16: [0, 13, 16, 5],
+    24: [0, 13, 17, 5],
+    40: [0, 13, 25, 30],
+    48: [0, 13, 25, 41],      # slot 3's budget of 5 still fits its table
+}
+RUNG_CASES = ["gqa-bf16", "sinks", "gqa-int8-pool", "mla-latent-scanned"]
+
+
+def _run_rung(make, case, k, in_loop, context, rows, eos_of):
+    """One form of the program on one set of contexts, traced with the
+    ladder and with the full extent alone: (outputs, the full extent's
+    outputs, pool, the full extent's pool)."""
+    fns = _forms(make, case, k)
+    full = _forms(make, case, k, full_extent=True)
+    inp = fns[2](context)
+    probe = jax.device_get(full[in_loop](inp, NO_EOS, *rows))
+    eos = np.asarray([-1, -1, eos_of(probe[0]), -1], np.int32)
+    (*got, pool_a), (*want, pool_b) = jax.device_get(
+        (fns[in_loop](inp, eos, *rows), full[in_loop](inp, eos, *rows)))
+    return got, want, pool_a, pool_b
+
+
+@pytest.mark.parametrize("sampled", [False, True], ids=["greedy", "top-p"])
+@pytest.mark.parametrize("form", ["pregathered", "in-loop"])
+@pytest.mark.parametrize("positions", list(RUNG_CONTEXTS))
+@pytest.mark.parametrize("case", RUNG_CASES)
+def test_decode_chunk_rung_equals_full_extent(case, positions, form,
+                                              sampled):
+    k = 4
+    got, want, pool_a, pool_b = _run_rung(
+        _decode_chunk, case, k, form == "in-loop",
+        RUNG_CONTEXTS[positions], _sampling_rows(sampled),
+        lambda toks: toks[k // 2, 2])
+    assert got[3] == positions and want[3] == MB * BS
+    for x, y in zip(got[:3], want[:3]):               # toks, emits, moe
+        np.testing.assert_array_equal(x, y)
+    assert got[1][:, 1].all() and not got[1][:, 2].all()   # eos took slot 2
+    _assert_pools_close(pool_a, pool_b)
+
+
+@pytest.mark.parametrize("form", ["pregathered", "in-loop"])
+def test_layers_held_one_by_one_read_the_full_extent(form):
+    """The batcher's layout for MoE layers (a list, run unrolled): the
+    ladder is the full extent alone, so the program is the one with no
+    switch and says so, whatever the contexts."""
+    k = 4
+    got, want, pool_a, pool_b = _run_rung(
+        _decode_chunk, "mla-latent", k, form == "in-loop", RUNG_CONTEXTS[8],
+        _sampling_rows(False), lambda toks: toks[k // 2, 2])
+    assert got[3] == want[3] == MB * BS
+    for x, y in zip(got[:3], want[:3]):
+        np.testing.assert_array_equal(x, y)
+    for a, b in zip(pool_a.planes(), pool_b.planes()):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("sampled", [False, True], ids=["greedy", "top-k"])
+@pytest.mark.parametrize("form", ["pregathered", "in-loop"])
+@pytest.mark.parametrize("positions", list(RUNG_CONTEXTS))
+@pytest.mark.parametrize("case", RUNG_CASES[:3])   # it carries no latent pool
+def test_speculative_chunk_rung_equals_full_extent(case, positions, form,
+                                                   sampled):
+    got, want, pool_a, pool_b = _run_rung(
+        _spec_chunk, case, 3, form == "in-loop", RUNG_CONTEXTS[positions],
+        _sampling_rows(sampled, top_k=20), lambda toks: toks[1, 2, 0])
+    for x, y in zip(got, want):                       # toks, keeps, eos_seen
+        np.testing.assert_array_equal(x, y)
+    assert got[1][:, 1].any() and got[2][-1, 2]
+    _assert_pools_close(pool_a, pool_b)
+
+
+@pytest.mark.parametrize("form", ["pregathered", "in-loop"])
+@pytest.mark.parametrize("make", [_decode_chunk, _spec_chunk],
+                         ids=["plain", "speculative"])
+def test_a_slot_without_budget_does_not_raise_the_rung(make, form):
+    """Slot 0 has no budget and a stale context of 40, three rungs past
+    the live slots' 16. The program takes the rung of 16, and every live
+    slot reads as with the full extent."""
+    plain = make is _decode_chunk
+    k = 4 if plain else 3
+    got, want, pool_a, pool_b = _run_rung(
+        make, "gqa-bf16", k, form == "in-loop", [40, 13, 16, 5],
+        _sampling_rows(True, top_k=20),
+        lambda toks: toks[k // 2, 2] if plain else toks[1, 2, 0])
+    if plain:
+        assert got[3] == 16
+    live = got[1].astype(bool)            # emits [K, R], or keeps > 0
+    assert not live[:, 0].any() and live[:, 1:].any(axis=0).all()
+    np.testing.assert_array_equal(got[1], want[1])
+    mask = live if plain else live[..., None]     # toks [K, R(, G+1)]
+    np.testing.assert_array_equal(np.where(mask, got[0], 0),
+                                  np.where(mask, want[0], 0))
+    _assert_pools_close(pool_a, pool_b, skip_dummy=True)
 
 
 def test_batcher_on_the_in_loop_gather_emits_the_engines_tokens(monkeypatch):

@@ -148,7 +148,7 @@ def test_forty_decode_steps_through_the_latent_pool_match_reference():
     zeros, ones = np.zeros((r,), np.int32), np.ones((r,), np.float32)
     toks_all, cur, cl, pg, moe_sum = [], first, cl0, paged, 0
     for c in range(5):
-        toks, emits, moe, pg = transformer.paged_decode_chunk(
+        toks, emits, moe, _, pg = transformer.paged_decode_chunk(
             PARAMS, CFG, 8, jnp.asarray(cur), pg, jnp.asarray(tables),
             jnp.asarray(cl), jnp.asarray(zeros), jnp.asarray(zeros + 8 * c),
             jnp.asarray(ones), jnp.asarray(zeros), jnp.asarray(ones),
@@ -188,6 +188,10 @@ def test_batcher_over_32_tokens_a_program_emits_the_engines_tokens():
     assert counters["batcher_moe_layer_passes"] > 0
     assert counters["batcher_moe_idle_rows"] == 0 or \
         counters["batcher_moe_rows"] > counters["batcher_moe_idle_rows"]
+    # MoE layers are held one by one, so the pool ladder is the full
+    # extent alone (transformer._pool_ladder) and the counter says so
+    assert counters["batcher_decode_pool_positions"] \
+        == 128 * counters["batcher_weight_passes"]
 
 
 def test_batcher_chunked_prefill_and_radix_hit_keep_the_tokens():

@@ -271,8 +271,8 @@ def test_pp_spec_chunk_matches_single_stage():
         params, cfg, 4, cur2, pg, jnp.asarray(tables), cl2, seeds, steps0,
         temps, tks, tps, ds, jnp.full((r,), 4, jnp.int32), eos,
         dummy_block=0)
-    ft, fe, _, _ = follow(w_paged)
-    gt, ge, _, _ = follow(PagedKVCache(
+    ft, fe, *_ = follow(w_paged)
+    gt, ge, *_ = follow(PagedKVCache(
         k=jnp.asarray(g_paged.k), v=jnp.asarray(g_paged.v),
         k_scale=g_paged.k_scale, v_scale=g_paged.v_scale))
     np.testing.assert_array_equal(np.asarray(fe), np.asarray(ge))
