@@ -85,8 +85,10 @@ class ModelConfig:
     shared_attn_mlp_norm: bool = False
     sliding_window: Optional[int] = None  # Mistral-style local attention
     # Per-LAYER attention windows (GPT-Neo alternating global/local-256):
-    # a full per-layer tuple, entries None => global. Mutually exclusive
-    # with the uniform ``sliding_window``. Threaded through the runtime
+    # a full per-layer tuple, entries None => global. ``sliding_window``
+    # beside it only names the width every windowed layer has, as a
+    # source's ``sliding_window`` key does beside ``layer_types``
+    # (Trinity/afmoe); the tuple decides. Threaded through the runtime
     # as an int32 leaf ``attn_window`` ([L], -1 == global) in the layer
     # param tree (models/params.py, convert.py), so every scan / unroll /
     # pipeline-stage / sharding path carries it without special cases;
@@ -94,6 +96,10 @@ class ModelConfig:
     # the XLA attention formulation — the pallas flash kernels take
     # static windows only (models/transformer.py).
     attn_windows: Optional[Tuple[Optional[int], ...]] = None
+    # Trinity (afmoe) gated attention: a per-layer ``attn_gate`` linear
+    # [D, H*hd] beside q/k/v; the attention output is multiplied by
+    # sigmoid(attn_gate(h)) elementwise, before the o projection.
+    attn_gate: bool = False
     # Gemma-2 logit softcapping: scores/logits squashed to
     # cap * tanh(x / cap). ``attn_softcap`` applies to attention scores
     # (pre-mask; forces the XLA attention formulation — the flash
@@ -291,8 +297,11 @@ class ModelConfig:
             assert len(self.attn_windows) == self.num_layers, (
                 f"attn_windows has {len(self.attn_windows)} entries for "
                 f"{self.num_layers} layers")
-            assert self.sliding_window is None, (
-                "attn_windows and sliding_window are mutually exclusive")
+            assert self.sliding_window is None or all(
+                w in (None, self.sliding_window)
+                for w in self.attn_windows), (
+                "sliding_window beside attn_windows names the width of "
+                "the windowed layers: every entry is None or equal to it")
         if self.rope_layers is not None:
             object.__setattr__(self, "rope_layers",
                                tuple(int(v) for v in self.rope_layers))
@@ -325,6 +334,7 @@ class ModelConfig:
             assert self.num_kv_heads == self.num_heads, (
                 "MLA materializes k/v per head: num_kv_heads == num_heads")
             assert self.position_embedding == "rope" and self.qk_norm is None
+            assert not self.attn_gate, "MLA has no attn_gate path"
         if self.mla_latent_cache:
             assert self.mla, "mla_latent_cache requires an MLA config"
             assert self.kv_quant is None, (
@@ -381,11 +391,26 @@ class ModelConfig:
         (transformer.layer_segments), init (params.init_params) and
         sharding (param_specs) — a field zeroed here is zeroed
         everywhere."""
+        n = self.dense_prefix_layers if num_layers is None else num_layers
         return self.replace(
             num_experts=0, moe_shared_experts=0, moe_router="softmax",
             dense_prefix_layers=0, moe_intermediate_size=None,
-            num_layers=(self.dense_prefix_layers if num_layers is None
-                        else num_layers))
+            num_layers=n, **self._per_layer(0, n))
+
+    def moe_segment_cfg(self) -> "ModelConfig":
+        """The MoE tail of a mixed stack as a stack of its own (init and
+        sharding build the two segments apart): the per-layer tuples cut
+        to the layers behind the dense prefix."""
+        k = self.dense_prefix_layers
+        return self.replace(dense_prefix_layers=0,
+                            num_layers=self.num_layers - k,
+                            **self._per_layer(k, self.num_layers))
+
+    def _per_layer(self, start: int, stop: int) -> dict:
+        """attn_windows / rope_layers of layers [start, stop)."""
+        return {name: (None if getattr(self, name) is None
+                       else getattr(self, name)[start:stop])
+                for name in ("attn_windows", "rope_layers")}
 
     @property
     def qk_head_dim(self) -> int:

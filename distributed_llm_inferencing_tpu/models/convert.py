@@ -219,7 +219,8 @@ SUPPORTED_MODEL_TYPES = ("gpt2", "opt", "llama", "mistral", "mixtral",
                          "qwen3_moe", "granite", "olmo2", "glm", "glm4",
                          "nemotron", "deepseek_v3", "ernie4_5", "smollm3",
                          "hunyuan_v1_dense", "exaone4", "dbrx", "glm4_moe",
-                         "ernie4_5_moe", "gpt_oss", "hunyuan_v1_moe")
+                         "ernie4_5_moe", "gpt_oss", "hunyuan_v1_moe",
+                         "afmoe")
 
 
 def config_from_hf(hf_config) -> ModelConfig:
@@ -908,6 +909,58 @@ def config_from_hf(hf_config) -> ModelConfig:
             mlp_bias=False, qk_norm="rms_head", qk_norm_after_rope=True,
             tie_word_embeddings=getattr(hf_config, "tie_word_embeddings",
                                         False))
+    if mt == "afmoe":
+        # Arcee Trinity (modeling_afmoe.py): gated grouped-query
+        # attention with shared [head_dim] q/k RMS norms, sliding layers
+        # that rotate and full layers that do not (layer_types), four
+        # norms a layer, a sqrt(hidden) embedding multiplier
+        # (mup_enabled), and a sigmoid token-choice router with a
+        # selection-only expert_bias over num_dense_layers dense layers
+        # and one group — deepseek_v3's routing at n_group 1.
+        if getattr(hf_config, "score_func", "sigmoid") != "sigmoid":
+            raise NotImplementedError(
+                f"afmoe score_func {hf_config.score_func!r} — only "
+                "sigmoid converts")
+        kinds = list(hf_config.layer_types)
+        win = hf_config.sliding_window
+        sliding = [t == "sliding_attention" for t in kinds]
+        L, nd = hf_config.num_hidden_layers, hf_config.num_dense_layers
+        E = hf_config.num_experts if nd < L else 0
+        return ModelConfig(
+            name=getattr(hf_config, "name_or_path", mt) or mt,
+            family="afmoe", vocab_size=hf_config.vocab_size,
+            hidden_size=hf_config.hidden_size,
+            intermediate_size=hf_config.intermediate_size,
+            moe_intermediate_size=(hf_config.moe_intermediate_size if E
+                                   else None),
+            num_layers=L, num_heads=hf_config.num_attention_heads,
+            num_kv_heads=hf_config.num_key_value_heads,
+            head_dim=hf_config.head_dim,
+            max_position_embeddings=hf_config.max_position_embeddings,
+            norm_type="rmsnorm", norm_eps=hf_config.rms_norm_eps,
+            activation=_act_from_hf(hf_config.hidden_act),
+            gated_mlp=True, position_embedding="rope",
+            rope_theta=getattr(hf_config, "rope_theta", 10000.0),
+            attn_bias=False, mlp_bias=False, qk_norm="rms_head",
+            post_block_norms=True, attn_gate=True,
+            embed_scale=(hf_config.hidden_size ** 0.5
+                         if getattr(hf_config, "mup_enabled", False)
+                         else None),
+            sliding_window=win if any(sliding) else None,
+            attn_windows=(tuple(win if s_ else None for s_ in sliding)
+                          if any(sliding) else None),
+            rope_layers=tuple(int(s_) for s_ in sliding),
+            num_experts=E,
+            num_experts_per_tok=hf_config.num_experts_per_tok,
+            moe_router="deepseek_v3" if E else "softmax",
+            moe_n_group=1, moe_topk_group=1,
+            moe_routed_scale=float(getattr(hf_config, "route_scale", 1.0)),
+            moe_norm_topk=bool(getattr(hf_config, "route_norm", True)),
+            moe_shared_experts=(getattr(hf_config, "num_shared_experts", 0)
+                                or 0) if E else 0,
+            dense_prefix_layers=nd if 0 < nd < L else 0,
+            tie_word_embeddings=getattr(hf_config, "tie_word_embeddings",
+                                        False))
     if mt == "exaone4":
         # EXAONE 4.0: the olmo2 sublayer-postnorm topology (x +
         # norm(f(x)), norms named post_attention/post_feedforward) with
@@ -1525,6 +1578,58 @@ def convert_state_dict(cfg: ModelConfig, sd, dtype=None):
             "final_norm": {"scale": get("model.norm.weight") + off},
         }
         if pref:   # glm4_moe first_k_dense_replace: dense prefix segment
+            params["layers_dense"] = _stack(
+                [layer(i, False) for i in range(pref)])
+        if not cfg.tie_word_embeddings:
+            params["lm_head"] = {"w": get("lm_head.weight").T}
+    elif fam == "afmoe":
+        # model.layers.N.{input_layernorm, post_attention_layernorm (on
+        # the attention OUTPUT), pre_mlp_layernorm, post_mlp_layernorm},
+        # self_attn.{q,k,v,o,gate}_proj + q_norm/k_norm, and mlp.{gate,
+        # up,down}_proj (dense) or mlp.{router.gate, expert_bias,
+        # experts.E.*, shared_experts.*} (modeling_afmoe.py)
+        def layer(i, moe):
+            p = f"model.layers.{i}."
+
+            def lin(n):
+                return {"w": get(p + n + ".weight").T}
+
+            def scale(n):
+                return {"scale": get(p + n + ".weight")}
+            lp = {
+                "attn_norm": scale("input_layernorm"),
+                "attn_post_norm": scale("post_attention_layernorm"),
+                "mlp_norm": scale("pre_mlp_layernorm"),
+                "mlp_post_norm": scale("post_mlp_layernorm"),
+                "q": lin("self_attn.q_proj"), "k": lin("self_attn.k_proj"),
+                "v": lin("self_attn.v_proj"), "o": lin("self_attn.o_proj"),
+                "attn_gate": lin("self_attn.gate_proj"),
+                "q_norm": scale("self_attn.q_norm"),
+                "k_norm": scale("self_attn.k_norm"),
+            }
+            if not moe:
+                lp.update(gate=lin("mlp.gate_proj"), up=lin("mlp.up_proj"),
+                          down=lin("mlp.down_proj"))
+                return lp
+            lp["router"] = {"w": get(p + "mlp.router.gate.weight").T,
+                            "bias": get(p + "mlp.expert_bias")}
+            ex = [f"mlp.experts.{e}." for e in range(cfg.num_experts)]
+            lp["experts"] = {
+                nm: {"w": np.stack([get(p + e + f"{nm}_proj.weight").T
+                                    for e in ex])}
+                for nm in ("gate", "up", "down")}
+            if cfg.moe_shared_experts:
+                for nm in ("gate", "up", "down"):
+                    lp[f"shared_{nm}"] = lin(f"mlp.shared_experts.{nm}_proj")
+            return lp
+        pref = cfg.dense_prefix_layers
+        params = {
+            "embed": {"tokens": get("model.embed_tokens.weight")},
+            "layers": _stack([layer(i, cfg.is_moe)
+                              for i in range(pref, cfg.num_layers)]),
+            "final_norm": {"scale": get("model.norm.weight")},
+        }
+        if pref:
             params["layers_dense"] = _stack(
                 [layer(i, False) for i in range(pref)])
         if not cfg.tie_word_embeddings:
@@ -2388,11 +2493,18 @@ def convert_state_dict(cfg: ModelConfig, sd, dtype=None):
     # mixed layer_types through the shared llama branch, ...); no family
     # branch emits its own copy. sharding.param_specs expects the leaf
     # whenever cfg.attn_windows is set.
-    if cfg.attn_windows is not None:
-        params["layers"]["attn_window"] = np.asarray(
-            [-1 if w is None else w for w in cfg.attn_windows], np.int32)
-    if cfg.rope_layers is not None:   # per-layer NoPE (smollm3/exaone4)
-        params["layers"]["rope_on"] = np.asarray(cfg.rope_layers, np.int32)
+    # A dense prefix is a segment of its own (layers_dense) and takes its
+    # layers' entries.
+    pref = cfg.dense_prefix_layers if "layers_dense" in params else 0
+    for leaf, per_layer in (
+            ("attn_window", None if cfg.attn_windows is None else
+             [-1 if w is None else w for w in cfg.attn_windows]),
+            ("rope_on", cfg.rope_layers)):   # per-layer NoPE
+        if per_layer is not None:            # (smollm3/exaone4/afmoe)
+            per_layer = np.asarray(per_layer, np.int32)
+            params["layers"][leaf] = per_layer[pref:]
+            if pref:
+                params["layers_dense"][leaf] = per_layer[:pref]
 
     return _to_jax(params, dtype)
 

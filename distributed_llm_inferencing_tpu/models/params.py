@@ -26,10 +26,7 @@ def init_params(cfg: ModelConfig, key, dtype=None):
         # dense prefix as two independent stacked segments
         # (transformer.layer_segments runs them back to back)
         k1, k2 = jax.random.split(key)
-        kd = cfg.dense_prefix_layers
-        tail = init_params(
-            cfg.replace(dense_prefix_layers=0,
-                        num_layers=cfg.num_layers - kd), k1, dtype)
+        tail = init_params(cfg.moe_segment_cfg(), k1, dtype)
         prefix = init_params(cfg.dense_segment_cfg(), k2, dtype)
         tail["layers_dense"] = prefix["layers"]
         return tail
@@ -123,6 +120,8 @@ def init_params(cfg: ModelConfig, key, dtype=None):
             "v": lin(D, cfg.kv_dim, cfg.attn_bias),
             "o": lin(cfg.q_dim, D, cfg.o_bias_effective),
         }
+        if cfg.attn_gate:   # trinity (afmoe): gate on the attention output
+            layers["attn_gate"] = lin(D, cfg.q_dim, False)
     if cfg.post_block_norms:   # gemma2 sandwich norms
         layers["attn_post_norm"] = norm_p()
         layers["mlp_post_norm"] = norm_p()

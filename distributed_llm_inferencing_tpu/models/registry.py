@@ -240,6 +240,35 @@ register(ModelConfig(
     tie_word_embeddings=False, parallel_residual=True,
     shared_attn_mlp_norm=True))
 
+# --- Trinity (afmoe): three windowed rotary layers to one full layer
+# without rotation, a sigmoid gate on the attention output, q/k RMSNorm,
+# four norms a layer, a sqrt(D) embedding multiplier, sigmoid-routed
+# experts + one shared behind leading dense layers ---
+def _afmoe(name, layer_types, window, **kw):
+    """``layer_types`` as the source lists them: windowed layers rotate,
+    full ones do not (models/reference/afmoe_ref.py has the equations)."""
+    sliding = [t == "sliding_attention" for t in layer_types]
+    return ModelConfig(
+        name=name, family="afmoe", num_layers=len(layer_types),
+        norm_type="rmsnorm", norm_eps=1e-5, activation="silu",
+        gated_mlp=True, position_embedding="rope", rope_theta=10000.0,
+        attn_bias=False, mlp_bias=False, tie_word_embeddings=False,
+        qk_norm="rms_head", post_block_norms=True, attn_gate=True,
+        sliding_window=window,
+        attn_windows=tuple(window if s else None for s in sliding),
+        rope_layers=tuple(int(s) for s in sliding),
+        moe_router="deepseek_v3", moe_n_group=1, moe_topk_group=1,
+        moe_norm_topk=True, moe_shared_experts=1, **kw)
+
+
+register(_afmoe(
+    "trinity-mini", (["sliding_attention"] * 3 + ["full_attention"]) * 8,
+    2048, vocab_size=200192, hidden_size=2048, intermediate_size=6144,
+    moe_intermediate_size=1024, num_heads=32, num_kv_heads=4, head_dim=128,
+    max_position_embeddings=131072, embed_scale=2048 ** 0.5,
+    num_experts=128, num_experts_per_tok=8, moe_routed_scale=2.826,
+    dense_prefix_layers=2))
+
 # --- Tiny configs for tests/dryrun (not real checkpoints) ---
 register(ModelConfig(
     name="tiny-gpt2", family="gpt2", vocab_size=256, hidden_size=64,
@@ -299,3 +328,11 @@ register(ModelConfig(
     num_experts=16, num_experts_per_tok=3, moe_router="deepseek_v3",
     moe_n_group=1, moe_topk_group=1, moe_routed_scale=2.448,
     moe_norm_topk=True, moe_shared_experts=2, dense_prefix_layers=1))
+register(_afmoe(
+    # trinity-mini's switches at toy widths: one leading dense layer and
+    # one period [windowed x 3, full], as the benchmark's cut has it
+    "tiny-afmoe", ["sliding_attention"] * 4 + ["full_attention"], 8,
+    vocab_size=256, hidden_size=64, intermediate_size=96,
+    moe_intermediate_size=32, num_heads=4, num_kv_heads=2, head_dim=16,
+    max_position_embeddings=256, embed_scale=8.0, num_experts=16,
+    num_experts_per_tok=4, moe_routed_scale=2.826, dense_prefix_layers=1))

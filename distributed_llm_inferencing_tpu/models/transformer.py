@@ -24,6 +24,7 @@ Param pytree schema (all leaves jnp arrays; optional leaves absent, never None):
      "layers": {
         "attn_norm": {"scale": [L,D], "bias": [L,D]?},
         "q"|"k"|"v"|"o": {"w": [L,din,dout], "b": [L,dout]?},
+        "attn_gate": {"w": [L,D,H*hd]}?,   # cfg.attn_gate (trinity/afmoe)
         "mlp_norm": {"scale": [L,D], "bias": [L,D]?},
         # dense MLP:
         "up": {"w": [L,D,I], "b"?}, "gate": {"w": [L,D,I]}?, "down": {"w": [L,I,D], "b"?},
@@ -328,10 +329,28 @@ def _layer_window(cfg: ModelConfig, lp):
     leaf ([L] stacked; -1 == global) — under scan/unroll/pipeline ``lp``
     holds this layer's scalar slice, so every serving path threads it
     with no extra plumbing. Uniform-window families fall through to the
-    static cfg.sliding_window."""
-    if isinstance(lp, dict) and "attn_window" in lp:
+    static cfg.sliding_window, and so does a layer whose window
+    scan_layer_stack could name at trace time (_static_window_cfg: its
+    cfg carries no attn_windows, whatever leaf ``lp`` still holds)."""
+    if (cfg.attn_windows is not None and isinstance(lp, dict)
+            and "attn_window" in lp):
         return lp["attn_window"]
     return cfg.sliding_window
+
+
+def _static_window_cfg(seg_cfg: ModelConfig, cfg: ModelConfig, start: int,
+                       n: int) -> ModelConfig:
+    """``seg_cfg`` for layers [start, start + n) of ``cfg``'s stack: where
+    they share one window (always, for a layer held on its own) the
+    window is a trace-time constant, cfg.sliding_window (None == global),
+    and the bounded pool reads below can count its blocks. Layers of
+    mixed windows under one scan keep the traced leaf."""
+    if cfg.attn_windows is None:
+        return seg_cfg
+    wins = set(cfg.attn_windows[start:start + n])
+    if len(wins) != 1:
+        return seg_cfg
+    return seg_cfg.replace(attn_windows=None, sliding_window=wins.pop())
 
 
 def embed(params, cfg: ModelConfig, tokens, q_positions):
@@ -443,23 +462,27 @@ def scan_layer_stack(make_body, x, params, cfg: ModelConfig, xs):
     pool planes). Each segment scans its own stacked tree (or, for the
     batcher's per-layer lists of MoE layers, loops Python-side);
     per-layer outputs are re-stacked and concatenated back to [L, ...]
-    order. Returns (carry, tuple_of_[L,...]_outputs)."""
+    order. A body's ``seg_cfg`` names its layers' attention window as a
+    constant where it can (_static_window_cfg). Returns (carry,
+    tuple_of_[L,...]_outputs)."""
     seg_outs = []
     for layers_seg, seg_cfg, start, n in layer_segments(params, cfg):
         seg_xs = tuple(p[start:start + n] for p in xs)
-        body = make_body(seg_cfg)
         if isinstance(layers_seg, (list, tuple)):
             # per-layer weight buffers (batcher._unstack_layers): the
             # grouped expert matmul takes whole buffers, and under a scan
             # each pass would first copy them out of the stack
             outs = []
             for i, lp in enumerate(layers_seg):
+                body = make_body(_static_window_cfg(seg_cfg, cfg,
+                                                    start + i, 1))
                 x, out = body(x, (lp,) + tuple(p[i] for p in seg_xs))
                 outs.append(out)
             seg_outs.append(tuple(
                 jnp.stack([o[j] for o in outs])
                 for j in range(len(outs[0]))))
         else:
+            body = make_body(_static_window_cfg(seg_cfg, cfg, start, n))
             x, co = jax.lax.scan(body, x, (layers_seg,) + seg_xs)
             seg_outs.append(co)
     if len(seg_outs) == 1:
@@ -625,6 +648,17 @@ def _mla_latent_attn(h, lp, cfg: ModelConfig, q_positions, cache_k,
     return attn, (ck, cache_v)
 
 
+def _attn_gate(attn_flat, h, lp, cfg: ModelConfig):
+    """Trinity (afmoe) gated attention: the heads' output [B,s,H*hd]
+    times sigmoid of a linear of the block's normed input, elementwise,
+    ahead of the o projection. The sigmoid and the product are float32."""
+    if not cfg.attn_gate:
+        return attn_flat
+    with jax.named_scope("attn_gate"):
+        g = jax.nn.sigmoid(_linear(h, lp["attn_gate"]).astype(jnp.float32))
+        return (attn_flat.astype(jnp.float32) * g).astype(attn_flat.dtype)
+
+
 def _block_body(x, lp, cfg: ModelConfig, q_positions, attend_write,
                 mla_latent_attend=None, fused_q_attend=None,
                 lora_ids=None, valid=None, moe_stats=False):
@@ -731,7 +765,8 @@ def _block_body(x, lp, cfg: ModelConfig, q_positions, attend_write,
     vd = cfg.v_head_dim_effective
     if vd < attn.shape[-1]:   # MLA: v rode the cache zero-padded
         attn = attn[..., :vd]
-    attn_flat = attn.reshape(B, s, cfg.num_heads * vd)
+    attn_flat = _attn_gate(attn.reshape(B, s, cfg.num_heads * vd), h, lp,
+                           cfg)
     attn = _lora_apply(
         _linear(attn_flat, lp["o"], row_sharded=cfg.tp_row_sharded),
         attn_flat, lp, "o", lora_ids)
@@ -1117,17 +1152,32 @@ def _pool_pregather(paged, block_tables, dt):
 
 
 @jax.named_scope("kv_gather")
-def _layer_gather(pool, scales, block_tables, dt):
+def _layer_gather(pool, scales, block_tables, dt, kind=None):
     """One layer's planes gathered inside the step (long contexts, where
     the whole chunk's gather would pass _PREGATHER_MAX_BYTES); ``scales``
-    is the layer's (k_scale, v_scale) for an int8 pool, else empty."""
-    from distributed_llm_inferencing_tpu.ops.paged_kvcache import gather_seq
-    got = tuple(gather_seq(p, block_tables) for p in pool)
-    if scales:
-        from distributed_llm_inferencing_tpu.ops.kvcache import dequant_kv
-        got = tuple(dequant_kv(g, gather_seq(sc, block_tables), dt)
-                    for g, sc in zip(got, scales))
+    is the layer's (k_scale, v_scale) for an int8 pool, else empty.
+    ``kind`` (win | full) names the layer's kind as an inner scope where
+    the model has both."""
+    from distributed_llm_inferencing_tpu.ops.paged_kvcache import (
+        gather_seq, kind_scope)
+    with kind_scope(kind):
+        got = tuple(gather_seq(p, block_tables) for p in pool)
+        if scales:
+            from distributed_llm_inferencing_tpu.ops.kvcache import (
+                dequant_kv)
+            got = tuple(dequant_kv(g, gather_seq(sc, block_tables), dt)
+                        for g, sc in zip(got, scales))
     return got
+
+
+def _layer_kind(cfg: ModelConfig, window):
+    """win | full where the model mixes windowed and full layers and
+    this layer's window is a trace-time constant; else None."""
+    if cfg.attn_windows is None:
+        return None
+    if window is None:
+        return "full"
+    return "win" if isinstance(window, int) else None
 
 
 def _layers_scanned(params, cfg: ModelConfig) -> bool:
@@ -1170,7 +1220,7 @@ def _pool_rung(ladder, bs: int, context_lens, live):
 
 
 def _attend_pool_rung(rung, ladder, pre: bool, planes, scales, block_tables,
-                      dt, pool_pos, pool_valid, attend_pool):
+                      dt, pool_pos, pool_valid, attend_pool, kind=None):
     """The pool side of a decode chunk's attention, as far as ``rung``
     says (lax.switch: only the taken branch runs). Branch i takes the
     first ``ladder[i]`` columns of the block tables -- a slice of the
@@ -1183,7 +1233,8 @@ def _attend_pool_rung(rung, ladder, pre: bool, planes, scales, block_tables,
         def run():
             n = mb_i * bs
             got = (tuple(p[:, :n] for p in planes) if pre else
-                   _layer_gather(planes, scales, block_tables[:, :mb_i], dt))
+                   _layer_gather(planes, scales, block_tables[:, :mb_i], dt,
+                                 kind))
             return attend_pool(got, pool_pos[:, :n], pool_valid[:, :n])
         return run
     return jax.lax.switch(rung, [branch(m) for m in ladder])
@@ -1231,6 +1282,12 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
     ``context_lens`` and ``budget`` before the scan; ``_attend_pool_rung``
     is a lax.switch inside this one program): positions past it have
     weight zero in every slot, so the result is the full extent's.
+    Where layers are held one by one (the ladder is then the full extent
+    alone) a layer's window is a trace-time constant, and a windowed
+    layer gathers and reads, a slot, only the block-table columns that
+    hold its window behind that slot's context (``window_read``); full
+    layers, a scanned stack's traced per-layer windows, and a chunk small
+    enough to pre-gather read as before.
 
     An MLA model's pool is latent (cfg.mla_latent_cache): one plane of
     shared rows, so one side buffer, and attention is the absorbed form
@@ -1239,16 +1296,19 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
 
     tokens: [R] last emitted token per slot; steps0: [R] tokens emitted so
     far. Returns (toks [K, R] int32, emits [K, R] bool, moe int32 [5],
-    pool_positions int32, new paged); the emitted tokens of slot r are
+    pool_positions int32, window_positions int32, new paged); the
+    emitted tokens of slot r are
     ``toks[:emits[:, r].sum(), r]``, ``moe`` is the sum of _moe's
     MOE_STATS vectors over the chunk's passes and MoE layers, slots no
     longer alive counted as idle rows (zeros for a dense model), and
     ``pool_positions`` is the pool extent each slot was gathered and
-    attended over on every pass of this chunk.
+    attended over on every pass of this chunk, ``window_positions`` what
+    a windowed layer read instead (the widest, should widths differ;
+    ``pool_positions`` where no layer took the bounded read).
     """
     from distributed_llm_inferencing_tpu.ops.attention import attend
     from distributed_llm_inferencing_tpu.ops.paged_kvcache import (
-        PagedKVCache, gather_seq)
+        PagedKVCache, kind_scope, window_read)
     from distributed_llm_inferencing_tpu.ops.sampling import sample_batch
 
     from distributed_llm_inferencing_tpu.ops.pallas import fused_decode
@@ -1289,6 +1349,17 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
     pre = gathered_bytes <= _PREGATHER_MAX_BYTES
     pool = (_pool_pregather(paged, block_tables, dt) if pre
             else paged.planes())         # gathered per layer in-loop
+    # window -> (block ids, positions, validity) of the bounded read,
+    # fixed for the chunk like the pool's horizon
+    win_reads = {}
+    if cfg.attn_windows is not None and not pre and len(ladder) == 1:
+        for w in {w for w in cfg.attn_windows if w is not None}:
+            read = window_read(w, bs, block_tables, cl0)
+            if read is not None:
+                win_reads[w] = read + (read[1] < cl0[:, None],)
+    window_positions = (jnp.int32(max(v[1].shape[1]
+                                      for v in win_reads.values()))
+                        if win_reads else pool_positions)
 
     def body(carry, t):
         cur, side, cl, alive = carry
@@ -1311,17 +1382,25 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
                                 s_, n_.astype(dt), (0, t, 0, 0))
                             for s_, n_ in zip(sd, new))
 
-                def attend_side(q, sd2, **kw):
+                def attend_side(q, sd2, sliding_window=None, **kw):
+                    kind = _layer_kind(cfg, sliding_window)
+
                     def attend_pool(got, pos, valid):
-                        with jax.named_scope("attention"):
+                        with jax.named_scope("attention"), kind_scope(kind):
                             # a latent pool's rows stand for K and for V
                             return attend(
                                 q, (got[0], sd2[0]), (got[-1], sd2[-1]),
                                 q_pos, (pos, side_pos), (valid, side_valid),
-                                **kw)
+                                sliding_window=sliding_window, **kw)
+                    if kind == "win" and sliding_window in win_reads:
+                        bt_w, pos_w, valid_w = win_reads[sliding_window]
+                        return attend_pool(
+                            _layer_gather(pl[:n_planes], pl[n_planes:],
+                                          bt_w, dt, kind), pos_w, valid_w)
                     return _attend_pool_rung(
                         rung, ladder, pre, pl[:n_planes], pl[n_planes:],
-                        block_tables, dt, pool_pos, pool_valid, attend_pool)
+                        block_tables, dt, pool_pos, pool_valid, attend_pool,
+                        kind)
 
                 tail = dict(valid=alive[:, None], moe_stats=True)
                 if latent:
@@ -1384,9 +1463,10 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
             k8, ks = quant_kv(side[0])
             v8, vs = quant_kv(side[1])
             side = (k8, v8, ks, vs)
-        return toks, emits, moe, pool_positions, PagedKVCache(*(
-            plane.at[:, blk, off].set(jnp.swapaxes(sd, 1, 2))
-            for plane, sd in zip(paged.planes(), side)))
+        return toks, emits, moe, pool_positions, window_positions, \
+            PagedKVCache(*(
+                plane.at[:, blk, off].set(jnp.swapaxes(sd, 1, 2))
+                for plane, sd in zip(paged.planes(), side)))
 
 
 def _paged_decode_chunk_stepwise(params, cfg: ModelConfig, k: int, tokens,
@@ -1420,8 +1500,9 @@ def _paged_decode_chunk_stepwise(params, cfg: ModelConfig, k: int, tokens,
         jnp.arange(k, dtype=jnp.int32))
     # this path counts no expert loads (MOE_STATS stays zero) and the
     # paged kernels walk each slot's whole block table
-    return (toks, emits, jnp.zeros((len(MOE_STATS),), jnp.int32),
-            jnp.int32(block_tables.shape[1] * paged.block_size), paged)
+    whole = jnp.int32(block_tables.shape[1] * paged.block_size)
+    return (toks, emits, jnp.zeros((len(MOE_STATS),), jnp.int32), whole,
+            whole, paged)
 
 
 def paged_speculative_chunk(params, cfg: ModelConfig, k: int, gamma: int,
@@ -1663,7 +1744,9 @@ def paged_prefill_tail(params, cfg: ModelConfig, tokens, tail_len,
 
     Each row's prefix (``prefix_len[b]`` tokens in ``prefix_blocks[b]``, a
     radix-cache hit) is NOT recomputed — its K/V is gathered from shared
-    blocks per layer. Fresh tail K/V is scattered into ``tail_blocks``.
+    blocks per layer (a layer whose window is a trace-time constant
+    gathers only the columns that hold it: paged_attend_prefix). Fresh
+    tail K/V is scattered into ``tail_blocks``.
     Batching admissions into one program is what keeps burst TTFT at one
     dispatch round trip instead of one per queued request (the reference
     served admissions fully serialized, worker/app.py:252-330).
@@ -1745,11 +1828,12 @@ def paged_prefill_tail(params, cfg: ModelConfig, tokens, tail_len,
                 with jax.named_scope("kv_write"):
                     nk = write_block_run(ck, k, tail_blocks)
                     nv = write_block_run(cv, v, tail_blocks)
+                win = _layer_window(seg_cfg, lp)
                 attn = paged_attend_prefix(
                     q, k, v, nk, nv, prefix_blocks, prefix_len, q_pos,
-                    tail_valid, sliding_window=_layer_window(seg_cfg, lp),
+                    tail_valid, sliding_window=win,
                     alibi=_alibi(seg_cfg), softcap=seg_cfg.attn_softcap,
-                    sinks=_sinks(seg_cfg, lp))
+                    sinks=_sinks(seg_cfg, lp), kind=_layer_kind(cfg, win))
                 return attn, (nk, nv)
 
             return _block_body(x, lp, seg_cfg, q_pos, attend_write,

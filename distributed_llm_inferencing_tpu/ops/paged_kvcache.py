@@ -34,6 +34,7 @@ implicit inside HF ``generate`` (SURVEY.md §2.4).
 
 from __future__ import annotations
 
+import contextlib
 from typing import NamedTuple, Optional
 
 import jax
@@ -130,6 +131,39 @@ def gather_seq(cache_layer, block_tables):
     return g.reshape(r, mb * bs, *g.shape[3:])
 
 
+def kind_scope(kind):
+    """Inner named scope of a layer's kind (win | full), or nothing for a
+    model of one kind, whose programs then carry the names they had."""
+    return jax.named_scope(kind) if kind else contextlib.nullcontext()
+
+
+def window_columns(window: int, bs: int, mb: int) -> Optional[int]:
+    """How many block-table columns hold a window of ``window`` positions
+    wherever it starts inside a block: ceil(window / bs) + 1. None where
+    that is no fewer than the table's ``mb`` (the whole table is read)."""
+    n = -(-window // bs) + 1
+    return n if n < mb else None
+
+
+def window_read(window: int, bs: int, block_tables, horizon):
+    """A windowed layer's share of a slot's cached positions: queries at
+    or past ``horizon`` [R] see cached positions in (horizon - window,
+    horizon) and none before. Returns (block ids [R, n], positions
+    [R, n * bs]) of the window_columns that hold them, the first column
+    clipped so that all n lie in the table, or None where the whole
+    table is read. The window mask stays the exact cut: every position
+    left out here had weight zero."""
+    r, mb = block_tables.shape
+    n = window_columns(window, bs, mb)
+    if n is None:
+        return None
+    first = jnp.clip((horizon - window + 1) // bs, 0, mb - n)
+    cols = first[:, None] + jnp.arange(n, dtype=jnp.int32)[None, :]
+    pos = cols[:, :, None] * bs + jnp.arange(bs, dtype=jnp.int32)
+    return (jnp.take_along_axis(block_tables, cols, axis=1),
+            pos.reshape(r, n * bs))
+
+
 def paged_attend_decode(q, cache_k_layer, cache_v_layer, block_tables,
                         context_lens,
                         sliding_window: Optional[int] = None,
@@ -192,7 +226,7 @@ def paged_attend_prefix(q, k_new, v_new, cache_k_layer, cache_v_layer,
                         sliding_window: Optional[int] = None,
                         k_scale_layer=None, v_scale_layer=None,
                         alibi=None, softcap: Optional[float] = None, sinks=None,
-                        expand_rows=None):
+                        expand_rows=None, kind: Optional[str] = None):
     """Tail-prefill attention: fresh tail K/V plus a cached prefix.
 
     This is what makes prefix-cache hits save *compute*, not just memory:
@@ -210,11 +244,19 @@ def paged_attend_prefix(q, k_new, v_new, cache_k_layer, cache_v_layer,
     ``k_new``/``v_new`` from the tail's own rows, so a tail over a cached
     prefix sees the K and V a whole prefill would have computed;
     ``cache_v_layer`` is then None.
+
+    A static ``sliding_window`` shorter than the prefix bucket gathers,
+    a row, only the columns that hold the window behind ``prefix_len``
+    (window_read: the tail's first query sees the most of the prefix).
+    ``kind`` (win | full) names the layer's kind as an inner scope.
     """
     b, t = q.shape[0], q.shape[1]
     bs = cache_k_layer.shape[1]
-    pb = prefix_blocks.shape[1]
-    with jax.named_scope("kv_gather"):
+    read = (window_read(sliding_window, bs, prefix_blocks, prefix_len)
+            if isinstance(sliding_window, int) else None)
+    if read is not None:
+        prefix_blocks, prefix_pos = read
+    with jax.named_scope("kv_gather"), kind_scope(kind):
         kp = gather_seq(cache_k_layer, prefix_blocks)   # [B, PB*bs, Hkv, hd]
         if expand_rows is None:
             vp = gather_seq(cache_v_layer, prefix_blocks)
@@ -227,11 +269,12 @@ def paged_attend_prefix(q, k_new, v_new, cache_k_layer, cache_v_layer,
                             q.dtype)
     if expand_rows is not None:
         kp, vp = expand_rows(kp)
-    p = pb * bs
-    prefix_pos = jnp.broadcast_to(jnp.arange(p, dtype=jnp.int32), (b, p))
+    if read is None:
+        p = prefix_blocks.shape[1] * bs
+        prefix_pos = jnp.broadcast_to(jnp.arange(p, dtype=jnp.int32), (b, p))
     prefix_valid = prefix_pos < prefix_len[:, None]
 
-    with jax.named_scope("attention"):
+    with jax.named_scope("attention"), kind_scope(kind):
         return attend(q, (kp, k_new.astype(kp.dtype)),
                       (vp, v_new.astype(vp.dtype)), q_positions,
                       (prefix_pos, q_positions), (prefix_valid, tail_valid),

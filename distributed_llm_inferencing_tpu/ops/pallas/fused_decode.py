@@ -77,7 +77,8 @@ def supported(cfg, q_leaf=None) -> bool:
     positional term on q), plain per-head attention over an unquantized
     paged pool, bias-free q projection. Anything else keeps the unfused
     formulation (which is always semantically complete)."""
-    if cfg.mla or cfg.qk_norm or cfg.qkv_clip is not None:
+    if (cfg.mla or cfg.qk_norm or cfg.qkv_clip is not None
+            or cfg.attn_gate):
         return False
     if cfg.attn_softcap is not None or cfg.attn_sinks:
         return False

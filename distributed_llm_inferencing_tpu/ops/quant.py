@@ -45,7 +45,7 @@ import jax
 import jax.numpy as jnp
 
 # leaves quantized under params["layers"] / params root
-_LINEAR_LEAVES = ("q", "k", "v", "o", "up", "gate", "down",
+_LINEAR_LEAVES = ("q", "k", "v", "o", "attn_gate", "up", "gate", "down",
                   # deepseek MLA bottlenecks + expansions and shared
                   # experts (the q_a/kv_a latents are matmul weights like
                   # any other; their mid-stack norms stay float)

@@ -42,10 +42,8 @@ def param_specs(cfg: ModelConfig, spec: MeshSpec,
         # deepseek mixed stack: the dense prefix carries the plain-MLP
         # layer schema as its own stacked segment (pp would shard the
         # two segments independently — refused upstream, mesh.validate)
-        tail = param_specs(
-            cfg.replace(dense_prefix_layers=0,
-                        num_layers=cfg.num_layers - cfg.dense_prefix_layers),
-            spec, shard_layers_over_pp)
+        tail = param_specs(cfg.moe_segment_cfg(), spec,
+                           shard_layers_over_pp)
         prefix = param_specs(cfg.dense_segment_cfg(), spec,
                              shard_layers_over_pp)
         tail["layers_dense"] = prefix["layers"]
@@ -109,6 +107,8 @@ def param_specs(cfg: ModelConfig, spec: MeshSpec,
             "v": lin(P(L, None, kv_tp)),
             "o": lin(P(L, "tp", None)),
         }
+        if cfg.attn_gate:   # multiplies the heads' output: sharded as q
+            layers["attn_gate"] = lin(P(L, None, "tp"))
     if cfg.post_block_norms:   # gemma2 sandwich norms
         layers["attn_post_norm"] = norm_p()
         layers["mlp_post_norm"] = norm_p()

@@ -56,7 +56,8 @@ from distributed_llm_inferencing_tpu.models.config import ModelConfig
 from distributed_llm_inferencing_tpu.models.params import init_params
 from distributed_llm_inferencing_tpu.native import BlockPool
 from distributed_llm_inferencing_tpu.ops import kvblock_quant as kvq
-from distributed_llm_inferencing_tpu.ops.paged_kvcache import init_paged_cache
+from distributed_llm_inferencing_tpu.ops.paged_kvcache import (
+    init_paged_cache, window_columns)
 from distributed_llm_inferencing_tpu.ops.sampling import (
     PREFIX_K, SamplingParams, sample_batch)
 from distributed_llm_inferencing_tpu.parallel import sharding as shd
@@ -74,6 +75,14 @@ log = logging.getLogger("dli.batcher")
 
 TAIL_BUCKETS_X_BS = (1, 2, 4, 8, 16, 32, 64, 128, 256, 512)  # × block_size
 PREFIX_BUCKETS = (0, 1, 2, 4, 8, 16, 32, 64, 128, 256, 512)  # blocks
+# Most score elements a head that one admission program may hold: rows x
+# tail x (prefix + tail), as the program is bucketed. paged_attend_prefix
+# holds every head's scores in float32 (at 32 heads the budget is 2.2
+# GB; 16 rows of a 512 tail over a 512-block prefix would be 9 GB), so
+# rows past it wait for the next step (_collect_wave). The budget is the
+# widest wave 64 slots form over no cached prefix, 64 rows of a 512 tail
+# and the one dummy prefix block; over 512 blocks of 16 it leaves 2 rows.
+WAVE_SCORE_BUDGET = 64 * 512 * (512 + 16)
 
 
 @dataclasses.dataclass
@@ -319,6 +328,23 @@ class ContinuousBatcher:
                 raise ValueError(
                     f"{cfg.name}: MLA serves from the latent paged pool, "
                     "which cannot take " + "; ".join(refused))
+        if cfg.attn_windows is not None:
+            # windows that differ by layer ride the layer tree (or are a
+            # layer's trace-time constant, where layers are held one by
+            # one); the Pallas kernels take one static window, and a
+            # request for them is refused by name, not dropped
+            refused = [why for why, hit in (
+                ("a Pallas attention backend (attn_backend / "
+                 "DLI_ATTENTION: the kernels take one static window)",
+                 os.environ.get("DLI_ATTENTION", cfg.attn_backend)
+                 .startswith("pallas")),
+                ("DLI_FUSED_DECODE (the fused step takes one static "
+                 "window)", fused_decode.enabled()))
+                if hit]
+            if refused:
+                raise ValueError(
+                    f"{cfg.name}: per-layer attention windows cannot "
+                    "take " + "; ".join(refused))
         self.cfg = cfg = cfg.replace(
             attn_backend=_backend(cfg, self.mesh_spec.num_devices),
             # int4 pallas routing hint (models/config.py): this GSPMD
@@ -411,6 +437,10 @@ class ContinuousBatcher:
         self.metrics.inc("batcher_stall_program_ms", 0)
         self.metrics.inc("batcher_stall_host_ms", 0)
         self._pool_positions = 0  # the last decode chunk's (_run_decode)
+        self._window_positions = 0   # ... and what a windowed layer read
+        self._wave_cut = None   # (tail, prefix) group the bound last cut
+        # admission waves cut short by WAVE_SCORE_BUDGET
+        self.metrics.inc("batcher_admit_waves_bounded", 0)
         self._pass_mean = {}      # (kind, k) -> [mean wall per pass, n]
         self._step_program_s = 0.0   # this step's wall inside programs
         if speculative:
@@ -1066,10 +1096,16 @@ class ContinuousBatcher:
                     return toks, emits, jnp.zeros(
                         (len(transformer.MOE_STATS),), jnp.int32), \
                         jnp.int32(mb * paged.block_size), paged
-                return transformer.paged_decode_chunk(
-                    p, cfg, k, tokens, paged, bt, cl, seeds, steps0, temps,
-                    tks, tps, ds.astype(bool), budget, eos_ids, dummy,
-                    lora_ids=aids)
+                toks, emits, moe, pool_pos, win_pos, paged = \
+                    transformer.paged_decode_chunk(
+                        p, cfg, k, tokens, paged, bt, cl, seeds, steps0,
+                        temps, tks, tps, ds.astype(bool), budget, eos_ids,
+                        dummy, lora_ids=aids)
+                if cfg.attn_windows is not None:
+                    # [pool, window]; a model of one kind keeps the
+                    # program it had
+                    pool_pos = jnp.stack([pool_pos, win_pos])
+                return toks, emits, moe, pool_pos, paged
 
             fn = jax.jit(chunk, donate_argnums=(4,))
             self._decode_fns[(k, r, mb, use_lora)] = fn
@@ -1204,7 +1240,10 @@ class ContinuousBatcher:
         chose, transformer._pool_rung) come back with them, into
         ``_pool_positions`` for the chunk's span and, a pass, into
         ``batcher_decode_pool_positions``: its ratio to
-        ``batcher_weight_passes`` is the mean extent."""
+        ``batcher_weight_passes`` is the mean extent. What a windowed
+        layer read instead (paged_kvcache.window_read; the same number
+        for a model without one) goes to ``_window_positions`` and
+        ``batcher_decode_window_positions`` alike."""
         bt = np.asarray(a["bt"], np.int32)
         r, mb = bt.shape
         use_lora = "aids" in a
@@ -1232,9 +1271,12 @@ class ContinuousBatcher:
                      pool_positions))
             for name, n in zip(transformer.MOE_STATS, moe):
                 self.metrics.inc(f"batcher_moe_{name}", int(n))
-            self._pool_positions = int(pool_positions)
+            self._pool_positions, self._window_positions = (
+                int(n) for n in np.broadcast_to(pool_positions, (2,)))
             self.metrics.inc("batcher_decode_pool_positions",
                              self._pool_positions * int(a["k"]))
+            self.metrics.inc("batcher_decode_window_positions",
+                             self._window_positions * int(a["k"]))
             return toks, emits
 
     def _hist_deltas(self) -> list:
@@ -2073,6 +2115,7 @@ class ContinuousBatcher:
         fit, each with its radix match and block allocation done."""
         wave: List[dict] = []
         taken: set = set()
+        self._wave_cut = None   # the (tail, prefix) group the bound cut
         while True:
             free = [i for i, a in enumerate(self.active)
                     if a is None and i not in taken]
@@ -2115,6 +2158,16 @@ class ContinuousBatcher:
                 self.pool.release(prep["tail_alloc"])
                 self._requeue_front(req)
                 break
+            if prep is not None and self._past_score_budget(wave, prep):
+                # its (tail, prefix) group's program would hold more
+                # scores than WAVE_SCORE_BUDGET: run what the wave has,
+                # this request FIRST next step
+                self._wave_cut = (prep["t"], prep["pb"])
+                self.metrics.inc("batcher_admit_waves_bounded")
+                self.pool.release(prep["prefix_blocks"])
+                self.pool.release(prep["tail_alloc"])
+                self._requeue_front(req)
+                break
             if prep is None:
                 if wave:
                     # part of the wave is already allocated — admit it now,
@@ -2151,6 +2204,24 @@ class ContinuousBatcher:
             wave.append(prep)
         return wave
 
+    def _past_score_budget(self, wave: List[dict], prep: dict) -> bool:
+        """Whether one more row takes the admission program of ``prep``'s
+        (tail, prefix) group past WAVE_SCORE_BUDGET. A row alone always
+        runs, whatever its size."""
+        t, pb = prep["t"], prep["pb"]
+        rows = 1 + sum(m["t"] == t and m["pb"] == pb for m in wave)
+        if rows == 1:
+            return False
+        b = self._wave_rows(rows)
+        return b * t * (pb * self.block_size + t) > WAVE_SCORE_BUDGET
+
+    def _wave_rows(self, members: int) -> int:
+        """Rows of the admission program that carries ``members``."""
+        b = self._bucket_wave(members)
+        if self.mesh_spec.pp > 1:   # wave rows microbatch over pp stages
+            b = -(-b // self.mesh_spec.pp) * self.mesh_spec.pp
+        return b
+
     def _admit_group(self, t: int, pb: int, members: List[dict]):
         """One batched admission program for wave members sharing a
         (tail-bucket, prefix-bucket); rows padded to a power-of-two wave
@@ -2161,6 +2232,11 @@ class ContinuousBatcher:
         # tokens the program's shape pays for, and the slots that stand
         # still while it runs
         tokens = sum(m["tail_len"] for m in members)
+        # ... what it did not have to prefill: cached positions its rows
+        # attend that an earlier admission left (as prefill_cached_tokens
+        # counts them: a chunked prompt's own earlier chunks are not)
+        hits = sum(max(0, m["cached"] - m["req"]._prefill_counted)
+                   for m in members)
         active = sum(a is not None for a in self.active)
         w0 = clock.now()
         for m in members:
@@ -2184,19 +2260,32 @@ class ContinuousBatcher:
             attrs={"members": len(members), "rows": b,
                    "tail_bucket": t, "prefix_bucket": pb,
                    "tokens": tokens, "padded_tokens": b * t,
-                   "active": active})
+                   "active": active, "prefix_positions": hits,
+                   **self._gathered_prefix(b, pb),
+                   "bounded": int(self._wave_cut == (t, pb))})
         with self.profiler.phase("admit_post"):
             for j, m in enumerate(members):
                 if m["req"]._wave_span is None:
                     m["req"]._wave_span = wave.span_id
                 self._post_admit(m, int(first[j]))
 
+    def _gathered_prefix(self, b: int, pb: int) -> dict:
+        """Prefix positions one layer of an admission program gathers
+        over its ``b`` rows: the whole prefix bucket in a full layer, the
+        columns that hold the window in a windowed one
+        (paged_kvcache.window_read)."""
+        bs = self.block_size
+        out = {"gathered_full": b * pb * bs}
+        wins = {w for w in self.cfg.attn_windows or () if w is not None}
+        if wins:
+            out["gathered_win"] = b * bs * max(
+                window_columns(w, bs, pb) or pb for w in wins)
+        return out
+
     def _pack_admit(self, t: int, pb: int, members: List[dict]):
         """(rows, JSON-safe args) of one admission program."""
         bs = self.block_size
-        b = self._bucket_wave(len(members))
-        if self.mesh_spec.pp > 1:   # wave rows microbatch over pp stages
-            b = -(-b // self.mesh_spec.pp) * self.mesh_spec.pp
+        b = self._wave_rows(len(members))
         toks = np.zeros((b, t), np.int32)
         tail_len = np.ones((b,), np.int32)
         tail_blocks = np.full((b, t // bs), self._dummy, np.int32)
@@ -2712,7 +2801,8 @@ class ContinuousBatcher:
             "batcher.decode_chunk", w0, w1,
             attrs={"k": k, "slots": len(active),
                    "kv_bytes_per_token": self.paged.bytes_per_token,
-                   "pool_positions": self._pool_positions})
+                   "pool_positions": self._pool_positions,
+                   "window_positions": self._window_positions})
         # drafting history stays current even when the adaptive controller
         # runs plain chunks in a speculative batcher — pure function of
         # program outputs, so lockstep followers mirror it in replay()

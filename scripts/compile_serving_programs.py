@@ -4,16 +4,23 @@ described TPU — no chip, no arrays — before spending a chip call.
 
     JAX_PLATFORMS=cpu python scripts/compile_serving_programs.py [tp ...]
     JAX_PLATFORMS=cpu MODEL=kanana python scripts/compile_serving_programs.py 1
+    JAX_PLATFORMS=cpu MODEL=trinity python scripts/compile_serving_programs.py 1
 
 For each tensor-parallel width given (default: 1 and 4) the real jitted
 programs of ``runtime/batcher.py`` are lowered for ``v5e:2x2`` at
 chip_smoke.py's serving shape (mistral-7b int8, 32 layers; ``LAYERS=2``
 compiles as long: the stack is one scan), or with ``MODEL=kanana`` at
 the benchmark cell's (kanana-2-30b-a3b bf16, 7 layers, 64 slots, the
-latent pool of 10,240 blocks), with shapes from
+latent pool of 10,240 blocks) or ``MODEL=trinity`` at its cell's
+(trinity-mini bf16, 5 layers, 64 slots, 12,288 blocks, contexts to 9216:
+the 2-row admit over a 512-block prefix and the decode chunks with the
+windowed read), with shapes from
 ``jax.eval_shape`` and shardings from ``parallel/sharding.py``. Prints
 what ``compiled.memory_analysis()`` says each device must hold and the
-collectives in the program text. What the chip's compiler refuses, it
+collectives in the program text. With ``TEXT_DIR=<dir>`` each program's
+text goes there too, less what names source lines (each instruction's
+``metadata={...}`` and the tables of files, functions and stack frames
+at the top), so that two trees' programs compare with ``diff -r``. What the chip's compiler refuses, it
 refuses here. A compile that passes is not a chip run: this gives bytes,
 never a time. It loads libtpu, so run it while no test run needs
 ``tests/test_tpu_compile.py``.
@@ -39,8 +46,9 @@ from distributed_llm_inferencing_tpu.parallel.mesh import MeshSpec, create_mesh 
 from distributed_llm_inferencing_tpu.runtime.batcher import (  # noqa: E402
     ContinuousBatcher, _backend)
 
-# model, quant, layers, slots, block, blocks, max_seq, admit shapes as
-# (tail, prefix blocks, wave), decode chunk sizes
+# model, quant, depth (a layer count, or the cell's overrides), slots,
+# block, blocks, max_seq, admit shapes as (tail, prefix blocks, wave),
+# decode chunk sizes
 TARGETS = {
     "mistral": ("mistral-7b", "int8", 0, 8, 16, 1024, 2048,   # chip_smoke.py
                 ((512, 0, 1), (512, 32, 1), (32, 0, 2)), (32, 1)),
@@ -48,15 +56,32 @@ TARGETS = {
     # programs and its decode chunks
     "kanana": ("kanana-2-30b-a3b", None, 7, 64, 16, 10240, 2560,
                ((512, 0, 64), (128, 0, 64), (512, 0, 1)), (8, 1)),
+    # benchmarks/chip/configs/trinity-mini-l5.json: the widest admit the
+    # wave bound lets through, a chunk of a prefix's first admission
+    "trinity": ("trinity-mini", None, {
+        "num_layers": 5, "dense_prefix_layers": 1,
+        "attn_windows": (2048, 2048, 2048, 2048, None),
+        "rope_layers": (1, 1, 1, 1, 0)}, 64, 16, 12288, 9216,
+        ((512, 512, 2), (512, 128, 1)), (8, 1)),
 }
 (MODEL, QUANT, DEPTH, SLOTS, BLOCK, BLOCKS, MAX_SEQ, ADMIT,
  DECODE) = TARGETS[os.environ.get("MODEL", "mistral")]
+TEXT_DIR = os.environ.get("TEXT_DIR")
 GIB = 2.0 ** 30
 
 
 def report(name, lowered, t0):
     compiled = lowered.compile()
     mem, text = compiled.memory_analysis(), compiled.as_text()
+    if TEXT_DIR:
+        import re
+        os.makedirs(TEXT_DIR, exist_ok=True)
+        with open(os.path.join(
+                TEXT_DIR, re.sub(r"[^A-Za-z0-9=_]+", "_", name)), "w") as f:
+            text = re.sub(
+                r"^FileNames\n.*?^StackFrames\n(?:\d+ \{[^\n]*\}\n)*", "",
+                text, flags=re.S | re.M)
+            f.write(re.sub(r", metadata=\{[^}]*\}", "", text))
     print(f"{name}: {time.time() - t0:.1f}s to compile; per device "
           f"arguments {mem.argument_size_in_bytes / GIB:.2f} GiB, "
           f"transient {mem.temp_size_in_bytes / GIB:.2f} GiB "
@@ -71,13 +96,15 @@ def report(name, lowered, t0):
 def main(widths):
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
-    layers = int(os.environ.get("LAYERS", DEPTH))
+    layers = DEPTH if isinstance(DEPTH, dict) else int(
+        os.environ.get("LAYERS", DEPTH))
     for tp in widths:
         spec = MeshSpec(tp=tp)
         mesh = create_mesh(spec, topo.devices)
         cfg = get_config(MODEL).replace(quant=QUANT)
         if layers:
-            cfg = cfg.replace(num_layers=layers)
+            cfg = cfg.replace(**(layers if isinstance(layers, dict)
+                                 else {"num_layers": layers}))
         # what ContinuousBatcher.__init__ pins
         cfg = cfg.replace(attn_backend=_backend(cfg, spec.num_devices),
                           tp_row_sharded=tp > 1, mla_latent_cache=cfg.mla)
@@ -119,13 +146,14 @@ def main(widths):
             for t, pb, wave in ADMIT:
                 n_ints = wave * (t + t // BLOCK + pb + 6)
                 t0 = time.time()
-                report(f"admit tail={t} prefix_blocks={pb} wave={wave}",
+                report(f"{MODEL} tp={tp} admit tail={t} prefix_blocks={pb} "
+                       f"wave={wave}",
                        b._admit_jit(t, pb, wave).lower(
                            params, arr((n_ints,), jnp.int32),
                            arr((2, wave), jnp.float32), paged), t0)
             for k in DECODE:
                 t0 = time.time()
-                report(f"decode chunk k={k}",
+                report(f"{MODEL} tp={tp} decode chunk k={k}",
                        b._decode_jit(k, SLOTS, mb).lower(
                            params, arr((SLOTS,), jnp.int32),
                            arr((SLOTS * (mb + 7),), jnp.int32),

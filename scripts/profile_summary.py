@@ -38,8 +38,12 @@ import json
 import os
 import sys
 
-SCOPES = ("kv_gather", "attention", "kv_write", "mlp", "moe_route",
-          "moe_experts", "moe_combine", "mla_absorb", "lm_head", "sample")
+SCOPES = ("kv_gather", "attention", "attn_gate", "kv_write", "mlp",
+          "moe_route", "moe_experts", "moe_combine", "mla_absorb",
+          "lm_head", "sample")
+# a layer's kind, named inside kv_gather / attention where a model mixes
+# windowed and full layers: reported as `attention/win`
+KINDS = ("win", "full")
 NO_SCOPE = "(no scope)"
 # instructions a compiler pass makes without an op name, by what their
 # own name starts with: `lax.ragged_dot` becomes `ragged-dot-none.N`
@@ -99,10 +103,13 @@ def _varints(buf):
 
 
 def scope_of(op_name: str) -> str:
-    """`jit(chunk)/while/body/attention/dot_general` -> `attention`."""
-    for part in reversed(op_name.split("/")):
-        if part in SCOPES:
-            return part
+    """`jit(chunk)/while/body/attention/dot_general` -> `attention`;
+    `.../attention/win/dot_general` -> `attention/win`."""
+    parts = op_name.split("/")
+    for i in range(len(parts) - 1, -1, -1):
+        if parts[i] in SCOPES:
+            kind = parts[i + 1] if i + 1 < len(parts) else None
+            return f"{parts[i]}/{kind}" if kind in KINDS else parts[i]
     return NO_SCOPE
 
 

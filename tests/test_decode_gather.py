@@ -244,7 +244,7 @@ def _run_both(make, case, k, rows, eos_of):
 @pytest.mark.parametrize("k", [1, 8])
 @pytest.mark.parametrize("case", list(CASES))
 def test_decode_chunk_in_loop_gather_equals_pregathered(case, k, sampled):
-    (toks, emits, moe, positions), budget = _run_both(
+    (toks, emits, moe, positions, _), budget = _run_both(
         _decode_chunk, case, k, _sampling_rows(sampled),
         lambda toks: toks[k // 2, 2])     # dies mid-chunk
     # the rung that holds CONTEXT's 30; layers held one by one read it all

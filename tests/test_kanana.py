@@ -148,7 +148,7 @@ def test_forty_decode_steps_through_the_latent_pool_match_reference():
     zeros, ones = np.zeros((r,), np.int32), np.ones((r,), np.float32)
     toks_all, cur, cl, pg, moe_sum = [], first, cl0, paged, 0
     for c in range(5):
-        toks, emits, moe, _, pg = transformer.paged_decode_chunk(
+        toks, emits, moe, _, _, pg = transformer.paged_decode_chunk(
             PARAMS, CFG, 8, jnp.asarray(cur), pg, jnp.asarray(tables),
             jnp.asarray(cl), jnp.asarray(zeros), jnp.asarray(zeros + 8 * c),
             jnp.asarray(ones), jnp.asarray(zeros), jnp.asarray(ones),

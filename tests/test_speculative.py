@@ -242,7 +242,7 @@ def test_paged_speculative_chunk_matches_plain_chunk():
     budget = jnp.full((3,), n_new, jnp.int32)
     eos = jnp.full((3,), -1, jnp.int32)
 
-    ptoks, pemits, _, _, _ = transformer.paged_decode_chunk(
+    ptoks, pemits, *_ = transformer.paged_decode_chunk(
         params, cfg, n_new, cur0, paged0, tables, cl0, seeds, steps0,
         temps, tks, tps, ds, budget, eos, dummy_block=0)
     plain = [[int(ptoks[t, r]) for t in range(n_new) if bool(pemits[t, r])]
@@ -293,7 +293,7 @@ def test_paged_speculative_chunk_eos_and_budget():
     ones = jnp.ones((2,), jnp.float32)
     ds = jnp.zeros((2,), bool)
     # row 0: tiny budget; row 1: eos = its first plain-decode token
-    ptoks, pemits, _, _, _ = transformer.paged_decode_chunk(
+    ptoks, pemits, *_ = transformer.paged_decode_chunk(
         params, cfg, 4, cur0, paged0, tables, cl0, seeds, steps0, ones,
         jnp.zeros((2,), jnp.int32), ones, ds, jnp.full((2,), 4, jnp.int32),
         jnp.full((2,), -1, jnp.int32), dummy_block=0)
