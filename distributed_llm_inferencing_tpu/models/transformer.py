@@ -514,8 +514,12 @@ def scan_layer_stack(make_body, x, params, cfg: ModelConfig, xs):
     ``make_body(seg_cfg)`` returns a ``lax.scan`` body
     ``(carry, (lp, *per_layer_xs)) -> (carry, per_layer_out)``;
     ``xs`` is a tuple of [L, ...]-stacked per-layer arrays (cache or
-    pool planes). Each segment scans its own stacked tree (or, for the
-    batcher's per-layer lists of MoE layers, loops Python-side);
+    pool planes; the decode chunks pass ``arange(L)``, so that a
+    segment's body gets its layers' indices in the whole stack), and
+    ``x`` any pytree the body carries (the decode chunks carry their
+    side buffers beside the hidden state). Each segment scans its own
+    stacked tree (or, for the batcher's per-layer lists of MoE layers,
+    loops Python-side);
     per-layer outputs are re-stacked and concatenated back to [L, ...]
     order. A body's ``seg_cfg`` names its layers' attention window as a
     constant where it can (_static_window_cfg). Returns (carry,
@@ -1207,22 +1211,50 @@ def _pool_pregather(paged, block_tables, dt):
 
 
 @jax.named_scope("kv_gather")
-def _layer_gather(pool, scales, block_tables, dt, kind=None):
+def _layer_gather(pool, scales, block_tables, dt, kind=None, layer=None):
     """One layer's planes gathered inside the step (long contexts, where
     the whole chunk's gather would pass _PREGATHER_MAX_BYTES); ``scales``
     is the layer's (k_scale, v_scale) for an int8 pool, else empty.
     ``kind`` (win | full) names the layer's kind as an inner scope where
-    the model has both."""
+    the model has both. Under a layer scan ``layer`` is the scan's index
+    and ``pool`` and ``scales`` are the stacked [L, NB, ...] planes as
+    they lie: the gather goes by (layer, block), so no layer's slice is
+    copied out of the stack on the way to a lax.switch branch. Where
+    layers are held one by one it is absent and the planes are that
+    layer's own."""
     from distributed_llm_inferencing_tpu.ops.paged_kvcache import (
         gather_seq, kind_scope)
     with kind_scope(kind):
-        got = tuple(gather_seq(p, block_tables) for p in pool)
+        got = tuple(gather_seq(p, block_tables, layer) for p in pool)
         if scales:
             from distributed_llm_inferencing_tpu.ops.kvcache import (
                 dequant_kv)
-            got = tuple(dequant_kv(g, gather_seq(sc, block_tables), dt)
-                        for g, sc in zip(got, scales))
+            got = tuple(
+                dequant_kv(g, gather_seq(sc, block_tables, layer), dt)
+                for g, sc in zip(got, scales))
     return got
+
+
+def _write_side(side, new, at, dt, layer=None):
+    """Put a pass's fresh rows ``new`` ([R, n, Hkv, w] a plane) into the
+    chunk's side buffers at entry ``at``. Returns (the buffers to carry
+    on, this layer's [R, K, Hkv, w] rows for attention's side segment).
+    Where layers are held one by one ``side`` is the layer's own slice
+    and the two are the same; under a layer scan ``side`` is the whole
+    [L, R, K, Hkv, w] stack riding the scan's carry and ``layer`` the
+    scan's index: the rows are written in place and the stack is never
+    sliced into per-layer inputs and stacked again from outputs."""
+    with jax.named_scope("kv_write"):
+        if layer is None:
+            rows = tuple(
+                jax.lax.dynamic_update_slice(s_, n_.astype(dt), (0, at, 0, 0))
+                for s_, n_ in zip(side, new))
+            return rows, rows
+        side = tuple(
+            jax.lax.dynamic_update_slice(s_, n_.astype(dt)[None],
+                                         (layer, 0, at, 0, 0))
+            for s_, n_ in zip(side, new))
+        return side, tuple(s_[layer] for s_ in side)
 
 
 def _layer_kind(cfg: ModelConfig, window):
@@ -1248,13 +1280,17 @@ def _pool_ladder(mb: int, scanned: bool = True):
     so a rung is at most 1.5 times the one below it from a quarter up
     (mistral's 128 -> 16/32/48/64/96/128, a toy 6 -> 1/2/3/5/6). A rung
     is a branch of one lax.switch in the layer body, not a program.
-    Under a layer scan (``scanned``) the branch reads the scan's slice
-    of the pool where it lies. Where layers are held one by one each
-    layer's slice of the stacked pool would be copied out as the
-    branch's operand on every pass, and a switch around the whole chunk
-    costs seconds a program at every start, so there the ladder is the
-    full extent alone: lax.switch inlines its one branch. PERF.md
-    section 6, PR 30, has the chip's numbers for each."""
+    A conditional takes its operands as buffers. Under a layer scan
+    (``scanned``) they are the stacked pool as it lies and the scan's
+    layer index, and the branch gathers by (layer, block)
+    (_layer_gather): handed the scan's slice of the pool instead, XLA
+    copied every layer's slice out of the stack on every pass (PERF.md
+    section 6, PR 36). Where layers are held one by one the slice is a
+    static one of the stacked pool, copied out the same way, and a
+    switch around the whole chunk costs seconds a program at every
+    start, so there the ladder is the full extent alone: lax.switch
+    inlines its one branch, and the gather fuses into attention.
+    PERF.md section 6, PR 30, has the chip's numbers for each."""
     if not scanned:
         return (mb,)
     return tuple(sorted({-(-mb * n // 8) for n in (1, 2, 3, 4, 6, 8)}))
@@ -1275,21 +1311,28 @@ def _pool_rung(ladder, bs: int, context_lens, live):
 
 
 def _attend_pool_rung(rung, ladder, pre: bool, planes, scales, block_tables,
-                      dt, pool_pos, pool_valid, attend_pool, kind=None):
+                      dt, pool_pos, pool_valid, attend_pool, kind=None,
+                      layer=None):
     """The pool side of a decode chunk's attention, as far as ``rung``
     says (lax.switch: only the taken branch runs). Branch i takes the
     first ``ladder[i]`` columns of the block tables -- a slice of the
     pre-gathered planes when ``pre``, else this layer's gather -- and
     calls ``attend_pool(planes, positions, valid)``, which brings the
-    side segment: all scores still meet in one softmax."""
+    side segment: all scores still meet in one softmax. Under a layer
+    scan ``layer`` is the scan's index and ``planes`` (and ``scales``)
+    are stacked [L, ...], taken by the branch as they lie
+    (_layer_gather)."""
     bs = pool_pos.shape[1] // block_tables.shape[1]
 
     def branch(mb_i):
         def run():
             n = mb_i * bs
-            got = (tuple(p[:, :n] for p in planes) if pre else
-                   _layer_gather(planes, scales, block_tables[:, :mb_i], dt,
-                                 kind))
+            if pre:
+                got = tuple(p[:, :n] if layer is None else p[layer, :, :n]
+                            for p in planes)
+            else:
+                got = _layer_gather(planes, scales, block_tables[:, :mb_i],
+                                    dt, kind, layer)
             return attend_pool(got, pool_pos[:, :n], pool_valid[:, :n])
         return run
     return jax.lax.switch(rung, [branch(m) for m in ladder])
@@ -1325,7 +1368,13 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
     *side buffer* [L, R, K, Hkv, hd] (dynamic_update_slice at step index)
     instead of two dynamic scatters into the block pool per layer per
     step, and the whole side buffer scatters into the pool in ONE op
-    after the scan. Each step's attention takes two KV segments,
+    after the scan. Under a layer scan no cache state rides the scan as
+    per-layer inputs and outputs: the scan brings the layer's index, the
+    side buffers ride its carry whole and are written in place at
+    (layer, 0, step, 0, 0) (_write_side), and the in-loop gather indexes
+    the stacked pool by (layer, block). Where layers are held one by one
+    (the batcher's MoE layers) each layer takes its own static slices,
+    as before. Each step's attention takes two KV segments,
     ``gather(pool) masked < cl0`` and ``side masked <= t``
     (ops/attention.attend): their scores meet in one softmax and K and V
     are never concatenated or widened. The pool is loop-invariant during
@@ -1390,7 +1439,8 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
     pool_pos = jnp.broadcast_to(jnp.arange(mb * bs, dtype=jnp.int32),
                                 (r, mb * bs))
     pool_valid = pool_pos < cl0[:, None]
-    ladder = _pool_ladder(mb, _layers_scanned(params, cfg))
+    scanned = _layers_scanned(params, cfg)
+    ladder = _pool_ladder(mb, scanned)
     rung, pool_positions = _pool_rung(ladder, bs, cl0, budget > 0)
     side_pos = cl0[:, None] + jnp.arange(k, dtype=jnp.int32)[None, :]
     side0 = (jnp.zeros((L, r, k, cfg.cache_kv_heads, cfg.cache_head_dim),
@@ -1402,8 +1452,10 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
     gathered_bytes = n_planes * dt.itemsize * L * r * mb * bs \
         * cfg.cache_kv_heads * cfg.cache_head_dim
     pre = gathered_bytes <= _PREGATHER_MAX_BYTES
-    pool = (_pool_pregather(paged, block_tables, dt) if pre
-            else paged.planes())         # gathered per layer in-loop
+    if pre:
+        pool, scales = _pool_pregather(paged, block_tables, dt), ()
+    else:                                # gathered per layer in-loop
+        pool, scales = paged.planes()[:n_planes], paged.planes()[n_planes:]
     # window -> (block ids, positions, validity) of the bounded read,
     # fixed for the chunk like the pool's horizon
     win_reads = {}
@@ -1426,16 +1478,14 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
             jnp.arange(k, dtype=jnp.int32)[None, :] <= t, (r, k))
 
         def make_layer(seg_cfg):
-            def layer(x, layer_in):
+            def layer(carry, layer_in):
                 lp, *rest = layer_in
-                sd, pl = rest[:n_planes], rest[n_planes:]
-
-                def write_side(*new):
-                    with jax.named_scope("kv_write"):
-                        return tuple(
-                            jax.lax.dynamic_update_slice(
-                                s_, n_.astype(dt), (0, t, 0, 0))
-                            for s_, n_ in zip(sd, new))
+                if scanned:   # the planes where they lie, by the index
+                    (x, sd), (li,), pl, sc = carry, rest, pool, scales
+                else:         # this layer's static slices
+                    x, li, sd = carry, None, rest[:n_planes]
+                    pl, sc = (rest[n_planes:2 * n_planes],
+                              rest[2 * n_planes:])
 
                 def attend_side(q, sd2, sliding_window=None, **kw):
                     kind = _layer_kind(cfg, sliding_window)
@@ -1450,41 +1500,50 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
                     if kind == "win" and sliding_window in win_reads:
                         bt_w, pos_w, valid_w = win_reads[sliding_window]
                         return attend_pool(
-                            _layer_gather(pl[:n_planes], pl[n_planes:],
-                                          bt_w, dt, kind), pos_w, valid_w)
+                            _layer_gather(pl, sc, bt_w, dt, kind, li),
+                            pos_w, valid_w)
                     return _attend_pool_rung(
-                        rung, ladder, pre, pl[:n_planes], pl[n_planes:],
-                        block_tables, dt, pool_pos, pool_valid, attend_pool,
-                        kind)
+                        rung, ladder, pre, pl, sc, block_tables, dt,
+                        pool_pos, pool_valid, attend_pool, kind, li)
+
+                def done(x2, out):
+                    # (side buffers..., moe): the buffers go on in the
+                    # scan's carry, or out as this layer's slices
+                    return ((x2, out[:-1]), out[-1:]) if scanned else (x2,
+                                                                       out)
 
                 tail = dict(valid=alive[:, None], moe_stats=True)
                 if latent:
                     def mla_latent_attend(h, qp):
-                        sd2 = write_side(
-                            _mla_latent_rows(h, lp, seg_cfg, qp))
+                        sd2, rows = _write_side(
+                            sd, (_mla_latent_rows(h, lp, seg_cfg, qp),), t,
+                            dt, li)
                         attn = _mla_absorbed(
                             _mla_q(h, lp, seg_cfg, qp), lp, seg_cfg,
                             lambda q_eff: attend_side(
-                                q_eff, sd2, scale=_mla_scale(seg_cfg)))
+                                q_eff, rows, scale=_mla_scale(seg_cfg)))
                         return attn, sd2
-                    return _block_body(x, lp, seg_cfg, q_pos, None,
-                                       mla_latent_attend=mla_latent_attend,
-                                       **tail)
+                    return done(*_block_body(
+                        x, lp, seg_cfg, q_pos, None,
+                        mla_latent_attend=mla_latent_attend, **tail))
 
                 def attend_write(q, kh, vh):
-                    sd2 = write_side(kh, vh)
+                    sd2, rows = _write_side(sd, (kh, vh), t, dt, li)
                     return attend_side(
-                        q, sd2, sliding_window=_layer_window(seg_cfg, lp),
+                        q, rows, sliding_window=_layer_window(seg_cfg, lp),
                         alibi=_alibi(seg_cfg), softcap=seg_cfg.attn_softcap,
                         sinks=_sinks(seg_cfg, lp)), sd2
-                return _block_body(x, lp, seg_cfg, q_pos, attend_write,
-                                   lora_ids=lora_ids, **tail)
+                return done(*_block_body(x, lp, seg_cfg, q_pos, attend_write,
+                                         lora_ids=lora_ids, **tail))
             return layer
 
-        xs = side + pool
-        if quantized and not pre:
-            xs = xs + (paged.k_scale, paged.v_scale)
-        x2, (*side, moe) = scan_layer_stack(make_layer, x, params, cfg, xs)
+        if scanned:
+            (x2, side), (moe,) = scan_layer_stack(
+                make_layer, (x, side), params, cfg,
+                (jnp.arange(L, dtype=jnp.int32),))
+        else:
+            x2, (*side, moe) = scan_layer_stack(make_layer, x, params, cfg,
+                                                side + pool + scales)
         logits = unembed(params, cfg, x2)[:, 0]
         with jax.named_scope("sample"):
             nxt = sample_batch(logits, seeds, steps0 + t, temps, tks, tps,
@@ -1642,7 +1701,8 @@ def paged_speculative_chunk(params, cfg: ModelConfig, k: int, gamma: int,
     pool_pos = jnp.broadcast_to(jnp.arange(mb * bs, dtype=jnp.int32),
                                 (r, mb * bs))
     pool_valid = pool_pos < cl0[:, None]
-    ladder = _pool_ladder(mb, _layers_scanned(params, cfg))
+    scanned = _layers_scanned(params, cfg)
+    ladder = _pool_ladder(mb, scanned)
     rung, _ = _pool_rung(ladder, bs, cl0, budget > 0)
     side0 = jnp.zeros((L, r, E, cfg.num_kv_heads, cfg.head_dim), dt)
     entry_step = jnp.arange(E, dtype=jnp.int32) // g1               # [E]
@@ -1651,9 +1711,9 @@ def paged_speculative_chunk(params, cfg: ModelConfig, k: int, gamma: int,
         * cfg.num_kv_heads * cfg.head_dim
     pre = gathered_bytes <= _PREGATHER_MAX_BYTES
     if pre:
-        pool_k, pool_v = _pool_pregather(paged, block_tables, dt)
-    else:
-        pool_k, pool_v = paged.k, paged.v   # gathered per layer in-loop
+        pool, scales = _pool_pregather(paged, block_tables, dt), ()
+    else:                                # gathered per layer in-loop
+        pool, scales = paged.planes()[:2], paged.planes()[2:]
 
     def body(carry, t):
         (cur, hist, hist_len, side_k, side_v, side_pos, acc_mask, cl,
@@ -1669,15 +1729,17 @@ def paged_speculative_chunk(params, cfg: ModelConfig, k: int, gamma: int,
         side_valid = acc_mask | is_cur_block
 
         def make_layer(seg_cfg):
-            def layer(x, layer_in):
-                lp, sk, sv, kp, vp, *scales = layer_in
+            def layer(carry, layer_in):
+                lp, *rest = layer_in
+                if scanned:   # as in paged_decode_chunk
+                    (x, sd), (li,), pl, sc = carry, rest, pool, scales
+                else:
+                    x, li, sd, pl, sc = (carry, None, rest[:2], rest[2:4],
+                                         rest[4:])
 
                 def attend_write(q, kh, vh):
-                    with jax.named_scope("kv_write"):
-                        sk2 = jax.lax.dynamic_update_slice(
-                            sk, kh.astype(dt), (0, t * g1, 0, 0))
-                        sv2 = jax.lax.dynamic_update_slice(
-                            sv, vh.astype(dt), (0, t * g1, 0, 0))
+                    sd2, (sk2, sv2) = _write_side(sd, (kh, vh), t * g1, dt,
+                                                  li)
 
                     def attend_pool(got, pos, valid):
                         with jax.named_scope("attention"):
@@ -1689,21 +1751,22 @@ def paged_speculative_chunk(params, cfg: ModelConfig, k: int, gamma: int,
                                 softcap=seg_cfg.attn_softcap,
                                 sinks=_sinks(seg_cfg, lp))
                     attn = _attend_pool_rung(
-                        rung, ladder, pre, (kp, vp), scales, block_tables,
-                        dt, pool_pos, pool_valid, attend_pool)
-                    return attn, (sk2, sv2)
+                        rung, ladder, pre, pl, sc, block_tables, dt,
+                        pool_pos, pool_valid, attend_pool, layer=li)
+                    return attn, sd2
 
-                x, (sk2, sv2) = _block_body(x, lp, seg_cfg, qp,
-                                            attend_write,
-                                            lora_ids=lora_ids)
-                return x, (sk2, sv2)
+                x2, sd2 = _block_body(x, lp, seg_cfg, qp, attend_write,
+                                      lora_ids=lora_ids)
+                return ((x2, sd2), ()) if scanned else (x2, sd2)
             return layer
 
-        xs = (side_k, side_v, pool_k, pool_v)
-        if quantized and not pre:
-            xs = xs + (paged.k_scale, paged.v_scale)
-        x2, (side_k, side_v) = scan_layer_stack(make_layer, x, params, cfg,
-                                                xs)
+        if scanned:
+            (x2, (side_k, side_v)), _ = scan_layer_stack(
+                make_layer, (x, (side_k, side_v)), params, cfg,
+                (jnp.arange(L, dtype=jnp.int32),))
+        else:
+            x2, (side_k, side_v) = scan_layer_stack(
+                make_layer, x, params, cfg, (side_k, side_v) + pool + scales)
         logits = unembed(params, cfg, x2)                 # [R, g1, V] f32
 
         # per-row acceptance (ops/speculative.py): greedy rows accept

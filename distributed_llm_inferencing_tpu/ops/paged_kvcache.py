@@ -124,9 +124,13 @@ def write_block_run(cache_layer, new_blocks, block_ids):
         reshaped.astype(cache_layer.dtype))
 
 
-def gather_seq(cache_layer, block_tables):
-    """[NB, bs, Hkv, hd] + [R, MB] -> contiguous [R, MB*bs, Hkv, hd]."""
-    g = cache_layer[block_tables]            # [R, MB, bs, Hkv, hd]
+def gather_seq(cache_layer, block_tables, layer=None):
+    """[NB, bs, Hkv, hd] + [R, MB] -> contiguous [R, MB*bs, Hkv, hd].
+    With ``layer`` (a scalar, traced under a layer scan) ``cache_layer``
+    is the stacked [L, NB, ...] plane and the gather's index is (layer,
+    block): the layer's slice is never taken out of the stack first."""
+    g = (cache_layer[block_tables] if layer is None
+         else cache_layer[layer, block_tables])   # [R, MB, bs, Hkv, hd]
     r, mb, bs = g.shape[0], g.shape[1], g.shape[2]
     return g.reshape(r, mb * bs, *g.shape[3:])
 

@@ -7,11 +7,10 @@ SamplingParams, and implements the pipeline as a jit-friendly pure function
 so it fuses into the decode step instead of running host-side per token.
 
 A row's top-k and nucleus cuts are two scalars (the k-th largest logit, the
-smallest logit of the nucleus). At serving sizes ``nucleus_thresholds`` finds
-both exactly by a search over the logits' bit patterns, a fixed number of
-masked reductions over ``[R, V]`` whatever the distribution's shape, and
-nothing sorts the vocabulary; a small ``[R, V]`` is still sorted
-(``_SORT_BELOW``).
+smallest logit of the nucleus). ``nucleus_thresholds`` finds both exactly by
+a search over the logits' bit patterns, a fixed number of masked reductions
+over ``[R, V]`` whatever the distribution's shape and at every size: nothing
+sorts the vocabulary.
 """
 
 from __future__ import annotations
@@ -95,25 +94,34 @@ def _largest_key_reaching(scaled, weight, floor, target):
                              jnp.zeros(scaled.shape[:1], jnp.uint32))
 
 
-# Under this many logits a pass, nucleus_thresholds sorts them instead. Not
-# because the sort is cheaper (16 x 32,000: 0.55 ms against the search's
-# 0.1): with no sort in it, mistral-7b's decode chunk compiles 1.2 ms a
-# pass slower on a v5e, because XLA's memory-space assignment then moves
-# the chunk's four side-buffer copies into VMEM and cycles them through HBM
-# in every step of the layer loop (PERF.md section 6, PR 28). Goes when
-# the layer scan carries the side buffers once (ROADMAP S4 (b)).
-_SORT_BELOW = 1 << 20
+def nucleus_thresholds(scaled, k, top_ps):
+    """The two scalars a row's top-k ∩ top-p mask needs.
 
+    scaled: [R, V] f32 logits (temperature applied); k: [R] int32 in 1..V
+    (V = top-k off); top_ps: [R] f32. Returns (kth, thresh), both [R] f32:
+    ``kth`` cuts the row to its k largest logits (the k-th largest; the
+    search gives -inf where no row of the batch has k < V: nothing to cut),
+    ``thresh`` is the smallest logit of the nucleus — the least value ``v``
+    of the row such that the probability of the logits strictly above
+    ``v``, renormalised over the top-k set, is below ``top_p`` (HF's
+    TopPLogitsWarper: the token that crosses ``top_p`` is kept). The
+    sampling support is ``scaled >= max(kth, thresh)``. Both come from
+    ``_largest_key_reaching`` and the row is never sorted;
+    tests/test_sampling.py holds them to one descending sort.
 
-def _thresholds_by_sort(scaled, k, top_ps):
-    sorted_desc = jnp.sort(scaled, axis=-1)[:, ::-1]
-    kth = jnp.take_along_axis(sorted_desc, (k - 1)[:, None], axis=-1)
-    in_top_k = jnp.sum(sorted_desc >= kth, axis=-1, keepdims=True)
-    _, thresh = nucleus_mask_sorted(sorted_desc, in_top_k, top_ps[:, None])
-    return kth[:, 0], thresh[:, 0]
+    Two things to know:
 
-
-def _thresholds_by_search(scaled, k, top_ps):
+    1. The mass before a token is a float32 sum: a masked sum in memory
+       order here, a cumulative sum in sorted order in a sort-based
+       form. Both round the same real number; where it lies within
+       float32 summation error of ``top_p`` the boundary token may fall
+       on either side.
+    2. The top-k set is ``{x >= kth}``: a run of equal logits across the
+       k-th place belongs to it whole (it is what the mask by value keeps),
+       and the nucleus is normalised over that set, i.e. over exactly the
+       tokens that can be drawn. (Until PR 28 the sort normalised over k
+       positions, part of such a run, and still kept the whole run.)
+    """
     v = scaled.shape[-1]
     top = jnp.max(scaled, axis=-1)
     neg_inf = jnp.full_like(top, -jnp.inf)
@@ -128,38 +136,6 @@ def _thresholds_by_search(scaled, k, top_ps):
     # top_p <= 0 reaches its target at every key: the top token stays
     t = jnp.clip(t, jnp.uint32(_KEY_NEG_INF), _float_keys(top))
     return kth, _keys_to_float(t)
-
-
-def nucleus_thresholds(scaled, k, top_ps):
-    """The two scalars a row's top-k ∩ top-p mask needs.
-
-    scaled: [R, V] f32 logits (temperature applied); k: [R] int32 in 1..V
-    (V = top-k off); top_ps: [R] f32. Returns (kth, thresh), both [R] f32:
-    ``kth`` cuts the row to its k largest logits (the k-th largest; the
-    search gives -inf where no row of the batch has k < V: nothing to cut),
-    ``thresh`` is the smallest logit of the nucleus — the least value ``v``
-    of the row such that the probability of the logits strictly above
-    ``v``, renormalised over the top-k set, is below ``top_p`` (HF's
-    TopPLogitsWarper: the token that crosses ``top_p`` is kept). The
-    sampling support is ``scaled >= max(kth, thresh)``. From
-    ``_SORT_BELOW`` logits up both come from ``_largest_key_reaching``, and
-    the row is never sorted; below, from one descending sort.
-
-    Two things to know, in either form:
-
-    1. The mass before a token is a float32 sum: a masked sum in memory
-       order (search) or a cumulative sum in sorted order (sort). Both
-       round the same real number; where it lies within float32 summation
-       error of ``top_p`` the boundary token may fall on either side.
-    2. The top-k set is ``{x >= kth}``: a run of equal logits across the
-       k-th place belongs to it whole (it is what the mask by value keeps),
-       and the nucleus is normalised over that set, i.e. over exactly the
-       tokens that can be drawn. (Until PR 28 the sort normalised over k
-       positions, part of such a run, and still kept the whole run.)
-    """
-    by_sort = scaled.shape[0] * scaled.shape[1] < _SORT_BELOW
-    return (_thresholds_by_sort if by_sort
-            else _thresholds_by_search)(scaled, k, top_ps)
 
 
 def _mask_top_p(logits, p: float):

@@ -19,11 +19,13 @@ windowed read), with shapes from
 what ``compiled.memory_analysis()`` says each device must hold and the
 collectives in the program text. With ``TEXT_DIR=<dir>`` each program's
 text goes there too, less what names source lines (each instruction's
-``metadata={...}`` and the tables of files, functions and stack frames
-at the top), so that two trees' programs compare with ``diff -r``. What the chip's compiler refuses, it
-refuses here. A compile that passes is not a chip run: this gives bytes,
-never a time. It loads libtpu, so run it while no test run needs
-``tests/test_tpu_compile.py``.
+``metadata={...}``, the tables of files, functions and stack frames at
+the top, and the call-site locations inside a Mosaic kernel's
+serialized module, in whose place goes the hash of the module printed
+without them), so that two trees' programs compare with ``diff -r``.
+What the chip's compiler refuses, it refuses here. A compile that passes
+is not a chip run: this gives bytes, never a time. It loads libtpu, so
+run it while no test run needs ``tests/test_tpu_compile.py``.
 """
 
 import os
@@ -73,6 +75,32 @@ TEXT_DIR = os.environ.get("TEXT_DIR")
 GIB = 2.0 ** 30
 
 
+def _kernel_hash(match):
+    """A Mosaic kernel's serialized module (``"body": "<base64>"`` in its
+    custom call's backend_config) holds the source locations of its call
+    sites, line numbers of the model code included: replace it by the
+    hash of the module printed without locations."""
+    import base64
+    import hashlib
+    from jax._src.interpreters.mlir import make_ir_context
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+    with make_ir_context() as ctx:
+        tpu.register_dialect(ctx)
+        ctx.allow_unregistered_dialects = True
+        asm = ir.Module.parse(base64.b64decode(match.group(1))) \
+            .operation.get_asm(enable_debug_info=False)
+    return '"body":"sha256:%s"' % hashlib.sha256(asm.encode()).hexdigest()
+
+
+def scrub(text):
+    """The program text less what names source lines."""
+    text = re.sub(r"^FileNames\n.*?^StackFrames\n(?:\d+ \{[^\n]*\}\n)*", "",
+                  text, flags=re.S | re.M)
+    text = re.sub(r", metadata=\{[^}]*\}", "", text)
+    return re.sub(r'"body": *"([A-Za-z0-9+/=]+)"', _kernel_hash, text)
+
+
 def report(name, lowered, t0):
     compiled = lowered.compile()
     mem, text = compiled.memory_analysis(), compiled.as_text()
@@ -80,10 +108,7 @@ def report(name, lowered, t0):
         os.makedirs(TEXT_DIR, exist_ok=True)
         with open(os.path.join(
                 TEXT_DIR, re.sub(r"[^A-Za-z0-9=_]+", "_", name)), "w") as f:
-            text = re.sub(
-                r"^FileNames\n.*?^StackFrames\n(?:\d+ \{[^\n]*\}\n)*", "",
-                text, flags=re.S | re.M)
-            f.write(re.sub(r", metadata=\{[^}]*\}", "", text))
+            f.write(scrub(text))
     print(f"{name}: {time.time() - t0:.1f}s to compile; per device "
           f"arguments {mem.argument_size_in_bytes / GIB:.2f} GiB, "
           f"transient {mem.temp_size_in_bytes / GIB:.2f} GiB "

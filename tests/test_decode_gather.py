@@ -87,8 +87,16 @@ CASES = {
     "lora": lambda: (_llama(), _lora, np.asarray([0, 1, 2, 0], np.int32)),
 }
 # the same configuration with its layers left stacked, under lax.scan as
-# the engine runs them: the pool ladder engages only there
+# the engine runs them: the pool ladder engages only there, and the
+# in-loop gather goes by (layer, block) from the stacked planes. A dense
+# layer ahead of the MoE layers makes two scanned segments: the second's
+# layer index starts where the first ended
 CASES["mla-latent-scanned"] = CASES["mla-latent"]
+# two segments again, K and V planes with an int8 pool's two scale planes
+# (indexed by layer like the rest), traced per-layer windows
+CASES["afmoe-int8-scanned"] = lambda: (
+    get_config("tiny-afmoe").replace(
+        dtype="bfloat16", attn_backend="xla", kv_quant="int8"), None, None)
 
 
 def _random_pool(cfg):
@@ -258,7 +266,8 @@ def test_decode_chunk_in_loop_gather_equals_pregathered(case, k, sampled):
 
 @pytest.mark.parametrize("sampled", [False, True],
                          ids=["greedy", "top-k"])
-@pytest.mark.parametrize("case", ["gqa-bf16", "gqa-int8-pool"])
+@pytest.mark.parametrize("case", ["gqa-bf16", "gqa-int8-pool",
+                                  "afmoe-int8-scanned"])
 def test_speculative_chunk_in_loop_gather_equals_pregathered(case, sampled):
     # accept_rejection_batch covers sampled rows whose top_k lies inside
     # the prefix tier; the cells' top-p-only rows draw one token a pass
@@ -320,7 +329,11 @@ RUNG_CONTEXTS = {
     40: [0, 13, 25, 30],
     48: [0, 13, 25, 41],      # slot 3's budget of 5 still fits its table
 }
-RUNG_CASES = ["gqa-bf16", "sinks", "gqa-int8-pool", "mla-latent-scanned"]
+RUNG_CASES = ["gqa-bf16", "sinks", "gqa-int8-pool", "mla-latent-scanned",
+              "afmoe-int8-scanned"]
+# (case, passes a chunk); a chunk of one pass writes its side buffer once
+RUNG_CHUNKS = [(c, 4) for c in RUNG_CASES] + [
+    ("gqa-int8-pool", 1), ("mla-latent-scanned", 1)]
 
 
 def _run_rung(make, case, k, in_loop, context, rows, eos_of):
@@ -340,19 +353,30 @@ def _run_rung(make, case, k, in_loop, context, rows, eos_of):
 @pytest.mark.parametrize("sampled", [False, True], ids=["greedy", "top-p"])
 @pytest.mark.parametrize("form", ["pregathered", "in-loop"])
 @pytest.mark.parametrize("positions", list(RUNG_CONTEXTS))
-@pytest.mark.parametrize("case", RUNG_CASES)
-def test_decode_chunk_rung_equals_full_extent(case, positions, form,
+@pytest.mark.parametrize("case, k", RUNG_CHUNKS,
+                         ids=[f"{c}-k{k}" for c, k in RUNG_CHUNKS])
+def test_decode_chunk_rung_equals_full_extent(case, k, positions, form,
                                               sampled):
-    k = 4
     got, want, pool_a, pool_b = _run_rung(
         _decode_chunk, case, k, form == "in-loop",
         RUNG_CONTEXTS[positions], _sampling_rows(sampled),
         lambda toks: toks[k // 2, 2])
     assert got[3] == positions and want[3] == MB * BS
-    for x, y in zip(got[:3], want[:3]):               # toks, emits, moe
+    for x, y in zip(got[1:3], want[1:3]):             # emits, moe
         np.testing.assert_array_equal(x, y)
+    # toks: what a slot computes after its eos is nobody's (``emits``
+    # masks it) and tiny-afmoe computes something else there from a
+    # shorter pool (slot 2's last pass; the parent's program did too):
+    # there, hold the passes a slot was alive in, elsewhere every token
+    emits = got[1].astype(bool)
+    dead_differ = case == "afmoe-int8-scanned"
+    alive = (np.vstack([emits[:1] | True, emits[:-1]]) if dead_differ
+             else np.ones_like(emits))
+    np.testing.assert_array_equal(np.where(alive, got[0], 0),
+                                  np.where(alive, want[0], 0))
     assert got[1][:, 1].all() and not got[1][:, 2].all()   # eos took slot 2
-    _assert_pools_close(pool_a, pool_b)
+    # a dead slot's rows land in the reserved block
+    _assert_pools_close(pool_a, pool_b, skip_dummy=dead_differ)
 
 
 @pytest.mark.parametrize("form", ["pregathered", "in-loop"])
@@ -408,6 +432,41 @@ def test_a_slot_without_budget_does_not_raise_the_rung(make, form):
     np.testing.assert_array_equal(np.where(mask, got[0], 0),
                                   np.where(mask, want[0], 0))
     _assert_pools_close(pool_a, pool_b, skip_dummy=True)
+
+
+def _cond_operand_shapes(jaxpr):
+    """The shape of every operand of every ``cond`` (lax.switch) of a
+    jaxpr, those of nested jaxprs (the scans' bodies) included."""
+    shapes = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "cond":
+            shapes += [tuple(v.aval.shape) for v in eqn.invars[1:]]
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            shapes += _cond_operand_shapes(sub)
+    return shapes
+
+
+@pytest.mark.parametrize("make, case", [
+    (_decode_chunk, "gqa-bf16"), (_decode_chunk, "gqa-int8-pool"),
+    (_decode_chunk, "mla-latent-scanned"),
+    (_spec_chunk, "gqa-bf16"), (_spec_chunk, "gqa-int8-pool"),
+], ids=["plain-bf16", "plain-int8-pool", "plain-latent-two-segments",
+        "speculative-bf16", "speculative-int8-pool"])
+def test_no_operand_of_the_switch_is_one_layers_pool(make, case):
+    """A conditional takes its operands as buffers: a layer's slice of
+    the pool handed to the rung's lax.switch is copied out of the stack
+    on every pass of every layer (3 ms of mistral-7b's 17.9 ms pass on a
+    v5e: PERF.md section 6, PR 36). Under a layer scan the in-loop
+    gather's branches take the stacked planes as they lie and the layer's
+    index."""
+    chunk, inputs, _ = make(case, 3)
+    args = (inputs(CONTEXT), NO_EOS) + _sampling_rows(True, top_k=20)
+    with mock.patch.object(transformer, "_PREGATHER_MAX_BYTES", 0):
+        shapes = set(_cond_operand_shapes(jax.make_jaxpr(chunk)(*args).jaxpr))
+    planes = _setup(case)[2].planes()
+    assert {tuple(p.shape) for p in planes} <= shapes
+    assert () in shapes                               # the layer's index
+    assert not {tuple(p.shape[1:]) for p in planes} & shapes
 
 
 def test_batcher_on_the_in_loop_gather_emits_the_engines_tokens(monkeypatch):

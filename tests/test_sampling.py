@@ -6,9 +6,10 @@ row's draw never depends on its chunk-mates' configs, the property the
 scheduler's reproducibility contract rests on, (c) that the prefix
 fast path samples the same *distribution* the full-vocab path does, and
 (d) the full tier's thresholds (``nucleus_thresholds``: a search over bit
-patterns from ``_SORT_BELOW`` logits up, a sort below) in both forms
-against a float64 oracle, and the search against the sort-based full tier
-it replaced, kept here as ``_sorted_full_draw``.
+patterns at every size) against a float64 oracle and against the one
+descending sort it replaced, kept here as the reference
+(``_thresholds_by_sort``, ``_sorted_full_draw``), at the benchmark cells'
+sizes too.
 """
 
 import jax
@@ -118,7 +119,7 @@ def test_prefix_path_matches_full_distribution():
     np.testing.assert_allclose(counts[:k] / n, p, atol=0.04)
 
 
-# ---- the full tier: thresholds by search, or by sort when small --------
+# ---- the full tier: thresholds by search, held to a sort ---------------
 
 V = 2000
 ROW_KINDS = ("flat", "peaked", "bf16_ties", "neg_inf")
@@ -155,8 +156,19 @@ def _oracle(x, k, p):
     return in_topk & (above < p), ~in_topk | (np.abs(above - p) > MARGIN)
 
 
-FORMS = {"search": jax.jit(sampling._thresholds_by_search),
-         "sort": jax.jit(sampling._thresholds_by_sort)}
+def _thresholds_by_sort(scaled, k, top_ps):
+    """``nucleus_thresholds`` by one descending sort of the vocabulary:
+    what ops/sampling.py ran under 2**20 logits a pass until PR 36, kept
+    as the reference the search is held to."""
+    sorted_desc = jnp.sort(scaled, axis=-1)[:, ::-1]
+    kth = jnp.take_along_axis(sorted_desc, (k - 1)[:, None], axis=-1)
+    in_top_k = jnp.sum(sorted_desc >= kth, axis=-1, keepdims=True)
+    _, thresh = nucleus_mask_sorted(sorted_desc, in_top_k, top_ps[:, None])
+    return kth[:, 0], thresh[:, 0]
+
+
+FORMS = {"search": jax.jit(sampling.nucleus_thresholds),
+         "sort": jax.jit(_thresholds_by_sort)}
 
 
 def _kept(x, k, p, form):
@@ -166,14 +178,6 @@ def _kept(x, k, p, form):
         jnp.full((r,), p, jnp.float32))
     cut = np.maximum(np.asarray(kth), np.asarray(thresh))
     return x >= cut[:, None], np.asarray(kth), np.asarray(thresh)
-
-
-@pytest.fixture
-def search_sample(monkeypatch):
-    """sample_batch jitted with the search at any size (the tests' rows
-    are far under _SORT_BELOW, where it would sort)."""
-    monkeypatch.setattr(sampling, "_SORT_BELOW", 0)
-    return jax.jit(sample_batch)
 
 
 @pytest.mark.parametrize("form", list(FORMS))
@@ -211,16 +215,18 @@ def _sorted_full_draw(logits, seeds, steps, temps, top_ks, top_ps):
         lambda kk, l: jax.random.categorical(kk, l))(keys, masked)
 
 
-@pytest.mark.parametrize("block", range(4))
-def test_tokens_equal_the_sorted_form_at_the_cells_settings(block,
-                                                            search_sample):
-    """256 random rows in four blocks of 64 (a decode pass of the kanana
-    cell) at the benchmark's settings, temperature 0.7, top-p 0.9, top-k
-    off: the drawn token is the sort-based form's, bit for bit, in every
-    row the oracle is sure of (a flat row's tokens weigh about 1e-4
-    each, so in a quarter of such rows some token's mass lies within the
-    margin of 0.9; even there the tokens rarely differ)."""
-    r, v = 64, 8192
+@pytest.mark.parametrize("block, r, v", [
+    (0, 64, 8192), (1, 64, 8192), (2, 64, 8192), (3, 64, 8192),
+    (4, 16, 32000), (5, 16, 32000),      # a pass of the mistral cells
+])
+def test_tokens_equal_the_sorted_form_at_the_cells_settings(block, r, v):
+    """Random rows in blocks of 64 (a decode pass of the kanana cell) and
+    of 16 x 32,000 (one of the mistral cells, which sorted until PR 36)
+    at the benchmark's settings, temperature 0.7, top-p 0.9, top-k off:
+    the drawn token is the sort-based form's, bit for bit, in every row
+    the oracle is sure of (a flat row's tokens weigh about 1e-4 each, so
+    in a quarter of such rows some token's mass lies within the margin
+    of 0.9; even there the tokens rarely differ)."""
     kind = ROW_KINDS[block % 3]
     x = _rows(kind, r, v, seed=10 + block)
     seeds = jnp.arange(r, dtype=jnp.int32) + 1000 * block
@@ -228,8 +234,8 @@ def test_tokens_equal_the_sorted_form_at_the_cells_settings(block,
     temps = jnp.full((r,), 0.7, jnp.float32)
     tks = jnp.zeros((r,), jnp.int32)
     tps = jnp.full((r,), 0.9, jnp.float32)
-    got = np.asarray(search_sample(jnp.asarray(x), seeds, steps, temps, tks,
-                                   tps, jnp.ones((r,), bool)))
+    got = np.asarray(_jit_sample(jnp.asarray(x), seeds, steps, temps, tks,
+                                 tps, jnp.ones((r,), bool)))
     want = np.asarray(jax.jit(_sorted_full_draw)(x, seeds, steps, temps,
                                                  tks, tps))
     scaled = x / np.float32(0.7)
@@ -246,13 +252,17 @@ def _primitives(jaxpr):
             yield from _primitives(sub)
 
 
+# rows x vocabulary of a decode pass in the benchmark's cells
+CELL_SIZES = [(16, 32000), (64, 128256), (64, 200192)]
+CELL_IDS = ["mistral", "kanana", "trinity"]
+
+
 @pytest.mark.parametrize("name", ["sample_batch", "sample", "warp_logits"])
-@pytest.mark.parametrize("rows,vocab,sorts", [(64, 128256, False),
-                                              (16, 32000, True)])
-def test_no_path_sorts_a_large_vocabulary(name, rows, vocab, sorts):
-    """At the kanana cell's 64 x 128,256 no sampling path holds a `sort`;
-    at the mistral cells' 16 x 32,000, under _SORT_BELOW, the thresholds
-    still come from one."""
+@pytest.mark.parametrize("rows,vocab", CELL_SIZES, ids=CELL_IDS)
+def test_no_path_sorts_the_vocabulary(name, rows, vocab):
+    """At no cell's size does a sampling path hold a `sort`: not at the
+    expert cells' 64 x 128,256 and 64 x 200,192, and since PR 36 not at
+    the mistral cells' 16 x 32,000 either."""
     x = jax.ShapeDtypeStruct((rows, vocab), jnp.float32)
     i = jax.ShapeDtypeStruct((rows,), jnp.int32)
     f = jax.ShapeDtypeStruct((rows,), jnp.float32)
@@ -266,12 +276,39 @@ def test_no_path_sorts_a_large_vocabulary(name, rows, vocab, sorts):
     else:
         jaxpr = jax.make_jaxpr(lambda x: warp_logits(x, sp))(x)
     prims = set(_primitives(jaxpr.jaxpr))
-    assert ("sort" in prims) == sorts, prims
-    assert bool(prims & {"while", "scan"}) != sorts   # the search's loop
+    assert "sort" not in prims, prims
+    assert prims & {"while", "scan"}                  # the search's loop
+
+
+@pytest.mark.parametrize("kind", ["flat", "bf16_ties"])
+@pytest.mark.parametrize("rows,vocab", CELL_SIZES, ids=CELL_IDS)
+def test_thresholds_equal_the_sort_at_the_cells_sizes(rows, vocab, kind):
+    """The search against the reference sort at each cell's rows x
+    vocabulary and settings (temperature 0.7, top-p 0.9, top-k off, and
+    top-k 500 beside it): ``kth`` is an order statistic and equal bit
+    for bit; the two nucleus cuts keep the same tokens wherever the
+    float64 oracle is sure (docstring, 1.: within float32 summation
+    error of ``top_p`` the boundary token may fall on either side)."""
+    x = _rows(kind, rows, vocab, seed=7) / np.float32(0.7)
+    for k in (0, 500):
+        kept = {}
+        for form in FORMS:
+            kept[form], kth, _ = _kept(x, k, 0.9, form)
+            kept[form + "_kth"] = kth
+        if k:
+            np.testing.assert_array_equal(kept["search_kth"],
+                                          kept["sort_kth"])
+        for r in range(0, rows, max(1, rows // 4)):   # the oracle is slow
+            want, sure = _oracle(x[r], k, 0.9)
+            np.testing.assert_array_equal(kept["search"][r][sure],
+                                          want[sure])
+            np.testing.assert_array_equal(kept["search"][r][sure],
+                                          kept["sort"][r][sure])
+        assert (kept["search"] != kept["sort"]).sum() <= rows
 
 
 @pytest.mark.parametrize("mate", ["covered", "uncovered", "greedy"])
-def test_full_tier_row_independent_of_chunk_mates(mate, search_sample):
+def test_full_tier_row_independent_of_chunk_mates(mate):
     """A full-tier row (top-k off) draws the same token whatever its
     chunk-mate asks for: a prefix-tier row, another full-tier row with
     its own k and p, or a greedy row."""
@@ -281,7 +318,7 @@ def test_full_tier_row_independent_of_chunk_mates(mate, search_sample):
                   "uncovered": (PREFIX_K + 7, 0.5, True),
                   "greedy": (0, 1.0, False)}[mate]
     def draw(step, temps, tks, tps, ds):
-        return np.asarray(search_sample(
+        return np.asarray(_jit_sample(
             jnp.asarray(logits), jnp.asarray([21, 22], jnp.int32),
             jnp.full((2,), step, jnp.int32), jnp.asarray(temps, jnp.float32),
             jnp.asarray(tks, jnp.int32), jnp.asarray(tps, jnp.float32),

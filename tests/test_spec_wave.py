@@ -24,7 +24,6 @@ from distributed_llm_inferencing_tpu.utils import clock
 
 CFG = get_config("tiny-llama").replace(dtype="float32", attn_backend="xla")
 PARAMS = init_params(CFG, jax.random.PRNGKey(0), dtype=jnp.float32)
-RNG = np.random.default_rng(17)
 
 
 def _drain(b, reqs, limit=600):
@@ -73,7 +72,10 @@ def _mk(speculative="ngram", slots=4, spec_gamma=3,
     return b
 
 
-def _repetitive(n=24, rng=RNG):
+def _repetitive(rng, n=24):
+    """A prompt that repeats four tokens drawn from ``rng``: every test
+    brings a generator of its own, so its prompts do not depend on which
+    tests the process ran before it."""
     base = rng.integers(0, CFG.vocab_size, 4).tolist()
     return (base * (n // 4 + 2))[:n]
 
@@ -93,8 +95,9 @@ def test_greedy_bitwise_wave_and_plain():
     """Greedy outputs identical across the plain batcher and wave
     speculation — mixed repetitive/random prompts so both
     accepted-heavy and miss-heavy slots are exercised."""
-    prompts = [_repetitive(), RNG.integers(0, 256, 11).tolist(),
-               _repetitive(20), RNG.integers(0, 256, 7).tolist()]
+    rng = np.random.default_rng(17)
+    prompts = [_repetitive(rng), rng.integers(0, 256, 11).tolist(),
+               _repetitive(rng, 20), rng.integers(0, 256, 7).tolist()]
     plain, _ = _run(ContinuousBatcher(CFG, PARAMS, num_blocks=256,
                                       block_size=8, slots=4, max_seq=160),
                     prompts)
@@ -105,8 +108,9 @@ def test_greedy_bitwise_wave_and_plain():
 def test_wave_drafts_actually_accept():
     """On a repetitive workload the wave path must land accepted drafts
     (tokens-per-weight-pass > 1) and count them in the wave metrics."""
+    rng = np.random.default_rng(17)
     b = _mk()
-    prompts = [_repetitive() for _ in range(4)]
+    prompts = [_repetitive(rng) for _ in range(4)]
     _run(b, prompts, n=32)
     snap = b.metrics.snapshot()["counters"]
     assert snap.get("spec_wave_dispatches", 0) > 0
@@ -132,12 +136,8 @@ def test_hostile_slot_rides_wave_while_friendly_keeps_drafting(tick_clock):
     (uncovered rows draw the plain chunk's exact sample)."""
     sp_hostile = SamplingParams(temperature=1.0, top_k=0, top_p=1.0)
     b = _mk()
-    # its own generator: with the module's RNG the prompts depended on
-    # which tests had drawn from it before (alone: draft-friendly; after
-    # the not-slow selection of this file: one prompt whose greedy
-    # continuation never repeats, and its controller rightly fell back)
     rng = np.random.default_rng(17)
-    friendly = [b.submit(_repetitive(rng=rng), max_new_tokens=48,
+    friendly = [b.submit(_repetitive(rng), max_new_tokens=48,
                          sampling=SamplingParams.greedy(), seed=10 + i)
                 for i in range(3)]
     hostile_prompt = rng.integers(0, CFG.vocab_size, 24).tolist()
@@ -172,8 +172,9 @@ def test_all_hostile_wave_falls_back_to_true_plain_chunks():
     """When EVERY request converges to width 0 the step runs real plain
     programs (not degenerate all-zero verify passes) — visible as plain
     controller modes and bit-identical output."""
+    rng = np.random.default_rng(17)
     sp = SamplingParams(temperature=1.0, top_k=0, top_p=1.0)
-    prompts = [RNG.integers(0, CFG.vocab_size, 20).tolist()
+    prompts = [rng.integers(0, CFG.vocab_size, 20).tolist()
                for _ in range(4)]
     b = _mk()
     toks, reqs = _run(b, prompts, n=40, sampling=sp, seed0=300)
@@ -202,8 +203,9 @@ def test_zero_gamma_wave_runs_plain_without_controllers():
 def test_fixed_width_wave_without_adaptivity():
     """spec_adaptive=False pins every slot at the full static width —
     wave dispatches happen, no controllers exist, greedy parity holds."""
+    rng = np.random.default_rng(17)
     b = _mk(spec_adaptive=False)
-    prompts = [_repetitive(), _repetitive(20)]
+    prompts = [_repetitive(rng), _repetitive(rng, 20)]
     toks, reqs = _run(b, prompts, n=16)
     for r in reqs:
         assert r._spec_ctl is None
@@ -218,8 +220,9 @@ def test_fixed_width_wave_without_adaptivity():
 
 
 def test_cost_ledger_attributes_draft_and_verify_tokens():
+    rng = np.random.default_rng(17)
     b = _mk()
-    prompts = [_repetitive() for _ in range(4)]
+    prompts = [_repetitive(rng) for _ in range(4)]
     _, reqs = _run(b, prompts, n=32)
     for r in reqs:
         cost = r.cost
@@ -238,8 +241,9 @@ def test_cost_ledger_attributes_draft_and_verify_tokens():
 
 
 def test_spec_wave_stats_surface():
+    rng = np.random.default_rng(17)
     b = _mk()
-    reqs = [b.submit(_repetitive(), max_new_tokens=24,
+    reqs = [b.submit(_repetitive(rng), max_new_tokens=24,
                      sampling=SamplingParams.greedy(), seed=5)]
     for _ in range(3):
         b.step()
@@ -258,6 +262,7 @@ def test_wave_metrics_reach_tsdb_catalog():
     counters (as rates) in the catalog — including BEFORE any decode ran
     (the batcher pre-registers them at 0, so 'no samples yet' can never
     read as 'metric not exported')."""
+    rng = np.random.default_rng(17)
     from distributed_llm_inferencing_tpu.runtime.tsdb import TSDB
     from distributed_llm_inferencing_tpu.utils.metrics import (
         parse_prometheus)
@@ -271,7 +276,7 @@ def test_wave_metrics_reach_tsdb_catalog():
     assert "spec_wave_accepted_tokens" in cat
     assert "spec_wave_drafted_tokens" in cat
     # after a run the gauge carries the amortization signal
-    _run(b, [_repetitive() for _ in range(2)], n=16)
+    _run(b, [_repetitive(rng) for _ in range(2)], n=16)
     ts.ingest_prometheus("w0", parse_prometheus(b.metrics.prometheus()),
                          t=101.0)
     pts = ts.query("decode_tokens_per_weight_pass", node="w0", now=102.0)
@@ -281,10 +286,11 @@ def test_wave_metrics_reach_tsdb_catalog():
 def test_profiler_tags_spec_phases():
     """/api/profile attribution: wave chunks must land their wall time
     in the spec_draft / spec_verify phases, not plain dispatch."""
+    rng = np.random.default_rng(17)
     from distributed_llm_inferencing_tpu.utils.profiler import PhaseProfiler
     b = _mk()
     b.profiler = PhaseProfiler(enabled=True, sample_every=1)
-    _run(b, [_repetitive() for _ in range(2)], n=16)
+    _run(b, [_repetitive(rng) for _ in range(2)], n=16)
     phases = b.profiler.summary()["phases"]
     assert "spec_verify" in phases, phases
     assert "spec_draft" in phases, phases
@@ -299,6 +305,7 @@ def test_wave_lockstep_broadcast_carries_widths_not_history():
     broadcasts ship per-slot widths + history DELTAS (never the full
     history), and a follower replaying the JSON'd programs reconstructs
     the leader's drafting history and emits identical programs."""
+    rng = np.random.default_rng(17)
     mk = lambda: ContinuousBatcher(  # noqa: E731
         CFG, PARAMS, num_blocks=64, block_size=8, slots=2, max_seq=96,
         seed=0, speculative="ngram", spec_gamma=3)
@@ -315,7 +322,7 @@ def test_wave_lockstep_broadcast_carries_widths_not_history():
         return run()
 
     leader.program_hook = hook
-    prompts = [_repetitive(20), RNG.integers(0, 256, 7).tolist()]
+    prompts = [_repetitive(rng, 20), rng.integers(0, 256, 7).tolist()]
     reqs = [leader.submit(p, max_new_tokens=12,
                           sampling=SamplingParams.greedy(), seed=9 + i)
             for i, p in enumerate(prompts)]
@@ -339,9 +346,9 @@ def test_wave_lockstep_broadcast_carries_widths_not_history():
 def test_wave_eos_and_stream_order():
     plain = ContinuousBatcher(CFG, PARAMS, num_blocks=64, block_size=8,
                               slots=2, max_seq=128, seed=0)
-    # its own generator (see the hostile-slot test): a prompt drawn from
-    # the shared RNG may loop on one token and leave no usable eos
-    prompt = _repetitive(18, rng=np.random.default_rng(17))
+    # seed 17: a prompt whose greedy continuation does not loop on one
+    # token, so it has a usable eos
+    prompt = _repetitive(np.random.default_rng(17), 18)
     r0 = plain.submit(prompt, max_new_tokens=10,
                       sampling=SamplingParams.greedy())
     _drain(plain, [r0])
@@ -370,7 +377,8 @@ def test_wave_sampled_distribution_against_noise_floor():
     """Sampled mode under wave widths: empirical distribution of the
     speculative-verified positions must sit within the plain-vs-plain
     sampling noise floor (same calibration as the pre-wave suite)."""
-    prompt = (RNG.integers(0, 256, 4).tolist() * 5)[:18]
+    rng = np.random.default_rng(17)
+    prompt = (rng.integers(0, 256, 4).tolist() * 5)[:18]
     sp = SamplingParams(temperature=1.2, top_k=8, top_p=0.95)
     n = 100
 

@@ -281,6 +281,37 @@ def test_expert_dispatch_is_a_grouped_matmul(compile_on_chip, model, tokens,
         <= 16 * rows * d + 32 * 2 ** 20
 
 
+def _decode_chunk_text(compile_on_chip, cfg, k, slots, bs, blocks, mb,
+                       kernel=True):
+    """The text of ``paged_decode_chunk`` compiled for the described chip:
+    ``k`` passes over ``slots`` slots, a pool of ``blocks`` + 1 blocks of
+    ``bs`` (the last the reserved one), ``mb`` block-table columns."""
+    from distributed_llm_inferencing_tpu.models import transformer
+    from distributed_llm_inferencing_tpu.models.params import init_params
+    from distributed_llm_inferencing_tpu.ops.paged_kvcache import (
+        PagedKVCache, init_paged_cache)
+
+    def shapes(tree):
+        return jax.tree.map(lambda s: (s.shape, s.dtype), tree)
+
+    params = shapes(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    pool = shapes(jax.eval_shape(
+        lambda: list(init_paged_cache(cfg, blocks + 1, bs).planes())))
+
+    def chunk(params, pool, tokens, bt, ints, floats, ds):
+        cl, seeds, steps, tks, budget, eos = ints
+        return transformer.paged_decode_chunk(
+            params, cfg, k, tokens, PagedKVCache(*pool), bt, cl, seeds,
+            steps, floats[0], tks, floats[1], ds, budget, eos, blocks)
+
+    return compile_on_chip(
+        chunk, params, pool, ((slots,), jnp.int32),
+        ((slots, mb), jnp.int32), ((6, slots), jnp.int32),
+        ((2, slots), jnp.float32), ((slots,), jnp.bool_),
+        kernel=kernel).as_text()
+
+
 def test_decode_chunk_sorts_nothing_vocabulary_sized(compile_on_chip):
     """A decode chunk of the kanana cell (64 slots over 128,256 logits,
     the dense layer and one expert layer, the latent pool) samples
@@ -290,36 +321,34 @@ def test_decode_chunk_sorts_nothing_vocabulary_sized(compile_on_chip):
     held `sort(f32[64,128256])`, a third of the pass on the chip
     (PERF.md section 6). The expert dispatch's sort of its 384 (token,
     choice) pairs stays."""
-    from distributed_llm_inferencing_tpu.models import transformer
-    from distributed_llm_inferencing_tpu.models.params import init_params
-    from distributed_llm_inferencing_tpu.ops.paged_kvcache import (
-        PagedKVCache, init_paged_cache)
-    slots, bs, blocks, mb = 64, 16, 640, 160
     cfg = KANANA.replace(num_layers=2, attn_backend="xla",
                          mla_latent_cache=True)
-
-    def shapes(tree):
-        return jax.tree.map(lambda s: (s.shape, s.dtype), tree)
-
-    params = shapes(jax.eval_shape(
-        lambda: init_params(cfg, jax.random.PRNGKey(0))))
-    pool = shapes(jax.eval_shape(
-        lambda: init_paged_cache(cfg, blocks + 1, bs).k))
-
-    def chunk(params, pool, tokens, bt, ints, floats, ds):
-        cl, seeds, steps, tks, budget, eos = ints
-        return transformer.paged_decode_chunk(
-            params, cfg, 1, tokens, PagedKVCache(k=pool), bt, cl, seeds,
-            steps, floats[0], tks, floats[1], ds, budget, eos, blocks)
-
-    compiled = compile_on_chip(
-        chunk, params, pool, ((slots,), jnp.int32),
-        ((slots, mb), jnp.int32), ((6, slots), jnp.int32),
-        ((2, slots), jnp.float32), ((slots,), jnp.bool_))
-    sorts = re.findall(r"= \(?([^=\n]*?)\)? sort\(", compiled.as_text())
+    text = _decode_chunk_text(compile_on_chip, cfg, k=1, slots=64, bs=16,
+                              blocks=640, mb=160)
+    sorts = re.findall(r"= \(?([^=\n]*?)\)? sort\(", text)
     assert sorts, "the expert dispatch's sort should be in the program"
     wide = [s for s in sorts
             if any(int(d) >= cfg.vocab_size
                    for dims in re.findall(r"\[([\d,]+)\]", s)
                    for d in dims.split(","))]
     assert not wide, f"a sort over the vocabulary: {wide}"
+
+
+def test_decode_chunk_copies_no_layers_pool_out_of_the_stack(compile_on_chip):
+    """The decode-sat cell's decode chunk (mistral-7b, int8 weights, 16
+    slots, 8 passes, the pool of 1025 blocks gathered in the loop): under
+    the layer scan each rung's branch gathers by (layer, block) from the
+    stacked pool where it lies. While the scan handed the layer's slice
+    to the lax.switch, the program held two `dynamic-slice` fusions with
+    a result of `bf16[1025,16,8,128]`, a copy of every layer's K and V
+    pool on every pass: 3 ms of a 17.9 ms pass on the chip (PERF.md
+    section 6, PR 36). tests/test_decode_gather.py holds the same of the
+    jaxpr at toy widths."""
+    text = _decode_chunk_text(
+        compile_on_chip, CFG.replace(quant="int8", attn_backend="xla"),
+        k=8, slots=16, bs=BS, blocks=NB - 1, mb=MB, kernel=False)
+    assert "conditional(" in text, "the rungs' lax.switch should be there"
+    plane = f"bf16[{NB},{BS},{HKV},{HD}]"
+    made = re.findall(rf"%(\S+) = {re.escape(plane)}\S* (?!parameter|"
+                      rf"get-tuple-element)(\S+?)\(", text)
+    assert not made, f"one layer's plane is materialized: {made[:4]}"
