@@ -40,11 +40,12 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import json
 import logging
 import os
 import threading
 import time
-from typing import Callable, Dict, List, Optional, Sequence
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -69,7 +70,8 @@ from distributed_llm_inferencing_tpu.runtime import kvwire as kvwire_mod
 from distributed_llm_inferencing_tpu.runtime import tsdb as tsdb_mod
 from distributed_llm_inferencing_tpu.utils import clock, locks, trace
 from distributed_llm_inferencing_tpu.utils.metrics import Metrics
-from distributed_llm_inferencing_tpu.utils.profiler import PhaseProfiler
+from distributed_llm_inferencing_tpu.utils.profiler import (
+    PhaseProfiler, call_deltas, call_readings)
 
 log = logging.getLogger("dli.batcher")
 
@@ -116,6 +118,10 @@ class BatchRequest:
     # rides the request object instead of a contextvar
     trace_ctx: Optional[object] = None
     _last_emit_at: Optional[float] = None
+    # the scheduler's phase clocks and its stalls' sum when the first
+    # token was emitted: read again at finish, the differences split
+    # decode_ms by where it went (_decode_account)
+    _clocks0: Optional[tuple] = None
     # internal scheduling state
     _blocks: List[int] = dataclasses.field(default_factory=list)
     _preemptions: int = 0
@@ -262,6 +268,48 @@ class ContinuousBatcher:
     # Either loss counts from 50 ms on: under it, it is the scheduling
     # noise of a shared host and moves no end-to-end metric.
     STALL_FLOOR_S = 0.050
+    # Why it stood still (``cause`` on the event and the span,
+    # batcher_stall_cause_<cause>_ms): one fixed rule over what the
+    # process did during the stalled call (or step, for a host stall),
+    # each test against HALF of what was lost, so that whatever is named
+    # accounts for most of the loss, in this order because each later
+    # test is only meaningful once the earlier ones failed:
+    #   gc                     the cycle collector ran that long (it
+    #                          holds the interpreter, so it would also
+    #                          read as a late heartbeat);
+    #   interpreter_held       the heartbeat woke that late while the
+    #                          process burned CPU: some thread ran and
+    #                          kept the interpreter from the others;
+    #   descheduled            the heartbeat woke that late and nothing
+    #                          burned CPU: the whole process did not run
+    #                          (a frozen or starved container);
+    #   host_runtime_busy      the heartbeat was on time and the process
+    #                          burned CPU: threads that need no
+    #                          interpreter worked (the runtime's own);
+    #   device_or_runtime_wait every thread idle, heartbeat on time: the
+    #                          device ran long or the runtime waited on
+    #                          it (``memory`` and, where a trace runs,
+    #                          its XLA Modules line decide which);
+    #   thread_blocked         the same in a host stall, where no program
+    #                          was awaited: the scheduler thread waited
+    #                          in a host bracket (a stream callback, a
+    #                          lock).
+    STALL_CAUSES = ("gc", "interpreter_held", "descheduled",
+                    "host_runtime_busy", "device_or_runtime_wait",
+                    "thread_blocked")
+
+    @staticmethod
+    def _stall_cause(where: str, lost_ms: float, did: dict) -> str:
+        half = lost_ms / 2
+        if did["gc_ms"] >= half:
+            return "gc"
+        burned = did["process_cpu_ms"] >= half
+        if did["heartbeat_late_ms"] >= half:
+            return "interpreter_held" if burned else "descheduled"
+        if burned:
+            return "host_runtime_busy"
+        return ("device_or_runtime_wait" if where == "program"
+                else "thread_blocked")
 
     def __init__(self, cfg: ModelConfig, params=None, *,
                  num_blocks: int = 512, block_size: int = 16,
@@ -440,6 +488,10 @@ class ContinuousBatcher:
         # side); alert on dli_batcher_stall_*_ms_total
         self.metrics.inc("batcher_stall_program_ms", 0)
         self.metrics.inc("batcher_stall_host_ms", 0)
+        for cause in self.STALL_CAUSES:   # ... and by cause (_stall_cause)
+            self.metrics.inc(f"batcher_stall_cause_{cause}_ms", 0)
+        self._stall_lost_s = 0.0  # both sides' sum: a request reads it twice
+        self._wave_count = 0      # admit programs run (`wave` on their spans)
         self._pool_positions = 0  # the last decode chunk's (_run_decode)
         self._window_positions = 0   # ... and what a windowed layer read
         self._wave_cut = None   # (tail, prefix) group the bound last cut
@@ -452,8 +504,6 @@ class ContinuousBatcher:
                          "spec_wave_accepted_tokens",
                          "spec_wave_plain_rides"):
                 self.metrics.inc(name, 0)
-            self.metrics.gauge("spec_wave_drafting_slots", 0.0)
-            self.metrics.gauge("spec_wave_gamma_mean", 0.0)
         # device-drafting token history, maintained incrementally (a
         # per-step rebuild would be O(slots * max_seq) host work on the
         # hot path): row i holds slot i's prompt + emitted tokens
@@ -586,8 +636,9 @@ class ContinuousBatcher:
         for name in ("lora_loads", "lora_evictions", "lora_load_failures",
                      "lora_requests"):
             self.metrics.inc(name, 0)
-        # opt-in sampling phase profiler for this step loop
-        # (utils/profiler.py; DLI_PROFILE=1 or worker POST /api/profile)
+        # this step loop's phase clocks, always on, and its opt-in
+        # sampling profiler (utils/profiler.py; DLI_PROFILE=1 or worker
+        # POST /api/profile)
         self.profiler = PhaseProfiler.from_env()
         self.context_lens = np.zeros((slots,), np.int32)
         self.active: List[Optional[BatchRequest]] = [None] * slots
@@ -1258,9 +1309,11 @@ class ContinuousBatcher:
         floats = np.stack([np.asarray(a["temps"], np.float32),
                            np.asarray(a["tps"], np.float32)])
         fn = self._decode_jit(int(a["k"]), r, mb, use_lora)
-        # k and slots pair this host call with its device run exactly
+        # chunk names this host call's device run and its
+        # batcher.decode_chunk span exactly; k and slots describe it
         stats = ({"k": int(a["k"]),
-                  "slots": int(np.count_nonzero(a["budget"]))}
+                  "slots": int(np.count_nonzero(a["budget"])),
+                  "chunk": self._step_count + 1}
                  if self.profiler.enabled else {})
         with self.mesh:
             with self.profiler.phase("dispatch", **stats):
@@ -1367,37 +1420,80 @@ class ContinuousBatcher:
                     jnp.asarray(floats), self.paged)
                 return jax.device_get((toks, keeps, eos_seen))
 
-    def _note_program(self, wall_s: float, kind: str = "", k: int = 0,
-                      slots: int = 0):
-        """Stall accounting for one program call's wall: it is program
-        time of this step, and a decode chunk (``k`` passes) is judged
-        against the running mean of earlier chunks of its kind and
-        size. Admit waves only add their wall: their sizes differ too
-        widely for a mean to say anything."""
+    def _note_program(self, before: tuple, kind: str = "", k: int = 0,
+                      slots: int = 0) -> Tuple[float, float]:
+        """Close one program call that began at ``before``
+        (``call_readings()``, taken at its start): its wall, read once
+        here, is program time of this step, and a decode chunk (``k``
+        passes) is judged against the running mean of earlier chunks of
+        its kind and size. Admit waves only add their wall: their sizes
+        differ too widely for a mean to say anything. Returns the call's
+        (start, end) in epoch seconds, for its histogram and span."""
+        t0, t1 = before[0], time.perf_counter()
+        wall_s = t1 - t0
+        span = self.profiler.epoch(t0), self.profiler.epoch(t1)
         self._step_program_s += wall_s
         if not k:
-            return
+            return span
         st = self._pass_mean.get((kind, k))
         if st is None:   # the first of its size compiles, or reads the cache
             self._pass_mean[(kind, k)] = [0.0, 0]
-            return
+            return span
         mean, n = st
         per_pass = wall_s / k
         lost = wall_s - mean * k
         if (n >= self.STALL_MIN_CHUNKS and lost >= self.STALL_FLOOR_S
                 and per_pass > self.STALL_PROGRAM_FACTOR * mean):
-            self._stall("program", lost, k, slots)
+            # the bracket that grew: the launch, if it took at least
+            # half of what was lost, else the wait for the outputs (a
+            # speculative chunk has one bracket for both)
+            launch_s = self.profiler.step_clocks().get("dispatch", 0.0)
+            grew = ("spec_verify" if kind != "decode" else
+                    "dispatch" if launch_s >= lost / 2 else "device_wait")
+            self._stall("program", lost, k, slots, before, span, grew)
             # a stall is not the norm: it enters the mean at the limit
             per_pass = self.STALL_PROGRAM_FACTOR * mean
         st[:] = mean + (per_pass - mean) / min(n + 1, 64), n + 1
+        return span
 
-    def _stall(self, where: str, lost_s: float, k: int, slots: int):
-        """Count one stall (``where``: program | host) and journal it."""
+    def _stall(self, where: str, lost_s: float, k: int, slots: int,
+               before: tuple, span: Tuple[float, float], grew: str):
+        """Count one stall (``where``: program | host), say what the
+        process and the device's memory were doing over it (``before``:
+        the readings at the stalled call's or step's start), name its
+        cause (``_stall_cause``) and journal it, as an event and as a
+        ``batcher.stall`` span over the call."""
+        ms = round(lost_s * 1e3, 1)
+        rec = {"where": where, "ms": ms, "in": grew, "k": k, "slots": slots,
+               "pool_positions": self._pool_positions,
+               **call_deltas(before), "memory": self._device_memory()}
+        rec["cause"] = self._stall_cause(where, ms, rec)
+        self._stall_lost_s += lost_s
         self.metrics.inc(f"batcher_stall_{where}_ms", lost_s * 1e3)
-        log.warning("scheduler stall (%s): %.0f ms lost, k=%d, slots=%d",
-                    where, lost_s * 1e3, k, slots)
-        events.emit("scheduler-stall", where=where,
-                    ms=round(lost_s * 1e3, 1), k=k, slots=slots)
+        self.metrics.inc(f"batcher_stall_cause_{rec['cause']}_ms",
+                         lost_s * 1e3)
+        log.warning("scheduler stall (%s): %.0f ms lost, k=%d, slots=%d: %s",
+                    where, lost_s * 1e3, k, slots, json.dumps(rec))
+        events.emit("scheduler-stall", **rec)
+        trace.get_tracer().record("batcher.stall", *span, attrs=rec)
+
+    def _device_memory(self) -> dict:
+        """``memory_stats()`` of this process's fullest device, as the
+        backend gives them (none on the CPU). Read when a stall fired,
+        never on the step's path."""
+        best: dict = {}
+        for d in self.mesh.devices.flat:
+            if d.process_index != jax.process_index():
+                continue
+            try:
+                st = d.memory_stats() or {}
+            except Exception as e:   # a backend without the call
+                log.debug("memory_stats: %r", e)
+                continue
+            if st.get("bytes_in_use", 0) >= best.get("bytes_in_use", 0):
+                best = {key: int(v) for key, v in st.items()
+                        if isinstance(v, (int, float))}
+        return best
 
     def _wave_params(self, use_lora: bool):
         """The parameter tree a wave's program runs against: the base
@@ -2020,12 +2116,9 @@ class ContinuousBatcher:
             last[key] = st[key]
         if self.kvtier is not None:
             a = self.kvtier.arena.stats()
-            self.metrics.gauge("kvtier_host_blocks", a["blocks"])
             # stored (possibly quantized) bytes — the honest budget
-            # fraction; logical_bytes is the full-precision equivalent,
-            # so stored/logical exposes the arena's compression ratio
+            # fraction
             self.metrics.gauge("kvtier_host_bytes", a["bytes"])
-            self.metrics.gauge("kvtier_logical_bytes", a["logical_bytes"])
             self.metrics.gauge(
                 "kvtier_occupancy",
                 a["bytes"] / max(1, a["capacity_bytes"]))
@@ -2242,26 +2335,30 @@ class ContinuousBatcher:
         hits = sum(max(0, m["cached"] - m["req"]._prefill_counted)
                    for m in members)
         active = sum(a is not None for a in self.active)
-        w0 = clock.now()
+        self._wave_count += 1
+        before = call_readings()
+        w0 = self.profiler.epoch(before[0])
         for m in members:
             # cost ledger: queue phase ends when the FIRST wave carrying
             # the request starts dispatching (chunked-prefill passes and
             # preemption re-admissions keep the original stamp)
             if m["req"].admitted_at is None:
                 m["req"].admitted_at = w0
+        # wave names this call's device run and its span exactly
         with self.profiler.phase("admit_run", rows=b, tail_bucket=t,
-                                 prefix_bucket=pb, tokens=tokens):
+                                 prefix_bucket=pb, tokens=tokens,
+                                 wave=self._wave_count):
             if self.program_hook is not None:
                 first = self.program_hook(
                     "admit", admit_args, lambda: self._run_admit(admit_args))
             else:
                 first = self._run_admit(admit_args)
-        w1 = clock.now()
+        w0, w1 = self._note_program(before)
         self.metrics.observe("batcher_admit_wave", w1 - w0)
-        self._note_program(w1 - w0)
         wave = trace.get_tracer().record(
             "batcher.admit_wave", w0, w1,
-            attrs={"members": len(members), "rows": b,
+            attrs={"wave": self._wave_count,
+                   "members": len(members), "rows": b,
                    "tail_bucket": t, "prefix_bucket": pb,
                    "tokens": tokens, "padded_tokens": b * t,
                    "active": active, "prefix_positions": hits,
@@ -2394,7 +2491,6 @@ class ContinuousBatcher:
                 self.metrics.inc("chunk_prefill_stalls")
                 self._gauge_stall_streak(req)
                 if req._chunk_stalls > 4:
-                    self.metrics.inc("chunk_prefill_stall_failures")
                     self._fail_req(req, "KV block pool exhausted "
                                         "(chunked prefill made no progress)")
                     return
@@ -2418,6 +2514,8 @@ class ContinuousBatcher:
             self._hist_synced[slot] = 0   # row rewritten: full re-sync
         if req.first_token_at is None:
             req.first_token_at = clock.now()
+            req._clocks0 = (self.profiler.read(req.first_token_at),
+                            self._stall_lost_s)
         self._emit(req, first)
         if self._hist is not None and req.tokens:
             # the fused-sampled first token extends the history
@@ -2509,6 +2607,7 @@ class ContinuousBatcher:
                               3),
             "prefill_ms": round(max(0.0, first - admitted) * 1e3, 3),
             "decode_ms": round(max(0.0, end - first) * 1e3, 3),
+            **self._decode_account(req, end),
             "prefill_cached_tokens": req._cost_cached,
             "prefill_uncached_tokens": req._cost_uncached,
             "decode_tokens": len(req.tokens),
@@ -2527,6 +2626,34 @@ class ContinuousBatcher:
                 gaps[min(len(gaps) - 1, int(len(gaps) * 0.95))] * 1e3, 3)
             cost["itl_max_ms"] = round(gaps[-1] * 1e3, 3)
         return cost
+
+    def _decode_account(self, req: BatchRequest, end: float) -> dict:
+        """Where the request's decode phase went, from the scheduler's
+        phase clocks as they stood at its first token and at ``end``:
+        five parts that sum to ``decode_ms``, and what the stall
+        accounting judged lost meanwhile, which lies inside them. Empty
+        for a request that never emitted a token."""
+        if req._clocks0 is None:
+            return {}
+        then, lost0 = req._clocks0
+        now = self.profiler.read(end)
+
+        def ms(*names):
+            return sum(now.get(n, 0.0) - then.get(n, 0.0)
+                       for n in names) * 1e3
+        admit_run = ms("admit_run")
+        return {key: round(v, 3) for key, v in (
+            # decode program calls, this request's and its neighbours'
+            ("decode_chunk_ms", ms("dispatch", "device_wait",
+                                   "spec_verify")),
+            # others' admit programs, and the host work around them
+            # (radix match, packing, slot binding)
+            ("decode_admit_run_ms", admit_run),
+            ("decode_admit_host_ms", ms("admit") - admit_run),
+            ("decode_emit_ms", ms("emit")),
+            ("decode_host_ms", ms("host_prep", "spec_draft",
+                                  "bookkeeping", "other", "between")),
+            ("decode_stall_ms", (self._stall_lost_s - lost0) * 1e3))}
 
     def _observe_finished(self, req: BatchRequest):
         """Per-request histograms + retroactive trace spans, reconstructed
@@ -2565,7 +2692,10 @@ class ContinuousBatcher:
             tr.record("batcher.ttft", req.submitted_at, req.first_token_at,
                       parent=g)
             tr.record("batcher.decode", req.first_token_at, end, parent=g,
-                      attrs={"tokens": len(req.tokens)})
+                      attrs={"tokens": len(req.tokens),
+                             **{k: v for k, v in cost.items()
+                                if k.startswith("decode_") and
+                                k.endswith("_ms")}})
         # trace tail-sampling: errored and SLO-violating requests keep
         # their spans in the tracer's retained ring, so the postmortem
         # doesn't race the main ring's oldest-first eviction (a
@@ -2638,10 +2768,10 @@ class ContinuousBatcher:
 
     def step(self) -> int:
         """Admit a wave + one K-token decode chunk. Returns active slots."""
-        t0 = time.perf_counter()
         busy = 0
         work0 = (self._step_count, self._tokens_out)
         self._step_program_s = 0.0
+        before = call_readings()   # should the host stand still this step
         prof_rec = self.profiler.step_begin()
         try:
             busy = self._step_inner()
@@ -2653,7 +2783,7 @@ class ContinuousBatcher:
             m = self.metrics
             with self.profiler.phase("bookkeeping"):
                 if busy:   # idle polls would drown the step histogram
-                    m.observe("batcher_step", time.perf_counter() - t0)
+                    m.observe("batcher_step", self.profiler.elapsed())
                 m.gauge("batcher_queue_depth", len(self.queue))
                 active_slots = sum(a is not None for a in self.active)
                 m.gauge("batcher_active_slots", active_slots)
@@ -2670,13 +2800,35 @@ class ContinuousBatcher:
             # the profile must see
             did_work = bool(busy) or \
                 (self._step_count, self._tokens_out) != work0
-            self.profiler.step_end(prof_rec, keep=did_work, active=busy)
+            wall = self.profiler.step_end(prof_rec, keep=did_work,
+                                          active=busy)
             if did_work:
+                # the step's clocks as counters, so that a scrape (and a
+                # benchmark's result line, which prints the window's
+                # batcher_* deltas) holds the busy wall by bracket
+                for name, s in self.profiler.last_step.items():
+                    m.inc(f"batcher_clock_{name}_ms", s * 1e3)
                 # the step's wall outside its program calls: the host
-                host_over = (time.perf_counter() - t0 - self._step_program_s
+                host_over = (wall - self._step_program_s
                              - self.STALL_HOST_BOUND_S)
                 if host_over >= self.STALL_FLOOR_S:
-                    self._stall("host", host_over, 0, busy)
+                    self._host_stall(host_over, busy, before, wall)
+
+    # brackets in which the step waits for a program, not the host
+    PROGRAM_BRACKETS = ("dispatch", "device_wait", "spec_verify",
+                        "admit", "admit_run")
+
+    def _host_stall(self, lost_s: float, busy: int, before: tuple,
+                    wall_s: float):
+        """A busy step's host part went over its bound: the stall lies
+        over the whole step, in the host bracket that took the most of
+        it (the nested ``admit_prep`` / ``admit_post`` stand for
+        ``admit``, whose rest is ``admit_run``)."""
+        host = {name: s for name, s in self.profiler.last_step.items()
+                if name not in self.PROGRAM_BRACKETS}
+        t0 = self.profiler.epoch(before[0])
+        self._stall("host", lost_s, 0, busy, before, (t0, t0 + wall_s),
+                    max(host, key=host.get))
 
     def _step_inner(self) -> int:
         # service migration snapshots first: a flagged slot must not
@@ -2784,7 +2936,7 @@ class ContinuousBatcher:
         adaptive-speculation fallback/probe path. Returns tokens
         emitted."""
         k = int(decode_args["k"])
-        w0 = clock.now()
+        before = call_readings()
         if self.program_hook is not None:
             if self._hist is not None:
                 # adaptive fallback under lockstep: a freshly-admitted
@@ -2798,12 +2950,11 @@ class ContinuousBatcher:
         else:
             toks, emits = self._run_decode(decode_args)
         self._step_count += 1
-        w1 = clock.now()
+        w0, w1 = self._note_program(before, "decode", k, len(active))
         self.metrics.observe("batcher_decode_chunk", w1 - w0)
-        self._note_program(w1 - w0, "decode", k, len(active))
         trace.get_tracer().record(
             "batcher.decode_chunk", w0, w1,
-            attrs={"k": k, "slots": len(active),
+            attrs={"chunk": self._step_count, "k": k, "slots": len(active),
                    "kv_bytes_per_token": self.paged.bytes_per_token,
                    "pool_positions": self._pool_positions,
                    "window_positions": self._window_positions})
@@ -2964,9 +3115,6 @@ class ContinuousBatcher:
                     gammas[i] = max(0, g_max)
         drafting = [i for i in active if gammas[i] > 0]
         riding = [i for i in active if gammas[i] == 0]
-        m.gauge("spec_wave_drafting_slots", float(len(drafting)))
-        m.gauge("spec_wave_gamma_mean",
-                float(np.mean([gammas[i] for i in active])))
         m.gauge("spec_mode", 1.0 if drafting else 0.0)
 
         if not drafting:
@@ -2996,6 +3144,9 @@ class ContinuousBatcher:
         spec_key = ("spec", k_it, g_max, self.slots, self.max_blocks,
                     self._hist.shape[1], "aids" in decode_args)
         compiled = spec_key not in self._decode_fns
+        # the controllers' clock is the runtime's (a test makes it tick
+        # by reads); the stall accounting's is the profiler's
+        before = call_readings()
         w0 = clock.now()
         if self.program_hook is not None:
             # lockstep: widths are scheduler decisions, so they ride the
@@ -3013,10 +3164,11 @@ class ContinuousBatcher:
         w1 = clock.now()
         m.inc("spec_wave_dispatches")
         m.observe("batcher_decode_chunk", w1 - w0)
-        self._note_program(w1 - w0, f"spec{g_max}", k_it, len(active))
+        self._note_program(before, f"spec{g_max}", k_it, len(active))
         trace.get_tracer().record(
             "batcher.spec_wave_chunk", w0, w1,
-            attrs={"k": k_it, "gamma_max": g_max, "slots": len(active),
+            attrs={"chunk": self._step_count, "k": k_it,
+                   "gamma_max": g_max, "slots": len(active),
                    "drafting": len(drafting), "riding": len(riding)})
         self._apply_spec_hist(toks, keeps,
                               np.asarray(decode_args["cl"], np.int32))

@@ -257,8 +257,24 @@ EVENT_TYPES = (
         "(`where` program), or a busy step spent over 100 ms outside "
         "its program calls (`where` host). `ms` is what was lost (over "
         "the mean, over the 100 ms), also added to "
-        "`dli_batcher_stall_{program,host}_ms_total`.",
-        ("where", "ms", "k", "slots")),
+        "`dli_batcher_stall_{program,host}_ms_total` and, by `cause`, "
+        "to one of the `batcher_stall_cause_*` counters. `in` is the bracket "
+        "that grew (`dispatch` or `device_wait` of the stalled chunk, "
+        "`spec_verify`; the step's largest host bracket), "
+        "`pool_positions` the last chunk's read extent; over the "
+        "stalled call (or step): the scheduler thread's and the "
+        "process's CPU time, the process's involuntary context switches "
+        "and major faults, the cycle collector's time and the worst "
+        "lateness of the process's 50 ms heartbeat; `memory` is the "
+        "fullest device's `memory_stats()` at that moment; `cause` is "
+        "what `ContinuousBatcher._stall_cause` makes of them (`gc`, "
+        "`interpreter_held`, `descheduled`, `host_runtime_busy`, "
+        "`device_or_runtime_wait`, `thread_blocked`). The same record "
+        "is a `batcher.stall` span over the call.",
+        ("where", "ms", "in", "k", "slots", "pool_positions",
+         "thread_cpu_ms", "process_cpu_ms", "invol_switches",
+         "major_faults", "gc_ms", "heartbeat_late_ms", "memory",
+         "cause")),
     # ---- multi-LoRA adapter serving (models/lora.py) ------------------
     EventType(
         "adapter-loaded", "info",

@@ -340,8 +340,9 @@ class WorkerAgent:
     def api_profile(self, body):
         """Decode-profiler readout: per-phase wall attribution of the
         batcher step loop (``PhaseProfiler.summary()``) per batched
-        model. Zero-cost when the profiler is off — the payload then
-        just reports enabled=false."""
+        model. With the profiler off the payload still holds the
+        always-on ``clocks`` (the busy steps' wall by bracket since the
+        batcher was built); the sampled tables are then empty."""
         out = {}
         for name, p in self._batcher_profilers():
             out[name] = {"summary": p.summary()}

@@ -1,4 +1,5 @@
-"""Low-overhead sampling profiler for the batcher's decode step loop.
+"""Always-on phase clocks and a low-overhead sampling profiler for the
+batcher's decode step loop.
 
 The Chrome-trace spans from PR 1 answer "where did THIS request's time
 go"; the XLA profiler (`/profile/start`) answers "what did the device
@@ -12,13 +13,20 @@ without changing what is measured.
 
 :class:`PhaseProfiler` is the answer: the step loop brackets its phases
 with ``profiler.phase("dispatch")`` context managers and one
-``step_begin()/step_end()`` pair per step. When disabled (the default)
-every call is a single attribute check returning a shared no-op — no
-allocation, no timestamps, no annotation, zero samples. When enabled:
+``step_begin()/step_end()`` pair per step. Whether or not it is enabled,
+every bracket of a busy step adds its wall to a cumulative **clock** of
+its name (seconds since construction; ``clocks()``, ``read()``), and the
+step's wall outside every top-level bracket to ``other`` (the time from
+a step that left slots running to the next one goes to ``between``):
+about ten ``perf_counter`` pairs a step, no annotation, no sample. Each request
+reads the clocks twice (first token, finish) and so accounts for its
+decode time by bracket (``runtime/batcher.py: _cost_record``). When
+enabled, besides:
 
 - every bracket is also a ``jax.profiler.TraceAnnotation("dli.<phase>")``
-  (keyword stats ride along: ``k``/``slots`` on ``dispatch``, ``rows``/
-  ``tail_bucket``/``prefix_bucket``/``tokens`` on ``admit_run``) and the
+  (keyword stats ride along: ``k``/``slots``/``chunk`` on ``dispatch``,
+  ``rows``/``tail_bucket``/``prefix_bucket``/``tokens``/``wave`` on
+  ``admit_run``) and the
   step a ``StepTraceAnnotation("dli.step", step_num=...)``, so ANY
   profiler trace (worker ``POST /profile/start``, the benchmark's
   ``Tracer``) holds the host phases in the ``/host:CPU`` plane on the
@@ -56,20 +64,34 @@ Nested brackets (``summary()["nested"]``, never folded into ``phases``):
 
 Export: ``summary()`` and ``chrome_events()`` (the sampled brackets at
 their real timestamps, mergeable into the PR 1 ``/api/trace`` export).
+
+Beside the clocks, what a stalled program call needs to say why it
+stalled: ``call_readings()`` (the thread's and the process's CPU clocks, the
+process's involuntary context switches and major faults, the seconds
+spent in the cycle collector) taken at each call's start, and
+``call_deltas()``, their deltas with the worst lateness of the process's
+**heartbeat** over the call (one daemon thread a process, asleep 50 ms
+at a time: if it woke late, no thread of this process ran).
 """
 
 from __future__ import annotations
 
+import gc
 import os
+import resource
 import threading
 import time
 from collections import deque
 from typing import Dict, List, Optional
 
+from distributed_llm_inferencing_tpu.utils import clock
+
 # canonical order of summary()'s phases (unknown names sort after these)
 PHASE_ORDER = ("admit", "host_prep", "spec_draft", "dispatch",
                "spec_verify", "device_wait", "emit", "bookkeeping",
                "other")
+
+_ORDER = {name: i for i, name in enumerate(PHASE_ORDER)}
 
 DEFAULT_CAPACITY = 2048
 
@@ -102,30 +124,129 @@ _NOOP = _Noop()
 
 
 class _Phase:
-    """One bracket of an enabled profiler's step: a TraceAnnotation, and
-    in a sampled step one ``[name, start, end, depth]`` entry."""
+    """One bracket of a step: its wall goes to the clock of its name;
+    under an enabled profiler it is a TraceAnnotation too, and in a
+    sampled step one ``[name, start, end, depth]`` entry."""
     __slots__ = ("prof", "ann", "span")
 
     def __init__(self, prof: "PhaseProfiler", name: str, stats: dict):
         self.prof = prof
-        self.ann = _annotations()[0]("dli." + name, **stats)
+        self.ann = (_annotations()[0]("dli." + name, **stats)
+                    if prof._annotate else None)
         self.span = [name, 0.0, 0.0, 0]
 
     def __enter__(self):
         prof = self.prof
-        self.ann.__enter__()
-        self.span[3] = prof._depth
-        prof._depth += 1
+        if self.ann is not None:
+            self.ann.__enter__()
+        self.span[3] = len(prof._open)
+        prof._open.append(self.span)
         if prof._cur is not None:
             prof._cur.append(self.span)    # ordered by start
         self.span[1] = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        self.span[2] = time.perf_counter()
-        self.prof._depth -= 1
-        self.ann.__exit__(*exc)
+        span = self.span
+        span[2] = time.perf_counter()
+        prof = self.prof
+        prof._open.pop()
+        wall = span[2] - span[1]
+        prof._step[span[0]] = prof._step.get(span[0], 0.0) + wall
+        if span[3]:
+            prof._nested.add(span[0])
+        else:
+            prof._step_top += wall
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
         return False
+
+
+# ---- what the process was doing over a program call ------------------
+
+HEARTBEAT_S = 0.050
+
+
+class _Vitals:
+    """One a process: the seconds spent in the cycle collector (a
+    ``gc.callbacks`` timer, two clock reads a collection) and the
+    heartbeat, a daemon thread that sleeps ``HEARTBEAT_S`` at a time
+    (through the ``utils/clock.py`` seam) and keeps how late each
+    wake-up came. A thread that wakes late was not scheduled, or could
+    not take the interpreter: either way this process's Python stood
+    still that long."""
+
+    def __init__(self):
+        self.gc_s = 0.0
+        self._gc_t0 = None
+        self._beats: deque = deque(maxlen=512)   # (woke, late s): 25 s
+        self._asleep = time.perf_counter()       # the current nap's start
+        self._lock = threading.Lock()
+        gc.callbacks.append(self._on_gc)
+        threading.Thread(target=self._beat, name="dli-heartbeat",
+                         daemon=True).start()
+
+    def _on_gc(self, phase, info):
+        if phase == "start":
+            self._gc_t0 = time.perf_counter()
+        elif self._gc_t0 is not None:
+            self.gc_s += time.perf_counter() - self._gc_t0
+            self._gc_t0 = None
+
+    def _beat(self):
+        while True:
+            self._asleep = t = time.perf_counter()
+            clock.sleep(HEARTBEAT_S)
+            woke = time.perf_counter()
+            with self._lock:
+                self._beats.append((woke, woke - t - HEARTBEAT_S))
+
+    def late_s(self, since: float) -> float:
+        """The worst lateness of a wake-up since ``since`` (a
+        ``perf_counter`` time), the nap still running included."""
+        with self._lock:
+            late = max((late for woke, late in self._beats
+                        if woke >= since), default=0.0)
+        return max(late, time.perf_counter() - self._asleep - HEARTBEAT_S,
+                   0.0)
+
+
+_VITALS: Optional[_Vitals] = None
+_VITALS_LOCK = threading.Lock()
+
+
+def vitals() -> _Vitals:
+    """The process's one :class:`_Vitals`, started on first use (a
+    batcher's construction), never at import."""
+    global _VITALS
+    if _VITALS is None:
+        with _VITALS_LOCK:
+            if _VITALS is None:
+                _VITALS = _Vitals()
+    return _VITALS
+
+
+def call_readings() -> tuple:
+    """``(perf_counter, thread CPU s, process CPU s, involuntary context
+    switches, major faults, collector s)`` now: what a program call
+    notes at its start (three clock reads and one ``getrusage``)."""
+    ru = resource.getrusage(resource.RUSAGE_SELF)
+    return (time.perf_counter(), time.thread_time(), time.process_time(),
+            ru.ru_nivcsw, ru.ru_majflt, vitals().gc_s)
+
+
+def call_deltas(before: tuple) -> dict:
+    """What the thread and the process did since ``before``
+    (:func:`call_readings`, taken on this thread)."""
+    now = call_readings()
+    return {
+        "thread_cpu_ms": round((now[1] - before[1]) * 1e3, 1),
+        "process_cpu_ms": round((now[2] - before[2]) * 1e3, 1),
+        "invol_switches": now[3] - before[3],
+        "major_faults": now[4] - before[4],
+        "gc_ms": round((now[5] - before[5]) * 1e3, 1),
+        "heartbeat_late_ms": round(vitals().late_s(before[0]) * 1e3, 1),
+    }
 
 
 def step_phases(rec: dict) -> Dict[str, float]:
@@ -145,11 +266,11 @@ def step_phases(rec: dict) -> Dict[str, float]:
 class PhaseProfiler:
     """Bounded ring of per-step phase timelines for one batcher.
 
-    Thread model: ``step_begin``/``step_end`` and the phase brackets run
-    on the scheduler thread only; ``configure``/readers may run on HTTP
-    handler threads — the ring and config flip under ``_lock``, and the
-    in-flight step (``_in_step``, ``_cur``, ``_depth``) is
-    scheduler-thread-private.
+    Thread model: ``step_begin``/``step_end``, the phase brackets and
+    ``read()`` run on the scheduler thread only; ``configure``/readers
+    may run on HTTP handler threads — the ring, the clocks and config
+    flip under ``_lock``, and the in-flight step (``_in_step``, ``_cur``,
+    ``_open``, ``_step``) is scheduler-thread-private.
     """
 
     def __init__(self, capacity: int = DEFAULT_CAPACITY,
@@ -158,10 +279,28 @@ class PhaseProfiler:
         self.sample_every = max(1, int(sample_every))
         self._ring: deque = deque(maxlen=max(16, int(capacity)))
         self._lock = threading.Lock()
-        self._in_step = False     # a step of an enabled profiler is open
+        self._in_step = False     # a step is open
+        self._annotate = False    # ... of an enabled profiler
         self._cur: Optional[List[list]] = None   # its brackets, if sampled
-        self._depth = 0
+        self._open: List[list] = []      # the brackets open now, outer first
         self._step_ann = None
+        # the clocks: seconds per bracket name over the busy steps so
+        # far, top-level and nested names side by side (``_nested`` says
+        # which), and the open step's own share, folded in at its end
+        self._clocks: Dict[str, float] = {}
+        self._nested: set = set()
+        self._busy = [0, 0.0]     # busy steps, their wall
+        self._step: Dict[str, float] = {}
+        self._step_top = 0.0      # ... its top-level brackets' wall
+        self._t0 = time.perf_counter()     # the open (or last) step's start
+        # when the last step ended, if it left slots running: the next
+        # step follows at once, and the time between the two (the loop,
+        # the step's own prologue and epilogue, other threads holding
+        # the interpreter) is the scheduler's too: the clock `between`
+        self._chain: Optional[float] = None
+        self._shift = clock.now() - self._t0    # perf_counter -> epoch
+        self.last_step: Dict[str, float] = {}   # the last busy step's clocks
+        vitals()
         self._step_n = 0          # steps seen while enabled (sampling clock)
         self._sampled = 0         # steps actually recorded
 
@@ -202,59 +341,132 @@ class PhaseProfiler:
     # ---- hot path ----------------------------------------------------
 
     def step_begin(self) -> Optional[dict]:
-        """Open one scheduler step. Every step of an enabled profiler is
-        a ``dli.step`` annotation and its brackets ``dli.<phase>``
-        annotations; returns the step's record, or None when it is not
-        sampled (disabled, or skipped by the sampling stride)."""
-        if not self.enabled:
-            return None
-        self._step_n += 1
-        self._step_ann = _annotations()[1]("dli.step",
-                                           step_num=self._step_n)
-        self._step_ann.__enter__()
+        """Open one scheduler step: its start is read once, here, and
+        anchors the step's times to the epoch (``epoch()``). Every step
+        of an enabled profiler is a ``dli.step`` annotation and its
+        brackets ``dli.<phase>`` annotations; returns the step's record,
+        or None when it is not sampled (disabled, or skipped by the
+        sampling stride)."""
+        self._annotate = self.enabled
+        if self._annotate:
+            self._step_n += 1
+            self._step_ann = _annotations()[1]("dli.step",
+                                               step_num=self._step_n)
+            self._step_ann.__enter__()
+        self._t0 = t0 = time.perf_counter()
+        self._shift = clock.now() - t0
+        self._step = ({} if self._chain is None
+                      else {"between": t0 - self._chain})
+        self._step_top = 0.0
+        del self._open[:]
         self._in_step = True
-        self._depth = 0
-        if (self._step_n - 1) % self.sample_every:
+        if not self._annotate or (self._step_n - 1) % self.sample_every:
             return None
         self._cur = []
-        return {"t": time.time(), "t0": time.perf_counter()}
+        return {"t": t0 + self._shift}
 
-    def step_end(self, rec: Optional[dict], keep: bool = True, **meta):
-        """Close the step. ``keep=False`` discards its record (idle
-        polls)."""
-        if self._in_step:
-            self._in_step = False
+    def step_end(self, rec: Optional[dict], keep: bool = True,
+                 active: int = 0, **meta) -> float:
+        """Close the step and return its wall. ``keep=False`` discards
+        its record and its share of the clocks (idle polls);
+        ``active`` (slots still running: the next step follows at once)
+        and ``meta`` ride a sampled step's record."""
+        end = time.perf_counter()
+        total = end - self._t0
+        self._chain = end if keep and active else None
+        self._in_step = False
+        if self._step_ann is not None:
             self._step_ann.__exit__(None, None, None)
             self._step_ann = None
-        if rec is None:
-            return
         spans, self._cur = self._cur, None
         if not keep:
-            return
-        t0 = rec.pop("t0")
-        rec["total"] = time.perf_counter() - t0
-        shift = rec["t"] - t0      # perf_counter -> epoch, one anchor
-        rec["spans"] = [(name, start + shift, end + shift, depth)
-                        for name, start, end, depth in spans]
-        if meta:
-            rec["meta"] = meta
+            self._step = {}
+            return total
+        step = self.last_step = self._step
+        step["other"] = max(0.0, total - self._step_top)
+        shift = self._shift        # perf_counter -> epoch, one anchor
+        if rec is not None:
+            rec["total"] = total
+            rec["spans"] = [(name, start + shift, end + shift, depth)
+                            for name, start, end, depth in spans]
+            rec["meta"] = {"active": active, **meta}
         with self._lock:
-            self._ring.append(rec)
-            self._sampled += 1
+            for name, s in step.items():
+                self._clocks[name] = self._clocks.get(name, 0.0) + s
+            self._busy[0] += 1
+            self._busy[1] += total
+            if rec is not None:
+                self._ring.append(rec)
+                self._sampled += 1
+        return total
 
     def phase(self, name: str, **stats):
         """Bracket of the current step; ``stats`` become the
-        annotation's keyword stats. Outside a step of an enabled
-        profiler the cost is one attribute check and a shared no-op."""
+        annotation's keyword stats under an enabled profiler. Outside a
+        step the cost is one attribute check and a shared no-op."""
         if not self._in_step:
             return _NOOP
         return _Phase(self, name, stats)
+
+    def step_clocks(self) -> Dict[str, float]:
+        """Seconds per bracket name of the open step's closed brackets
+        (the scheduler thread's own view; not a copy)."""
+        return self._step
+
+    def elapsed(self) -> float:
+        """Seconds since the open step began."""
+        return time.perf_counter() - self._t0
+
+    def epoch(self, t: float) -> float:
+        """A ``perf_counter`` time of the open step as epoch seconds."""
+        return t + self._shift
+
+    def read(self, at: Optional[float] = None) -> Dict[str, float]:
+        """The clocks at this moment, or at the epoch time ``at`` of a
+        timestamp just taken in the open step, on the scheduler thread:
+        the busy steps so far, and of the open step its closed brackets,
+        the elapsed part of those still open, and under ``other`` its
+        wall so far outside every top-level bracket. Two readings differ,
+        over the top-level names, by the wall between them."""
+        now = time.perf_counter() if at is None else at - self._shift
+        out = dict(self._clocks)
+        if not self._in_step:
+            return out
+        for name, s in self._step.items():
+            out[name] = out.get(name, 0.0) + s
+        top = self._step_top
+        for name, start, _, depth in self._open:
+            out[name] = out.get(name, 0.0) + max(0.0, now - start)
+            if not depth:
+                top += max(0.0, now - start)
+        out["other"] = out.get("other", 0.0) + max(0.0, now - self._t0 - top)
+        return out
 
     # ---- export ------------------------------------------------------
 
     def samples(self) -> List[dict]:
         with self._lock:
             return list(self._ring)
+
+    def clocks(self) -> dict:
+        """The always-on account of the busy steps since construction:
+        their count and wall, the seconds under each top-level bracket
+        (``phases``: inclusive of what each nests, summing to ``wall_s``
+        with ``other``) and each nested one (``nested``), and the
+        seconds between a busy step and the one that followed it at
+        once (``between_s``: no step's wall, but the scheduler's
+        time)."""
+        with self._lock:
+            clocks = dict(self._clocks)
+            steps, wall = self._busy
+        between = clocks.pop("between", 0.0)
+        top = sorted((k for k in clocks if k not in self._nested),
+                     key=lambda k: _ORDER.get(k, len(_ORDER)))
+        return {"steps": steps, "wall_s": round(wall, 6),
+                "between_s": round(between, 6),
+                "phases": {k: round(clocks[k], 6) for k in top},
+                "nested": {k: round(clocks[k], 6)
+                           for k in sorted(self._nested & set(clocks))}}
 
     def summary(self) -> dict:
         """Aggregate over the ring: seconds and fraction of the sampled
@@ -272,8 +484,6 @@ class PhaseProfiler:
             for name, start, end, depth in s["spans"]:
                 if depth:
                     nested[name] = nested.get(name, 0.0) + (end - start)
-        order = {n: i for i, n in enumerate(PHASE_ORDER)}
-
         def table(items) -> dict:
             return {k: {"s": round(v, 6),
                         "frac": round(v / wall, 4) if wall else 0.0}
@@ -281,12 +491,13 @@ class PhaseProfiler:
         return {
             "enabled": self.enabled,
             "sample_every": self.sample_every,
+            "clocks": self.clocks(),
             "steps_sampled": len(samples),
             "steps_seen": self._step_n,
             "wall_s": round(wall, 6),
             "phases": table(sorted(
                 totals.items(),
-                key=lambda kv: order.get(kv[0], len(order)))),
+                key=lambda kv: _ORDER.get(kv[0], len(_ORDER)))),
             "nested": table(sorted(nested.items())),
         }
 

@@ -1,8 +1,8 @@
 #!/usr/bin/env python3
 """Reduce a JAX profiler trace of a TPU worker to two tables, without
-TensorBoard:
+TensorBoard, and set the scheduler's own account of its time beside them:
 
-    python scripts/profile_summary.py <trace_dir> [--json]
+    python scripts/profile_summary.py [<trace_dir>] [--account FILE] [--json]
 
 1. The device's time by named scope: the self time of each `XLA Ops`
    event (a `while`'s time less its body's ops) under the innermost scope
@@ -17,6 +17,15 @@ TensorBoard:
    estimated from the program calls themselves: no run starts before its
    `dli.dispatch` / `dli.admit_run` does, none ends after its
    `dli.device_wait` / `dli.admit_run` does.
+
+3. With `--account FILE`: the batcher's always-on phase clocks
+   (`utils/profiler.py`), i.e. the busy steps' wall by bracket, from a
+   benchmark's result line (the window's `batcher_clock_<phase>_ms`
+   counters) or from `GET /api/profile` (`summary()["clocks"]`, since the
+   batcher was built); with a trace, each bracket's clock beside the
+   device's idle time under it: the host's two views in one place. The
+   clocks are all of the window or the process's life, the trace a few
+   seconds of it: compare the shares, not the seconds.
 
 `<trace_dir>` is what `POST /profile/start` or `jax.profiler.start_trace`
 wrote (its newest `plugins/profile/*/*.xplane.pb`), or the file itself.
@@ -354,6 +363,49 @@ def summarize(path: str) -> dict:
     return out
 
 
+NESTED = ("admit_prep", "admit_run", "admit_post")
+
+
+def read_account(path: str) -> dict:
+    """{phase: seconds} of the scheduler's clocks from a JSON file (its
+    last line, if it has several): a benchmark's result line, a worker's
+    or master's `GET /api/profile` answer (the first profiler found), or
+    a `summary()` itself."""
+    with open(path) as f:
+        doc = json.loads(f.read().strip().splitlines()[-1])
+    prefix, suffix = "batcher_clock_", "_ms"
+    if "counters" in doc:
+        return {k[len(prefix):-len(suffix)]: v * 1e-3
+                for k, v in doc["counters"].items()
+                if k.startswith(prefix) and k.endswith(suffix)}
+    while "clocks" not in doc:      # {"profilers": {model: {"summary": ..
+        nested = [v for v in doc.values() if isinstance(v, dict)]
+        if not nested:
+            sys.exit(f"error: no clocks in {path}")
+        doc = nested[0]
+    return {**doc["clocks"]["phases"], **doc["clocks"]["nested"],
+            "between": doc["clocks"]["between_s"]}
+
+
+def render_account(clocks: dict, idle: dict = None) -> str:
+    """The busy steps' wall by bracket (nested brackets indented, not
+    summed again; `between` is the time from one busy step to the next,
+    no step's wall), and beside each the device's idle time under it."""
+    wall = sum(s for name, s in clocks.items()
+               if name not in NESTED + ("between",))
+    lines = [f"scheduler clocks: busy steps' wall {wall:.4f} s"
+             + ("; device idle under each bracket from the trace"
+                if idle is not None else "")]
+    for name, s in sorted(clocks.items(), key=lambda kv: -kv[1]):
+        label = ("  " if name in NESTED else "") + name
+        line = (f"    {label:<14} {s:9.4f} s "
+                f"{100 * s / wall if wall else 0:6.2f} %")
+        if idle is not None:
+            line += f"   idle {idle.get(name, 0.0) * 1e3:9.3f} ms"
+        lines.append(line)
+    return "\n".join(lines)
+
+
 def render(summary: dict) -> str:
     lines = [f"trace: {summary['xplane']}"]
     for dev in summary["devices"]:
@@ -391,12 +443,27 @@ def render(summary: dict) -> str:
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
-    ap.add_argument("trace_dir")
+    ap.add_argument("trace_dir", nargs="?")
+    ap.add_argument("--account", metavar="FILE",
+                    help="a result line or a GET /api/profile answer: "
+                    "print the scheduler's phase clocks too")
     ap.add_argument("--json", action="store_true",
                     help="print the summary as one JSON object")
     args = ap.parse_args(argv)
-    summary = summarize(args.trace_dir)
-    print(json.dumps(summary) if args.json else render(summary))
+    if not (args.trace_dir or args.account):
+        ap.error("give a trace directory, --account, or both")
+    summary = summarize(args.trace_dir) if args.trace_dir else {}
+    if args.account:
+        summary["clocks"] = read_account(args.account)
+    if args.json:
+        print(json.dumps(summary))
+        return 0
+    if args.trace_dir:
+        print(render(summary))
+    if args.account:
+        idle = (summary["devices"][0]["idle_by_phase"]
+                if summary.get("devices") else None)
+        print(render_account(summary["clocks"], idle))
     return 0
 
 

@@ -3,8 +3,11 @@ profiler annotations, the programs' named scopes, the admit wave's span
 attributes and the stall counters (docs/observability.md, "Decode
 profiler")."""
 
+import gc
 import glob
+import json
 import re
+import threading
 import time
 
 import jax
@@ -16,7 +19,7 @@ from distributed_llm_inferencing_tpu.models.registry import get_config
 from distributed_llm_inferencing_tpu.ops.sampling import SamplingParams
 from distributed_llm_inferencing_tpu.runtime import events
 from distributed_llm_inferencing_tpu.runtime.batcher import ContinuousBatcher
-from distributed_llm_inferencing_tpu.utils import trace
+from distributed_llm_inferencing_tpu.utils import clock, trace
 from distributed_llm_inferencing_tpu.utils.profiler import (
     PhaseProfiler, step_phases)
 
@@ -33,10 +36,12 @@ def batcher(model="tiny-llama", **kw):
     return ContinuousBatcher(cfg, None, seed=0, **kw)
 
 
-def serve(b, lengths, new_tokens=6, max_steps=400):
+def serve(b, lengths, new_tokens=6, max_steps=400, chunk_cap=0):
     reqs = [b.submit(RNG.integers(3, b.cfg.vocab_size, n).tolist(),
                      max_new_tokens=new_tokens, sampling=GREEDY)
             for n in lengths]
+    for r in reqs:
+        r.chunk_cap = chunk_cap         # 0: as large as the budget allows
     for _ in range(max_steps):
         b.step()
         if all(r.done.is_set() for r in reqs):
@@ -97,6 +102,91 @@ def test_sampled_step_is_an_ordered_timeline(sample_every):
         [start * 1e6 for _, start, _, _ in samples[0]["spans"]]
 
 
+# ---- the clocks that are always on ------------------------------------
+
+@pytest.mark.parametrize("enabled", [False, True])
+def test_clocks_run_whether_or_not_the_profiler_does(enabled):
+    b = batcher()
+    b.profiler = PhaseProfiler(enabled=enabled)
+    assert b.profiler.clocks() == {"steps": 0, "wall_s": 0.0,
+                                   "between_s": 0.0, "phases": {},
+                                   "nested": {}}
+    serve(b, [9, 20, 5], chunk_cap=2)
+    b.step()                            # an idle poll adds nothing
+    c = b.profiler.clocks()
+    assert c["steps"] >= 2 and c["wall_s"] > 0
+    assert set(c["phases"]) >= {"admit", "host_prep", "dispatch",
+                                "device_wait", "emit", "bookkeeping",
+                                "other"}
+    assert set(c["nested"]) == {"admit_prep", "admit_run", "admit_post"}
+    # the top-level clocks, `other` among them, are the busy steps' wall
+    assert sum(c["phases"].values()) == pytest.approx(c["wall_s"], abs=1e-4)
+    assert sum(c["nested"].values()) <= c["phases"]["admit"] + 1e-5
+    # from a step that left slots running to the next: no step's wall
+    assert 0 < c["between_s"] < c["wall_s"]
+    flat = {**c["phases"], **c["nested"], "between": c["between_s"]}
+    assert b.profiler.read() == pytest.approx(flat, abs=1e-6)
+    # ... and the result line's: the same seconds as counters
+    counters = b.metrics.snapshot()["counters"]
+    for name, sec in flat.items():
+        assert counters[f"batcher_clock_{name}_ms"] == \
+            pytest.approx(sec * 1e3, abs=1e-2)
+    summ = b.profiler.summary()
+    assert summ["clocks"] == c
+    if not enabled:                     # no sample, no annotation
+        assert summ["steps_sampled"] == 0 and summ["phases"] == {}
+        return
+    # every step was sampled: the ring's sums are the clocks
+    assert summ["steps_sampled"] == c["steps"]
+    assert summ["wall_s"] == pytest.approx(c["wall_s"], abs=1e-5)
+    for kind in ("phases", "nested"):
+        assert set(summ[kind]) == set(c[kind])
+        for name, row in summ[kind].items():
+            assert row["s"] == pytest.approx(c[kind][name], abs=1e-4)
+
+
+PARTS = ("decode_chunk_ms", "decode_admit_run_ms", "decode_admit_host_ms",
+         "decode_emit_ms", "decode_host_ms")
+
+
+@pytest.mark.parametrize("model", ["tiny-llama", "tiny-mixtral"])
+@pytest.mark.parametrize("neighbour", [False, True])
+def test_a_request_accounts_for_its_decode_time(model, neighbour):
+    tr = trace.get_tracer()
+    b = batcher(model)
+    serve(b, [9])                       # compile outside the account
+    t_mark = time.time()
+    first = b.submit(RNG.integers(3, b.cfg.vocab_size, 9).tolist(),
+                     max_new_tokens=30, sampling=GREEDY, eos_token_id=None)
+    first.chunk_cap = 4
+    b.step()
+    assert len(first.tokens) >= 1 and not first.done.is_set()
+    if neighbour:                       # admitted while `first` decodes
+        serve(b, [12], new_tokens=2)
+    while not first.done.is_set():
+        b.step()
+    cost = first.cost
+    assert first.error is None and cost["decode_tokens"] == 30
+    assert all(cost[p] >= 0 for p in PARTS)
+    # the five parts are the decode phase, up to the microseconds
+    # between a timestamp and the clocks' reading beside it
+    assert sum(cost[p] for p in PARTS) == \
+        pytest.approx(cost["decode_ms"], abs=1.0)
+    assert cost["decode_chunk_ms"] > 0 and cost["decode_emit_ms"] > 0
+    assert cost["decode_stall_ms"] == 0
+    if neighbour:
+        assert cost["decode_admit_run_ms"] > 0
+        assert cost["decode_admit_host_ms"] > 0
+    else:                               # nobody else was admitted
+        assert cost["decode_admit_run_ms"] == 0
+        assert cost["decode_admit_host_ms"] < 1.0   # empty waves' polls
+    span = [s for s in tr.spans() if s.name == "batcher.decode"
+            and s.start >= t_mark and s.attrs["tokens"] == 30][-1]
+    assert {k: span.attrs[k] for k in PARTS + ("decode_stall_ms",)} == \
+        {k: cost[k] for k in PARTS + ("decode_stall_ms",)}
+    assert span.attrs["decode_ms"] == cost["decode_ms"]
+
+
 # ---- the same phases in a profiler trace ------------------------------
 
 def host_events(trace_dir):
@@ -147,6 +237,16 @@ def test_profiler_trace_holds_the_host_phases(tmp_path, enabled):
                    key=lambda e: e[1])
     assert dispatch and len(dispatch) == len(waits)
     assert all(e[3]["k"] >= 1 and e[3]["slots"] == 2 for e in dispatch)
+    # an annotation and the batcher's own span of the same program call
+    # carry one number
+    spans = trace.get_tracer().spans()
+    chunks = {s.attrs["chunk"] for s in spans
+              if s.name == "batcher.decode_chunk"}
+    waves = {s.attrs["wave"]: s for s in spans
+             if s.name == "batcher.admit_wave"}
+    assert {e[3]["chunk"] for e in dispatch} <= chunks
+    assert len({e[3]["chunk"] for e in dispatch}) == len(dispatch)
+    assert waves[run[3]["wave"]].attrs["tokens"] == run[3]["tokens"]
     pairs = [(d[1], w[2]) for d, w in zip(dispatch, waits)]
     ops = [e for e in evs if e[3].get("hlo_module") == "jit_chunk"]
     assert ops
@@ -275,6 +375,112 @@ def test_stall_counters(journal, where):
     assert [e["data"]["where"] for e in stalls] == [where]
     assert stalls[0]["data"]["ms"] == pytest.approx(got, abs=0.1)
     assert stalls[0]["severity"] == "warning"
+    # every thread slept: the device (the hook stands for it) ran long,
+    # or the scheduler thread waited in its stream callback
+    data = stalls[0]["data"]
+    assert (data["cause"], data["in"]) == {
+        "program": ("device_or_runtime_wait", "device_wait"),
+        "host": ("thread_blocked", "emit")}[where]
+    assert data["k"] == (4 if where == "program" else 0)
+    assert reqs[0].cost["decode_stall_ms"] == pytest.approx(got, abs=0.1)
+
+
+class _LateClock(clock.SystemClock):
+    """The seam's sleep runs `extra` seconds long: the heartbeat, the
+    one thread that sleeps through the seam here, wakes that late."""
+    extra = 0.0
+
+    def sleep(self, seconds):
+        time.sleep(seconds + self.extra)
+
+
+def _burn(stop):
+    """Work that needs no interpreter (BLAS releases it), as a runtime's
+    own threads do."""
+    a = np.ones((256, 256), np.float32)
+    while not stop.is_set():
+        a @ a
+
+
+@pytest.mark.parametrize("cause", ["host_runtime_busy", "gc", "descheduled",
+                                   "interpreter_held"])
+def test_a_stalled_call_says_what_went_on(journal, cause):
+    tr = trace.get_tracer()
+    b = batcher()
+    late = _LateClock()
+    prev = clock.get_clock()
+    clock.set_clock(late)
+    # live objects the collector has to walk: a full collection of them
+    # takes a fifth of a second
+    ballast = [[] for _ in range(3_000_000)] if cause == "gc" else None
+    calls = {"decode": 0}
+
+    def hook(kind, payload, run):
+        if kind == "decode":
+            calls["decode"] += 1
+            if calls["decode"] == 12:
+                stop = threading.Event()
+                burners = [threading.Thread(target=_burn, args=(stop,))
+                           for _ in range(2)]
+                if cause in ("host_runtime_busy", "interpreter_held"):
+                    for t in burners:   # some threads work meanwhile
+                        t.start()
+                if cause == "gc":
+                    gc.collect()
+                else:
+                    if cause in ("descheduled", "interpreter_held"):
+                        late.extra = 0.5    # no heartbeat meanwhile
+                    time.sleep(0.5)
+                    late.extra = 0.0
+                stop.set()
+                for t in burners:
+                    if t.ident is not None:
+                        t.join(10)
+        return run()
+    b.program_hook = hook
+    t_mark = time.time()
+    try:
+        req = b.submit(RNG.integers(3, b.cfg.vocab_size, 9).tolist(),
+                       max_new_tokens=100, sampling=GREEDY, eos_token_id=None)
+        req.chunk_cap = 4
+        while not req.done.is_set():
+            b.step()
+    finally:
+        clock.set_clock(prev)
+        del ballast
+    stalls = [e for e in journal.tail() if e["type"] == "scheduler-stall"]
+    # the one that was made (a crowded host may add small ones of its own)
+    data = max(stalls, key=lambda e: e["data"]["ms"])["data"]
+    assert data["ms"] >= 150
+    assert set(data) == set(events._BY_NAME["scheduler-stall"].fields)
+    assert data["where"] == "program" and data["cause"] == cause, data
+    assert (data["k"], data["slots"]) == (4, 1)
+    assert data["pool_positions"] > 0 and data["memory"] == {}  # the CPU
+    half = data["ms"] / 2
+    assert (data["gc_ms"] >= half) == (cause == "gc")
+    if cause != "gc":       # the collector holds the interpreter itself
+        assert (data["heartbeat_late_ms"] >= half) == \
+            (cause in ("descheduled", "interpreter_held"))
+        assert (data["process_cpu_ms"] >= half) == \
+            (cause in ("host_runtime_busy", "interpreter_held"))
+    assert data["invol_switches"] >= 0 and data["major_faults"] >= 0
+    # the hook's time passes before the launch: the call's own brackets
+    # did not grow, so it reads as the wait
+    assert data["in"] == "device_wait"
+    counters = b.metrics.snapshot()["counters"]
+    assert counters[f"batcher_stall_cause_{cause}_ms"] >= data["ms"] - 0.1
+    assert sum(counters[f"batcher_stall_cause_{c}_ms"]
+               for c in b.STALL_CAUSES) == pytest.approx(
+        counters["batcher_stall_program_ms"]
+        + counters["batcher_stall_host_ms"], abs=0.1 * len(stalls))
+    # the same record as a span over the stalled call, beside its chunk
+    span = next(s for s in tr.spans() if s.name == "batcher.stall"
+                and s.start >= t_mark and s.attrs == data)
+    chunk = next(s for s in tr.spans() if s.name == "batcher.decode_chunk"
+                 and s.start == span.start)
+    assert chunk.end == span.end and chunk.attrs["k"] == 4
+    assert req.cost["decode_stall_ms"] == pytest.approx(
+        sum(e["data"]["ms"] for e in stalls), abs=0.1 * len(stalls))
 
 
 # ---- the operator's reduction (scripts/profile_summary.py) ------------
@@ -325,3 +531,35 @@ def test_profile_summary_splits_idle_time_over_host_phases():
     assert idle == pytest.approx({
         "device_wait": 0.001, "emit": 0.001, "other": 0.001,
         "host_prep": 0.001, "dispatch": 0.002})
+
+
+def test_profile_summary_prints_the_account(tmp_path):
+    import importlib.util
+    from pathlib import Path
+    spec = importlib.util.spec_from_file_location(
+        "profile_summary", Path(__file__).resolve().parents[1]
+        / "scripts" / "profile_summary.py")
+    ps = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(ps)
+    b = batcher()
+    serve(b, [9, 12], chunk_cap=2)
+    clocks = b.profiler.clocks()
+    want = {**clocks["phases"], **clocks["nested"],
+            "between": clocks["between_s"]}
+    # a worker's GET /api/profile answer, and a benchmark's result line
+    api = tmp_path / "profile.json"
+    api.write_text(json.dumps({"status": "success", "profilers": {
+        "tiny-llama": {"summary": b.profiler.summary()}}}))
+    assert ps.read_account(str(api)) == want
+    line = tmp_path / "line.json"
+    line.write_text("noise\n" + json.dumps({"counters": {
+        k: v for k, v in b.metrics.snapshot()["counters"].items()
+        if k.startswith("batcher_")}}))
+    assert ps.read_account(str(line)) == pytest.approx(want, abs=1e-5)
+    text = ps.render_account(want, {"emit": 0.001, "admit_prep": 0.002})
+    rows = {ln.split()[0]: ln for ln in text.splitlines()[1:]}
+    assert set(rows) == set(want)
+    assert f"{clocks['wall_s']:.4f} s" in text.splitlines()[0]
+    assert rows["emit"].endswith("idle     1.000 ms")
+    assert rows["admit_prep"].startswith("      admit_prep")  # nested
+    assert ps.main(["--account", str(line)]) == 0
