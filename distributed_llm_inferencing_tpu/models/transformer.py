@@ -513,10 +513,12 @@ def scan_layer_stack(make_body, x, params, cfg: ModelConfig, xs):
 
     ``make_body(seg_cfg)`` returns a ``lax.scan`` body
     ``(carry, (lp, *per_layer_xs)) -> (carry, per_layer_out)``;
-    ``xs`` is a tuple of [L, ...]-stacked per-layer arrays (cache or
-    pool planes; the decode chunks pass ``arange(L)``, so that a
-    segment's body gets its layers' indices in the whole stack), and
-    ``x`` any pytree the body carries (the decode chunks carry their
+    ``xs`` is a tuple of [L, ...]-stacked per-layer arrays (the dense
+    cache's planes, or the block pool's in paged_decode_step; the admit
+    and decode-chunk programs pass ``arange(L)`` and close over the
+    stacked pool, so that a segment's body gets its layers' indices in
+    the whole stack and the pool is no input or output of the stack),
+    and ``x`` any pytree the body carries (the decode chunks carry their
     side buffers beside the hidden state). Each segment scans its own
     stacked tree (or, for the batcher's per-layer lists of MoE layers,
     loops Python-side);
@@ -638,9 +640,10 @@ def _mla_latent_rows(h, lp, cfg: ModelConfig, q_positions):
 
 
 def _mla_split_rows(rows, cfg: ModelConfig):
-    """Latent rows [B,s,1,rd+r] -> (k_rot [B,s,1,rd], c [B,s,r])."""
+    """Latent rows [B,s,1,rd+r] (or as a pool stores them, zeros after
+    the rd + r columns) -> (k_rot [B,s,1,rd], c [B,s,r])."""
     rd = cfg.qk_rope_head_dim
-    return rows[..., :rd], rows[:, :, 0, rd:]
+    return rows[..., :rd], rows[:, :, 0, rd:rd + cfg.kv_lora_rank]
 
 
 def _mla_absorbed(q, lp, cfg: ModelConfig, attend_rows):
@@ -1211,17 +1214,16 @@ def _pool_pregather(paged, block_tables, dt):
 
 
 @jax.named_scope("kv_gather")
-def _layer_gather(pool, scales, block_tables, dt, kind=None, layer=None):
+def _layer_gather(pool, scales, block_tables, dt, layer, kind=None):
     """One layer's planes gathered inside the step (long contexts, where
-    the whole chunk's gather would pass _PREGATHER_MAX_BYTES); ``scales``
-    is the layer's (k_scale, v_scale) for an int8 pool, else empty.
-    ``kind`` (win | full) names the layer's kind as an inner scope where
-    the model has both. Under a layer scan ``layer`` is the scan's index
-    and ``pool`` and ``scales`` are the stacked [L, NB, ...] planes as
-    they lie: the gather goes by (layer, block), so no layer's slice is
-    copied out of the stack on the way to a lax.switch branch. Where
-    layers are held one by one it is absent and the planes are that
-    layer's own."""
+    the whole chunk's gather would pass _PREGATHER_MAX_BYTES). ``pool``
+    and ``scales`` (an int8 pool's (k_scale, v_scale), else empty) are
+    the stacked [L, NB, ...] planes as they lie and ``layer`` the
+    layer's index, the scan's or a constant where layers are held one by
+    one: the gather goes by (layer, block), so no layer's slice is
+    copied out of the stack, on the way to a lax.switch branch or
+    hoisted out of the token loop. ``kind`` (win | full) names the
+    layer's kind as an inner scope where the model has both."""
     from distributed_llm_inferencing_tpu.ops.paged_kvcache import (
         gather_seq, kind_scope)
     with kind_scope(kind):
@@ -1235,21 +1237,15 @@ def _layer_gather(pool, scales, block_tables, dt, kind=None, layer=None):
     return got
 
 
-def _write_side(side, new, at, dt, layer=None):
+def _write_side(side, new, at, dt, layer):
     """Put a pass's fresh rows ``new`` ([R, n, Hkv, w] a plane) into the
     chunk's side buffers at entry ``at``. Returns (the buffers to carry
     on, this layer's [R, K, Hkv, w] rows for attention's side segment).
-    Where layers are held one by one ``side`` is the layer's own slice
-    and the two are the same; under a layer scan ``side`` is the whole
-    [L, R, K, Hkv, w] stack riding the scan's carry and ``layer`` the
-    scan's index: the rows are written in place and the stack is never
-    sliced into per-layer inputs and stacked again from outputs."""
+    ``side`` is the whole [L, R, K, Hkv, w] stack riding the layer
+    stack's carry and ``layer`` the layer's index: the rows are written
+    in place and the stack is never sliced into per-layer inputs and
+    stacked again from outputs."""
     with jax.named_scope("kv_write"):
-        if layer is None:
-            rows = tuple(
-                jax.lax.dynamic_update_slice(s_, n_.astype(dt), (0, at, 0, 0))
-                for s_, n_ in zip(side, new))
-            return rows, rows
         side = tuple(
             jax.lax.dynamic_update_slice(s_, n_.astype(dt)[None],
                                          (layer, 0, at, 0, 0))
@@ -1280,17 +1276,17 @@ def _pool_ladder(mb: int, scanned: bool = True):
     so a rung is at most 1.5 times the one below it from a quarter up
     (mistral's 128 -> 16/32/48/64/96/128, a toy 6 -> 1/2/3/5/6). A rung
     is a branch of one lax.switch in the layer body, not a program.
-    A conditional takes its operands as buffers. Under a layer scan
-    (``scanned``) they are the stacked pool as it lies and the scan's
-    layer index, and the branch gathers by (layer, block)
-    (_layer_gather): handed the scan's slice of the pool instead, XLA
-    copied every layer's slice out of the stack on every pass (PERF.md
-    section 6, PR 36). Where layers are held one by one the slice is a
-    static one of the stacked pool, copied out the same way, and a
-    switch around the whole chunk costs seconds a program at every
-    start, so there the ladder is the full extent alone: lax.switch
-    inlines its one branch, and the gather fuses into attention.
-    PERF.md section 6, PR 30, has the chip's numbers for each."""
+    A conditional takes its operands as buffers: they are the stacked
+    pool as it lies and the layer's index, and the branch gathers by
+    (layer, block) (_layer_gather); handed the scan's slice of the pool
+    instead, XLA copied every layer's slice out of the stack on every
+    pass (PERF.md section 6, PR 36). Where layers are held one by one
+    (not ``scanned``) a branch in the layer body fuses less than the
+    code outside it and a switch around the whole chunk costs seconds a
+    program at every start, so there the ladder is the full extent
+    alone: lax.switch inlines its one branch, and the gather fuses into
+    attention. PERF.md section 6, PR 30, has the chip's numbers for
+    each."""
     if not scanned:
         return (mb,)
     return tuple(sorted({-(-mb * n // 8) for n in (1, 2, 3, 4, 6, 8)}))
@@ -1311,28 +1307,26 @@ def _pool_rung(ladder, bs: int, context_lens, live):
 
 
 def _attend_pool_rung(rung, ladder, pre: bool, planes, scales, block_tables,
-                      dt, pool_pos, pool_valid, attend_pool, kind=None,
-                      layer=None):
+                      dt, pool_pos, pool_valid, attend_pool, layer,
+                      kind=None):
     """The pool side of a decode chunk's attention, as far as ``rung``
     says (lax.switch: only the taken branch runs). Branch i takes the
     first ``ladder[i]`` columns of the block tables -- a slice of the
     pre-gathered planes when ``pre``, else this layer's gather -- and
     calls ``attend_pool(planes, positions, valid)``, which brings the
-    side segment: all scores still meet in one softmax. Under a layer
-    scan ``layer`` is the scan's index and ``planes`` (and ``scales``)
-    are stacked [L, ...], taken by the branch as they lie
-    (_layer_gather)."""
+    side segment: all scores still meet in one softmax. ``planes`` (and
+    ``scales``) are stacked [L, ...] and ``layer`` is the layer's index:
+    the branch takes them as they lie (_layer_gather)."""
     bs = pool_pos.shape[1] // block_tables.shape[1]
 
     def branch(mb_i):
         def run():
             n = mb_i * bs
             if pre:
-                got = tuple(p[:, :n] if layer is None else p[layer, :, :n]
-                            for p in planes)
+                got = tuple(p[layer, :, :n] for p in planes)
             else:
                 got = _layer_gather(planes, scales, block_tables[:, :mb_i],
-                                    dt, kind, layer)
+                                    dt, layer, kind)
             return attend_pool(got, pool_pos[:, :n], pool_valid[:, :n])
         return run
     return jax.lax.switch(rung, [branch(m) for m in ladder])
@@ -1368,13 +1362,15 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
     *side buffer* [L, R, K, Hkv, hd] (dynamic_update_slice at step index)
     instead of two dynamic scatters into the block pool per layer per
     step, and the whole side buffer scatters into the pool in ONE op
-    after the scan. Under a layer scan no cache state rides the scan as
-    per-layer inputs and outputs: the scan brings the layer's index, the
-    side buffers ride its carry whole and are written in place at
-    (layer, 0, step, 0, 0) (_write_side), and the in-loop gather indexes
-    the stacked pool by (layer, block). Where layers are held one by one
-    (the batcher's MoE layers) each layer takes its own static slices,
-    as before. Each step's attention takes two KV segments,
+    after the scan. No cache state rides the layer stack as per-layer
+    inputs and outputs, scanned or held one by one (the batcher's MoE
+    layers): a layer takes its index, the side buffers ride the stack's
+    carry whole and are written in place at (layer, 0, step, 0, 0)
+    (_write_side), and the in-loop gather indexes the stacked pool by
+    (layer, block). (Static slices of the loop-invariant pool were
+    hoisted out of the token loop and re-laid out, the whole pool once a
+    chunk: PERF.md section 6, PR 38.) Each step's attention takes two KV
+    segments,
     ``gather(pool) masked < cl0`` and ``side masked <= t``
     (ops/attention.attend): their scores meet in one softmax and K and V
     are never concatenated or widened. The pool is loop-invariant during
@@ -1412,7 +1408,7 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
     """
     from distributed_llm_inferencing_tpu.ops.attention import attend
     from distributed_llm_inferencing_tpu.ops.paged_kvcache import (
-        PagedKVCache, kind_scope, window_read)
+        PagedKVCache, kind_scope, window_read, write_rows)
     from distributed_llm_inferencing_tpu.ops.sampling import sample_batch
 
     from distributed_llm_inferencing_tpu.ops.pallas import fused_decode
@@ -1439,8 +1435,7 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
     pool_pos = jnp.broadcast_to(jnp.arange(mb * bs, dtype=jnp.int32),
                                 (r, mb * bs))
     pool_valid = pool_pos < cl0[:, None]
-    scanned = _layers_scanned(params, cfg)
-    ladder = _pool_ladder(mb, scanned)
+    ladder = _pool_ladder(mb, _layers_scanned(params, cfg))
     rung, pool_positions = _pool_rung(ladder, bs, cl0, budget > 0)
     side_pos = cl0[:, None] + jnp.arange(k, dtype=jnp.int32)[None, :]
     side0 = (jnp.zeros((L, r, k, cfg.cache_kv_heads, cfg.cache_head_dim),
@@ -1479,18 +1474,14 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
 
         def make_layer(seg_cfg):
             def layer(carry, layer_in):
-                lp, *rest = layer_in
-                if scanned:   # the planes where they lie, by the index
-                    (x, sd), (li,), pl, sc = carry, rest, pool, scales
-                else:         # this layer's static slices
-                    x, li, sd = carry, None, rest[:n_planes]
-                    pl, sc = (rest[n_planes:2 * n_planes],
-                              rest[2 * n_planes:])
+                (x, sd), (lp, li) = carry, layer_in
 
                 def attend_side(q, sd2, sliding_window=None, **kw):
                     kind = _layer_kind(cfg, sliding_window)
 
                     def attend_pool(got, pos, valid):
+                        if latent:   # the rows' own columns (lane_width)
+                            got = (got[0][..., :cfg.cache_head_dim],)
                         with jax.named_scope("attention"), kind_scope(kind):
                             # a latent pool's rows stand for K and for V
                             return attend(
@@ -1500,17 +1491,16 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
                     if kind == "win" and sliding_window in win_reads:
                         bt_w, pos_w, valid_w = win_reads[sliding_window]
                         return attend_pool(
-                            _layer_gather(pl, sc, bt_w, dt, kind, li),
+                            _layer_gather(pool, scales, bt_w, dt, li, kind),
                             pos_w, valid_w)
                     return _attend_pool_rung(
-                        rung, ladder, pre, pl, sc, block_tables, dt,
-                        pool_pos, pool_valid, attend_pool, kind, li)
+                        rung, ladder, pre, pool, scales, block_tables, dt,
+                        pool_pos, pool_valid, attend_pool, li, kind)
 
                 def done(x2, out):
                     # (side buffers..., moe): the buffers go on in the
-                    # scan's carry, or out as this layer's slices
-                    return ((x2, out[:-1]), out[-1:]) if scanned else (x2,
-                                                                       out)
+                    # stack's carry, the layer's MOE_STATS out
+                    return (x2, out[:-1]), out[-1:]
 
                 tail = dict(valid=alive[:, None], moe_stats=True)
                 if latent:
@@ -1537,13 +1527,9 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
                                          lora_ids=lora_ids, **tail))
             return layer
 
-        if scanned:
-            (x2, side), (moe,) = scan_layer_stack(
-                make_layer, (x, side), params, cfg,
-                (jnp.arange(L, dtype=jnp.int32),))
-        else:
-            x2, (*side, moe) = scan_layer_stack(make_layer, x, params, cfg,
-                                                side + pool + scales)
+        (x2, side), (moe,) = scan_layer_stack(
+            make_layer, (x, side), params, cfg,
+            (jnp.arange(L, dtype=jnp.int32),))
         logits = unembed(params, cfg, x2)[:, 0]
         with jax.named_scope("sample"):
             nxt = sample_batch(logits, seeds, steps0 + t, temps, tks, tps,
@@ -1555,7 +1541,7 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
         # a pass nobody is alive in (the chunk outran every budget)
         # counts for nothing
         moe = jnp.sum(moe, axis=0) * jnp.any(alive)
-        return (nxt, tuple(side), new_cl, new_alive), (nxt, emit, alive, moe)
+        return (nxt, side, new_cl, new_alive), (nxt, emit, alive, moe)
 
     (_, side, _, _), (toks, emits, wrote, moe) = jax.lax.scan(
         body, (tokens, side0, context_lens, budget > 0),
@@ -1579,7 +1565,7 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
             side = (k8, v8, ks, vs)
         return toks, emits, moe, pool_positions, window_positions, \
             PagedKVCache(*(
-                plane.at[:, blk, off].set(jnp.swapaxes(sd, 1, 2))
+                write_rows(plane, jnp.swapaxes(sd, 1, 2), blk, off)
                 for plane, sd in zip(paged.planes(), side)))
 
 
@@ -1683,7 +1669,7 @@ def paged_speculative_chunk(params, cfg: ModelConfig, k: int, gamma: int,
     """
     from distributed_llm_inferencing_tpu.ops.attention import attend
     from distributed_llm_inferencing_tpu.ops.paged_kvcache import (
-        PagedKVCache)
+        PagedKVCache, write_rows)
     from distributed_llm_inferencing_tpu.ops.speculative import (
         accept_rejection_batch, propose_ngram_device)
 
@@ -1701,8 +1687,7 @@ def paged_speculative_chunk(params, cfg: ModelConfig, k: int, gamma: int,
     pool_pos = jnp.broadcast_to(jnp.arange(mb * bs, dtype=jnp.int32),
                                 (r, mb * bs))
     pool_valid = pool_pos < cl0[:, None]
-    scanned = _layers_scanned(params, cfg)
-    ladder = _pool_ladder(mb, scanned)
+    ladder = _pool_ladder(mb, _layers_scanned(params, cfg))
     rung, _ = _pool_rung(ladder, bs, cl0, budget > 0)
     side0 = jnp.zeros((L, r, E, cfg.num_kv_heads, cfg.head_dim), dt)
     entry_step = jnp.arange(E, dtype=jnp.int32) // g1               # [E]
@@ -1729,13 +1714,8 @@ def paged_speculative_chunk(params, cfg: ModelConfig, k: int, gamma: int,
         side_valid = acc_mask | is_cur_block
 
         def make_layer(seg_cfg):
-            def layer(carry, layer_in):
-                lp, *rest = layer_in
-                if scanned:   # as in paged_decode_chunk
-                    (x, sd), (li,), pl, sc = carry, rest, pool, scales
-                else:
-                    x, li, sd, pl, sc = (carry, None, rest[:2], rest[2:4],
-                                         rest[4:])
+            def layer(carry, layer_in):   # as in paged_decode_chunk
+                (x, sd), (lp, li) = carry, layer_in
 
                 def attend_write(q, kh, vh):
                     sd2, (sk2, sv2) = _write_side(sd, (kh, vh), t * g1, dt,
@@ -1751,22 +1731,18 @@ def paged_speculative_chunk(params, cfg: ModelConfig, k: int, gamma: int,
                                 softcap=seg_cfg.attn_softcap,
                                 sinks=_sinks(seg_cfg, lp))
                     attn = _attend_pool_rung(
-                        rung, ladder, pre, pl, sc, block_tables, dt,
-                        pool_pos, pool_valid, attend_pool, layer=li)
+                        rung, ladder, pre, pool, scales, block_tables, dt,
+                        pool_pos, pool_valid, attend_pool, li)
                     return attn, sd2
 
                 x2, sd2 = _block_body(x, lp, seg_cfg, qp, attend_write,
                                       lora_ids=lora_ids)
-                return ((x2, sd2), ()) if scanned else (x2, sd2)
+                return (x2, sd2), ()
             return layer
 
-        if scanned:
-            (x2, (side_k, side_v)), _ = scan_layer_stack(
-                make_layer, (x, (side_k, side_v)), params, cfg,
-                (jnp.arange(L, dtype=jnp.int32),))
-        else:
-            x2, (side_k, side_v) = scan_layer_stack(
-                make_layer, x, params, cfg, (side_k, side_v) + pool + scales)
+        (x2, (side_k, side_v)), _ = scan_layer_stack(
+            make_layer, (x, (side_k, side_v)), params, cfg,
+            (jnp.arange(L, dtype=jnp.int32),))
         logits = unembed(params, cfg, x2)                 # [R, g1, V] f32
 
         # per-row acceptance (ops/speculative.py): greedy rows accept
@@ -1843,14 +1819,12 @@ def paged_speculative_chunk(params, cfg: ModelConfig, k: int, gamma: int,
             from distributed_llm_inferencing_tpu.ops.kvcache import quant_kv
             k8, ks = quant_kv(side_k)
             v8, vs = quant_kv(side_v)
-            paged = PagedKVCache(
-                k=paged.k.at[:, blk, off].set(k8),
-                v=paged.v.at[:, blk, off].set(v8),
-                k_scale=paged.k_scale.at[:, blk, off].set(ks),
-                v_scale=paged.v_scale.at[:, blk, off].set(vs))
+            side = (k8, v8, ks, vs)
         else:
-            paged = PagedKVCache(k=paged.k.at[:, blk, off].set(side_k),
-                                 v=paged.v.at[:, blk, off].set(side_v))
+            side = (side_k, side_v)
+        paged = PagedKVCache(*(
+            write_rows(plane, sd, blk, off)
+            for plane, sd in zip(paged.planes(), side)))
     return toks, keeps, eos_seen, paged
 
 
@@ -1869,6 +1843,16 @@ def paged_prefill_tail(params, cfg: ModelConfig, tokens, tail_len,
     dispatch round trip instead of one per queued request (the reference
     served admissions fully serialized, worker/app.py:252-330).
 
+    The pool is no per-layer input or output of the layer stack: a layer
+    takes its index, gathers its prefix from the stacked planes where
+    they lie by (layer, block), attends the fresh tail from its own
+    projections (never back from the pool), and hands the tail's rows out
+    ([L, B, T, Hkv, w] a plane: small). After the stack one scatter a
+    plane writes them into the donated pool in place. Handed the planes
+    layer by layer and given them back re-stacked, XLA could not alias
+    the donated pool to the result and copied it whole, two to four
+    times a wave (PERF.md section 6, PR 38).
+
     tokens: [B, T] right-padded tails (T a multiple of block_size);
     tail_len: [B] real tail tokens (>= 1; padding rows use 1);
     tail_blocks: [B, T // bs] int32 (padding rows all-dummy; legacy
@@ -1877,7 +1861,7 @@ def paged_prefill_tail(params, cfg: ModelConfig, tokens, tail_len,
     Returns (last-token logits [B, V] f32, new paged).
     """
     from distributed_llm_inferencing_tpu.ops.paged_kvcache import (
-        PagedKVCache, paged_attend_prefix, write_block_run)
+        PagedKVCache, paged_attend_prefix, write_blocks)
     b, t = tokens.shape
     if tail_blocks.ndim == 1:
         tail_blocks = tail_blocks[None]
@@ -1888,12 +1872,10 @@ def paged_prefill_tail(params, cfg: ModelConfig, tokens, tail_len,
         jnp.arange(t, dtype=jnp.int32), (b, t))
     tail_valid = jnp.arange(t, dtype=jnp.int32)[None, :] < tail_len[:, None]
     x = embed(params, cfg, tokens, q_pos)
-    quantized = paged.quantized
 
     def make_body(seg_cfg):
         def body(x, layer_in):
-            lp, ck, *rest = layer_in
-            cv, scales = (rest[0], rest[1:]) if rest else (None, ())
+            lp, li = layer_in
 
             if seg_cfg.mla_latent_cache:
                 # the pool takes the tail's latent rows and nothing else;
@@ -1909,58 +1891,49 @@ def paged_prefill_tail(params, cfg: ModelConfig, tokens, tail_len,
 
                 def mla_latent_attend(h, qp):
                     rows = _mla_latent_rows(h, lp, seg_cfg, qp)
-                    with jax.named_scope("kv_write"):
-                        nk = write_block_run(ck, rows, tail_blocks)
                     k, v = expand(rows)
                     attn = paged_attend_prefix(
-                        _mla_q(h, lp, seg_cfg, qp), k, v, nk, None,
+                        _mla_q(h, lp, seg_cfg, qp), k, v, paged.k, None,
                         prefix_blocks, prefix_len, qp, tail_valid,
-                        expand_rows=expand)
-                    return attn, (nk,)
+                        expand_rows=expand, layer=li)
+                    return attn, (rows,)
                 return _block_body(x, lp, seg_cfg, q_pos, None,
                                    mla_latent_attend=mla_latent_attend,
                                    valid=tail_valid)
 
             def attend_write(q, k, v):
-                if quantized:
-                    # store int8 + scales; the tail attends its own fresh
-                    # bf16 K/V plus the dequantized cached prefix
-                    from distributed_llm_inferencing_tpu.ops.kvcache import (
-                        quant_kv)
-                    cks, cvs = scales
-                    with jax.named_scope("kv_write"):
-                        k8, ks = quant_kv(k)
-                        v8, vs = quant_kv(v)
-                        nk = write_block_run(ck, k8, tail_blocks)
-                        nv = write_block_run(cv, v8, tail_blocks)
-                        nks = write_block_run(cks, ks, tail_blocks)
-                        nvs = write_block_run(cvs, vs, tail_blocks)
-                    attn = paged_attend_prefix(
-                        q, k, v, nk, nv, prefix_blocks, prefix_len, q_pos,
-                        tail_valid,
-                        sliding_window=_layer_window(seg_cfg, lp),
-                        k_scale_layer=nks, v_scale_layer=nvs,
-                        alibi=_alibi(seg_cfg), softcap=seg_cfg.attn_softcap,
-                        sinks=_sinks(seg_cfg, lp))
-                    return attn, (nk, nv, nks, nvs)
-                with jax.named_scope("kv_write"):
-                    nk = write_block_run(ck, k, tail_blocks)
-                    nv = write_block_run(cv, v, tail_blocks)
                 win = _layer_window(seg_cfg, lp)
                 attn = paged_attend_prefix(
-                    q, k, v, nk, nv, prefix_blocks, prefix_len, q_pos,
-                    tail_valid, sliding_window=win,
+                    q, k, v, paged.k, paged.v, prefix_blocks, prefix_len,
+                    q_pos, tail_valid, sliding_window=win,
+                    k_scale_layer=paged.k_scale, v_scale_layer=paged.v_scale,
                     alibi=_alibi(seg_cfg), softcap=seg_cfg.attn_softcap,
-                    sinks=_sinks(seg_cfg, lp), kind=_layer_kind(cfg, win))
-                return attn, (nk, nv)
+                    sinks=_sinks(seg_cfg, lp), kind=_layer_kind(cfg, win),
+                    layer=li)
+                if not paged.quantized:
+                    return attn, (k, v)
+                # store int8 + scales; the tail attended its own fresh
+                # bf16 K/V plus the dequantized cached prefix
+                from distributed_llm_inferencing_tpu.ops.kvcache import (
+                    quant_kv)
+                with jax.named_scope("kv_write"):
+                    k8, ks = quant_kv(k)
+                    v8, vs = quant_kv(v)
+                return attn, (k8, v8, ks, vs)
 
             return _block_body(x, lp, seg_cfg, q_pos, attend_write,
                                lora_ids=lora_ids, valid=tail_valid)
         return body
 
-    x, cache_out = scan_layer_stack(make_body, x, params, cfg,
-                                    paged.planes())
-    new_paged = PagedKVCache(*cache_out)
+    x, tails = scan_layer_stack(
+        make_body, x, params, cfg,
+        (jnp.arange(cfg.num_layers, dtype=jnp.int32),))
+    # ONE write a plane of every layer's tail rows, whole blocks, into
+    # the pool where it lies
+    with jax.named_scope("kv_write"):
+        new_paged = PagedKVCache(*(
+            write_blocks(plane, rows, tail_blocks)
+            for plane, rows in zip(paged.planes(), tails)))
     # project only the last real position through the vocab head ([D,V] over
     # one row per sequence, not T padded rows)
     last_x = jnp.take_along_axis(

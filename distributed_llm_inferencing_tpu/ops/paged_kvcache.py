@@ -10,8 +10,9 @@ vLLM/PagedAttention:
   layer. Which blocks a sequence owns is *host-side* state, managed by the
   native C++ allocator (native/src/block_pool.cc) with ref-counted radix
   prefix sharing. An MLA model's pool is latent (cfg.mla_latent_cache):
-  ``k`` alone, [L, NB, bs, 1, rd + r], one shared row a token a layer
-  (transformer._mla_latent_rows), and no ``v``. A block is a block: the
+  ``k`` alone, [L, NB, bs, 1, lane_width(rd + r)], one shared row a
+  token a layer (transformer._mla_latent_rows) in the first rd + r
+  columns, zeros after them, and no ``v``. A block is a block: the
   allocator, the block tables and the radix cache never look inside.
 - ``block_tables``: [R, MB] int32 — per serving *slot*, the block ids
   covering its sequence, in order. Slot count R and max-blocks MB are
@@ -42,6 +43,34 @@ import jax.numpy as jnp
 
 from distributed_llm_inferencing_tpu.models.config import ModelConfig
 from distributed_llm_inferencing_tpu.ops.attention import attend
+
+
+LANES = 128   # a TPU tile's minor extent
+
+
+def lane_width(w: int) -> int:
+    """``w`` rounded up to whole 128-lane tiles: the row width a latent
+    pool is stored at. The TPU's default layout of an array whose minor
+    axis is not a multiple of 128 puts another axis minor-most
+    (bf16[7,10241,16,1,576] arrives as {1,4,3,2,0}: the block axis), a
+    layout no gather or scatter takes, so every program that touched
+    kanana's pool first re-laid the whole of it out, as far as 640
+    columns, and copied it back (PERF.md section 6, PR 38). Stored 640
+    wide it arrives as {4,2,3,1,0}: blocks of (positions, columns)
+    tiles, the singleton head axis out of the way, which the gather by
+    (layer, block) and the scatters read and write where it lies."""
+    return -(-w // LANES) * LANES
+
+
+def fit_rows(rows, plane):
+    """``rows`` [..., w] in ``plane``'s dtype and at its row width: zeros
+    after a latent pool's rd + r columns (lane_width), nothing to do for
+    any other plane."""
+    rows = rows.astype(plane.dtype)
+    pad = plane.shape[-1] - rows.shape[-1]
+    if pad == 0:
+        return rows
+    return jnp.pad(rows, [(0, 0)] * (rows.ndim - 1) + [(0, pad)])
 
 
 class PagedKVCache(NamedTuple):
@@ -82,7 +111,8 @@ def init_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
     shape = (cfg.num_layers, num_blocks, block_size, cfg.cache_kv_heads,
              cfg.cache_head_dim)
     if cfg.mla_latent_cache:   # config.py refuses kv_quant with it
-        return PagedKVCache(k=jnp.zeros(shape, dtype))
+        return PagedKVCache(k=jnp.zeros(
+            shape[:-1] + (lane_width(shape[-1]),), dtype))
     if cfg.kv_quant == "int8":
         return PagedKVCache(
             k=jnp.zeros(shape, jnp.int8), v=jnp.zeros(shape, jnp.int8),
@@ -103,7 +133,7 @@ def write_token(cache_layer, new, block_tables, positions):
     blk = jnp.take_along_axis(
         block_tables, (positions // bs)[:, None], axis=1)[:, 0]   # [R]
     off = positions % bs
-    return cache_layer.at[blk, off].set(new.astype(cache_layer.dtype))
+    return cache_layer.at[blk, off].set(fit_rows(new, cache_layer))
 
 
 def write_block_run(cache_layer, new_blocks, block_ids):
@@ -121,7 +151,54 @@ def write_block_run(cache_layer, new_blocks, block_ids):
     b, t = new_blocks.shape[:2]
     reshaped = new_blocks.reshape(b * (t // bs), bs, *new_blocks.shape[2:])
     return cache_layer.at[block_ids.reshape(-1)].set(
-        reshaped.astype(cache_layer.dtype))
+        fit_rows(reshaped, cache_layer))
+
+
+def write_rows(plane, rows, blk, off):
+    """Rows of every layer into the stacked plane, in place in a donated
+    pool: a program's ONE write of it (the decode chunks' side rows, an
+    admission's tails through write_blocks).
+
+    plane: [L, NB, bs, Hkv, w] (a scale plane: no w); rows:
+    [L, *blk.shape, Hkv, w]; blk, off: the block id and the offset in it
+    of each position. Duplicate positions may only occur on the reserved
+    dummy block, where last-write-wins garbage is by design.
+
+    What the scatter moves follows the plane's tile. Where the tile's
+    second-minor axis is the heads' it is a position's slab over the
+    layers and heads, [L, Hkv, w]. With one head (the latent pool, MQA)
+    that axis is the block's positions, and for such a slab XLA re-lays
+    the whole pool out to put the layers there, scatters, and copies it
+    back (kanana's decode chunk: `copy.922`, `copy.927`, 9.6 ms a chunk
+    against 0.43; PERF.md section 6, PR 38): there the layer is an
+    index too and the scatter moves rows."""
+    rows = fit_rows(rows, plane)
+    if plane.shape[3] != 1:
+        return plane.at[:, blk, off].set(rows)
+    layer = jnp.arange(plane.shape[0]).reshape((-1,) + (1,) * blk.ndim)
+    return plane.at[layer, blk, off].set(rows)
+
+
+def write_blocks(plane, rows, block_ids):
+    """Runs of whole blocks of every layer (a wave's prefilled tails)
+    into the stacked plane, in place in a donated pool: one scatter.
+
+    plane: [L, NB, bs, Hkv, w] (a scale plane: no w); rows:
+    [L, B, T, Hkv, w] with T a multiple of bs; block_ids: [B, T // bs].
+    Duplicate ids may only occur on the reserved dummy block (padding
+    rows), as in write_block_run, which writes one layer's plane.
+
+    Where the heads leave the tile's second-minor axis part empty
+    (trinity's 4 of 8) XLA re-tiles both planes for this window and
+    copies them back: four passes over a plane a wave that a scatter by
+    position (write_rows) would not make. It is not taken: with it the
+    wave's prefix gather reads the (4, 128)-tiled pool directly, and
+    that program (tail 512 over 256 prefix blocks, 2 rows) halted the
+    core on a v5e (PERF.md section 6, PR 38)."""
+    L, bs = plane.shape[0], plane.shape[2]
+    b, t = rows.shape[1:3]
+    return plane.at[:, block_ids.reshape(-1)].set(fit_rows(
+        rows.reshape(L, b * (t // bs), bs, *rows.shape[3:]), plane))
 
 
 def gather_seq(cache_layer, block_tables, layer=None):
@@ -204,8 +281,9 @@ def paged_attend_decode(q, cache_k_layer, cache_v_layer, block_tables,
     r, mb = block_tables.shape
     bs = cache_k_layer.shape[1]
     with jax.named_scope("kv_gather"):
-        k = gather_seq(cache_k_layer, block_tables)
-        # a latent pool's rows are K and V at once: gathered once
+        # a latent pool's rows are K and V at once, gathered once, and
+        # as wide as q_eff in their first columns (lane_width)
+        k = gather_seq(cache_k_layer, block_tables)[..., :q.shape[-1]]
         v = (k if cache_v_layer is cache_k_layer
              else gather_seq(cache_v_layer, block_tables))
         if k_scale_layer is not None:
@@ -230,7 +308,8 @@ def paged_attend_prefix(q, k_new, v_new, cache_k_layer, cache_v_layer,
                         sliding_window: Optional[int] = None,
                         k_scale_layer=None, v_scale_layer=None,
                         alibi=None, softcap: Optional[float] = None, sinks=None,
-                        expand_rows=None, kind: Optional[str] = None):
+                        expand_rows=None, kind: Optional[str] = None,
+                        layer=None):
     """Tail-prefill attention: fresh tail K/V plus a cached prefix.
 
     This is what makes prefix-cache hits save *compute*, not just memory:
@@ -253,24 +332,29 @@ def paged_attend_prefix(q, k_new, v_new, cache_k_layer, cache_v_layer,
     a row, only the columns that hold the window behind ``prefix_len``
     (window_read: the tail's first query sees the most of the prefix).
     ``kind`` (win | full) names the layer's kind as an inner scope.
+
+    With ``layer`` (gather_seq) the planes and scales are the stacked
+    [L, NB, ...] ones and the prefix is gathered by (layer, block): the
+    tail is never read back from the pool, so the caller may write it
+    after the whole stack (transformer.paged_prefill_tail).
     """
     b, t = q.shape[0], q.shape[1]
-    bs = cache_k_layer.shape[1]
+    bs = cache_k_layer.shape[1 if layer is None else 2]
     read = (window_read(sliding_window, bs, prefix_blocks, prefix_len)
             if isinstance(sliding_window, int) else None)
     if read is not None:
         prefix_blocks, prefix_pos = read
     with jax.named_scope("kv_gather"), kind_scope(kind):
-        kp = gather_seq(cache_k_layer, prefix_blocks)   # [B, PB*bs, Hkv, hd]
+        kp = gather_seq(cache_k_layer, prefix_blocks, layer)  # [B, PB*bs, ..]
         if expand_rows is None:
-            vp = gather_seq(cache_v_layer, prefix_blocks)
+            vp = gather_seq(cache_v_layer, prefix_blocks, layer)
         if k_scale_layer is not None:   # int8 pool: dequantize the prefix
             from distributed_llm_inferencing_tpu.ops.kvcache import (
                 dequant_kv)
-            kp = dequant_kv(kp, gather_seq(k_scale_layer, prefix_blocks),
-                            q.dtype)
-            vp = dequant_kv(vp, gather_seq(v_scale_layer, prefix_blocks),
-                            q.dtype)
+            kp = dequant_kv(
+                kp, gather_seq(k_scale_layer, prefix_blocks, layer), q.dtype)
+            vp = dequant_kv(
+                vp, gather_seq(v_scale_layer, prefix_blocks, layer), q.dtype)
     if expand_rows is not None:
         kp, vp = expand_rows(kp)
     if read is None:

@@ -542,7 +542,7 @@ class ContinuousBatcher:
             init_paged_cache(cfg, num_blocks + 1, block_size),
             shd.named(self.mesh, shd.paged_cache_specs(cfg, self.mesh_spec)))
         # what one cached token takes of the pool, from the pool's own
-        # shape (a latent pool: L x (rd + r) x 2 bytes)
+        # shape (a latent pool: L x lane_width(rd + r) x 2 bytes)
         self.metrics.gauge("batcher_kv_bytes_per_token",
                            float(self.paged.bytes_per_token))
         if cfg.is_moe:

@@ -5,19 +5,23 @@ described TPU — no chip, no arrays — before spending a chip call.
     JAX_PLATFORMS=cpu python scripts/compile_serving_programs.py [tp ...]
     JAX_PLATFORMS=cpu MODEL=kanana python scripts/compile_serving_programs.py 1
     JAX_PLATFORMS=cpu MODEL=trinity python scripts/compile_serving_programs.py 1
+    JAX_PLATFORMS=cpu MODEL=mistral-cell python scripts/compile_serving_programs.py 1
 
 For each tensor-parallel width given (default: 1 and 4) the real jitted
 programs of ``runtime/batcher.py`` are lowered for ``v5e:2x2`` at
 chip_smoke.py's serving shape (mistral-7b int8, 32 layers; ``LAYERS=2``
-compiles as long: the stack is one scan), or with ``MODEL=kanana`` at
-the benchmark cell's (kanana-2-30b-a3b bf16, 7 layers, 64 slots, the
-latent pool of 10,240 blocks) or ``MODEL=trinity`` at its cell's
+compiles as long: the stack is one scan), with ``MODEL=mistral-cell`` at
+the benchmark cells' (16 slots, chunks of 8 passes), with
+``MODEL=kanana`` at its cell's (kanana-2-30b-a3b bf16, 7 layers, 64
+slots, the latent pool of 10,240 blocks) or ``MODEL=trinity`` at its cell's
 (trinity-mini bf16, 5 layers, 64 slots, 12,288 blocks, contexts to 9216:
 the 2-row admit over a 512-block prefix and the decode chunks with the
 windowed read), with shapes from
 ``jax.eval_shape`` and shardings from ``parallel/sharding.py``. Prints
-what ``compiled.memory_analysis()`` says each device must hold and the
-collectives in the program text. With ``TEXT_DIR=<dir>`` each program's
+what ``compiled.memory_analysis()`` says each device must hold, the
+collectives in the program text, and every instruction that yields a
+pool-sized or plane-sized array (``pool_sized``: a program that reads
+the pool where it lies has one scatter a plane and nothing else). With ``TEXT_DIR=<dir>`` each program's
 text goes there too, less what names source lines (each instruction's
 ``metadata={...}``, the tables of files, functions and stack frames at
 the top, and the call-site locations inside a Mosaic kernel's
@@ -28,6 +32,7 @@ is not a chip run: this gives bytes, never a time. It loads libtpu, so
 run it while no test run needs ``tests/test_tpu_compile.py``.
 """
 
+import math
 import os
 import re
 import sys
@@ -55,6 +60,10 @@ from distributed_llm_inferencing_tpu.runtime.batcher import (  # noqa: E402
 TARGETS = {
     "mistral": ("mistral-7b", "int8", 0, 8, 16, 1024, 2048,   # chip_smoke.py
                 ((512, 0, 1), (512, 32, 1), (32, 0, 2)), (32, 1)),
+    # benchmarks/chip/configs/mistral-7b-int8.json: 16 slots, its widest
+    # admit wave, the 2-row one and the smallest; the decode chunks
+    "mistral-cell": ("mistral-7b", "int8", 0, 16, 16, 1024, 2048,
+                     ((512, 0, 16), (512, 0, 2), (128, 0, 1)), (8, 1)),
     # benchmarks/chip/configs/kanana-2-30b-a3b-l7.json: its widest admit
     # programs, its smallest (6 rows an expert: lax.ragged_dot, like
     # every admit program) and its decode chunks (the streaming kernel)
@@ -69,8 +78,9 @@ TARGETS = {
         "rope_layers": (1, 1, 1, 1, 0)}, 64, 16, 12288, 9216,
         ((512, 512, 2), (512, 128, 1)), (8, 1)),
 }
+TARGET = os.environ.get("MODEL", "mistral")
 (MODEL, QUANT, DEPTH, SLOTS, BLOCK, BLOCKS, MAX_SEQ, ADMIT,
- DECODE) = TARGETS[os.environ.get("MODEL", "mistral")]
+ DECODE) = TARGETS[TARGET]
 TEXT_DIR = os.environ.get("TEXT_DIR")
 GIB = 2.0 ** 30
 
@@ -101,9 +111,50 @@ def scrub(text):
     return re.sub(r'"body": *"([A-Za-z0-9+/=]+)"', _kernel_hash, text)
 
 
-def report(name, lowered, t0):
+def pool_sized(text, paged):
+    """(name, operation) of every instruction of a program's text that
+    yields an array with as many elements as a plane of the pool
+    ``paged`` or as one layer of it: a program that reads the pool where
+    it lies and writes it once, in place, has the one scatter a plane
+    and nothing else. Parameters, get-tuple-elements and bitcasts yield
+    no new array, a tuple other than a fusion's (a loop's state, a
+    conditional's operands) only hands arrays on, and what a fusion
+    computes inside is the fusion's one result (a fusion around a
+    scatter is named ``fusion(scatter)``)."""
+    counts = set()
+    for plane in jax.tree.leaves(paged):   # as one device holds it
+        shape = (plane.sharding.shard_shape(plane.shape)
+                 if getattr(plane, "sharding", None) else plane.shape)
+        counts |= {math.prod(shape), math.prod(shape[1:])}
+    fused = set(re.findall(r" fusion\(.*calls=%([^\s,)]+)", text))
+    scatters, found, inside = set(), [], None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) \(.*\{$", line)
+        if head:
+            inside = head.group(1)
+            continue
+        made = re.match(r"\s+(?:ROOT )?%(\S+) = (.*?) ([a-z][\w-]*)\(", line)
+        if not made:
+            continue
+        name, shapes, op = made.groups()
+        if op == "scatter":
+            scatters.add(inside)
+        if inside in fused or op in ("parameter", "get-tuple-element",
+                                     "bitcast") or (
+                shapes.startswith("(") and op != "fusion"):
+            continue
+        if any(math.prod(int(d) for d in dims.split(",")) in counts
+               for dims in re.findall(r"\[([\d,]+)\]", shapes)):
+            calls = re.search(r"calls=%([^\s,)]+)", line)
+            found.append((name, op, calls and calls.group(1)))
+    return [(name, "fusion(scatter)" if called in scatters else op)
+            for name, op, called in found]
+
+
+def report(name, lowered, t0, paged):
     compiled = lowered.compile()
     mem, text = compiled.memory_analysis(), compiled.as_text()
+    sized = pool_sized(text, paged)
     if TEXT_DIR:
         os.makedirs(TEXT_DIR, exist_ok=True)
         with open(os.path.join(
@@ -120,7 +171,9 @@ def report(name, lowered, t0):
           f"pallas calls {text.count('tpu_custom_call')} (ragged-dot "
           f"{len(re.findall(r'%ragged-dot[^ ]* = ', text))}, "
           f"expert_stream_matmul "
-          f"{len(re.findall(r'%expert_stream_matmul[^ ]* = ', text))})",
+          f"{len(re.findall(r'%expert_stream_matmul[^ ]* = ', text))}); "
+          f"pool- or plane-sized arrays made {len(sized)}: "
+          f"{' '.join(f'{n}({op})' for n, op in sized)}",
           flush=True)
 
 
@@ -179,18 +232,18 @@ def main(widths):
             for t, pb, wave in ADMIT:
                 n_ints = wave * (t + t // BLOCK + pb + 6)
                 t0 = time.time()
-                report(f"{MODEL} tp={tp} admit tail={t} prefix_blocks={pb} "
+                report(f"{TARGET} tp={tp} admit tail={t} prefix_blocks={pb} "
                        f"wave={wave}",
                        b._admit_jit(t, pb, wave).lower(
                            params, arr((n_ints,), jnp.int32),
-                           arr((2, wave), jnp.float32), paged), t0)
+                           arr((2, wave), jnp.float32), paged), t0, paged)
             for k in DECODE:
                 t0 = time.time()
-                report(f"{MODEL} tp={tp} decode chunk k={k}",
+                report(f"{TARGET} tp={tp} decode chunk k={k}",
                        b._decode_jit(k, SLOTS, mb).lower(
                            params, arr((SLOTS,), jnp.int32),
                            arr((SLOTS * (mb + 7),), jnp.int32),
-                           arr((2, SLOTS), jnp.float32), paged), t0)
+                           arr((2, SLOTS), jnp.float32), paged), t0, paged)
 
 
 if __name__ == "__main__":

@@ -68,6 +68,106 @@ def tiny_glm45_moe_model(seed=58):
     return model
 
 
+# ---- the plain form of transformer.paged_prefill_tail ------------------
+# As it stood before PR 38: the layer stack takes the pool's planes layer
+# by layer, each layer writes its tail into its slice
+# (write_block_run) and attends its prefix from the slice it wrote, and
+# the slices come back re-stacked. The serving form gathers the prefix
+# from the stacked pool by (layer, block) and writes once after the
+# stack; tests hold the two equal bit for bit (tests/test_decode_gather.py,
+# tests/test_trinity.py).
+
+def paged_prefill_tail_per_layer_write(params, cfg, tokens, tail_len,
+                                       tail_blocks, prefix_blocks,
+                                       prefix_len, paged, lora_ids=None):
+    import jax
+    import jax.numpy as jnp
+    from distributed_llm_inferencing_tpu.models import transformer as tf
+    from distributed_llm_inferencing_tpu.ops.paged_kvcache import (
+        PagedKVCache, paged_attend_prefix, write_block_run)
+    b, t = tokens.shape
+    if tail_blocks.ndim == 1:
+        tail_blocks = tail_blocks[None]
+    if tail_blocks.shape[0] != b:
+        raise ValueError(
+            f"tail_blocks batch {tail_blocks.shape[0]} != tokens batch {b}")
+    q_pos = prefix_len[:, None] + jnp.broadcast_to(
+        jnp.arange(t, dtype=jnp.int32), (b, t))
+    tail_valid = jnp.arange(t, dtype=jnp.int32)[None, :] < tail_len[:, None]
+    x = tf.embed(params, cfg, tokens, q_pos)
+    quantized = paged.quantized
+
+    def make_body(seg_cfg):
+        def body(x, layer_in):
+            lp, ck, *rest = layer_in
+            cv, scales = (rest[0], rest[1:]) if rest else (None, ())
+
+            if seg_cfg.mla_latent_cache:
+                def expand(rows):
+                    return tf._mla_expand(
+                        *tf._mla_split_rows(rows, seg_cfg), lp, seg_cfg)
+
+                def mla_latent_attend(h, qp):
+                    rows = tf._mla_latent_rows(h, lp, seg_cfg, qp)
+                    with jax.named_scope("kv_write"):
+                        nk = write_block_run(ck, rows, tail_blocks)
+                    k, v = expand(rows)
+                    attn = paged_attend_prefix(
+                        tf._mla_q(h, lp, seg_cfg, qp), k, v, nk, None,
+                        prefix_blocks, prefix_len, qp, tail_valid,
+                        expand_rows=expand)
+                    return attn, (nk,)
+                return tf._block_body(
+                    x, lp, seg_cfg, q_pos, None,
+                    mla_latent_attend=mla_latent_attend, valid=tail_valid)
+
+            def attend_write(q, k, v):
+                if quantized:
+                    # store int8 + scales; the tail attends its own fresh
+                    # bf16 K/V plus the dequantized cached prefix
+                    from distributed_llm_inferencing_tpu.ops.kvcache import (
+                        quant_kv)
+                    cks, cvs = scales
+                    with jax.named_scope("kv_write"):
+                        k8, ks = quant_kv(k)
+                        v8, vs = quant_kv(v)
+                        nk = write_block_run(ck, k8, tail_blocks)
+                        nv = write_block_run(cv, v8, tail_blocks)
+                        nks = write_block_run(cks, ks, tail_blocks)
+                        nvs = write_block_run(cvs, vs, tail_blocks)
+                    attn = paged_attend_prefix(
+                        q, k, v, nk, nv, prefix_blocks, prefix_len, q_pos,
+                        tail_valid,
+                        sliding_window=tf._layer_window(seg_cfg, lp),
+                        k_scale_layer=nks, v_scale_layer=nvs,
+                        alibi=tf._alibi(seg_cfg),
+                        softcap=seg_cfg.attn_softcap,
+                        sinks=tf._sinks(seg_cfg, lp))
+                    return attn, (nk, nv, nks, nvs)
+                with jax.named_scope("kv_write"):
+                    nk = write_block_run(ck, k, tail_blocks)
+                    nv = write_block_run(cv, v, tail_blocks)
+                win = tf._layer_window(seg_cfg, lp)
+                attn = paged_attend_prefix(
+                    q, k, v, nk, nv, prefix_blocks, prefix_len, q_pos,
+                    tail_valid, sliding_window=win,
+                    alibi=tf._alibi(seg_cfg), softcap=seg_cfg.attn_softcap,
+                    sinks=tf._sinks(seg_cfg, lp),
+                    kind=tf._layer_kind(cfg, win))
+                return attn, (nk, nv)
+
+            return tf._block_body(x, lp, seg_cfg, q_pos, attend_write,
+                                  lora_ids=lora_ids, valid=tail_valid)
+        return body
+
+    x, cache_out = tf.scan_layer_stack(make_body, x, params, cfg,
+                                       paged.planes())
+    last_x = jnp.take_along_axis(
+        x, jnp.maximum(tail_len - 1, 0)[:, None, None].astype(jnp.int32),
+        axis=1)                                         # [B, 1, D]
+    return tf.unembed(params, cfg, last_x)[:, 0], PagedKVCache(*cache_out)
+
+
 # ---- lock-order watchdog gate (utils/locks.py) ------------------------
 # When the suite runs with DLI_LOCK_CHECK=1 (scripts/check.sh arms it
 # for the chaos suite), every runtime lock is instrumented and a

@@ -498,3 +498,132 @@ def test_batcher_on_the_in_loop_gather_emits_the_engines_tokens(monkeypatch):
             break
     assert r.wait() == want
     assert spy.call_count > 0
+
+
+# -- the admit program's two ways to the pool ----------------------------
+# transformer.paged_prefill_tail gathers a layer's prefix from the
+# stacked pool by (layer, block) and writes every layer's tail once after
+# the stack; conftest.paged_prefill_tail_per_layer_write is the plain
+# form it replaced (each layer writes its slice and attends from it, the
+# slices come back re-stacked). Same pool, same waves: the first-token
+# logits and every plane are equal bit for bit, and no block but a
+# wave's tail blocks and the reserved one changes.
+
+T = 2 * BS            # a tail bucket of two blocks
+PB = 4                # prefix columns: 24 cached positions and a dummy pad
+
+# wave -> rows of (tail_len, tail blocks, prefix blocks, prefix_len); bt
+# row r is slot r's MB blocks. "fresh": two prompts without a prefix, one
+# ending inside its second block, and a padding row (tail_len 1, every
+# block the reserved one). "cached": a tail over a cached prefix of three
+# random blocks (24 positions: longer than layer-windows' 8 and 16), a
+# prompt's second chunk over the chunk the fresh wave wrote, and a
+# padding row between them.
+WAVES = {
+    "fresh": lambda bt: [
+        (T, bt[0, :2], [], 0), (11, bt[2, :2], [], 0), (1, [], [], 0)],
+    "cached": lambda bt: [
+        (T, bt[1, 3:5], bt[1, :3], 3 * BS), (1, [], [], 0),
+        (9, bt[0, 2:4], bt[0, :2], T)],
+}
+
+
+def _wave_inputs(cfg, rows):
+    rng = np.random.default_rng(17)
+    toks = rng.integers(0, cfg.vocab_size, (len(rows), T)).astype(np.int32)
+    tail_blocks = np.full((len(rows), T // BS), DUMMY, np.int32)
+    prefix_blocks = np.full((len(rows), PB), DUMMY, np.int32)
+    for i, (_, tb, pfb, _) in enumerate(rows):
+        tail_blocks[i, :len(tb)] = tb
+        prefix_blocks[i, :len(pfb)] = pfb
+    return (toks, np.asarray([r[0] for r in rows], np.int32), tail_blocks,
+            prefix_blocks, np.asarray([r[3] for r in rows], np.int32))
+
+
+@functools.lru_cache(maxsize=None)
+def _tail_forms(case):
+    from conftest import paged_prefill_tail_per_layer_write
+    cfg, params, _, _, lora_ids = _setup(case)
+    ids = None if lora_ids is None else lora_ids[:3]
+    return tuple(
+        jax.jit(lambda *a, f=f: f(params, cfg, *a, lora_ids=ids))
+        for f in (transformer.paged_prefill_tail,
+                  paged_prefill_tail_per_layer_write))
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_prefill_tail_equals_the_per_layer_write(case):
+    cfg, _, pool, bt, _ = _setup(case)
+    new_fn, plain_fn = _tail_forms(case)
+    new_pool = plain_pool = pool
+    for wave in ("fresh", "cached"):
+        rows = WAVES[wave](bt)
+        inputs = _wave_inputs(cfg, rows)
+        before = new_pool
+        (new_logits, new_pool), (plain_logits, plain_pool) = jax.device_get(
+            (new_fn(*inputs, new_pool), plain_fn(*inputs, plain_pool)))
+        np.testing.assert_array_equal(new_logits, plain_logits)
+        assert np.isfinite(new_logits).all()
+        written = sorted({int(b) for r in rows for b in r[1]})
+        kept = [b for b in range(1, 1 + R * MB) if b not in written]
+        for got, want, was in zip(new_pool.planes(), plain_pool.planes(),
+                                  jax.device_get(before).planes()):
+            # every block but the reserved one, where padding rows land
+            np.testing.assert_array_equal(got[:, 1:], want[:, 1:])
+            np.testing.assert_array_equal(got[:, kept], was[:, kept])
+            assert (got[:, written] != was[:, written]).any()
+
+
+def _made(jaxpr):
+    """(primitive, shape) of every output of every equation of a jaxpr,
+    nested jaxprs (scans' and branches' bodies, jitted helpers) included."""
+    out = []
+    for eqn in jaxpr.eqns:
+        out += [(eqn.primitive.name, tuple(v.aval.shape))
+                for v in eqn.outvars]
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out += _made(sub)
+    return out
+
+
+def _assert_pool_is_read_where_it_lies(jaxpr, planes):
+    """No equation yields one layer's plane, and a whole plane comes only
+    out of the scatter that writes it (and the loops and jitted helpers
+    that hand it on)."""
+    made = _made(jaxpr)
+    layer = {tuple(p.shape[1:]) for p in planes}
+    whole = {tuple(p.shape) for p in planes}
+    assert not [m for m in made if m[1] in layer]
+    makers = {name for name, shape in made if shape in whole}
+    assert "scatter" in makers
+    assert makers <= {"scatter", "pjit", "scan", "while", "cond"}, makers
+
+
+@pytest.mark.parametrize("case", ["moe", "mla-latent"])
+def test_layers_held_one_by_one_take_no_slice_of_the_pool(case):
+    """Where layers are held one by one (the batcher's MoE layers) the
+    decode chunk took static slices of the stacked pool, which XLA
+    hoisted out of the token loop and re-laid out, the whole pool once a
+    chunk (kanana: 19 ms a chunk of 8 passes; PERF.md section 6, PR 38).
+    Now a layer takes its index, as under a scan."""
+    chunk, inputs, _ = _decode_chunk(case, 3)
+    args = (inputs(CONTEXT), NO_EOS) + _sampling_rows(True)
+    with mock.patch.object(transformer, "_PREGATHER_MAX_BYTES", 0):
+        jaxpr = jax.make_jaxpr(chunk)(*args).jaxpr
+    _assert_pool_is_read_where_it_lies(jaxpr, _setup(case)[2].planes())
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_prefill_tail_takes_no_layers_plane_in_or_out(case):
+    """The admit program's layer stack neither takes the pool's planes
+    layer by layer nor gives them back re-stacked: a donated pool that
+    went in as slices and came out of a stack could not be written in
+    place, and every wave copied it whole (mistral: `copy.123`,
+    `copy.124` and a slice and an update a layer)."""
+    cfg, params, pool, bt, lora_ids = _setup(case)
+    ids = None if lora_ids is None else lora_ids[:3]
+    jaxpr = jax.make_jaxpr(
+        lambda *a: transformer.paged_prefill_tail(
+            params, cfg, *a, lora_ids=ids))(
+        *_wave_inputs(cfg, WAVES["cached"](bt)), pool).jaxpr
+    _assert_pool_is_read_where_it_lies(jaxpr, pool.planes())

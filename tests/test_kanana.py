@@ -86,7 +86,7 @@ def _blocks(start, n_tokens):
 def test_prefill_into_the_latent_pool_matches_reference():
     p = _prompts(1, (13, 29))
     paged = init_paged_cache(CFG, NB, BS)
-    assert paged.v is None and paged.k.shape[3:] == (1, 8 + 32)
+    assert paged.v is None and paged.k.shape[3:] == (1, 128)   # 8 + 32
     logits, paged = _prefill(CFG, PARAMS, paged, [
         (p[0], _blocks(1, 13), [], 0), (p[1], _blocks(10, 29), [], 0)])
     for i in range(2):
@@ -242,15 +242,18 @@ def test_latent_pool_matches_the_materialized_formulation():
         got.append(np.asarray(lg_p)[0])
         cur = int(np.argmax(want[-1]))
     assert _err(np.stack(got), np.stack(want)) < F32_TOL
+    # a row is stored whole 128-lane tiles wide (lane_width): the toy's
+    # rd + r in one tile, the cell's 576 in 640, zeros after the row
     L, r, rd = CFG.num_layers, CFG.kv_lora_rank, CFG.qk_rope_head_dim
-    assert paged.bytes_per_token == L * (r + rd) * 4
+    assert r + rd < 128 and paged.bytes_per_token == L * 128 * 4
+    assert not np.asarray(paged.k[..., r + rd:]).any()
     bf16 = init_paged_cache(CFG.replace(dtype="bfloat16"), NB, BS)
-    assert bf16.bytes_per_token == L * (r + rd) * 2
+    assert bf16.bytes_per_token == L * 128 * 2
     full = get_config("kanana-2-30b-a3b").replace(num_layers=7,
                                                   mla_latent_cache=True)
     shape = jax.eval_shape(lambda: init_paged_cache(full, 4, 16))
-    assert shape.k.shape == (7, 4, 16, 1, 576) and shape.v is None
-    assert 7 * 576 * 2 == 8064
+    assert shape.k.shape == (7, 4, 16, 1, 640) and shape.v is None
+    assert full.cache_head_dim == 576 and 7 * 640 * 2 == 8960
     assert 2 * 32 * 192 * 2 * 7 == 172032      # the materialized pool's
 
 

@@ -48,7 +48,8 @@ def topo():
 def compile_on_chip(topo):
     """compile_on_chip(fn, (shape, dtype) pytree..., kernel=True) -> the
     compiled program. Arguments are ShapeDtypeStructs placed on one
-    described device; the persistent compile cache is off around the
+    described device (``donate``: the positions of those the program may
+    write in place); the persistent compile cache is off around the
     compile (an entry written for a described chip cannot be read back
     without one, and warns). ``kernel`` says whether the program has to
     hold a Pallas kernel (a ``tpu_custom_call``) or is plain XLA."""
@@ -58,14 +59,15 @@ def compile_on_chip(topo):
         shape, dtype = leaf
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    def run(fn, *args, kernel=True):
+    def run(fn, *args, kernel=True, donate=()):
         args = [jax.tree.map(struct, a,
                              is_leaf=lambda x: isinstance(x, tuple))
                 for a in args]
         cache_was = jax.config.jax_enable_compilation_cache
         jax.config.update("jax_enable_compilation_cache", False)
         try:
-            compiled = jax.jit(fn).lower(*args).compile()
+            compiled = jax.jit(fn, donate_argnums=donate).lower(
+                *args).compile()
         finally:
             jax.config.update("jax_enable_compilation_cache", cache_was)
         assert ("tpu_custom_call" in compiled.as_text()) == kernel, (
@@ -281,23 +283,41 @@ def test_expert_dispatch_is_a_grouped_matmul(compile_on_chip, model, tokens,
         <= 16 * rows * d + 32 * 2 ** 20
 
 
-def _decode_chunk_text(compile_on_chip, cfg, k, slots, bs, blocks, mb,
-                       kernel=True):
-    """The text of ``paged_decode_chunk`` compiled for the described chip:
-    ``k`` passes over ``slots`` slots, a pool of ``blocks`` + 1 blocks of
-    ``bs`` (the last the reserved one), ``mb`` block-table columns."""
-    from distributed_llm_inferencing_tpu.models import transformer
+def _serving_shapes(cfg, bs, blocks, held=False):
+    """(shape, dtype) trees of a model's parameters and of its pool of
+    ``blocks`` + 1 blocks of ``bs`` (the last the reserved one), as
+    a list of planes. ``held``: MoE layers a list of per-layer trees,
+    as the batcher holds them (``_unstack_layers``)."""
     from distributed_llm_inferencing_tpu.models.params import init_params
     from distributed_llm_inferencing_tpu.ops.paged_kvcache import (
-        PagedKVCache, init_paged_cache)
+        init_paged_cache)
 
     def shapes(tree):
         return jax.tree.map(lambda s: (s.shape, s.dtype), tree)
 
     params = shapes(jax.eval_shape(
         lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    if held:
+        one = jax.tree.map(lambda s: (s[0][1:], s[1]), params["layers"],
+                           is_leaf=lambda x: isinstance(x, tuple))
+        params["layers"] = [one] * (cfg.num_layers
+                                    - cfg.dense_prefix_layers)
     pool = shapes(jax.eval_shape(
         lambda: list(init_paged_cache(cfg, blocks + 1, bs).planes())))
+    return params, pool
+
+
+def _decode_chunk_text(compile_on_chip, cfg, k, slots, bs, blocks, mb,
+                       kernel=True, held=False, donate=False):
+    """The text of ``paged_decode_chunk`` compiled for the described chip:
+    ``k`` passes over ``slots`` slots, a pool of ``blocks`` + 1 blocks of
+    ``bs`` (the last the reserved one), ``mb`` block-table columns.
+    ``held`` as in _serving_shapes; ``donate``: the pool is donated, as
+    the batcher's ``_decode_jit`` donates it."""
+    from distributed_llm_inferencing_tpu.models import transformer
+    from distributed_llm_inferencing_tpu.ops.paged_kvcache import (
+        PagedKVCache)
+    params, pool = _serving_shapes(cfg, bs, blocks, held)
 
     def chunk(params, pool, tokens, bt, ints, floats, ds):
         cl, seeds, steps, tks, budget, eos = ints
@@ -309,7 +329,29 @@ def _decode_chunk_text(compile_on_chip, cfg, k, slots, bs, blocks, mb,
         chunk, params, pool, ((slots,), jnp.int32),
         ((slots, mb), jnp.int32), ((6, slots), jnp.int32),
         ((2, slots), jnp.float32), ((slots,), jnp.bool_),
-        kernel=kernel).as_text()
+        kernel=kernel, donate=(1,) if donate else ()).as_text()
+
+
+def _admit_text(compile_on_chip, cfg, t, pb, wave, bs, blocks, kernel=True,
+                held=False):
+    """_decode_chunk_text's twin for an admit program: the text of
+    ``paged_prefill_tail`` over a wave of ``wave`` tails of ``t`` tokens,
+    ``pb`` prefix blocks a row, the pool donated as ``_admit_jit``
+    donates it."""
+    from distributed_llm_inferencing_tpu.models import transformer
+    from distributed_llm_inferencing_tpu.ops.paged_kvcache import (
+        PagedKVCache)
+    params, pool = _serving_shapes(cfg, bs, blocks, held)
+
+    def admit(params, pool, tokens, tail_blocks, prefix_blocks, lens):
+        return transformer.paged_prefill_tail(
+            params, cfg, tokens, lens[0], tail_blocks, prefix_blocks,
+            lens[1], PagedKVCache(*pool))
+
+    return compile_on_chip(
+        admit, params, pool, ((wave, t), jnp.int32),
+        ((wave, t // bs), jnp.int32), ((wave, pb), jnp.int32),
+        ((2, wave), jnp.int32), kernel=kernel, donate=(1,)).as_text()
 
 
 def test_decode_chunk_sorts_nothing_vocabulary_sized(compile_on_chip):
@@ -352,3 +394,81 @@ def test_decode_chunk_copies_no_layers_pool_out_of_the_stack(compile_on_chip):
     made = re.findall(rf"%(\S+) = {re.escape(plane)}\S* (?!parameter|"
                       rf"get-tuple-element)(\S+?)\(", text)
     assert not made, f"one layer's plane is materialized: {made[:4]}"
+
+
+@functools.lru_cache(maxsize=None)
+def _pool_sized():
+    """scripts/compile_serving_programs.py's ``pool_sized``, whose count
+    its report prints for every program: (name, operation) of the
+    instructions that yield a pool-sized or plane-sized array."""
+    import importlib.util
+    import pathlib
+    spec = importlib.util.spec_from_file_location(
+        "compile_serving_programs", pathlib.Path(__file__).parent.parent
+        / "scripts" / "compile_serving_programs.py")
+    script = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(script)
+    return script.pool_sized
+
+
+# model -> (config as the batcher pins it on a one-device TPU, slots,
+# block size, pool blocks, block-table columns, an admit program's
+# (tail, prefix blocks, wave), the plane copies its admit program and
+# its decode chunk may keep)
+def _cells():
+    moe = dict(attn_backend="xla", expert_matmul="pallas")
+    return {
+        # benchmarks/chip/configs/mistral-7b-int8.json
+        "mistral": (CFG.replace(quant="int8", attn_backend="xla"),
+                    16, BS, NB - 1, MB, (512, 0, 2), (0, 0)),
+        # .../kanana-2-30b-a3b-l7.json: the latent pool
+        "kanana": (KANANA.replace(num_layers=7, mla_latent_cache=True,
+                                  **moe),
+                   64, 16, 10240, 160, (128, 0, 1), (0, 0)),
+        # .../trinity-mini-l5.json. K and V of 4 heads arrive in
+        # (4, 128) tiles; the chunk's attention and the wave's write of
+        # whole blocks take (8, 128) tiles of (positions, width), so XLA
+        # re-tiles either plane once a chunk (the parent's ten per-layer
+        # copies were the same bytes) and, in a wave, there and back
+        # (1.55 ms a plane a copy on the chip; PERF.md section 6, PR
+        # 38). Storing the heads ahead of a block's positions would end
+        # both (PERF.md section 7).
+        "trinity": (get_config("trinity-mini").replace(
+            num_layers=5, dense_prefix_layers=1,
+            attn_windows=(2048,) * 4 + (None,),
+            rope_layers=(1, 1, 1, 1, 0), **moe),
+            64, 16, 12288, 576, (512, 128, 1), (4, 2)),
+    }
+
+
+@pytest.mark.parametrize("program", ["admit", "decode-chunk-8"])
+@pytest.mark.parametrize("model", ["mistral", "kanana", "trinity"])
+def test_no_serving_program_copies_the_pool(compile_on_chip, model, program):
+    """The cells' admit programs and decode chunks of 8 passes read the
+    stacked pool where it lies, by (layer, block), and write it once, in
+    place: in the program text nothing but the scatter that writes a
+    donated plane yields an array with as many elements as the pool's
+    plane or as one layer of it. Before PR 38 kanana's chunk held
+    `fusion.1095` (the pool sliced into its layers), `copy.956`-`962`
+    (each re-laid out) and `copy.922` / `copy.927` (the whole pool, to
+    the scatter's layout and back): 19 ms a chunk on the chip; every
+    admit program sliced the pool into layers, stacked the layers'
+    outputs into a fresh buffer and copied that into the donated one
+    (mistral: `copy.123`, `copy.124`). PERF.md section 6, PR 38."""
+    cfg, slots, bs, blocks, mb, (t, pb, wave), kept = _cells()[model]
+    held = cfg.is_moe
+    kept = kept[program != "admit"]
+    if program == "admit":
+        text = _admit_text(compile_on_chip, cfg, t, pb, wave, bs, blocks,
+                           kernel=held, held=held)
+    else:
+        text = _decode_chunk_text(compile_on_chip, cfg, 8, slots, bs,
+                                  blocks, mb, kernel=held, held=held,
+                                  donate=True)
+    _, pool = _serving_shapes(cfg, bs, blocks)
+    made = _pool_sized()(text, [jax.ShapeDtypeStruct(*p) for p in pool])
+    writes = [m for m in made if m[1] in ("fusion(scatter)", "scatter")]
+    assert len(writes) == len(pool), f"one write a plane: {made}"
+    rest = [m for m in made if m not in writes]
+    assert [op for _, op in rest] == ["copy"] * kept, (
+        f"the pool, or a layer of it, is materialized: {rest}")

@@ -278,6 +278,45 @@ def test_tail_prefill_windowed_prefix_read_is_the_masked_read(
         assert same(a[:, SPARE:], b_[:, SPARE:])
 
 
+@pytest.mark.parametrize("kv_quant", [None, "int8"], ids=["f32", "int8"])
+@pytest.mark.parametrize("prefix_len", [3, 16, 45])
+def test_tail_prefill_equals_the_per_layer_write(params, prefix_len,
+                                                 kv_quant):
+    """paged_prefill_tail (the prefix gathered from the stacked pool by
+    (layer, block), a windowed layer's bounded columns likewise, one
+    write after the stack) against the plain form it replaced
+    (conftest.paged_prefill_tail_per_layer_write): layers held one by
+    one, the dense layer ahead of them, a prefix shorter than the
+    window, of two windows and of nearly six, a padding row beside the
+    real one, a float32 pool and an int8 one with its scale planes.
+    First-token logits and every plane bit for bit, the reserved block
+    aside."""
+    from conftest import paged_prefill_tail_per_layer_write
+    cfg, held = cfg32().replace(kv_quant=kv_quant), held_one_by_one(params)
+    mb = 16
+    paged, tables = filled_pool(cfg, held, [prefix_len], mb)
+    cached = prefix_len // BS * BS
+    tail = np.zeros((2, 8), np.int32)
+    tail[0, :7] = tokens(7, seed=9)
+    pfb = np.zeros((2, mb), np.int32)
+    pfb[0, :cached // BS] = tables[0, :cached // BS]
+    (logits, got), (logits_p, want) = (
+        jax.jit(lambda *a, f=f: f(held, cfg, *a))(
+            jnp.asarray(tail), jnp.asarray([7, 1]),
+            jnp.asarray([[SPARE, SPARE + 1], [0, 0]]), jnp.asarray(pfb),
+            jnp.asarray([cached, 0]), paged)
+        for f in (transformer.paged_prefill_tail,
+                  paged_prefill_tail_per_layer_write))
+    assert len(got.planes()) == (4 if kv_quant else 2)
+    np.testing.assert_array_equal(np.asarray(logits), np.asarray(logits_p))
+    for a, b_, was in zip(got.planes(), want.planes(), paged.planes()):
+        np.testing.assert_array_equal(np.asarray(a[:, 1:]),
+                                      np.asarray(b_[:, 1:]))
+        np.testing.assert_array_equal(np.asarray(a[:, 1:SPARE]),
+                                      np.asarray(was[:, 1:SPARE]))
+        assert (np.asarray(a[:, SPARE:]) != np.asarray(was[:, SPARE:])).any()
+
+
 def test_a_scanned_stack_keeps_the_traced_window(params):
     """Stacked layers of mixed windows under one scan keep the traced
     leaf; a segment of one window, and a layer on its own, get a
