@@ -29,6 +29,12 @@ class ModelConfig:
     num_kv_heads: int = 12  # < num_heads => GQA
     head_dim: int = 64
     max_position_embeddings: int = 1024
+    # Looped models (Ouro: total_ut_steps): the whole layer stack runs
+    # this many times a token over ONE set of weights, the final norm
+    # between passes, and each (step, layer) pair keeps K and V of its
+    # own: cache plane step * num_layers + layer (cache_planes). 1 is
+    # every other family; num_layers stays the weights' depth.
+    loop_steps: int = 1
 
     # Architecture switches
     norm_type: str = "layernorm"  # layernorm | rmsnorm
@@ -289,6 +295,10 @@ class ModelConfig:
     expert_matmul: str = "xla"
 
     def __post_init__(self):
+        assert self.loop_steps >= 1, f"loop_steps={self.loop_steps}"
+        assert self.loop_steps == 1 or not self.post_norm, (
+            "a looped stack takes the final norm between passes; a "
+            "post_norm model has none")
         assert self.num_heads % self.num_kv_heads == 0, (
             f"num_heads={self.num_heads} must be divisible by "
             f"num_kv_heads={self.num_kv_heads}"
@@ -440,6 +450,12 @@ class ModelConfig:
     # cache_specs): the latent layout stores ONE shared
     # [k_rot | c] row per token in the k plane and nothing in the v
     # plane (attention reads v as a slice of k — the c part).
+    @property
+    def cache_planes(self) -> int:
+        """Leading axis of every cache and pool plane: one K and V plane
+        a (loop step, layer) pair, step-major."""
+        return self.loop_steps * self.num_layers
+
     @property
     def cache_kv_heads(self) -> int:
         return 1 if self.mla_latent_cache else self.num_kv_heads

@@ -220,7 +220,7 @@ SUPPORTED_MODEL_TYPES = ("gpt2", "opt", "llama", "mistral", "mixtral",
                          "nemotron", "deepseek_v3", "ernie4_5", "smollm3",
                          "hunyuan_v1_dense", "exaone4", "dbrx", "glm4_moe",
                          "ernie4_5_moe", "gpt_oss", "hunyuan_v1_moe",
-                         "afmoe")
+                         "afmoe", "ouro")
 
 
 def config_from_hf(hf_config) -> ModelConfig:
@@ -961,6 +961,43 @@ def config_from_hf(hf_config) -> ModelConfig:
             dense_prefix_layers=nd if 0 < nd < L else 0,
             tie_word_embeddings=getattr(hf_config, "tie_word_embeddings",
                                         False))
+    if mt == "ouro":
+        # ByteDance Ouro (modeling_ouro.py, LoopLM): a llama-shaped layer
+        # under sandwich norms (input_layernorm_2 and
+        # post_attention_layernorm_2 on the sublayers' outputs), the
+        # whole stack run total_ut_steps times over one set of weights
+        # with model.norm after every pass, and an exit gate on each
+        # pass's result. The served path runs every step
+        # (early_exit_threshold 1, the published value): a lower
+        # threshold is refused, not served without its exits.
+        if float(getattr(hf_config, "early_exit_threshold", 1.0)) < 1.0:
+            raise NotImplementedError(
+                f"ouro early_exit_threshold "
+                f"{hf_config.early_exit_threshold!r} — only 1 (every "
+                "token runs every step) converts")
+        if getattr(hf_config, "use_sliding_window", False):
+            raise NotImplementedError("ouro use_sliding_window")
+        return ModelConfig(
+            name=getattr(hf_config, "name_or_path", mt) or mt,
+            family="ouro", vocab_size=hf_config.vocab_size,
+            hidden_size=hf_config.hidden_size,
+            intermediate_size=hf_config.intermediate_size,
+            num_layers=hf_config.num_hidden_layers,
+            loop_steps=int(hf_config.total_ut_steps),
+            num_heads=hf_config.num_attention_heads,
+            num_kv_heads=hf_config.num_key_value_heads,
+            head_dim=getattr(hf_config, "head_dim", None)
+            or hf_config.hidden_size // hf_config.num_attention_heads,
+            max_position_embeddings=hf_config.max_position_embeddings,
+            norm_type="rmsnorm", norm_eps=hf_config.rms_norm_eps,
+            activation=_act_from_hf(hf_config.hidden_act),
+            gated_mlp=True, position_embedding="rope",
+            rope_theta=float(getattr(hf_config, "rope_theta", 10000.0)),
+            attn_bias=bool(getattr(hf_config, "attention_bias", False)),
+            o_bias=(False if getattr(hf_config, "attention_bias", False)
+                    else None), mlp_bias=False, post_block_norms=True,
+            tie_word_embeddings=getattr(hf_config, "tie_word_embeddings",
+                                        False))
     if mt == "exaone4":
         # EXAONE 4.0: the olmo2 sublayer-postnorm topology (x +
         # norm(f(x)), norms named post_attention/post_feedforward) with
@@ -1632,6 +1669,45 @@ def convert_state_dict(cfg: ModelConfig, sd, dtype=None):
         if pref:
             params["layers_dense"] = _stack(
                 [layer(i, False) for i in range(pref)])
+        if not cfg.tie_word_embeddings:
+            params["lm_head"] = {"w": get("lm_head.weight").T}
+    elif fam == "ouro":
+        # model.layers.N.{input_layernorm, input_layernorm_2 (on the
+        # attention OUTPUT), post_attention_layernorm (before the MLP),
+        # post_attention_layernorm_2 (on the MLP's output)},
+        # self_attn.{q,k,v,o}_proj, mlp.{gate,up,down}_proj; model.norm
+        # (after every pass) and model.early_exit_gate, a Linear(D, 1)
+        # (modeling_ouro.py)
+        def layer(i):
+            p = f"model.layers.{i}."
+
+            def lin(n, bias=False):
+                out = {"w": get(p + n + ".weight").T}
+                if bias:
+                    out["b"] = get(p + n + ".bias")
+                return out
+
+            def scale(n):
+                return {"scale": get(p + n + ".weight")}
+            return {
+                "attn_norm": scale("input_layernorm"),
+                "attn_post_norm": scale("input_layernorm_2"),
+                "mlp_norm": scale("post_attention_layernorm"),
+                "mlp_post_norm": scale("post_attention_layernorm_2"),
+                "q": lin("self_attn.q_proj", cfg.attn_bias),
+                "k": lin("self_attn.k_proj", cfg.attn_bias),
+                "v": lin("self_attn.v_proj", cfg.attn_bias),
+                "o": lin("self_attn.o_proj"),
+                "gate": lin("mlp.gate_proj"), "up": lin("mlp.up_proj"),
+                "down": lin("mlp.down_proj"),
+            }
+        params = {
+            "embed": {"tokens": get("model.embed_tokens.weight")},
+            "layers": _stack([layer(i) for i in range(cfg.num_layers)]),
+            "final_norm": {"scale": get("model.norm.weight")},
+            "exit_gate": {"w": get("model.early_exit_gate.weight").T,
+                          "b": get("model.early_exit_gate.bias")},
+        }
         if not cfg.tie_word_embeddings:
             params["lm_head"] = {"w": get("lm_head.weight").T}
     elif fam == "dbrx":

@@ -198,6 +198,12 @@ def init_params(cfg: ModelConfig, key, dtype=None):
         params["final_norm"] = (
             {"scale": ones((D,)), "bias": zeros((D,))}
             if cfg.norm_type == "layernorm" else {"scale": ones((D,))})
+    if cfg.loop_steps > 1:
+        # Ouro's early-exit gate, a linear D -> 1 on each pass's normed
+        # result: loaded and kept, read by the plain reference alone (at
+        # the published threshold 1 every token runs every step, and the
+        # served path does not evaluate it)
+        params["exit_gate"] = {"w": w((D, 1)), "b": zeros((1,))}
     if cfg.embed_proj_dim:
         params["embed"]["project_in"] = {"w": w((E, D))}
         params["embed"]["project_out"] = {"w": w((D, E))}

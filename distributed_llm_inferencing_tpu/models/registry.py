@@ -269,6 +269,23 @@ register(_afmoe(
     num_experts=128, num_experts_per_tok=8, moe_routed_scale=2.826,
     dense_prefix_layers=2))
 
+# --- Ouro (ByteDance LoopLM): a llama-shaped layer under sandwich norms,
+# and the whole stack run loop_steps times a token over one set of
+# weights, the final norm between passes; each (step, layer) pair has
+# its own K and V plane (models/reference/ouro_ref.py has the equations) ---
+def _ouro(name, **kw):
+    return ModelConfig(
+        name=name, family="ouro", norm_type="rmsnorm", norm_eps=1e-6,
+        activation="silu", gated_mlp=True, position_embedding="rope",
+        rope_theta=1000000.0, attn_bias=False, mlp_bias=False,
+        tie_word_embeddings=False, post_block_norms=True, **kw)
+
+
+register(_ouro(
+    "ouro-2.6b", vocab_size=49152, hidden_size=2048, intermediate_size=5632,
+    num_layers=48, loop_steps=4, num_heads=16, num_kv_heads=16, head_dim=128,
+    max_position_embeddings=65536))
+
 # --- Tiny configs for tests/dryrun (not real checkpoints) ---
 register(ModelConfig(
     name="tiny-gpt2", family="gpt2", vocab_size=256, hidden_size=64,
@@ -336,3 +353,9 @@ register(_afmoe(
     moe_intermediate_size=32, num_heads=4, num_kv_heads=2, head_dim=16,
     max_position_embeddings=256, embed_scale=8.0, num_experts=16,
     num_experts_per_tok=4, moe_routed_scale=2.826, dense_prefix_layers=1))
+
+register(_ouro(
+    # ouro-2.6b's switches at toy widths: 3 layers run 3 times, 9 planes
+    "tiny-ouro", vocab_size=256, hidden_size=64, intermediate_size=128,
+    num_layers=3, loop_steps=3, num_heads=4, num_kv_heads=4, head_dim=16,
+    max_position_embeddings=256))

@@ -39,6 +39,7 @@ Param pytree schema (all leaves jnp arrays; optional leaves absent, never None):
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -553,6 +554,56 @@ def scan_layer_stack(make_body, x, params, cfg: ModelConfig, xs):
     return x, cat
 
 
+def loop_passes(cfg: ModelConfig, xs):
+    """A looped model's passes over its layer stack (Ouro:
+    ``cfg.loop_steps`` of them over the same weights), for a Python loop
+    that runs scan_layer_stack once a pass: yields ``(u, xs_u)`` under
+    ``jax.named_scope("loop_step_<u>")``. ``xs`` are stacked
+    [cfg.cache_planes, ...] -- the dense cache's planes, or
+    ``arange(cache_planes)`` where the program closes over the pool --
+    and pass u takes rows [u * L, (u + 1) * L): a body's layer index is
+    then its (step, layer) pair's plane, u * L + l, while its weights
+    are layer l's, the same arrays on every pass (closed over once,
+    never stacked per step). With one step: ``xs`` as they came and no
+    scope, so the trace and the program text of before the loop
+    existed."""
+    T, L = cfg.loop_steps, cfg.num_layers
+    if T == 1:
+        yield 0, xs
+        return
+    for u in range(T):
+        with jax.named_scope(f"loop_step_{u}"):
+            yield u, tuple(p[u * L:(u + 1) * L] for p in xs)
+
+
+def loop_pass_end(params, cfg: ModelConfig, u: int, x):
+    """The final norm between passes: pass u's result is the next pass's
+    input (the last pass's is unembed's to take)."""
+    if u + 1 == cfg.loop_steps:
+        return x
+    return norm(x, params["final_norm"], cfg.norm_type, cfg.norm_eps)
+
+
+def loop_layer_stack(make_body, carry, params, cfg: ModelConfig, xs):
+    """scan_layer_stack, once a pass of loop_passes, the final norm
+    between passes. ``carry`` is the hidden state, or a tuple that holds
+    it first (the decode chunks carry their side buffers behind it).
+    Returns (carry, outputs concatenated back to [cache_planes, ...]
+    order)."""
+    outs = []
+    for u, xs_u in loop_passes(cfg, xs):
+        carry, out = scan_layer_stack(make_body, carry, params, cfg, xs_u)
+        if isinstance(carry, tuple):
+            carry = (loop_pass_end(params, cfg, u, carry[0]),) + carry[1:]
+        else:
+            carry = loop_pass_end(params, cfg, u, carry)
+        outs.append(out)
+    if len(outs) == 1:
+        return carry, outs[0]
+    return carry, tuple(jnp.concatenate([o[j] for o in outs], axis=0)
+                        for j in range(len(outs[0])))
+
+
 def _mla_qkv(h, lp, cfg: ModelConfig, q_positions):
     """DeepSeek-V3 multi-head latent attention projections (HF
     modeling_deepseek_v3.py:327-446), materialized per head. q and kv
@@ -1020,7 +1071,7 @@ def forward(
 
     cache_xs = (cache.k, cache.v) + (
         (cache.k_scale, cache.v_scale) if cache.quantized else ())
-    x, cache_out = scan_layer_stack(make_body, x, params, cfg, cache_xs)
+    x, cache_out = loop_layer_stack(make_body, x, params, cfg, cache_xs)
     logits = unembed(params, cfg, x)
     planes = dict(zip(("k", "v", "k_scale", "v_scale"), cache_out))
     return logits, KVCache(lengths=new_lengths, **planes)
@@ -1184,7 +1235,7 @@ def paged_decode_step(params, cfg: ModelConfig, tokens, paged,
                                lora_ids=lora_ids)
         return body
 
-    x, cache_out = scan_layer_stack(make_body, x, params, cfg,
+    x, cache_out = loop_layer_stack(make_body, x, params, cfg,
                                     paged.planes())
     logits = unembed(params, cfg, x)[:, 0]              # [R, V]
     return logits, PagedKVCache(*cache_out)
@@ -1405,12 +1456,11 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
     attended over on every pass of this chunk, ``window_positions`` what
     a windowed layer read instead (the widest, should widths differ;
     ``pool_positions`` where no layer took the bounded read).
-    """
-    from distributed_llm_inferencing_tpu.ops.attention import attend
-    from distributed_llm_inferencing_tpu.ops.paged_kvcache import (
-        PagedKVCache, kind_scope, window_read, write_rows)
-    from distributed_llm_inferencing_tpu.ops.sampling import sample_batch
 
+    A looped model (cfg.loop_steps > 1) runs the stack that many times a
+    pass (loop_layer_stack): the side buffers and the pool have a plane
+    a (step, layer) pair, a layer's index into both is its pair's.
+    """
     from distributed_llm_inferencing_tpu.ops.pallas import fused_decode
     if (_cfg_backend(cfg, op="paged").startswith("pallas")
             or fused_decode.eligible(cfg, paged.quantized)):
@@ -1422,9 +1472,29 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
             params, cfg, k, tokens, paged, block_tables, context_lens,
             seeds, steps0, temps, tks, tps, ds, budget, eos_ids,
             dummy_block, lora_ids=lora_ids)
+    return decode_chunk_with_logits(
+        params, cfg, k, tokens, paged, block_tables, context_lens, seeds,
+        steps0, temps, tks, tps, ds, budget, eos_ids, dummy_block,
+        lora_ids=lora_ids)[:-1]
+
+
+def decode_chunk_with_logits(params, cfg: ModelConfig, k: int, tokens, paged,
+                             block_tables, context_lens, seeds, steps0,
+                             temps, tks, tps, ds, budget, eos_ids,
+                             dummy_block: int, lora_ids=None):
+    """paged_decode_chunk's side-buffer formulation: what it returns and,
+    last, the passes' logits [K, R, V] float32. No serving program takes
+    the logits (a jit drops the output nothing reads, so
+    paged_decode_chunk's program has none); a comparison with a plain
+    reference reads them where the pool is too large to copy for a
+    second path (benchmarks/chip/compare_reference_loop.py)."""
+    from distributed_llm_inferencing_tpu.ops.attention import attend
+    from distributed_llm_inferencing_tpu.ops.paged_kvcache import (
+        PagedKVCache, kind_scope, window_read, write_rows)
+    from distributed_llm_inferencing_tpu.ops.sampling import sample_batch
 
     r = tokens.shape[0]
-    L = cfg.num_layers
+    L = cfg.cache_planes                  # a plane a (loop step, layer)
     bs = paged.block_size
     mb = block_tables.shape[1]
     dt = jnp.dtype(cfg.dtype)             # compute dtype (pool may be int8)
@@ -1527,7 +1597,7 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
                                          lora_ids=lora_ids, **tail))
             return layer
 
-        (x2, side), (moe,) = scan_layer_stack(
+        (x2, side), (moe,) = loop_layer_stack(
             make_layer, (x, side), params, cfg,
             (jnp.arange(L, dtype=jnp.int32),))
         logits = unembed(params, cfg, x2)[:, 0]
@@ -1541,9 +1611,10 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
         # a pass nobody is alive in (the chunk outran every budget)
         # counts for nothing
         moe = jnp.sum(moe, axis=0) * jnp.any(alive)
-        return (nxt, side, new_cl, new_alive), (nxt, emit, alive, moe)
+        return (nxt, side, new_cl, new_alive), (nxt, emit, alive, moe,
+                                                logits)
 
-    (_, side, _, _), (toks, emits, wrote, moe) = jax.lax.scan(
+    (_, side, _, _), (toks, emits, wrote, moe, logits) = jax.lax.scan(
         body, (tokens, side0, context_lens, budget > 0),
         jnp.arange(k, dtype=jnp.int32))
     # the largest load of a pass and layer adds up like the others: the
@@ -1563,10 +1634,11 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
             k8, ks = quant_kv(side[0])
             v8, vs = quant_kv(side[1])
             side = (k8, v8, ks, vs)
-        return toks, emits, moe, pool_positions, window_positions, \
-            PagedKVCache(*(
-                write_rows(plane, jnp.swapaxes(sd, 1, 2), blk, off)
-                for plane, sd in zip(paged.planes(), side)))
+        return (toks, emits, moe, pool_positions, window_positions,
+                PagedKVCache(*(
+                    write_rows(plane, jnp.swapaxes(sd, 1, 2), blk, off)
+                    for plane, sd in zip(paged.planes(), side))),
+                logits)
 
 
 def _paged_decode_chunk_stepwise(params, cfg: ModelConfig, k: int, tokens,
@@ -1673,6 +1745,10 @@ def paged_speculative_chunk(params, cfg: ModelConfig, k: int, gamma: int,
     from distributed_llm_inferencing_tpu.ops.speculative import (
         accept_rejection_batch, propose_ngram_device)
 
+    if cfg.loop_steps > 1:
+        raise ValueError(
+            f"{cfg.name}: paged_speculative_chunk runs the stack once a "
+            "verify pass; a looped model's steps are not carried through it")
     r = tokens.shape[0]
     L = cfg.num_layers
     bs = paged.block_size
@@ -1873,7 +1949,7 @@ def paged_prefill_tail(params, cfg: ModelConfig, tokens, tail_len,
     tail_valid = jnp.arange(t, dtype=jnp.int32)[None, :] < tail_len[:, None]
     x = embed(params, cfg, tokens, q_pos)
 
-    def make_body(seg_cfg):
+    def make_body(seg_cfg, paged):       # its layers read this pool
         def body(x, layer_in):
             lp, li = layer_in
 
@@ -1925,19 +2001,33 @@ def paged_prefill_tail(params, cfg: ModelConfig, tokens, tail_len,
                                lora_ids=lora_ids, valid=tail_valid)
         return body
 
-    x, tails = scan_layer_stack(
-        make_body, x, params, cfg,
-        (jnp.arange(cfg.num_layers, dtype=jnp.int32),))
     # ONE write a plane of every layer's tail rows, whole blocks, into
-    # the pool where it lies
-    with jax.named_scope("kv_write"):
-        new_paged = PagedKVCache(*(
-            write_blocks(plane, rows, tail_blocks)
-            for plane, rows in zip(paged.planes(), tails)))
+    # the pool where it lies. A looped model writes after each of its
+    # steps, that step's planes alone (its rows for all steps at once
+    # would be 1.5 MiB a token of the wave at Ouro-2.6B): the next
+    # step's bodies close over the pool so written, whose planes they
+    # read are still as they came in.
+    L = cfg.num_layers
+    for u, xs_u in loop_passes(
+            cfg, (jnp.arange(cfg.cache_planes, dtype=jnp.int32),)):
+        x, tails = scan_layer_stack(
+            functools.partial(make_body, paged=paged), x, params, cfg, xs_u)
+        x = loop_pass_end(params, cfg, u, x)
+        with jax.named_scope("kv_write"):
+            paged = PagedKVCache(*(
+                write_blocks(plane, rows, tail_blocks,
+                             None if cfg.loop_steps == 1 else u * L)
+                for plane, rows in zip(paged.planes(), tails)))
+        if cfg.loop_steps > 1:
+            # the next pass waits for this write: left free, the
+            # scheduler kept every pass's tail rows to the program's end
+            # (3.4 GiB for a wave of 1024 tokens at Ouro-2.6B, described
+            # v5e compile)
+            x, paged = jax.lax.optimization_barrier((x, paged))
     # project only the last real position through the vocab head ([D,V] over
     # one row per sequence, not T padded rows)
     last_x = jnp.take_along_axis(
         x, jnp.maximum(tail_len - 1, 0)[:, None, None].astype(jnp.int32),
         axis=1)                                         # [B, 1, D]
     last = unembed(params, cfg, last_x)[:, 0]           # [B, V]
-    return last, new_paged
+    return last, paged

@@ -80,7 +80,7 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int,
     # mla_latent_cache: the k plane holds one shared [k_rot | c] latent
     # row per token; the v plane is zero-width (attention reads v as the
     # rows of k — transformer._mla_absorbed)
-    shape = (cfg.num_layers, batch, max_seq, cfg.cache_kv_heads,
+    shape = (cfg.cache_planes, batch, max_seq, cfg.cache_kv_heads,
              cfg.cache_head_dim)
     vshape = shape[:-1] + (cfg.cache_v_head_dim,)
     if cfg.kv_quant == "int8":

@@ -7,9 +7,11 @@ prompt prefixes. The paged cache is the TPU-native analogue of
 vLLM/PagedAttention:
 
 - ``k``/``v``: [L, NB, bs, Hkv, hd] — a pool of NB fixed-size blocks per
-  layer. Which blocks a sequence owns is *host-side* state, managed by the
-  native C++ allocator (native/src/block_pool.cc) with ref-counted radix
-  prefix sharing. An MLA model's pool is latent (cfg.mla_latent_cache):
+  layer (L = cfg.cache_planes: a looped model, cfg.loop_steps > 1, has a
+  plane a (step, layer) pair, step-major). Which blocks a sequence owns
+  is *host-side* state, managed by the native C++ allocator
+  (native/src/block_pool.cc) with ref-counted radix prefix sharing. An
+  MLA model's pool is latent (cfg.mla_latent_cache):
   ``k`` alone, [L, NB, bs, 1, lane_width(rd + r)], one shared row a
   token a layer (transformer._mla_latent_rows) in the first rd + r
   columns, zeros after them, and no ``v``. A block is a block: the
@@ -108,7 +110,7 @@ class PagedKVCache(NamedTuple):
 def init_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
                      dtype=None) -> PagedKVCache:
     dtype = dtype or jnp.dtype(cfg.dtype)
-    shape = (cfg.num_layers, num_blocks, block_size, cfg.cache_kv_heads,
+    shape = (cfg.cache_planes, num_blocks, block_size, cfg.cache_kv_heads,
              cfg.cache_head_dim)
     if cfg.mla_latent_cache:   # config.py refuses kv_quant with it
         return PagedKVCache(k=jnp.zeros(
@@ -179,14 +181,17 @@ def write_rows(plane, rows, blk, off):
     return plane.at[layer, blk, off].set(rows)
 
 
-def write_blocks(plane, rows, block_ids):
+def write_blocks(plane, rows, block_ids, first_plane=None):
     """Runs of whole blocks of every layer (a wave's prefilled tails)
     into the stacked plane, in place in a donated pool: one scatter.
 
     plane: [L, NB, bs, Hkv, w] (a scale plane: no w); rows:
     [L, B, T, Hkv, w] with T a multiple of bs; block_ids: [B, T // bs].
     Duplicate ids may only occur on the reserved dummy block (padding
-    rows), as in write_block_run, which writes one layer's plane.
+    rows), as in write_block_run, which writes one layer's plane. With
+    ``first_plane`` (a looped model's step, transformer.
+    paged_prefill_tail) ``rows`` are that step's layers alone and go to
+    planes [first_plane, first_plane + L) of the [T * L, ...] stack.
 
     Where the heads leave the tile's second-minor axis part empty
     (trinity's 4 of 8) XLA re-tiles both planes for this window and
@@ -195,10 +200,25 @@ def write_blocks(plane, rows, block_ids):
     wave's prefix gather reads the (4, 128)-tiled pool directly, and
     that program (tail 512 over 256 prefix blocks, 2 rows) halted the
     core on a v5e (PERF.md section 6, PR 38)."""
-    L, bs = plane.shape[0], plane.shape[2]
+    L, bs = rows.shape[0], plane.shape[2]
     b, t = rows.shape[1:3]
-    return plane.at[:, block_ids.reshape(-1)].set(fit_rows(
-        rows.reshape(L, b * (t // bs), bs, *rows.shape[3:]), plane))
+    ids = block_ids.reshape(-1)
+    rows = fit_rows(rows.reshape(L, b * (t // bs), bs, *rows.shape[3:]),
+                    plane)
+    if first_plane is None:
+        return plane.at[:, ids].set(rows)
+    # a scatter whose window covers part of the plane axis is expanded
+    # by XLA:TPU into a loop of one update a block, over a transposed
+    # copy of the rows (0.75 GiB for a wave of 2048 tokens at
+    # Ouro-2.6B, described v5e compile): the same loop, written here,
+    # takes each block's rows where they lie
+    tail = (0,) * (plane.ndim - 2)
+
+    def put(j, plane):
+        return jax.lax.dynamic_update_slice(
+            plane, jax.lax.dynamic_slice_in_dim(rows, j, 1, axis=1),
+            (first_plane, ids[j]) + tail)
+    return jax.lax.fori_loop(0, ids.shape[0], put, plane)
 
 
 def gather_seq(cache_layer, block_tables, layer=None):

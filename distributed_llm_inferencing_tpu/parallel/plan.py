@@ -77,7 +77,7 @@ def make_plan(model: str | ModelConfig, mesh: Dict[str, int] | MeshSpec,
         leaves[path] = {"shape": list(shape), "spec": pspecs.get(path)}
 
     # KV cache per device
-    kv_elems = (cfg.num_layers * batch * max_seq * cfg.num_kv_heads
+    kv_elems = (cfg.cache_planes * batch * max_seq * cfg.num_kv_heads
                 * cfg.qk_head_dim * 2)
     kv_shard = axis_sizes["dp"] * (axis_sizes["tp"] if spec.tp <= cfg.num_kv_heads else 1)
     kv_per_device = kv_elems * bytes_per_el // kv_shard

@@ -176,6 +176,8 @@ def param_specs(cfg: ModelConfig, spec: MeshSpec,
         specs["final_norm"] = (
             {"scale": P(None), "bias": P(None)}
             if cfg.norm_type == "layernorm" else {"scale": P(None)})
+    if cfg.loop_steps > 1:   # Ouro's exit gate: D + 1 numbers, replicated
+        specs["exit_gate"] = {"w": P(None, None), "b": P(None)}
     if cfg.embed_proj_dim:   # opt-350m embed projections: small, replicated
         specs["embed"]["project_in"] = {"w": P(None, None)}
         specs["embed"]["project_out"] = {"w": P(None, None)}

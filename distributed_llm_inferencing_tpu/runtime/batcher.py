@@ -393,6 +393,28 @@ class ContinuousBatcher:
                 raise ValueError(
                     f"{cfg.name}: per-layer attention windows cannot "
                     "take " + "; ".join(refused))
+        if cfg.loop_steps > 1:
+            # a looped model's stack runs loop_steps times a pass over
+            # one set of weights, a K and V plane a (step, layer) pair
+            # (transformer.loop_layer_stack); what does not carry the
+            # loop is refused here by name, not run for one step
+            refused = [why for why, hit in (
+                ("speculative decoding (paged_speculative_chunk verifies "
+                 "through one pass of the stack)", bool(speculative)),
+                ("pp > 1 (parallel/paged_pipeline.py's stages own one "
+                 "pass's layer slices of the pool)", self.mesh_spec.pp > 1),
+                ("a Pallas attention backend (attn_backend / "
+                 "DLI_ATTENTION: the stepwise chunk copies the pool a "
+                 "loop step)",
+                 os.environ.get("DLI_ATTENTION", cfg.attn_backend)
+                 .startswith("pallas")),
+                ("DLI_FUSED_DECODE (the fused step runs in the stepwise "
+                 "chunk)", fused_decode.enabled()))
+                if hit]
+            if refused:
+                raise ValueError(
+                    f"{cfg.name}: a looped stack (loop_steps="
+                    f"{cfg.loop_steps}) cannot take " + "; ".join(refused))
         self.cfg = cfg = cfg.replace(
             attn_backend=_backend(cfg, self.mesh_spec.num_devices),
             # int4 pallas routing hint (models/config.py): this GSPMD
@@ -497,6 +519,9 @@ class ContinuousBatcher:
         self._wave_cut = None   # (tail, prefix) group the bound last cut
         # admission waves cut short by WAVE_SCORE_BUDGET
         self.metrics.inc("batcher_admit_waves_bounded", 0)
+        # traversals of the layer stack by decode passes: loop_steps a
+        # weight pass (batcher_weight_passes stays one a decode pass)
+        self.metrics.inc("batcher_stack_passes", 0)
         self._pass_mean = {}      # (kind, k) -> [mean wall per pass, n]
         self._step_program_s = 0.0   # this step's wall inside programs
         if speculative:
@@ -2362,6 +2387,7 @@ class ContinuousBatcher:
                    "tail_bucket": t, "prefix_bucket": pb,
                    "tokens": tokens, "padded_tokens": b * t,
                    "active": active, "prefix_positions": hits,
+                   "loop_steps": self.cfg.loop_steps,
                    **self._gathered_prefix(b, pb),
                    "bounded": int(self._wave_cut == (t, pb))})
         with self.profiler.phase("admit_post"):
@@ -2956,6 +2982,7 @@ class ContinuousBatcher:
             "batcher.decode_chunk", w0, w1,
             attrs={"chunk": self._step_count, "k": k, "slots": len(active),
                    "kv_bytes_per_token": self.paged.bytes_per_token,
+                   "loop_steps": self.cfg.loop_steps,
                    "pool_positions": self._pool_positions,
                    "window_positions": self._window_positions})
         # drafting history stays current even when the adaptive controller
@@ -3004,6 +3031,8 @@ class ContinuousBatcher:
         self.metrics.gauge("decode_tokens_per_weight_pass",
                            emitted / passes if passes else 0.0)
         self.metrics.inc("batcher_weight_passes", passes)
+        self.metrics.inc("batcher_stack_passes",
+                         passes * self.cfg.loop_steps)
         self.metrics.inc("batcher_tokens_emitted", emitted)
         if any(d and not 0 < tk <= PREFIX_K
                for tk, d in zip(decode_args["tks"], decode_args["ds"])):

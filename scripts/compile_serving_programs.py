@@ -6,6 +6,7 @@ described TPU — no chip, no arrays — before spending a chip call.
     JAX_PLATFORMS=cpu MODEL=kanana python scripts/compile_serving_programs.py 1
     JAX_PLATFORMS=cpu MODEL=trinity python scripts/compile_serving_programs.py 1
     JAX_PLATFORMS=cpu MODEL=mistral-cell python scripts/compile_serving_programs.py 1
+    JAX_PLATFORMS=cpu MODEL=ouro python scripts/compile_serving_programs.py 1
 
 For each tensor-parallel width given (default: 1 and 4) the real jitted
 programs of ``runtime/batcher.py`` are lowered for ``v5e:2x2`` at
@@ -16,7 +17,8 @@ the benchmark cells' (16 slots, chunks of 8 passes), with
 slots, the latent pool of 10,240 blocks) or ``MODEL=trinity`` at its cell's
 (trinity-mini bf16, 5 layers, 64 slots, 12,288 blocks, contexts to 9216:
 the 2-row admit over a 512-block prefix and the decode chunks with the
-windowed read), with shapes from
+windowed read) or ``MODEL=ouro`` at its cell's (ouro-2.6b bf16 whole, 48
+layers run 4 times, 8 slots, 192 planes of 321 blocks), with shapes from
 ``jax.eval_shape`` and shardings from ``parallel/sharding.py``. Prints
 what ``compiled.memory_analysis()`` says each device must hold, the
 collectives in the program text, and every instruction that yields a
@@ -77,6 +79,13 @@ TARGETS = {
         "attn_windows": (2048, 2048, 2048, 2048, None),
         "rope_layers": (1, 1, 1, 1, 0)}, 64, 16, 12288, 9216,
         ((512, 512, 2), (512, 128, 1)), (8, 1)),
+    # benchmarks/chip/configs/ouro-2.6b.json: 48 layers run 4 times, 192
+    # planes of 321 blocks; its widest admit wave (8 rows of 256: a step's
+    # tail rows are 0.8 GB, written a step at a time), the probes' 512
+    # tail over a cached prefix, and the decode chunks
+    "ouro": ("ouro-2.6b", None, 0, 8, 16, 320, 640,
+             ((256, 0, 8), (256, 0, 4), (128, 0, 8), (128, 0, 1), (512, 0, 1),
+              (16, 32, 8)), (8, 1)),
 }
 TARGET = os.environ.get("MODEL", "mistral")
 (MODEL, QUANT, DEPTH, SLOTS, BLOCK, BLOCKS, MAX_SEQ, ADMIT,
@@ -152,7 +161,13 @@ def pool_sized(text, paged):
 
 
 def report(name, lowered, t0, paged):
-    compiled = lowered.compile()
+    try:
+        compiled = lowered.compile()
+    except jax.errors.JaxRuntimeError as e:   # what the chip would refuse
+        print(f"{name}: REFUSED: " + " | ".join(
+            line.strip() for line in str(e).splitlines()[:8] if line.strip()),
+            flush=True)
+        return
     mem, text = compiled.memory_analysis(), compiled.as_text()
     sized = pool_sized(text, paged)
     if TEXT_DIR:

@@ -54,6 +54,7 @@ SCOPES = ("kv_gather", "attention", "attn_gate", "kv_write", "mlp",
 # windowed and full layers: reported as `attention/win`
 KINDS = ("win", "full")
 NO_SCOPE = "(no scope)"
+LOOP_STEP = "loop_step_"   # transformer.loop_layer_stack names each pass
 # instructions a compiler pass makes without an op name, by what their
 # own name starts with: `lax.ragged_dot` becomes `ragged-dot-none.N`
 # custom calls (the grouped expert matmuls of admit programs); the
@@ -116,13 +117,17 @@ def _varints(buf):
 
 def scope_of(op_name: str) -> str:
     """`jit(chunk)/while/body/attention/dot_general` -> `attention`;
-    `.../attention/win/dot_general` -> `attention/win`."""
+    `.../attention/win/dot_general` -> `attention/win`; under a looped
+    model's pass (`.../loop_step_2/while/body/mlp/dot_general`) the
+    step goes first: `loop_step_2:mlp`, `loop_step_2:(no scope)`."""
     parts = op_name.split("/")
+    step = next((p + ":" for p in parts if p.startswith(LOOP_STEP)), "")
     for i in range(len(parts) - 1, -1, -1):
         if parts[i] in SCOPES:
             kind = parts[i + 1] if i + 1 < len(parts) else None
-            return f"{parts[i]}/{kind}" if kind in KINDS else parts[i]
-    return NO_SCOPE
+            return step + (f"{parts[i]}/{kind}" if kind in KINDS
+                           else parts[i])
+    return step + NO_SCOPE
 
 
 def program_scopes(hlo) -> dict:
@@ -154,11 +159,11 @@ def program_scopes(hlo) -> dict:
     scopes = {}
     for instrs in comps.values():
         for name, scope, called in instrs:
-            if scope == NO_SCOPE and called:
+            if scope.endswith(NO_SCOPE) and called:
                 votes = {}
                 for cid in called:
                     for _, inner, _ in comps.get(cid, ()):
-                        if inner != NO_SCOPE:
+                        if not inner.endswith(NO_SCOPE):
                             votes[inner] = votes.get(inner, 0) + 1
                 if votes:
                     scope = max(votes, key=votes.get)
