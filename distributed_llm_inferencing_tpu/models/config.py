@@ -293,6 +293,14 @@ class ModelConfig:
     # ops/pallas/grouped_matmul.py at decode size) | "pallas_interpret"
     # (tests). Not a serving option: the batcher overwrites it.
     expert_matmul: str = "xla"
+    # Pinned by the batcher beside it, by the same rule: the form of a
+    # decode chunk's read of the paged pool (models/transformer.py
+    # _pool_kernel). "xla" (the in-loop gather as far as _pool_ladder's
+    # rung, everywhere) | "pallas" (a one-device TPU program:
+    # ops/pallas/paged_attention.py where the pool's shape is one it
+    # reads as it lies) | "pallas_interpret" (tests). Not a serving
+    # option: the batcher overwrites it.
+    pool_kernel: str = "xla"
 
     def __post_init__(self):
         assert self.loop_steps >= 1, f"loop_steps={self.loop_steps}"

@@ -1326,7 +1326,10 @@ def _pool_ladder(mb: int, scanned: bool = True):
     at: 1, 2, 3, 4, 6 and 8 eighths of the block table's ``mb`` columns,
     so a rung is at most 1.5 times the one below it from a quarter up
     (mistral's 128 -> 16/32/48/64/96/128, a toy 6 -> 1/2/3/5/6). A rung
-    is a branch of one lax.switch in the layer body, not a program.
+    is a branch of one lax.switch in the layer body, not a program. This
+    is the XLA form of the pool's read: where _pool_kernel takes the
+    Pallas kernel (which stops at each slot's own length) no ladder and
+    no switch are built.
     A conditional takes its operands as buffers: they are the stacked
     pool as it lies and the layer's index, and the branch gathers by
     (layer, block) (_layer_gather); handed the scan's slice of the pool
@@ -1383,6 +1386,35 @@ def _attend_pool_rung(rung, ladder, pre: bool, planes, scales, block_tables,
     return jax.lax.switch(rung, [branch(m) for m in ladder])
 
 
+def _pool_kernel(params, cfg: ModelConfig, paged):
+    """Which form a decode chunk's read of the pool takes, from what the
+    trace can see: ``cfg.pool_kernel`` (pinned by the batcher: "pallas"
+    only in a one-device TPU program, since GSPMD does not partition a
+    Pallas call) where ops/pallas/paged_attention.py computes this
+    model's attention and reads this pool as it lies -- a scanned stack
+    (the kernel takes the layer's index from the scan; layers held one
+    by one keep the fused XLA form they were tuned in), K and V planes
+    unquantized and in the compute dtype, no latent pool, heads that
+    fill whole (8, 128) tiles, no ALiBi, sinks or softcap, a window that
+    is None or one trace-time integer -- else None: the in-loop gather
+    as far as _pool_ladder's rung (_attend_pool_rung). In the
+    benchmark's cells the kernel serves mistral-7b and Ouro-2.6B; kanana
+    (a latent MQA plane) and trinity (4 K/V heads, layers held one by
+    one, windowed reads already bounded) keep the XLA form."""
+    if (not cfg.pool_kernel.startswith("pallas") or paged.quantized
+            or cfg.mla_latent_cache or cfg.attn_windows is not None
+            or cfg.position_embedding == "alibi" or cfg.attn_sinks
+            or cfg.attn_softcap is not None
+            or paged.k.dtype != jnp.dtype(cfg.dtype)
+            or not _layers_scanned(params, cfg)):
+        return None
+    from distributed_llm_inferencing_tpu.ops.pallas import paged_attention
+    if not paged_attention.supported(paged.k.shape[3], paged.k.shape[4],
+                                     paged.k.dtype):
+        return None
+    return cfg.pool_kernel
+
+
 def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
                        block_tables, context_lens, seeds, steps0, temps,
                        tks, tps, ds, budget, eos_ids, dummy_block: int,
@@ -1421,14 +1453,22 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
     (layer, block). (Static slices of the loop-invariant pool were
     hoisted out of the token loop and re-laid out, the whole pool once a
     chunk: PERF.md section 6, PR 38.) Each step's attention takes two KV
-    segments,
-    ``gather(pool) masked < cl0`` and ``side masked <= t``
-    (ops/attention.attend): their scores meet in one softmax and K and V
-    are never concatenated or widened. The pool is loop-invariant during
-    the chunk, which is what makes the split exact. On the chip (PERF.md
-    section 5) the pass is the weights, the per-layer gather of every
-    slot's block table (``kv_gather``) and attention's one read of what
-    was gathered. Both stop at the rung of ``_pool_ladder`` that holds
+    segments, the pool's positions ``< cl0`` and ``side masked <= t``:
+    their scores meet in one softmax and K and V are never concatenated
+    or widened. The pool is loop-invariant during the chunk, which is
+    what makes the split exact. The pool's segment takes one of two
+    forms (``_pool_kernel``, from what the trace can see). *The kernel*
+    (a one-device TPU program, a scanned stack, unquantized K and V
+    planes of whole (8, 128) tiles: mistral-7b, Ouro-2.6B):
+    ops/pallas/paged_attention.paged_attend reads each live slot's pages
+    where the pool lies, by (layer, block-table entry), as far as that
+    slot's own context, and keeps both segments' softmax inside the
+    call: K and V cross HBM once and nothing of their size is written.
+    *The in-loop gather* (everything else; ops/attention.attend over
+    ``gather(pool) masked < cl0`` and the side rows): on the chip
+    (PERF.md section 5) the per-layer gather of every slot's block table
+    (``kv_gather``) writes a copy that attention reads, K and V crossing
+    HBM three times. Both stop at the rung of ``_pool_ladder`` that holds
     the longest live context (``_pool_rung``, chosen on the device from
     ``context_lens`` and ``budget`` before the scan; ``_attend_pool_rung``
     is a lax.switch inside this one program): positions past it have
@@ -1453,7 +1493,9 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
     MOE_STATS vectors over the chunk's passes and MoE layers, slots no
     longer alive counted as idle rows (zeros for a dense model), and
     ``pool_positions`` is the pool extent each slot was gathered and
-    attended over on every pass of this chunk, ``window_positions`` what
+    attended over on every pass of this chunk (the ladder's rung; under
+    the kernel the longest live context in whole blocks, which is what
+    it walks at most), ``window_positions`` what
     a windowed layer read instead (the widest, should widths differ;
     ``pool_positions`` where no layer took the bounded read).
 
@@ -1505,8 +1547,21 @@ def decode_chunk_with_logits(params, cfg: ModelConfig, k: int, tokens, paged,
     pool_pos = jnp.broadcast_to(jnp.arange(mb * bs, dtype=jnp.int32),
                                 (r, mb * bs))
     pool_valid = pool_pos < cl0[:, None]
+    kernel = _pool_kernel(params, cfg, paged)
     ladder = _pool_ladder(mb, _layers_scanned(params, cfg))
-    rung, pool_positions = _pool_rung(ladder, bs, cl0, budget > 0)
+    if kernel:
+        # the kernel walks each live slot's block table to its own
+        # length: no rung, no branch; the extent a pass reads at most is
+        # the longest live context's, in whole blocks
+        from distributed_llm_inferencing_tpu.ops.pallas import (
+            paged_attention)
+        pool_positions = -(-jnp.max(jnp.where(budget > 0, cl0, 0))
+                           // bs) * bs
+        walk = paged_attention.pool_walk(
+            cl0, budget > 0, paged.k, mb,
+            sliding_window=cfg.sliding_window)
+    else:
+        rung, pool_positions = _pool_rung(ladder, bs, cl0, budget > 0)
     side_pos = cl0[:, None] + jnp.arange(k, dtype=jnp.int32)[None, :]
     side0 = (jnp.zeros((L, r, k, cfg.cache_kv_heads, cfg.cache_head_dim),
                        dt),) * n_planes
@@ -1516,7 +1571,7 @@ def decode_chunk_with_logits(params, cfg: ModelConfig, k: int, tokens, paged,
     # per-step per-layer gather (transient, one layer at a time).
     gathered_bytes = n_planes * dt.itemsize * L * r * mb * bs \
         * cfg.cache_kv_heads * cfg.cache_head_dim
-    pre = gathered_bytes <= _PREGATHER_MAX_BYTES
+    pre = gathered_bytes <= _PREGATHER_MAX_BYTES and not kernel
     if pre:
         pool, scales = _pool_pregather(paged, block_tables, dt), ()
     else:                                # gathered per layer in-loop
@@ -1545,6 +1600,17 @@ def decode_chunk_with_logits(params, cfg: ModelConfig, k: int, tokens, paged,
         def make_layer(seg_cfg):
             def layer(carry, layer_in):
                 (x, sd), (lp, li) = carry, layer_in
+
+                def attend_kernel(q, rows):
+                    # pool and side rows in one softmax inside the call,
+                    # the planes taken where they lie at the layer's
+                    # index
+                    with jax.named_scope("attention"):
+                        return paged_attention.paged_attend(
+                            q, pool[0], pool[1], li, block_tables, cl0,
+                            cl0 + t, walk, (rows[0], rows[1], t),
+                            sliding_window=seg_cfg.sliding_window,
+                            interpret=kernel == "pallas_interpret")
 
                 def attend_side(q, sd2, sliding_window=None, **kw):
                     kind = _layer_kind(cfg, sliding_window)
@@ -1589,6 +1655,8 @@ def decode_chunk_with_logits(params, cfg: ModelConfig, k: int, tokens, paged,
 
                 def attend_write(q, kh, vh):
                     sd2, rows = _write_side(sd, (kh, vh), t, dt, li)
+                    if kernel:
+                        return attend_kernel(q, rows), sd2
                     return attend_side(
                         q, rows, sliding_window=_layer_window(seg_cfg, lp),
                         alibi=_alibi(seg_cfg), softcap=seg_cfg.attn_softcap,
@@ -1670,8 +1738,9 @@ def _paged_decode_chunk_stepwise(params, cfg: ModelConfig, k: int, tokens,
     (_, paged, _, _), (toks, emits) = jax.lax.scan(
         body, (tokens, paged, context_lens, budget > 0),
         jnp.arange(k, dtype=jnp.int32))
-    # this path counts no expert loads (MOE_STATS stays zero) and the
-    # paged kernels walk each slot's whole block table
+    # this path counts no expert loads (MOE_STATS stays zero); the
+    # extent is reported as the whole block table's (the gather's and
+    # the fused kernel's; the paged kernel stops at each slot's length)
     whole = jnp.int32(block_tables.shape[1] * paged.block_size)
     return (toks, emits, jnp.zeros((len(MOE_STATS),), jnp.int32), whole,
             whole, paged)

@@ -184,13 +184,14 @@ def resolve_backend(requested: str = "auto", n_devices: int = 1,
     jit program spans one device.
 
     ``op="paged"`` (the continuous batcher's block-table decode): auto
-    resolves to xla, the gather formulation (the decode chunks of
-    models/transformer.py, which gather and read the pool as far as the
-    rung of _pool_ladder that holds the longest live context, and
-    ops/paged_kvcache.paged_attend_decode). No chip run on record
-    compares it with the pallas paged kernel (PERF.md section 7; ROADMAP
-    S4 (c): what the rungs leave of the XLA path's cost is the bar).
-    Explicit "pallas" is honored.
+    resolves to xla, the side-buffer decode chunks of
+    models/transformer.py and ops/paged_kvcache.paged_attend_decode's
+    gather. This backend does not say how those chunks read the pool:
+    they take the Pallas paged kernel by themselves where the batcher's
+    ``cfg.pool_kernel`` pin and the pool's shape allow
+    (transformer._pool_kernel; PERF.md section 6, PR 40) and the gather
+    as far as _pool_ladder's rung elsewhere. Explicit "pallas" is
+    honored: the stepwise chunk, which writes the pool every step.
     """
     requested = os.environ.get("DLI_ATTENTION", requested)
     if requested in ("xla", "pallas", "pallas_interpret"):

@@ -28,8 +28,12 @@ contiguous [R, MB*bs, ...] view, which ``attend`` then reads once as it
 is (grouped-query form, no copy: ops/attention.py). The gather writes that
 view to HBM and covers the whole block table whatever the context, so it
 is a cost of its own beside attention (``kv_gather`` in PERF.md section
-5). A hand-tiled Pallas variant that skips the materialization is
-ops/pallas/paged_attention.py; the batcher does not select it.
+5). ops/pallas/paged_attention.py reads the pages where they lie
+instead, each slot as far as its own context: the decode chunks of a
+one-device TPU program take it where the pool's shape allows
+(models/transformer.py _pool_kernel: mistral-7b and Ouro-2.6B among the
+benchmark's cells; PERF.md section 6, PR 40), everything else keeps the
+gather.
 
 The reference framework has no counterpart at any level — its KV cache was
 implicit inside HF ``generate`` (SURVEY.md §2.4).
@@ -278,11 +282,14 @@ def paged_attend_decode(q, cache_k_layer, cache_v_layer, block_tables,
     just written (the query sits at context_lens - 1).
 
     backend "pallas" routes to the block-table-driven kernel
-    (ops/pallas/paged_attention.py) which skips the gather
-    materialization below. "auto" resolves to the XLA gather formulation
-    (ops/attention.resolve_backend); which of the two is faster on a chip
-    at serving shapes has not been measured (PERF.md section 7). The
-    gather copies MB*bs positions per slot whatever ``context_lens``
+    (ops/pallas/paged_attention.py paged_flash_decode) which skips the
+    gather materialization below and stops at each slot's own length.
+    "auto" resolves to the XLA gather formulation
+    (ops/attention.resolve_backend): this stepwise entry writes the pool
+    on every step and is no serving path; the decode chunks choose the
+    kernel themselves (models/transformer.py _pool_kernel), where it
+    was measured at 1.5-4.2 times the gather's speed (PERF.md section 5).
+    The gather copies MB*bs positions per slot whatever ``context_lens``
     says, and attention then reads all of them.
 
     int8 caches (``k_scale_layer``/``v_scale_layer`` present) always take

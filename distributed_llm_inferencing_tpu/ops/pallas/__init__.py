@@ -10,29 +10,36 @@ two attention regimes —
 - ``flash_decode``: single-token cached attention streaming the KV cache
   from HBM (bandwidth-bound)
 
-plus the paged and fused decode kernels:
+plus the fused decode kernels:
 
-- ``paged_attention.paged_flash_decode``: block-table-driven decode
-  attention straight out of the paged pool (no gather materialization)
 - ``quant_matmul.q4_matmul``: nibble-packed int4 dequant-GEMV that
   never materializes unpacked weights in HBM
 - ``fused_decode.fused_decode_step``: dequant-GEMV -> RoPE -> paged
   flash attention chained in ONE pallas_call (``DLI_FUSED_DECODE``)
 
-and the one kernel a benchmark cell runs (kanana's and trinity's decode
-chunks, PERF.md section 3):
+and the two kernels the benchmark's cells run, each chosen by the code
+from shapes it can see in a one-device TPU program's decode chunks
+(PERF.md section 3):
 
-- ``grouped_matmul.grouped_matmul``: the experts' grouped matmul where
-  an expert holds a handful of rows, each hit expert's weights streamed
-  once (``lax.ragged_dot`` keeps every other size; the batcher pins
-  ``cfg.expert_matmul``, models/transformer.py:_expert_stream decides)
+- ``grouped_matmul.grouped_matmul`` (kanana, trinity): the experts'
+  grouped matmul where an expert holds a handful of rows, each hit
+  expert's weights streamed once (``lax.ragged_dot`` keeps every other
+  size; the batcher pins ``cfg.expert_matmul``,
+  models/transformer.py:_expert_stream decides)
+- ``paged_attention.paged_attend`` (mistral-7b, Ouro-2.6B): a pass's
+  attention over the paged pool, the pages read where they lie by
+  (plane, block-table entry), each slot as far as its own context, the
+  chunk's side rows in the same softmax (the in-loop gather as far as
+  _pool_ladder's rung keeps every other pool; the batcher pins
+  ``cfg.pool_kernel``, models/transformer.py:_pool_kernel decides);
+  ``paged_flash_decode`` is the stepwise path's entry on it
 
 All run in interpreter mode on CPU for tests (tests/test_pallas_attention.py,
 tests/test_pallas_parity.py, tests/test_grouped_matmul.py — the
 differential suites against the XLA oracles) and compiled on TPU via
 ops/attention.py's backend dispatch (the attention kernels) or the
-batcher's pin (the grouped matmul); tests/test_tpu_compile.py compiles
-them for a described v5e.
+batcher's pins (the grouped matmul, the pool kernel);
+tests/test_tpu_compile.py compiles them for a described v5e.
 """
 
 from distributed_llm_inferencing_tpu.ops.pallas.flash_attention import (  # noqa: F401

@@ -15,8 +15,10 @@ are pure loss — this kernel chains all three in ONE ``pallas_call``:
   per-slot cos/sin rows and parks q in VMEM scratch;
 - grid steps (slot, kv-head, j) walk the slot's block table with the
   scalar-prefetched indices driving the K/V BlockSpec index maps
-  (paged_attention.py's trick: each step DMAs its [bs, hd] tile straight
-  from the pool) and accumulate online softmax over the q scratch;
+  (each step DMAs its [bs, hd] tile straight from the pool: the grid the
+  paged kernel had before PR 40, a 4 KB tile a step over head-major
+  copies of both planes, which is why this kernel wins no cell: ROADMAP
+  S8 (a)) and accumulate online softmax over the q scratch;
 - the last block normalizes and writes the [g, hd] context — q never
   touches HBM.
 
@@ -165,7 +167,7 @@ def _fused_kernel(bt_ref, len_ref, x_ref, w_ref, s_ref, cos_ref, sin_ref,
 
     # Block-table entries past the sequence skip their FLOPs (the DMA
     # still happens — the static grid is the price of one compiled
-    # program for every slot mix), same as paged_attention.py.
+    # program for every slot mix).
     @pl.when(kv_start < length)
     def _compute():
         q = q_scr[:]                                      # [g, hd] f32

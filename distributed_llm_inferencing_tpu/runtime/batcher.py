@@ -425,7 +425,10 @@ class ContinuousBatcher:
             # the experts' decode-sized grouped matmuls
             # (models/transformer.py _expert_stream): like the attention
             # kernels, a Pallas call that GSPMD does not partition
-            expert_matmul=_expert_backend(self.mesh_spec.num_devices))
+            expert_matmul=_expert_backend(self.mesh_spec.num_devices),
+            # ... and the decode chunk's read of the pool
+            # (transformer._pool_kernel), by the same rule
+            pool_kernel=_expert_backend(self.mesh_spec.num_devices))
         validate_spec(self.mesh_spec, cfg)
         self.mesh = create_mesh(self.mesh_spec)
         self.block_size = block_size
@@ -516,6 +519,7 @@ class ContinuousBatcher:
         self._wave_count = 0      # admit programs run (`wave` on their spans)
         self._pool_positions = 0  # the last decode chunk's (_run_decode)
         self._window_positions = 0   # ... and what a windowed layer read
+        self._pool_kernel = None  # whether they read it by the kernel
         self._wave_cut = None   # (tail, prefix) group the bound last cut
         # admission waves cut short by WAVE_SCORE_BUDGET
         self.metrics.inc("batcher_admit_waves_bounded", 0)
@@ -1359,7 +1363,24 @@ class ContinuousBatcher:
                              self._pool_positions * int(a["k"]))
             self.metrics.inc("batcher_decode_window_positions",
                              self._window_positions * int(a["k"]))
+            self.metrics.inc("batcher_pool_kernel_passes",
+                             int(a["k"]) * self.pool_kernel)
             return toks, emits
+
+    @property
+    def pool_kernel(self) -> bool:
+        """Whether the plain decode chunks read the pool by the Pallas
+        kernel (ops/pallas/paged_attention.py): the program's own choice
+        (transformer._pool_kernel, a trace-time constant of the config
+        as pinned, the parameters' form and the pool's shape), asked
+        once. The pipelined chunk and the speculative one keep the XLA
+        form. ``batcher_pool_kernel_passes`` over
+        ``batcher_weight_passes`` is 1.0 where it holds, and the
+        ``batcher.decode_chunk`` span carries it as ``pool_kernel``."""
+        if self._pool_kernel is None:
+            self._pool_kernel = self.mesh_spec.pp == 1 and bool(
+                transformer._pool_kernel(self.params, self.cfg, self.paged))
+        return self._pool_kernel
 
     def _hist_deltas(self) -> list:
         """JSON-safe per-slot history deltas for the lockstep broadcast:
@@ -2984,7 +3005,8 @@ class ContinuousBatcher:
                    "kv_bytes_per_token": self.paged.bytes_per_token,
                    "loop_steps": self.cfg.loop_steps,
                    "pool_positions": self._pool_positions,
-                   "window_positions": self._window_positions})
+                   "window_positions": self._window_positions,
+                   "pool_kernel": int(self.pool_kernel)})
         # drafting history stays current even when the adaptive controller
         # runs plain chunks in a speculative batcher — pure function of
         # program outputs, so lockstep followers mirror it in replay()
@@ -3285,9 +3307,10 @@ def _backend(cfg: ModelConfig, num_devices: int = 1) -> str:
 
 
 def _expert_backend(num_devices: int = 1, platform: str = "") -> str:
-    """``cfg.expert_matmul`` of a program over ``num_devices`` devices of
-    ``platform`` (the process's own backend unless a described one is
-    named: scripts/compile_serving_programs.py): the Pallas kernel only
-    in a one-device TPU program, which GSPMD need not partition."""
+    """``cfg.expert_matmul`` and ``cfg.pool_kernel`` of a program over
+    ``num_devices`` devices of ``platform`` (the process's own backend
+    unless a described one is named:
+    scripts/compile_serving_programs.py): a Pallas kernel only in a
+    one-device TPU program, which GSPMD need not partition."""
     on_tpu = (platform or jax.default_backend()) == "tpu"
     return "pallas" if on_tpu and num_devices == 1 else "xla"

@@ -1,0 +1,215 @@
+#!/usr/bin/env python3
+"""Device time of a decode pass's attention over the paged pool at the
+benchmark cells' shapes, in the two forms models/transformer.py has
+(PERF.md section 6, PR 40):
+
+- ``rung``: the XLA form, ``_attend_pool_rung``'s taken branch: gather
+  every slot's block-table columns as far as the rung of
+  ``_pool_ladder`` that holds the longest live context, then the
+  two-segment ``attend`` over (the gathered copy, the side rows);
+- ``kernel``: ops/pallas/paged_attention.paged_attend, which walks each
+  live slot's block table to its own length and reads the pages where
+  they lie.
+
+One timed call is a ``lax.scan`` over every plane of the stacked pool
+(mistral-7b: 32, Ouro-2.6B: 192), as a decode pass makes them, so a call
+is milliseconds and the dispatch cost is out of the number. Shapes:
+mistral 16 slots x 32 query heads over 8 K/V heads, 1025 blocks, 128
+block-table columns, window 4096; Ouro 8 slots x 16 heads, 321 blocks, 40
+columns. Lengths: every slot at the table's end; the cells' own ragged
+draws (mistral ``decode-sat``: 16 live contexts of 81-768; Ouro
+``cot-sat``: 8 of 249-576); mistral ``chat-steady``: one live slot of 16.
+Each row gives the time, the live K and V bytes (every live slot's
+context once) and their share of the HBM peak, and the kernel's largest
+difference from the rung form.
+
+On the chip: ``python scripts/bench_paged_attend.py`` (~2 min; the last
+stdout line is JSON, the table goes to chiprun_out/pr40/microbench.json).
+``--small`` rehearses the control flow on the CPU with the kernel
+interpreted (its times mean nothing).
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import statistics
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+HBM_GBPS = 819.0      # TPU v5e (benchmarks/chip/peaks.json)
+SIDE = 8              # decode_chunk_cap: the side rows of a chunk
+
+
+def timed(fn, args, calls, repeats):
+    out = jax.block_until_ready(fn(*args))
+    sets = []
+    for _ in range(repeats):
+        t0 = time.perf_counter()
+        for _ in range(calls):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        sets.append((time.perf_counter() - t0) / calls * 1e3)
+    return out, min(sets), statistics.median(sets)
+
+
+def forms(bs, mb, window, interpret):
+    """name -> jitted f(q, k, v, bt, cl, live, side_k, side_v, t): every
+    plane's attention output summed in float32."""
+    from distributed_llm_inferencing_tpu.models.transformer import (
+        _pool_ladder, _pool_rung)
+    from distributed_llm_inferencing_tpu.ops.attention import attend
+    from distributed_llm_inferencing_tpu.ops.paged_kvcache import gather_seq
+    from distributed_llm_inferencing_tpu.ops.pallas import (
+        paged_attention as pa)
+    ladder = _pool_ladder(mb)
+
+    def over_planes(one_plane, q, k):
+        def body(acc, plane):
+            return acc + one_plane(plane).astype(jnp.float32), None
+        return jax.lax.scan(body, jnp.zeros(q.shape, jnp.float32),
+                            jnp.arange(k.shape[0], dtype=jnp.int32))[0]
+
+    def rung(q, k, v, bt, cl, live, side_k, side_v, t):
+        r = q.shape[0]
+        idx, _ = _pool_rung(ladder, bs, cl, live)
+        side_pos = cl[:, None] + jnp.arange(SIDE, dtype=jnp.int32)[None, :]
+        side_valid = jnp.broadcast_to(
+            jnp.arange(SIDE, dtype=jnp.int32)[None, :] <= t, (r, SIDE))
+
+        def branch(m):
+            def run(plane):
+                pos = jnp.broadcast_to(
+                    jnp.arange(m * bs, dtype=jnp.int32), (r, m * bs))
+                return attend(
+                    q, (gather_seq(k, bt[:, :m], plane), side_k[plane]),
+                    (gather_seq(v, bt[:, :m], plane), side_v[plane]),
+                    (cl + t)[:, None], (pos, side_pos),
+                    (pos < cl[:, None], side_valid), sliding_window=window)
+            return run
+        return over_planes(
+            lambda plane: jax.lax.switch(idx, [branch(m) for m in ladder],
+                                         plane), q, k)
+
+    def kernel(q, k, v, bt, cl, live, side_k, side_v, t):
+        walk = pa.pool_walk(cl, live, k, mb, sliding_window=window)
+        return over_planes(
+            lambda plane: pa.paged_attend(
+                q, k, v, plane, bt, cl, cl + t, walk,
+                (side_k[plane], side_v[plane], t),
+                sliding_window=window, interpret=interpret), q, k)
+
+    return {"rung": jax.jit(rung), "kernel": jax.jit(kernel)}
+
+
+def kernel_plans(sweep):
+    """(label, (_STEP_ROWS, _TAIL_ROWS, _STEP_BYTES)) of the kernel's own
+    plan and of each ``rows:tail:KiB`` of ``--sweep``. A plan is read
+    when a form is traced, so each gets its own jit (forms) and is set
+    (use_plan) before that jit's first call."""
+    from distributed_llm_inferencing_tpu.ops.pallas import (
+        paged_attention as pa)
+    plans = [("", (pa._STEP_ROWS, pa._TAIL_ROWS, pa._STEP_BYTES))]
+    for spec in filter(None, sweep.split(",")):
+        rows, tail, kib = (int(x) for x in spec.split(":"))
+        plans.append(("@" + spec, (rows, tail, kib * 1024)))
+    return plans
+
+
+def use_plan(plan):
+    from distributed_llm_inferencing_tpu.ops.pallas import (
+        paged_attention as pa)
+    pa._STEP_ROWS, pa._TAIL_ROWS, pa._STEP_BYTES = plan
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--small", action="store_true")
+    ap.add_argument("--calls", type=int, default=20)
+    ap.add_argument("--repeats", type=int, default=3)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--out", default="chiprun_out/pr40/microbench.json")
+    ap.add_argument("--sweep", default="", help="rows:tail:KiB,... : time "
+                    "the kernel alone at these (_STEP_ROWS, _TAIL_ROWS, "
+                    "_STEP_BYTES) too")
+    args = ap.parse_args()
+    dev = jax.devices()[0]
+    if not args.small and dev.platform != "tpu":
+        print(json.dumps({"ok": False, "why": f"no TPU: {dev.platform}"}))
+        return 2
+    bs, hd = 16, 128
+    # (name, planes, slots, query heads, K/V heads, blocks, columns,
+    #  window, cases of (name, live slots, shortest, longest context))
+    models = [
+        ("mistral-7b", 32, 16, 32, 8, 1025, 128, 4096,
+         [("full", 16, 2048, 2048), ("decode-sat", 16, 81, 768),
+          ("chat-steady", 1, 81, 768)]),
+        ("ouro-2.6b", 192, 8, 16, 16, 321, 40, None,
+         [("full", 8, 640, 640), ("cot-sat", 8, 249, 576)]),
+    ]
+    if args.small:
+        models = [(name, 2, 4, h, hkv, 33, 8, 24 if window else None,
+                   [("full", 4, 128, 128), ("ragged", 3, 5, 100)])
+                  for name, _, _, h, hkv, _, _, window, _ in models]
+        args.calls, args.repeats = 1, 1
+    rng = np.random.default_rng(args.seed)
+    plans = kernel_plans(args.sweep)
+    table = []
+    for name, planes, r, h, hkv, nb, mb, window, cases in models:
+        keys = jax.random.split(jax.random.PRNGKey(args.seed), 5)
+        k, v = (jax.random.normal(kk, (planes, nb, bs, hkv, hd),
+                                  jnp.bfloat16) for kk in keys[:2])
+        q = jax.random.normal(keys[2], (r, 1, h, hd), jnp.bfloat16)
+        side_k, side_v = (jax.random.normal(
+            kk, (planes, r, SIDE, hkv, hd), jnp.bfloat16) for kk in keys[3:])
+        # the rung form once, the kernel under every plan
+        runs = [(form + label, fn, plan)
+                for label, plan in plans
+                for form, fn in forms(bs, mb, window, args.small).items()
+                if form == "kernel" or not label]
+        for case, n_live, lo, hi in cases:
+            lens = np.zeros(r, np.int64)
+            lens[rng.permutation(r)[:n_live]] = rng.integers(
+                lo, hi + 1, n_live)
+            bt = np.zeros((r, mb), np.int32)
+            bt.reshape(-1)[:] = rng.permutation(r * mb) % (nb - 1)
+            call = (q, k, v, jnp.asarray(bt), jnp.asarray(lens, jnp.int32),
+                    jnp.asarray(lens > 0), side_k, side_v, jnp.int32(3))
+            gb = planes * int(lens.sum()) * hkv * hd * 2 * 2 / 1e9
+            ref = None
+            for form, fn, plan in runs:
+                use_plan(plan)
+                y, best, med = timed(fn, call, args.calls, args.repeats)
+                y = np.asarray(y)[lens > 0]
+                ref = y if ref is None else ref
+                row = {"model": name, "case": case, "form": form,
+                       "live_slots": n_live, "longest": int(lens.max()),
+                       "mean_live": round(float(lens.sum()) / n_live, 1),
+                       "live_gb": round(gb, 4), "ms_min": round(best, 4),
+                       "ms_median": round(med, 4),
+                       "us_a_plane": round(best / planes * 1e3, 2),
+                       "hbm_share_of_live_bytes": round(
+                           gb / HBM_GBPS / (best / 1e3) * 100, 1),
+                       "max_abs_diff_vs_rung": float(np.max(np.abs(y - ref))),
+                       "sum_scale": float(np.max(np.abs(ref)))}
+                table.append(row)
+                print(json.dumps(row), flush=True)
+        use_plan(plans[0][1])
+        del k, v
+    os.makedirs(os.path.dirname(args.out), exist_ok=True)
+    with open(args.out, "w") as f:
+        json.dump({"device": dev.device_kind, "rows": table}, f, indent=1)
+    print(json.dumps({"ok": True, "device": dev.device_kind,
+                      "n": len(table), "out": args.out}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

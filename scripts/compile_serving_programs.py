@@ -186,7 +186,9 @@ def report(name, lowered, t0, paged):
           f"pallas calls {text.count('tpu_custom_call')} (ragged-dot "
           f"{len(re.findall(r'%ragged-dot[^ ]* = ', text))}, "
           f"expert_stream_matmul "
-          f"{len(re.findall(r'%expert_stream_matmul[^ ]* = ', text))}); "
+          f"{len(re.findall(r'%expert_stream_matmul[^ ]* = ', text))}, "
+          f"paged_pool_attend "
+          f"{len(re.findall(r'%paged_pool_attend[^ ]* = ', text))}); "
           f"pool- or plane-sized arrays made {len(sized)}: "
           f"{' '.join(f'{n}({op})' for n, op in sized)}",
           flush=True)
@@ -208,7 +210,9 @@ def main(widths):
         cfg = cfg.replace(attn_backend=_backend(cfg, spec.num_devices),
                           tp_row_sharded=tp > 1, mla_latent_cache=cfg.mla,
                           expert_matmul=_expert_backend(spec.num_devices,
-                                                        "tpu"))
+                                                        "tpu"),
+                          pool_kernel=_expert_backend(spec.num_devices,
+                                                      "tpu"))
         print(f"--- {MODEL} {QUANT}, {cfg.num_layers} layers, tp={tp}, "
               f"attn_backend={cfg.attn_backend}", flush=True)
 

@@ -143,6 +143,141 @@ def test_paged_flash_decode_sliding_window():
                                rtol=2e-5, atol=2e-5)
 
 
+# ---- paged_attend: the decode chunk's pool kernel vs the two-segment ---
+# ---- attend over gathered K and V ---------------------------------------
+
+@pytest.mark.parametrize("side_rows", [1, 8])
+@pytest.mark.parametrize("window", [None, 9])
+@pytest.mark.parametrize("g,hkv", [(1, 8), (4, 8), (1, 16), (4, 16)])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_paged_attend_matches_two_segment_attend(monkeypatch, dtype, g, hkv,
+                                                 window, side_rows):
+    """The kernel, interpreted, against ops/attention.attend over (the
+    gathered pool, the chunk's side rows): a plane of a stack taken by a
+    traced index (a looped model's u * L + l), ragged lengths with a dead
+    slot (length 0 and, apart from it, a slot that is not live), a length
+    on a block boundary and one at the table's end, a window shorter
+    than a context, every pass t of the side rows. Work items of four
+    pages in steps of two and tail steps of one, so a slot's walk takes
+    several items and ends on a short one, in steps of both widths."""
+    from distributed_llm_inferencing_tpu.ops import attention
+    from distributed_llm_inferencing_tpu.ops.pallas import paged_attention
+    from distributed_llm_inferencing_tpu.ops.paged_kvcache import gather_seq
+    dt = jnp.dtype(dtype)
+    rng = np.random.default_rng(g * 100 + hkv + side_rows)
+    planes, nb, bs, mb, hd = 3, 48, 4, 7, 128
+    h = g * hkv
+    lens = np.asarray([0, 13, 8, mb * bs, 5, 17], np.int32)
+    live = np.asarray([0, 1, 1, 1, 0, 1], bool)
+    r = len(lens)
+    k_planes, v_planes = (_rand(rng, planes, nb, bs, hkv, hd).astype(dt)
+                          for _ in range(2))
+    bt = jnp.asarray(rng.permutation(np.arange(1, nb))[:r * mb]
+                     .reshape(r, mb).astype(np.int32))
+    side_k, side_v = (_rand(rng, r, side_rows, hkv, hd).astype(dt)
+                      for _ in range(2))
+    q = _rand(rng, r, 1, h, hd).astype(dt)
+    cl = jnp.asarray(lens)
+    # (pages a tail step, a step, an item) = (1, 2, 4)
+    page_rows = bs * hkv
+    monkeypatch.setattr(paged_attention, "_TAIL_ROWS", page_rows)
+    monkeypatch.setattr(paged_attention, "_STEP_ROWS", 2 * page_rows)
+    monkeypatch.setattr(paged_attention, "_STEP_BYTES",
+                        4 * page_rows * hd * dt.itemsize)
+    walk = paged_attention.pool_walk(
+        cl, jnp.asarray(live), k_planes, mb, sliding_window=window)
+    assert int(walk.count[0]) == sum(
+        -(-(-(-n // bs) - (max(n - window + 1, 0) // bs if window else 0))
+           // 4) for n, a in zip(lens, live) if a)
+
+    @jax.jit
+    def both(plane, t):
+        out = paged_attention.paged_attend(
+            q, k_planes, v_planes, plane, bt, cl, cl + t, walk,
+            (side_k, side_v, t), sliding_window=window, interpret=True)
+        pool_pos = jnp.broadcast_to(jnp.arange(mb * bs), (r, mb * bs))
+        side_pos = cl[:, None] + jnp.arange(side_rows)[None, :]
+        ref = attention.attend(
+            q, (gather_seq(k_planes, bt, plane), side_k),
+            (gather_seq(v_planes, bt, plane), side_v), (cl + t)[:, None],
+            (pool_pos, side_pos),
+            (pool_pos < cl[:, None],
+             jnp.broadcast_to(jnp.arange(side_rows) <= t, (r, side_rows))),
+            sliding_window=window)
+        return out, ref
+
+    tol = 2e-5 if dtype == "float32" else 1e-2
+    for plane, t in ((2, 0), (1, side_rows - 1)):
+        out, ref = both(jnp.int32(plane), jnp.int32(t))
+        out, ref = (np.asarray(x, np.float32) for x in (out, ref))
+        # a slot the walk leaves out reads its side rows alone: what the
+        # chunk never emits; the live ones are the comparison
+        np.testing.assert_allclose(out[live], ref[live], rtol=tol, atol=tol)
+        assert np.isfinite(out).all()
+
+
+def _serve(cfg, prompts, new=10):
+    from distributed_llm_inferencing_tpu.runtime.batcher import (
+        ContinuousBatcher)
+    b = ContinuousBatcher(cfg, None, seed=0, slots=4, num_blocks=64,
+                          block_size=4, max_seq=64, prefill_chunk=4,
+                          decode_chunk_cap=8, kv_host_mb=0)
+    greedy = SamplingParams.greedy()
+    reqs = [b.submit(p, max_new_tokens=new, sampling=greedy, seed=0)
+            for p in prompts]
+    while b.inflight():
+        b.step()
+    assert all(r.error is None for r in reqs)
+    c = b.metrics.snapshot()["counters"]
+    from distributed_llm_inferencing_tpu.utils import trace
+    span = [s for s in trace.get_tracer().spans()
+            if s.name == "batcher.decode_chunk"][-1]
+    return ([r.tokens for r in reqs],
+            c.get("batcher_pool_kernel_passes", 0),
+            c["batcher_weight_passes"], span.attrs["pool_kernel"])
+
+
+# heads of the width and count the kernel reads as they lie (supported):
+# one (8, 128) tile of K/V heads a position
+_KERNEL_HEADS = dict(num_heads=8, num_kv_heads=8, head_dim=128)
+
+
+@pytest.mark.parametrize("model,shape,kernel", [
+    ("tiny-llama", dict(_KERNEL_HEADS, num_heads=16), True),   # G = 2
+    ("tiny-llama", dict(_KERNEL_HEADS, sliding_window=6), True),
+    ("tiny-ouro", _KERNEL_HEADS, True),        # planes u * L + l
+    ("tiny-llama", {}, False),                 # 4 heads of 8: not its shape
+    ("tiny-kanana", {}, False),                # a latent plane
+    ("tiny-afmoe", {}, False),                 # windows a layer, held 1 by 1
+])
+def test_batcher_pool_kernel_where_the_shape_allows(monkeypatch, model,
+                                                    shape, kernel):
+    """The batcher with its kernel pin interpreted (on a one-device TPU
+    it pins "pallas"): a dense and a looped model whose pool the kernel
+    reads as it lies emit the XLA form's greedy tokens, every decode pass
+    counted in ``batcher_pool_kernel_passes``; a pool of another shape, a
+    latent one and a windowed MoE model's keep the XLA form and count
+    none. The in-loop gather is the XLA form compared with (the chip's:
+    toy pools are otherwise pre-gathered)."""
+    from distributed_llm_inferencing_tpu.models import transformer
+    from distributed_llm_inferencing_tpu.runtime import batcher
+    monkeypatch.setattr(transformer, "_PREGATHER_MAX_BYTES", 0)
+    cfg = get_config(model).replace(dtype="float32", attn_backend="xla",
+                                    **shape)
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(1, cfg.vocab_size, n).tolist()
+               for n in (9, 5, 13)]
+    monkeypatch.setattr(batcher, "_expert_backend",
+                        lambda *a, **k: "pallas_interpret")
+    toks, passes, weight_passes, attr = _serve(cfg, prompts)
+    assert weight_passes > 0 and attr == int(kernel)
+    assert passes == (weight_passes if kernel else 0)
+    if kernel:
+        monkeypatch.setattr(batcher, "_expert_backend",
+                            lambda *a, **k: "xla")
+        assert _serve(cfg, prompts)[:2] == (toks, 0)
+
+
 # ---- fused_decode_step: dequant-GEMV -> RoPE -> paged attention -------
 
 def _fused_ref(cfg, x, q_leaf, k_pool, v_pool, bt, lens, positions,

@@ -307,9 +307,9 @@ def _serving_shapes(cfg, bs, blocks, held=False):
     return params, pool
 
 
-def _decode_chunk_text(compile_on_chip, cfg, k, slots, bs, blocks, mb,
-                       kernel=True, held=False, donate=False):
-    """The text of ``paged_decode_chunk`` compiled for the described chip:
+def _decode_chunk(compile_on_chip, cfg, k, slots, bs, blocks, mb,
+                  kernel=True, held=False, donate=False):
+    """``paged_decode_chunk`` compiled for the described chip:
     ``k`` passes over ``slots`` slots, a pool of ``blocks`` + 1 blocks of
     ``bs`` (the last the reserved one), ``mb`` block-table columns.
     ``held`` as in _serving_shapes; ``donate``: the pool is donated, as
@@ -329,7 +329,11 @@ def _decode_chunk_text(compile_on_chip, cfg, k, slots, bs, blocks, mb,
         chunk, params, pool, ((slots,), jnp.int32),
         ((slots, mb), jnp.int32), ((6, slots), jnp.int32),
         ((2, slots), jnp.float32), ((slots,), jnp.bool_),
-        kernel=kernel, donate=(1,) if donate else ()).as_text()
+        kernel=kernel, donate=(1,) if donate else ())
+
+
+def _decode_chunk_text(*args, **kw):
+    return _decode_chunk(*args, **kw).as_text()
 
 
 def _admit_text(compile_on_chip, cfg, t, pb, wave, bs, blocks, kernel=True,
@@ -416,14 +420,18 @@ def _pool_sized():
 # (tail, prefix blocks, wave), the plane copies its admit program and
 # its decode chunk may keep)
 def _cells():
-    moe = dict(attn_backend="xla", expert_matmul="pallas")
+    pins = dict(attn_backend="xla", expert_matmul="pallas",
+                pool_kernel="pallas")
     return {
         # benchmarks/chip/configs/mistral-7b-int8.json
-        "mistral": (CFG.replace(quant="int8", attn_backend="xla"),
+        "mistral": (CFG.replace(quant="int8", **pins),
                     16, BS, NB - 1, MB, (512, 0, 2), (0, 0)),
+        # .../ouro-2.6b.json: 192 planes, the widest admit wave
+        "ouro": (get_config("ouro-2.6b").replace(**pins),
+                 8, 16, 320, 40, (256, 0, 8), (0, 0)),
         # .../kanana-2-30b-a3b-l7.json: the latent pool
         "kanana": (KANANA.replace(num_layers=7, mla_latent_cache=True,
-                                  **moe),
+                                  **pins),
                    64, 16, 10240, 160, (128, 0, 1), (0, 0)),
         # .../trinity-mini-l5.json. K and V of 4 heads arrive in
         # (4, 128) tiles; the chunk's attention and the wave's write of
@@ -436,13 +444,18 @@ def _cells():
         "trinity": (get_config("trinity-mini").replace(
             num_layers=5, dense_prefix_layers=1,
             attn_windows=(2048,) * 4 + (None,),
-            rope_layers=(1, 1, 1, 1, 0), **moe),
+            rope_layers=(1, 1, 1, 1, 0), **pins),
             64, 16, 12288, 576, (512, 128, 1), (4, 2)),
     }
 
 
-@pytest.mark.parametrize("program", ["admit", "decode-chunk-8"])
-@pytest.mark.parametrize("model", ["mistral", "kanana", "trinity"])
+@pytest.mark.parametrize("model,program", [
+    (model, program) for model in ("mistral", "kanana", "trinity", "ouro")
+    for program in ("admit", "decode-chunk-8")
+    # a looped model's wave writes a step's tails behind that step, a
+    # block at a time (write_blocks(first_plane=)): updates in place,
+    # not the one scatter a plane this test counts
+    if (model, program) != ("ouro", "admit")])
 def test_no_serving_program_copies_the_pool(compile_on_chip, model, program):
     """The cells' admit programs and decode chunks of 8 passes read the
     stacked pool where it lies, by (layer, block), and write it once, in
@@ -454,17 +467,46 @@ def test_no_serving_program_copies_the_pool(compile_on_chip, model, program):
     the scatter's layout and back): 19 ms a chunk on the chip; every
     admit program sliced the pool into layers, stacked the layers'
     outputs into a fresh buffer and copied that into the donated one
-    (mistral: `copy.123`, `copy.124`). PERF.md section 6, PR 38."""
+    (mistral: `copy.123`, `copy.124`). PERF.md section 6, PR 38.
+
+    Since PR 40 the decode chunks of a scanned stack whose pool the
+    Pallas kernel reads as it lies (mistral, Ouro) hold it
+    (`paged_pool_attend`, one call in the scanned layer body) in place of the
+    ladder's `conditional`, and with it nothing of a rung's K or V size,
+    `bf16[slots, rung, Hkv, 128]`: the gather's copy that the pass wrote
+    and read back (20.4 of Ouro's 56 ms pass). Ouro's chunk's transient
+    falls from 1.23 GiB to 1.13 by those copies and no further: the rest
+    is `copy.111`-`113`, the q, k and v weights `bf16[48,2048,2048]`
+    re-laid out once a program (ROADMAP S10). kanana's and trinity's chunks and
+    every admit program have no such call: their traces are the
+    parent's (`transformer._pool_kernel` says None before anything else
+    is traced differently)."""
     cfg, slots, bs, blocks, mb, (t, pb, wave), kept = _cells()[model]
     held = cfg.is_moe
     kept = kept[program != "admit"]
+    pool_kernel = program != "admit" and model in ("mistral", "ouro")
     if program == "admit":
         text = _admit_text(compile_on_chip, cfg, t, pb, wave, bs, blocks,
                            kernel=held, held=held)
     else:
-        text = _decode_chunk_text(compile_on_chip, cfg, 8, slots, bs,
-                                  blocks, mb, kernel=held, held=held,
-                                  donate=True)
+        chunk = _decode_chunk(compile_on_chip, cfg, 8, slots, bs, blocks,
+                              mb, kernel=held or pool_kernel, held=held,
+                              donate=True)
+        text = chunk.as_text()
+    assert ("paged_pool_attend" in text) == pool_kernel
+    if pool_kernel:
+        from distributed_llm_inferencing_tpu.models.transformer import (
+            _pool_ladder)
+        switches = [ln for ln in text.splitlines()
+                    if " conditional(" in ln and "/sample/" not in ln]
+        assert not switches, f"the ladder's switch is built: {switches[:2]}"
+        rungs = "|".join(str(m * bs) for m in _pool_ladder(mb))
+        made = re.findall(rf"= (bf16\[{slots},(?:{rungs}),"
+                          rf"{cfg.num_kv_heads},{cfg.head_dim}\])", text)
+        assert not made, f"a rung of K or V is materialized: {made[:4]}"
+        if model == "ouro":
+            assert chunk.memory_analysis().temp_size_in_bytes \
+                < 1.15 * 2 ** 30
     _, pool = _serving_shapes(cfg, bs, blocks)
     made = _pool_sized()(text, [jax.ShapeDtypeStruct(*p) for p in pool])
     writes = [m for m in made if m[1] in ("fusion(scatter)", "scatter")]
