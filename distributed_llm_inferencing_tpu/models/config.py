@@ -15,6 +15,66 @@ from typing import Optional, Tuple
 
 
 @dataclasses.dataclass(frozen=True)
+class SSMConfig:
+    """A Mamba-2 state-space mixer beside the attention heads of every
+    block (Falcon-H1, modeling_falcon_h1.py), and the multipliers that
+    family scales its block's paths by. The mixer's equations are in
+    models/reference/falcon_h1_ref.py; the served forms in ops/ssm.py.
+
+    Per request the mixer keeps a recurrent state [n_heads, d_head,
+    d_state] (float32) and the last d_conv - 1 inputs of its causal
+    depthwise convolution, [conv_dim] each: a fixed size whatever the
+    context, overwritten on every token (ops/paged_kvcache.py keeps a
+    row a serving slot of each beside the block pool)."""
+    d_ssm: int = 4096          # mamba_d_ssm = n_heads * d_head
+    n_heads: int = 32          # mamba_n_heads
+    d_head: int = 128          # mamba_d_head
+    d_state: int = 256         # mamba_d_state (N)
+    n_groups: int = 2          # mamba_n_groups: B and C are a group's
+    d_conv: int = 4            # mamba_d_conv
+    chunk_size: int = 128      # mamba_chunk_size: prefill's scan chunk
+    conv_bias: bool = True     # mamba_conv_bias
+    # multipliers, each applied where the source applies it:
+    in_multiplier: float = 1.0    # ssm_in_multiplier: on in_proj's input
+    out_multiplier: float = 1.0   # ssm_out_multiplier: on out_proj's output
+    # ssm_multipliers: in_proj's output by part, (z, x, B, C, dt)
+    multipliers: Tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
+    attn_in_multiplier: float = 1.0    # on q/k/v projections' input
+    attn_out_multiplier: float = 1.0   # on o_proj's output
+    key_multiplier: float = 1.0        # on k_proj's output, before RoPE
+    # mlp_multipliers: (on gate_proj's output, on down_proj's output)
+    mlp_multipliers: Tuple[float, float] = (1.0, 1.0)
+
+    def __post_init__(self):
+        for name in ("multipliers", "mlp_multipliers"):
+            object.__setattr__(self, name,
+                               tuple(float(v) for v in getattr(self, name)))
+        assert len(self.multipliers) == 5 and len(self.mlp_multipliers) == 2
+        assert self.d_ssm == self.n_heads * self.d_head, (
+            f"d_ssm={self.d_ssm} != n_heads * d_head")
+        assert self.n_heads % self.n_groups == 0
+        assert self.d_ssm % self.n_groups == 0 and self.d_conv >= 2
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels of the depthwise convolution: [x | B | C]."""
+        return self.d_ssm + 2 * self.n_groups * self.d_state
+
+    @property
+    def proj_dim(self) -> int:
+        """in_proj's output: [z | x | B | C | dt]."""
+        return self.d_ssm + self.conv_dim + self.n_heads
+
+    @property
+    def state_elems(self) -> int:
+        return self.n_heads * self.d_head * self.d_state
+
+    @property
+    def conv_elems(self) -> int:
+        return (self.d_conv - 1) * self.conv_dim
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     # Identity
     name: str = "gpt2"
@@ -35,6 +95,12 @@ class ModelConfig:
     # own: cache plane step * num_layers + layer (cache_planes). 1 is
     # every other family; num_layers stays the weights' depth.
     loop_steps: int = 1
+    # State layers (Falcon-H1): every block runs a Mamba-2 mixer beside
+    # its attention heads, both reading the block's normed input, their
+    # outputs joined before the one residual add. None is every other
+    # family. (A dict, as a checkpoint's config.json gives it back, is
+    # taken for its fields.)
+    ssm: Optional[SSMConfig] = None
 
     # Architecture switches
     norm_type: str = "layernorm"  # layernorm | rmsnorm
@@ -299,7 +365,9 @@ class ModelConfig:
     # rung, everywhere) | "pallas" (a one-device TPU program:
     # ops/pallas/paged_attention.py where the pool's shape is one it
     # reads as it lies) | "pallas_interpret" (tests). Not a serving
-    # option: the batcher overwrites it.
+    # option: the batcher overwrites it. A model with state layers takes
+    # its decode chunk's one-step state update by the same pin
+    # (transformer._ssm_kernel, ops/pallas/ssm_step.py).
     pool_kernel: str = "xla"
 
     def __post_init__(self):
@@ -311,6 +379,16 @@ class ModelConfig:
             f"num_heads={self.num_heads} must be divisible by "
             f"num_kv_heads={self.num_kv_heads}"
         )
+        if isinstance(self.ssm, dict):
+            object.__setattr__(self, "ssm", SSMConfig(**self.ssm))
+        if self.ssm is not None:
+            assert (not self.mla and not self.is_moe and self.gated_mlp
+                    and self.loop_steps == 1 and not self.post_norm
+                    and not self.parallel_residual
+                    and not self.post_block_norms
+                    and not self.sublayer_postnorm_only), (
+                "a state-space mixer rides the plain pre-norm block with "
+                "a gated MLP (Falcon-H1's)")
         if self.rope_inv_freq is not None:
             # normalize (checkpoint config.json roundtrips tuple -> list)
             object.__setattr__(self, "rope_inv_freq",

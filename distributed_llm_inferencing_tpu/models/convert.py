@@ -220,7 +220,7 @@ SUPPORTED_MODEL_TYPES = ("gpt2", "opt", "llama", "mistral", "mixtral",
                          "nemotron", "deepseek_v3", "ernie4_5", "smollm3",
                          "hunyuan_v1_dense", "exaone4", "dbrx", "glm4_moe",
                          "ernie4_5_moe", "gpt_oss", "hunyuan_v1_moe",
-                         "afmoe", "ouro")
+                         "afmoe", "ouro", "falcon_h1")
 
 
 def config_from_hf(hf_config) -> ModelConfig:
@@ -998,6 +998,67 @@ def config_from_hf(hf_config) -> ModelConfig:
                     else None), mlp_bias=False, post_block_norms=True,
             tie_word_embeddings=getattr(hf_config, "tie_word_embeddings",
                                         False))
+    if mt == "falcon_h1":
+        # tiiuae Falcon-H1 (modeling_falcon_h1.py): every block runs a
+        # Mamba-2 mixer beside its attention heads (attn_layer_indices
+        # null: every block is the same), muP multipliers on every path.
+        # NOT YET CHECKED AGAINST A REAL CHECKPOINT: no published
+        # weights are in the repository; tests/test_falcon_h1.py runs a
+        # synthetic state dict under these names (and, where the
+        # installed transformers has the family, a random tiny
+        # FalconH1ForCausalLM's own logits).
+        from distributed_llm_inferencing_tpu.models.config import SSMConfig
+        g = lambda k, d=None: getattr(hf_config, k, d)   # noqa: E731
+        for key, want in (("mamba_rms_norm", True),
+                          ("mamba_norm_before_gate", False),
+                          ("mamba_proj_bias", False),
+                          ("projectors_bias", False),
+                          ("attention_bias", False), ("mlp_bias", False)):
+            if bool(g(key, want)) != want:
+                raise NotImplementedError(
+                    f"falcon_h1 {key}={g(key)!r} — only {want} (the "
+                    "published value) converts")
+        if g("attn_layer_indices") is not None:
+            raise NotImplementedError("falcon_h1 attn_layer_indices")
+        if g("rope_scaling") is not None:
+            raise NotImplementedError("falcon_h1 rope_scaling")
+        H = g("mamba_n_heads")
+        d_ssm = g("mamba_d_ssm") or int(g("mamba_expand", 2)
+                                        * hf_config.hidden_size)
+        return ModelConfig(
+            name=g("name_or_path", mt) or mt, family="falcon_h1",
+            vocab_size=hf_config.vocab_size,
+            hidden_size=hf_config.hidden_size,
+            intermediate_size=hf_config.intermediate_size,
+            num_layers=hf_config.num_hidden_layers,
+            num_heads=hf_config.num_attention_heads,
+            num_kv_heads=hf_config.num_key_value_heads,
+            head_dim=g("head_dim") or (hf_config.hidden_size
+                                       // hf_config.num_attention_heads),
+            max_position_embeddings=hf_config.max_position_embeddings,
+            norm_type="rmsnorm", norm_eps=hf_config.rms_norm_eps,
+            activation=_act_from_hf(hf_config.hidden_act),
+            gated_mlp=True, position_embedding="rope",
+            rope_theta=float(g("rope_theta", 10000.0)),
+            attn_bias=False, mlp_bias=False,
+            embed_scale=float(g("embedding_multiplier", 1.0)),
+            logit_scale=float(g("lm_head_multiplier", 1.0)),
+            tie_word_embeddings=bool(g("tie_word_embeddings", False)),
+            ssm=SSMConfig(
+                d_ssm=d_ssm, n_heads=H,
+                d_head=g("mamba_d_head") or d_ssm // H,
+                d_state=g("mamba_d_state"), n_groups=g("mamba_n_groups"),
+                d_conv=g("mamba_d_conv"),
+                chunk_size=g("mamba_chunk_size", 128),
+                conv_bias=bool(g("mamba_conv_bias", True)),
+                in_multiplier=float(g("ssm_in_multiplier", 1.0)),
+                out_multiplier=float(g("ssm_out_multiplier", 1.0)),
+                multipliers=tuple(g("ssm_multipliers", (1.0,) * 5)),
+                attn_in_multiplier=float(g("attention_in_multiplier", 1.0)),
+                attn_out_multiplier=float(g("attention_out_multiplier",
+                                            1.0)),
+                key_multiplier=float(g("key_multiplier", 1.0)),
+                mlp_multipliers=tuple(g("mlp_multipliers", (1.0, 1.0)))))
     if mt == "exaone4":
         # EXAONE 4.0: the olmo2 sublayer-postnorm topology (x +
         # norm(f(x)), norms named post_attention/post_feedforward) with
@@ -1707,6 +1768,46 @@ def convert_state_dict(cfg: ModelConfig, sd, dtype=None):
             "final_norm": {"scale": get("model.norm.weight")},
             "exit_gate": {"w": get("model.early_exit_gate.weight").T,
                           "b": get("model.early_exit_gate.bias")},
+        }
+        if not cfg.tie_word_embeddings:
+            params["lm_head"] = {"w": get("lm_head.weight").T}
+    elif fam == "falcon_h1":
+        # model.layers.N.{input_layernorm, pre_ff_layernorm,
+        # self_attn.{q,k,v,o}_proj, feed_forward.{gate,up,down}_proj,
+        # mamba.{in_proj, conv1d (weight [C, 1, K] + bias), A_log, D,
+        # dt_bias, norm, out_proj}}; model.final_layernorm; lm_head
+        # (modeling_falcon_h1.py). Not yet checked against a real
+        # checkpoint (see config_from_hf). No multiplier is folded into
+        # a weight: each is applied at run time where the source does.
+        def layer(i):
+            p = f"model.layers.{i}."
+
+            def lin(n):
+                return {"w": get(p + n + ".weight").T}
+            lp = {
+                "attn_norm": {"scale": get(p + "input_layernorm.weight")},
+                "mlp_norm": {"scale": get(p + "pre_ff_layernorm.weight")},
+                "q": lin("self_attn.q_proj"), "k": lin("self_attn.k_proj"),
+                "v": lin("self_attn.v_proj"), "o": lin("self_attn.o_proj"),
+                "gate": lin("feed_forward.gate_proj"),
+                "up": lin("feed_forward.up_proj"),
+                "down": lin("feed_forward.down_proj"),
+                "in_proj": lin("mamba.in_proj"),
+                # torch's depthwise filter [C, 1, K] -> taps [K, C]
+                "conv": {"w": get(p + "mamba.conv1d.weight")[:, 0, :].T},
+                "dt_bias": get(p + "mamba.dt_bias"),
+                "A_log": get(p + "mamba.A_log"),
+                "D": get(p + "mamba.D"),
+                "ssm_norm": {"scale": get(p + "mamba.norm.weight")},
+                "out_proj": lin("mamba.out_proj"),
+            }
+            if cfg.ssm.conv_bias:
+                lp["conv"]["b"] = get(p + "mamba.conv1d.bias")
+            return lp
+        params = {
+            "embed": {"tokens": get("model.embed_tokens.weight")},
+            "layers": _stack([layer(i) for i in range(cfg.num_layers)]),
+            "final_norm": {"scale": get("model.final_layernorm.weight")},
         }
         if not cfg.tie_word_embeddings:
             params["lm_head"] = {"w": get("lm_head.weight").T}

@@ -122,6 +122,31 @@ def init_params(cfg: ModelConfig, key, dtype=None):
         }
         if cfg.attn_gate:   # trinity (afmoe): gate on the attention output
             layers["attn_gate"] = lin(D, cfg.q_dim, False)
+    if cfg.ssm is not None:
+        # Falcon-H1's Mamba-2 mixer (ops/ssm.py), with the source's own
+        # initial values where it gives them (modeling_falcon_h1.py
+        # FalconH1Mixer.__init__): A_log = log(1..H), D = 1, dt_bias the
+        # inverse softplus of a log-uniform step in [1e-3, 1e-1]; the
+        # depthwise filter and its bias as torch's Conv1d draws them,
+        # uniform in +-1/sqrt(d_conv)
+        c = cfg.ssm
+        dt0 = jnp.maximum(jnp.exp(
+            jax.random.uniform(next(keys), (L, c.n_heads), jnp.float32)
+            * (math.log(0.1) - math.log(0.001)) + math.log(0.001)), 1e-4)
+        layers["in_proj"] = lin(D, c.proj_dim, False)
+        layers["conv"] = {"w": jax.random.uniform(
+            next(keys), (L, c.d_conv, c.conv_dim), jnp.float32,
+            -c.d_conv ** -0.5, c.d_conv ** -0.5).astype(dtype)}
+        if c.conv_bias:
+            layers["conv"]["b"] = jax.random.uniform(
+                next(keys), (L, c.conv_dim), jnp.float32,
+                -c.d_conv ** -0.5, c.d_conv ** -0.5).astype(dtype)
+        layers["dt_bias"] = (dt0 + jnp.log(-jnp.expm1(-dt0))).astype(dtype)
+        layers["A_log"] = jnp.broadcast_to(jnp.log(jnp.arange(
+            1, c.n_heads + 1, dtype=jnp.float32)), (L, c.n_heads)).astype(dtype)
+        layers["D"] = ones((L, c.n_heads))
+        layers["ssm_norm"] = {"scale": ones((L, c.d_ssm))}
+        layers["out_proj"] = lin(c.d_ssm, D, False)
     if cfg.post_block_norms:   # gemma2 sandwich norms
         layers["attn_post_norm"] = norm_p()
         layers["mlp_post_norm"] = norm_p()

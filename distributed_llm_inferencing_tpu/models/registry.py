@@ -12,7 +12,8 @@ from __future__ import annotations
 
 from typing import Dict
 
-from distributed_llm_inferencing_tpu.models.config import ModelConfig
+from distributed_llm_inferencing_tpu.models.config import (
+    ModelConfig, SSMConfig)
 
 _REGISTRY: Dict[str, ModelConfig] = {}
 
@@ -286,6 +287,33 @@ register(_ouro(
     num_layers=48, loop_steps=4, num_heads=16, num_kv_heads=16, head_dim=128,
     max_position_embeddings=65536))
 
+# --- Falcon-H1 (tiiuae): every block runs a Mamba-2 state-space mixer
+# beside its attention heads, both on the block's normed input, joined
+# before the one residual add; muP multipliers on every path
+# (models/reference/falcon_h1_ref.py has the equations, ops/ssm.py the
+# served forms) ---
+def _falcon_h1(name, **kw):
+    return ModelConfig(
+        name=name, family="falcon_h1", norm_type="rmsnorm", norm_eps=1e-5,
+        activation="silu", gated_mlp=True, position_embedding="rope",
+        attn_bias=False, mlp_bias=False, tie_word_embeddings=False, **kw)
+
+
+register(_falcon_h1(
+    "falcon-h1-34b", vocab_size=261120, hidden_size=5120,
+    intermediate_size=21504, num_layers=72, num_heads=20, num_kv_heads=4,
+    head_dim=128, max_position_embeddings=262144, rope_theta=1e11,
+    embed_scale=5.656854249492381, logit_scale=0.0078125,
+    ssm=SSMConfig(
+        d_ssm=4096, n_heads=32, d_head=128, d_state=256, n_groups=2,
+        d_conv=4, chunk_size=128, conv_bias=True,
+        in_multiplier=0.25, out_multiplier=0.08838834764831845,
+        multipliers=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+                     0.3535533905932738),
+        attn_in_multiplier=1.0, attn_out_multiplier=0.0375,
+        key_multiplier=0.011048543456039804,
+        mlp_multipliers=(0.1767766952966369, 0.011160714285714284))))
+
 # --- Tiny configs for tests/dryrun (not real checkpoints) ---
 register(ModelConfig(
     name="tiny-gpt2", family="gpt2", vocab_size=256, hidden_size=64,
@@ -359,3 +387,17 @@ register(_ouro(
     "tiny-ouro", vocab_size=256, hidden_size=64, intermediate_size=128,
     num_layers=3, loop_steps=3, num_heads=4, num_kv_heads=4, head_dim=16,
     max_position_embeddings=256))
+
+register(_falcon_h1(
+    # falcon-h1's switches at toy widths: head_dim != hidden / heads, 2
+    # groups, a scan chunk of 8, every multiplier away from 1
+    "tiny-falcon-h1", vocab_size=256, hidden_size=64, intermediate_size=128,
+    num_layers=2, num_heads=4, num_kv_heads=2, head_dim=24,
+    max_position_embeddings=256, rope_theta=1e6, embed_scale=2.5,
+    logit_scale=0.4,
+    ssm=SSMConfig(
+        d_ssm=64, n_heads=4, d_head=16, d_state=16, n_groups=2, d_conv=4,
+        chunk_size=8, conv_bias=True, in_multiplier=1.8,
+        out_multiplier=0.7, multipliers=(0.7, 1.6, 0.5, 1.5, 2.5),
+        attn_in_multiplier=1.1, attn_out_multiplier=0.5,
+        key_multiplier=0.6, mlp_multipliers=(0.7, 0.45))))

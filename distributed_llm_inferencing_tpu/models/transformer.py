@@ -128,14 +128,19 @@ def _lora_apply(y, x, lp, name, lora_ids):
 @jax.named_scope("mlp")
 def _mlp(x, lp, cfg: ModelConfig, lora_ids=None):
     if cfg.gated_mlp:
-        h = _act(_lora_apply(_linear(x, lp["gate"]), x, lp, "gate",
-                             lora_ids), cfg.activation) \
+        gate = _lora_apply(_linear(x, lp["gate"]), x, lp, "gate", lora_ids)
+        if cfg.ssm is not None:   # falcon-h1: mlp_multipliers[0]
+            gate = gate * jnp.asarray(cfg.ssm.mlp_multipliers[0], gate.dtype)
+        h = _act(gate, cfg.activation) \
             * _lora_apply(_linear(x, lp["up"]), x, lp, "up", lora_ids)
     else:
         h = _act(_lora_apply(_linear(x, lp["up"]), x, lp, "up", lora_ids),
                  cfg.activation)
     y = _linear(h, lp["down"], row_sharded=cfg.tp_row_sharded)
-    return _lora_apply(y, h, lp, "down", lora_ids)
+    y = _lora_apply(y, h, lp, "down", lora_ids)
+    if cfg.ssm is not None:       # ... and mlp_multipliers[1]
+        y = y * jnp.asarray(cfg.ssm.mlp_multipliers[1], y.dtype)
+    return y
 
 
 @jax.named_scope("moe_route")
@@ -774,7 +779,7 @@ def _attn_gate(attn_flat, h, lp, cfg: ModelConfig):
 
 def _block_body(x, lp, cfg: ModelConfig, q_positions, attend_write,
                 mla_latent_attend=None, fused_q_attend=None,
-                lora_ids=None, valid=None, moe_stats=False):
+                lora_ids=None, valid=None, moe_stats=False, ssm_mix=None):
     """One transformer block: norm → QKV (+RoPE) → attend → norm → MLP/MoE.
 
     The single definition of the block structure, shared by the dense path
@@ -800,11 +805,25 @@ def _block_body(x, lp, cfg: ModelConfig, q_positions, attend_write,
     ``valid`` and ``moe_stats`` go to _block_tail: which tokens are real
     (for the expert dispatch), and whether the layer's MOE_STATS vector
     rides out behind ``cache_out``.
+
+    ``ssm_mix(h, lp) -> (out [B,s,D], state_out)`` (cfg.ssm, Falcon-H1):
+    the block's Mamba-2 mixer (ops/ssm.py) reads the same normed input as
+    the attention heads, its output joins theirs ahead of the one
+    residual add (each under its multiplier), and ``state_out`` -- the
+    caller's new recurrent state and conv window, in whatever form its
+    regime keeps them -- rides out as the last elements of ``cache_out``.
     """
     tail = dict(valid=valid, moe_stats=moe_stats)
     B, s, _ = x.shape
     h = x if (cfg.post_norm or cfg.sublayer_postnorm_only) else norm(
         x, lp["attn_norm"], cfg.norm_type, cfg.norm_eps)
+    if cfg.ssm is not None:
+        mixed, state_out = ssm_mix(h, lp)
+        tail["ssm"] = (mixed, tuple(state_out))
+        # attention_in_multiplier on what q, k and v project
+        h_attn = h * jnp.asarray(cfg.ssm.attn_in_multiplier, h.dtype)
+    else:
+        h_attn = h
     if mla_latent_attend is not None:
         # latent formulation (cfg.mla_latent_cache): the whole attention
         # — projections, cache or pool, absorbed decode — runs inside
@@ -836,12 +855,16 @@ def _block_body(x, lp, cfg: ModelConfig, q_positions, attend_write,
     else:
         # LoRA deltas on the flat projection outputs (models/lora.py
         # rejects MLA/MoE bases, so the arms above never carry a pack)
-        q = _lora_apply(_linear(h, lp["q"]), h, lp, "q", lora_ids) \
-            .reshape(B, s, cfg.num_heads, cfg.head_dim)
-        k = _lora_apply(_linear(h, lp["k"]), h, lp, "k", lora_ids) \
-            .reshape(B, s, cfg.num_kv_heads, cfg.head_dim)
-        v = _lora_apply(_linear(h, lp["v"]), h, lp, "v", lora_ids) \
-            .reshape(B, s, cfg.num_kv_heads, cfg.head_dim)
+        q = _lora_apply(_linear(h_attn, lp["q"]), h_attn, lp, "q",
+                        lora_ids).reshape(B, s, cfg.num_heads, cfg.head_dim)
+        k = _lora_apply(_linear(h_attn, lp["k"]), h_attn, lp, "k",
+                        lora_ids).reshape(B, s, cfg.num_kv_heads,
+                                          cfg.head_dim)
+        v = _lora_apply(_linear(h_attn, lp["v"]), h_attn, lp, "v",
+                        lora_ids).reshape(B, s, cfg.num_kv_heads,
+                                          cfg.head_dim)
+        if cfg.ssm is not None:   # key_multiplier, ahead of the rotation
+            k = k * jnp.asarray(cfg.ssm.key_multiplier, k.dtype)
 
         if cfg.qkv_clip is not None:   # dbrx clip_qkv activation clamp
             q = jnp.clip(q, -cfg.qkv_clip, cfg.qkv_clip)
@@ -888,13 +911,21 @@ def _block_body(x, lp, cfg: ModelConfig, q_positions, attend_write,
 
 
 def _block_tail(x, h, attn, cache_out, lp, cfg: ModelConfig, lora_ids=None,
-                valid=None, moe_stats=False):
+                valid=None, moe_stats=False, ssm=None):
     """Post-attention half of the block: residual topology + MLP/MoE
     (shared by the materialized and MLA-latent attention dispatches).
     ``valid`` [B,s] marks the real tokens for the expert dispatch
     (_moe); with ``moe_stats`` the layer's MOE_STATS vector (zeros for a
-    dense layer) rides out as one more element of ``cache_out``."""
+    dense layer) rides out as one more element of ``cache_out``.
+    ``ssm`` (cfg.ssm): the mixer's output, which joins the attention
+    output here, and its new state, which goes out behind ``cache_out``
+    (ahead of the stats)."""
     stats = jnp.zeros((len(MOE_STATS),), jnp.int32)
+    if ssm is not None:
+        mixed, state_out = ssm
+        attn = attn * jnp.asarray(cfg.ssm.attn_out_multiplier,
+                                  attn.dtype) + mixed
+        cache_out = tuple(cache_out) + state_out
 
     def mlp_or_moe(h_in):
         nonlocal stats
@@ -944,7 +975,7 @@ def _block_tail(x, h, attn, cache_out, lp, cfg: ModelConfig, lora_ids=None,
 
 def _block(x, lp, cache_k, cache_v, *, cfg: ModelConfig, q_positions,
            write_starts, new_lengths, is_prefill, backend, mesh=None,
-           cache_ks=None, cache_vs=None):
+           cache_ks=None, cache_vs=None, ssm_state=None):
     """One transformer block over the dense cache.
 
     x: [B,s,D]; cache_k/v: [B,S,Hkv,hd] (this layer's slice);
@@ -955,8 +986,22 @@ def _block(x, lp, cache_k, cache_v, *, cfg: ModelConfig, q_positions,
     K/V block directly — O(s^2) instead of O(s * max_seq) over the mostly
     empty cache — while decode attends the cache (dequantized at read when
     ``cache_ks``/``cache_vs`` scales are present, ops/kvcache.py).
+
+    ``ssm_state`` (cfg.ssm): this layer's (recurrent state [B,H,P,N],
+    conv window) as they stood before the block's first token; the new
+    ones come back last. Positions at or past ``new_lengths`` (a
+    right-padded prompt's) advance neither.
     """
     quantized = cache_ks is not None
+    ssm_mix = None
+    if cfg.ssm is not None:
+        from distributed_llm_inferencing_tpu.ops import ssm
+
+        def ssm_mix(h, lp):
+            out, st, cw = ssm.mix_tokens(
+                h, lp, cfg, *ssm_state,
+                q_positions < new_lengths[:, None], _linear)
+            return out, (st, cw)
     if cfg.mla_latent_cache:
         # latent-layout cache: attention runs entirely inside the
         # absorbed-formulation callback (engine enables this only on
@@ -1024,7 +1069,8 @@ def _block(x, lp, cache_k, cache_v, *, cfg: ModelConfig, q_positions,
                                  sinks=_sinks(cfg, lp))
         return attn, cache_out
 
-    x, cache_out = _block_body(x, lp, cfg, q_positions, attend_write)
+    x, cache_out = _block_body(x, lp, cfg, q_positions, attend_write,
+                               ssm_mix=ssm_mix)
     return (x,) + cache_out
 
 
@@ -1057,24 +1103,30 @@ def forward(
     # one body serves both cache layouts: scale planes ride the scan xs
     # only when the cache is quantized. (The unrolled-list and
     # dense-prefix segment dispatch live in scan_layer_stack.)
+    # ... and a model with state layers (cfg.ssm) carries each layer's
+    # recurrent state and conv window the same way, last
+    names = ("k", "v") + (("k_scale", "v_scale") if cache.quantized else ()) \
+        + (("ssm", "conv") if cfg.ssm is not None else ())
+
     def make_body(seg_cfg):
         def body(x, layer_in):
-            lp, ck, cv, *scales = layer_in
+            lp, planes = layer_in[0], dict(zip(names, layer_in[1:]))
             out = _block(
-                x, lp, ck, cv, cfg=seg_cfg, q_positions=q_positions,
+                x, lp, planes["k"], planes["v"], cfg=seg_cfg,
+                q_positions=q_positions,
                 write_starts=write_starts, new_lengths=new_lengths,
                 is_prefill=is_prefill, backend=backend, mesh=mesh,
-                cache_ks=scales[0] if scales else None,
-                cache_vs=scales[1] if scales else None)
+                cache_ks=planes.get("k_scale"),
+                cache_vs=planes.get("v_scale"),
+                ssm_state=((planes["ssm"], planes["conv"])
+                           if "ssm" in planes else None))
             return out[0], tuple(out[1:])
         return body
 
-    cache_xs = (cache.k, cache.v) + (
-        (cache.k_scale, cache.v_scale) if cache.quantized else ())
+    cache_xs = tuple(getattr(cache, n) for n in names)
     x, cache_out = loop_layer_stack(make_body, x, params, cfg, cache_xs)
     logits = unembed(params, cfg, x)
-    planes = dict(zip(("k", "v", "k_scale", "v_scale"), cache_out))
-    return logits, KVCache(lengths=new_lengths, **planes)
+    return logits, KVCache(lengths=new_lengths, **dict(zip(names, cache_out)))
 
 
 def prefill(params, cfg: ModelConfig, tokens, lengths, cache: KVCache,
@@ -1131,6 +1183,7 @@ def paged_decode_step(params, cfg: ModelConfig, tokens, paged,
     from distributed_llm_inferencing_tpu.ops.paged_kvcache import (
         PagedKVCache, paged_attend_decode, write_token)
     from distributed_llm_inferencing_tpu.ops.pallas import fused_decode
+    _no_state_layers(cfg, "paged_decode_step")
     r = tokens.shape[0]
     backend = _cfg_backend(cfg)
     q_pos = context_lens[:, None]                       # [R, 1]
@@ -1239,6 +1292,17 @@ def paged_decode_step(params, cfg: ModelConfig, tokens, paged,
                                     paged.planes())
     logits = unembed(params, cfg, x)[:, 0]              # [R, V]
     return logits, PagedKVCache(*cache_out)
+
+
+def _no_state_layers(cfg: ModelConfig, what: str):
+    """The paths that carry no recurrent state refuse a model that has
+    one (cfg.ssm) by name; the side-buffer decode chunk and the wave
+    admission carry it."""
+    if cfg.ssm is not None:
+        raise NotImplementedError(
+            f"{cfg.name}: {what} carries no state-space state (cfg.ssm); "
+            "paged_prefill_tail and paged_decode_chunk's side-buffer "
+            "form do")
 
 
 # Cap for materializing the whole chunk's pool gather [L, R, P, Hkv, hd]
@@ -1415,6 +1479,23 @@ def _pool_kernel(params, cfg: ModelConfig, paged):
     return cfg.pool_kernel
 
 
+def _ssm_kernel(cfg: ModelConfig, paged):
+    """Which form a decode chunk's one-step state update takes:
+    ``cfg.pool_kernel`` (the batcher's pin: "pallas" only in a
+    one-device TPU program) where ops/pallas/ssm_step.py takes the state
+    plane's shape (float32, a head's [d_head, d_state] in whole tiles:
+    Falcon-H1-34B's 128 x 256), else None: the jax.numpy form
+    (ops/ssm.mix_step)."""
+    if not cfg.pool_kernel.startswith("pallas"):
+        return None
+    from distributed_llm_inferencing_tpu.ops.pallas import ssm_step
+    c = cfg.ssm
+    if not ssm_step.supported(c.n_heads, c.n_groups, c.d_head, c.d_state,
+                              paged.ssm.dtype):
+        return None
+    return cfg.pool_kernel
+
+
 def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
                        block_tables, context_lens, seeds, steps0, temps,
                        tks, tps, ds, budget, eos_ids, dummy_block: int,
@@ -1565,6 +1646,15 @@ def decode_chunk_with_logits(params, cfg: ModelConfig, k: int, tokens, paged,
     side_pos = cl0[:, None] + jnp.arange(k, dtype=jnp.int32)[None, :]
     side0 = (jnp.zeros((L, r, k, cfg.cache_kv_heads, cfg.cache_head_dim),
                        dt),) * n_planes
+    if cfg.ssm is not None:
+        # state layers: the per-slot state and conv planes ride the
+        # carry behind the side buffers, whole, and each layer of each
+        # pass reads its R rows and writes them back in place (slot r is
+        # row r; the dummy row behind them is the admit programs')
+        from distributed_llm_inferencing_tpu.ops import ssm
+        assert paged.ssm.shape[1] == r + 1, (paged.ssm.shape, r)
+        side0 = side0 + (paged.ssm, paged.conv)
+        ssm_kernel = _ssm_kernel(cfg, paged)
 
     # Pool K/V is loop-invariant: gather it ONCE for the whole chunk when
     # the materialization is modest; at long contexts fall back to a
@@ -1639,6 +1729,17 @@ def decode_chunk_with_logits(params, cfg: ModelConfig, k: int, tokens, paged,
                     return (x2, out[:-1]), out[-1:]
 
                 tail = dict(valid=alive[:, None], moe_stats=True)
+                if cfg.ssm is not None:
+                    def ssm_mix(h, lp):
+                        stp, cwp = sd[n_planes:]
+                        cw = jax.lax.dynamic_slice(
+                            cwp, (li, 0, 0), (1, r) + cwp.shape[2:])[0]
+                        out, stp, cw = ssm.mix_step(
+                            h, lp, seg_cfg, stp, li, cw, alive, _linear,
+                            kernel=ssm_kernel)
+                        return out, (stp, jax.lax.dynamic_update_slice(
+                            cwp, cw[None], (li, 0, 0)))
+                    tail["ssm_mix"] = ssm_mix
                 if latent:
                     def mla_latent_attend(h, qp):
                         sd2, rows = _write_side(
@@ -1654,7 +1755,8 @@ def decode_chunk_with_logits(params, cfg: ModelConfig, k: int, tokens, paged,
                         mla_latent_attend=mla_latent_attend, **tail))
 
                 def attend_write(q, kh, vh):
-                    sd2, rows = _write_side(sd, (kh, vh), t, dt, li)
+                    sd2, rows = _write_side(sd[:n_planes], (kh, vh), t, dt,
+                                            li)
                     if kernel:
                         return attend_kernel(q, rows), sd2
                     return attend_side(
@@ -1702,8 +1804,10 @@ def decode_chunk_with_logits(params, cfg: ModelConfig, k: int, tokens, paged,
             k8, ks = quant_kv(side[0])
             v8, vs = quant_kv(side[1])
             side = (k8, v8, ks, vs)
+        if cfg.ssm is not None:   # the state planes, as the passes left them
+            paged = paged._replace(ssm=side[n_planes], conv=side[n_planes + 1])
         return (toks, emits, moe, pool_positions, window_positions,
-                PagedKVCache(*(
+                paged.with_planes(tuple(
                     write_rows(plane, jnp.swapaxes(sd, 1, 2), blk, off)
                     for plane, sd in zip(paged.planes(), side))),
                 logits)
@@ -1719,6 +1823,7 @@ def _paged_decode_chunk_stepwise(params, cfg: ModelConfig, k: int, tokens,
     used when an explicit pallas backend is requested so the paged kernel
     actually runs."""
     from distributed_llm_inferencing_tpu.ops.sampling import sample_batch
+    _no_state_layers(cfg, "the stepwise decode chunk")
 
     def body(carry, t):
         cur, paged, cl, alive = carry
@@ -1818,6 +1923,8 @@ def paged_speculative_chunk(params, cfg: ModelConfig, k: int, gamma: int,
         raise ValueError(
             f"{cfg.name}: paged_speculative_chunk runs the stack once a "
             "verify pass; a looped model's steps are not carried through it")
+    _no_state_layers(cfg, "paged_speculative_chunk (a rejected draft "
+                     "would need its state rolled back)")
     r = tokens.shape[0]
     L = cfg.num_layers
     bs = paged.block_size
@@ -1975,7 +2082,7 @@ def paged_speculative_chunk(params, cfg: ModelConfig, k: int, gamma: int,
 
 def paged_prefill_tail(params, cfg: ModelConfig, tokens, tail_len,
                        tail_blocks, prefix_blocks, prefix_len, paged,
-                       lora_ids=None):
+                       lora_ids=None, slots=None):
     """Prefill a WAVE of prompt tails into paged blocks, each attending its
     own cached prefix.
 
@@ -2004,6 +2111,16 @@ def paged_prefill_tail(params, cfg: ModelConfig, tokens, tail_len,
     unbatched [T // bs] accepted when B == 1);
     prefix_blocks: [B, PB] (dummy-padded); prefix_len: [B].
     Returns (last-token logits [B, V] f32, new paged).
+
+    A model with state layers (cfg.ssm) takes ``slots`` [B]: the serving
+    slot whose state row each wave row continues (the dummy row, the
+    planes' last, for a padding row). A row whose ``prefix_len`` is 0
+    brings a request's first position and starts from a zero state and
+    window, whatever the slot held; a later chunk of a chunked prompt
+    goes on from what its slot holds. Positions past ``tail_len`` advance
+    neither (ops/ssm.mix_tokens). The state planes ride the layer
+    stack's carry: a layer reads its B rows by (layer, slot) and writes
+    them back in place, so no [L, B, ...] stack of states is ever held.
     """
     from distributed_llm_inferencing_tpu.ops.paged_kvcache import (
         PagedKVCache, paged_attend_prefix, write_blocks)
@@ -2018,9 +2135,30 @@ def paged_prefill_tail(params, cfg: ModelConfig, tokens, tail_len,
     tail_valid = jnp.arange(t, dtype=jnp.int32)[None, :] < tail_len[:, None]
     x = embed(params, cfg, tokens, q_pos)
 
+    has_ssm = cfg.ssm is not None
+    if has_ssm:
+        from distributed_llm_inferencing_tpu.ops import ssm
+        fresh = prefix_len == 0
+
     def make_body(seg_cfg, paged):       # its layers read this pool
         def body(x, layer_in):
             lp, li = layer_in
+            ssm_mix = None
+            if has_ssm:
+                x, stp, cwp = x
+
+                def ssm_mix(h, lp):
+                    st = jnp.where(fresh[:, None, None, None], 0.0,
+                                   stp[li, slots])
+                    cw = jnp.where(fresh[:, None], 0, cwp[li, slots])
+                    out, st, cw = ssm.mix_tokens(h, lp, seg_cfg, st, cw,
+                                                 tail_valid, _linear)
+                    return out, (stp.at[li, slots].set(st),
+                                 cwp.at[li, slots].set(cw))
+
+                def carry_state(out):
+                    x2, cache_out = out
+                    return (x2,) + tuple(cache_out[-2:]), cache_out[:-2]
 
             if seg_cfg.mla_latent_cache:
                 # the pool takes the tail's latent rows and nothing else;
@@ -2066,10 +2204,14 @@ def paged_prefill_tail(params, cfg: ModelConfig, tokens, tail_len,
                     v8, vs = quant_kv(v)
                 return attn, (k8, v8, ks, vs)
 
-            return _block_body(x, lp, seg_cfg, q_pos, attend_write,
-                               lora_ids=lora_ids, valid=tail_valid)
+            out = _block_body(x, lp, seg_cfg, q_pos, attend_write,
+                              lora_ids=lora_ids, valid=tail_valid,
+                              ssm_mix=ssm_mix)
+            return carry_state(out) if has_ssm else out
         return body
 
+    if has_ssm:
+        x = (x, paged.ssm, paged.conv)
     # ONE write a plane of every layer's tail rows, whole blocks, into
     # the pool where it lies. A looped model writes after each of its
     # steps, that step's planes alone (its rows for all steps at once
@@ -2082,8 +2224,11 @@ def paged_prefill_tail(params, cfg: ModelConfig, tokens, tail_len,
         x, tails = scan_layer_stack(
             functools.partial(make_body, paged=paged), x, params, cfg, xs_u)
         x = loop_pass_end(params, cfg, u, x)
+        if has_ssm:
+            x, stp, cwp = x
+            paged = paged._replace(ssm=stp, conv=cwp)
         with jax.named_scope("kv_write"):
-            paged = PagedKVCache(*(
+            paged = paged.with_planes(tuple(
                 write_blocks(plane, rows, tail_blocks,
                              None if cfg.loop_steps == 1 else u * L)
                 for plane, rows in zip(paged.planes(), tails)))

@@ -39,6 +39,11 @@ class KVCache(NamedTuple):
     lengths: jax.Array  # [B] int32 — filled slots (same for all layers)
     k_scale: Optional[jax.Array] = None   # [L, B, S, Hkv] f32 (int8 mode)
     v_scale: Optional[jax.Array] = None
+    # a model with state layers (cfg.ssm, ops/ssm.py): each sequence's
+    # recurrent state [L, B, H, P, N] float32 and conv window
+    # [L, B, (d_conv - 1) * conv_dim], as they stand after `lengths`
+    ssm: Optional[jax.Array] = None
+    conv: Optional[jax.Array] = None
 
     @property
     def max_seq(self) -> int:
@@ -91,11 +96,18 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int,
             v_scale=jnp.zeros(shape[:-1], jnp.float32))
     if cfg.kv_quant is not None:
         raise ValueError(f"unknown kv_quant mode {cfg.kv_quant!r}")
-    return KVCache(
+    cache = KVCache(
         k=jnp.zeros(shape, dtype),
         v=jnp.zeros(vshape, dtype),
         lengths=jnp.zeros((batch,), jnp.int32),
     )
+    if cfg.ssm is not None:
+        c = cfg.ssm
+        cache = cache._replace(
+            ssm=jnp.zeros((cfg.num_layers, batch, c.n_heads, c.d_head,
+                           c.d_state), jnp.float32),
+            conv=jnp.zeros((cfg.num_layers, batch, c.conv_elems), dtype))
+    return cache
 
 
 def write_block(cache_layer, new, starts):

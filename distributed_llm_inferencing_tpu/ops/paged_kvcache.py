@@ -23,6 +23,20 @@ vLLM/PagedAttention:
   invariant is position p of a slot's sequence lives in
   ``block_tables[r, p // bs]`` at offset ``p % bs``.
 
+- ``ssm``/``conv``: a second kind of cache in the same (donated) tree,
+  for a model with state layers (cfg.ssm, ops/ssm.py): a Mamba-2
+  mixer's recurrent state [L, R + 1, H, P, N] float32 and the last
+  d_conv - 1 inputs of its convolution [L, R + 1, (d_conv - 1) *
+  conv_dim] (stored flat: a minor axis of 3 would be padded to a tile's
+  128 lanes), indexed by serving *slot*, not by block: a fixed size a
+  request, overwritten on every token, with nothing to share or to cut
+  at a block boundary. Row R is the dummy row, which padded wave rows
+  write as dead K and V rows write the dummy block. A slot's rows are
+  zeroed by the admission program that brings a request's first
+  position into it; the radix cache, the host arena and the wire move
+  blocks only, so the batcher matches no prefix for such a model
+  (runtime/batcher.py).
+
 Attention over the paged cache gathers each slot's blocks back into a
 contiguous [R, MB*bs, ...] view, which ``attend`` then reads once as it
 is (grouped-query form, no copy: ops/attention.py). The gather writes that
@@ -86,6 +100,10 @@ class PagedKVCache(NamedTuple):
     # (ops/kvcache.py quant_kv scheme): [L, NB, bs, Hkv] f32
     k_scale: Optional[jax.Array] = None
     v_scale: Optional[jax.Array] = None
+    # state layers' per-slot planes (cfg.ssm), no part of planes():
+    # [L, R + 1, H, P, N] float32 and [L, R + 1, (d_conv - 1) * conv_dim]
+    ssm: Optional[jax.Array] = None
+    conv: Optional[jax.Array] = None
 
     @property
     def num_blocks(self) -> int:
@@ -100,9 +118,16 @@ class PagedKVCache(NamedTuple):
         return self.k_scale is not None
 
     def planes(self) -> tuple:
-        """The arrays the pool has, in field order (what a layer scan
-        carries; ``PagedKVCache(*planes)`` puts them back)."""
-        return tuple(p for p in self if p is not None)
+        """The block pool's arrays, in field order (what a layer scan
+        carries; ``with_planes`` puts them back). The per-slot state
+        planes are not among them."""
+        return tuple(p for p in self[:4] if p is not None)
+
+    def with_planes(self, planes) -> "PagedKVCache":
+        """This cache with its block pool's arrays replaced (in
+        planes()'s order); the state planes stay as they are."""
+        names = [n for n in self._fields[:4] if getattr(self, n) is not None]
+        return self._replace(**dict(zip(names, planes)))
 
     @property
     def bytes_per_token(self) -> int:
@@ -110,10 +135,32 @@ class PagedKVCache(NamedTuple):
         return sum(p.size * p.dtype.itemsize for p in self.planes()) \
             // (self.num_blocks * self.block_size)
 
+    @property
+    def state_bytes_per_slot(self) -> int:
+        """Bytes of recurrent state and conv window one serving slot
+        holds over all layers (0 for a model without state layers)."""
+        if self.ssm is None:
+            return 0
+        return sum(p.size * p.dtype.itemsize
+                   for p in (self.ssm, self.conv)) // self.ssm.shape[1]
+
 
 def init_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
-                     dtype=None) -> PagedKVCache:
+                     dtype=None, slots: int = 0) -> PagedKVCache:
+    """The block pool and, for a model with state layers (cfg.ssm), a
+    state row and a conv row for each of ``slots`` serving slots and one
+    dummy row behind them."""
     dtype = dtype or jnp.dtype(cfg.dtype)
+    if cfg.ssm is not None:
+        if cfg.kv_quant is not None or cfg.mla_latent_cache:
+            raise ValueError("state layers keep an unquantized K and V pool")
+        c = cfg.ssm
+        pool = init_paged_cache(cfg.replace(ssm=None), num_blocks,
+                                block_size, dtype)
+        return pool._replace(
+            ssm=jnp.zeros((cfg.num_layers, slots + 1, c.n_heads, c.d_head,
+                           c.d_state), jnp.float32),
+            conv=jnp.zeros((cfg.num_layers, slots + 1, c.conv_elems), dtype))
     shape = (cfg.cache_planes, num_blocks, block_size, cfg.cache_kv_heads,
              cfg.cache_head_dim)
     if cfg.mla_latent_cache:   # config.py refuses kv_quant with it

@@ -17,7 +17,7 @@ plus the fused decode kernels:
 - ``fused_decode.fused_decode_step``: dequant-GEMV -> RoPE -> paged
   flash attention chained in ONE pallas_call (``DLI_FUSED_DECODE``)
 
-and the two kernels the benchmark's cells run, each chosen by the code
+and the three kernels the benchmark's cells run, each chosen by the code
 from shapes it can see in a one-device TPU program's decode chunks
 (PERF.md section 3):
 
@@ -33,6 +33,11 @@ from shapes it can see in a one-device TPU program's decode chunks
   _pool_ladder's rung keeps every other pool; the batcher pins
   ``cfg.pool_kernel``, models/transformer.py:_pool_kernel decides);
   ``paged_flash_decode`` is the stepwise path's entry on it
+- ``ssm_step.ssm_step`` (falcon-h1-34b): a Mamba-2 mixer's one-step
+  update of the per-slot state plane, a (slot, group) tile read and
+  written once through an aliased output (the jax.numpy form, which
+  reads a state twice, keeps every other shape; the same pin,
+  models/transformer.py:_ssm_kernel decides)
 
 All run in interpreter mode on CPU for tests (tests/test_pallas_attention.py,
 tests/test_pallas_parity.py, tests/test_grouped_matmul.py — the

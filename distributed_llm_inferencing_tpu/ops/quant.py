@@ -50,7 +50,9 @@ _LINEAR_LEAVES = ("q", "k", "v", "o", "attn_gate", "up", "gate", "down",
                   # experts (the q_a/kv_a latents are matmul weights like
                   # any other; their mid-stack norms stay float)
                   "q_a", "q_b", "kv_a", "kv_b_k", "kv_b_v",
-                  "shared_gate", "shared_up", "shared_down")
+                  "shared_gate", "shared_up", "shared_down",
+                  # a Mamba-2 mixer's two projections (ops/ssm.py)
+                  "in_proj", "out_proj")
 
 MODES = ("int8", "int4")
 

@@ -109,6 +109,18 @@ def param_specs(cfg: ModelConfig, spec: MeshSpec,
         }
         if cfg.attn_gate:   # multiplies the heads' output: sharded as q
             layers["attn_gate"] = lin(P(L, None, "tp"))
+    if cfg.ssm is not None:
+        # the Mamba-2 mixer's leaves, replicated: the batcher refuses a
+        # mesh of more than one device for a model with state layers
+        # (the state plane has no sharding rule yet, ROADMAP R-M)
+        layers["in_proj"] = lin(P(L, None, None))
+        layers["conv"] = {"w": P(L, None, None)}
+        if cfg.ssm.conv_bias:
+            layers["conv"]["b"] = P(L, None)
+        for name in ("dt_bias", "A_log", "D"):
+            layers[name] = P(L, None)
+        layers["ssm_norm"] = {"scale": P(L, None)}
+        layers["out_proj"] = lin(P(L, None, None))
     if cfg.post_block_norms:   # gemma2 sandwich norms
         layers["attn_post_norm"] = norm_p()
         layers["mlp_post_norm"] = norm_p()
@@ -221,6 +233,11 @@ def paged_cache_specs(cfg: ModelConfig, spec: MeshSpec):
     L = "pp" if spec.pp > 1 else None
     kv = P(L, None, None, kv_tp, None)
     scale = P(L, None, None, kv_tp) if cfg.kv_quant else None
+    if cfg.ssm is not None:
+        # the per-slot state planes, replicated (the batcher serves a
+        # model with state layers on one device only)
+        return PagedKVCache(k=kv, v=kv, ssm=P(None, None, None, None, None),
+                            conv=P(None, None, None))
     return PagedKVCache(k=kv, v=kv, k_scale=scale, v_scale=scale)
 
 

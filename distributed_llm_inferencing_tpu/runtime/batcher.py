@@ -85,6 +85,28 @@ PREFIX_BUCKETS = (0, 1, 2, 4, 8, 16, 32, 64, 128, 256, 512)  # blocks
 # widest wave 64 slots form over no cached prefix, 64 rows of a 512 tail
 # and the one dummy prefix block; over 512 blocks of 16 it leaves 2 rows.
 WAVE_SCORE_BUDGET = 64 * 512 * (512 + 16)
+# ... and, for a model with state layers, the bytes of per-token
+# transients one admission program may hold at its widest point
+# (_wave_token_budget: the MLP's gate, up and product rows, or the
+# chunked scan's projections and float32 rows): Falcon-H1-34B's 129 KB a
+# token make it 4,096 tokens a wave, 0.5 GB, where the score budget
+# alone would let 32,768 through (4.2 GB beside 12.9 GB of arguments).
+WAVE_TRANSIENT_BYTES = 640 * 1024 * 1024
+
+
+def _wave_token_budget(cfg: ModelConfig) -> float:
+    """Most tokens (rows x tail, as bucketed) one admission program may
+    carry, from the widest per-token transient of the MLP and of the
+    chunked scan. A model without state layers has no such bound: its
+    waves are cut by WAVE_SCORE_BUDGET alone, as they were."""
+    if cfg.ssm is None:
+        return float("inf")
+    c, item = cfg.ssm, jnp.dtype(cfg.dtype).itemsize
+    mlp = 3 * cfg.intermediate_size * item
+    scan = ((c.proj_dim + 2 * c.conv_dim) * item
+            + 4 * (c.conv_dim + 3 * c.d_ssm
+                   + 2 * c.chunk_size * c.n_heads))
+    return WAVE_TRANSIENT_BYTES // max(mlp, scan)
 
 
 @dataclasses.dataclass
@@ -141,6 +163,11 @@ class BatchRequest:
     # prompt is mostly radix-cached): skip re-popping it — and the
     # match_prefix + alloc churn that costs — until a slot frees
     _noslot_bounce: bool = False
+    # state layers (cfg.ssm): the slot a chunked prompt took at its
+    # first chunk and keeps between chunks, its state in that slot's row
+    # (None: holds none); its blocks so far are _blocks, its positions
+    # so far _prefill_counted
+    _held_slot: Optional[int] = None
     # Disaggregated prefill/decode (runtime/kvwire.py): where to pull
     # missing prefix KV from ({"url": peer base URL, "model": name} — the
     # master's kv_source dispatch hint), and whether to export this
@@ -415,6 +442,41 @@ class ContinuousBatcher:
                 raise ValueError(
                     f"{cfg.name}: a looped stack (loop_steps="
                     f"{cfg.loop_steps}) cannot take " + "; ".join(refused))
+        if cfg.ssm is not None:
+            # state layers (a Mamba-2 mixer a block, ops/ssm.py) keep a
+            # recurrent state and a conv window a serving slot beside the
+            # block pool (ops/paged_kvcache.py); what does not carry
+            # them is refused here by name, not served without a state
+            host_mb = kv_host_mb
+            if host_mb is None:
+                try:
+                    host_mb = float(os.environ.get("DLI_KV_HOST_MB", 0))
+                except ValueError:
+                    host_mb = 0
+            refused = [why for why, hit in (
+                ("speculative decoding (a rejected draft's state cannot "
+                 "be rolled back)", bool(speculative)),
+                ("pp > 1 or any mesh of more than one device "
+                 "(parallel/paged_pipeline.py and sharding.py have no "
+                 "rule for the state plane)",
+                 self.mesh_spec.num_devices > 1),
+                ("kv_quant (the state is float32 and is not quantized)",
+                 cfg.kv_quant is not None),
+                ("kv_host_mb > 0 / DLI_KV_HOST_MB (the host arena, "
+                 "kvwire fetches and migrate_out move K and V blocks, "
+                 "no state)", host_mb > 0),
+                ("a Pallas attention backend (attn_backend / "
+                 "DLI_ATTENTION: the stepwise chunk carries no state)",
+                 os.environ.get("DLI_ATTENTION", cfg.attn_backend)
+                 .startswith("pallas")),
+                ("DLI_FUSED_DECODE (the fused step runs in the stepwise "
+                 "chunk)", fused_decode.enabled()))
+                if hit]
+            if refused:
+                raise ValueError(
+                    f"{cfg.name}: state-space layers cannot take "
+                    + "; ".join(refused))
+            kv_host_mb = 0   # unset: no arena for this model
         self.cfg = cfg = cfg.replace(
             attn_backend=_backend(cfg, self.mesh_spec.num_devices),
             # int4 pallas routing hint (models/config.py): this GSPMD
@@ -521,6 +583,9 @@ class ContinuousBatcher:
         self._window_positions = 0   # ... and what a windowed layer read
         self._pool_kernel = None  # whether they read it by the kernel
         self._wave_cut = None   # (tail, prefix) group the bound last cut
+        self._wave_token_budget = _wave_token_budget(cfg)
+        # state layers: slots that chunked prompts hold between chunks
+        self._holds: set = set()
         # admission waves cut short by WAVE_SCORE_BUDGET
         self.metrics.inc("batcher_admit_waves_bounded", 0)
         # traversals of the layer stack by decode passes: loop_steps a
@@ -568,8 +633,16 @@ class ContinuousBatcher:
         # not read "0 free blocks" as exhaustion
         self.metrics.gauge("batcher_free_kv_blocks", self.pool.free_count())
         self.paged = jax.device_put(
-            init_paged_cache(cfg, num_blocks + 1, block_size),
+            init_paged_cache(cfg, num_blocks + 1, block_size, slots=slots),
             shd.named(self.mesh, shd.paged_cache_specs(cfg, self.mesh_spec)))
+        # state layers: what a slot holds beside its blocks (0 for a
+        # model without them), true prompt positions through the chunked
+        # scan, and live slots x decode passes through the one-step update
+        self._state_bytes_per_slot = self.paged.state_bytes_per_slot
+        self.metrics.gauge("batcher_ssm_state_bytes_per_slot",
+                           float(self._state_bytes_per_slot))
+        self.metrics.inc("batcher_ssm_scan_positions", 0)
+        self.metrics.inc("batcher_ssm_step_slot_passes", 0)
         # what one cached token takes of the pool, from the pool's own
         # shape (a latent pool: L x lane_width(rd + r) x 2 bytes)
         self.metrics.gauge("batcher_kv_bytes_per_token",
@@ -1123,6 +1196,10 @@ class ContinuousBatcher:
                 tb = ints[b * t:b * (t + nb)].reshape(b, nb)
                 pfb = ints[b * (t + nb):b * (t + nb + pb)].reshape(b, pb)
                 rest = ints[b * (t + nb + pb):]
+                kw = {}
+                if cfg.ssm is not None:
+                    # state layers: each row's serving slot rides last
+                    rest, kw["slots"] = rest[:-b], rest[-b:]
                 if use_lora:
                     tl, pfl, seeds, steps, tks, ds, aids = \
                         rest.reshape(7, b)
@@ -1139,7 +1216,7 @@ class ContinuousBatcher:
                 else:
                     last, paged = transformer.paged_prefill_tail(
                         p, cfg, toks, tl, tb, pfb, pfl, paged,
-                        lora_ids=aids)
+                        lora_ids=aids, **kw)
                 with jax.named_scope("sample"):
                     first = sample_batch(last, seeds, steps, temps, tks,
                                          tps, ds.astype(bool))
@@ -1306,7 +1383,8 @@ class ContinuousBatcher:
             np.asarray(a["steps"], np.int32),
             np.asarray(a["tks"], np.int32),
             np.asarray(a["ds"], np.int32)] + (
-            [np.asarray(a["aids"], np.int32)] if use_lora else []))
+            [np.asarray(a["aids"], np.int32)] if use_lora else []) + (
+            [np.asarray(a["slots"], np.int32)] if "slots" in a else []))
         floats = np.stack([np.asarray(a["temps"], np.float32),
                            np.asarray(a["tps"], np.float32)])
         fn = self._admit_jit(toks.shape[1], pfb.shape[1], b, use_lora)
@@ -2061,6 +2139,10 @@ class ContinuousBatcher:
         the scheduler never serviced the flag within ``timeout``."""
         if self.program_hook is not None:
             return None          # lockstep: host-side evict can't ride
+        if self.cfg.ssm is not None:
+            raise ValueError(
+                f"{self.cfg.name}: migrate_out exports K and V blocks; a "
+                "state-space layer's state is not among them")
         req._migrate_requested = True
         self._work.set()
         if not req.done.wait(timeout):
@@ -2189,7 +2271,17 @@ class ContinuousBatcher:
         n = len(prompt)
         # Leave >=1 token for the tail: prefill must produce the last
         # token's logits (a fully-cached prompt would have nothing to run).
-        prefix_blocks, cached = self.pool.match_prefix(prompt[:n - 1])
+        if self.cfg.ssm is None:
+            prefix_blocks, cached = self.pool.match_prefix(prompt[:n - 1])
+        elif req._held_slot is not None:
+            # a later chunk of a chunked prompt: its prefix is its own
+            # earlier chunks' blocks, its state its held slot's row
+            prefix_blocks, cached = list(req._blocks), req._prefill_counted
+        else:
+            # state layers: K and V blocks without the state at their
+            # end are of no use, and the radix cache holds no state, so
+            # no prefix is matched (and none inserted, _post_admit)
+            prefix_blocks, cached = [], 0
         if self.kvtier is not None and self.program_hook is None:
             # tier 2b: a disaggregated request pulls its missing prefix
             # blocks from the prefill peer, receive-overlapped — the
@@ -2220,18 +2312,36 @@ class ContinuousBatcher:
             t = self._bucket_tail(tail_len)      # may raise ValueError
             tail_alloc = self.pool.alloc(t // bs)
             if tail_alloc is None:
-                self.pool.release(prefix_blocks)
+                self._release_prefix(req, prefix_blocks)
                 return None
             pb = max(self._bucket_prefix(len(prefix_blocks)), 1)
         except ValueError:
             # refuse-the-request path: drop the references this prep took,
             # or repeated oversized requests pin radix blocks forever
-            self.pool.release(prefix_blocks)
+            self._release_prefix(req, prefix_blocks)
             self.pool.release(tail_alloc or [])
             raise
         return {"t": t, "pb": pb, "n": n, "cached": cached,
                 "tail_len": tail_len, "prompt": prompt, "partial": partial,
                 "prefix_blocks": prefix_blocks, "tail_alloc": tail_alloc}
+
+    def _release_prefix(self, req: BatchRequest, prefix_blocks):
+        """Give back the references a prep took on its prefix blocks. A
+        chunked prompt that holds a slot (state layers) took none: its
+        prefix is its own blocks, which go with the request."""
+        if req._held_slot is None:
+            self.pool.release(prefix_blocks)
+
+    def _drop_hold(self, req: BatchRequest):
+        """A chunked prompt that holds a slot between chunks (state
+        layers) gives up the slot and the blocks of its chunks so far:
+        it failed, was cancelled, or starts again from its first token."""
+        if req._held_slot is not None:
+            self._holds.discard(req._held_slot)
+            req._held_slot = None
+            self.pool.release(req._blocks)
+            req._blocks = []
+            req._prefill_counted = 0
 
     def _admit_wave(self):
         """Admit queued requests into free slots as bucketed waves: one
@@ -2261,7 +2371,8 @@ class ContinuousBatcher:
         self._wave_cut = None   # the (tail, prefix) group the bound cut
         while True:
             free = [i for i, a in enumerate(self.active)
-                    if a is None and i not in taken]
+                    if a is None and i not in taken
+                    and i not in self._holds]
             if not free:
                 # no decode slot — only worth popping if the head could
                 # chunk-admit (needs no slot); cheap length pre-filter,
@@ -2272,6 +2383,8 @@ class ContinuousBatcher:
                 if (head is None or cap == 0 or head._noslot_bounce
                         or len(head.prompt) + len(head.tokens) - 1 <= cap):
                     break
+                if self.cfg.ssm is not None and head._held_slot is None:
+                    break   # state layers: a first chunk takes a slot
             with self._lock:
                 req = self.queue.popleft() if self.queue else None
             if req is None:
@@ -2290,7 +2403,7 @@ class ContinuousBatcher:
                 continue
             finally:
                 self._admitting = None
-            if (prep is not None and wave
+            if (prep is not None and wave and self.cfg.ssm is None
                     and (self._shared_wave_blocks(wave, prep["prompt"])
                          * self.block_size > prep["cached"])):
                 # an earlier wave member is about to insert a longer shared
@@ -2307,7 +2420,7 @@ class ContinuousBatcher:
                 # this request FIRST next step
                 self._wave_cut = (prep["t"], prep["pb"])
                 self.metrics.inc("batcher_admit_waves_bounded")
-                self.pool.release(prep["prefix_blocks"])
+                self._release_prefix(req, prep["prefix_blocks"])
                 self.pool.release(prep["tail_alloc"])
                 self._requeue_front(req)
                 break
@@ -2328,7 +2441,14 @@ class ContinuousBatcher:
                     self._requeue_front(req)
                 break
             prep["req"] = req
-            if prep["partial"]:
+            if req._held_slot is not None:
+                # state layers: the slot its first chunk took
+                prep["slot"] = req._held_slot
+                wave.append(prep)
+                if prep["partial"]:
+                    break
+                continue
+            if prep["partial"] and self.cfg.ssm is None:
                 prep["slot"] = None
                 wave.append(prep)
                 break
@@ -2345,6 +2465,8 @@ class ContinuousBatcher:
             prep["slot"] = free[0]
             taken.add(free[0])
             wave.append(prep)
+            if prep["partial"]:   # state layers: a first chunk, slot taken
+                break
         return wave
 
     def _past_score_budget(self, wave: List[dict], prep: dict) -> bool:
@@ -2356,7 +2478,8 @@ class ContinuousBatcher:
         if rows == 1:
             return False
         b = self._wave_rows(rows)
-        return b * t * (pb * self.block_size + t) > WAVE_SCORE_BUDGET
+        return (b * t * (pb * self.block_size + t) > WAVE_SCORE_BUDGET
+                or b * t > self._wave_token_budget)
 
     def _wave_rows(self, members: int) -> int:
         """Rows of the admission program that carries ``members``."""
@@ -2409,6 +2532,8 @@ class ContinuousBatcher:
                    "tokens": tokens, "padded_tokens": b * t,
                    "active": active, "prefix_positions": hits,
                    "loop_steps": self.cfg.loop_steps,
+                   "ssm_state_bytes_per_slot":
+                       self._state_bytes_per_slot,
                    **self._gathered_prefix(b, pb),
                    "bounded": int(self._wave_cut == (t, pb))})
         with self.profiler.phase("admit_post"):
@@ -2475,6 +2600,12 @@ class ContinuousBatcher:
             # base-only wave compiles/runs the unaugmented program, and
             # lockstep followers replaying the args pick the same one
             admit_args["aids"] = aids.tolist()
+        if self.cfg.ssm is not None:
+            # each row's state row; padding rows write the dummy row
+            # behind the slots' (ops/paged_kvcache.py)
+            admit_args["slots"] = (
+                [m["slot"] for m in members]
+                + [self.slots] * (b - len(members)))
         return b, admit_args
 
     def _post_admit(self, m: dict, first: int):
@@ -2513,10 +2644,27 @@ class ContinuousBatcher:
         # register the prompt's full blocks in the radix cache
         n_full = n // bs
         skip = cached // bs
-        if n_full > skip:
+        if n_full > skip and self.cfg.ssm is None:
             self.pool.insert_prefix(m["prompt"][:n_full * bs],
                                     tail_real[:n_full - skip], skip)
+        self.metrics.inc("batcher_ssm_scan_positions",
+                         tail_len if self.cfg.ssm is not None else 0)
 
+        if m.get("partial") and self.cfg.ssm is not None:
+            # state layers: the chunk's K and V are of use only with the
+            # state at their end, which this slot's row now holds; the
+            # request keeps the slot and its blocks, and its next chunk
+            # goes on from both (_prep_admit)
+            req._blocks = prefix_blocks + tail_real
+            req._kv_peak = max(req._kv_peak, len(req._blocks))
+            req._held_slot = slot
+            self._holds.add(slot)
+            self._chunked_admissions += 1
+            if not req._cancelled:
+                self._requeue_front(req)
+            else:
+                self._fail_req(req, "cancelled")
+            return
         if m.get("partial"):
             # drop our references — the radix keeps the chunk's blocks
             # alive (refcount-0 leaves evict only under pool pressure,
@@ -2547,6 +2695,9 @@ class ContinuousBatcher:
                 self._fail_req(req, "cancelled")
             return
 
+        if req._held_slot is not None:   # the last chunk: the hold ends
+            self._holds.discard(slot)
+            req._held_slot = None
         req._blocks = prefix_blocks + tail_real
         req._kv_peak = max(req._kv_peak, len(req._blocks))
         self.block_tables[slot, :] = self._dummy
@@ -2582,6 +2733,7 @@ class ContinuousBatcher:
         (cancelled in queue, admission refusal, pool exhaustion, scheduler
         stop/error) — same metrics/trace accounting as a normal finish, so
         submitted always reconciles with completed+failed."""
+        self._drop_hold(req)
         req.error = req.error or error or "failed"
         req.finished_at = req.finished_at or clock.now()
         self._observe_finished(req)
@@ -2774,6 +2926,10 @@ class ContinuousBatcher:
         if req is not None:
             self.pool.release(req._blocks)
             req._blocks = []
+            if self.cfg.ssm is not None:
+                # nothing of a preempted request's state is kept: it is
+                # prefilled again from its first token
+                req._prefill_counted = 0
             req._preemptions += 1
             if req._preemptions > 5:
                 self._fail_req(req, "preempted repeatedly: KV pool too small")
@@ -3004,6 +3160,8 @@ class ContinuousBatcher:
             attrs={"chunk": self._step_count, "k": k, "slots": len(active),
                    "kv_bytes_per_token": self.paged.bytes_per_token,
                    "loop_steps": self.cfg.loop_steps,
+                   "ssm_state_bytes_per_slot":
+                       self._state_bytes_per_slot,
                    "pool_positions": self._pool_positions,
                    "window_positions": self._window_positions,
                    "pool_kernel": int(self.pool_kernel)})
@@ -3020,7 +3178,7 @@ class ContinuousBatcher:
         outputs (the speculative path's are [K, R, G+1] keeps-shaped:
         _emit_spec_outputs). Returns tokens emitted."""
         budget = decode_args["budget"]
-        emitted = 0
+        emitted = live_passes = 0
         with self.profiler.phase("emit"):
             for i in active:
                 req = self.active[i]
@@ -3035,8 +3193,11 @@ class ContinuousBatcher:
                 req._weight_passes += passes
                 self.context_lens[i] += cnt
                 hit_eos = cnt < int(budget[i])   # stopped pre-budget
+                live_passes += cnt + hit_eos   # the eos pass ran alive
                 if hit_eos or len(req.tokens) >= req.max_new_tokens:
                     self._finish_slot(i)
+        self.metrics.inc("batcher_ssm_step_slot_passes",
+                         live_passes if self.cfg.ssm is not None else 0)
         self._count_passes(decode_args, passes, emitted)
         return emitted
 

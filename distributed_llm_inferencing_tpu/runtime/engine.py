@@ -96,6 +96,16 @@ class InferenceEngine:
         self.metrics = metrics or Metrics()
         self.mesh_spec = mesh_spec or MeshSpec()
         self._n_micro = pipeline_microbatches
+        if cfg.ssm is not None:
+            # the engine's bucketed prefill, prefix reuse, speculation
+            # and sharded caches carry K and V alone; a model with state
+            # layers is served by the batcher (runtime/batcher.py), whose
+            # cache manager keeps a state row a slot
+            raise ValueError(
+                f"{cfg.name}: state-space layers (cfg.ssm) are served by "
+                "the continuous batcher (serving='batched'); the engine's "
+                "dense-cache prefill / decode_step carry no recurrent "
+                "state")
         validate_spec(self.mesh_spec, cfg)
         self.mesh = create_mesh(self.mesh_spec)
         # Pin the attention backend now that the program's device span is

@@ -7,6 +7,7 @@ described TPU — no chip, no arrays — before spending a chip call.
     JAX_PLATFORMS=cpu MODEL=trinity python scripts/compile_serving_programs.py 1
     JAX_PLATFORMS=cpu MODEL=mistral-cell python scripts/compile_serving_programs.py 1
     JAX_PLATFORMS=cpu MODEL=ouro python scripts/compile_serving_programs.py 1
+    JAX_PLATFORMS=cpu MODEL=falcon-h1 python scripts/compile_serving_programs.py 1
 
 For each tensor-parallel width given (default: 1 and 4) the real jitted
 programs of ``runtime/batcher.py`` are lowered for ``v5e:2x2`` at
@@ -18,7 +19,10 @@ slots, the latent pool of 10,240 blocks) or ``MODEL=trinity`` at its cell's
 (trinity-mini bf16, 5 layers, 64 slots, 12,288 blocks, contexts to 9216:
 the 2-row admit over a 512-block prefix and the decode chunks with the
 windowed read) or ``MODEL=ouro`` at its cell's (ouro-2.6b bf16 whole, 48
-layers run 4 times, 8 slots, 192 planes of 321 blocks), with shapes from
+layers run 4 times, 8 slots, 192 planes of 321 blocks) or
+``MODEL=falcon-h1`` at its cell's (falcon-h1-34b bf16, 6 layers, 64 slots,
+4096 blocks, the state planes of 65 rows: the widest admit waves the
+token bound lets through and the decode chunks), with shapes from
 ``jax.eval_shape`` and shardings from ``parallel/sharding.py``. Prints
 what ``compiled.memory_analysis()`` says each device must hold, the
 collectives in the program text, and every instruction that yields a
@@ -86,6 +90,12 @@ TARGETS = {
     "ouro": ("ouro-2.6b", None, 0, 8, 16, 320, 640,
              ((256, 0, 8), (256, 0, 4), (128, 0, 8), (128, 0, 1), (512, 0, 1),
               (16, 32, 8)), (8, 1)),
+    # benchmarks/chip/configs/falcon-h1-34b-l6.json: 6 layers, 64 slots
+    # and their state rows; the widest admit waves under the token bound
+    # (4,096 tokens as bucketed), the smallest, and the decode chunks
+    "falcon-h1": ("falcon-h1-34b", None, 6, 64, 16, 4096, 1024,
+                  ((128, 1, 32), (256, 1, 16), (512, 1, 8), (128, 1, 1)),
+                  (8, 1)),
 }
 TARGET = os.environ.get("MODEL", "mistral")
 (MODEL, QUANT, DEPTH, SLOTS, BLOCK, BLOCKS, MAX_SEQ, ADMIT,
@@ -234,7 +244,8 @@ def main(widths):
                         mesh, P(*s.sharding.spec[1:]))),
                 params["layers"]) for _ in range(n)]
         paged = described(
-            jax.eval_shape(lambda: init_paged_cache(cfg, BLOCKS + 1, BLOCK)),
+            jax.eval_shape(lambda: init_paged_cache(cfg, BLOCKS + 1, BLOCK,
+                                                    slots=SLOTS)),
             shd.paged_cache_specs(cfg, spec))
         replicated = NamedSharding(mesh, P())
 
@@ -249,7 +260,9 @@ def main(widths):
         mb = MAX_SEQ // BLOCK
         with mesh:
             for t, pb, wave in ADMIT:
-                n_ints = wave * (t + t // BLOCK + pb + 6)
+                # ... + a state row a wave row, for a model with state layers
+                n_ints = wave * (t + t // BLOCK + pb + 6
+                                 + (cfg.ssm is not None))
                 t0 = time.time()
                 report(f"{TARGET} tp={tp} admit tail={t} prefix_blocks={pb} "
                        f"wave={wave}",

@@ -49,7 +49,11 @@ import sys
 
 SCOPES = ("kv_gather", "attention", "attn_gate", "kv_write", "mlp",
           "moe_route", "moe_experts", "moe_combine", "mla_absorb",
-          "lm_head", "sample")
+          "lm_head", "sample",
+          # a state-space mixer's parts (ops/ssm.py): the chunked scan in
+          # admit programs, the one-step update in decode chunks
+          "ssm_in_proj", "ssm_conv", "ssm_scan", "ssm_step",
+          "ssm_gate_norm", "ssm_out_proj")
 # a layer's kind, named inside kv_gather / attention where a model mixes
 # windowed and full layers: reported as `attention/win`
 KINDS = ("win", "full")

@@ -126,6 +126,42 @@ def test_paged_flash_decode_compiles(compile_on_chip):
         ((SLOTS,), jnp.int32))
 
 
+def test_ssm_state_step_writes_the_plane_in_place(compile_on_chip):
+    """ops/pallas/ssm_step.py at falcon-h1-34b's cell: 6 layers, 64
+    slots and the dummy row, 32 heads of 128 x 256 float32 in 2 groups,
+    under a layer scan that carries the donated plane: the kernel
+    compiles, the 1.6 GB plane is aliased to the result and no copy of
+    it (nor of a layer of it) is made."""
+    from distributed_llm_inferencing_tpu.ops.pallas import ssm_step
+    layers, slots, heads, p, n, g = 6, 64, 32, 128, 256, 2
+    assert ssm_step.supported(heads, g, p, n, jnp.float32)
+    assert not ssm_step.supported(4, 2, 16, 16, jnp.float32)   # the toy's
+
+    def passes(plane, decay, dtx, b, c):
+        def layer(carry, li):
+            plane, acc = carry
+            plane, y = ssm_step.ssm_step(plane, li, decay, dtx, b, c)
+            return (plane, acc + y), None
+        return jax.lax.scan(
+            layer, (plane, jnp.zeros((slots, heads, p), jnp.float32)),
+            jnp.arange(layers))[0]
+    f32 = jnp.float32
+    compiled = compile_on_chip(
+        passes, ((layers, slots + 1, heads, p, n), f32),
+        ((slots, heads), f32), ((slots, heads, p), f32),
+        ((slots, g, n), f32), ((slots, g, n), f32), donate=(0,))
+    mem = compiled.memory_analysis()
+    plane_bytes = layers * (slots + 1) * heads * p * n * 4
+    assert mem.alias_size_in_bytes >= plane_bytes
+    assert mem.temp_size_in_bytes < 2 ** 20
+    text = compiled.as_text()
+    assert "ssm_state_step" in text
+    assert not [ln for ln in text.splitlines()
+                if (" copy(" in ln or "copy-start(" in ln)
+                and ("f32[6,65,2,16,128,256]" in ln
+                     or "f32[6,65,32,128,256]" in ln)]
+
+
 @pytest.mark.parametrize("form", ["float", "int8", "int4"])
 def test_fused_decode_step_compiles(compile_on_chip, form):
     from distributed_llm_inferencing_tpu.ops.pallas.fused_decode import (
