@@ -713,7 +713,9 @@ def _mla_absorbed(q, lp, cfg: ModelConfig, attend_rows):
     ``attend_rows(q_eff)`` runs ops/attention.attend with the rows as
     both K and V (one kv head, read as stored: the context comes back
     rd + r wide and its first rd columns, the rope key's, are dropped
-    here, so no r-wide copy of the rows is ever cut) at
+    here, so no r-wide copy of the rows is ever cut; the paged kernel
+    returns it as wide as the pool stores a row, lane_width(rd + r),
+    and the zero tail is dropped with them) at
     ``scale=_mla_scale(cfg)``. q [B,s,H,qk_head_dim] -> attn
     [B,s,H,v_head_dim]."""
     H, rd, r = cfg.num_heads, cfg.qk_rope_head_dim, cfg.kv_lora_rank
@@ -725,7 +727,7 @@ def _mla_absorbed(q, lp, cfg: ModelConfig, attend_rows):
              jnp.einsum("bshn,rhn->bshr", q[..., rd:], wk)], axis=-1)
     ctx = attend_rows(q_eff)                             # [B,s,H,rd+r]
     with jax.named_scope("mla_absorb"):
-        return jnp.einsum("bshr,rhv->bshv", ctx[..., rd:], wv)
+        return jnp.einsum("bshr,rhv->bshv", ctx[..., rd:rd + r], wv)
 
 
 def _mla_scale(cfg: ModelConfig) -> float:
@@ -1352,17 +1354,20 @@ def _layer_gather(pool, scales, block_tables, dt, layer, kind=None):
     return got
 
 
-def _write_side(side, new, at, dt, layer):
+def _write_side(side, new, at, layer):
     """Put a pass's fresh rows ``new`` ([R, n, Hkv, w] a plane) into the
-    chunk's side buffers at entry ``at``. Returns (the buffers to carry
-    on, this layer's [R, K, Hkv, w] rows for attention's side segment).
+    chunk's side buffers at entry ``at`` (zeros after them where the
+    buffers are as wide as a latent pool stores a row). Returns (the
+    buffers to carry on, this layer's [R, K, Hkv, w] rows for
+    attention's side segment).
     ``side`` is the whole [L, R, K, Hkv, w] stack riding the layer
     stack's carry and ``layer`` the layer's index: the rows are written
     in place and the stack is never sliced into per-layer inputs and
     stacked again from outputs."""
+    from distributed_llm_inferencing_tpu.ops.paged_kvcache import fit_rows
     with jax.named_scope("kv_write"):
         side = tuple(
-            jax.lax.dynamic_update_slice(s_, n_.astype(dt)[None],
+            jax.lax.dynamic_update_slice(s_, fit_rows(n_, s_)[None],
                                          (layer, 0, at, 0, 0))
             for s_, n_ in zip(side, new))
         return side, tuple(s_[layer] for s_ in side)
@@ -1392,8 +1397,9 @@ def _pool_ladder(mb: int, scanned: bool = True):
     (mistral's 128 -> 16/32/48/64/96/128, a toy 6 -> 1/2/3/5/6). A rung
     is a branch of one lax.switch in the layer body, not a program. This
     is the XLA form of the pool's read: where _pool_kernel takes the
-    Pallas kernel (which stops at each slot's own length) no ladder and
-    no switch are built.
+    Pallas kernel (which stops at each slot's own length: mistral-7b,
+    Ouro-2.6B and kanana among the benchmark's cells) no ladder and no
+    switch are built.
     A conditional takes its operands as buffers: they are the stacked
     pool as it lies and the layer's index, and the branch gathers by
     (layer, block) (_layer_gather); handed the scan's slice of the pool
@@ -1403,8 +1409,9 @@ def _pool_ladder(mb: int, scanned: bool = True):
     code outside it and a switch around the whole chunk costs seconds a
     program at every start, so there the ladder is the full extent
     alone: lax.switch inlines its one branch, and the gather fuses into
-    attention. PERF.md section 6, PR 30, has the chip's numbers for
-    each."""
+    attention (trinity's full layers; kanana's on a mesh or with a
+    quantized pool). PERF.md section 6, PR 30, has the chip's numbers
+    for each."""
     if not scanned:
         return (mb,)
     return tuple(sorted({-(-mb * n // 8) for n in (1, 2, 3, 4, 6, 8)}))
@@ -1450,27 +1457,32 @@ def _attend_pool_rung(rung, ladder, pre: bool, planes, scales, block_tables,
     return jax.lax.switch(rung, [branch(m) for m in ladder])
 
 
-def _pool_kernel(params, cfg: ModelConfig, paged):
+def _pool_kernel(cfg: ModelConfig, paged):
     """Which form a decode chunk's read of the pool takes, from what the
     trace can see: ``cfg.pool_kernel`` (pinned by the batcher: "pallas"
     only in a one-device TPU program, since GSPMD does not partition a
     Pallas call) where ops/pallas/paged_attention.py computes this
-    model's attention and reads this pool as it lies -- a scanned stack
-    (the kernel takes the layer's index from the scan; layers held one
-    by one keep the fused XLA form they were tuned in), K and V planes
-    unquantized and in the compute dtype, no latent pool, heads that
-    fill whole (8, 128) tiles, no ALiBi, sinks or softcap, a window that
-    is None or one trace-time integer -- else None: the in-loop gather
-    as far as _pool_ladder's rung (_attend_pool_rung). In the
-    benchmark's cells the kernel serves mistral-7b and Ouro-2.6B; kanana
-    (a latent MQA plane) and trinity (4 K/V heads, layers held one by
-    one, windowed reads already bounded) keep the XLA form."""
+    model's attention and reads this pool as it lies -- planes
+    unquantized and in the compute dtype; K and V heads that fill whole
+    (8, 128) tiles, or one head of whole lanes (MQA; a latent pool's one
+    plane of shared rows, stored lane_width wide, which the kernel takes
+    as K and V at once); no ALiBi, sinks or softcap; a window that is
+    None or one trace-time integer, and none over a latent pool -- else
+    None: the in-loop gather as far as _pool_ladder's rung
+    (_attend_pool_rung). The stack may be scanned (the kernel takes the
+    layer's index from the scan) or held layer by layer (the index is a
+    constant handed in as an array: one lowering for all of them). In
+    the benchmark's cells the kernel serves mistral-7b, Ouro-2.6B and
+    kanana (its latent MQA plane, 7 layers held one by one); trinity
+    (per-layer windows, 4 K/V heads) and falcon-h1 (4 K/V heads) keep
+    the XLA form, as do int8 pools, meshes, the speculative chunk and
+    the CPU."""
     if (not cfg.pool_kernel.startswith("pallas") or paged.quantized
-            or cfg.mla_latent_cache or cfg.attn_windows is not None
+            or cfg.attn_windows is not None
             or cfg.position_embedding == "alibi" or cfg.attn_sinks
             or cfg.attn_softcap is not None
             or paged.k.dtype != jnp.dtype(cfg.dtype)
-            or not _layers_scanned(params, cfg)):
+            or (cfg.mla_latent_cache and cfg.sliding_window is not None)):
         return None
     from distributed_llm_inferencing_tpu.ops.pallas import paged_attention
     if not paged_attention.supported(paged.k.shape[3], paged.k.shape[4],
@@ -1539,8 +1551,9 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
     or widened. The pool is loop-invariant during the chunk, which is
     what makes the split exact. The pool's segment takes one of two
     forms (``_pool_kernel``, from what the trace can see). *The kernel*
-    (a one-device TPU program, a scanned stack, unquantized K and V
-    planes of whole (8, 128) tiles: mistral-7b, Ouro-2.6B):
+    (a one-device TPU program, unquantized K and V planes of whole
+    (8, 128) tiles or a latent pool's one plane of whole lanes:
+    mistral-7b, Ouro-2.6B, kanana):
     ops/pallas/paged_attention.paged_attend reads each live slot's pages
     where the pool lies, by (layer, block-table entry), as far as that
     slot's own context, and keeps both segments' softmax inside the
@@ -1564,7 +1577,10 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
     An MLA model's pool is latent (cfg.mla_latent_cache): one plane of
     shared rows, so one side buffer, and attention is the absorbed form
     (_mla_absorbed) over (gathered rows, side rows), the rows standing
-    for K and for V alike.
+    for K and for V alike. Under the kernel the side buffer is as wide
+    as the pool stores a row (lane_width: zeros after the rd + r
+    columns), the query is padded with zeros to match, and a page's rows
+    are fetched once for the scores and the weighted sum.
 
     tokens: [R] last emitted token per slot; steps0: [R] tokens emitted so
     far. Returns (toks [K, R] int32, emits [K, R] bool, moe int32 [5],
@@ -1628,7 +1644,7 @@ def decode_chunk_with_logits(params, cfg: ModelConfig, k: int, tokens, paged,
     pool_pos = jnp.broadcast_to(jnp.arange(mb * bs, dtype=jnp.int32),
                                 (r, mb * bs))
     pool_valid = pool_pos < cl0[:, None]
-    kernel = _pool_kernel(params, cfg, paged)
+    kernel = _pool_kernel(cfg, paged)
     ladder = _pool_ladder(mb, _layers_scanned(params, cfg))
     if kernel:
         # the kernel walks each live slot's block table to its own
@@ -1640,12 +1656,15 @@ def decode_chunk_with_logits(params, cfg: ModelConfig, k: int, tokens, paged,
                            // bs) * bs
         walk = paged_attention.pool_walk(
             cl0, budget > 0, paged.k, mb,
-            sliding_window=cfg.sliding_window)
+            sliding_window=cfg.sliding_window, n_planes=n_planes)
     else:
         rung, pool_positions = _pool_rung(ladder, bs, cl0, budget > 0)
     side_pos = cl0[:, None] + jnp.arange(k, dtype=jnp.int32)[None, :]
-    side0 = (jnp.zeros((L, r, k, cfg.cache_kv_heads, cfg.cache_head_dim),
-                       dt),) * n_planes
+    # (the kernel reads the side rows beside a page's: as wide as those)
+    side0 = (jnp.zeros(
+        (L, r, k, cfg.cache_kv_heads,
+         paged.k.shape[-1] if kernel else cfg.cache_head_dim),
+        dt),) * n_planes
     if cfg.ssm is not None:
         # state layers: the per-slot state and conv planes ride the
         # carry behind the side buffers, whole, and each layer of each
@@ -1691,15 +1710,16 @@ def decode_chunk_with_logits(params, cfg: ModelConfig, k: int, tokens, paged,
             def layer(carry, layer_in):
                 (x, sd), (lp, li) = carry, layer_in
 
-                def attend_kernel(q, rows):
+                def attend_kernel(q, rows, scale=None):
                     # pool and side rows in one softmax inside the call,
                     # the planes taken where they lie at the layer's
-                    # index
+                    # index; a latent pool's one plane as K and V alike
                     with jax.named_scope("attention"):
                         return paged_attention.paged_attend(
-                            q, pool[0], pool[1], li, block_tables, cl0,
-                            cl0 + t, walk, (rows[0], rows[1], t),
+                            q, pool[0], pool[-1], li, block_tables, cl0,
+                            cl0 + t, walk, (rows[0], rows[-1], t),
                             sliding_window=seg_cfg.sliding_window,
+                            scale=scale,
                             interpret=kernel == "pallas_interpret")
 
                 def attend_side(q, sd2, sliding_window=None, **kw):
@@ -1744,19 +1764,19 @@ def decode_chunk_with_logits(params, cfg: ModelConfig, k: int, tokens, paged,
                     def mla_latent_attend(h, qp):
                         sd2, rows = _write_side(
                             sd, (_mla_latent_rows(h, lp, seg_cfg, qp),), t,
-                            dt, li)
+                            li)
                         attn = _mla_absorbed(
                             _mla_q(h, lp, seg_cfg, qp), lp, seg_cfg,
-                            lambda q_eff: attend_side(
-                                q_eff, rows, scale=_mla_scale(seg_cfg)))
+                            lambda q_eff: (
+                                attend_kernel if kernel else attend_side)(
+                                    q_eff, rows, scale=_mla_scale(seg_cfg)))
                         return attn, sd2
                     return done(*_block_body(
                         x, lp, seg_cfg, q_pos, None,
                         mla_latent_attend=mla_latent_attend, **tail))
 
                 def attend_write(q, kh, vh):
-                    sd2, rows = _write_side(sd[:n_planes], (kh, vh), t, dt,
-                                            li)
+                    sd2, rows = _write_side(sd[:n_planes], (kh, vh), t, li)
                     if kernel:
                         return attend_kernel(q, rows), sd2
                     return attend_side(
@@ -1970,8 +1990,7 @@ def paged_speculative_chunk(params, cfg: ModelConfig, k: int, gamma: int,
                 (x, sd), (lp, li) = carry, layer_in
 
                 def attend_write(q, kh, vh):
-                    sd2, (sk2, sv2) = _write_side(sd, (kh, vh), t * g1, dt,
-                                                  li)
+                    sd2, (sk2, sv2) = _write_side(sd, (kh, vh), t * g1, li)
 
                     def attend_pool(got, pos, valid):
                         with jax.named_scope("attention"):

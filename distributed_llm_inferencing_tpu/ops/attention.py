@@ -189,8 +189,9 @@ def resolve_backend(requested: str = "auto", n_devices: int = 1,
     gather. This backend does not say how those chunks read the pool:
     they take the Pallas paged kernel by themselves where the batcher's
     ``cfg.pool_kernel`` pin and the pool's shape allow
-    (transformer._pool_kernel; PERF.md section 6, PR 40) and the gather
-    as far as _pool_ladder's rung elsewhere. Explicit "pallas" is
+    (transformer._pool_kernel: mistral-7b, Ouro-2.6B, kanana's latent
+    pool; PERF.md section 6, PRs 40 and 42) and the gather as far as
+    _pool_ladder's rung elsewhere. Explicit "pallas" is
     honored: the stepwise chunk, which writes the pool every step.
     """
     requested = os.environ.get("DLI_ATTENTION", requested)

@@ -45,9 +45,10 @@ is a cost of its own beside attention (``kv_gather`` in PERF.md section
 5). ops/pallas/paged_attention.py reads the pages where they lie
 instead, each slot as far as its own context: the decode chunks of a
 one-device TPU program take it where the pool's shape allows
-(models/transformer.py _pool_kernel: mistral-7b and Ouro-2.6B among the
-benchmark's cells; PERF.md section 6, PR 40), everything else keeps the
-gather.
+(models/transformer.py _pool_kernel: mistral-7b, Ouro-2.6B and, its
+latent plane's rows fetched once as K and V alike, kanana among the
+benchmark's cells; PERF.md section 6, PRs 40 and 42), everything else
+keeps the gather.
 
 The reference framework has no counterpart at any level — its KV cache was
 implicit inside HF ``generate`` (SURVEY.md §2.4).
@@ -334,7 +335,8 @@ def paged_attend_decode(q, cache_k_layer, cache_v_layer, block_tables,
     "auto" resolves to the XLA gather formulation
     (ops/attention.resolve_backend): this stepwise entry writes the pool
     on every step and is no serving path; the decode chunks choose the
-    kernel themselves (models/transformer.py _pool_kernel), where it
+    kernel themselves (models/transformer.py _pool_kernel: K and V
+    planes of 8 or more heads, a latent pool's one plane), where it
     was measured at 1.5-4.2 times the gather's speed (PERF.md section 5).
     The gather copies MB*bs positions per slot whatever ``context_lens``
     says, and attention then reads all of them.

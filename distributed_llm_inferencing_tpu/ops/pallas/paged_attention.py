@@ -35,6 +35,15 @@ lies and as far as each slot's own context:
   side rows (its own K and V of this and earlier passes, [K, Hkv, hd] a
   slot) start each slot's softmax state, so pool and side meet in one
   softmax inside the kernel.
+- A latent pool (MLA: models/transformer.py ``_mla_absorbed``) is the
+  same walk over ONE plane ``[L, NB, bs, 1, w]`` whose rows are K and V
+  at once (kanana: w = 640, a page 20 KB): a page is fetched once and
+  the same rows in VMEM give the scores and the weighted sum. With one
+  kv head every query head owns every row, so no head mask is built.
+  Steps and items are sized in bytes, so wide rows come fewer a step;
+  a call whose whole-block q, output and softmax state pass Mosaic's
+  scoped VMEM (kanana's 64 slots x 32 heads x 640: 21 MiB) asks for
+  what it needs and starts and finishes its slots in a loop.
 
 ``paged_flash_decode`` is the stepwise path's entry (one layer's pool, no
 side rows: paged_kvcache.paged_attend_decode with an explicit pallas
@@ -55,35 +64,56 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 LANES = 128
 
-# K (and V) bytes fetched a work item; two items are in VMEM at a time
-_STEP_BYTES = 512 * 1024
-# (position, kv head) rows a compute step, one softmax update over
-# [H, rows] scores: the MXU products of a step are independent, the steps
-# are a chain, so wide steps are what streams (2048 rows: 69-81 % of a
-# v5e's HBM peak, 512: 49-54 %; scripts/bench_paged_attend.py) ...
-_STEP_ROWS = 2048
-# ... and a slot's last pages, short of a step, go in narrow ones
-_TAIL_ROWS = 1024
+# K and V bytes (every plane's) fetched a work item; two items are in VMEM
+# at a time
+_ITEM_BYTES = 1024 * 1024
+# K and V bytes of a compute step, one softmax update over [H, rows]
+# scores: the MXU products of a step are independent, the steps are a
+# chain, so wide steps are what streams (K and V heads of 128 in bf16:
+# 2048 rows, 69-81 % of a v5e's HBM peak, 512: 49-54 %;
+# scripts/bench_paged_attend.py). In bytes and not in rows, since a row's
+# arithmetic and its room in VMEM both go by its width: a latent pool's
+# 640-wide rows, K and V at once, come 1024 a step ...
+_STEP_BYTES = 1024 * 1024
+# ... and a slot's last pages, short of a step, go in narrow ones (as
+# many pages as hold this, rounded up to a power of two: whole MXU tiles
+# of rows, and one bulk wait for a full item)
+_TAIL_BYTES = 512 * 1024
+# an item of up to this many pages starts and awaits its copies a page a
+# loop iteration; a longer one (small pages: 20 KB at kanana, 50 an item)
+# starts them in unrolled groups and awaits them in bulk: the scalar
+# core's work a page, which runs beside no vector work, was a third of
+# the latent kernel's time (PERF.md section 6, PR 42)
+_LOOP_PAGES = 16
+_GROUP = 8
+# Mosaic's scoped VMEM on a v5e without asking; a call that needs more
+# asks for what it needs (_paged_attend)
+_SCOPED_VMEM = 16 * 1024 * 1024
+# every slot's softmax state [R, H, hd] float32 up to this is started and
+# finished as one value; a larger one a slot at a time, in a loop
+_STATE_AT_ONCE = 1024 * 1024
 
 
-def _pages(bs: int, hkv: int, hd: int, itemsize: int, mb: int):
+def _pages(bs: int, hkv: int, hd: int, itemsize: int, mb: int,
+           n_planes: int = 2):
     """(pages a tail step, pages a step, pages a work item) for a page
-    of [bs, hkv, hd] and block tables of ``mb`` columns: each a multiple
-    of the one before."""
-    rows = bs * hkv
-    tail = max(1, min(_TAIL_ROWS // rows, mb))
-    step = tail * max(1, min(_STEP_ROWS // (tail * rows), -(-mb // tail)))
-    item = step * max(1, min(_STEP_BYTES // (step * rows * hd * itemsize),
-                             -(-mb // step)))
+    of [bs, hkv, hd] in each of ``n_planes`` planes and block tables of
+    ``mb`` columns: each a multiple of the one before."""
+    page = n_planes * bs * hkv * hd * itemsize
+    tail = min(1 << (max(_TAIL_BYTES // page, 1) - 1).bit_length(), mb)
+    step = tail * max(1, min(-(-_STEP_BYTES // (tail * page)),
+                             -(-mb // tail)))
+    item = step * max(1, min(_ITEM_BYTES // (step * page), -(-mb // step)))
     return tail, step, item
 
 
 def supported(hkv: int, hd: int, dtype) -> bool:
     """Whether a pool of ``hkv`` heads of ``hd`` in ``dtype`` is one the
     kernel reads as it lies: rows of whole 128-lane tiles, and kv heads
-    that fill a tile's sublanes, so that a page is contiguous in HBM and
-    [bs, Hkv, hd] reads as [bs * Hkv, hd] without a copy."""
-    return (hd % LANES == 0 and hkv % 8 == 0
+    that fill a tile's sublanes or one head alone (MQA, a latent pool's
+    shared row: the positions fill them), so that a page is contiguous
+    in HBM and [bs, Hkv, hd] reads as [bs * Hkv, hd] without a copy."""
+    return (hd % LANES == 0 and (hkv % 8 == 0 or hkv == 1)
             and jnp.dtype(dtype) in (jnp.dtype(jnp.bfloat16),
                                      jnp.dtype(jnp.float32)))
 
@@ -97,10 +127,12 @@ class PoolWalk(NamedTuple):
 
 
 def pool_walk(context_lens, live, planes, max_blocks: int, *,
-              sliding_window: Optional[int] = None, q_pos=None) -> PoolWalk:
+              sliding_window: Optional[int] = None, q_pos=None,
+              n_planes: int = 2) -> PoolWalk:
     """The work items of paged_attend's walk over every live slot's pool
     positions [first, context_lens) in ``planes`` ([..., bs, Hkv, hd]: K
-    or V as paged_attend takes them) under block tables of
+    or V as paged_attend takes them; ``n_planes`` 1 for a latent pool,
+    whose one plane is both) under block tables of
     ``max_blocks`` columns: as many columns an item as the kernel
     fetches for a pool of this shape (_pages), a slot's items in order,
     slots in order, a slot that is not ``live`` (or holds nothing) none.
@@ -110,7 +142,7 @@ def pool_walk(context_lens, live, planes, max_blocks: int, *,
     exact cut."""
     block_size, hkv, hd = planes.shape[-3:]
     pages = _pages(block_size, hkv, hd, planes.dtype.itemsize,
-                   max_blocks)[-1]
+                   max_blocks, n_planes)[-1]
     r = context_lens.shape[0]
     cl = jnp.where(live, context_lens, 0).astype(jnp.int32)
     first = jnp.zeros_like(cl)
@@ -149,6 +181,8 @@ def _batch(x):
 def _pv(p, v):
     """``p`` [..., H, S] float32 times ``v`` [..., S, hd] as stored, in
     float32."""
+    if v.shape[-2] == 1:         # one row (_scores): float32 on the VPU
+        return p * v.astype(jnp.float32)
     dims = (((p.ndim - 1,), (v.ndim - 2,)), _batch(p))
     if v.dtype != jnp.bfloat16:
         return jax.lax.dot_general(
@@ -166,6 +200,12 @@ def _pv(p, v):
 def _scores(q, k, scale):
     """All query heads [..., H, hd] against ``k``'s rows [..., S, hd] as
     they lie: [..., H, S]."""
+    if k.shape[-2] == 1:
+        # one row (a chunk of one pass over a one-head pool): Mosaic
+        # refuses the bf16 product with a one-row operand, and the VPU's
+        # float32 sum of the same products is as exact
+        return jnp.sum(q.astype(jnp.float32) * k.astype(jnp.float32),
+                       axis=-1, keepdims=True) * scale
     both_bf16 = q.dtype == jnp.bfloat16 and k.dtype == jnp.bfloat16
     return jax.lax.dot_general(
         q, k, (((q.ndim - 1,), (k.ndim - 1,)), _batch(q)),
@@ -183,23 +223,23 @@ def _div(x, n: int):
 
 def _kernel(slot_ref, col_ref, n_ref, count_ref, bt_ref, len_ref, qpos_ref,
             misc_ref, q_ref, *refs, bs, hkv, g, mb, plan, side_rows, scale,
-            window):
-    if side_rows:
-        sk_ref, sv_ref, k_hbm, v_hbm, o_ref = refs[:5]
-    else:
-        k_hbm, v_hbm, o_ref = refs[:3]
-    kbuf, vbuf, sem, m_scr, l_scr, acc_scr = refs[-6:]
-    r, h, _ = q_ref.shape
+            window, n_planes):
+    # n_planes 2: K and V planes (and side rows); 1: one plane whose rows
+    # are K and V at once, fetched once into one buffer
+    n_side = n_planes if side_rows else 0
+    side, hbm, o_ref = (refs[:n_side], refs[n_side:n_side + n_planes],
+                        refs[n_side + n_planes])
+    bufs, (sem, m_scr, l_scr, acc_scr) = refs[-4 - n_planes:-4], refs[-4:]
+    r, h, hd = q_ref.shape
     page = bs * hkv                      # rows of one page
     tail_pages, step_pages, pages = plan
     plane, t = misc_ref[0], misc_ref[1]
     count = count_ref[0]
+    at_once = r * h * hd * 4 <= _STATE_AT_ONCE
 
-    def page_copy(w, b, i, hbm, buf, which):
-        blk = bt_ref[slot_ref[w] * mb + col_ref[w] + i]
-        return pltpu.make_async_copy(
-            hbm.at[plane, blk], buf.at[b, pl.ds(i * page, page)],
-            sem.at[which, b])
+    # an item of many small pages starts its copies in unrolled groups
+    # and awaits them in bulk (_LOOP_PAGES)
+    grouped = pages > _LOOP_PAGES
 
     def start(w, b):
         """Start item ``w``'s page copies into buffer ``b``. (Loops, not
@@ -207,25 +247,53 @@ def _kernel(slot_ref, col_ref, n_ref, count_ref, bt_ref, len_ref, qpos_ref,
         program traces and lowers this kernel at every start of a
         worker, compile cache or not, and unrolled it cost a cell 6-10 s
         of set-up; PERF.md section 6, PR 40.)"""
+        def first():
+            return slot_ref[w] * mb + col_ref[w]
+        if grouped:           # the item's first table entry, read once
+            at, first = first(), lambda: at
+
         def one(i, carry):
-            page_copy(w, b, i, k_hbm, kbuf, 0).start()
-            page_copy(w, b, i, v_hbm, vbuf, 1).start()
+            for which in range(n_planes):
+                pltpu.make_async_copy(
+                    hbm[which].at[plane, bt_ref[first() + i]],
+                    bufs[which].at[b, pl.ds(i * page, page)],
+                    sem.at[which, b]).start()
             return carry
-        jax.lax.fori_loop(0, n_ref[w], one, 0)
+        n = n_ref[w]
+        if not grouped:
+            jax.lax.fori_loop(0, n, one, 0)
+            return
+
+        def group(j, carry):
+            for u in range(_GROUP):
+                one(j * _GROUP + u, carry)
+            return carry
+        jax.lax.fori_loop(0, n // _GROUP, group, 0)
+        jax.lax.fori_loop(n // _GROUP * _GROUP, n, one, 0)
 
     def wait(w, b):
-        """Wait for them: a DMA semaphore counts bytes, a wait takes one
-        page's."""
-        def one(i, carry):
-            for buf, which in ((kbuf, 0), (vbuf, 1)):
-                got = buf.at[b, pl.ds(0, page)]
+        """Wait for them: a DMA semaphore counts bytes, a wait takes as
+        many as its descriptor holds: a page's, or (many small pages)
+        those of 1, 2, 4, ... pages, one wait a set bit of the item's
+        page count."""
+        def pages_wait(n_pages):
+            for which in range(n_planes):
+                got = bufs[which].at[b, pl.ds(0, n_pages * page)]
                 pltpu.make_async_copy(got, got, sem.at[which, b]).wait()
-            return carry
-        jax.lax.fori_loop(0, n_ref[w], one, 0)
+
+        if not grouped:
+            def one(i, carry):
+                pages_wait(1)
+                return carry
+            jax.lax.fori_loop(0, n_ref[w], one, 0)
+            return
+        for bit in (1 << i for i in range(pages.bit_length())):
+            pl.when((n_ref[w] & bit) != 0)(
+                functools.partial(pages_wait, bit))
 
     # rows of a buffer that no copy has written yet may hold anything,
     # and 0 x NaN is NaN: V's start as zeros (K's scores are masked)
-    vbuf[...] = jnp.zeros_like(vbuf)
+    bufs[-1][...] = jnp.zeros_like(bufs[-1])
 
     @pl.when(count > 0)
     def _first():
@@ -233,10 +301,16 @@ def _kernel(slot_ref, col_ref, n_ref, count_ref, bt_ref, len_ref, qpos_ref,
 
     def head_mask(n_rows):
         """[H, n_rows] of (the row's kv head is the query head's own,
-        the row's position in its run of rows)."""
+        the row's position in its run of rows). With one kv head every
+        query head owns every row: no mask (None) is built."""
+        if hkv == 1:
+            return None, jax.lax.broadcasted_iota(jnp.int32, (h, n_rows), 1)
         qh = jax.lax.broadcasted_iota(jnp.int32, (h, n_rows), 0)
         row = jax.lax.broadcasted_iota(jnp.int32, (h, n_rows), 1)
         return _div(qh, g) == row - _div(row, hkv) * hkv, _div(row, hkv)
+
+    def both(own, mask):
+        return mask if own is None else own & mask
 
     def softmax_step(state, scores, mask, v):
         """One online-softmax step: state (m, l [..., H, 1], acc
@@ -256,20 +330,39 @@ def _kernel(slot_ref, col_ref, n_ref, count_ref, bt_ref, len_ref, qpos_ref,
         l_scr[at] = jnp.broadcast_to(l, lanes)
         acc_scr[at] = acc
 
-    state = (jnp.full((r, h, 1), NEG_INF, jnp.float32),
-             jnp.zeros((r, h, 1), jnp.float32),
-             jnp.zeros(acc_scr.shape, jnp.float32))
-    if side_rows:
-        # the chunk's own rows, every slot's at once: entry j is
-        # position len + j, written on pass j, real on pass t iff j <= t
-        own, j = head_mask(side_rows * hkv)
-        side_mask = own & (j <= t)
-        if window is not None:
-            side_mask &= (t - j) < window
-        state = softmax_step(
-            state, _scores(q_ref[...], sk_ref[...], scale), side_mask[None],
-            sv_ref[...])
-    put(slice(None), state)
+    def get(ref, at):
+        """Slot ``at``'s block of ``ref``, or (None) all of it."""
+        return ref[...] if at is None else ref[at]
+
+    def slots(lead):
+        """The softmax state before any row, and the slots it is for:
+        all at once (``lead`` = (r,)) or one (())."""
+        return (jnp.full(lead + (h, 1), NEG_INF, jnp.float32),
+                jnp.zeros(lead + (h, 1), jnp.float32),
+                jnp.zeros(lead + (h, hd), jnp.float32))
+
+    def begin(at, state):
+        """Slot ``at`` (None: every slot at once) starts from the
+        chunk's own rows: entry j is position len + j, written on pass
+        j, real on pass t iff j <= t."""
+        if side_rows:
+            own, j = head_mask(side_rows * hkv)
+            side_mask = both(own, j <= t)
+            if window is not None:
+                side_mask &= (t - j) < window
+            state = softmax_step(
+                state, _scores(get(q_ref, at), get(side[0], at), scale),
+                side_mask[None] if at is None else side_mask,
+                get(side[-1], at))
+        put(slice(None) if at is None else at, state)
+
+    if at_once:
+        begin(None, slots((r,)))
+    else:
+        def begin_one(s, carry):
+            begin(s, slots(()))
+            return carry
+        jax.lax.fori_loop(0, r, begin_one, 0)
 
     def steps(n_pages):
         """f(slot, buffer, first row, first position, lengths) -> one
@@ -278,15 +371,18 @@ def _kernel(slot_ref, col_ref, n_ref, count_ref, bt_ref, len_ref, qpos_ref,
 
         def one(s, b, row0, pos0, length, q_pos):
             pos = pos0 + pos_in_step
-            mask = own & (pos < length)
+            mask = both(own, pos < length)
             if window is not None:
                 mask &= (q_pos - pos) < window
             rows = pl.ds(pl.multiple_of(row0, tail_pages * page),
                          n_pages * page)
+            state = (m_scr[s][:, :1], l_scr[s][:, :1], acc_scr[s])
+            q, k = q_ref[s], bufs[0][b, rows]
+            scores = _scores(q, k, scale)
+            # one plane: the rows just read are V too
             put(s, softmax_step(
-                (m_scr[s][:, :1], l_scr[s][:, :1], acc_scr[s]),
-                _scores(q_ref[s], kbuf[b, rows], scale), mask,
-                vbuf[b, rows]))
+                state, scores, mask,
+                k if n_planes == 1 else bufs[1][b, rows]))
         return one
     wide, narrow = steps(step_pages), steps(tail_pages)
 
@@ -320,10 +416,19 @@ def _kernel(slot_ref, col_ref, n_ref, count_ref, bt_ref, len_ref, qpos_ref,
         return carry
     jax.lax.fori_loop(0, count, item, 0)
 
-    l = l_scr[...][:, :, :1]
-    o_ref[...] = jnp.where(
-        l > 0, acc_scr[...] / jnp.where(l > 0, l, 1.0), 0.0
-    ).astype(o_ref.dtype)
+    def finish(at):
+        l = get(l_scr, at)[..., :1]
+        o_ref[... if at is None else at] = jnp.where(
+            l > 0, get(acc_scr, at) / jnp.where(l > 0, l, 1.0), 0.0
+        ).astype(o_ref.dtype)
+
+    if at_once:
+        finish(None)
+    else:
+        def finish_one(s, carry):
+            finish(s)
+            return carry
+        jax.lax.fori_loop(0, r, finish_one, 0)
 
 
 def paged_attend(q, k_planes, v_planes, plane, block_tables, context_lens,
@@ -336,29 +441,47 @@ def paged_attend(q, k_planes, v_planes, plane, block_tables, context_lens,
 
     q [R, 1, H, hd]; k_planes, v_planes [L, NB, bs, Hkv, hd], read where
     they lie; plane: int32 scalar (traced under a layer scan; a looped
-    model's ``u * L + l``); block_tables [R, MB]; context_lens [R]: the
-    pool's horizon; q_pos [R]: each query's position (the window's
-    anchor); walk: pool_walk(...) of the same lengths, planes, table
-    width and window. side_k, side_v
+    model's ``u * L + l``; a constant where layers are held one by one,
+    which goes in as an array all the same, so that every layer's call
+    is one trace and one lowering); block_tables [R, MB]; context_lens
+    [R]: the pool's horizon; q_pos [R]: each query's position (the
+    window's anchor); walk: pool_walk(...) of the same lengths, planes,
+    table width and window. side_k, side_v
     [R, K, Hkv, hd]: this layer's rows of the chunk's side buffers;
     entry j is position context_lens + j, real for j <= t (int32
     scalar: the chunk's pass). (The layer's rows and not the side stack
     with the plane's index: handed the stack, XLA moved all of it into
     VMEM and back around every layer's call, 32 MiB a layer at
     mistral-7b; PERF.md section 6, PR 40.) A slot the walk leaves out
-    attends its side rows alone (zeros without them). Returns
-    [R, 1, H, hd] in q.dtype."""
+    attends its side rows alone (zeros without them).
+
+    A latent pool (``v_planes is k_planes``, and ``side_v is side_k``):
+    one plane [L, NB, bs, 1, w] whose rows are K and V at once. A page
+    is fetched once and the same rows in VMEM give the scores and the
+    weighted sum, so the rows cross HBM once for both. ``q`` may be
+    narrower than ``w`` (MLA's rd + r of a lane_width row): the pool's
+    columns past it are zeros and so are q's; the context comes back
+    ``w`` wide. Pass ``scale``: the default is the row's width's.
+
+    Returns [R, 1, H, hd] in q.dtype."""
     bs, hkv, hd = k_planes.shape[2:]
+    if q.shape[-1] < hd:
+        q = jnp.pad(q, [(0, 0)] * 3 + [(0, hd - q.shape[-1])])
+    if v_planes is k_planes:
+        v_planes = None
+        if side is not None:
+            assert side[1] is side[0], "one plane has one side buffer"
+            side = (side[0], None, side[2])
     # the plan is a static argument: a program lowers the kernel once
     # however many layers' bodies call it (jit's cache), and a plan set
     # by hand (tests, the microbenchmark's sweep) is traced anew
     return _paged_attend(
-        q, k_planes, v_planes, plane, block_tables, context_lens, q_pos,
-        walk, side, sliding_window=sliding_window,
+        q, k_planes, v_planes, jnp.asarray(plane, jnp.int32), block_tables,
+        context_lens, q_pos, walk, side, sliding_window=sliding_window,
         scale=float(hd ** -0.5) if scale is None else scale,
         interpret=interpret,
         plan=_pages(bs, hkv, hd, k_planes.dtype.itemsize,
-                    block_tables.shape[1]))
+                    block_tables.shape[1], 1 if v_planes is None else 2))
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -368,10 +491,17 @@ def _paged_attend(q, k_planes, v_planes, plane, block_tables, context_lens,
                   plan):
     r, one, h, hd = q.shape
     assert one == 1, "paged_attend takes exactly one query token a slot"
+    planes = [p for p in (k_planes, v_planes) if p is not None]
     n_planes, nb, bs, hkv, _ = k_planes.shape
     g = h // hkv
     mb = block_tables.shape[1]
     pages = plan[-1]
+    item_bytes = pages * bs * hkv * hd * k_planes.dtype.itemsize
+    # what the call holds in VMEM: q, the side rows and the output as
+    # whole blocks (the pipeline keeps two of each), two items a plane,
+    # the softmax state
+    vmem = (4 * r * h * hd * q.dtype.itemsize + 2 * len(planes) * item_bytes
+            + 4 * r * h * (hd + 2 * LANES))
 
     def whole(*shape):
         return pl.BlockSpec(shape, lambda i, *_: (0,) * len(shape))
@@ -384,14 +514,16 @@ def _paged_attend(q, k_planes, v_planes, plane, block_tables, context_lens,
         # same bytes where the heads fill a tile's sublanes (supported),
         # so a bitcast and not a copy
         rows = (r, side_rows * hkv, hd)
-        operands += [side_k.reshape(rows), side_v.reshape(rows)]
-        in_specs += [whole(*rows)] * 2
+        operands += [s_.reshape(rows) for s_ in (side_k, side_v)
+                     if s_ is not None]
+        in_specs += [whole(*rows)] * len(planes)
+        vmem += 2 * len(planes) * r * side_rows * hkv * hd * q.dtype.itemsize
     flat = (n_planes, nb, bs * hkv, hd)
-    operands += [k_planes.reshape(flat), v_planes.reshape(flat)]
-    in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
+    operands += [p.reshape(flat) for p in planes]
+    in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * len(planes)
     kernel = functools.partial(
         _kernel, bs=bs, hkv=hkv, g=g, mb=mb, plan=plan, side_rows=side_rows,
-        scale=scale, window=sliding_window)
+        scale=scale, window=sliding_window, n_planes=len(planes))
 
     def i32(x):
         return jnp.asarray(x, jnp.int32)
@@ -401,16 +533,19 @@ def _paged_attend(q, k_planes, v_planes, plane, block_tables, context_lens,
             num_scalar_prefetch=8, grid=(1,), in_specs=in_specs,
             out_specs=whole(r, h, hd),
             scratch_shapes=[
-                pltpu.VMEM((2, pages * bs * hkv, hd), k_planes.dtype),
-                pltpu.VMEM((2, pages * bs * hkv, hd), v_planes.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((2, pages * bs * hkv, hd), p.dtype)
+                for p in planes] + [
+                pltpu.SemaphoreType.DMA((len(planes), 2)),
                 pltpu.VMEM((r, h, LANES), jnp.float32),   # running max
                 pltpu.VMEM((r, h, LANES), jnp.float32),   # denominator
                 pltpu.VMEM((r, h, hd), jnp.float32),      # accumulator
             ]),
         out_shape=jax.ShapeDtypeStruct((r, h, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+            dimension_semantics=("arbitrary",),
+            # (kanana's 64 slots of 32 heads over 640-wide rows: 21 MiB)
+            vmem_limit_bytes=(None if vmem <= _SCOPED_VMEM * 3 // 4
+                              else vmem * 5 // 4)),
         interpret=interpret,
         name="paged_pool_attend",
     )(walk.slot, walk.col, walk.n, walk.count,

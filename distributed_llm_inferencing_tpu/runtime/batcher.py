@@ -1457,7 +1457,7 @@ class ContinuousBatcher:
         ``batcher.decode_chunk`` span carries it as ``pool_kernel``."""
         if self._pool_kernel is None:
             self._pool_kernel = self.mesh_spec.pp == 1 and bool(
-                transformer._pool_kernel(self.params, self.cfg, self.paged))
+                transformer._pool_kernel(self.cfg, self.paged))
         return self._pool_kernel
 
     def _hist_deltas(self) -> list:

@@ -146,80 +146,122 @@ def test_paged_flash_decode_sliding_window():
 # ---- paged_attend: the decode chunk's pool kernel vs the two-segment ---
 # ---- attend over gathered K and V ---------------------------------------
 
+# K and V planes of 8 or 16 heads of 128, with and without a window; then
+# one plane of shared rows (a latent pool: hkv 1, no window), 128 and 640
+# wide, the query narrower than the row as MLA's is
+_PAGED_SHAPES = [
+    (g, hkv, 128, window) for window in (None, 9)
+    for g, hkv in ((1, 8), (4, 8), (1, 16), (4, 16))
+] + [(4, 1, 128, None), (32, 1, 640, None)]
+
+
 @pytest.mark.parametrize("side_rows", [1, 8])
-@pytest.mark.parametrize("window", [None, 9])
-@pytest.mark.parametrize("g,hkv", [(1, 8), (4, 8), (1, 16), (4, 16)])
+@pytest.mark.parametrize("g,hkv,hd,window", _PAGED_SHAPES)
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
 def test_paged_attend_matches_two_segment_attend(monkeypatch, dtype, g, hkv,
-                                                 window, side_rows):
+                                                 hd, window, side_rows):
     """The kernel, interpreted, against ops/attention.attend over (the
     gathered pool, the chunk's side rows): a plane of a stack taken by a
-    traced index (a looped model's u * L + l), ragged lengths with a dead
+    traced index (a looped model's u * L + l) and by a constant one
+    (layers held one by one), ragged lengths with a dead
     slot (length 0 and, apart from it, a slot that is not live), a length
     on a block boundary and one at the table's end, a window shorter
     than a context, every pass t of the side rows. Work items of four
     pages in steps of two and tail steps of one, so a slot's walk takes
-    several items and ends on a short one, in steps of both widths."""
+    several items and ends on a short one, in steps of both widths.
+    With one K/V head the plane is a latent pool's: its rows are K and V
+    at once (handed in as both), zeros past the query's width, and the
+    reference is attend with the rows' own columns as K and V."""
     from distributed_llm_inferencing_tpu.ops import attention
     from distributed_llm_inferencing_tpu.ops.pallas import paged_attention
     from distributed_llm_inferencing_tpu.ops.paged_kvcache import gather_seq
     dt = jnp.dtype(dtype)
     rng = np.random.default_rng(g * 100 + hkv + side_rows)
-    planes, nb, bs, mb, hd = 3, 48, 4, 7, 128
+    shared = hkv == 1
+    planes, nb, bs, mb = (2, 20, 4, 4) if shared else (3, 48, 4, 7)
     h = g * hkv
-    lens = np.asarray([0, 13, 8, mb * bs, 5, 17], np.int32)
-    live = np.asarray([0, 1, 1, 1, 0, 1], bool)
+    qw = hd - 24 if shared else hd
+    lens = np.asarray([0, 13, 8, mb * bs, 5, 17][:4 if shared else 6],
+                      np.int32)
+    live = np.asarray([0, 1, 1, 1, 0, 1][:len(lens)], bool)
+    if shared:     # the empty slot first, then a dead one that holds rows
+        lens, live = lens[[0, 2, 1, 3]], np.asarray([1, 0, 1, 1], bool)
     r = len(lens)
-    k_planes, v_planes = (_rand(rng, planes, nb, bs, hkv, hd).astype(dt)
-                          for _ in range(2))
+
+    def rows(*lead):
+        x = _rand(rng, *lead, hkv, hd)
+        return (x * (jnp.arange(hd) < qw)).astype(dt)
+    k_planes = rows(planes, nb, bs)
+    v_planes = k_planes if shared else rows(planes, nb, bs)
     bt = jnp.asarray(rng.permutation(np.arange(1, nb))[:r * mb]
                      .reshape(r, mb).astype(np.int32))
-    side_k, side_v = (_rand(rng, r, side_rows, hkv, hd).astype(dt)
-                      for _ in range(2))
-    q = _rand(rng, r, 1, h, hd).astype(dt)
+    side_k = rows(r, side_rows)
+    side_v = side_k if shared else rows(r, side_rows)
+    q = _rand(rng, r, 1, h, qw).astype(dt)
     cl = jnp.asarray(lens)
     # (pages a tail step, a step, an item) = (1, 2, 4)
-    page_rows = bs * hkv
-    monkeypatch.setattr(paged_attention, "_TAIL_ROWS", page_rows)
-    monkeypatch.setattr(paged_attention, "_STEP_ROWS", 2 * page_rows)
-    monkeypatch.setattr(paged_attention, "_STEP_BYTES",
-                        4 * page_rows * hd * dt.itemsize)
-    walk = paged_attention.pool_walk(
-        cl, jnp.asarray(live), k_planes, mb, sliding_window=window)
-    assert int(walk.count[0]) == sum(
+    n_planes = 1 if shared else 2
+    page = n_planes * bs * hkv * hd * dt.itemsize
+    monkeypatch.setattr(paged_attention, "_TAIL_BYTES", page)
+    monkeypatch.setattr(paged_attention, "_STEP_BYTES", 2 * page)
+    monkeypatch.setattr(paged_attention, "_ITEM_BYTES", 4 * page)
+    # every slot's state as one value, and (the wide latent rows) a
+    # slot at a time
+    monkeypatch.setattr(paged_attention, "_STATE_AT_ONCE", 64 * 1024)
+    # an item's 4 pages started and awaited a page a loop iteration, or
+    # (a shared plane, 16 heads) in groups of 3 + 1 and in bulk
+    monkeypatch.setattr(paged_attention, "_LOOP_PAGES",
+                        2 if shared or hkv == 16 else 4)
+    monkeypatch.setattr(paged_attention, "_GROUP", 3)
+    items = sum(
         -(-(-(-n // bs) - (max(n - window + 1, 0) // bs if window else 0))
            // 4) for n, a in zip(lens, live) if a)
 
-    @jax.jit
     def both(plane, t):
+        # (the walk inside the program: made eagerly, its dozen small
+        # operations each compile, a second of every case)
+        walk = paged_attention.pool_walk(
+            cl, jnp.asarray(live), k_planes, mb, sliding_window=window,
+            n_planes=n_planes)
         out = paged_attention.paged_attend(
             q, k_planes, v_planes, plane, bt, cl, cl + t, walk,
-            (side_k, side_v, t), sliding_window=window, interpret=True)
+            (side_k, side_v, t), sliding_window=window, scale=0.11,
+            interpret=True)
         pool_pos = jnp.broadcast_to(jnp.arange(mb * bs), (r, mb * bs))
         side_pos = cl[:, None] + jnp.arange(side_rows)[None, :]
         ref = attention.attend(
-            q, (gather_seq(k_planes, bt, plane), side_k),
-            (gather_seq(v_planes, bt, plane), side_v), (cl + t)[:, None],
-            (pool_pos, side_pos),
+            q, (gather_seq(k_planes, bt, plane)[..., :qw],
+                side_k[..., :qw]),
+            (gather_seq(v_planes, bt, plane)[..., :qw], side_v[..., :qw]),
+            (cl + t)[:, None], (pool_pos, side_pos),
             (pool_pos < cl[:, None],
              jnp.broadcast_to(jnp.arange(side_rows) <= t, (r, side_rows))),
-            sliding_window=window)
-        return out, ref
+            sliding_window=window, scale=0.11)
+        return out, ref, walk.count[0]
 
     tol = 2e-5 if dtype == "float32" else 1e-2
-    for plane, t in ((2, 0), (1, side_rows - 1)):
-        out, ref = both(jnp.int32(plane), jnp.int32(t))
+    # one program a case: the plane's index traced (a scanned stack) or,
+    # for a shared plane with one side row, a constant of the trace
+    # (layers held one by one)
+    constant = shared and side_rows == 1
+    run = jax.jit(both, static_argnums=0 if constant else ())
+    for plane, t in ((planes - 1, 0), (planes - 1 if constant else 1,
+                                       side_rows - 1)):
+        out, ref, count = run(plane if constant else jnp.int32(plane),
+                              jnp.int32(t))
+        assert int(count) == items
         out, ref = (np.asarray(x, np.float32) for x in (out, ref))
         # a slot the walk leaves out reads its side rows alone: what the
         # chunk never emits; the live ones are the comparison
-        np.testing.assert_allclose(out[live], ref[live], rtol=tol, atol=tol)
-        assert np.isfinite(out).all()
+        np.testing.assert_allclose(out[live][..., :qw], ref[live],
+                                   rtol=tol, atol=tol)
+        assert np.isfinite(out).all() and not out[..., qw:].any()
 
 
-def _serve(cfg, prompts, new=10):
+def _serve(cfg, params, prompts, new=10):
     from distributed_llm_inferencing_tpu.runtime.batcher import (
         ContinuousBatcher)
-    b = ContinuousBatcher(cfg, None, seed=0, slots=4, num_blocks=64,
+    b = ContinuousBatcher(cfg, params, seed=0, slots=4, num_blocks=64,
                           block_size=4, max_seq=64, prefill_chunk=4,
                           decode_chunk_cap=8, kv_host_mb=0)
     greedy = SamplingParams.greedy()
@@ -245,20 +287,22 @@ _KERNEL_HEADS = dict(num_heads=8, num_kv_heads=8, head_dim=128)
 @pytest.mark.parametrize("model,shape,kernel", [
     ("tiny-llama", dict(_KERNEL_HEADS, num_heads=16), True),   # G = 2
     ("tiny-llama", dict(_KERNEL_HEADS, sliding_window=6), True),
-    ("tiny-ouro", _KERNEL_HEADS, True),        # planes u * L + l
+    ("tiny-ouro", dict(_KERNEL_HEADS, loop_steps=2), True),   # planes u * L + l
     ("tiny-llama", {}, False),                 # 4 heads of 8: not its shape
-    ("tiny-kanana", {}, False),                # a latent plane
+    # a latent plane, its 40-wide rows stored 128 wide; 4 layers held 1 by 1
+    ("tiny-kanana", {}, True),
     ("tiny-afmoe", {}, False),                 # windows a layer, held 1 by 1
 ])
 def test_batcher_pool_kernel_where_the_shape_allows(monkeypatch, model,
                                                     shape, kernel):
     """The batcher with its kernel pin interpreted (on a one-device TPU
-    it pins "pallas"): a dense and a looped model whose pool the kernel
-    reads as it lies emit the XLA form's greedy tokens, every decode pass
-    counted in ``batcher_pool_kernel_passes``; a pool of another shape, a
-    latent one and a windowed MoE model's keep the XLA form and count
-    none. The in-loop gather is the XLA form compared with (the chip's:
-    toy pools are otherwise pre-gathered)."""
+    it pins "pallas"): a dense, a looped and an MLA model (a latent
+    pool, MoE layers held one by one) whose pool the kernel reads as it
+    lies emit the XLA form's greedy tokens, every decode pass counted in
+    ``batcher_pool_kernel_passes``; a pool of another shape and a
+    windowed MoE model's keep the XLA form and count none. The in-loop
+    gather is the XLA form compared with (the chip's: toy pools are
+    otherwise pre-gathered)."""
     from distributed_llm_inferencing_tpu.models import transformer
     from distributed_llm_inferencing_tpu.runtime import batcher
     monkeypatch.setattr(transformer, "_PREGATHER_MAX_BYTES", 0)
@@ -269,13 +313,15 @@ def test_batcher_pool_kernel_where_the_shape_allows(monkeypatch, model,
                for n in (9, 5, 13)]
     monkeypatch.setattr(batcher, "_expert_backend",
                         lambda *a, **k: "pallas_interpret")
-    toks, passes, weight_passes, attr = _serve(cfg, prompts)
+    # (one set of weights for both runs: drawing them is seconds here)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    toks, passes, weight_passes, attr = _serve(cfg, params, prompts)
     assert weight_passes > 0 and attr == int(kernel)
     assert passes == (weight_passes if kernel else 0)
     if kernel:
         monkeypatch.setattr(batcher, "_expert_backend",
                             lambda *a, **k: "xla")
-        assert _serve(cfg, prompts)[:2] == (toks, 0)
+        assert _serve(cfg, params, prompts)[:2] == (toks, 0)
 
 
 # ---- fused_decode_step: dequant-GEMV -> RoPE -> paged attention -------

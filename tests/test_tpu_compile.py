@@ -126,6 +126,34 @@ def test_paged_flash_decode_compiles(compile_on_chip):
         ((SLOTS,), jnp.int32))
 
 
+@pytest.mark.parametrize("side_rows", [1, 2, 4, 8])
+def test_paged_attend_compiles_over_a_latent_plane(compile_on_chip,
+                                                   side_rows):
+    """ops/pallas/paged_attention.py at the kanana cell's decode shape:
+    64 slots of 32 heads over ONE plane of shared 640-wide rows (K and V
+    at once), 10,241 blocks of 16, block tables of 160 columns, the
+    query 576 wide, and the side rows of each of the cell's chunk sizes.
+    A chunk of one pass hands the kernel ONE side row a slot: Mosaic
+    refuses a bf16 product with a one-row operand ('vector.broadcast'
+    ... same element type), which no interpreted test shows (PERF.md
+    section 6, PR 42). The call asks for its own VMEM (21 MiB: the
+    whole-block q, output and softmax state)."""
+    from distributed_llm_inferencing_tpu.ops.pallas import paged_attention
+    slots, heads, mb = 64, 32, 160
+
+    def attend(q, rows, bt, cl, side, plane, t):
+        walk = paged_attention.pool_walk(cl, cl > 0, rows, mb, n_planes=1)
+        return paged_attention.paged_attend(
+            q, rows, rows, plane, bt, cl, cl + t, walk, (side, side, t),
+            scale=192 ** -0.5)
+
+    compile_on_chip(
+        attend, ((slots, 1, heads, 576), BF16),
+        ((7, 10241, BS, 1, 640), BF16), ((slots, mb), jnp.int32),
+        ((slots,), jnp.int32), ((slots, side_rows, 1, 640), BF16),
+        ((), jnp.int32), ((), jnp.int32))
+
+
 def test_ssm_state_step_writes_the_plane_in_place(compile_on_chip):
     """ops/pallas/ssm_step.py at falcon-h1-34b's cell: 6 layers, 64
     slots and the dummy row, 32 heads of 128 x 256 float32 in 2 groups,
@@ -513,14 +541,18 @@ def test_no_serving_program_copies_the_pool(compile_on_chip, model, program):
     and read back (20.4 of Ouro's 56 ms pass). Ouro's chunk's transient
     falls from 1.23 GiB to 1.13 by those copies and no further: the rest
     is `copy.111`-`113`, the q, k and v weights `bf16[48,2048,2048]`
-    re-laid out once a program (ROADMAP S10). kanana's and trinity's chunks and
-    every admit program have no such call: their traces are the
-    parent's (`transformer._pool_kernel` says None before anything else
-    is traced differently)."""
+    re-laid out once a program (ROADMAP S10). Since PR 42 kanana's chunk
+    holds it too, seven call sites (the dense layer's scan of one and six
+    layers held one by one) over the latent plane as it is stored,
+    `bf16[7,10241,16,1,640]`: no `bf16[64,2560,1,640]` (or 576) of
+    gathered rows, no copy of the plane. trinity's chunk and every admit
+    program have no such call: their traces are the parent's
+    (`transformer._pool_kernel` says None before anything else is
+    traced differently)."""
     cfg, slots, bs, blocks, mb, (t, pb, wave), kept = _cells()[model]
     held = cfg.is_moe
     kept = kept[program != "admit"]
-    pool_kernel = program != "admit" and model in ("mistral", "ouro")
+    pool_kernel = program != "admit" and model != "trinity"
     if program == "admit":
         text = _admit_text(compile_on_chip, cfg, t, pb, wave, bs, blocks,
                            kernel=held, held=held)
@@ -537,8 +569,10 @@ def test_no_serving_program_copies_the_pool(compile_on_chip, model, program):
                     if " conditional(" in ln and "/sample/" not in ln]
         assert not switches, f"the ladder's switch is built: {switches[:2]}"
         rungs = "|".join(str(m * bs) for m in _pool_ladder(mb))
+        heads, width = ((1, r"\d+") if cfg.mla_latent_cache
+                        else (cfg.num_kv_heads, cfg.head_dim))
         made = re.findall(rf"= (bf16\[{slots},(?:{rungs}),"
-                          rf"{cfg.num_kv_heads},{cfg.head_dim}\])", text)
+                          rf"{heads},{width}\])", text)
         assert not made, f"a rung of K or V is materialized: {made[:4]}"
         if model == "ouro":
             assert chunk.memory_analysis().temp_size_in_bytes \
