@@ -364,9 +364,9 @@ class ModelConfig:
     # _pool_kernel). "xla" (the in-loop gather as far as _pool_ladder's
     # rung, everywhere) | "pallas" (a one-device TPU program:
     # ops/pallas/paged_attention.py where the pool's shape is one it
-    # reads as it lies: K and V planes of 8 or more heads of whole
-    # lanes, one head's, a latent pool's one plane; scanned layers or
-    # layers held one by one) | "pallas_interpret" (tests). Not a serving
+    # reads as it lies: K and V planes whose heads of whole lanes fill
+    # a tile's 8 sublanes or divide them, a latent pool's one plane;
+    # scanned layers or layers held one by one) | "pallas_interpret" (tests). Not a serving
     # option: the batcher overwrites it. A model with state layers takes
     # its decode chunk's one-step state update by the same pin
     # (transformer._ssm_kernel, ops/pallas/ssm_step.py).

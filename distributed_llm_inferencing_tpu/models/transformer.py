@@ -1398,8 +1398,8 @@ def _pool_ladder(mb: int, scanned: bool = True):
     is a branch of one lax.switch in the layer body, not a program. This
     is the XLA form of the pool's read: where _pool_kernel takes the
     Pallas kernel (which stops at each slot's own length: mistral-7b,
-    Ouro-2.6B and kanana among the benchmark's cells) no ladder and no
-    switch are built.
+    Ouro-2.6B, kanana and falcon-h1 among the benchmark's cells, so none
+    of them runs a rung) no ladder and no switch are built.
     A conditional takes its operands as buffers: they are the stacked
     pool as it lies and the layer's index, and the branch gathers by
     (layer, block) (_layer_gather); handed the scan's slice of the pool
@@ -1463,20 +1463,22 @@ def _pool_kernel(cfg: ModelConfig, paged):
     only in a one-device TPU program, since GSPMD does not partition a
     Pallas call) where ops/pallas/paged_attention.py computes this
     model's attention and reads this pool as it lies -- planes
-    unquantized and in the compute dtype; K and V heads that fill whole
-    (8, 128) tiles, or one head of whole lanes (MQA; a latent pool's one
-    plane of shared rows, stored lane_width wide, which the kernel takes
-    as K and V at once); no ALiBi, sinks or softcap; a window that is
-    None or one trace-time integer, and none over a latent pool -- else
-    None: the in-loop gather as far as _pool_ladder's rung
-    (_attend_pool_rung). The stack may be scanned (the kernel takes the
+    unquantized and in the compute dtype; K and V heads of whole lanes
+    that fill a tile's 8 sublanes or divide them (4 or 2 heads lie in
+    (4, 128) or (2, 128) tiles, two or four of which are one (8, 128)
+    tile's bytes; one head: MQA, or a latent pool's one plane of shared
+    rows, stored lane_width wide, which the kernel takes as K and V at
+    once), the query heads any multiple of them; no ALiBi, sinks or
+    softcap; a window that is None or one trace-time integer, and none
+    over a latent pool -- else None: the in-loop gather as far as
+    _pool_ladder's rung (_attend_pool_rung). The stack may be scanned (the kernel takes the
     layer's index from the scan) or held layer by layer (the index is a
     constant handed in as an array: one lowering for all of them). In
-    the benchmark's cells the kernel serves mistral-7b, Ouro-2.6B and
-    kanana (its latent MQA plane, 7 layers held one by one); trinity
-    (per-layer windows, 4 K/V heads) and falcon-h1 (4 K/V heads) keep
-    the XLA form, as do int8 pools, meshes, the speculative chunk and
-    the CPU."""
+    the benchmark's cells the kernel serves mistral-7b, Ouro-2.6B,
+    kanana (its latent MQA plane, 7 layers held one by one) and
+    falcon-h1 (20 query heads over 4 K/V heads); trinity (per-layer
+    windows) keeps the XLA form, as do int8 pools, meshes, the
+    speculative chunk and the CPU."""
     if (not cfg.pool_kernel.startswith("pallas") or paged.quantized
             or cfg.attn_windows is not None
             or cfg.position_embedding == "alibi" or cfg.attn_sinks
@@ -1551,9 +1553,9 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
     or widened. The pool is loop-invariant during the chunk, which is
     what makes the split exact. The pool's segment takes one of two
     forms (``_pool_kernel``, from what the trace can see). *The kernel*
-    (a one-device TPU program, unquantized K and V planes of whole
-    (8, 128) tiles or a latent pool's one plane of whole lanes:
-    mistral-7b, Ouro-2.6B, kanana):
+    (a one-device TPU program, unquantized K and V planes whose heads
+    fill or divide a tile's 8 sublanes, or a latent pool's one plane of
+    whole lanes: mistral-7b, Ouro-2.6B, kanana, falcon-h1):
     ops/pallas/paged_attention.paged_attend reads each live slot's pages
     where the pool lies, by (layer, block-table entry), as far as that
     slot's own context, and keeps both segments' softmax inside the

@@ -190,8 +190,8 @@ def resolve_backend(requested: str = "auto", n_devices: int = 1,
     they take the Pallas paged kernel by themselves where the batcher's
     ``cfg.pool_kernel`` pin and the pool's shape allow
     (transformer._pool_kernel: mistral-7b, Ouro-2.6B, kanana's latent
-    pool; PERF.md section 6, PRs 40 and 42) and the gather as far as
-    _pool_ladder's rung elsewhere. Explicit "pallas" is
+    pool, falcon-h1's 4 K/V heads; PERF.md section 6, PRs 40, 42 and 43)
+    and the gather as far as _pool_ladder's rung elsewhere. Explicit "pallas" is
     honored: the stepwise chunk, which writes the pool every step.
     """
     requested = os.environ.get("DLI_ATTENTION", requested)

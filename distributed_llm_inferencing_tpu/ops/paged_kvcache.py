@@ -45,10 +45,11 @@ is a cost of its own beside attention (``kv_gather`` in PERF.md section
 5). ops/pallas/paged_attention.py reads the pages where they lie
 instead, each slot as far as its own context: the decode chunks of a
 one-device TPU program take it where the pool's shape allows
-(models/transformer.py _pool_kernel: mistral-7b, Ouro-2.6B and, its
-latent plane's rows fetched once as K and V alike, kanana among the
-benchmark's cells; PERF.md section 6, PRs 40 and 42), everything else
-keeps the gather.
+(models/transformer.py _pool_kernel: mistral-7b, Ouro-2.6B, falcon-h1
+(4 K/V heads: half a tile of heads a position) and, its latent plane's
+rows fetched once as K and V alike, kanana among the benchmark's cells;
+PERF.md section 6, PRs 40, 42 and 43), everything else keeps the
+gather.
 
 The reference framework has no counterpart at any level — its KV cache was
 implicit inside HF ``generate`` (SURVEY.md §2.4).
@@ -336,8 +337,9 @@ def paged_attend_decode(q, cache_k_layer, cache_v_layer, block_tables,
     (ops/attention.resolve_backend): this stepwise entry writes the pool
     on every step and is no serving path; the decode chunks choose the
     kernel themselves (models/transformer.py _pool_kernel: K and V
-    planes of 8 or more heads, a latent pool's one plane), where it
-    was measured at 1.5-4.2 times the gather's speed (PERF.md section 5).
+    planes whose heads fill a tile's sublanes or divide them, a latent
+    pool's one plane), where it was measured at 1.5-6.2 times the
+    gather's speed (PERF.md section 5).
     The gather copies MB*bs positions per slot whatever ``context_lens``
     says, and attention then reads all of them.
 

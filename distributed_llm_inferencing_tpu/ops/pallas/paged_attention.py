@@ -20,13 +20,17 @@ lies and as far as each slot's own context:
   computed, across slots too, so a call has one exposed fetch and not
   one a slot. The list is made once a chunk, from the lengths the pool
   holds for the whole chunk.
-- A page's rows are (position, kv head) pairs, 128 or 256 of them. One
+- A page's rows are (position, kv head) pairs, 128 or 256 of them (64
+  at falcon-h1's 4 heads: an (8, 128) tile of rows is then two
+  positions' heads, where XLA stores (4, 128) tiles of one position's;
+  the bytes and their order are the same, ``supported``). One
   MXU product of all query heads against those rows as they lie,
   ``[H, hd] x [rows, hd]^T``, gives every (query head, kv head) pair; the
   pairs whose kv head is not the query head's own are masked to -inf, so
   their probabilities are exactly 0 and ``p @ V`` over the same rows is
   the grouped-query sum. No head is ever sliced out of a page (a strided
-  sublane read) and G query heads share their kv head's rows at no cost.
+  sublane read) and G query heads share their kv head's rows at no cost,
+  G a power of two or not (falcon-h1: 20 query heads over 4).
 - The mathematics is ops/attention.attend's: bf16 K and V as stored,
   float32 scores, one online softmax in float32, float32 probabilities
   times V in float32 (the probabilities go through the MXU as three bf16
@@ -110,10 +114,17 @@ def _pages(bs: int, hkv: int, hd: int, itemsize: int, mb: int,
 def supported(hkv: int, hd: int, dtype) -> bool:
     """Whether a pool of ``hkv`` heads of ``hd`` in ``dtype`` is one the
     kernel reads as it lies: rows of whole 128-lane tiles, and kv heads
-    that fill a tile's sublanes or one head alone (MQA, a latent pool's
-    shared row: the positions fill them), so that a page is contiguous
-    in HBM and [bs, Hkv, hd] reads as [bs * Hkv, hd] without a copy."""
-    return (hd % LANES == 0 and (hkv % 8 == 0 or hkv == 1)
+    that fill a tile's 8 sublanes (8, 16, ...) or divide them: 4 or 2
+    (XLA stores such planes in (4, 128) or (2, 128) tiles, heads by
+    lanes, and two or four consecutive ones hold one (8, 128) tile's
+    bytes in the same order, bf16's packed row pairs included, since a
+    pair never straddles two of them) or one head alone (MQA, a latent
+    pool's shared row: the positions fill them). A page is then
+    contiguous in HBM and [bs, Hkv, hd] reads as [bs * Hkv, hd] without
+    a copy: a ``bitcast`` in the program's text, which
+    tests/test_tpu_compile.py holds at 4 heads. 3, 6 or 12 heads are
+    padded to a tile by the layout, so their flat view is a copy."""
+    return (hd % LANES == 0 and (hkv % 8 == 0 or 8 % hkv == 0)
             and jnp.dtype(dtype) in (jnp.dtype(jnp.bfloat16),
                                      jnp.dtype(jnp.float32)))
 
@@ -307,7 +318,12 @@ def _kernel(slot_ref, col_ref, n_ref, count_ref, bt_ref, len_ref, qpos_ref,
             return None, jax.lax.broadcasted_iota(jnp.int32, (h, n_rows), 1)
         qh = jax.lax.broadcasted_iota(jnp.int32, (h, n_rows), 0)
         row = jax.lax.broadcasted_iota(jnp.int32, (h, n_rows), 1)
-        return _div(qh, g) == row - _div(row, hkv) * hkv, _div(row, hkv)
+        if g & (g - 1) == 0:
+            return _div(qh, g) == row - _div(row, hkv) * hkv, _div(row, hkv)
+        # a group that is no power of two (falcon-h1: 20 heads over 4),
+        # by products: the VPU has no integer divide
+        first = (row - _div(row, hkv) * hkv) * g
+        return (first <= qh) & (qh < first + g), _div(row, hkv)
 
     def both(own, mask):
         return mask if own is None else own & mask
@@ -511,8 +527,8 @@ def _paged_attend(q, k_planes, v_planes, plane, block_tables, context_lens,
         side_k, side_v, t = side
         side_rows = side_k.shape[1]
         # [K, Hkv] -> K * Hkv rows, [bs, Hkv] -> bs * Hkv below: the
-        # same bytes where the heads fill a tile's sublanes (supported),
-        # so a bitcast and not a copy
+        # same bytes where the heads fill a tile's sublanes or divide
+        # them (supported), so a bitcast and not a copy
         rows = (r, side_rows * hkv, hd)
         operands += [s_.reshape(rows) for s_ in (side_k, side_v)
                      if s_ is not None]

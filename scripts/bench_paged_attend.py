@@ -12,23 +12,26 @@ benchmark cells' shapes, in the two forms models/transformer.py has
   they lie.
 
 One timed call is a ``lax.scan`` over every plane of the stacked pool
-(mistral-7b: 32, Ouro-2.6B: 192, kanana at 7 layers: 7), as a decode
-pass makes them, so a call is milliseconds and the dispatch cost is out
-of the number. Shapes: mistral 16 slots x 32 query heads over 8 K/V
-heads, 1025 blocks, 128 block-table columns, window 4096; Ouro 8 slots x
-16 heads, 321 blocks, 40 columns; kanana 64 slots x 32 heads over ONE
-plane of shared rows 640 wide (a latent pool: K and V at once, the
-query 576 wide), 10,241 blocks, 160 columns, its layers held one by one
-(the XLA form's ladder is the full extent alone). Lengths: every slot
-at the table's end; the cells' own ragged draws (mistral
-``decode-sat``: 16 live contexts of 81-768; Ouro ``cot-sat``: 8 of
-249-576; kanana ``reason-sat``: 64 of 65-1600); mistral
+(mistral-7b: 32, Ouro-2.6B: 192, falcon-h1 at 6 layers: 6, kanana at 7
+layers: 7), as a decode pass makes them, so a call is milliseconds and
+the dispatch cost is out of the number. Shapes: mistral 16 slots x 32
+query heads over 8 K/V heads, 1025 blocks, 128 block-table columns,
+window 4096; Ouro 8 slots x 16 heads, 321 blocks, 40 columns; falcon-h1
+at 6 layers (12 planes: K and V) 64 slots x 20 query heads over 4 K/V
+heads (a group of 5, half a tile of heads a position), 4097 blocks, 64
+columns; kanana 64 slots x 32 heads over ONE plane of shared rows 640
+wide (a latent pool: K and V at once, the query 576 wide), 10,241
+blocks, 160 columns, its layers held one by one (the XLA form's ladder
+is the full extent alone). Lengths: every slot at the table's end; the
+cells' own ragged draws (mistral ``decode-sat``: 16 live contexts of
+81-768; Ouro ``cot-sat``: 8 of 249-576; falcon-h1 ``chat-sat``: 64 of
+130-1024; kanana ``reason-sat``: 64 of 65-1600); mistral
 ``chat-steady``: one live slot of 16. Each row gives the time, the live
 K and V bytes (every live slot's context once; a latent row once for
 both) and their share of the HBM peak, and the kernel's largest
 difference from the rung form.
 
-On the chip: ``python scripts/bench_paged_attend.py`` (~3 min; the last
+On the chip: ``python scripts/bench_paged_attend.py`` (~4 min; the last
 stdout line is JSON, the table goes to chiprun_out/microbench.json).
 ``--only kanana`` times one model; ``--sweep step:tail:item,...`` (KiB)
 the kernel alone under other step plans. ``--small`` rehearses the
@@ -184,6 +187,8 @@ def main():
           ("chat-steady", 1, 81, 768)]),
         ("ouro-2.6b", 192, 8, 16, 16, (128, 128), 321, 40, None,
          [("full", 8, 640, 640), ("cot-sat", 8, 249, 576)]),
+        ("falcon-h1-34b-l6", 6, 64, 20, 4, (128, 128), 4097, 64, None,
+         [("full", 64, 1024, 1024), ("chat-sat", 64, 130, 1024)]),
         ("kanana-2-30b-a3b-l7", 7, 64, 32, 1, (640, 576), 10241, 160, None,
          [("full", 64, 2560, 2560), ("reason-sat", 64, 65, 1600)]),
     ]

@@ -148,15 +148,25 @@ def test_paged_flash_decode_sliding_window():
 
 # K and V planes of 8 or 16 heads of 128, with and without a window; then
 # one plane of shared rows (a latent pool: hkv 1, no window), 128 and 640
-# wide, the query narrower than the row as MLA's is
+# wide, the query narrower than the row as MLA's is; each with the side
+# rows of a chunk of 1 and of 8 passes. Then heads that fill half or a
+# quarter of a tile's sublanes (4: falcon-h1's 20 query heads over them, a
+# group that is no power of two, and trinity's 32; 2), the 4 with every
+# chunk size's side rows (4 rows at a chunk of one: half a tile)
 _PAGED_SHAPES = [
-    (g, hkv, 128, window) for window in (None, 9)
-    for g, hkv in ((1, 8), (4, 8), (1, 16), (4, 16))
-] + [(4, 1, 128, None), (32, 1, 640, None)]
+    (g, hkv, hd, window, side_rows)
+    for g, hkv, hd, window in [
+        (g, hkv, 128, window) for window in (None, 9)
+        for g, hkv in ((1, 8), (4, 8), (1, 16), (4, 16))
+    ] + [(4, 1, 128, None), (32, 1, 640, None)]
+    for side_rows in (1, 8)
+] + [(5, 4, 128, window, side_rows) for window in (None, 9)
+     for side_rows in (1, 2, 4, 8)
+] + [(8, 4, 128, None, 1), (8, 4, 128, 9, 2), (8, 4, 128, None, 4),
+     (8, 4, 128, 9, 8), (3, 2, 128, None, 1), (3, 2, 128, 9, 8)]
 
 
-@pytest.mark.parametrize("side_rows", [1, 8])
-@pytest.mark.parametrize("g,hkv,hd,window", _PAGED_SHAPES)
+@pytest.mark.parametrize("g,hkv,hd,window,side_rows", _PAGED_SHAPES)
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
 def test_paged_attend_matches_two_segment_attend(monkeypatch, dtype, g, hkv,
                                                  hd, window, side_rows):
@@ -209,9 +219,9 @@ def test_paged_attend_matches_two_segment_attend(monkeypatch, dtype, g, hkv,
     # slot at a time
     monkeypatch.setattr(paged_attention, "_STATE_AT_ONCE", 64 * 1024)
     # an item's 4 pages started and awaited a page a loop iteration, or
-    # (a shared plane, 16 heads) in groups of 3 + 1 and in bulk
+    # (a shared plane, 4 and 16 heads) in groups of 3 + 1 and in bulk
     monkeypatch.setattr(paged_attention, "_LOOP_PAGES",
-                        2 if shared or hkv == 16 else 4)
+                        2 if shared or hkv in (4, 16) else 4)
     monkeypatch.setattr(paged_attention, "_GROUP", 3)
     items = sum(
         -(-(-(-n // bs) - (max(n - window + 1, 0) // bs if window else 0))
@@ -279,6 +289,21 @@ def _serve(cfg, params, prompts, new=10):
             c["batcher_weight_passes"], span.attrs["pool_kernel"])
 
 
+def test_paged_attend_supported_shapes():
+    """Pools the kernel reads as they lie: K/V heads of whole lanes that
+    fill a tile's 8 sublanes or divide them (falcon-h1's and trinity's
+    4; 2; MQA's and a latent pool's 1), bf16 or float32."""
+    from distributed_llm_inferencing_tpu.ops.pallas.paged_attention import (
+        supported)
+    for hkv in (1, 2, 4, 8, 16):
+        assert supported(hkv, 128, jnp.bfloat16)
+    assert supported(4, 256, jnp.float32) and supported(1, 640, jnp.bfloat16)
+    assert not supported(3, 128, jnp.bfloat16)
+    assert not supported(12, 128, jnp.bfloat16)
+    assert not supported(4, 64, jnp.bfloat16)
+    assert not supported(4, 128, jnp.int8)
+
+
 # heads of the width and count the kernel reads as they lie (supported):
 # one (8, 128) tile of K/V heads a position
 _KERNEL_HEADS = dict(num_heads=8, num_kv_heads=8, head_dim=128)
@@ -292,6 +317,11 @@ _KERNEL_HEADS = dict(num_heads=8, num_kv_heads=8, head_dim=128)
     # a latent plane, its 40-wide rows stored 128 wide; 4 layers held 1 by 1
     ("tiny-kanana", {}, True),
     ("tiny-afmoe", {}, False),                 # windows a layer, held 1 by 1
+    # falcon-h1's heads: 20 over 4 (G = 5), half a tile of K/V heads a
+    # position, beside the state layers' planes in the chunk's carry
+    ("tiny-falcon-h1", dict(num_heads=20, num_kv_heads=4, head_dim=128),
+     True),
+    ("tiny-falcon-h1", {}, False),             # 2 heads of 24: not its shape
 ])
 def test_batcher_pool_kernel_where_the_shape_allows(monkeypatch, model,
                                                     shape, kernel):

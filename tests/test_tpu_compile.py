@@ -154,6 +154,41 @@ def test_paged_attend_compiles_over_a_latent_plane(compile_on_chip,
         ((), jnp.int32), ((), jnp.int32))
 
 
+@pytest.mark.parametrize("side_rows", [1, 2, 4, 8])
+def test_paged_attend_compiles_over_four_head_planes(compile_on_chip,
+                                                     side_rows):
+    """ops/pallas/paged_attention.py at the falcon-h1 cell's decode
+    shape: 64 slots of 20 query heads (2.5 tiles of sublanes, a group of
+    5) over K and V planes of 4 heads, `[6, 4097, 16, 4, 128]` each,
+    block tables of 64 columns, and the side rows of each of the cell's
+    chunk sizes (a chunk of one pass: 4 rows, half a tile). XLA lays
+    4-head planes out in (4, 128) tiles; two of them hold the bytes of
+    one (8, 128) tile in the same order, so the flat view the kernel
+    reads, `[6, 4097, 64, 128]`, must be a `bitcast` of the stored plane
+    and nothing plane-sized may be copied (PERF.md section 6, PR 43)."""
+    from distributed_llm_inferencing_tpu.ops.pallas import paged_attention
+    slots, heads, hkv, mb, planes = 64, 20, 4, 64, (6, 4097, BS, 4, HD)
+    assert paged_attention.supported(hkv, HD, BF16)
+    assert paged_attention._pages(BS, hkv, HD, 2, mb) == (16, 32, 32)
+
+    def attend(q, k, v, bt, cl, side_k, side_v, plane, t):
+        walk = paged_attention.pool_walk(cl, cl > 0, k, mb)
+        return paged_attention.paged_attend(
+            q, k, v, plane, bt, cl, cl + t, walk, (side_k, side_v, t))
+
+    text = compile_on_chip(
+        attend, ((slots, 1, heads, HD), BF16), (planes, BF16),
+        (planes, BF16), ((slots, mb), jnp.int32), ((slots,), jnp.int32),
+        ((slots, side_rows, hkv, HD), BF16),
+        ((slots, side_rows, hkv, HD), BF16), ((), jnp.int32),
+        ((), jnp.int32)).as_text()
+    flat = [ln for ln in text.splitlines()
+            if re.search(r"= bf16\[6,4097,64,128\]", ln)]
+    assert len(flat) == 2 and all(" bitcast(" in ln for ln in flat), flat
+    assert not re.findall(r"= bf16\[6,4097,16,4,128\]\S* "
+                          r"(?!parameter)\S+\(", text)
+
+
 def test_ssm_state_step_writes_the_plane_in_place(compile_on_chip):
     """ops/pallas/ssm_step.py at falcon-h1-34b's cell: 6 layers, 64
     slots and the dummy row, 32 heads of 128 x 256 float32 in 2 groups,
@@ -347,11 +382,12 @@ def test_expert_dispatch_is_a_grouped_matmul(compile_on_chip, model, tokens,
         <= 16 * rows * d + 32 * 2 ** 20
 
 
-def _serving_shapes(cfg, bs, blocks, held=False):
+def _serving_shapes(cfg, bs, blocks, held=False, slots=0):
     """(shape, dtype) trees of a model's parameters and of its pool of
     ``blocks`` + 1 blocks of ``bs`` (the last the reserved one), as
-    a list of planes. ``held``: MoE layers a list of per-layer trees,
-    as the batcher holds them (``_unstack_layers``)."""
+    [a list of planes, the state layers' planes of ``slots`` + 1 rows by
+    field name: empty without cfg.ssm]. ``held``: MoE layers a list of
+    per-layer trees, as the batcher holds them (``_unstack_layers``)."""
     from distributed_llm_inferencing_tpu.models.params import init_params
     from distributed_llm_inferencing_tpu.ops.paged_kvcache import (
         init_paged_cache)
@@ -366,9 +402,11 @@ def _serving_shapes(cfg, bs, blocks, held=False):
                            is_leaf=lambda x: isinstance(x, tuple))
         params["layers"] = [one] * (cfg.num_layers
                                     - cfg.dense_prefix_layers)
-    pool = shapes(jax.eval_shape(
-        lambda: list(init_paged_cache(cfg, blocks + 1, bs).planes())))
-    return params, pool
+    cache = jax.eval_shape(
+        lambda: init_paged_cache(cfg, blocks + 1, bs, slots=slots))
+    state = {} if cfg.ssm is None else {"ssm": cache.ssm, "conv": cache.conv}
+    # (a list: compile_on_chip takes every tuple for a (shape, dtype) leaf)
+    return params, [shapes(list(cache.planes())), shapes(state)]
 
 
 def _decode_chunk(compile_on_chip, cfg, k, slots, bs, blocks, mb,
@@ -381,13 +419,14 @@ def _decode_chunk(compile_on_chip, cfg, k, slots, bs, blocks, mb,
     from distributed_llm_inferencing_tpu.models import transformer
     from distributed_llm_inferencing_tpu.ops.paged_kvcache import (
         PagedKVCache)
-    params, pool = _serving_shapes(cfg, bs, blocks, held)
+    params, pool = _serving_shapes(cfg, bs, blocks, held, slots)
 
     def chunk(params, pool, tokens, bt, ints, floats, ds):
         cl, seeds, steps, tks, budget, eos = ints
         return transformer.paged_decode_chunk(
-            params, cfg, k, tokens, PagedKVCache(*pool), bt, cl, seeds,
-            steps, floats[0], tks, floats[1], ds, budget, eos, blocks)
+            params, cfg, k, tokens, PagedKVCache(*pool[0], **pool[1]), bt,
+            cl, seeds, steps, floats[0], tks, floats[1], ds, budget, eos,
+            blocks)
 
     return compile_on_chip(
         chunk, params, pool, ((slots,), jnp.int32),
@@ -401,7 +440,7 @@ def _decode_chunk_text(*args, **kw):
 
 
 def _admit_text(compile_on_chip, cfg, t, pb, wave, bs, blocks, kernel=True,
-                held=False):
+                held=False, slots=0):
     """_decode_chunk_text's twin for an admit program: the text of
     ``paged_prefill_tail`` over a wave of ``wave`` tails of ``t`` tokens,
     ``pb`` prefix blocks a row, the pool donated as ``_admit_jit``
@@ -409,17 +448,19 @@ def _admit_text(compile_on_chip, cfg, t, pb, wave, bs, blocks, kernel=True,
     from distributed_llm_inferencing_tpu.models import transformer
     from distributed_llm_inferencing_tpu.ops.paged_kvcache import (
         PagedKVCache)
-    params, pool = _serving_shapes(cfg, bs, blocks, held)
+    params, pool = _serving_shapes(cfg, bs, blocks, held, slots)
 
     def admit(params, pool, tokens, tail_blocks, prefix_blocks, lens):
         return transformer.paged_prefill_tail(
             params, cfg, tokens, lens[0], tail_blocks, prefix_blocks,
-            lens[1], PagedKVCache(*pool))
+            lens[1], PagedKVCache(*pool[0], **pool[1]),
+            slots=lens[2] if pool[1] else None)
 
     return compile_on_chip(
         admit, params, pool, ((wave, t), jnp.int32),
         ((wave, t // bs), jnp.int32), ((wave, pb), jnp.int32),
-        ((2, wave), jnp.int32), kernel=kernel, donate=(1,)).as_text()
+        ((2 + bool(pool[1]), wave), jnp.int32), kernel=kernel,
+        donate=(1,)).as_text()
 
 
 def test_decode_chunk_sorts_nothing_vocabulary_sized(compile_on_chip):
@@ -510,11 +551,20 @@ def _cells():
             attn_windows=(2048,) * 4 + (None,),
             rope_layers=(1, 1, 1, 1, 0), **pins),
             64, 16, 12288, 576, (512, 128, 1), (4, 2)),
+        # .../falcon-h1-34b-l6.json: 20 query heads over 4 K/V heads,
+        # the state planes of 65 rows beside the pool. Its admit
+        # programs keep trinity's four copies of a K or V plane (the
+        # wave's write of whole blocks); its decode chunk reads the
+        # (4, 128)-tiled planes by the kernel as they lie: two such
+        # tiles are one (8, 128) tile's bytes, the flat view a bitcast
+        "falcon-h1": (get_config("falcon-h1-34b").replace(
+            num_layers=6, **pins), 64, 16, 4096, 64, (512, 1, 8), (4, 0)),
     }
 
 
 @pytest.mark.parametrize("model,program", [
-    (model, program) for model in ("mistral", "kanana", "trinity", "ouro")
+    (model, program)
+    for model in ("mistral", "kanana", "trinity", "ouro", "falcon-h1")
     for program in ("admit", "decode-chunk-8")
     # a looped model's wave writes a step's tails behind that step, a
     # block at a time (write_blocks(first_plane=)): updates in place,
@@ -545,8 +595,12 @@ def test_no_serving_program_copies_the_pool(compile_on_chip, model, program):
     holds it too, seven call sites (the dense layer's scan of one and six
     layers held one by one) over the latent plane as it is stored,
     `bf16[7,10241,16,1,640]`: no `bf16[64,2560,1,640]` (or 576) of
-    gathered rows, no copy of the plane. trinity's chunk and every admit
-    program have no such call: their traces are the parent's
+    gathered rows, no copy of the plane. Since PR 43 falcon-h1's chunk
+    holds it, one call in the scanned layer body over planes of 4 K/V
+    heads, `bf16[6,4097,16,4,128]`, whose flat view is a bitcast: no
+    `bf16[64,768,4,128]` of a rung (the state planes are the carry's,
+    written in place by `ssm_state_step`). trinity's chunk and every
+    admit program have no such call: their traces are the parent's
     (`transformer._pool_kernel` says None before anything else is
     traced differently)."""
     cfg, slots, bs, blocks, mb, (t, pb, wave), kept = _cells()[model]
@@ -555,7 +609,7 @@ def test_no_serving_program_copies_the_pool(compile_on_chip, model, program):
     pool_kernel = program != "admit" and model != "trinity"
     if program == "admit":
         text = _admit_text(compile_on_chip, cfg, t, pb, wave, bs, blocks,
-                           kernel=held, held=held)
+                           kernel=held, held=held, slots=slots)
     else:
         chunk = _decode_chunk(compile_on_chip, cfg, 8, slots, bs, blocks,
                               mb, kernel=held or pool_kernel, held=held,
@@ -577,7 +631,7 @@ def test_no_serving_program_copies_the_pool(compile_on_chip, model, program):
         if model == "ouro":
             assert chunk.memory_analysis().temp_size_in_bytes \
                 < 1.15 * 2 ** 30
-    _, pool = _serving_shapes(cfg, bs, blocks)
+    _, (pool, _) = _serving_shapes(cfg, bs, blocks)
     made = _pool_sized()(text, [jax.ShapeDtypeStruct(*p) for p in pool])
     writes = [m for m in made if m[1] in ("fusion(scatter)", "scatter")]
     assert len(writes) == len(pool), f"one write a plane: {made}"
