@@ -340,8 +340,10 @@ class ModelConfig:
     # attention matmuls so the HBM read stays int8.
     kv_quant: Optional[str] = None
 
-    # Attention kernel backend: auto | xla | pallas | pallas_interpret
-    # (trace-time static; see ops/attention.py resolve_backend)
+    # The dense cache's attention backend (the single-stream engine's
+    # forward passes): auto | xla | pallas | pallas_interpret
+    # (trace-time static; see ops/attention.py resolve_backend). The
+    # batcher pins "xla" and its paged programs read it nowhere.
     attn_backend: str = "auto"
 
     # Pinned by the engine at init (like attn_backend's resolution): True
@@ -352,9 +354,9 @@ class ModelConfig:
     # view (shard_map) callers keep False: their weights arrive pre-
     # sliced and the kernel is a plain local matmul.
     tp_row_sharded: bool = False
-    # Pinned by the batcher at init, where attn_backend is resolved: the
-    # form of the experts' grouped matmuls where a call holds few rows an
-    # expert (models/transformer.py _expert_stream). "xla"
+    # Pinned by the batcher at init: the form of the experts' grouped
+    # matmuls where a call holds few rows an expert
+    # (models/transformer.py _expert_stream). "xla"
     # (lax.ragged_dot everywhere) | "pallas" (a one-device TPU program:
     # ops/pallas/grouped_matmul.py at decode size) | "pallas_interpret"
     # (tests). Not a serving option: the batcher overwrites it.

@@ -352,20 +352,23 @@ def _alibi(cfg: ModelConfig):
     return alibi_slopes(cfg.num_heads) * cfg.alibi_scale
 
 
-def _cfg_backend(cfg: ModelConfig, n_devices: int = 1, op: str = "dense"):
-    """resolve_backend, then force the XLA formulation for per-layer
-    windows (the pallas flash/paged kernels take static windows only,
+def _cfg_backend(cfg: ModelConfig, n_devices: int = 1):
+    """resolve_backend (the dense cache's flash kernels: forward,
+    attend_prefill, attend_decode), then force the XLA formulation for
+    per-layer windows (the flash kernels take static windows only,
     while the traced ``attn_window`` scalar flows through the XLA masks
     unchanged) and for attention softcapping (the kernels' online
     softmax has no tanh hook).
 
     ``n_devices`` is the device count of the PROGRAM's mesh: the engine
-    and the batcher resolve against ``mesh_spec.num_devices`` and pin the
-    result in ``cfg.attn_backend``, so the forward passes below only read
-    that pin. A bare ``auto`` reaching them is a direct call (tests,
-    dryrun), which is a one-device program — never the process's device
-    count, which on a four-chip host says nothing about this program."""
-    b = resolve_backend(cfg.attn_backend, n_devices, op=op)
+    resolves against ``mesh_spec.num_devices`` and pins the result in
+    ``cfg.attn_backend``, so ``forward`` only reads that pin. A bare
+    ``auto`` reaching it is a direct call (tests, dryrun), which is a
+    one-device program — never the process's device count, which on a
+    four-chip host says nothing about this program. The paged paths
+    below (the batcher's) read it nowhere: how a decode chunk reads the
+    pool is ``_pool_kernel``'s choice."""
+    b = resolve_backend(cfg.attn_backend, n_devices)
     if b.startswith("pallas") and (cfg.attn_windows is not None
                                    or cfg.attn_softcap is not None
                                    or cfg.attn_sinks or cfg.mla):
@@ -780,8 +783,8 @@ def _attn_gate(attn_flat, h, lp, cfg: ModelConfig):
 
 
 def _block_body(x, lp, cfg: ModelConfig, q_positions, attend_write,
-                mla_latent_attend=None, fused_q_attend=None,
-                lora_ids=None, valid=None, moe_stats=False, ssm_mix=None):
+                mla_latent_attend=None, lora_ids=None, valid=None,
+                moe_stats=False, ssm_mix=None):
     """One transformer block: norm → QKV (+RoPE) → attend → norm → MLP/MoE.
 
     The single definition of the block structure, shared by the dense path
@@ -789,13 +792,6 @@ def _block_body(x, lp, cfg: ModelConfig, q_positions, attend_write,
     paged_prefill_tail) so the three can never diverge. ``attend_write(q,
     k, v) -> (attn [B,s,H,hd], cache_out)`` owns the regime-specific part:
     cache update + attention formulation.
-
-    ``fused_q_attend(h, k, v) -> (attn, cache_out)`` (DLI_FUSED_DECODE,
-    ops/pallas/fused_decode.py): the q projection + RoPE + attention run
-    fused inside the callback's single pallas_call — the block computes
-    ONLY k/v here (their projections feed the cache write, which the
-    kernel reads back). The caller gates eligibility
-    (fused_decode.supported); ineligible configs never reach this arm.
 
     cfg.post_norm flips pre-LN (norm -> sublayer -> residual) to the
     post-LN order opt-350m uses (sublayer -> residual -> norm);
@@ -834,23 +830,6 @@ def _block_body(x, lp, cfg: ModelConfig, q_positions, attend_write,
         vd = cfg.v_head_dim_effective
         attn = _linear(attn.reshape(B, s, cfg.num_heads * vd), lp["o"],
                        row_sharded=cfg.tp_row_sharded)
-        return _block_tail(x, h, attn, cache_out, lp, cfg, **tail)
-    if fused_q_attend is not None:
-        # fused decode arm: project/rotate ONLY k and v (the kernel owns
-        # q end-to-end); eligibility (no qk_norm/clip, full-width
-        # non-interleaved rope) was gated by the caller
-        k = _linear(h, lp["k"]).reshape(B, s, cfg.num_kv_heads,
-                                        cfg.head_dim)
-        v = _linear(h, lp["v"]).reshape(B, s, cfg.num_kv_heads,
-                                        cfg.head_dim)
-        if cfg.position_embedding == "rope":
-            k = apply_rope(k, q_positions, cfg.rope_theta, cfg.rope_pct,
-                           cfg.rope_interleaved,
-                           inv_freq=cfg.rope_inv_freq,
-                           attn_factor=cfg.rope_attn_factor)
-        attn, cache_out = fused_q_attend(h, k, v)
-        attn = _linear(attn.reshape(B, s, cfg.num_heads * cfg.head_dim),
-                       lp["o"], row_sharded=cfg.tp_row_sharded)
         return _block_tail(x, h, attn, cache_out, lp, cfg, **tail)
     if cfg.mla:
         q, k, v = _mla_qkv(h, lp, cfg, q_positions)   # rope applied inside
@@ -1184,55 +1163,15 @@ def paged_decode_step(params, cfg: ModelConfig, tokens, paged,
     """
     from distributed_llm_inferencing_tpu.ops.paged_kvcache import (
         PagedKVCache, paged_attend_decode, write_token)
-    from distributed_llm_inferencing_tpu.ops.pallas import fused_decode
     _no_state_layers(cfg, "paged_decode_step")
-    r = tokens.shape[0]
-    backend = _cfg_backend(cfg)
     q_pos = context_lens[:, None]                       # [R, 1]
     x = embed(params, cfg, tokens[:, None], q_pos)      # [R, 1, D]
     quantized = paged.quantized
-    # Fused dequant-GEMV -> RoPE -> paged flash attention
-    # (ops/pallas/fused_decode.py, DLI_FUSED_DECODE): one pallas_call per
-    # layer replaces the q einsum + rope + attention chain — q never
-    # round-trips HBM. Compiled by Mosaic unless a test asked for
-    # interpret mode (DLI_FUSED_DECODE=interpret); the unfused
-    # formulation below stays bitwise-authoritative everywhere the gate
-    # declines.
-    # the fused kernel owns q end-to-end, so a wave carrying LoRA rows —
-    # explicit ids, or an adapter pack riding the layer tree — must run
-    # the unfused formulation where the q/o deltas have a seam
-    has_lora = (isinstance(params.get("layers"), dict)
-                and "lora" in params["layers"])
-    use_fused = (fused_decode.eligible(cfg, quantized)
-                 and lora_ids is None and not has_lora)
-    fused_interpret = fused_decode.interpret_requested()
-    rope_cos = rope_sin = None
-    if use_fused and cfg.position_embedding == "rope":
-        rope_cos, rope_sin = fused_decode.rope_cos_sin(
-            cfg, context_lens, cfg.head_dim)
 
     def make_body(seg_cfg):
         def body(x, layer_in):
             lp, ck, *rest = layer_in                    # ck: [NB, bs, Hkv, hd]
             cv, scales = (rest[0], rest[1:]) if rest else (None, ())
-
-            if use_fused and fused_decode.supported(seg_cfg, lp["q"]):
-                def fused_q_attend(h, k, v):
-                    with jax.named_scope("kv_write"):
-                        nk = write_token(ck, k[:, 0], block_tables,
-                                         context_lens)
-                        nv = write_token(cv, v[:, 0], block_tables,
-                                         context_lens)
-                    with jax.named_scope("attention"):
-                        attn = fused_decode.fused_decode_step(
-                            h[:, 0], lp["q"], nk, nv, block_tables,
-                            context_lens + 1,
-                            rope_cos=rope_cos, rope_sin=rope_sin,
-                            sliding_window=_layer_window(seg_cfg, lp),
-                            interpret=fused_interpret)
-                    return attn[:, None], (nk, nv)
-                return _block_body(x, lp, seg_cfg, q_pos, None,
-                                   fused_q_attend=fused_q_attend)
 
             if seg_cfg.mla_latent_cache:
                 def mla_latent_attend(h, qp):
@@ -1268,7 +1207,6 @@ def paged_decode_step(params, cfg: ModelConfig, tokens, paged,
                     attn = paged_attend_decode(
                         q, nk, nv, block_tables, context_lens + 1,
                         sliding_window=_layer_window(seg_cfg, lp),
-                        backend=backend,
                         k_scale_layer=nks, v_scale_layer=nvs,
                         alibi=_alibi(seg_cfg), softcap=seg_cfg.attn_softcap,
                         sinks=_sinks(seg_cfg, lp))
@@ -1281,7 +1219,6 @@ def paged_decode_step(params, cfg: ModelConfig, tokens, paged,
                 attn = paged_attend_decode(
                     q, nk, nv, block_tables, context_lens + 1,
                     sliding_window=_layer_window(seg_cfg, lp),
-                    backend=backend,
                     alibi=_alibi(seg_cfg), softcap=seg_cfg.attn_softcap,
                     sinks=_sinks(seg_cfg, lp))
                 return attn, (nk, nv)
@@ -1298,13 +1235,12 @@ def paged_decode_step(params, cfg: ModelConfig, tokens, paged,
 
 def _no_state_layers(cfg: ModelConfig, what: str):
     """The paths that carry no recurrent state refuse a model that has
-    one (cfg.ssm) by name; the side-buffer decode chunk and the wave
-    admission carry it."""
+    one (cfg.ssm) by name; the decode chunk and the wave admission
+    carry it."""
     if cfg.ssm is not None:
         raise NotImplementedError(
             f"{cfg.name}: {what} carries no state-space state (cfg.ssm); "
-            "paged_prefill_tail and paged_decode_chunk's side-buffer "
-            "form do")
+            "paged_prefill_tail and paged_decode_chunk do")
 
 
 # Cap for materializing the whole chunk's pool gather [L, R, P, Hkv, hd]
@@ -1602,17 +1538,6 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
     pass (loop_layer_stack): the side buffers and the pool have a plane
     a (step, layer) pair, a layer's index into both is its pair's.
     """
-    from distributed_llm_inferencing_tpu.ops.pallas import fused_decode
-    if (_cfg_backend(cfg, op="paged").startswith("pallas")
-            or fused_decode.eligible(cfg, paged.quantized)):
-        # explicit pallas request (A/B and debug escape hatch) or the
-        # fused decode kernel (DLI_FUSED_DECODE): the side-buffer
-        # formulation below bypasses the paged/fused kernels, so run the
-        # stepwise write+attend loop that dispatches to them instead
-        return _paged_decode_chunk_stepwise(
-            params, cfg, k, tokens, paged, block_tables, context_lens,
-            seeds, steps0, temps, tks, tps, ds, budget, eos_ids,
-            dummy_block, lora_ids=lora_ids)
     return decode_chunk_with_logits(
         params, cfg, k, tokens, paged, block_tables, context_lens, seeds,
         steps0, temps, tks, tps, ds, budget, eos_ids, dummy_block,
@@ -1623,8 +1548,8 @@ def decode_chunk_with_logits(params, cfg: ModelConfig, k: int, tokens, paged,
                              block_tables, context_lens, seeds, steps0,
                              temps, tks, tps, ds, budget, eos_ids,
                              dummy_block: int, lora_ids=None):
-    """paged_decode_chunk's side-buffer formulation: what it returns and,
-    last, the passes' logits [K, R, V] float32. No serving program takes
+    """paged_decode_chunk: what it returns and, last, the passes' logits
+    [K, R, V] float32. No serving program takes
     the logits (a jit drops the output nothing reads, so
     paged_decode_chunk's program has none); a comparison with a plain
     reference reads them where the pool is too large to copy for a
@@ -1833,44 +1758,6 @@ def decode_chunk_with_logits(params, cfg: ModelConfig, k: int, tokens, paged,
                     write_rows(plane, jnp.swapaxes(sd, 1, 2), blk, off)
                     for plane, sd in zip(paged.planes(), side))),
                 logits)
-
-
-def _paged_decode_chunk_stepwise(params, cfg: ModelConfig, k: int, tokens,
-                                 paged, block_tables, context_lens, seeds,
-                                 steps0, temps, tks, tps, ds, budget,
-                                 eos_ids, dummy_block: int, lora_ids=None):
-    """K decode steps via per-step ``paged_decode_step`` (pool writes and
-    the backend-dispatched paged attention every step). Semantically
-    identical to the side-buffer formulation in ``paged_decode_chunk``;
-    used when an explicit pallas backend is requested so the paged kernel
-    actually runs."""
-    from distributed_llm_inferencing_tpu.ops.sampling import sample_batch
-    _no_state_layers(cfg, "the stepwise decode chunk")
-
-    def body(carry, t):
-        cur, paged, cl, alive = carry
-        bt_eff = jnp.where(alive[:, None], block_tables, dummy_block)
-        cl_eff = jnp.where(alive, cl, 0)
-        logits, paged = paged_decode_step(params, cfg, cur, paged, bt_eff,
-                                          cl_eff, lora_ids=lora_ids)
-        with jax.named_scope("sample"):
-            nxt = sample_batch(logits, seeds, steps0 + t, temps, tks, tps,
-                               ds)
-        is_eos = alive & (eos_ids >= 0) & (nxt == eos_ids)
-        emit = alive & ~is_eos
-        new_cl = cl + alive.astype(cl.dtype)
-        new_alive = emit & (t + 1 < budget)
-        return (nxt, paged, new_cl, new_alive), (nxt, emit)
-
-    (_, paged, _, _), (toks, emits) = jax.lax.scan(
-        body, (tokens, paged, context_lens, budget > 0),
-        jnp.arange(k, dtype=jnp.int32))
-    # this path counts no expert loads (MOE_STATS stays zero); the
-    # extent is reported as the whole block table's (the gather's and
-    # the fused kernel's; the paged kernel stops at each slot's length)
-    whole = jnp.int32(block_tables.shape[1] * paged.block_size)
-    return (toks, emits, jnp.zeros((len(MOE_STATS),), jnp.int32), whole,
-            whole, paged)
 
 
 def paged_speculative_chunk(params, cfg: ModelConfig, k: int, gamma: int,

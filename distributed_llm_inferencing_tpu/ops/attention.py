@@ -13,8 +13,9 @@ are two backends behind one dispatch:
   head-expanded f32 form it replaced wrote K and V back to HBM as
   f32[B,S,Hkv,G,hd] and was 87 % of the serving decode pass; PERF.md
   section 6, PR 25, has the record. This is what the continuous batcher
-  runs, the reference implementation, and the path on non-TPU hosts and
-  multi-device meshes.
+  runs beside its pool kernel (ops/pallas/paged_attention.py, chosen by
+  transformer._pool_kernel, not by this dispatch), the reference
+  implementation, and the path on non-TPU hosts and multi-device meshes.
 - **pallas** (ops/pallas/flash_attention.py): hand-tiled online-softmax
   kernels for the two hot regimes (prefill flash attention, cached flash
   decode).
@@ -175,29 +176,28 @@ def attend(
 # Backend dispatch (trace-time static)
 # ----------------------------------------------------------------------
 
-def resolve_backend(requested: str = "auto", n_devices: int = 1,
-                    op: str = "dense") -> str:
-    """'auto' | 'xla' | 'pallas' | 'pallas_interpret' -> concrete backend.
+def resolve_backend(requested: str = "auto", n_devices: int = 1) -> str:
+    """'auto' | 'xla' | 'pallas' | 'pallas_interpret' -> concrete backend
+    of the dense cache's attention (attend_prefill, attend_decode: the
+    single-stream engine's forward passes).
 
     ``DLI_ATTENTION`` overrides (test/debug escape hatch). Pallas kernels
     are single-program kernels, so auto only picks them when the enclosing
     jit program spans one device.
 
-    ``op="paged"`` (the continuous batcher's block-table decode): auto
-    resolves to xla, the side-buffer decode chunks of
-    models/transformer.py and ops/paged_kvcache.paged_attend_decode's
-    gather. This backend does not say how those chunks read the pool:
-    they take the Pallas paged kernel by themselves where the batcher's
-    ``cfg.pool_kernel`` pin and the pool's shape allow
-    (transformer._pool_kernel: mistral-7b, Ouro-2.6B, kanana's latent
-    pool, falcon-h1's 4 K/V heads; PERF.md section 6, PRs 40, 42 and 43)
-    and the gather as far as _pool_ladder's rung elsewhere. Explicit "pallas" is
-    honored: the stepwise chunk, which writes the pool every step.
+    The continuous batcher's paged programs do not come here: it pins
+    ``attn_backend="xla"`` as a constant, and its decode chunks choose
+    how they read the pool themselves, from what the trace sees
+    (transformer._pool_kernel: the Pallas paged kernel where the
+    batcher's ``cfg.pool_kernel`` pin and the pool's shape allow, i.e.
+    mistral-7b, Ouro-2.6B, kanana's latent pool, falcon-h1's 4 K/V
+    heads; PERF.md section 6, PRs 40, 42 and 43; the gather as far as
+    _pool_ladder's rung elsewhere).
     """
     requested = os.environ.get("DLI_ATTENTION", requested)
     if requested in ("xla", "pallas", "pallas_interpret"):
         return requested
-    if op != "paged" and jax.default_backend() == "tpu" and n_devices == 1:
+    if jax.default_backend() == "tpu" and n_devices == 1:
         return "pallas"
     return "xla"
 

@@ -321,41 +321,29 @@ def window_read(window: int, bs: int, block_tables, horizon):
 def paged_attend_decode(q, cache_k_layer, cache_v_layer, block_tables,
                         context_lens,
                         sliding_window: Optional[int] = None,
-                        backend: str = "xla",
                         k_scale_layer=None, v_scale_layer=None,
                         alibi=None, softcap: Optional[float] = None, sinks=None,
                         scale: Optional[float] = None):
-    """Single-token attention over the paged cache.
+    """Single-token attention over the paged cache, the plain gather
+    formulation.
 
     q: [R, 1, H, hd]; context_lens: [R] — filled slots INCLUDING the token
     just written (the query sits at context_lens - 1).
 
-    backend "pallas" routes to the block-table-driven kernel
-    (ops/pallas/paged_attention.py paged_flash_decode) which skips the
-    gather materialization below and stops at each slot's own length.
-    "auto" resolves to the XLA gather formulation
-    (ops/attention.resolve_backend): this stepwise entry writes the pool
-    on every step and is no serving path; the decode chunks choose the
-    kernel themselves (models/transformer.py _pool_kernel: K and V
-    planes whose heads fill a tile's sublanes or divide them, a latent
-    pool's one plane), where it was measured at 1.5-6.2 times the
-    gather's speed (PERF.md section 5).
     The gather copies MB*bs positions per slot whatever ``context_lens``
-    says, and attention then reads all of them.
+    says, and attention then reads all of them. This is
+    transformer.paged_decode_step's attention: the reference form of a
+    decode pass (tests, benchmarks/chip/compare_reference*.py), which
+    writes the pool on every step, and no serving path. The decode
+    chunks read the pool in their own way (models/transformer.py
+    _pool_kernel: the Pallas paged kernel over K and V planes whose
+    heads fill a tile's sublanes or divide them, or a latent pool's one
+    plane, where it was measured at 1.5-6.2 times the gather's speed,
+    PERF.md section 5; the in-loop gather elsewhere).
 
-    int8 caches (``k_scale_layer``/``v_scale_layer`` present) always take
-    the gather formulation — the dequant fuses into the gather/matmul;
-    the pallas kernel has no int8 rule.
+    int8 caches (``k_scale_layer``/``v_scale_layer`` present): the
+    dequant fuses into the gather/matmul.
     """
-    if backend.startswith("pallas") and k_scale_layer is None \
-            and alibi is None and sinks is None:
-        from distributed_llm_inferencing_tpu.ops.pallas.paged_attention import (
-            paged_flash_decode)
-        with jax.named_scope("attention"):
-            return paged_flash_decode(
-                q, cache_k_layer, cache_v_layer, block_tables, context_lens,
-                sliding_window=sliding_window,
-                interpret=(backend == "pallas_interpret"))
     r, mb = block_tables.shape
     bs = cache_k_layer.shape[1]
     with jax.named_scope("kv_gather"):

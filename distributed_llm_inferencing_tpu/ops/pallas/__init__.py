@@ -10,12 +10,11 @@ two attention regimes —
 - ``flash_decode``: single-token cached attention streaming the KV cache
   from HBM (bandwidth-bound)
 
-plus the fused decode kernels:
+(the single-stream engine's dense cache: ``attn_backend`` /
+``DLI_ATTENTION``, ops/attention.py's backend dispatch), plus
 
 - ``quant_matmul.q4_matmul``: nibble-packed int4 dequant-GEMV that
   never materializes unpacked weights in HBM
-- ``fused_decode.fused_decode_step``: dequant-GEMV -> RoPE -> paged
-  flash attention chained in ONE pallas_call (``DLI_FUSED_DECODE``)
 
 and the three kernels the benchmark's cells run, each chosen by the code
 from shapes it can see in a one-device TPU program's decode chunks
@@ -34,8 +33,8 @@ from shapes it can see in a one-device TPU program's decode chunks
   divide them (16, 8, 4, 2, 1), or a latent pool's one plane of shared
   rows taken as K and V at once (the in-loop gather as far as
   _pool_ladder's rung keeps every other pool; the batcher pins
-  ``cfg.pool_kernel``, models/transformer.py:_pool_kernel decides);
-  ``paged_flash_decode`` is the stepwise path's entry on it
+  ``cfg.pool_kernel``, models/transformer.py:_pool_kernel decides:
+  the one place a decode chunk's read of the pool is chosen)
 - ``ssm_step.ssm_step`` (falcon-h1-34b): a Mamba-2 mixer's one-step
   update of the per-slot state plane, a (slot, group) tile read and
   written once through an aliased output (the jax.numpy form, which

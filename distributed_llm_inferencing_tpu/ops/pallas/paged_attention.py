@@ -49,10 +49,8 @@ lies and as far as each slot's own context:
   scoped VMEM (kanana's 64 slots x 32 heads x 640: 21 MiB) asks for
   what it needs and starts and finishes its slots in a loop.
 
-``paged_flash_decode`` is the stepwise path's entry (one layer's pool, no
-side rows: paged_kvcache.paged_attend_decode with an explicit pallas
-backend). The decode chunk calls ``paged_attend`` (models/transformer.py
-``_pool_kernel`` says where).
+The decode chunk calls ``paged_attend``, the one entry
+(models/transformer.py ``_pool_kernel`` says where).
 """
 
 from __future__ import annotations
@@ -138,7 +136,7 @@ class PoolWalk(NamedTuple):
 
 
 def pool_walk(context_lens, live, planes, max_blocks: int, *,
-              sliding_window: Optional[int] = None, q_pos=None,
+              sliding_window: Optional[int] = None,
               n_planes: int = 2) -> PoolWalk:
     """The work items of paged_attend's walk over every live slot's pool
     positions [first, context_lens) in ``planes`` ([..., bs, Hkv, hd]: K
@@ -148,9 +146,8 @@ def pool_walk(context_lens, live, planes, max_blocks: int, *,
     fetches for a pool of this shape (_pages), a slot's items in order,
     slots in order, a slot that is not ``live`` (or holds nothing) none.
     Under a window ``first`` is the first page a
-    query at ``q_pos`` can reach (default ``context_lens``: a chunk's
-    first pass; its later passes see less); the kernel's mask is the
-    exact cut."""
+    query at ``context_lens`` can reach (a chunk's first pass; its later
+    passes see less); the kernel's mask is the exact cut."""
     block_size, hkv, hd = planes.shape[-3:]
     pages = _pages(block_size, hkv, hd, planes.dtype.itemsize,
                    max_blocks, n_planes)[-1]
@@ -158,8 +155,7 @@ def pool_walk(context_lens, live, planes, max_blocks: int, *,
     cl = jnp.where(live, context_lens, 0).astype(jnp.int32)
     first = jnp.zeros_like(cl)
     if sliding_window is not None:
-        q_pos = cl if q_pos is None else q_pos.astype(jnp.int32)
-        first = jnp.clip(q_pos - sliding_window + 1, 0, cl) // block_size
+        first = jnp.clip(cl - sliding_window + 1, 0, cl) // block_size
     n_pages = -(-cl // block_size) - first
     n_items = -(-n_pages // pages)
     ends = jnp.cumsum(n_items)
@@ -237,9 +233,8 @@ def _kernel(slot_ref, col_ref, n_ref, count_ref, bt_ref, len_ref, qpos_ref,
             window, n_planes):
     # n_planes 2: K and V planes (and side rows); 1: one plane whose rows
     # are K and V at once, fetched once into one buffer
-    n_side = n_planes if side_rows else 0
-    side, hbm, o_ref = (refs[:n_side], refs[n_side:n_side + n_planes],
-                        refs[n_side + n_planes])
+    side, hbm, o_ref = (refs[:n_planes], refs[n_planes:2 * n_planes],
+                        refs[2 * n_planes])
     bufs, (sem, m_scr, l_scr, acc_scr) = refs[-4 - n_planes:-4], refs[-4:]
     r, h, hd = q_ref.shape
     page = bs * hkv                      # rows of one page
@@ -361,16 +356,14 @@ def _kernel(slot_ref, col_ref, n_ref, count_ref, bt_ref, len_ref, qpos_ref,
         """Slot ``at`` (None: every slot at once) starts from the
         chunk's own rows: entry j is position len + j, written on pass
         j, real on pass t iff j <= t."""
-        if side_rows:
-            own, j = head_mask(side_rows * hkv)
-            side_mask = both(own, j <= t)
-            if window is not None:
-                side_mask &= (t - j) < window
-            state = softmax_step(
-                state, _scores(get(q_ref, at), get(side[0], at), scale),
-                side_mask[None] if at is None else side_mask,
-                get(side[-1], at))
-        put(slice(None) if at is None else at, state)
+        own, j = head_mask(side_rows * hkv)
+        side_mask = both(own, j <= t)
+        if window is not None:
+            side_mask &= (t - j) < window
+        put(slice(None) if at is None else at, softmax_step(
+            state, _scores(get(q_ref, at), get(side[0], at), scale),
+            side_mask[None] if at is None else side_mask,
+            get(side[-1], at)))
 
     if at_once:
         begin(None, slots((r,)))
@@ -448,12 +441,12 @@ def _kernel(slot_ref, col_ref, n_ref, count_ref, bt_ref, len_ref, qpos_ref,
 
 
 def paged_attend(q, k_planes, v_planes, plane, block_tables, context_lens,
-                 q_pos, walk: PoolWalk, side=None, *,
+                 q_pos, walk: PoolWalk, side, *,
                  sliding_window: Optional[int] = None,
                  scale: Optional[float] = None, interpret: bool = False):
     """One query token a slot over the pool's positions
-    [0, context_lens) of plane ``plane`` and, with ``side`` =
-    (side_k, side_v, t), over the chunk's own rows.
+    [0, context_lens) of plane ``plane`` and over the chunk's own rows,
+    ``side`` = (side_k, side_v, t).
 
     q [R, 1, H, hd]; k_planes, v_planes [L, NB, bs, Hkv, hd], read where
     they lie; plane: int32 scalar (traced under a layer scan; a looped
@@ -469,7 +462,7 @@ def paged_attend(q, k_planes, v_planes, plane, block_tables, context_lens,
     with the plane's index: handed the stack, XLA moved all of it into
     VMEM and back around every layer's call, 32 MiB a layer at
     mistral-7b; PERF.md section 6, PR 40.) A slot the walk leaves out
-    attends its side rows alone (zeros without them).
+    attends its side rows alone.
 
     A latent pool (``v_planes is k_planes``, and ``side_v is side_k``):
     one plane [L, NB, bs, 1, w] whose rows are K and V at once. A page
@@ -485,9 +478,8 @@ def paged_attend(q, k_planes, v_planes, plane, block_tables, context_lens,
         q = jnp.pad(q, [(0, 0)] * 3 + [(0, hd - q.shape[-1])])
     if v_planes is k_planes:
         v_planes = None
-        if side is not None:
-            assert side[1] is side[0], "one plane has one side buffer"
-            side = (side[0], None, side[2])
+        assert side[1] is side[0], "one plane has one side buffer"
+        side = (side[0], None, side[2])
     # the plan is a static argument: a program lowers the kernel once
     # however many layers' bodies call it (jit's cache), and a plan set
     # by hand (tests, the microbenchmark's sweep) is traced anew
@@ -522,18 +514,16 @@ def _paged_attend(q, k_planes, v_planes, plane, block_tables, context_lens,
     def whole(*shape):
         return pl.BlockSpec(shape, lambda i, *_: (0,) * len(shape))
     operands, in_specs = [q.reshape(r, h, hd)], [whole(r, h, hd)]
-    side_rows, t = 0, 0
-    if side is not None:
-        side_k, side_v, t = side
-        side_rows = side_k.shape[1]
-        # [K, Hkv] -> K * Hkv rows, [bs, Hkv] -> bs * Hkv below: the
-        # same bytes where the heads fill a tile's sublanes or divide
-        # them (supported), so a bitcast and not a copy
-        rows = (r, side_rows * hkv, hd)
-        operands += [s_.reshape(rows) for s_ in (side_k, side_v)
-                     if s_ is not None]
-        in_specs += [whole(*rows)] * len(planes)
-        vmem += 2 * len(planes) * r * side_rows * hkv * hd * q.dtype.itemsize
+    side_k, side_v, t = side
+    side_rows = side_k.shape[1]
+    # [K, Hkv] -> K * Hkv rows, [bs, Hkv] -> bs * Hkv below: the same
+    # bytes where the heads fill a tile's sublanes or divide them
+    # (supported), so a bitcast and not a copy
+    rows = (r, side_rows * hkv, hd)
+    operands += [s_.reshape(rows) for s_ in (side_k, side_v)
+                 if s_ is not None]
+    in_specs += [whole(*rows)] * len(planes)
+    vmem += 2 * len(planes) * r * side_rows * hkv * hd * q.dtype.itemsize
     flat = (n_planes, nb, bs * hkv, hd)
     operands += [p.reshape(flat) for p in planes]
     in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * len(planes)
@@ -568,25 +558,3 @@ def _paged_attend(q, k_planes, v_planes, plane, block_tables, context_lens,
       i32(block_tables).reshape(-1), i32(context_lens), i32(q_pos),
       jnp.stack([i32(plane), i32(t)]), *operands)
     return out[:, None]
-
-
-def paged_flash_decode(
-    q,                    # [R, 1, H, hd] — one query token per slot
-    k_pool,               # [NB, bs, Hkv, hd] — one layer's block pool
-    v_pool,               # [NB, bs, Hkv, hd]
-    block_tables,         # [R, MB] int32 — pool block ids per slot
-    context_lens,         # [R] int32 — fill AFTER this token's write
-    *,
-    sliding_window: Optional[int] = None,
-    interpret: bool = False,
-):
-    """Paged single-token attention without gather materialization: the
-    stepwise path's entry on ``paged_attend`` (a one-plane stack, no side
-    rows; the query's own K and V are in the pool, at context_lens - 1)."""
-    walk = pool_walk(
-        context_lens, context_lens > 0, k_pool, block_tables.shape[1],
-        sliding_window=sliding_window, q_pos=context_lens - 1)
-    return paged_attend(
-        q, k_pool[None], v_pool[None], 0, block_tables, context_lens,
-        context_lens - 1, walk, sliding_window=sliding_window,
-        interpret=interpret)

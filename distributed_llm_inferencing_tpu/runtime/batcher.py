@@ -361,14 +361,6 @@ class ContinuousBatcher:
                     f"batched serving shards tensors (tp/ep) and pipeline "
                     f"stages (pp); {ax}={getattr(self.mesh_spec, ax)} "
                     "unsupported (the slot scheduler owns the batch dim)")
-        from distributed_llm_inferencing_tpu.ops.pallas import fused_decode
-        if fused_decode.enabled() and self.mesh_spec.num_devices > 1:
-            # a single-program kernel with no partitioning rule: refuse
-            # it here, where the program's mesh is known, instead of
-            # dropping to the unfused path without a word
-            raise ValueError(
-                "DLI_FUSED_DECODE needs a one-device program; this load "
-                f"spans {self.mesh_spec.num_devices} devices — unset it")
         if self.mesh_spec.pp > 1:
             # pipeline-parallel serving (parallel/paged_pipeline.py):
             # slots microbatch over pp inside one GPipe-scheduled program
@@ -387,12 +379,6 @@ class ContinuousBatcher:
                  "planes)", self.mesh_spec.pp > 1),
                 ("speculative decoding (paged_speculative_chunk carries "
                  "K and V side buffers)", bool(speculative)),
-                ("a Pallas attention backend (attn_backend / "
-                 "DLI_ATTENTION: no kernel reads latent rows)",
-                 os.environ.get("DLI_ATTENTION", cfg.attn_backend)
-                 .startswith("pallas")),
-                ("DLI_FUSED_DECODE (the fused step is per-head K/V)",
-                 fused_decode.enabled()),
                 ("sliding windows or score softcapping (the absorbed "
                  "form threads neither)",
                  cfg.sliding_window is not None
@@ -403,23 +389,6 @@ class ContinuousBatcher:
                 raise ValueError(
                     f"{cfg.name}: MLA serves from the latent paged pool, "
                     "which cannot take " + "; ".join(refused))
-        if cfg.attn_windows is not None:
-            # windows that differ by layer ride the layer tree (or are a
-            # layer's trace-time constant, where layers are held one by
-            # one); the Pallas kernels take one static window, and a
-            # request for them is refused by name, not dropped
-            refused = [why for why, hit in (
-                ("a Pallas attention backend (attn_backend / "
-                 "DLI_ATTENTION: the kernels take one static window)",
-                 os.environ.get("DLI_ATTENTION", cfg.attn_backend)
-                 .startswith("pallas")),
-                ("DLI_FUSED_DECODE (the fused step takes one static "
-                 "window)", fused_decode.enabled()))
-                if hit]
-            if refused:
-                raise ValueError(
-                    f"{cfg.name}: per-layer attention windows cannot "
-                    "take " + "; ".join(refused))
         if cfg.loop_steps > 1:
             # a looped model's stack runs loop_steps times a pass over
             # one set of weights, a K and V plane a (step, layer) pair
@@ -429,14 +398,7 @@ class ContinuousBatcher:
                 ("speculative decoding (paged_speculative_chunk verifies "
                  "through one pass of the stack)", bool(speculative)),
                 ("pp > 1 (parallel/paged_pipeline.py's stages own one "
-                 "pass's layer slices of the pool)", self.mesh_spec.pp > 1),
-                ("a Pallas attention backend (attn_backend / "
-                 "DLI_ATTENTION: the stepwise chunk copies the pool a "
-                 "loop step)",
-                 os.environ.get("DLI_ATTENTION", cfg.attn_backend)
-                 .startswith("pallas")),
-                ("DLI_FUSED_DECODE (the fused step runs in the stepwise "
-                 "chunk)", fused_decode.enabled()))
+                 "pass's layer slices of the pool)", self.mesh_spec.pp > 1))
                 if hit]
             if refused:
                 raise ValueError(
@@ -464,13 +426,7 @@ class ContinuousBatcher:
                  cfg.kv_quant is not None),
                 ("kv_host_mb > 0 / DLI_KV_HOST_MB (the host arena, "
                  "kvwire fetches and migrate_out move K and V blocks, "
-                 "no state)", host_mb > 0),
-                ("a Pallas attention backend (attn_backend / "
-                 "DLI_ATTENTION: the stepwise chunk carries no state)",
-                 os.environ.get("DLI_ATTENTION", cfg.attn_backend)
-                 .startswith("pallas")),
-                ("DLI_FUSED_DECODE (the fused step runs in the stepwise "
-                 "chunk)", fused_decode.enabled()))
+                 "no state)", host_mb > 0))
                 if hit]
             if refused:
                 raise ValueError(
@@ -478,15 +434,19 @@ class ContinuousBatcher:
                     + "; ".join(refused))
             kv_host_mb = 0   # unset: no arena for this model
         self.cfg = cfg = cfg.replace(
-            attn_backend=_backend(cfg, self.mesh_spec.num_devices),
+            # the paged programs read no attention backend (the dense
+            # cache's flash kernels, ops/attention.py): whatever was
+            # asked for, stats() reports the XLA form they run beside
+            # the pool kernel
+            attn_backend="xla",
             # int4 pallas routing hint (models/config.py): this GSPMD
             # program din-shards o/down over tp, and the kernel's
             # partition rule would all-gather those shards every step
             tp_row_sharded=self.mesh_spec.tp > 1,
             mla_latent_cache=cfg.mla,
             # the experts' decode-sized grouped matmuls
-            # (models/transformer.py _expert_stream): like the attention
-            # kernels, a Pallas call that GSPMD does not partition
+            # (models/transformer.py _expert_stream): a Pallas call,
+            # which GSPMD does not partition
             expert_matmul=_expert_backend(self.mesh_spec.num_devices),
             # ... and the decode chunk's read of the pool
             # (transformer._pool_kernel), by the same rule
@@ -951,19 +911,17 @@ class ContinuousBatcher:
         return sum(a is not None for a in self.active) + queued
 
     def stats(self) -> dict:
-        from distributed_llm_inferencing_tpu.ops.pallas import fused_decode
         return {
             "slots": self.slots,
             "mesh": self.mesh_spec.axis_sizes(),
-            # the backend pinned at construction, and the kernels that
-            # would run in pallas interpret mode — only ever by request
-            # (tests); chip_smoke.py refuses a load that lists any
+            # the backend pinned at construction ("xla"), and the
+            # kernels that would run in pallas interpret mode — only
+            # ever by request (tests); chip_smoke.py refuses a load that
+            # lists any
             "attn_backend": self.cfg.attn_backend,
-            "interpreted_kernels": [name for name, on in (
-                ("attention", self.cfg.attn_backend == "pallas_interpret"),
-                ("fused_decode", fused_decode.interpret_requested()),
-                ("int4_matmul",
-                 os.environ.get("DLI_INT4_PALLAS") == "interpret")) if on],
+            "interpreted_kernels": (
+                ["int4_matmul"]
+                if os.environ.get("DLI_INT4_PALLAS") == "interpret" else []),
             "active": sum(a is not None for a in self.active),
             "queued": len(self.queue),
             "steps": self._step_count,
@@ -3459,12 +3417,6 @@ def _unstack_layers(layers: dict) -> list:
     return [jax.tree.map(lambda per: per[i], layers,
                          is_leaf=lambda x: isinstance(x, list))
             for i in range(n)]
-
-
-def _backend(cfg: ModelConfig, num_devices: int = 1) -> str:
-    from distributed_llm_inferencing_tpu.models.transformer import (
-        _cfg_backend)
-    return _cfg_backend(cfg, num_devices, op="paged")
 
 
 def _expert_backend(num_devices: int = 1, platform: str = "") -> str:

@@ -74,16 +74,13 @@ KNOBS = (
          "default, and worker/generate/bench refuse a default of cpu.",
          f"{_P}/__init__.py"),
     Knob("DLI_ATTENTION", "auto", "enum",
-         "Attention implementation override (`pallas`/`xla`/`auto`) — "
-         "test/debug escape hatch.", f"{_P}/ops/attention.py"),
+         "Dense-cache flash kernels of the single-stream engine "
+         "(`pallas`/`xla`/`auto`): test/debug override. The batcher "
+         "does not read it: its pool read is `transformer._pool_kernel`'s "
+         "choice.", f"{_P}/ops/attention.py"),
     Knob("DLI_INT4_PALLAS", "auto", "enum",
          "Int4 fused-unpack Pallas matmul: `1` force, `0` disable, "
          "`auto` = on where supported.", f"{_P}/ops/pallas/quant_matmul.py"),
-    Knob("DLI_FUSED_DECODE", "0", "enum",
-         "Fused dequant-GEMV -> RoPE -> paged-attention decode step "
-         "(one pallas_call per layer; one-device loads only). "
-         "`interpret` runs it in pallas interpret mode (tests).",
-         f"{_P}/ops/pallas/fused_decode.py"),
     Knob("DLI_MLA_LATENT", "1", "bool",
          "MLA latent-KV decode layout on eligible meshes; `0` pins the "
          "materialized layout.", f"{_P}/runtime/engine.py"),

@@ -58,8 +58,8 @@ echo "== wave speculation + pallas kernel parity + decode-speed smoke =="
 # Wave-level batched speculation (per-slot draft widths, per-request
 # controllers — docs/serving.md "Wave-level speculation") and the
 # interpret-mode differential suite pinning every pallas kernel — incl.
-# the fused dequant-GEMV->RoPE->paged-attention decode step behind
-# DLI_FUSED_DECODE — against its XLA oracle; the smoke gates the
+# the paged pool kernel the decode chunks take by themselves
+# (transformer._pool_kernel) — against its XLA oracle; the smoke gates the
 # per-slot tokens-per-weight-pass amortization and the single-stream
 # spec-vs-plain regression (BENCH_r05's inversion must stay gone)
 timeout -k 10 600 env JAX_PLATFORMS=cpu \

@@ -58,7 +58,7 @@ from distributed_llm_inferencing_tpu.ops.paged_kvcache import init_paged_cache  
 from distributed_llm_inferencing_tpu.parallel import sharding as shd  # noqa: E402
 from distributed_llm_inferencing_tpu.parallel.mesh import MeshSpec, create_mesh  # noqa: E402
 from distributed_llm_inferencing_tpu.runtime.batcher import (  # noqa: E402
-    ContinuousBatcher, _backend, _expert_backend)
+    ContinuousBatcher, _expert_backend)
 
 # model, quant, depth (a layer count, or the cell's overrides), slots,
 # block, blocks, max_seq, admit shapes as (tail, prefix blocks, wave),
@@ -217,8 +217,8 @@ def main(widths):
             cfg = cfg.replace(**(layers if isinstance(layers, dict)
                                  else {"num_layers": layers}))
         # what ContinuousBatcher.__init__ pins (on the described TPU)
-        cfg = cfg.replace(attn_backend=_backend(cfg, spec.num_devices),
-                          tp_row_sharded=tp > 1, mla_latent_cache=cfg.mla,
+        cfg = cfg.replace(attn_backend="xla", tp_row_sharded=tp > 1,
+                          mla_latent_cache=cfg.mla,
                           expert_matmul=_expert_backend(spec.num_devices,
                                                         "tpu"),
                           pool_kernel=_expert_backend(spec.num_devices,

@@ -168,6 +168,21 @@ def paged_prefill_tail_per_layer_write(params, cfg, tokens, tail_len,
     return tf.unembed(params, cfg, last_x)[:, 0], PagedKVCache(*cache_out)
 
 
+def served_as_under_auto(build, asked, env, monkeypatch):
+    """``attn_backend`` / ``DLI_ATTENTION`` name the dense cache's flash
+    kernels (ops/attention.resolve_backend); the batcher pins "xla" and
+    reads neither. So ``build(asked)`` under ``env`` is the batcher
+    ``build("auto")`` is: the same pinned config, the pool's read
+    transformer._pool_kernel's choice. Returns it."""
+    auto = build("auto")
+    for key, value in env.items():
+        monkeypatch.setenv(key, value)
+    b = build(asked)
+    assert b.cfg.attn_backend == "xla" and b.cfg == auto.cfg
+    assert b.pool_kernel == auto.pool_kernel
+    return b
+
+
 # ---- lock-order watchdog gate (utils/locks.py) ------------------------
 # When the suite runs with DLI_LOCK_CHECK=1 (scripts/check.sh arms it
 # for the chaos suite), every runtime lock is instrumented and a

@@ -16,6 +16,11 @@ the program). The rung cases run each rung, forced by the contexts,
 against the same program with the ladder patched to ``(mb,)``, the full
 extent: the same tokens, the same ``emits``, the same pool. Layers held
 one by one (the batcher's MoE layout) have the full extent alone.
+
+The chunk in the in-loop form is also held, on the logits of every live
+pass, to ``paged_decode_step`` fed the chunk's own tokens: the one other
+formulation of a decode pass, which writes the pool and gathers all of
+it every step.
 """
 
 import functools
@@ -146,15 +151,20 @@ def _sampling_rows(sampled, top_k=0):
 NO_EOS = np.full((R,), -1, np.int32)
 SEEDS = np.asarray([1, 2, 3, 4], np.int32)
 STEPS0 = np.asarray([0, 3, 1, 7], np.int32)
+TOKENS = np.asarray([0, 5, 9, 17], np.int32)   # the plain chunk's input
+
+
+def _budget(k):
+    """Slot 0 is dead from the start; slot 3 runs out of budget inside
+    an 8-pass chunk."""
+    return np.minimum(k, np.asarray([0, 8, 8, 5])).astype(np.int32)
 
 
 def _decode_chunk(case, k):
     """The plain chunk as a function of (inputs, eos ids, sampling rows),
     and what makes its inputs from the slots' contexts and budgets."""
     cfg, params, pool, bt, lora_ids = _setup(case)
-    tokens = np.asarray([0, 5, 9, 17], np.int32)
-    # slot 3 runs out of budget inside an 8-pass chunk
-    budget = np.minimum(k, np.asarray([0, 8, 8, 5])).astype(np.int32)
+    budget = _budget(k)
 
     def inputs(context):
         return np.asarray(context, np.int32), budget
@@ -162,7 +172,7 @@ def _decode_chunk(case, k):
     def chunk(inp, eos_ids, temps, tks, tps, ds):
         context, budget = inp
         return transformer.paged_decode_chunk(
-            params, cfg, k, tokens, pool, bt, context, SEEDS, STEPS0,
+            params, cfg, k, TOKENS, pool, bt, context, SEEDS, STEPS0,
             temps, tks, tps, ds, budget, eos_ids, DUMMY, lora_ids=lora_ids)
     return chunk, inputs, budget
 
@@ -276,6 +286,95 @@ def test_speculative_chunk_in_loop_gather_equals_pregathered(case, sampled):
         lambda toks: toks[1, 2, 0])
     assert not keeps[:, 0].any()
     assert eos_seen[-1, 2] and not eos_seen[-1, 1]
+
+
+# -- the chunk against single steps ----------------------------------------
+
+# largest logit error of a live (pass, slot) over the spread of the step
+# form's logits there: tests/test_kanana.py's measure and its tolerance
+F32_TOL = 2e-4
+
+
+@functools.lru_cache(maxsize=None)
+def _setup_f32(case):
+    """_setup's configuration, weights and block tables in float32, a
+    float32 (or int8) pool of random rows, and paged_decode_step jitted
+    for them. Over an int8 pool the step form would quantize each fresh
+    row as it writes it, where the chunk reads its own rows as computed
+    until it ends (the side buffers): its steps run over the same
+    pool's rows dequantized, in float32, so that both read one set of
+    values."""
+    cfg, params, _, bt, lora_ids = _setup(case)
+    cfg = cfg.replace(dtype="float32")
+    params = jax.tree.map(
+        lambda a: a.astype(jnp.float32) if a.dtype == jnp.bfloat16 else a,
+        params)
+    pool = step_pool = _random_pool(cfg)
+    if pool.quantized:
+        from distributed_llm_inferencing_tpu.ops.kvcache import dequant_kv
+        step_pool = PagedKVCache(
+            k=dequant_kv(pool.k, pool.k_scale, jnp.float32),
+            v=dequant_kv(pool.v, pool.v_scale, jnp.float32))
+    step_cfg = cfg.replace(kv_quant=None)
+    step = jax.jit(lambda *a: transformer.paged_decode_step(
+        params, step_cfg, *a, lora_ids=lora_ids))
+    return cfg, params, pool, bt, lora_ids, step, step_pool
+
+
+@pytest.mark.parametrize("k", [1, 8])
+@pytest.mark.parametrize("case", list(CASES))
+def test_decode_chunk_logits_equal_single_steps(case, k):
+    """The chunk in the form the chip's cells take without the kernel
+    (the in-loop gather, pool and side rows in one softmax, the pool
+    written once at the end) against paged_decode_step, the one other
+    formulation of a decode pass (the pool written and gathered whole
+    every step; what benchmarks/chip/compare_reference*.py hold to the
+    float32 references), fed the chunk's own tokens: every live pass's
+    logits, and the pool the passes leave."""
+    cfg, params, pool, bt, lora_ids, step, pool_b = _setup_f32(case)
+    budget = _budget(k)
+    with mock.patch.object(transformer, "_PREGATHER_MAX_BYTES", 0), \
+            mock.patch.object(transformer, "_layer_gather",
+                              side_effect=transformer._layer_gather) as spy:
+        toks, emits, *_, pool_a, logits = jax.device_get(jax.jit(
+            lambda pool: transformer.decode_chunk_with_logits(
+                params, cfg, k, TOKENS, pool, bt, CONTEXT, SEEDS, STEPS0,
+                *_sampling_rows(False), budget, NO_EOS, DUMMY,
+                lora_ids=lora_ids))(pool))
+    assert spy.call_count > 0, "not the in-loop gather"
+    cur = TOKENS
+    for t in range(k):
+        alive = t < budget
+        assert (emits[t] == alive).all()
+        want, pool_b = step(cur, pool_b, np.where(alive[:, None], bt, DUMMY),
+                            np.where(alive, CONTEXT + t, 0))
+        want = np.asarray(want)[alive]
+        err = np.abs(logits[t][alive] - want).max(-1) / want.std(-1)
+        assert err.max() < F32_TOL, (t, err)
+        assert (toks[t][alive] == logits[t][alive].argmax(-1)).all()
+        cur = toks[t]
+    if not pool.quantized:      # (the chunk's rows go in as int8 levels)
+        _assert_pools_close(pool_a, jax.device_get(pool_b), skip_dummy=True)
+
+
+def test_the_chunk_traces_one_program_whatever_attention_is_asked(
+        monkeypatch):
+    """``attn_backend`` / ``DLI_ATTENTION`` choose the dense cache's
+    flash kernels (ops/attention.resolve_backend); the decode chunk
+    reads neither, and how it reads the pool is _pool_kernel's choice."""
+    cfg, params, pool, bt, _ = _setup("gqa-bf16")        # tiny-llama
+
+    def jaxpr(attn_backend):
+        return str(jax.make_jaxpr(
+            lambda pool: transformer.paged_decode_chunk(
+                params, cfg.replace(attn_backend=attn_backend), 2, TOKENS,
+                pool, bt, CONTEXT, SEEDS, STEPS0, *_sampling_rows(False),
+                _budget(2), NO_EOS, DUMMY))(pool))
+    want = jaxpr("auto")
+    monkeypatch.setenv("DLI_ATTENTION", "pallas")
+    assert jaxpr("auto") == want
+    monkeypatch.delenv("DLI_ATTENTION")
+    assert jaxpr("pallas") == want
 
 
 # -- the rungs -----------------------------------------------------------

@@ -530,8 +530,6 @@ def test_the_wave_bound_comes_from_the_configuration():
     ({"speculative": "ngram"}, {}, "speculative decoding"),
     ({"kv_host_mb": 8}, {}, "kv_host_mb > 0"),
     ({}, {"DLI_KV_HOST_MB": "8"}, "the host arena"),
-    ({}, {"DLI_ATTENTION": "pallas"}, "Pallas attention backend"),
-    ({}, {"DLI_FUSED_DECODE": "1"}, "DLI_FUSED_DECODE"),
     ({"cfg_kw": {"kv_quant": "int8"}}, {}, "kv_quant"),
     ({"mesh": {"pp": 2}}, {}, "pp > 1 or any mesh"),
     ({"mesh": {"tp": 2}}, {}, "more than one device"),
@@ -548,6 +546,19 @@ def test_what_does_not_carry_a_state_is_refused_by_name(kw, env, match,
     with pytest.raises(ValueError, match=match):
         ContinuousBatcher(cfg, None, slots=2, num_blocks=16,
                           block_size=BS, max_seq=32, **kw)
+
+
+@pytest.mark.parametrize("asked,env", [
+    ("auto", {"DLI_ATTENTION": "pallas"}),
+    ("pallas", {}),
+])
+def test_a_request_for_pallas_attention_serves_as_auto_does(asked, env,
+                                                            monkeypatch):
+    from conftest import served_as_under_auto
+    served_as_under_auto(lambda attn_backend: ContinuousBatcher(
+        cfg32().replace(attn_backend=attn_backend), None, slots=2,
+        num_blocks=16, block_size=BS, max_seq=32),
+        asked, env, monkeypatch)
 
 
 def test_the_paths_without_a_state_refuse_by_name(params):

@@ -150,9 +150,6 @@ def test_latent_speculative_verify_matches_plain_greedy():
     (dict(cfg=dict(kv_quant="int8")), {}, "kv_quant"),
     (dict(mesh=dict(pp=2)), {}, "pp > 1"),
     (dict(batcher=dict(speculative="ngram")), {}, "speculative"),
-    (dict(), {"DLI_ATTENTION": "pallas"}, "Pallas"),
-    (dict(cfg=dict(attn_backend="pallas")), {}, "Pallas"),
-    (dict(), {"DLI_FUSED_DECODE": "1"}, "DLI_FUSED_DECODE"),
     (dict(cfg=dict(sliding_window=16)), {}, "sliding windows"),
 ])
 def test_batcher_refuses_what_the_latent_pool_cannot_take(
@@ -173,6 +170,24 @@ def test_batcher_refuses_what_the_latent_pool_cannot_take(
                           mesh_spec=MeshSpec(**kw.get("mesh", {})),
                           **kw.get("batcher", {}))
     assert named in str(e.value)
+
+
+@pytest.mark.parametrize("asked,env", [
+    ("auto", {"DLI_ATTENTION": "pallas"}),
+    ("pallas", {}),
+    ("pallas_interpret", {}),
+])
+def test_batcher_serves_the_latent_pool_whatever_attention_is_asked(
+        asked, env, monkeypatch):
+    from conftest import served_as_under_auto
+    from distributed_llm_inferencing_tpu.runtime.batcher import (
+        ContinuousBatcher)
+    b = served_as_under_auto(lambda attn_backend: ContinuousBatcher(
+        get_config("tiny-deepseek").replace(
+            dtype="float32", attn_backend=attn_backend),
+        num_blocks=16, block_size=8, slots=2, max_seq=32, seed=0),
+        asked, env, monkeypatch)
+    assert b.stats()["interpreted_kernels"] == []
 
 
 def test_deepseek_tp_ep_batcher_matches_engine():

@@ -271,18 +271,24 @@ def test_a_model_without_a_loop_counts_one_stack_pass():
     assert chunk.attrs["loop_steps"] == 1
 
 
-@pytest.mark.parametrize("kw,env,match", [
-    ({"speculative": "ngram"}, {}, "speculative decoding"),
-    ({}, {"DLI_ATTENTION": "pallas"}, "Pallas attention backend"),
-    ({}, {"DLI_FUSED_DECODE": "1"}, "DLI_FUSED_DECODE"),
-])
-def test_what_does_not_carry_the_loop_is_refused_by_name(kw, env, match,
-                                                         monkeypatch):
-    for key, value in env.items():
-        monkeypatch.setenv(key, value)
-    with pytest.raises(ValueError, match=match):
+def test_what_does_not_carry_the_loop_is_refused_by_name():
+    with pytest.raises(ValueError, match="speculative decoding"):
         ContinuousBatcher(cfg32(), None, slots=2, num_blocks=16,
-                          block_size=BS, max_seq=32, kv_host_mb=0, **kw)
+                          block_size=BS, max_seq=32, kv_host_mb=0,
+                          speculative="ngram")
+
+
+@pytest.mark.parametrize("asked,env", [
+    ("auto", {"DLI_ATTENTION": "pallas"}),
+    ("pallas", {}),
+])
+def test_a_request_for_pallas_attention_serves_as_auto_does(asked, env,
+                                                            monkeypatch):
+    from conftest import served_as_under_auto
+    served_as_under_auto(lambda attn_backend: ContinuousBatcher(
+        cfg32().replace(attn_backend=attn_backend), None, slots=2,
+        num_blocks=16, block_size=BS, max_seq=32, kv_host_mb=0),
+        asked, env, monkeypatch)
 
 
 def test_a_pipeline_is_refused_by_name():

@@ -111,31 +111,6 @@ def test_sliding_window_paged():
     assert dense_toks == paged_toks
 
 
-@pytest.mark.parametrize("window", [None, 8])
-def test_pallas_paged_decode_matches_xla(window):
-    """Block-table-driven Pallas kernel ≡ gather-based XLA formulation."""
-    from distributed_llm_inferencing_tpu.ops.paged_kvcache import (
-        paged_attend_decode)
-    rng = np.random.default_rng(3)
-    R, MB, NB, H, HKV, HD = 4, 4, 24, 8, 4, 16
-    q = jnp.asarray(rng.standard_normal((R, 1, H, HD)), jnp.float32)
-    kp = jnp.asarray(rng.standard_normal((NB, BS, HKV, HD)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((NB, BS, HKV, HD)), jnp.float32)
-    # distinct blocks per slot; slot 0 inactive (dummy block 0, len counts 1
-    # token just written)
-    bt = np.zeros((R, MB), np.int32)
-    ids = rng.permutation(np.arange(1, NB))[: R * MB].reshape(R, MB)
-    bt[1:] = ids[1:]
-    lens = np.asarray([1, 5, BS * 2, BS * 3 + 3], np.int32)
-    xla_out = paged_attend_decode(q, kp, vp, jnp.asarray(bt),
-                                  jnp.asarray(lens), sliding_window=window)
-    pl_out = paged_attend_decode(q, kp, vp, jnp.asarray(bt),
-                                 jnp.asarray(lens), sliding_window=window,
-                                 backend="pallas_interpret")
-    np.testing.assert_allclose(np.asarray(xla_out)[1:], np.asarray(pl_out)[1:],
-                               rtol=2e-5, atol=2e-5)
-
-
 def test_prefix_reuse_matches_full_prefill():
     """Tail prefill over a cached prefix ≡ full prefill of the whole prompt."""
     cfg = _cfg("tiny-llama")

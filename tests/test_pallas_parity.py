@@ -5,8 +5,8 @@ The kernels (ops/pallas/) are the TPU-compiled fast path; the XLA
 formulations are the always-available oracle. This suite pins them
 together in tier-1 so a kernel edit can't silently diverge: odd shapes,
 batch > 1, masked tails (context lengths mid-block), every quantized
-weight form, and the end-to-end batcher greedy parity for the fused
-decode step behind ``DLI_FUSED_DECODE``.
+weight form, and the end-to-end batcher greedy parity for the paged
+pool kernel the decode chunks take where the shape allows.
 """
 
 import numpy as np
@@ -18,12 +18,6 @@ from distributed_llm_inferencing_tpu.models.params import init_params
 from distributed_llm_inferencing_tpu.models.registry import get_config
 from distributed_llm_inferencing_tpu.ops.attention import attend_prefill
 from distributed_llm_inferencing_tpu.ops.pallas import flash_attention
-from distributed_llm_inferencing_tpu.ops.pallas.fused_decode import (
-    fused_decode_step, rope_cos_sin, supported)
-from distributed_llm_inferencing_tpu.ops.pallas.paged_attention import (
-    paged_flash_decode)
-from distributed_llm_inferencing_tpu.ops.paged_kvcache import (
-    paged_attend_decode)
 from distributed_llm_inferencing_tpu.ops.sampling import SamplingParams
 
 
@@ -90,55 +84,6 @@ def test_flash_prefill_odd_shapes(B, S, H, Hkv, hd):
                _rand(rng, B, S, Hkv, hd))
     ref = attend_prefill(q, k, v, backend="xla")
     out = flash_attention(q, k, v, block_q=16, block_kv=16, interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
-
-
-# ---- paged_flash_decode: block-table kernel vs the gather formulation --
-
-def _paged_pool(rng, nb, bs, hkv, hd):
-    return (_rand(rng, nb, bs, hkv, hd), _rand(rng, nb, bs, hkv, hd))
-
-
-@pytest.mark.parametrize("lens", [
-    [5, 17, 32, 1],        # masked tails mid-block + a full block + 1
-    [9, 9, 9, 9],          # uniform
-    [31, 2, 16, 7],        # block-boundary -1 / cross-block mix
-])
-def test_paged_flash_decode_matches_gather(lens):
-    rng = np.random.default_rng(sum(lens))
-    r, nb, bs, mb, h, hkv, hd = len(lens), 32, 8, 4, 4, 2, 16
-    k_pool, v_pool = _paged_pool(rng, nb, bs, hkv, hd)
-    bt = np.zeros((r, mb), np.int32)
-    used = set([0])
-    for i in range(r):
-        for j in range(mb):
-            b = int(rng.integers(1, nb))
-            while b in used:
-                b = int(rng.integers(1, nb))
-            used.add(b)
-            bt[i, j] = b
-    q = _rand(rng, r, 1, h, hd)
-    lens_a = jnp.asarray(lens, jnp.int32)
-    ref = paged_attend_decode(q, k_pool, v_pool, jnp.asarray(bt), lens_a,
-                              backend="xla")
-    out = paged_flash_decode(q, k_pool, v_pool, jnp.asarray(bt), lens_a,
-                             interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
-
-
-def test_paged_flash_decode_sliding_window():
-    rng = np.random.default_rng(11)
-    r, nb, bs, mb, h, hkv, hd = 2, 16, 8, 3, 4, 4, 16
-    k_pool, v_pool = _paged_pool(rng, nb, bs, hkv, hd)
-    bt = np.asarray([[1, 2, 3], [4, 5, 6]], np.int32)
-    lens = jnp.asarray([20, 13], jnp.int32)
-    q = _rand(rng, r, 1, h, hd)
-    ref = paged_attend_decode(q, k_pool, v_pool, jnp.asarray(bt), lens,
-                              sliding_window=6, backend="xla")
-    out = paged_flash_decode(q, k_pool, v_pool, jnp.asarray(bt), lens,
-                             sliding_window=6, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
 
@@ -352,145 +297,3 @@ def test_batcher_pool_kernel_where_the_shape_allows(monkeypatch, model,
         monkeypatch.setattr(batcher, "_expert_backend",
                             lambda *a, **k: "xla")
         assert _serve(cfg, params, prompts)[:2] == (toks, 0)
-
-
-# ---- fused_decode_step: dequant-GEMV -> RoPE -> paged attention -------
-
-def _fused_ref(cfg, x, q_leaf, k_pool, v_pool, bt, lens, positions,
-               sliding_window=None):
-    """The unfused oracle: XLA q projection + apply_rope + gather
-    attention — exactly the ops the kernel chains."""
-    from distributed_llm_inferencing_tpu.models.transformer import _linear
-    from distributed_llm_inferencing_tpu.ops.rope import apply_rope
-    r, d = x.shape
-    hd = k_pool.shape[-1]
-    q = _linear(x[:, None], q_leaf)
-    h = q.shape[-1] // hd
-    q = q.reshape(r, 1, h, hd)
-    if positions is not None:
-        q = apply_rope(q, positions[:, None], cfg.rope_theta,
-                       cfg.rope_pct, cfg.rope_interleaved,
-                       inv_freq=cfg.rope_inv_freq,
-                       attn_factor=cfg.rope_attn_factor)
-    return paged_attend_decode(q, k_pool, v_pool, bt, lens,
-                               sliding_window=sliding_window,
-                               backend="xla")[:, 0]
-
-
-def _quant_leaf(w, form):
-    if form == "float":
-        return {"w": jnp.asarray(w)}
-    if form == "int8":
-        from distributed_llm_inferencing_tpu.ops.quant import quantize_weight
-        return quantize_weight(jnp.asarray(w))
-    from distributed_llm_inferencing_tpu.ops.quant import (
-        quantize_weight_int4)
-    return quantize_weight_int4(jnp.asarray(w))
-
-
-@pytest.mark.parametrize("form", ["float", "int8", "int4"])
-@pytest.mark.parametrize("gqa", ["gqa", "mqa", "mha"])
-def test_fused_decode_step_matches_unfused(form, gqa):
-    cfg = get_config("tiny-llama").replace(dtype="float32")
-    rng = np.random.default_rng(hash((form, gqa)) % 2**31)
-    hkv = {"gqa": 2, "mqa": 1, "mha": 4}[gqa]
-    r, nb, bs, mb, h, hd, d = 3, 16, 8, 3, 4, 16, 32
-    k_pool, v_pool = _paged_pool(rng, nb, bs, hkv, hd)
-    bt = np.asarray([[1, 2, 3], [4, 5, 6], [7, 8, 9]], np.int32)
-    lens = jnp.asarray([7, 21, 12], jnp.int32)
-    positions = lens - 1
-    x = rng.normal(size=(r, d)).astype(np.float32)
-    w = rng.normal(size=(d, h * hd)).astype(np.float32) / np.sqrt(d)
-    leaf = _quant_leaf(w, form)
-    cos, sin = rope_cos_sin(cfg, positions, hd)
-    ref = _fused_ref(cfg, jnp.asarray(x), leaf, k_pool, v_pool,
-                     jnp.asarray(bt), lens, positions)
-    out = fused_decode_step(jnp.asarray(x), leaf, k_pool, v_pool,
-                            jnp.asarray(bt), lens, rope_cos=cos,
-                            rope_sin=sin, interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=3e-5, atol=3e-5)
-
-
-def test_fused_decode_step_no_rope_and_window():
-    """Positional-free q (learned/none embeddings) and a sliding window."""
-    cfg = get_config("tiny-llama").replace(dtype="float32")
-    rng = np.random.default_rng(23)
-    r, nb, bs, mb, hkv, h, hd, d = 2, 16, 8, 3, 2, 4, 16, 32
-    k_pool, v_pool = _paged_pool(rng, nb, bs, hkv, hd)
-    bt = np.asarray([[1, 2, 3], [4, 5, 6]], np.int32)
-    lens = jnp.asarray([19, 8], jnp.int32)
-    x = rng.normal(size=(r, d)).astype(np.float32)
-    leaf = {"w": jnp.asarray(
-        rng.normal(size=(d, h * hd)).astype(np.float32))}
-    ref = _fused_ref(cfg, jnp.asarray(x), leaf, k_pool, v_pool,
-                     jnp.asarray(bt), lens, None, sliding_window=5)
-    out = fused_decode_step(jnp.asarray(x), leaf, k_pool, v_pool,
-                            jnp.asarray(bt), lens, sliding_window=5,
-                            interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=3e-5, atol=3e-5)
-
-
-def test_fused_supported_gate():
-    cfg = get_config("tiny-llama")
-    assert supported(cfg)
-    assert not supported(cfg.replace(qk_norm="rms_head"))
-    assert not supported(cfg.replace(rope_interleaved=True))
-    assert not supported(cfg.replace(attn_softcap=30.0))
-    assert not supported(cfg.replace(kv_quant="int8"))
-    assert not supported(cfg, {"w": None, "b": None})   # biased q leaf
-
-
-# ---- end-to-end: batcher greedy parity with DLI_FUSED_DECODE ----------
-
-def _batch_tokens(monkeypatch, fused: bool, quant=None, spec=False):
-    from distributed_llm_inferencing_tpu.runtime.batcher import (
-        ContinuousBatcher)
-    monkeypatch.setenv("DLI_FUSED_DECODE", "interpret" if fused else "0")
-    cfg = get_config("tiny-llama").replace(dtype="float32",
-                                           attn_backend="xla")
-    params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
-    if quant:
-        cfg = cfg.replace(quant=quant)
-    b = ContinuousBatcher(
-        cfg, params, num_blocks=128, block_size=8, slots=4, max_seq=96,
-        seed=0, speculative="ngram" if spec else None, spec_gamma=3)
-    rng = np.random.default_rng(5)
-    base = rng.integers(0, 256, 4).tolist()
-    prompts = [(base * 6)[:20], rng.integers(0, 256, 9).tolist(),
-               rng.integers(0, 256, 13).tolist()]
-    reqs = [b.submit(p, max_new_tokens=12, sampling=SamplingParams.greedy(),
-                     seed=50 + i) for i, p in enumerate(prompts)]
-    for _ in range(200):
-        b.step()
-        if all(r.done.is_set() for r in reqs):
-            break
-    return [r.wait() for r in reqs]
-
-
-@pytest.mark.slow   # two full batcher runs per form — the suite's most
-                    # exhaustive parametrization; check.sh's dedicated
-                    # pallas step runs it (no -m filter), and the
-                    # kernel-level fused parity grid above stays in the
-                    # bare tier-1 command's budget
-@pytest.mark.parametrize("quant", [None, "int8"])
-def test_batcher_greedy_bitwise_fused_on_off(monkeypatch, quant):
-    """The acceptance bar: greedy decode through the continuous batcher
-    is bitwise identical with DLI_FUSED_DECODE on and off."""
-    off = _batch_tokens(monkeypatch, fused=False, quant=quant)
-    on = _batch_tokens(monkeypatch, fused=True, quant=quant)
-    assert on == off
-
-
-@pytest.mark.slow   # three full batcher runs; check.sh's dedicated step
-                    # runs it (no -m filter), bare tier-1 keeps the
-                    # two-run fused on/off parity below
-def test_batcher_greedy_bitwise_fused_with_spec_wave(monkeypatch):
-    """Fused decode composes with wave speculation: spec chunks keep the
-    side-buffer program, plain rides (and all-plain fallback chunks) go
-    through the fused stepwise path — tokens identical either way."""
-    off = _batch_tokens(monkeypatch, fused=False, spec=True)
-    on = _batch_tokens(monkeypatch, fused=True, spec=True)
-    plain = _batch_tokens(monkeypatch, fused=False, spec=False)
-    assert on == off == plain

@@ -116,16 +116,6 @@ def test_flash_kernels_compile_with_alibi(compile_on_chip):
         ((SLOTS, 1024, heads, hd), BF16), ((SLOTS,), jnp.int32))
 
 
-def test_paged_flash_decode_compiles(compile_on_chip):
-    from distributed_llm_inferencing_tpu.ops.pallas.paged_attention import (
-        paged_flash_decode)
-    compile_on_chip(
-        functools.partial(paged_flash_decode, sliding_window=WINDOW),
-        ((SLOTS, 1, H, HD), BF16), ((NB, BS, HKV, HD), BF16),
-        ((NB, BS, HKV, HD), BF16), ((SLOTS, MB), jnp.int32),
-        ((SLOTS,), jnp.int32))
-
-
 @pytest.mark.parametrize("side_rows", [1, 2, 4, 8])
 def test_paged_attend_compiles_over_a_latent_plane(compile_on_chip,
                                                    side_rows):
@@ -223,29 +213,6 @@ def test_ssm_state_step_writes_the_plane_in_place(compile_on_chip):
                 if (" copy(" in ln or "copy-start(" in ln)
                 and ("f32[6,65,2,16,128,256]" in ln
                      or "f32[6,65,32,128,256]" in ln)]
-
-
-@pytest.mark.parametrize("form", ["float", "int8", "int4"])
-def test_fused_decode_step_compiles(compile_on_chip, form):
-    from distributed_llm_inferencing_tpu.ops.pallas.fused_decode import (
-        fused_decode_step)
-    q_leaf = {
-        "float": {"w": ((D, H * HD), BF16)},
-        "int8": {"q": ((D, H * HD), jnp.int8),
-                 "scale": ((H * HD,), jnp.float32)},
-        "int4": {"p4": ((D // 2, H * HD), jnp.uint8),
-                 "scale": ((H * HD,), jnp.float32)},
-    }[form]
-
-    def step(x, leaf, k, v, bt, lens, cos, sin):
-        return fused_decode_step(x, leaf, k, v, bt, lens, rope_cos=cos,
-                                 rope_sin=sin, sliding_window=WINDOW)
-
-    compile_on_chip(
-        step, ((SLOTS, D), BF16), q_leaf, ((NB, BS, HKV, HD), BF16),
-        ((NB, BS, HKV, HD), BF16), ((SLOTS, MB), jnp.int32),
-        ((SLOTS,), jnp.int32), ((SLOTS, HD), jnp.float32),
-        ((SLOTS, HD), jnp.float32))
 
 
 # up/gate (4096 x 14336) and down (14336 x 4096); f32 activations take

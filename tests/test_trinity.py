@@ -407,18 +407,20 @@ def test_the_accepted_cells_widest_wave_is_inside_the_budget():
         < 4 * 512 * (512 * 16 + 512)
 
 
-# ---- refusals --------------------------------------------------------------
+# ---- the attention backend is not the batcher's ----------------------------
 
-@pytest.mark.parametrize("env,match", [
-    ({"DLI_ATTENTION": "pallas"}, "Pallas attention backend"),
-    ({"DLI_FUSED_DECODE": "1"}, "DLI_FUSED_DECODE"),
+@pytest.mark.parametrize("asked,env", [
+    ("auto", {"DLI_ATTENTION": "pallas"}),
+    ("pallas", {}),
 ])
-def test_what_cannot_serve_it_is_refused_by_name(env, match, monkeypatch):
-    for key, value in env.items():
-        monkeypatch.setenv(key, value)
-    with pytest.raises(ValueError, match=match):
-        ContinuousBatcher(cfg32(), None, slots=2, num_blocks=16,
-                          block_size=BS, max_seq=32, kv_host_mb=0)
+def test_a_request_for_pallas_attention_serves_as_auto_does(asked, env,
+                                                            monkeypatch):
+    """Per-layer windows: the in-loop gather either way."""
+    from conftest import served_as_under_auto
+    served_as_under_auto(lambda attn_backend: ContinuousBatcher(
+        cfg32().replace(attn_backend=attn_backend), None, slots=2,
+        num_blocks=16, block_size=BS, max_seq=32, kv_host_mb=0),
+        asked, env, monkeypatch)
 
 
 def test_the_registry_has_the_source_sizes():
