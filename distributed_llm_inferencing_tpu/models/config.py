@@ -75,6 +75,36 @@ class SSMConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class SWAConfig:
+    """Layers of two kinds in one stack (MiMo-V2, modeling_mimo_v2.py):
+    ``pattern[l]`` 1 makes layer l a *windowed* layer, 0 a *full* one
+    (the source's hybrid_layer_pattern). A full layer's attention is the
+    model's own (num_kv_heads, rope_theta, attn_sinks, no window); a
+    windowed layer's has the K/V head count, rotary base and sink given
+    here and sees ModelConfig.sliding_window positions. Head and value
+    widths are the model's in both kinds. The kinds differ in the shape
+    of their K, V and sink leaves, so each kind is a stack of its own
+    (``layers`` the windowed, ``layers_full`` the full ones;
+    transformer.layer_segments runs them in the pattern's order), and in
+    their caches: the batcher's block pool holds the full layers' K and
+    V alone, the windowed layers' live in a ring a serving slot
+    (ops/paged_kvcache.py). The equations are in
+    models/reference/mimo_v2_ref.py."""
+    pattern: Tuple[int, ...] = ()
+    num_kv_heads: int = 8          # swa_num_key_value_heads
+    rope_theta: float = 10000.0    # swa_rope_theta
+    sinks: bool = True             # add_swa_attention_sink_bias
+
+    def __post_init__(self):
+        object.__setattr__(self, "pattern",
+                           tuple(int(v) for v in self.pattern))
+        assert set(self.pattern) <= {0, 1}, self.pattern
+
+    def kinds(self) -> Tuple[str, ...]:
+        return tuple("swa" if v else "full" for v in self.pattern)
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     # Identity
     name: str = "gpt2"
@@ -101,6 +131,15 @@ class ModelConfig:
     # family. (A dict, as a checkpoint's config.json gives it back, is
     # taken for its fields.)
     ssm: Optional[SSMConfig] = None
+    # Windowed and full layers of different shapes in one stack
+    # (MiMo-V2): see SWAConfig. None is every other family; beside it
+    # ``sliding_window`` is the windowed kind's width. (A dict is taken
+    # for its fields, as ssm's is.)
+    swa: Optional[SWAConfig] = None
+    # swa | full on the config of ONE kind's layers (kind_cfg), whose
+    # other fields are then that kind's own and whose ``swa`` is None;
+    # never set by a caller.
+    attn_kind: Optional[str] = None
 
     # Architecture switches
     norm_type: str = "layernorm"  # layernorm | rmsnorm
@@ -256,8 +295,13 @@ class ModelConfig:
     # MLA value head width; v is zero-padded to head_dim inside the block
     # so every cache/attention path keeps a single head_dim, and the
     # attention output is sliced back before the o projection. None =>
-    # head_dim (all non-MLA families).
+    # head_dim (all other families but MiMo-V2, whose v projection,
+    # caches and o projection are this wide beside q and k heads of
+    # head_dim: nothing is padded there).
     v_head_dim: Optional[int] = None
+    # MiMo-V2 attention_value_scale: v is multiplied by it as projected
+    # (the caches hold the scaled rows). None => no scale.
+    attn_value_scale: Optional[float] = None
     # MLA's actual point: cache ONE shared latent row per token —
     # [k_rot (qk_rope_head_dim, post-RoPE) | c (kv_lora_rank, normed)] —
     # instead of materialized per-head K/V, and decode via the absorbed
@@ -320,6 +364,15 @@ class ModelConfig:
     # width (the dense prefix of a mixed stack). None => experts are
     # intermediate_size wide (Mixtral).
     moe_intermediate_size: Optional[int] = None
+    # The share of each MoE layer's experts this program holds, (first,
+    # count): expert parallelism's cut as one chip sees it. The router
+    # stays num_experts wide and a token's weights are normalised over
+    # all its chosen experts; the expert leaves are [count, ...] and
+    # _moe sums the chosen experts in [first, first + count) alone (a
+    # choice that falls elsewhere costs no read and adds nothing). The
+    # partial sum goes on to the next layer: nothing stands in for the
+    # other shares or their exchange. None => all of them.
+    experts_held: Optional[Tuple[int, int]] = None
     # Numerics
     dtype: str = "bfloat16"  # activation/weight dtype on device
     # Weight-only quantization (ops/quant.py): None | "int8" | "int4".
@@ -393,6 +446,32 @@ class ModelConfig:
                     and not self.sublayer_postnorm_only), (
                 "a state-space mixer rides the plain pre-norm block with "
                 "a gated MLP (Falcon-H1's)")
+        if isinstance(self.swa, dict):
+            object.__setattr__(self, "swa", SWAConfig(**self.swa))
+        if self.swa is not None:
+            assert len(self.swa.pattern) == self.num_layers, (
+                f"swa.pattern has {len(self.swa.pattern)} entries for "
+                f"{self.num_layers} layers")
+            assert self.num_heads % self.swa.num_kv_heads == 0
+            assert (self.sliding_window and not self.mla
+                    and self.ssm is None and self.loop_steps == 1
+                    and self.attn_windows is None
+                    and self.rope_layers is None
+                    and self.position_embedding == "rope"), (
+                "layer kinds (cfg.swa) ride the plain rope block; "
+                "sliding_window is the windowed kind's width")
+            k = self.dense_prefix_layers
+            assert len(set(self.swa.pattern[:k])) <= 1, (
+                "the dense prefix is one stack: its layers share a kind")
+        if self.experts_held is not None:
+            object.__setattr__(self, "experts_held",
+                               tuple(int(v) for v in self.experts_held))
+            first, count = self.experts_held
+            # (a dense prefix's own config keeps the field and has no
+            # experts: its layers count the same MOE_STATS as the rest)
+            assert not self.num_experts or (
+                0 <= first and count >= 1
+                and first + count <= self.num_experts), self.experts_held
         if self.rope_inv_freq is not None:
             # normalize (checkpoint config.json roundtrips tuple -> list)
             object.__setattr__(self, "rope_inv_freq",
@@ -514,10 +593,70 @@ class ModelConfig:
                             **self._per_layer(k, self.num_layers))
 
     def _per_layer(self, start: int, stop: int) -> dict:
-        """attn_windows / rope_layers of layers [start, stop)."""
-        return {name: (None if getattr(self, name) is None
-                       else getattr(self, name)[start:stop])
-                for name in ("attn_windows", "rope_layers")}
+        """attn_windows / rope_layers / swa.pattern of layers
+        [start, stop)."""
+        out = {name: (None if getattr(self, name) is None
+                      else getattr(self, name)[start:stop])
+               for name in ("attn_windows", "rope_layers")}
+        if self.swa is not None:
+            out["swa"] = dataclasses.replace(
+                self.swa, pattern=self.swa.pattern[start:stop])
+        return out
+
+    def kind_cfg(self, kind: str, num_layers: int) -> "ModelConfig":
+        """The config of ``num_layers`` layers of one kind of a model
+        with layer kinds (cfg.swa): a homogeneous stack, its attention
+        fields that kind's own. The ONE derivation shared by execution
+        (transformer.layer_segments), init and sharding."""
+        kw = dict(swa=None, attn_kind=kind, num_layers=num_layers,
+                  dense_prefix_layers=0)
+        if kind == "swa":
+            kw.update(num_kv_heads=self.swa.num_kv_heads,
+                      rope_theta=self.swa.rope_theta,
+                      attn_sinks=self.swa.sinks)
+        else:
+            kw.update(sliding_window=None)
+        return self.replace(**kw)
+
+    def kind_stacks(self):
+        """(param-tree key, that stack's config) of each stack a model
+        with layer kinds has, the empty ones left out: ``layers`` the
+        windowed MoE (or only) layers, ``layers_full`` the full ones,
+        ``layers_dense`` the leading dense layers (one kind). Shared by
+        init and sharding; transformer.layer_segments runs their runs in
+        the pattern's order."""
+        kinds, k = self.swa.kinds(), self.dense_prefix_layers
+        stacks = [("layers", self, "swa", kinds[k:].count("swa")),
+                  ("layers_full", self, "full", kinds[k:].count("full"))]
+        if k:
+            stacks.append(("layers_dense", self.dense_segment_cfg(),
+                           kinds[0], k))
+        return [(name, base.kind_cfg(kind, n))
+                for name, base, kind, n in stacks if n]
+
+    def kind_layers(self, kind: str) -> Tuple[int, ...]:
+        """Indices, in the whole stack, of the layers of ``kind``."""
+        return tuple(i for i, k in enumerate(self.swa.kinds()) if k == kind)
+
+    @property
+    def cache_index(self) -> Tuple[int, ...]:
+        """Per layer, its plane in its own kind's cache (the full
+        layers' block pool, the windowed layers' ring): how many layers
+        of its kind lie before it."""
+        seen = {"swa": 0, "full": 0}
+        out = []
+        for k in self.swa.kinds():
+            out.append(seen[k])
+            seen[k] += 1
+        return tuple(out)
+
+    @property
+    def slot_cache(self) -> bool:
+        """Whether a serving slot holds a cache of its own beside its
+        blocks (state layers' planes, windowed layers' ring): the
+        batcher then matches and inserts no prefix, a chunked prompt
+        keeps its slot, a preempted request is prefilled again."""
+        return self.ssm is not None or self.swa is not None
 
     @property
     def qk_head_dim(self) -> int:
@@ -548,6 +687,8 @@ class ModelConfig:
 
     @property
     def cache_kv_heads(self) -> int:
+        if self.swa is not None:   # the dense cache: the wider kind's
+            return max(self.num_kv_heads, self.swa.num_kv_heads)
         return 1 if self.mla_latent_cache else self.num_kv_heads
 
     @property
@@ -558,7 +699,9 @@ class ModelConfig:
 
     @property
     def cache_v_head_dim(self) -> int:
-        return 0 if self.mla_latent_cache else self.qk_head_dim
+        if self.mla:   # materialized: v rides zero-padded to qk_head_dim
+            return 0 if self.mla_latent_cache else self.qk_head_dim
+        return self.v_head_dim_effective
 
     @property
     def q_dim(self) -> int:
@@ -567,6 +710,11 @@ class ModelConfig:
     @property
     def kv_dim(self) -> int:
         return self.num_kv_heads * self.qk_head_dim
+
+    @property
+    def v_dim(self) -> int:
+        """Width of the v projection (kv_dim but for MiMo-V2)."""
+        return self.num_kv_heads * self.v_head_dim_effective
 
     @property
     def is_moe(self) -> bool:

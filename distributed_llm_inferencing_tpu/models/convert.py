@@ -220,7 +220,7 @@ SUPPORTED_MODEL_TYPES = ("gpt2", "opt", "llama", "mistral", "mixtral",
                          "nemotron", "deepseek_v3", "ernie4_5", "smollm3",
                          "hunyuan_v1_dense", "exaone4", "dbrx", "glm4_moe",
                          "ernie4_5_moe", "gpt_oss", "hunyuan_v1_moe",
-                         "afmoe", "ouro", "falcon_h1")
+                         "afmoe", "ouro", "falcon_h1", "mimo_v2")
 
 
 def config_from_hf(hf_config) -> ModelConfig:
@@ -961,6 +961,76 @@ def config_from_hf(hf_config) -> ModelConfig:
             dense_prefix_layers=nd if 0 < nd < L else 0,
             tie_word_embeddings=getattr(hf_config, "tie_word_embeddings",
                                         False))
+    if mt == "mimo_v2":
+        # XiaomiMiMo MiMo-V2 (config.json, model_type mimo_v2): windowed
+        # and full layers of different shapes in one stack
+        # (hybrid_layer_pattern; models/config.py SWAConfig), q and k
+        # heads of head_dim with the first int(head_dim *
+        # partial_rotary_factor) columns rotated, value heads of
+        # v_head_dim scaled by attention_value_scale, leading dense
+        # layers (moe_layer_freq) and deepseek_v3's routing at one group
+        # with no shared expert. The language model alone: the
+        # multi-token-prediction layers and the vision and audio towers
+        # are not built. Not yet checked against a real checkpoint
+        # (nothing is downloaded): tests/test_mimo_v2.py converts a
+        # synthetic state dict under the names assumed below.
+        from distributed_llm_inferencing_tpu.models.config import SWAConfig
+        g = lambda key, d=None: getattr(hf_config, key, d)   # noqa: E731
+        for key, want in (("scoring_func", "sigmoid"),
+                          ("topk_method", "noaux_tc"),
+                          ("swa_head_dim", g("head_dim")),
+                          ("swa_v_head_dim", g("v_head_dim")),
+                          ("swa_num_attention_heads",
+                           g("num_attention_heads"))):
+            if g(key, want) != want:
+                raise NotImplementedError(
+                    f"mimo_v2 {key}={g(key)!r} — only {want!r} converts")
+        if (g("rope_scaling") or {}).get("rope_type", "default") != "default":
+            raise NotImplementedError("mimo_v2 rope_scaling")
+        if g("n_shared_experts"):
+            raise NotImplementedError("mimo_v2 n_shared_experts")
+        L = hf_config.num_hidden_layers
+        freq = list(g("moe_layer_freq") or [1] * L)
+        nd = freq.index(1) if 1 in freq else L
+        if any(f != 1 for f in freq[nd:]):
+            raise NotImplementedError(
+                "mimo_v2 moe_layer_freq: dense layers behind the first "
+                "expert layer")
+        E = hf_config.n_routed_experts if nd < L else 0
+        return ModelConfig(
+            name=g("name_or_path", mt) or mt, family="mimo_v2",
+            vocab_size=hf_config.vocab_size,
+            hidden_size=hf_config.hidden_size,
+            intermediate_size=hf_config.intermediate_size,
+            moe_intermediate_size=(hf_config.moe_intermediate_size if E
+                                   else None),
+            num_layers=L, num_heads=hf_config.num_attention_heads,
+            num_kv_heads=hf_config.num_key_value_heads,
+            head_dim=hf_config.head_dim, v_head_dim=hf_config.v_head_dim,
+            attn_value_scale=g("attention_value_scale"),
+            max_position_embeddings=hf_config.max_position_embeddings,
+            norm_type="rmsnorm", norm_eps=g("layernorm_epsilon", 1e-5),
+            activation=_act_from_hf(hf_config.hidden_act),
+            gated_mlp=True, position_embedding="rope",
+            rope_theta=float(g("rope_theta", 10000.0)),
+            rope_pct=float(g("partial_rotary_factor", 1.0)),
+            attn_bias=bool(g("attention_bias", False)), mlp_bias=False,
+            attn_sinks=bool(g("add_full_attention_sink_bias", False)),
+            sliding_window=g("sliding_window"),
+            swa=SWAConfig(
+                pattern=tuple(hf_config.hybrid_layer_pattern),
+                num_kv_heads=hf_config.swa_num_key_value_heads,
+                rope_theta=float(g("swa_rope_theta", 10000.0)),
+                sinks=bool(g("add_swa_attention_sink_bias", False))),
+            num_experts=E,
+            num_experts_per_tok=hf_config.num_experts_per_tok,
+            moe_router="deepseek_v3" if E else "softmax",
+            moe_n_group=g("n_group", 1) or 1,
+            moe_topk_group=g("topk_group", 1) or 1,
+            moe_routed_scale=float(g("routed_scaling_factor") or 1.0),
+            moe_norm_topk=bool(g("norm_topk_prob", True)),
+            dense_prefix_layers=nd if 0 < nd < L else 0,
+            tie_word_embeddings=g("tie_word_embeddings", False))
     if mt == "ouro":
         # ByteDance Ouro (modeling_ouro.py, LoopLM): a llama-shaped layer
         # under sandwich norms (input_layernorm_2 and
@@ -1809,6 +1879,67 @@ def convert_state_dict(cfg: ModelConfig, sd, dtype=None):
             "layers": _stack([layer(i) for i in range(cfg.num_layers)]),
             "final_norm": {"scale": get("model.final_layernorm.weight")},
         }
+        if not cfg.tie_word_embeddings:
+            params["lm_head"] = {"w": get("lm_head.weight").T}
+    elif fam == "mimo_v2":
+        # model.layers.N.{input_layernorm, post_attention_layernorm,
+        # self_attn.{qkv_proj (fused rows [q | k | v], cut by the
+        # layer's kind: 4 or 8 K/V heads), o_proj, attention_sink_bias
+        # (windowed layers)}, mlp.{gate,up,down}_proj (dense) or
+        # mlp.{gate.weight, gate.e_score_correction_bias, experts.E.*}};
+        # model.norm; lm_head. NOT yet checked against a real checkpoint:
+        # the names are deepseek_v3's with a fused qkv_proj
+        # (attention_projection_layout: fused_qkv) and may need a
+        # correction when the files are at hand. The value scale is not
+        # folded into Wv: the block applies it at run time.
+        kinds, pref = cfg.swa.kinds(), cfg.dense_prefix_layers
+
+        def layer(i, moe):
+            p = f"model.layers.{i}."
+            kc = cfg.kind_cfg(kinds[i], 1)
+
+            def lin(n):
+                return {"w": get(p + n + ".weight").T}
+            qkv = get(p + "self_attn.qkv_proj.weight").T
+            assert qkv.shape[1] == kc.q_dim + kc.kv_dim + kc.v_dim, (
+                i, kinds[i], qkv.shape)
+            lp = {
+                "attn_norm": {"scale": get(p + "input_layernorm.weight")},
+                "mlp_norm": {
+                    "scale": get(p + "post_attention_layernorm.weight")},
+                "q": {"w": qkv[:, :kc.q_dim]},
+                "k": {"w": qkv[:, kc.q_dim:kc.q_dim + kc.kv_dim]},
+                "v": {"w": qkv[:, kc.q_dim + kc.kv_dim:]},
+                "o": lin("self_attn.o_proj"),
+            }
+            if kc.attn_sinks:
+                lp["sinks"] = get(p + "self_attn.attention_sink_bias")
+            if not moe:
+                lp.update(gate=lin("mlp.gate_proj"), up=lin("mlp.up_proj"),
+                          down=lin("mlp.down_proj"))
+                return lp
+            lp["router"] = {
+                "w": get(p + "mlp.gate.weight").T,
+                "bias": get(p + "mlp.gate.e_score_correction_bias")}
+            first, count = cfg.experts_held or (0, cfg.num_experts)
+            ex = [f"mlp.experts.{e}." for e in range(first, first + count)]
+            lp["experts"] = {
+                nm: {"w": np.stack([get(p + e + f"{nm}_proj.weight").T
+                                    for e in ex])}
+                for nm in ("gate", "up", "down")}
+            return lp
+        params = {
+            "embed": {"tokens": get("model.embed_tokens.weight")},
+            "final_norm": {"scale": get("model.norm.weight")},
+        }
+        for name, kind in (("layers", "swa"), ("layers_full", "full")):
+            mine = [i for i in range(pref, cfg.num_layers)
+                    if kinds[i] == kind]
+            if mine:
+                params[name] = _stack([layer(i, True) for i in mine])
+        if pref:
+            params["layers_dense"] = _stack(
+                [layer(i, False) for i in range(pref)])
         if not cfg.tie_word_embeddings:
             params["lm_head"] = {"w": get("lm_head.weight").T}
     elif fam == "dbrx":

@@ -30,6 +30,17 @@ def init_params(cfg: ModelConfig, key, dtype=None):
         prefix = init_params(cfg.dense_segment_cfg(), k2, dtype)
         tail["layers_dense"] = prefix["layers"]
         return tail
+    if cfg.swa is not None:
+        # layer kinds (MiMo-V2): a stack a kind, each of its own shapes
+        # (transformer.layer_segments runs them in the pattern's order);
+        # embedding, final norm and head come with the first
+        params = None
+        for kk, (name, stack_cfg) in zip(jax.random.split(key, 3),
+                                         cfg.kind_stacks()):
+            part = init_params(stack_cfg, kk, dtype)
+            params = params or part
+            params[name] = part["layers"]
+        return params
     L, D, I = cfg.num_layers, cfg.hidden_size, cfg.intermediate_size
     keys = iter(jax.random.split(key, 64))
 
@@ -117,8 +128,9 @@ def init_params(cfg: ModelConfig, key, dtype=None):
             "attn_norm": norm_p(),
             "q": lin(D, cfg.q_dim, cfg.attn_bias),
             "k": lin(D, cfg.kv_dim, cfg.attn_bias),
-            "v": lin(D, cfg.kv_dim, cfg.attn_bias),
-            "o": lin(cfg.q_dim, D, cfg.o_bias_effective),
+            "v": lin(D, cfg.v_dim, cfg.attn_bias),
+            "o": lin(cfg.num_heads * cfg.v_head_dim_effective, D,
+                     cfg.o_bias_effective),
         }
         if cfg.attn_gate:   # trinity (afmoe): gate on the attention output
             layers["attn_gate"] = lin(D, cfg.q_dim, False)
@@ -168,7 +180,9 @@ def init_params(cfg: ModelConfig, key, dtype=None):
     if cfg.rope_layers is not None:   # per-layer NoPE (smollm3/exaone4)
         layers["rope_on"] = jnp.asarray(cfg.rope_layers, jnp.int32)
     if cfg.attn_sinks:   # gpt-oss: one learned sink logit per head
-        layers["sinks"] = zeros((L, cfg.num_heads))
+        # (MiMo-V2's windowed kind: drawn, so that leaving them out shows)
+        layers["sinks"] = (w((L, cfg.num_heads), 1.0) if cfg.attn_kind
+                           else zeros((L, cfg.num_heads)))
     if not cfg.shared_attn_mlp_norm:   # phi/falcon-7b: one norm per block
         layers["mlp_norm"] = norm_p()
     if cfg.is_moe:
@@ -181,6 +195,8 @@ def init_params(cfg: ModelConfig, key, dtype=None):
             # weighting by unbiased ones differ
             layers["router"]["bias"] = jax.random.normal(
                 next(keys), (L, E), jnp.float32) * 0.02
+        if cfg.experts_held is not None:   # this program's share of them
+            E = cfg.experts_held[1]
         layers["experts"] = {
             "gate": ew((L, E, D, I)),
             "up": ew((L, E, D, I)),

@@ -13,7 +13,7 @@ from __future__ import annotations
 from typing import Dict
 
 from distributed_llm_inferencing_tpu.models.config import (
-    ModelConfig, SSMConfig)
+    ModelConfig, SSMConfig, SWAConfig)
 
 _REGISTRY: Dict[str, ModelConfig] = {}
 
@@ -314,6 +314,33 @@ register(_falcon_h1(
         key_multiplier=0.011048543456039804,
         mlp_multipliers=(0.1767766952966369, 0.011160714285714284))))
 
+# --- MiMo-V2 (XiaomiMiMo): windowed layers (8 K/V heads, a sink a query
+# head, rotary base 1e4) and full layers (4 K/V heads, no sink, base 1e7)
+# in one stack, q and k heads 192 wide with the first 64 columns rotated,
+# v heads 128 wide and scaled, a leading dense layer, then sigmoid-routed
+# experts with no shared one (models/reference/mimo_v2_ref.py has the
+# equations). The language model alone: the multi-token-prediction
+# layers and the vision and audio towers are not built. ---
+def _mimo_v2(name, pattern, swa_kv_heads, swa_rope_theta, **kw):
+    return ModelConfig(
+        name=name, family="mimo_v2", norm_type="rmsnorm", norm_eps=1e-5,
+        activation="silu", gated_mlp=True, position_embedding="rope",
+        attn_bias=False, mlp_bias=False, tie_word_embeddings=False,
+        swa=SWAConfig(pattern=tuple(pattern), num_kv_heads=swa_kv_heads,
+                      rope_theta=swa_rope_theta, sinks=True),
+        moe_router="deepseek_v3", moe_n_group=1, moe_topk_group=1,
+        moe_norm_topk=True, moe_routed_scale=1.0, dense_prefix_layers=1,
+        **kw)
+
+
+register(_mimo_v2(
+    "mimo-v2.5", [0, 1, 1, 1, 1] + [0, 1, 1, 1, 1, 1] * 7 + [0], 8, 1e4,
+    vocab_size=152576, hidden_size=4096, intermediate_size=16384,
+    moe_intermediate_size=2048, num_layers=48, num_heads=64, num_kv_heads=4,
+    head_dim=192, v_head_dim=128, attn_value_scale=0.707, rope_pct=0.334,
+    rope_theta=1e7, sliding_window=128, max_position_embeddings=1048576,
+    num_experts=256, num_experts_per_tok=8))
+
 # --- Tiny configs for tests/dryrun (not real checkpoints) ---
 register(ModelConfig(
     name="tiny-gpt2", family="gpt2", vocab_size=256, hidden_size=64,
@@ -401,3 +428,15 @@ register(_falcon_h1(
         out_multiplier=0.7, multipliers=(0.7, 1.6, 0.5, 1.5, 2.5),
         attn_in_multiplier=1.1, attn_out_multiplier=0.5,
         key_multiplier=0.6, mlp_multipliers=(0.7, 0.45))))
+
+register(_mimo_v2(
+    # mimo-v2.5's switches at toy widths: the benchmark's cut of one
+    # leading dense full layer and one period [windowed x 5, full], K/V
+    # heads 4 against 2, value heads narrower than the q and k heads, 8
+    # of 24 columns rotated, the two rotary bases apart
+    "tiny-mimo-v2", [0, 1, 1, 1, 1, 1, 0], 4, 1e2,
+    vocab_size=256, hidden_size=64, intermediate_size=128,
+    moe_intermediate_size=32, num_layers=7, num_heads=8, num_kv_heads=2,
+    head_dim=24, v_head_dim=16, attn_value_scale=0.707, rope_pct=0.334,
+    rope_theta=1e4, sliding_window=8, max_position_embeddings=256,
+    num_experts=32, num_experts_per_tok=4))

@@ -265,6 +265,9 @@ def _expert_stream(cfg: ModelConfig, ex, n_rows: int, dtype):
     holds at these widths; else None (``lax.ragged_dot``)."""
     if (not cfg.expert_matmul.startswith("pallas")
             or n_rows > _STREAM_ROWS_PER_EXPERT * cfg.num_experts):
+        # (the pairs of ALL the experts against their count: a program
+        # that holds a share of them, cfg.experts_held, sees that share
+        # of the pairs, so the bound is the same rows a held expert)
         return None
     from distributed_llm_inferencing_tpu.ops.pallas import grouped_matmul
     for name in ("gate", "up", "down"):
@@ -275,12 +278,31 @@ def _expert_stream(cfg: ModelConfig, ex, n_rows: int, dtype):
     return cfg.expert_matmul
 
 
+def _held_pieces(cfg: ModelConfig, n_rows: int) -> int:
+    """In how many pieces _moe walks its sorted (token, choice) rows: 1
+    for a model that holds all its experts; for a held share
+    (cfg.experts_held) as many as leave a piece twice the share's part of
+    the rows, where that divides them (16 of 256 held: 8 pieces)."""
+    if cfg.experts_held is None:
+        return 1
+    pieces = cfg.num_experts // (2 * cfg.experts_held[1])
+    return pieces if pieces > 1 and n_rows % pieces == 0 else 1
+
+
 # what _moe counts of one call, in this order (MOE_STATS names them for
 # whoever sums the vectors: runtime/batcher.py). stream_passes: 1 where
 # the call's grouped matmuls took the streaming kernel (a trace-time
 # constant), so stream_passes / layer_passes says which form ran
+# rows_away (last): choices of real tokens that fell on experts this
+# program does not hold (cfg.experts_held); a model that holds all its
+# experts counts the names before it alone (_n_moe_stats), its programs
+# what they were
 MOE_STATS = ("layer_passes", "experts_hit", "max_load", "rows", "idle_rows",
-             "stream_passes")
+             "stream_passes", "rows_away")
+
+
+def _n_moe_stats(cfg: ModelConfig) -> int:
+    return len(MOE_STATS) - (cfg.experts_held is None)
 
 
 def _moe(x, lp, cfg: ModelConfig, valid=None):
@@ -300,12 +322,25 @@ def _moe(x, lp, cfg: ModelConfig, valid=None):
     ``lax.ragged_dot``, or the streaming kernel for a decode chunk's few
     rows an expert; either way a row's product is its own.
 
-    Returns (out like x, stats int32 [6] in MOE_STATS order)."""
+    A program that holds a share of the experts (cfg.experts_held:
+    first, count) routes over all of them and keeps the router's
+    weights, and sums the chosen experts it holds alone: a choice that
+    falls elsewhere sorts behind every real pair as a pad position's
+    does, into no expert's run, and is counted (rows_away). ``E`` below
+    is then the held count and an expert's index its place in the share.
+
+    Returns (out like x, stats int32 [_n_moe_stats] in MOE_STATS order)."""
     *lead, D = x.shape
     xf = x.reshape(-1, D)
     N, E, k = xf.shape[0], cfg.num_experts, cfg.num_experts_per_tok
     idx, w = _moe_route(xf, lp, cfg)
     with jax.named_scope("moe_route"):
+        if cfg.experts_held is not None:
+            first, E = cfg.experts_held
+            away = (idx < first) | (idx >= first + E)
+            if valid is not None:
+                away = away & valid.reshape(N, 1)
+            idx = jnp.where(away, E, idx - first)
         if valid is not None:
             real = valid.reshape(N, 1)
             idx, w = jnp.where(real, idx, E), jnp.where(real, w, 0.0)
@@ -314,20 +349,57 @@ def _moe(x, lp, cfg: ModelConfig, valid=None):
         row_expert = jnp.minimum(expert[order], E - 1)
         group_sizes = jnp.zeros((E,), jnp.int32).at[expert].add(
             1, mode="drop")                  # expert E (not real) is dropped
-        rows = xf[order // k]                               # [N*k, D]
-    with jax.named_scope("moe_experts"):
         ex = lp["experts"]
-        stream = _expert_stream(cfg, ex, N * k, rows.dtype)
+        stream = _expert_stream(cfg, ex, N * k, xf.dtype)
+        pieces = 1 if stream else _held_pieces(cfg, N * k)
+        if pieces == 1:
+            rows = xf[order // k]                           # [N*k, D]
+
+    def experts(rows, group_sizes, row_expert):
         h = _glu_h(_grouped_linear(rows, ex["gate"], group_sizes, row_expert,
                                    stream),
                    _grouped_linear(rows, ex["up"], group_sizes, row_expert,
                                    stream), cfg)
-        y = _grouped_linear(h, ex["down"], group_sizes, row_expert, stream)
+        return _grouped_linear(h, ex["down"], group_sizes, row_expert, stream)
+
+    with jax.named_scope("moe_experts"):
+        if pieces == 1:
+            y = experts(rows, group_sizes, row_expert)
+        else:
+            # a held share: the real pairs are the first n_real sorted
+            # rows, a sixteenth of them at MiMo's share. Walk the sorted
+            # rows a piece at a time as far as the real pairs reach (one
+            # piece unless the router sends this share twice its part),
+            # each piece's runs cut from the experts' own: the gathered
+            # rows, the three products and what lies between them are a
+            # piece's, not every pair's (PERF.md section 6, PR 45)
+            c = N * k // pieces
+            ends = jnp.cumsum(group_sizes)
+
+            def piece(carry):
+                i, y = carry
+                lo = i * c
+                at = jax.lax.dynamic_slice_in_dim
+                sizes = (jnp.clip(ends - lo, 0, c)
+                         - jnp.clip(ends - group_sizes - lo, 0, c))
+                y_c = experts(xf[at(order, lo, c) // k], sizes,
+                              at(row_expert, lo, c))
+                return i + 1, jax.lax.dynamic_update_slice_in_dim(
+                    y, y_c, lo, 0)
+
+            _, y = jax.lax.while_loop(
+                lambda carry: carry[0] * c < ends[-1], piece,
+                (jnp.int32(0), jnp.zeros((N * k, D), xf.dtype)))
     with jax.named_scope("moe_combine"):
         n_real = jnp.sum(group_sizes)
         y = jnp.where((jnp.arange(N * k) < n_real)[:, None], y, 0)
-        y = jnp.zeros_like(y).at[order].set(y).reshape(N, k, D)
-        out = jnp.sum(y.astype(jnp.float32) * w[..., None], axis=1)
+        if pieces == 1:
+            y = jnp.zeros_like(y).at[order].set(y)
+        else:   # a pair reads its row where it lies: no rows are scattered
+            y = y[jnp.zeros_like(order).at[order].set(
+                jnp.arange(N * k, dtype=order.dtype))]
+        out = jnp.sum(y.reshape(N, k, D).astype(jnp.float32) * w[..., None],
+                      axis=1)
         out = out.astype(x.dtype).reshape(*lead, D)
     if cfg.moe_shared_experts:
         with jax.named_scope("mlp"):
@@ -335,10 +407,13 @@ def _moe(x, lp, cfg: ModelConfig, valid=None):
                 * _linear(x, lp["shared_up"])
             out = out + _linear(h, lp["shared_down"],
                                 row_sharded=cfg.tp_row_sharded)
-    stats = jnp.stack([jnp.int32(1), jnp.sum(group_sizes > 0),
-                       jnp.max(group_sizes), n_real, N * k - n_real,
-                       jnp.int32(stream is not None)])
-    return out, stats.astype(jnp.int32)
+    stats = [jnp.int32(1), jnp.sum(group_sizes > 0),
+             jnp.max(group_sizes), n_real, N * k - n_real,
+             jnp.int32(stream is not None)]
+    if cfg.experts_held is not None:
+        stats[4] = stats[4] - jnp.sum(away)   # idle rows: pads alone
+        stats.append(jnp.sum(away))
+    return out, jnp.stack(stats).astype(jnp.int32)
 
 
 def _alibi(cfg: ModelConfig):
@@ -371,10 +446,12 @@ def _cfg_backend(cfg: ModelConfig, n_devices: int = 1):
     b = resolve_backend(cfg.attn_backend, n_devices)
     if b.startswith("pallas") and (cfg.attn_windows is not None
                                    or cfg.attn_softcap is not None
-                                   or cfg.attn_sinks or cfg.mla):
+                                   or cfg.attn_sinks or cfg.mla
+                                   or cfg.swa is not None):
         # mla: qk_head_dim (192) is off the kernels' 128-lane tiling and
         # v rides zero-padded — keep the XLA formulation until a
-        # dedicated MLA kernel exists
+        # dedicated MLA kernel exists. Layer kinds (mimo-v2): 192-wide
+        # heads again, value heads of another width, sinks in one kind
         return "xla"
     return b
 
@@ -510,11 +587,46 @@ def layer_segments(params, cfg: ModelConfig):
     segments — only the MLP half of the block differs — so callers
     slice their [L, ...]-stacked cache/pool planes by (start, count)
     and run the same block body under each segment's cfg."""
+    if cfg.swa is not None:
+        return _kind_segments(params, cfg)
     if "layers_dense" not in params:
         return [(params["layers"], cfg, 0, cfg.num_layers)]
     k = cfg.dense_prefix_layers
     return [(params["layers_dense"], cfg.dense_segment_cfg(), 0, k),
             (params["layers"], cfg, k, cfg.num_layers - k)]
+
+
+def _kind_segments(params, cfg: ModelConfig):
+    """layer_segments of a model with layer kinds (cfg.swa, MiMo-V2):
+    the runs of one kind, in the pattern's order, each under its kind's
+    own config (ModelConfig.kind_cfg: K/V head count, rotary base, sink,
+    window). A kind's layers are a stack of their own, their K, V and
+    sink leaves of that kind's shapes (``layers`` the windowed,
+    ``layers_full`` the full ones, ``layers_dense`` the leading dense
+    layers), stacked [n, ...] or, in the batcher, a list of per-layer
+    trees; a run takes its slice. The kinds' caches differ too: a
+    caller's per-layer arrays are the dense cache's planes, as wide as
+    the wider kind (_block), or each layer's index in its own kind's
+    cache (ModelConfig.cache_index: the block pool, the ring)."""
+    kinds, k = cfg.swa.kinds(), cfg.dense_prefix_layers
+    stacks = {"swa": params.get("layers"), "full": params.get("layers_full")}
+    taken = {"swa": 0, "full": 0}
+    segs, i = [], 0
+    if k:
+        segs.append((params["layers_dense"],
+                     cfg.dense_segment_cfg().kind_cfg(kinds[0], k), 0, k))
+        i = k
+    while i < len(kinds):
+        kind, n = kinds[i], 1
+        while i + n < len(kinds) and kinds[i + n] == kind:
+            n += 1
+        stack, a = stacks[kind], taken[kind]
+        run = (stack[a:a + n] if isinstance(stack, (list, tuple))
+               else jax.tree.map(lambda leaf: leaf[a:a + n], stack))
+        segs.append((run, cfg.kind_cfg(kind, n), i, n))
+        taken[kind] += n
+        i += n
+    return segs
 
 
 def scan_layer_stack(make_body, x, params, cfg: ModelConfig, xs):
@@ -843,7 +955,10 @@ def _block_body(x, lp, cfg: ModelConfig, q_positions, attend_write,
                                           cfg.head_dim)
         v = _lora_apply(_linear(h_attn, lp["v"]), h_attn, lp, "v",
                         lora_ids).reshape(B, s, cfg.num_kv_heads,
-                                          cfg.head_dim)
+                                          cfg.v_head_dim_effective)
+        if cfg.attn_value_scale is not None:   # mimo-v2: the caches
+            # hold the scaled rows
+            v = v * jnp.asarray(cfg.attn_value_scale, v.dtype)
         if cfg.ssm is not None:   # key_multiplier, ahead of the rotation
             k = k * jnp.asarray(cfg.ssm.key_multiplier, k.dtype)
 
@@ -901,7 +1016,7 @@ def _block_tail(x, h, attn, cache_out, lp, cfg: ModelConfig, lora_ids=None,
     ``ssm`` (cfg.ssm): the mixer's output, which joins the attention
     output here, and its new state, which goes out behind ``cache_out``
     (ahead of the stats)."""
-    stats = jnp.zeros((len(MOE_STATS),), jnp.int32)
+    stats = jnp.zeros((_n_moe_stats(cfg),), jnp.int32)
     if ssm is not None:
         mixed, state_out = ssm
         attn = attn * jnp.asarray(cfg.ssm.attn_out_multiplier,
@@ -1092,6 +1207,13 @@ def forward(
     def make_body(seg_cfg):
         def body(x, layer_in):
             lp, planes = layer_in[0], dict(zip(names, layer_in[1:]))
+            # layer kinds: the dense cache is as wide as the wider kind's
+            # K/V heads, and the narrower kind takes its first heads
+            hk = seg_cfg.num_kv_heads
+            wide = planes if (seg_cfg.attn_kind
+                              and planes["k"].shape[2] != hk) else None
+            if wide:
+                planes = {n: p[:, :, :hk] for n, p in planes.items()}
             out = _block(
                 x, lp, planes["k"], planes["v"], cfg=seg_cfg,
                 q_positions=q_positions,
@@ -1101,6 +1223,9 @@ def forward(
                 cache_vs=planes.get("v_scale"),
                 ssm_state=((planes["ssm"], planes["conv"])
                            if "ssm" in planes else None))
+            if wide:
+                return out[0], tuple(wide[n].at[:, :, :hk].set(o)
+                                     for n, o in zip(names, out[1:]))
             return out[0], tuple(out[1:])
         return body
 
@@ -1234,13 +1359,19 @@ def paged_decode_step(params, cfg: ModelConfig, tokens, paged,
 
 
 def _no_state_layers(cfg: ModelConfig, what: str):
-    """The paths that carry no recurrent state refuse a model that has
-    one (cfg.ssm) by name; the decode chunk and the wave admission
-    carry it."""
+    """The paths that carry no per-slot cache refuse a model that has
+    one (cfg.ssm's state, cfg.swa's ring) by name; the decode chunk and
+    the wave admission carry both."""
     if cfg.ssm is not None:
         raise NotImplementedError(
             f"{cfg.name}: {what} carries no state-space state (cfg.ssm); "
             "paged_prefill_tail and paged_decode_chunk do")
+    if cfg.swa is not None:
+        raise NotImplementedError(
+            f"{cfg.name}: {what} carries no window ring (cfg.swa: the "
+            "windowed layers' K and V lie in a ring a slot, the pool holds "
+            "the full layers alone); paged_prefill_tail and "
+            "paged_decode_chunk do")
 
 
 # Cap for materializing the whole chunk's pool gather [L, R, P, Hkv, hd]
@@ -1416,7 +1547,7 @@ def _pool_kernel(cfg: ModelConfig, paged):
     windows) keeps the XLA form, as do int8 pools, meshes, the
     speculative chunk and the CPU."""
     if (not cfg.pool_kernel.startswith("pallas") or paged.quantized
-            or cfg.attn_windows is not None
+            or cfg.attn_windows is not None or cfg.swa is not None
             or cfg.position_embedding == "alibi" or cfg.attn_sinks
             or cfg.attn_softcap is not None
             or paged.k.dtype != jnp.dtype(cfg.dtype)
@@ -1444,6 +1575,32 @@ def _ssm_kernel(cfg: ModelConfig, paged):
                               paged.ssm.dtype):
         return None
     return cfg.pool_kernel
+
+
+def _attend_flat_rows(q, k, v, hkv: int, vd: int, *args, **kw):
+    """ops/attention.attend over caches that store a position's K/V
+    heads side by side in ONE row (ops/paged_kvcache.flat_rows: a model
+    with layer kinds), read as they lie. ``k``, ``v``: segments
+    [R, S, 1, W] (W the plane's width: hkv heads' columns, then zeros).
+    Each query head goes in zero-expanded to the row's width, its own
+    values in its K/V head's columns, so one contraction over the whole
+    row gives its scores (the other heads' columns meet zeros), and of
+    the context that comes back as wide as a V row it keeps its own
+    head's columns. Four (eight) times the products of the head-by-head
+    form, on a handful of query rows; what it spares is the relayout of
+    the gathered K and V that a view of 768 columns as 4 heads of 192
+    costs (0.61 + 0.32 s of an 8 s trace, a layer; PERF.md section 6,
+    PR 45). q [R, Sq, H, hd] -> [R, Sq, H, vd]."""
+    from distributed_llm_inferencing_tpu.ops.attention import attend
+    from distributed_llm_inferencing_tpu.ops.paged_kvcache import fit_rows
+    b, sq, h, hd = q.shape
+    g = h // hkv
+    eye = jnp.eye(hkv, dtype=q.dtype)
+    wide = jnp.einsum("bqhgd,hk->bqhgkd", q.reshape(b, sq, hkv, g, hd),
+                      eye).reshape(b, sq, h, hkv * hd)
+    ctx = attend(fit_rows(wide, k[0]), k, v, *args, scale=hd ** -0.5, **kw)
+    ctx = ctx[..., :hkv * vd].reshape(b, sq, hkv, g, hkv, vd)
+    return jnp.einsum("bqhgkv,hk->bqhgv", ctx, eye).reshape(b, sq, h, vd)
 
 
 def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
@@ -1556,11 +1713,15 @@ def decode_chunk_with_logits(params, cfg: ModelConfig, k: int, tokens, paged,
     second path (benchmarks/chip/compare_reference_loop.py)."""
     from distributed_llm_inferencing_tpu.ops.attention import attend
     from distributed_llm_inferencing_tpu.ops.paged_kvcache import (
-        PagedKVCache, kind_scope, window_read, write_rows)
+        PagedKVCache, flat_rows, kind_scope, ring_read, window_read,
+        write_rows)
     from distributed_llm_inferencing_tpu.ops.sampling import sample_batch
 
     r = tokens.shape[0]
     L = cfg.cache_planes                  # a plane a (loop step, layer)
+    kinds = cfg.swa is not None           # two caches for two layer kinds
+    if kinds:
+        L = paged.k.shape[0]              # the pool's planes: full layers
     bs = paged.block_size
     mb = block_tables.shape[1]
     dt = jnp.dtype(cfg.dtype)             # compute dtype (pool may be int8)
@@ -1592,6 +1753,20 @@ def decode_chunk_with_logits(params, cfg: ModelConfig, k: int, tokens, paged,
         (L, r, k, cfg.cache_kv_heads,
          paged.k.shape[-1] if kernel else cfg.cache_head_dim),
         dt),) * n_planes
+    if kinds:
+        # a side buffer a kind and plane: the full layers' rows go to the
+        # pool after the scan, the windowed layers' to the ring, which
+        # holds what lies below the chunk's horizon (ring_read: fixed for
+        # the chunk like the pool's), slot r in row r
+        assert paged.ring_k.shape[1] == r + 1, (paged.ring_k.shape, r)
+        vd = cfg.v_head_dim_effective
+
+        def sides(planes):   # rows as the caches store them: flat
+            return tuple(jnp.zeros((p.shape[0], r, k, 1, p.shape[-1]), dt)
+                         for p in planes)
+        side0 = sides(paged.planes()) + sides((paged.ring_k, paged.ring_v))
+        ring = paged.ring_k.shape[2]
+        ring_pos, ring_valid = ring_read(ring, cl0)
     if cfg.ssm is not None:
         # state layers: the per-slot state and conv planes ride the
         # carry behind the side buffers, whole, and each layer of each
@@ -1623,6 +1798,8 @@ def decode_chunk_with_logits(params, cfg: ModelConfig, k: int, tokens, paged,
     window_positions = (jnp.int32(max(v[1].shape[1]
                                       for v in win_reads.values()))
                         if win_reads else pool_positions)
+    if kinds:   # what a windowed layer reads instead: its slot's ring
+        window_positions = jnp.int32(ring)
 
     def body(carry, t):
         cur, side, cl, alive = carry
@@ -1652,9 +1829,19 @@ def decode_chunk_with_logits(params, cfg: ModelConfig, k: int, tokens, paged,
                 def attend_side(q, sd2, sliding_window=None, **kw):
                     kind = _layer_kind(cfg, sliding_window)
 
+                    if kinds:
+                        kind = "attention_full"
+
                     def attend_pool(got, pos, valid):
                         if latent:   # the rows' own columns (lane_width)
                             got = (got[0][..., :cfg.cache_head_dim],)
+                        if kinds:   # a position's heads lie in one row
+                            with jax.named_scope("attention"), \
+                                    kind_scope(kind):
+                                return _attend_flat_rows(
+                                    q, (got[0], sd2[0]), (got[1], sd2[1]),
+                                    cfg.num_kv_heads, vd, q_pos,
+                                    (pos, side_pos), (valid, side_valid))
                         with jax.named_scope("attention"), kind_scope(kind):
                             # a latent pool's rows stand for K and for V
                             return attend(
@@ -1702,8 +1889,29 @@ def decode_chunk_with_logits(params, cfg: ModelConfig, k: int, tokens, paged,
                         x, lp, seg_cfg, q_pos, None,
                         mla_latent_attend=mla_latent_attend, **tail))
 
+                def attend_ring(q, kh, vh):
+                    # a windowed layer: its slot's ring below the
+                    # horizon and the chunk's own rows, one softmax
+                    sd2, rows = _write_side(
+                        sd[2:], (flat_rows(kh), flat_rows(vh)), t, li)
+                    with jax.named_scope("attention"), \
+                            jax.named_scope("attention_swa"):
+                        return _attend_flat_rows(
+                            q, (paged.ring_k[li, :r], rows[0]),
+                            (paged.ring_v[li, :r], rows[1]),
+                            cfg.swa.num_kv_heads, vd, q_pos,
+                            (ring_pos, side_pos), (ring_valid, side_valid),
+                            sliding_window=seg_cfg.sliding_window,
+                            sinks=_sinks(seg_cfg, lp)), sd[:2] + sd2
+
                 def attend_write(q, kh, vh):
+                    if seg_cfg.attn_kind == "swa":
+                        return attend_ring(q, kh, vh)
+                    if kinds:
+                        kh, vh = flat_rows(kh), flat_rows(vh)
                     sd2, rows = _write_side(sd[:n_planes], (kh, vh), t, li)
+                    if kinds:
+                        sd2 = sd2 + sd[2:]
                     if kernel:
                         return attend_kernel(q, rows), sd2
                     return attend_side(
@@ -1716,7 +1924,8 @@ def decode_chunk_with_logits(params, cfg: ModelConfig, k: int, tokens, paged,
 
         (x2, side), (moe,) = loop_layer_stack(
             make_layer, (x, side), params, cfg,
-            (jnp.arange(L, dtype=jnp.int32),))
+            (jnp.asarray(cfg.cache_index, jnp.int32) if kinds
+             else jnp.arange(L, dtype=jnp.int32),))
         logits = unembed(params, cfg, x2)[:, 0]
         with jax.named_scope("sample"):
             nxt = sample_batch(logits, seeds, steps0 + t, temps, tks, tps,
@@ -1753,6 +1962,16 @@ def decode_chunk_with_logits(params, cfg: ModelConfig, k: int, tokens, paged,
             side = (k8, v8, ks, vs)
         if cfg.ssm is not None:   # the state planes, as the passes left them
             paged = paged._replace(ssm=side[n_planes], conv=side[n_planes + 1])
+        if kinds:
+            # the windowed layers' rows: position p at p % ring of the
+            # slot's own row, a dead slot's in the dummy row
+            with jax.named_scope("ring_write"):
+                row = jnp.where(wrote, jnp.arange(r)[None, :], r)   # [K, R]
+                paged = paged._replace(**{
+                    name: write_rows(getattr(paged, name),
+                                     jnp.swapaxes(sd, 1, 2), row, pos % ring)
+                    for name, sd in zip(("ring_k", "ring_v"), side[2:])})
+            side = side[:2]
         return (toks, emits, moe, pool_positions, window_positions,
                 paged.with_planes(tuple(
                     write_rows(plane, jnp.swapaxes(sd, 1, 2), blk, off)
@@ -1988,6 +2207,120 @@ def paged_speculative_chunk(params, cfg: ModelConfig, k: int, gamma: int,
     return toks, keeps, eos_seen, paged
 
 
+def _kinds_prefill_tail(params, cfg: ModelConfig, tokens, tail_len,
+                        tail_blocks, prefix_blocks, prefix_len, paged,
+                        slots):
+    """paged_prefill_tail for a model with layer kinds (cfg.swa,
+    MiMo-V2): two caches for two kinds of layer. A full layer gathers
+    its cached prefix (a chunked prompt's earlier chunks: such a model
+    matches no other) from the block pool, which holds the full layers
+    alone, and hands its tail's K and V rows out for the pool; a
+    windowed layer reads its slot's ring (``slots`` [B], the dummy row
+    for a padding row) for what lies before the tail and keeps the
+    tail's last ring_positions rows for it (ops/paged_kvcache.ring_read,
+    ring_take). The kinds' rows differ in shape, so they ride the layer
+    stack's carry, a buffer a kind and plane written in place at the
+    layer's index in its own kind's cache (cfg.cache_index), and after
+    the stack each cache takes its rows in ONE scatter a plane, in
+    place in the donated tree."""
+    from distributed_llm_inferencing_tpu.ops.attention import attend
+    from distributed_llm_inferencing_tpu.ops.paged_kvcache import (
+        flat_rows, head_rows, paged_attend_prefix, ring_read, ring_take,
+        write_blocks, write_rows)
+    b, t = tokens.shape
+    ring = paged.ring_k.shape[2]
+    take, ring_row, ring_off = ring_take(
+        t, tail_len, prefix_len, slots, paged.ring_k.shape[1] - 1, ring)
+    hd, vd = cfg.head_dim, cfg.v_head_dim_effective
+    dt = jnp.dtype(cfg.dtype)
+    q_pos = prefix_len[:, None] + jnp.broadcast_to(
+        jnp.arange(t, dtype=jnp.int32), (b, t))
+    tail_valid = jnp.arange(t, dtype=jnp.int32)[None, :] < tail_len[:, None]
+    ring_pos, ring_valid = ring_read(ring, prefix_len)
+    x = embed(params, cfg, tokens, q_pos)
+    # a windowed layer's tail goes in bands: the window is no longer than
+    # the ring, so a query block of the ring's length sees the block
+    # before it (the ring itself, for the first) and its own, and a tail
+    # of 2048 holds 16 x (128 x 256) scores a head, not 2048 x 2176. A
+    # tail the ring's length does not divide is one band
+    band = ring if t % ring == 0 else t
+
+    def fold(a):          # [B, t, ...] -> a row a band
+        return a.reshape(b * (t // band), band, *a.shape[2:])
+
+    def before(ring_a, a):   # what lies before each band, folded alike
+        if band == t:
+            return ring_a
+        return fold(jnp.concatenate([ring_a, a[:, :t - band]], axis=1))
+
+    def make_body(seg_cfg):
+        windowed = seg_cfg.attn_kind == "swa"
+
+        def body(carry, layer_in):
+            (x, full_rows, swa_rows), (lp, li) = carry, layer_in
+            out = {}
+
+            def put(bufs, rows):   # as the caches store them: flat
+                return tuple(jax.lax.dynamic_update_slice(
+                    buf, flat_rows(r).astype(buf.dtype)[None],
+                    (li, 0, 0, 0, 0)) for buf, r in zip(bufs, rows))
+
+            def attend_write(q, k, v):
+                if not windowed:
+                    attn = paged_attend_prefix(
+                        q, k, v, paged.k, paged.v, prefix_blocks,
+                        prefix_len, q_pos, tail_valid,
+                        kind="attention_full", layer=li)
+                    out["full"] = put(full_rows, (k, v))
+                    return attn, ()
+                with jax.named_scope("kv_gather"), \
+                        jax.named_scope("attention_swa"):
+                    rk = head_rows(paged.ring_k[li, slots], *k.shape[-2:])
+                    rv = head_rows(paged.ring_v[li, slots], *v.shape[-2:])
+                with jax.named_scope("attention"), \
+                        jax.named_scope("attention_swa"):
+                    attn = attend(
+                        fold(q), (before(rk, k), fold(k)),
+                        (before(rv, v), fold(v)), fold(q_pos),
+                        (before(ring_pos, q_pos), fold(q_pos)),
+                        (before(ring_valid, tail_valid), fold(tail_valid)),
+                        sliding_window=seg_cfg.sliding_window,
+                        sinks=_sinks(seg_cfg, lp)).reshape(b, t, -1, vd)
+                with jax.named_scope("ring_write"):
+                    out["swa"] = put(swa_rows, tuple(
+                        jnp.take_along_axis(r, take[:, :, None, None],
+                                            axis=1) for r in (k, v)))
+                return attn, ()
+
+            x, _ = _block_body(x, lp, seg_cfg, q_pos, attend_write,
+                               valid=tail_valid)
+            return (x, out.get("full", full_rows),
+                    out.get("swa", swa_rows)), ()
+        return body
+
+    def bufs(n_layers, heads, n):
+        return (jnp.zeros((n_layers, b, n, 1, heads * hd), dt),
+                jnp.zeros((n_layers, b, n, 1, heads * vd), dt))
+    carry = (x, bufs(paged.k.shape[0], cfg.num_kv_heads, t),
+             bufs(paged.ring_k.shape[0], cfg.swa.num_kv_heads,
+                  take.shape[1]))
+    (x, full_rows, swa_rows), _ = scan_layer_stack(
+        make_body, carry, params, cfg,
+        (jnp.asarray(cfg.cache_index, jnp.int32),))
+    with jax.named_scope("kv_write"):
+        paged = paged.with_planes(tuple(
+            write_blocks(plane, rows, tail_blocks)
+            for plane, rows in zip(paged.planes(), full_rows)))
+    with jax.named_scope("ring_write"):
+        paged = paged._replace(
+            ring_k=write_rows(paged.ring_k, swa_rows[0], ring_row, ring_off),
+            ring_v=write_rows(paged.ring_v, swa_rows[1], ring_row, ring_off))
+    last_x = jnp.take_along_axis(
+        x, jnp.maximum(tail_len - 1, 0)[:, None, None].astype(jnp.int32),
+        axis=1)
+    return unembed(params, cfg, last_x)[:, 0], paged
+
+
 def paged_prefill_tail(params, cfg: ModelConfig, tokens, tail_len,
                        tail_blocks, prefix_blocks, prefix_len, paged,
                        lora_ids=None, slots=None):
@@ -2032,6 +2365,10 @@ def paged_prefill_tail(params, cfg: ModelConfig, tokens, tail_len,
     """
     from distributed_llm_inferencing_tpu.ops.paged_kvcache import (
         PagedKVCache, paged_attend_prefix, write_blocks)
+    if cfg.swa is not None:
+        return _kinds_prefill_tail(params, cfg, tokens, tail_len,
+                                   tail_blocks, prefix_blocks, prefix_len,
+                                   paged, slots)
     b, t = tokens.shape
     if tail_blocks.ndim == 1:
         tail_blocks = tail_blocks[None]

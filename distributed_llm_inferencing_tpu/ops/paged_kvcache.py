@@ -37,6 +37,32 @@ vLLM/PagedAttention:
   blocks only, so the batcher matches no prefix for such a model
   (runtime/batcher.py).
 
+- ``ring_k``/``ring_v``: a third kind of cache in the same tree, for a
+  model whose windowed layers are a kind of their own (cfg.swa,
+  MiMo-V2): their K and V, [L_swa, R + 1, ring, 1, Hkv_swa * w], a row a
+  serving *slot* as the state planes have (row R the dummy row), and in
+  a slot's row position p at ``p % ring``. ring_positions(cfg, bs) is
+  the window in whole blocks: a windowed layer's query at or past a
+  program's horizon (a tail's first position, a decode chunk's first)
+  sees at most window - 1 positions before the horizon, the program's
+  own rows ride beside the ring (the fresh tail, the chunk's side
+  buffers) and go into it after the stack, in one scatter a plane. Its
+  bytes do not grow with max_seq. Row j of a slot whose horizon is h
+  holds position h - 1 - ((h - 1 - j) mod ring) (ring_read), which a
+  slot's present tenant wrote if it is not negative: a reused slot needs
+  no clearing. The block pool of such a model holds its FULL layers'
+  K and V alone ([L_full, ...]); a layer's index into either is
+  cfg.cache_index. In both a position's heads lie side by side in ONE
+  row (flat_rows: [.., 1, Hkv * w], MiMo-V2's 4 x 192 = 768 and 4 x 128
+  = 512 columns in the pool, 8 x 192 and 8 x 128 in the ring: whole
+  128-lane tiles, nothing padded), as a latent pool's one plane lies: a
+  head axis of 4 leaves half of every (8, 128) tile empty and XLA
+  re-tiled such a pool around each wave's write (four pool-sized copies
+  a program in the described v5e compile), and a minor axis of 192 is no
+  whole tile (lane_width). head_rows views a read as heads again. As
+  with the state planes, the radix cache, the arena and the wire move
+  blocks only, so the batcher matches no prefix for such a model.
+
 Attention over the paged cache gathers each slot's blocks back into a
 contiguous [R, MB*bs, ...] view, which ``attend`` then reads once as it
 is (grouped-query form, no copy: ops/attention.py). The gather writes that
@@ -95,6 +121,19 @@ def fit_rows(rows, plane):
     return jnp.pad(rows, [(0, 0)] * (rows.ndim - 1) + [(0, pad)])
 
 
+def flat_rows(rows):
+    """[..., H, w] -> [..., 1, H * w]: a position's heads side by side
+    in one row, as the pool and ring of a model with layer kinds store
+    them (init_paged_cache)."""
+    return rows.reshape(*rows.shape[:-2], 1, -1)
+
+
+def head_rows(flat, heads: int, w: int):
+    """[..., 1, W] (flat_rows, perhaps padded to whole lanes) ->
+    [..., heads, w]."""
+    return flat[..., 0, :heads * w].reshape(*flat.shape[:-2], heads, w)
+
+
 class PagedKVCache(NamedTuple):
     k: jax.Array   # [L, NB, bs, Hkv, hd] (model dtype, or int8)
     v: Optional[jax.Array] = None   # like k; None in a latent pool
@@ -106,6 +145,10 @@ class PagedKVCache(NamedTuple):
     # [L, R + 1, H, P, N] float32 and [L, R + 1, (d_conv - 1) * conv_dim]
     ssm: Optional[jax.Array] = None
     conv: Optional[jax.Array] = None
+    # windowed layers' per-slot ring (cfg.swa), no part of planes():
+    # [L_swa, R + 1, ring, 1, lane_width(Hkv_swa * w)] each
+    ring_k: Optional[jax.Array] = None
+    ring_v: Optional[jax.Array] = None
 
     @property
     def num_blocks(self) -> int:
@@ -146,6 +189,60 @@ class PagedKVCache(NamedTuple):
         return sum(p.size * p.dtype.itemsize
                    for p in (self.ssm, self.conv)) // self.ssm.shape[1]
 
+    @property
+    def ring_bytes_per_slot(self) -> int:
+        """Bytes of windowed layers' K and V one serving slot holds over
+        all of them (0 for a model without a ring)."""
+        if self.ring_k is None:
+            return 0
+        return sum(p.size * p.dtype.itemsize
+                   for p in (self.ring_k, self.ring_v)) \
+            // self.ring_k.shape[1]
+
+
+RING_MAX = 256   # positions: a wider window is not a slot's to hold
+
+
+def ring_positions(cfg: ModelConfig, block_size: int) -> int:
+    """Positions a slot's ring holds (cfg.swa): the window, in whole
+    blocks. A model whose window is wider than RING_MAX is refused: its
+    windowed layers want blocks freed behind the window, not a ring."""
+    n = -(-cfg.sliding_window // block_size) * block_size
+    if n > RING_MAX:
+        raise ValueError(
+            f"{cfg.name}: a window of {cfg.sliding_window} positions is "
+            f"more than a slot's ring holds ({RING_MAX})")
+    return n
+
+
+def ring_read(ring: int, horizon):
+    """What a slot's ring holds for a program whose first own position
+    is ``horizon`` [R]: (positions [R, ring], valid [R, ring]). Row j
+    holds the last position below the horizon that is j mod ring; it is
+    the slot's present tenant's if it is not negative. The window mask
+    stays the exact cut."""
+    j = jnp.arange(ring, dtype=jnp.int32)[None, :]
+    last = horizon[:, None] - 1
+    pos = last - (last - j) % ring
+    return pos, pos >= 0
+
+
+def ring_take(t: int, tail_len, prefix_len, slots, dummy_row: int,
+              ring: int):
+    """Which of a wave's fresh tail rows ([B, t, ...]) the ring keeps:
+    (index into the tail [B, n], slot row [B, n], offset [B, n]) with
+    n = min(t, ring): each wave row's last n real positions, position p
+    at p % ring of its slot's row. Entries before a tail's first
+    position (a tail shorter than n) go to the dummy row, as a padding
+    wave row's all do (its ``slots`` entry is the dummy row already);
+    positions past ``tail_len`` in a padded bucket are never taken."""
+    n = min(t, ring)
+    i = tail_len[:, None] - n + jnp.arange(n, dtype=jnp.int32)[None, :]
+    real = i >= 0
+    i = jnp.maximum(i, 0)
+    return (i, jnp.where(real, slots[:, None], dummy_row),
+            (prefix_len[:, None] + i) % ring)
+
 
 def init_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
                      dtype=None, slots: int = 0) -> PagedKVCache:
@@ -153,6 +250,20 @@ def init_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
     state row and a conv row for each of ``slots`` serving slots and one
     dummy row behind them."""
     dtype = dtype or jnp.dtype(cfg.dtype)
+    if cfg.swa is not None:
+        # the full layers' pool and the windowed layers' ring
+        if cfg.kv_quant is not None:
+            raise ValueError("layer kinds keep an unquantized pool and ring")
+        n_full = len(cfg.kind_layers("full"))
+        n_swa = cfg.num_layers - n_full
+        ring = ring_positions(cfg, block_size)
+
+        def planes(lead, heads):   # a position's heads in one row
+            return tuple(jnp.zeros(lead + (1, lane_width(heads * w)), dtype)
+                         for w in (cfg.head_dim, cfg.v_head_dim_effective))
+        k, v = planes((n_full, num_blocks, block_size), cfg.num_kv_heads)
+        rk, rv = planes((n_swa, slots + 1, ring), cfg.swa.num_kv_heads)
+        return PagedKVCache(k=k, v=v, ring_k=rk, ring_v=rv)
     if cfg.ssm is not None:
         if cfg.kv_quant is not None or cfg.mla_latent_cache:
             raise ValueError("state layers keep an unquantized K and V pool")
@@ -423,6 +534,10 @@ def paged_attend_prefix(q, k_new, v_new, cache_k_layer, cache_v_layer,
                 vp, gather_seq(v_scale_layer, prefix_blocks, layer), q.dtype)
     if expand_rows is not None:
         kp, vp = expand_rows(kp)
+    elif kp.shape[-2:] != k_new.shape[-2:]:
+        # a pool that stores a position's heads in one row (flat_rows)
+        kp = head_rows(kp, *k_new.shape[-2:])
+        vp = head_rows(vp, *v_new.shape[-2:])
     if read is None:
         p = prefix_blocks.shape[1] * bs
         prefix_pos = jnp.broadcast_to(jnp.arange(p, dtype=jnp.int32), (b, p))

@@ -48,6 +48,15 @@ def param_specs(cfg: ModelConfig, spec: MeshSpec,
                              shard_layers_over_pp)
         tail["layers_dense"] = prefix["layers"]
         return tail
+    if cfg.swa is not None:
+        # layer kinds (mimo-v2): a stack a kind, as init_params builds
+        # them (the batcher serves such a model on one device only)
+        specs = None
+        for name, stack_cfg in cfg.kind_stacks():
+            part = param_specs(stack_cfg, spec, shard_layers_over_pp)
+            specs = specs or part
+            specs[name] = part["layers"]
+        return specs
     kv_tp = kv_head_axis(cfg.num_kv_heads, spec.tp)
     L = "pp" if shard_layers_over_pp else None
 
@@ -233,6 +242,11 @@ def paged_cache_specs(cfg: ModelConfig, spec: MeshSpec):
     L = "pp" if spec.pp > 1 else None
     kv = P(L, None, None, kv_tp, None)
     scale = P(L, None, None, kv_tp) if cfg.kv_quant else None
+    if cfg.swa is not None:
+        # the full layers' pool and the windowed layers' per-slot ring,
+        # replicated (one device only, as for state layers below)
+        rep = P(None, None, None, None, None)
+        return PagedKVCache(k=rep, v=rep, ring_k=rep, ring_v=rep)
     if cfg.ssm is not None:
         # the per-slot state planes, replicated (the batcher serves a
         # model with state layers on one device only)

@@ -85,28 +85,54 @@ PREFIX_BUCKETS = (0, 1, 2, 4, 8, 16, 32, 64, 128, 256, 512)  # blocks
 # widest wave 64 slots form over no cached prefix, 64 rows of a 512 tail
 # and the one dummy prefix block; over 512 blocks of 16 it leaves 2 rows.
 WAVE_SCORE_BUDGET = 64 * 512 * (512 + 16)
-# ... and, for a model with state layers, the bytes of per-token
-# transients one admission program may hold at its widest point
-# (_wave_token_budget: the MLP's gate, up and product rows, or the
-# chunked scan's projections and float32 rows): Falcon-H1-34B's 129 KB a
-# token make it 4,096 tokens a wave, 0.5 GB, where the score budget
-# alone would let 32,768 through (4.2 GB beside 12.9 GB of arguments).
+WAVE_SCORE_HEADS = 32   # ... the head count it was sized at
+# ... and, for a model with a per-slot cache (state layers, ring
+# layers), the bytes of per-token transients one admission program may
+# hold at its widest point (_wave_token_budget: the MLP's gate, up and
+# product rows, or the chunked scan's projections and float32 rows):
+# Falcon-H1-34B's 129 KB a token make it 4,096 tokens a wave, 0.5 GB,
+# where the score budget alone would let 32,768 through (4.2 GB beside
+# 12.9 GB of arguments); MiMo-V2.5's dense layer (16,384 wide: 96 KB a
+# token) 6,826, so 2 rows of 2048 / 4 of 1024 / 8 of 512 / 16 of 256.
 WAVE_TRANSIENT_BYTES = 640 * 1024 * 1024
+
+
+def _asked_host_mb(kv_host_mb: Optional[float]) -> float:
+    """The host arena a caller asked for, by keyword or DLI_KV_HOST_MB
+    (0: none asked): what a model whose slots hold a cache of their own
+    refuses above 0 (the arena moves blocks alone)."""
+    if kv_host_mb is not None:
+        return kv_host_mb
+    try:
+        return float(os.environ.get("DLI_KV_HOST_MB", 0))
+    except ValueError:
+        return 0
+
+
+def _wave_score_budget(cfg: ModelConfig) -> float:
+    """Most score elements a head one admission program may hold: the
+    bound is in bytes (heads x 4 B x elements), so a model of more than
+    WAVE_SCORE_HEADS query heads takes fewer elements a head
+    (MiMo-V2.5's 64: half). The models it was sized with keep
+    WAVE_SCORE_BUDGET."""
+    return WAVE_SCORE_BUDGET * min(1.0, WAVE_SCORE_HEADS / cfg.num_heads)
 
 
 def _wave_token_budget(cfg: ModelConfig) -> float:
     """Most tokens (rows x tail, as bucketed) one admission program may
     carry, from the widest per-token transient of the MLP and of the
-    chunked scan. A model without state layers has no such bound: its
-    waves are cut by WAVE_SCORE_BUDGET alone, as they were."""
-    if cfg.ssm is None:
+    chunked scan. A model without a per-slot cache has no such bound:
+    its waves are cut by the score budget alone, as they were."""
+    if not cfg.slot_cache:
         return float("inf")
-    c, item = cfg.ssm, jnp.dtype(cfg.dtype).itemsize
-    mlp = 3 * cfg.intermediate_size * item
-    scan = ((c.proj_dim + 2 * c.conv_dim) * item
-            + 4 * (c.conv_dim + 3 * c.d_ssm
-                   + 2 * c.chunk_size * c.n_heads))
-    return WAVE_TRANSIENT_BYTES // max(mlp, scan)
+    item = jnp.dtype(cfg.dtype).itemsize
+    widest = 3 * cfg.intermediate_size * item
+    if cfg.ssm is not None:
+        c = cfg.ssm
+        widest = max(widest, (c.proj_dim + 2 * c.conv_dim) * item
+                     + 4 * (c.conv_dim + 3 * c.d_ssm
+                            + 2 * c.chunk_size * c.n_heads))
+    return WAVE_TRANSIENT_BYTES // widest
 
 
 @dataclasses.dataclass
@@ -409,12 +435,7 @@ class ContinuousBatcher:
             # recurrent state and a conv window a serving slot beside the
             # block pool (ops/paged_kvcache.py); what does not carry
             # them is refused here by name, not served without a state
-            host_mb = kv_host_mb
-            if host_mb is None:
-                try:
-                    host_mb = float(os.environ.get("DLI_KV_HOST_MB", 0))
-                except ValueError:
-                    host_mb = 0
+            host_mb = _asked_host_mb(kv_host_mb)
             refused = [why for why, hit in (
                 ("speculative decoding (a rejected draft's state cannot "
                  "be rolled back)", bool(speculative)),
@@ -432,6 +453,30 @@ class ContinuousBatcher:
                 raise ValueError(
                     f"{cfg.name}: state-space layers cannot take "
                     + "; ".join(refused))
+            kv_host_mb = 0   # unset: no arena for this model
+        if cfg.swa is not None:
+            # layer kinds (MiMo-V2): the windowed layers' K and V lie in
+            # a ring a serving slot beside the pool, which holds the full
+            # layers alone (ops/paged_kvcache.py); what carries no ring
+            # is refused here by name, not served from half a cache
+            host_mb = _asked_host_mb(kv_host_mb)
+            refused = [why for why, hit in (
+                ("speculative decoding (paged_speculative_chunk carries "
+                 "one kind of side buffer and no ring)", bool(speculative)),
+                ("pp > 1 or any mesh of more than one device "
+                 "(parallel/paged_pipeline.py and sharding.py have no "
+                 "rule for the ring or for a share of the experts)",
+                 self.mesh_spec.num_devices > 1),
+                ("kv_quant (pool and ring are not quantized)",
+                 cfg.kv_quant is not None),
+                ("kv_host_mb > 0 / DLI_KV_HOST_MB (the host arena, "
+                 "kvwire fetches and migrate_out move the pool's blocks, "
+                 "no ring)", host_mb > 0))
+                if hit]
+            if refused:
+                raise ValueError(
+                    f"{cfg.name}: windowed layers in a per-slot ring "
+                    "cannot take " + "; ".join(refused))
             kv_host_mb = 0   # unset: no arena for this model
         self.cfg = cfg = cfg.replace(
             # the paged programs read no attention backend (the dense
@@ -580,8 +625,11 @@ class ContinuousBatcher:
                                            self.mesh_spec)
             del params     # or the stacked leaves below live on in it
             if cfg.is_moe and self.mesh_spec.pp == 1:
-                self.params["layers"] = _unstack_layers(
-                    self.params.pop("layers"))
+                # (a model with layer kinds: each kind's MoE stack)
+                for name in ("layers", "layers_full"):
+                    if name in self.params:
+                        self.params[name] = _unstack_layers(
+                            self.params.pop(name))
 
         # +1: block 0 is the reserved dummy every inactive table entry
         # points at, so it never carries real KV
@@ -603,12 +651,21 @@ class ContinuousBatcher:
                            float(self._state_bytes_per_slot))
         self.metrics.inc("batcher_ssm_scan_positions", 0)
         self.metrics.inc("batcher_ssm_step_slot_passes", 0)
+        # ring layers: what a slot's ring takes (0 for a model without
+        # one), and the ring positions a windowed layer read, a slot,
+        # summed over decode passes (beside batcher_decode_pool_positions)
+        self._ring_bytes_per_slot = self.paged.ring_bytes_per_slot
+        self.metrics.gauge("batcher_kv_ring_bytes_per_slot",
+                           float(self._ring_bytes_per_slot))
+        self.metrics.inc("batcher_decode_ring_positions", 0)
         # what one cached token takes of the pool, from the pool's own
         # shape (a latent pool: L x lane_width(rd + r) x 2 bytes)
         self.metrics.gauge("batcher_kv_bytes_per_token",
                            float(self.paged.bytes_per_token))
         if cfg.is_moe:
-            for name in transformer.MOE_STATS:
+            # (experts_held: layer passes x the experts this program
+            # holds; rows_away stays 0 where it holds them all)
+            for name in transformer.MOE_STATS + ("experts_held",):
                 self.metrics.inc(f"batcher_moe_{name}", 0)
         self.block_tables = np.full((slots, self.max_blocks), self._dummy,
                                     np.int32)
@@ -1155,8 +1212,9 @@ class ContinuousBatcher:
                 pfb = ints[b * (t + nb):b * (t + nb + pb)].reshape(b, pb)
                 rest = ints[b * (t + nb + pb):]
                 kw = {}
-                if cfg.ssm is not None:
-                    # state layers: each row's serving slot rides last
+                if cfg.slot_cache:
+                    # state layers, ring layers: each row's serving slot
+                    # rides last
                     rest, kw["slots"] = rest[:-b], rest[-b:]
                 if use_lora:
                     tl, pfl, seeds, steps, tks, ds, aids = \
@@ -1220,7 +1278,7 @@ class ContinuousBatcher:
                         p, cfg, k, tokens, paged, bt, cl, seeds, steps0,
                         temps, tks, tps, ds.astype(bool), budget, eos_ids,
                         dummy, lora_ids=aids)
-                if cfg.attn_windows is not None:
+                if cfg.attn_windows is not None or cfg.swa is not None:
                     # [pool, window]; a model of one kind keeps the
                     # program it had
                     pool_pos = jnp.stack([pool_pos, win_pos])
@@ -1393,12 +1451,19 @@ class ContinuousBatcher:
                      pool_positions))
             for name, n in zip(transformer.MOE_STATS, moe):
                 self.metrics.inc(f"batcher_moe_{name}", int(n))
+            if self.cfg.is_moe:
+                self.metrics.inc(
+                    "batcher_moe_experts_held", int(moe[0]) * (
+                        self.cfg.experts_held or (0, self.cfg.num_experts))[1])
             self._pool_positions, self._window_positions = (
                 int(n) for n in np.broadcast_to(pool_positions, (2,)))
             self.metrics.inc("batcher_decode_pool_positions",
                              self._pool_positions * int(a["k"]))
             self.metrics.inc("batcher_decode_window_positions",
                              self._window_positions * int(a["k"]))
+            self.metrics.inc("batcher_decode_ring_positions",
+                             self._window_positions * int(a["k"])
+                             if self.cfg.swa is not None else 0)
             self.metrics.inc("batcher_pool_kernel_passes",
                              int(a["k"]) * self.pool_kernel)
             return toks, emits
@@ -2097,10 +2162,11 @@ class ContinuousBatcher:
         the scheduler never serviced the flag within ``timeout``."""
         if self.program_hook is not None:
             return None          # lockstep: host-side evict can't ride
-        if self.cfg.ssm is not None:
+        if self.cfg.slot_cache:
             raise ValueError(
                 f"{self.cfg.name}: migrate_out exports K and V blocks; a "
-                "state-space layer's state is not among them")
+                "state-space layer's state and a windowed layer's ring "
+                "are not among them")
         req._migrate_requested = True
         self._work.set()
         if not req.done.wait(timeout):
@@ -2229,16 +2295,17 @@ class ContinuousBatcher:
         n = len(prompt)
         # Leave >=1 token for the tail: prefill must produce the last
         # token's logits (a fully-cached prompt would have nothing to run).
-        if self.cfg.ssm is None:
+        if not self.cfg.slot_cache:
             prefix_blocks, cached = self.pool.match_prefix(prompt[:n - 1])
         elif req._held_slot is not None:
             # a later chunk of a chunked prompt: its prefix is its own
             # earlier chunks' blocks, its state its held slot's row
             prefix_blocks, cached = list(req._blocks), req._prefill_counted
         else:
-            # state layers: K and V blocks without the state at their
-            # end are of no use, and the radix cache holds no state, so
-            # no prefix is matched (and none inserted, _post_admit)
+            # state layers, ring layers: K and V blocks without the
+            # state (the ring) at their end are of no use, and the radix
+            # cache holds neither, so no prefix is matched (and none
+            # inserted, _post_admit)
             prefix_blocks, cached = [], 0
         if self.kvtier is not None and self.program_hook is None:
             # tier 2b: a disaggregated request pulls its missing prefix
@@ -2341,8 +2408,8 @@ class ContinuousBatcher:
                 if (head is None or cap == 0 or head._noslot_bounce
                         or len(head.prompt) + len(head.tokens) - 1 <= cap):
                     break
-                if self.cfg.ssm is not None and head._held_slot is None:
-                    break   # state layers: a first chunk takes a slot
+                if self.cfg.slot_cache and head._held_slot is None:
+                    break   # a per-slot cache: a first chunk takes a slot
             with self._lock:
                 req = self.queue.popleft() if self.queue else None
             if req is None:
@@ -2361,7 +2428,7 @@ class ContinuousBatcher:
                 continue
             finally:
                 self._admitting = None
-            if (prep is not None and wave and self.cfg.ssm is None
+            if (prep is not None and wave and not self.cfg.slot_cache
                     and (self._shared_wave_blocks(wave, prep["prompt"])
                          * self.block_size > prep["cached"])):
                 # an earlier wave member is about to insert a longer shared
@@ -2406,7 +2473,7 @@ class ContinuousBatcher:
                 if prep["partial"]:
                     break
                 continue
-            if prep["partial"] and self.cfg.ssm is None:
+            if prep["partial"] and not self.cfg.slot_cache:
                 prep["slot"] = None
                 wave.append(prep)
                 break
@@ -2436,7 +2503,8 @@ class ContinuousBatcher:
         if rows == 1:
             return False
         b = self._wave_rows(rows)
-        return (b * t * (pb * self.block_size + t) > WAVE_SCORE_BUDGET
+        return (b * t * (pb * self.block_size + t)
+                > _wave_score_budget(self.cfg)
                 or b * t > self._wave_token_budget)
 
     def _wave_rows(self, members: int) -> int:
@@ -2492,6 +2560,7 @@ class ContinuousBatcher:
                    "loop_steps": self.cfg.loop_steps,
                    "ssm_state_bytes_per_slot":
                        self._state_bytes_per_slot,
+                   "kv_ring_bytes_per_slot": self._ring_bytes_per_slot,
                    **self._gathered_prefix(b, pb),
                    "bounded": int(self._wave_cut == (t, pb))})
         with self.profiler.phase("admit_post"):
@@ -2558,9 +2627,9 @@ class ContinuousBatcher:
             # base-only wave compiles/runs the unaugmented program, and
             # lockstep followers replaying the args pick the same one
             admit_args["aids"] = aids.tolist()
-        if self.cfg.ssm is not None:
-            # each row's state row; padding rows write the dummy row
-            # behind the slots' (ops/paged_kvcache.py)
+        if self.cfg.slot_cache:
+            # each row's state (ring) row; padding rows write the dummy
+            # row behind the slots' (ops/paged_kvcache.py)
             admit_args["slots"] = (
                 [m["slot"] for m in members]
                 + [self.slots] * (b - len(members)))
@@ -2602,15 +2671,16 @@ class ContinuousBatcher:
         # register the prompt's full blocks in the radix cache
         n_full = n // bs
         skip = cached // bs
-        if n_full > skip and self.cfg.ssm is None:
+        if n_full > skip and not self.cfg.slot_cache:
             self.pool.insert_prefix(m["prompt"][:n_full * bs],
                                     tail_real[:n_full - skip], skip)
         self.metrics.inc("batcher_ssm_scan_positions",
                          tail_len if self.cfg.ssm is not None else 0)
 
-        if m.get("partial") and self.cfg.ssm is not None:
-            # state layers: the chunk's K and V are of use only with the
-            # state at their end, which this slot's row now holds; the
+        if m.get("partial") and self.cfg.slot_cache:
+            # state layers, ring layers: the chunk's K and V are of use
+            # only with the state (the ring) at their end, which this
+            # slot's row now holds; the
             # request keeps the slot and its blocks, and its next chunk
             # goes on from both (_prep_admit)
             req._blocks = prefix_blocks + tail_real
@@ -2884,9 +2954,9 @@ class ContinuousBatcher:
         if req is not None:
             self.pool.release(req._blocks)
             req._blocks = []
-            if self.cfg.ssm is not None:
-                # nothing of a preempted request's state is kept: it is
-                # prefilled again from its first token
+            if self.cfg.slot_cache:
+                # nothing of a preempted request's state or ring is
+                # kept: it is prefilled again from its first token
                 req._prefill_counted = 0
             req._preemptions += 1
             if req._preemptions > 5:
@@ -3120,6 +3190,7 @@ class ContinuousBatcher:
                    "loop_steps": self.cfg.loop_steps,
                    "ssm_state_bytes_per_slot":
                        self._state_bytes_per_slot,
+                   "kv_ring_bytes_per_slot": self._ring_bytes_per_slot,
                    "pool_positions": self._pool_positions,
                    "window_positions": self._window_positions,
                    "pool_kernel": int(self.pool_kernel)})

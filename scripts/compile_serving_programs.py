@@ -8,6 +8,7 @@ described TPU — no chip, no arrays — before spending a chip call.
     JAX_PLATFORMS=cpu MODEL=mistral-cell python scripts/compile_serving_programs.py 1
     JAX_PLATFORMS=cpu MODEL=ouro python scripts/compile_serving_programs.py 1
     JAX_PLATFORMS=cpu MODEL=falcon-h1 python scripts/compile_serving_programs.py 1
+    JAX_PLATFORMS=cpu MODEL=mimo python scripts/compile_serving_programs.py 1
 
 For each tensor-parallel width given (default: 1 and 4) the real jitted
 programs of ``runtime/batcher.py`` are lowered for ``v5e:2x2`` at
@@ -22,7 +23,10 @@ windowed read) or ``MODEL=ouro`` at its cell's (ouro-2.6b bf16 whole, 48
 layers run 4 times, 8 slots, 192 planes of 321 blocks) or
 ``MODEL=falcon-h1`` at its cell's (falcon-h1-34b bf16, 6 layers, 64 slots,
 4096 blocks, the state planes of 65 rows: the widest admit waves the
-token bound lets through and the decode chunks), with shapes from
+token bound lets through and the decode chunks) or ``MODEL=mimo`` at its
+cell's (mimo-v2.5 bf16, 7 layers of two kinds, 16 of 256 experts, 64
+slots, the full layers' pool of 20,480 blocks and the rings of 65 rows),
+with shapes from
 ``jax.eval_shape`` and shardings from ``parallel/sharding.py``. Prints
 what ``compiled.memory_analysis()`` says each device must hold, the
 collectives in the program text, and every instruction that yields a
@@ -96,6 +100,16 @@ TARGETS = {
     "falcon-h1": ("falcon-h1-34b", None, 6, 64, 16, 4096, 1024,
                   ((128, 1, 32), (256, 1, 16), (512, 1, 8), (128, 1, 1)),
                   (8, 1)),
+    # benchmarks/chip/configs/mimo-v2.5-l7.json: 7 layers of two kinds,
+    # 16 of 256 experts, 64 slots and their rings beside the full
+    # layers' pool; the widest admit waves the byte bounds let through,
+    # the second chunk of a chunked 4096 prompt, and the decode chunks
+    "mimo": ("mimo-v2.5", None, {
+        "num_layers": 7, "vocab_size": 19072, "experts_held": (0, 16),
+        "swa": {"pattern": (0, 1, 1, 1, 1, 1, 0), "num_kv_heads": 8,
+                "rope_theta": 1e4, "sinks": True}}, 64, 16, 20480, 5120,
+        ((2048, 1, 2), (1024, 1, 4), (512, 1, 8), (256, 1, 16),
+         (2048, 128, 1), (256, 1, 1)), (8, 1)),
 }
 TARGET = os.environ.get("MODEL", "mistral")
 (MODEL, QUANT, DEPTH, SLOTS, BLOCK, BLOCKS, MAX_SEQ, ADMIT,
@@ -236,13 +250,16 @@ def main(widths):
             jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))),
             shd.param_specs(cfg, spec))
         if cfg.is_moe:
-            # the batcher holds MoE layers one by one (_unstack_layers)
-            n = cfg.num_layers - cfg.dense_prefix_layers
-            params["layers"] = [jax.tree.map(
-                lambda s: jax.ShapeDtypeStruct(
-                    s.shape[1:], s.dtype, sharding=NamedSharding(
-                        mesh, P(*s.sharding.spec[1:]))),
-                params["layers"]) for _ in range(n)]
+            # the batcher holds MoE layers one by one (_unstack_layers:
+            # each kind's stack of a model with layer kinds)
+            for name in ("layers", "layers_full"):
+                if name in params:
+                    n = jax.tree.leaves(params[name])[0].shape[0]
+                    params[name] = [jax.tree.map(
+                        lambda s: jax.ShapeDtypeStruct(
+                            s.shape[1:], s.dtype, sharding=NamedSharding(
+                                mesh, P(*s.sharding.spec[1:]))),
+                        params[name]) for _ in range(n)]
         paged = described(
             jax.eval_shape(lambda: init_paged_cache(cfg, BLOCKS + 1, BLOCK,
                                                     slots=SLOTS)),
@@ -260,9 +277,9 @@ def main(widths):
         mb = MAX_SEQ // BLOCK
         with mesh:
             for t, pb, wave in ADMIT:
-                # ... + a state row a wave row, for a model with state layers
-                n_ints = wave * (t + t // BLOCK + pb + 6
-                                 + (cfg.ssm is not None))
+                # ... + a slot row a wave row, for a model with a per-slot
+                # cache (state layers, ring layers)
+                n_ints = wave * (t + t // BLOCK + pb + 6 + cfg.slot_cache)
                 t0 = time.time()
                 report(f"{TARGET} tp={tp} admit tail={t} prefix_blocks={pb} "
                        f"wave={wave}",

@@ -53,10 +53,13 @@ SCOPES = ("kv_gather", "attention", "attn_gate", "kv_write", "mlp",
           # a state-space mixer's parts (ops/ssm.py): the chunked scan in
           # admit programs, the one-step update in decode chunks
           "ssm_in_proj", "ssm_conv", "ssm_scan", "ssm_step",
-          "ssm_gate_norm", "ssm_out_proj")
+          "ssm_gate_norm", "ssm_out_proj",
+          # a windowed layer's rows into its slot's ring (mimo-v2)
+          "ring_write")
 # a layer's kind, named inside kv_gather / attention where a model mixes
 # windowed and full layers: reported as `attention/win`
-KINDS = ("win", "full")
+# (mimo-v2's kinds, of different shapes: `attention/attention_swa`)
+KINDS = ("win", "full", "attention_swa", "attention_full")
 NO_SCOPE = "(no scope)"
 LOOP_STEP = "loop_step_"   # transformer.loop_layer_stack names each pass
 # instructions a compiler pass makes without an op name, by what their

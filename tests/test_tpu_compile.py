@@ -364,14 +364,20 @@ def _serving_shapes(cfg, bs, blocks, held=False, slots=0):
 
     params = shapes(jax.eval_shape(
         lambda: init_params(cfg, jax.random.PRNGKey(0))))
-    if held:
-        one = jax.tree.map(lambda s: (s[0][1:], s[1]), params["layers"],
-                           is_leaf=lambda x: isinstance(x, tuple))
-        params["layers"] = [one] * (cfg.num_layers
-                                    - cfg.dense_prefix_layers)
+    if held:   # (a model with layer kinds: each kind's stack)
+        for name in ("layers", "layers_full"):
+            if name in params:
+                n = jax.tree.leaves(
+                    params[name],
+                    is_leaf=lambda x: isinstance(x, tuple))[0][0][0]
+                one = jax.tree.map(lambda s: (s[0][1:], s[1]), params[name],
+                                   is_leaf=lambda x: isinstance(x, tuple))
+                params[name] = [one] * n
     cache = jax.eval_shape(
         lambda: init_paged_cache(cfg, blocks + 1, bs, slots=slots))
     state = {} if cfg.ssm is None else {"ssm": cache.ssm, "conv": cache.conv}
+    if cfg.swa is not None:
+        state = {"ring_k": cache.ring_k, "ring_v": cache.ring_v}
     # (a list: compile_on_chip takes every tuple for a (shape, dtype) leaf)
     return params, [shapes(list(cache.planes())), shapes(state)]
 
@@ -605,3 +611,54 @@ def test_no_serving_program_copies_the_pool(compile_on_chip, model, program):
     rest = [m for m in made if m not in writes]
     assert [op for _, op in rest] == ["copy"] * kept, (
         f"the pool, or a layer of it, is materialized: {rest}")
+
+
+@pytest.mark.parametrize("program", ["admit", "decode-chunk-8"])
+def test_mimo_programs_fit_the_chip_and_copy_neither_cache(compile_on_chip,
+                                                           program):
+    """benchmarks/chip/configs/mimo-v2.5-l7.json: 7 layers of two kinds
+    held one by one, 16 of 256 experts, 64 slots, the full layers' pool of
+    20,480 blocks (a position's 4 heads in one row: 768 and 512 columns)
+    and the windowed layers' rings of 65 rows (1536 and 1024). The widest
+    admit wave the byte bounds let through (2 rows of 2048) and the chunk
+    of 8 passes fit the chip's 16 GB with their arguments, and write pool
+    and ring once each, in place: nothing else in the program yields an
+    array the size of a plane of either or of one layer of it (with the
+    heads as an axis of 4 the admit programs held four pool-sized copies,
+    PERF.md section 6, PR 45). The chunk's experts take the streaming
+    kernel, a wave's lax.ragged_dot."""
+    cfg = get_config("mimo-v2.5").replace(
+        num_layers=7, vocab_size=19072, experts_held=(0, 16),
+        swa={"pattern": (0, 1, 1, 1, 1, 1, 0), "num_kv_heads": 8,
+             "rope_theta": 1e4, "sinks": True},
+        attn_backend="xla", expert_matmul="pallas", pool_kernel="pallas")
+    slots, bs, blocks, mb = 64, 16, 20480, 320
+    if program == "admit":
+        from distributed_llm_inferencing_tpu.models import transformer
+        from distributed_llm_inferencing_tpu.ops.paged_kvcache import (
+            PagedKVCache)
+        params, pool = _serving_shapes(cfg, bs, blocks, True, slots)
+        t, pb, wave = 2048, 1, 2
+
+        def admit(params, pool, tokens, tail_blocks, prefix_blocks, lens):
+            return transformer.paged_prefill_tail(
+                params, cfg, tokens, lens[0], tail_blocks, prefix_blocks,
+                lens[1], PagedKVCache(*pool[0], **pool[1]), slots=lens[2])
+        compiled = compile_on_chip(
+            admit, params, pool, ((wave, t), jnp.int32),
+            ((wave, t // bs), jnp.int32), ((wave, pb), jnp.int32),
+            ((3, wave), jnp.int32), kernel=True, donate=(1,))
+    else:
+        compiled = _decode_chunk(compile_on_chip, cfg, 8, slots, bs, blocks,
+                                 mb, kernel=True, held=True, donate=True)
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
+    assert ("expert_stream_matmul" in text) == (program != "admit")
+    assert "paged_pool_attend" not in text
+    _, (pool, ring) = _serving_shapes(cfg, bs, blocks, True, slots)
+    planes = [jax.ShapeDtypeStruct(*p) for p in pool + list(ring.values())]
+    made = _pool_sized()(text, planes)
+    writes = [m for m in made if m[1] in ("fusion(scatter)", "scatter")]
+    assert len(writes) == 4, f"one write a plane of pool and ring: {made}"
+    copies = [m for m in made if m[1] in ("copy", "fusion", "transpose")]
+    assert not copies, f"a plane, or a layer of one, is copied: {copies}"
