@@ -420,7 +420,8 @@ class ModelConfig:
     # rung, everywhere) | "pallas" (a one-device TPU program:
     # ops/pallas/paged_attention.py where the pool's shape is one it
     # reads as it lies: K and V planes whose heads of whole lanes fill
-    # a tile's 8 sublanes or divide them, a latent pool's one plane;
+    # a tile's 8 sublanes or divide them, a latent pool's one plane, the
+    # flat rows of a model with layer kinds' full layers;
     # scanned layers or layers held one by one) | "pallas_interpret" (tests). Not a serving
     # option: the batcher overwrites it. A model with state layers takes
     # its decode chunk's one-step state update by the same pin

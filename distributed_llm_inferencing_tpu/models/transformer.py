@@ -1465,8 +1465,9 @@ def _pool_ladder(mb: int, scanned: bool = True):
     is a branch of one lax.switch in the layer body, not a program. This
     is the XLA form of the pool's read: where _pool_kernel takes the
     Pallas kernel (which stops at each slot's own length: mistral-7b,
-    Ouro-2.6B, kanana and falcon-h1 among the benchmark's cells, so none
-    of them runs a rung) no ladder and no switch are built.
+    Ouro-2.6B, kanana, falcon-h1 and mimo-v2.5's full layers among the
+    benchmark's cells, so none of them runs a rung) no ladder and no
+    switch are built.
     A conditional takes its operands as buffers: they are the stacked
     pool as it lies and the layer's index, and the branch gathers by
     (layer, block) (_layer_gather); handed the scan's slice of the pool
@@ -1476,8 +1477,9 @@ def _pool_ladder(mb: int, scanned: bool = True):
     code outside it and a switch around the whole chunk costs seconds a
     program at every start, so there the ladder is the full extent
     alone: lax.switch inlines its one branch, and the gather fuses into
-    attention (trinity's full layers; kanana's on a mesh or with a
-    quantized pool). PERF.md section 6, PR 30, has the chip's numbers
+    attention (trinity's full layers; kanana's, or mimo-v2.5's full
+    layers', wherever the kernel is not taken: a mesh, a quantized pool,
+    the CPU). PERF.md section 6, PR 30, has the chip's numbers
     for each."""
     if not scanned:
         return (mb,)
@@ -1540,22 +1542,36 @@ def _pool_kernel(cfg: ModelConfig, paged):
     over a latent pool -- else None: the in-loop gather as far as
     _pool_ladder's rung (_attend_pool_rung). The stack may be scanned (the kernel takes the
     layer's index from the scan) or held layer by layer (the index is a
-    constant handed in as an array: one lowering for all of them). In
+    constant handed in as an array: one lowering for all of them). A
+    model with layer kinds (cfg.swa) is judged by its pool-backed (full)
+    layers' own config (kind_cfg), its windowed layers' ring being no
+    part of the pool: the pool then holds flat rows (one row a position,
+    its K/V heads side by side, V's narrower than K's), which the kernel
+    takes where both widths and a value head are whole lanes (a query
+    head's context is then whole lanes of a V row). In
     the benchmark's cells the kernel serves mistral-7b, Ouro-2.6B,
-    kanana (its latent MQA plane, 7 layers held one by one) and
-    falcon-h1 (20 query heads over 4 K/V heads); trinity (per-layer
-    windows) keeps the XLA form, as do int8 pools, meshes, the
-    speculative chunk and the CPU."""
+    kanana (its latent MQA plane, 7 layers held one by one),
+    falcon-h1 (20 query heads over 4 K/V heads) and mimo-v2.5's two full
+    layers (rows of 768 and 512 columns under 64 query heads); trinity
+    (per-layer windows) keeps the XLA form, as do int8 pools, meshes,
+    the speculative chunk and the CPU."""
+    attn = cfg if cfg.swa is None else cfg.kind_cfg("full", 1)
     if (not cfg.pool_kernel.startswith("pallas") or paged.quantized
-            or cfg.attn_windows is not None or cfg.swa is not None
-            or cfg.position_embedding == "alibi" or cfg.attn_sinks
-            or cfg.attn_softcap is not None
+            or cfg.attn_windows is not None
+            or attn.position_embedding == "alibi" or attn.attn_sinks
+            or attn.attn_softcap is not None
             or paged.k.dtype != jnp.dtype(cfg.dtype)
             or (cfg.mla_latent_cache and cfg.sliding_window is not None)):
         return None
     from distributed_llm_inferencing_tpu.ops.pallas import paged_attention
     if not paged_attention.supported(paged.k.shape[3], paged.k.shape[4],
                                      paged.k.dtype):
+        return None
+    if cfg.swa is not None and not (
+            paged.k.shape[3] == 1
+            and cfg.v_head_dim_effective % paged_attention.LANES == 0
+            and paged.v.shape[4] == cfg.num_kv_heads
+            * cfg.v_head_dim_effective):
         return None
     return cfg.pool_kernel
 
@@ -1577,30 +1593,43 @@ def _ssm_kernel(cfg: ModelConfig, paged):
     return cfg.pool_kernel
 
 
+def _flat_rows_q(q, hkv: int, k_rows):
+    """Query heads [R, Sq, H, hd] zero-expanded to the width of
+    ``k_rows``' flat rows ([..., W]: hkv heads' columns, then zeros):
+    each head's own values in its K/V head's columns, so that one
+    contraction over a whole row is its scores (the other heads' columns
+    meet zeros). [R, Sq, H, W]."""
+    from distributed_llm_inferencing_tpu.ops.paged_kvcache import fit_rows
+    b, sq, h, hd = q.shape
+    wide = jnp.einsum("bqhgd,hk->bqhgkd", q.reshape(b, sq, hkv, h // hkv, hd),
+                      jnp.eye(hkv, dtype=q.dtype))
+    return fit_rows(wide.reshape(b, sq, h, hkv * hd), k_rows)
+
+
 def _attend_flat_rows(q, k, v, hkv: int, vd: int, *args, **kw):
     """ops/attention.attend over caches that store a position's K/V
     heads side by side in ONE row (ops/paged_kvcache.flat_rows: a model
-    with layer kinds), read as they lie. ``k``, ``v``: segments
-    [R, S, 1, W] (W the plane's width: hkv heads' columns, then zeros).
-    Each query head goes in zero-expanded to the row's width, its own
-    values in its K/V head's columns, so one contraction over the whole
-    row gives its scores (the other heads' columns meet zeros), and of
-    the context that comes back as wide as a V row it keeps its own
-    head's columns. Four (eight) times the products of the head-by-head
+    with layer kinds), read as they lie: the XLA form (a windowed
+    layer's ring in every program; the full layers' gathered pool rows
+    where _pool_kernel does not take the Pallas kernel, which reads the
+    same rows in the pool by the same expansion of q). ``k``, ``v``:
+    segments [R, S, 1, W] (W the plane's width: hkv heads' columns, then
+    zeros). Each query head goes in zero-expanded to the row's width
+    (_flat_rows_q), and of the context that comes back as wide as a V
+    row it keeps its own head's columns (the kernel does so inside the
+    call, a head's columns being whole lanes there). Four (eight)
+    times the products of the head-by-head
     form, on a handful of query rows; what it spares is the relayout of
     the gathered K and V that a view of 768 columns as 4 heads of 192
     costs (0.61 + 0.32 s of an 8 s trace, a layer; PERF.md section 6,
     PR 45). q [R, Sq, H, hd] -> [R, Sq, H, vd]."""
     from distributed_llm_inferencing_tpu.ops.attention import attend
-    from distributed_llm_inferencing_tpu.ops.paged_kvcache import fit_rows
     b, sq, h, hd = q.shape
-    g = h // hkv
-    eye = jnp.eye(hkv, dtype=q.dtype)
-    wide = jnp.einsum("bqhgd,hk->bqhgkd", q.reshape(b, sq, hkv, g, hd),
-                      eye).reshape(b, sq, h, hkv * hd)
-    ctx = attend(fit_rows(wide, k[0]), k, v, *args, scale=hd ** -0.5, **kw)
-    ctx = ctx[..., :hkv * vd].reshape(b, sq, hkv, g, hkv, vd)
-    return jnp.einsum("bqhgkv,hk->bqhgv", ctx, eye).reshape(b, sq, h, vd)
+    ctx = attend(_flat_rows_q(q, hkv, k[0]), k, v, *args, scale=hd ** -0.5,
+                 **kw)
+    ctx = ctx[..., :hkv * vd].reshape(b, sq, hkv, h // hkv, hkv, vd)
+    return jnp.einsum("bqhgkv,hk->bqhgv", ctx,
+                      jnp.eye(hkv, dtype=q.dtype)).reshape(b, sq, h, vd)
 
 
 def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
@@ -1647,8 +1676,9 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
     what makes the split exact. The pool's segment takes one of two
     forms (``_pool_kernel``, from what the trace can see). *The kernel*
     (a one-device TPU program, unquantized K and V planes whose heads
-    fill or divide a tile's 8 sublanes, or a latent pool's one plane of
-    whole lanes: mistral-7b, Ouro-2.6B, kanana, falcon-h1):
+    fill or divide a tile's 8 sublanes, a latent pool's one plane of
+    whole lanes, or the flat rows of a model with layer kinds' full
+    layers: mistral-7b, Ouro-2.6B, kanana, falcon-h1, mimo-v2.5):
     ops/pallas/paged_attention.paged_attend reads each live slot's pages
     where the pool lies, by (layer, block-table entry), as far as that
     slot's own context, and keeps both segments' softmax inside the
@@ -1676,6 +1706,14 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
     as the pool stores a row (lane_width: zeros after the rd + r
     columns), the query is padded with zeros to match, and a page's rows
     are fetched once for the scores and the weighted sum.
+
+    A model with layer kinds (cfg.swa) keeps a side buffer a kind and
+    plane: its windowed layers read their slot's ring below the horizon
+    where it lies, in XLA (_attend_flat_rows), and its full layers the
+    pool, whose rows hold a position's heads side by side: by the kernel
+    (q zero-expanded to a K row, _flat_rows_q; a head's own columns of V
+    picked inside the call) or, where it is not taken, by the gather of
+    the whole block table and the same expansion.
 
     tokens: [R] last emitted token per slot; steps0: [R] tokens emitted so
     far. Returns (toks [K, R] int32, emits [K, R] bool, moe int32 [5],
@@ -1742,9 +1780,12 @@ def decode_chunk_with_logits(params, cfg: ModelConfig, k: int, tokens, paged,
             paged_attention)
         pool_positions = -(-jnp.max(jnp.where(budget > 0, cl0, 0))
                            // bs) * bs
+        # (layer kinds: the pool is the full layers', flat rows of K
+        # and of V, and the window is the ring's layers')
         walk = paged_attention.pool_walk(
             cl0, budget > 0, paged.k, mb,
-            sliding_window=cfg.sliding_window, n_planes=n_planes)
+            sliding_window=None if kinds else cfg.sliding_window,
+            n_planes=n_planes, v_planes=paged.v)
     else:
         rung, pool_positions = _pool_rung(ladder, bs, cl0, budget > 0)
     side_pos = cl0[:, None] + jnp.arange(k, dtype=jnp.int32)[None, :]
@@ -1817,13 +1858,20 @@ def decode_chunk_with_logits(params, cfg: ModelConfig, k: int, tokens, paged,
                 def attend_kernel(q, rows, scale=None):
                     # pool and side rows in one softmax inside the call,
                     # the planes taken where they lie at the layer's
-                    # index; a latent pool's one plane as K and V alike
-                    with jax.named_scope("attention"):
+                    # index; a latent pool's one plane as K and V alike;
+                    # a full layer's flat rows under q expanded to them,
+                    # a head's own columns of V picked inside the call
+                    with jax.named_scope("attention"), \
+                            kind_scope("attention_full" if kinds else None):
+                        if kinds:
+                            q, scale = (_flat_rows_q(q, cfg.num_kv_heads,
+                                                     rows[0]),
+                                        q.shape[-1] ** -0.5)
                         return paged_attention.paged_attend(
                             q, pool[0], pool[-1], li, block_tables, cl0,
                             cl0 + t, walk, (rows[0], rows[-1], t),
                             sliding_window=seg_cfg.sliding_window,
-                            scale=scale,
+                            scale=scale, v_head_dim=vd if kinds else None,
                             interpret=kernel == "pallas_interpret")
 
                 def attend_side(q, sd2, sliding_window=None, **kw):

@@ -191,7 +191,8 @@ def resolve_backend(requested: str = "auto", n_devices: int = 1) -> str:
     (transformer._pool_kernel: the Pallas paged kernel where the
     batcher's ``cfg.pool_kernel`` pin and the pool's shape allow, i.e.
     mistral-7b, Ouro-2.6B, kanana's latent pool, falcon-h1's 4 K/V
-    heads; PERF.md section 6, PRs 40, 42 and 43; the gather as far as
+    heads, mimo-v2.5's full layers' flat rows; PERF.md section 6, PRs
+    40, 42, 43 and 46; the gather as far as
     _pool_ladder's rung elsewhere).
     """
     requested = os.environ.get("DLI_ATTENTION", requested)

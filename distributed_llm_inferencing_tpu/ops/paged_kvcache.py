@@ -72,10 +72,11 @@ is a cost of its own beside attention (``kv_gather`` in PERF.md section
 instead, each slot as far as its own context: the decode chunks of a
 one-device TPU program take it where the pool's shape allows
 (models/transformer.py _pool_kernel: mistral-7b, Ouro-2.6B, falcon-h1
-(4 K/V heads: half a tile of heads a position) and, its latent plane's
-rows fetched once as K and V alike, kanana among the benchmark's cells;
-PERF.md section 6, PRs 40, 42 and 43), everything else keeps the
-gather.
+(4 K/V heads: half a tile of heads a position), its latent plane's
+rows fetched once as K and V alike, kanana, and mimo-v2.5's full layers
+(flat_rows: rows of 768 columns of K and 512 of V) among the
+benchmark's cells; PERF.md section 6, PRs 40, 42, 43 and 46),
+everything else keeps the gather.
 
 The reference framework has no counterpart at any level — its KV cache was
 implicit inside HF ``generate`` (SURVEY.md §2.4).
@@ -448,8 +449,9 @@ def paged_attend_decode(q, cache_k_layer, cache_v_layer, block_tables,
     writes the pool on every step, and no serving path. The decode
     chunks read the pool in their own way (models/transformer.py
     _pool_kernel: the Pallas paged kernel over K and V planes whose
-    heads fill a tile's sublanes or divide them, or a latent pool's one
-    plane, where it was measured at 1.5-6.2 times the gather's speed,
+    heads fill a tile's sublanes or divide them, a latent pool's one
+    plane or a model with layer kinds' flat rows, where it was measured
+    at 1.5-6.2 times the gather's speed,
     PERF.md section 5; the in-loop gather elsewhere).
 
     int8 caches (``k_scale_layer``/``v_scale_layer`` present): the

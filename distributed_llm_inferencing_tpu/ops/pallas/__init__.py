@@ -26,12 +26,15 @@ from shapes it can see in a one-device TPU program's decode chunks
   size; the batcher pins ``cfg.expert_matmul``,
   models/transformer.py:_expert_stream decides)
 - ``paged_attention.paged_attend`` (mistral-7b, Ouro-2.6B, kanana,
-  falcon-h1-34b): a pass's attention over the paged pool, the pages
+  falcon-h1-34b, mimo-v2.5's full layers): a pass's attention over the
+  paged pool, the pages
   read where they lie by (plane, block-table entry), each slot as far
   as its own context, the chunk's side rows in the same softmax; K and
   V planes whose heads of whole lanes fill a tile's 8 sublanes or
-  divide them (16, 8, 4, 2, 1), or a latent pool's one plane of shared
-  rows taken as K and V at once (the in-loop gather as far as
+  divide them (16, 8, 4, 2, 1), a latent pool's one plane of shared
+  rows taken as K and V at once, or flat rows (a model with layer
+  kinds: a position's heads side by side in one row of each plane, V's
+  narrower than K's) (the in-loop gather as far as
   _pool_ladder's rung keeps every other pool; the batcher pins
   ``cfg.pool_kernel``, models/transformer.py:_pool_kernel decides:
   the one place a decode chunk's read of the pool is chosen)

@@ -48,6 +48,25 @@ lies and as far as each slot's own context:
   a call whose whole-block q, output and softmax state pass Mosaic's
   scoped VMEM (kanana's 64 slots x 32 heads x 640: 21 MiB) asks for
   what it needs and starts and finishes its slots in a loop.
+- Flat rows (a model with layer kinds, MiMo-V2: ops/paged_kvcache.py
+  ``flat_rows``) are the same walk again over K and V planes
+  ``[L, NB, bs, 1, Wk]`` and ``[L, NB, bs, 1, Wv]`` whose one row a
+  position holds its K/V heads side by side, a head a column offset,
+  and whose widths differ (mimo-v2.5: 4 heads of 192 and of 128, rows
+  of 768 and 512 columns, a page 24 KB + 16 KB). Pages, steps, items
+  and the two buffers are sized from each plane's own row bytes. The
+  query comes zero-expanded to a K row (each query head's values in its
+  own K/V head's columns), so the one product ``[H, Wk] x [rows, Wk]^T``
+  is the head-by-head scores and no head mask is built; ``p @ V`` is
+  taken a K/V head at a time, that head's query heads against its own
+  ``v_head_dim`` columns of the rows (whole lanes: a static, aligned
+  slice), so the softmax state and the output are ``[R, H,
+  v_head_dim]`` and the other heads' columns are never multiplied.
+  (Picked outside the call instead, as the XLA form picks, the state
+  and the q and output blocks are as wide as a V row: 34 MB of VMEM at
+  mimo-v2.5's 64 slots x 64 heads against 25 here, four times the
+  accumulator's traffic a step and four times the streamed rows of
+  ``p @ V``; PERF.md section 6, PR 46.)
 
 The decode chunk calls ``paged_attend``, the one entry
 (models/transformer.py ``_pool_kernel`` says where).
@@ -97,11 +116,13 @@ _STATE_AT_ONCE = 1024 * 1024
 
 
 def _pages(bs: int, hkv: int, hd: int, itemsize: int, mb: int,
-           n_planes: int = 2):
+           n_planes: int = 2, vw: Optional[int] = None):
     """(pages a tail step, pages a step, pages a work item) for a page
-    of [bs, hkv, hd] in each of ``n_planes`` planes and block tables of
+    of [bs, hkv, hd] in each of ``n_planes`` planes (``vw``: the width
+    of V's rows where it is not K's, flat rows) and block tables of
     ``mb`` columns: each a multiple of the one before."""
-    page = n_planes * bs * hkv * hd * itemsize
+    page = bs * hkv * itemsize * (
+        hd if n_planes == 1 else hd + (hd if vw is None else vw))
     tail = min(1 << (max(_TAIL_BYTES // page, 1) - 1).bit_length(), mb)
     step = tail * max(1, min(-(-_STEP_BYTES // (tail * page)),
                              -(-mb // tail)))
@@ -137,11 +158,12 @@ class PoolWalk(NamedTuple):
 
 def pool_walk(context_lens, live, planes, max_blocks: int, *,
               sliding_window: Optional[int] = None,
-              n_planes: int = 2) -> PoolWalk:
+              n_planes: int = 2, v_planes=None) -> PoolWalk:
     """The work items of paged_attend's walk over every live slot's pool
     positions [first, context_lens) in ``planes`` ([..., bs, Hkv, hd]: K
     or V as paged_attend takes them; ``n_planes`` 1 for a latent pool,
-    whose one plane is both) under block tables of
+    whose one plane is both; ``v_planes`` where V's rows are not as wide
+    as K's: flat rows) under block tables of
     ``max_blocks`` columns: as many columns an item as the kernel
     fetches for a pool of this shape (_pages), a slot's items in order,
     slots in order, a slot that is not ``live`` (or holds nothing) none.
@@ -149,8 +171,9 @@ def pool_walk(context_lens, live, planes, max_blocks: int, *,
     query at ``context_lens`` can reach (a chunk's first pass; its later
     passes see less); the kernel's mask is the exact cut."""
     block_size, hkv, hd = planes.shape[-3:]
-    pages = _pages(block_size, hkv, hd, planes.dtype.itemsize,
-                   max_blocks, n_planes)[-1]
+    pages = _pages(block_size, hkv, hd, planes.dtype.itemsize, max_blocks,
+                   n_planes,
+                   None if v_planes is None else v_planes.shape[-1])[-1]
     r = context_lens.shape[0]
     cl = jnp.where(live, context_lens, 0).astype(jnp.int32)
     first = jnp.zeros_like(cl)
@@ -230,13 +253,18 @@ def _div(x, n: int):
 
 def _kernel(slot_ref, col_ref, n_ref, count_ref, bt_ref, len_ref, qpos_ref,
             misc_ref, q_ref, *refs, bs, hkv, g, mb, plan, side_rows, scale,
-            window, n_planes):
+            window, n_planes, row_heads):
     # n_planes 2: K and V planes (and side rows); 1: one plane whose rows
-    # are K and V at once, fetched once into one buffer
+    # are K and V at once, fetched once into one buffer. row_heads > 1:
+    # flat rows, a position's K/V heads side by side in its one row (q
+    # zero-expanded to K's width, a query head's own values in its K/V
+    # head's columns), V's rows perhaps narrower than K's; the state and
+    # the output hold a query head's own K/V head's columns of V alone
     side, hbm, o_ref = (refs[:n_planes], refs[n_planes:2 * n_planes],
                         refs[2 * n_planes])
     bufs, (sem, m_scr, l_scr, acc_scr) = refs[-4 - n_planes:-4], refs[-4:]
-    r, h, hd = q_ref.shape
+    r, h, _ = q_ref.shape
+    hd = o_ref.shape[-1]                 # a context's width
     page = bs * hkv                      # rows of one page
     tail_pages, step_pages, pages = plan
     plane, t = misc_ref[0], misc_ref[1]
@@ -323,16 +351,28 @@ def _kernel(slot_ref, col_ref, n_ref, count_ref, bt_ref, len_ref, qpos_ref,
     def both(own, mask):
         return mask if own is None else own & mask
 
+    def pv(p, v):
+        if row_heads == 1:
+            return _pv(p, v)
+        # flat rows: a query head's context is its own K/V head's
+        # columns of the row, whole lanes of it; the other heads' columns
+        # are never multiplied
+        n = h // row_heads
+        return jnp.concatenate(
+            [_pv(p[..., j * n:(j + 1) * n, :], v[..., j * hd:(j + 1) * hd])
+             for j in range(row_heads)], axis=-2)
+
     def softmax_step(state, scores, mask, v):
         """One online-softmax step: state (m, l [..., H, 1], acc
-        [..., H, hd]) over ``scores`` [..., H, S] and ``v`` [..., S, hd]."""
+        [..., H, hd]) over ``scores`` [..., H, S] and ``v`` [..., S, hd]
+        (flat rows: [..., S, row_heads * hd])."""
         m_prev, l_prev, acc = state
         scores = jnp.where(mask, scores, NEG_INF)
         m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.where(mask, jnp.exp(scores - m_new), 0.0)
         return (m_new, l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True),
-                acc * alpha + _pv(p, v))
+                acc * alpha + pv(p, v))
 
     def put(at, state):
         m, l, acc = state
@@ -443,7 +483,8 @@ def _kernel(slot_ref, col_ref, n_ref, count_ref, bt_ref, len_ref, qpos_ref,
 def paged_attend(q, k_planes, v_planes, plane, block_tables, context_lens,
                  q_pos, walk: PoolWalk, side, *,
                  sliding_window: Optional[int] = None,
-                 scale: Optional[float] = None, interpret: bool = False):
+                 scale: Optional[float] = None,
+                 v_head_dim: Optional[int] = None, interpret: bool = False):
     """One query token a slot over the pool's positions
     [0, context_lens) of plane ``plane`` and over the chunk's own rows,
     ``side`` = (side_k, side_v, t).
@@ -472,8 +513,25 @@ def paged_attend(q, k_planes, v_planes, plane, block_tables, context_lens,
     columns past it are zeros and so are q's; the context comes back
     ``w`` wide. Pass ``scale``: the default is the row's width's.
 
+    Flat rows (``v_head_dim``: a model with layer kinds,
+    ops/paged_kvcache.flat_rows): k_planes [L, NB, bs, 1, Wk], v_planes
+    [L, NB, bs, 1, Wv], a position's K/V heads side by side in its one
+    row of each, ``Wv // v_head_dim`` of them, Wk and Wv whole lanes and
+    not the same; side_k and side_v as wide as their planes' rows. ``q``
+    [R, 1, H, Wk] comes zero-expanded (each query head's values in its
+    own K/V head's columns, zeros in the others': one contraction over
+    the row is then that head's scores), ``scale`` is the head's own,
+    and of ``p @ V`` a query head keeps its own K/V head's
+    ``v_head_dim`` columns: the context comes back [R, 1, H,
+    v_head_dim].
+
     Returns [R, 1, H, hd] in q.dtype."""
     bs, hkv, hd = k_planes.shape[2:]
+    row_heads = 1
+    if v_head_dim is not None:
+        assert hkv == 1 and v_head_dim % LANES == 0 and scale is not None
+        row_heads = v_planes.shape[-1] // v_head_dim
+        assert q.shape[2] % row_heads == 0, (q.shape, row_heads)
     if q.shape[-1] < hd:
         q = jnp.pad(q, [(0, 0)] * 3 + [(0, hd - q.shape[-1])])
     if v_planes is k_planes:
@@ -487,29 +545,35 @@ def paged_attend(q, k_planes, v_planes, plane, block_tables, context_lens,
         q, k_planes, v_planes, jnp.asarray(plane, jnp.int32), block_tables,
         context_lens, q_pos, walk, side, sliding_window=sliding_window,
         scale=float(hd ** -0.5) if scale is None else scale,
-        interpret=interpret,
+        interpret=interpret, row_heads=row_heads,
         plan=_pages(bs, hkv, hd, k_planes.dtype.itemsize,
-                    block_tables.shape[1], 1 if v_planes is None else 2))
+                    block_tables.shape[1],
+                    *((1,) if v_planes is None
+                      else (2, v_planes.shape[-1]))))
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "sliding_window", "scale", "interpret", "plan"))
+    "sliding_window", "scale", "interpret", "plan", "row_heads"))
 def _paged_attend(q, k_planes, v_planes, plane, block_tables, context_lens,
                   q_pos, walk, side, *, sliding_window, scale, interpret,
-                  plan):
+                  plan, row_heads=1):
     r, one, h, hd = q.shape
     assert one == 1, "paged_attend takes exactly one query token a slot"
     planes = [p for p in (k_planes, v_planes) if p is not None]
     n_planes, nb, bs, hkv, _ = k_planes.shape
+    # a row's width, plane by plane (K's is q's; flat rows: V's is its
+    # own), and a context's: a row of V, or of flat rows one head's part
+    widths = [p.shape[-1] for p in planes]
+    out_w = widths[-1] // row_heads
     g = h // hkv
     mb = block_tables.shape[1]
     pages = plan[-1]
-    item_bytes = pages * bs * hkv * hd * k_planes.dtype.itemsize
+    rows_bytes = bs * hkv * sum(widths) * k_planes.dtype.itemsize
     # what the call holds in VMEM: q, the side rows and the output as
     # whole blocks (the pipeline keeps two of each), two items a plane,
     # the softmax state
-    vmem = (4 * r * h * hd * q.dtype.itemsize + 2 * len(planes) * item_bytes
-            + 4 * r * h * (hd + 2 * LANES))
+    vmem = (2 * r * h * (hd + out_w) * q.dtype.itemsize
+            + 2 * pages * rows_bytes + 4 * r * h * (out_w + 2 * LANES))
 
     def whole(*shape):
         return pl.BlockSpec(shape, lambda i, *_: (0,) * len(shape))
@@ -519,17 +583,18 @@ def _paged_attend(q, k_planes, v_planes, plane, block_tables, context_lens,
     # [K, Hkv] -> K * Hkv rows, [bs, Hkv] -> bs * Hkv below: the same
     # bytes where the heads fill a tile's sublanes or divide them
     # (supported), so a bitcast and not a copy
-    rows = (r, side_rows * hkv, hd)
-    operands += [s_.reshape(rows) for s_ in (side_k, side_v)
-                 if s_ is not None]
-    in_specs += [whole(*rows)] * len(planes)
-    vmem += 2 * len(planes) * r * side_rows * hkv * hd * q.dtype.itemsize
-    flat = (n_planes, nb, bs * hkv, hd)
-    operands += [p.reshape(flat) for p in planes]
+    sides = [s_ for s_ in (side_k, side_v) if s_ is not None]
+    operands += [s_.reshape(r, side_rows * hkv, w)
+                 for s_, w in zip(sides, widths)]
+    in_specs += [whole(r, side_rows * hkv, w) for w in widths]
+    vmem += 2 * r * side_rows * hkv * sum(widths) * q.dtype.itemsize
+    operands += [p.reshape(n_planes, nb, bs * hkv, w)
+                 for p, w in zip(planes, widths)]
     in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * len(planes)
     kernel = functools.partial(
         _kernel, bs=bs, hkv=hkv, g=g, mb=mb, plan=plan, side_rows=side_rows,
-        scale=scale, window=sliding_window, n_planes=len(planes))
+        scale=scale, window=sliding_window, n_planes=len(planes),
+        row_heads=row_heads)
 
     def i32(x):
         return jnp.asarray(x, jnp.int32)
@@ -537,16 +602,16 @@ def _paged_attend(q, k_planes, v_planes, plane, block_tables, context_lens,
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=8, grid=(1,), in_specs=in_specs,
-            out_specs=whole(r, h, hd),
+            out_specs=whole(r, h, out_w),
             scratch_shapes=[
-                pltpu.VMEM((2, pages * bs * hkv, hd), p.dtype)
-                for p in planes] + [
+                pltpu.VMEM((2, pages * bs * hkv, w), p.dtype)
+                for p, w in zip(planes, widths)] + [
                 pltpu.SemaphoreType.DMA((len(planes), 2)),
                 pltpu.VMEM((r, h, LANES), jnp.float32),   # running max
                 pltpu.VMEM((r, h, LANES), jnp.float32),   # denominator
-                pltpu.VMEM((r, h, hd), jnp.float32),      # accumulator
+                pltpu.VMEM((r, h, out_w), jnp.float32),   # accumulator
             ]),
-        out_shape=jax.ShapeDtypeStruct((r, h, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((r, h, out_w), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             # (kanana's 64 slots of 32 heads over 640-wide rows: 21 MiB)

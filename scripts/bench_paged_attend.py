@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Device time of a decode pass's attention over the paged pool at the
 benchmark cells' shapes, in the two forms models/transformer.py has
-(PERF.md section 6, PRs 40 and 42):
+(PERF.md section 6, PRs 40, 42 and 46):
 
 - ``rung``: the XLA form, ``_attend_pool_rung``'s taken branch: gather
   every slot's block-table columns as far as the rung of
@@ -22,10 +22,16 @@ heads (a group of 5, half a tile of heads a position), 4097 blocks, 64
 columns; kanana 64 slots x 32 heads over ONE plane of shared rows 640
 wide (a latent pool: K and V at once, the query 576 wide), 10,241
 blocks, 160 columns, its layers held one by one (the XLA form's ladder
-is the full extent alone). Lengths: every slot at the table's end; the
+is the full extent alone); mimo-v2.5 at its cell's 2 full layers (2
+planes each of K and of V), 64 slots x 64 query heads of 192 over flat
+rows (a position's 4 K/V heads side by side: 768 columns of K, 512 of
+V), 20,481 blocks, 320 columns, held one by one (the XLA form gathers
+the whole table and reads the rows as they lie, ``_attend_flat_rows``).
+Lengths: every slot at the table's end; the
 cells' own ragged draws (mistral ``decode-sat``: 16 live contexts of
 81-768; Ouro ``cot-sat``: 8 of 249-576; falcon-h1 ``chat-sat``: 64 of
-130-1024; kanana ``reason-sat``: 64 of 65-1600); mistral
+130-1024; kanana ``reason-sat``: 64 of 65-1600; mimo ``longmix-sat``:
+64 of 512-5000); mistral
 ``chat-steady``: one live slot of 16. Each row gives the time, the live
 K and V bytes (every live slot's context once; a latent row once for
 both) and their share of the HBM peak, and the kernel's largest
@@ -71,24 +77,27 @@ def timed(fn, args, calls, repeats):
     return out, min(sets), statistics.median(sets)
 
 
-def forms(bs, mb, window, interpret, latent=False):
+def forms(bs, mb, window, interpret, latent=False, flat=None):
     """name -> jitted f(q, k, v, bt, cl, live, side_k, side_v, t): every
     plane's attention output summed in float32. ``latent``: f(q, rows,
     bt, cl, live, side_rows, t) over a latent pool's one plane (K and V
     at once, ``q`` as wide as the rows' own columns), its layers held
-    one by one, so the XLA form's ladder is the full extent alone."""
+    one by one, so the XLA form's ladder is the full extent alone.
+    ``flat`` = (K/V heads a row, a value head's width): flat rows, K's
+    and V's of different widths, held one by one too."""
     from distributed_llm_inferencing_tpu.models.transformer import (
-        _pool_ladder, _pool_rung)
+        _attend_flat_rows, _flat_rows_q, _pool_ladder, _pool_rung)
     from distributed_llm_inferencing_tpu.ops.attention import attend
     from distributed_llm_inferencing_tpu.ops.paged_kvcache import gather_seq
     from distributed_llm_inferencing_tpu.ops.pallas import (
         paged_attention as pa)
-    ladder = _pool_ladder(mb, scanned=not latent)
+    ladder = _pool_ladder(mb, scanned=not (latent or flat))
 
     def over_planes(one_plane, q, k):
         def body(acc, plane):
             return acc + one_plane(plane).astype(jnp.float32), None
-        return jax.lax.scan(body, jnp.zeros(q.shape, jnp.float32),
+        out = q.shape[:-1] + (flat[1],) if flat else q.shape
+        return jax.lax.scan(body, jnp.zeros(out, jnp.float32),
                             jnp.arange(k.shape[0], dtype=jnp.int32))[0]
 
     def rung(q, k, v, bt, cl, live, side_k, side_v, t):
@@ -102,6 +111,12 @@ def forms(bs, mb, window, interpret, latent=False):
             def run(plane):
                 pos = jnp.broadcast_to(
                     jnp.arange(m * bs, dtype=jnp.int32), (r, m * bs))
+                if flat:
+                    return _attend_flat_rows(
+                        q, (gather_seq(k, bt[:, :m], plane), side_k[plane]),
+                        (gather_seq(v, bt[:, :m], plane), side_v[plane]),
+                        *flat, (cl + t)[:, None], (pos, side_pos),
+                        (pos < cl[:, None], side_valid))
                 w = q.shape[-1]
                 got_k = gather_seq(k, bt[:, :m], plane)[..., :w]
                 got_v = (got_k if v is k
@@ -119,10 +134,15 @@ def forms(bs, mb, window, interpret, latent=False):
 
     def kernel(q, k, v, bt, cl, live, side_k, side_v, t):
         walk = pa.pool_walk(cl, live, k, mb, sliding_window=window,
-                            n_planes=1 if v is k else 2)
+                            n_planes=1 if v is k else 2, v_planes=v)
 
         def one_plane(plane):
             sk = side_k[plane]
+            if flat:
+                return pa.paged_attend(
+                    _flat_rows_q(q, flat[0], k), k, v, plane, bt, cl,
+                    cl + t, walk, (sk, side_v[plane], t), scale=SCALE,
+                    v_head_dim=flat[1], interpret=interpret)
             return pa.paged_attend(
                 q, k, v, plane, bt, cl, cl + t, walk,
                 (sk, sk if v is k else side_v[plane], t),
@@ -180,7 +200,8 @@ def main():
     bs = 16
     # (name, planes, slots, query heads, K/V heads, (row width, the
     #  query's), blocks, columns, window, cases of (name, live slots,
-    #  shortest, longest context)); one K/V head: a latent pool
+    #  shortest, longest context)); one K/V head: a latent pool; the
+    #  row width a pair (a K head's, a V head's): flat rows of K/V heads
     models = [
         ("mistral-7b", 32, 16, 32, 8, (128, 128), 1025, 128, 4096,
          [("full", 16, 2048, 2048), ("decode-sat", 16, 81, 768),
@@ -191,6 +212,8 @@ def main():
          [("full", 64, 1024, 1024), ("chat-sat", 64, 130, 1024)]),
         ("kanana-2-30b-a3b-l7", 7, 64, 32, 1, (640, 576), 10241, 160, None,
          [("full", 64, 2560, 2560), ("reason-sat", 64, 65, 1600)]),
+        ("mimo-v2.5-l7", 2, 64, 64, 4, ((192, 128), 192), 20481, 320, None,
+         [("full", 64, 5120, 5120), ("longmix-sat", 64, 512, 5000)]),
     ]
     if args.small:
         models = [(name, 2, 4, h, hkv, (256, 200) if hkv == 1 else hd, 33,
@@ -205,21 +228,25 @@ def main():
     for name, planes, r, h, hkv, (hd, qw), nb, mb, window, cases in models:
         keys = jax.random.split(jax.random.PRNGKey(args.seed), 5)
         latent = hkv == 1
+        flat = (hkv, hd[1]) if isinstance(hd, tuple) else None
         # a latent pool's columns past the query's are zeros, as stored
 
-        def rows(key, *lead):
+        def rows(key, *lead, v=False):
+            if flat:
+                return jax.random.normal(
+                    key, lead + (1, hkv * hd[v]), jnp.bfloat16)
             x = jax.random.normal(key, lead + (hkv, hd), jnp.bfloat16)
             return x * (jnp.arange(hd) < qw).astype(jnp.bfloat16)
-        pool = tuple(rows(kk, planes, nb, bs)
-                     for kk in keys[:1 if latent else 2])
+        pool = tuple(rows(kk, planes, nb, bs, v=bool(i))
+                     for i, kk in enumerate(keys[:1 if latent else 2]))
         q = jax.random.normal(keys[2], (r, 1, h, qw), jnp.bfloat16)
-        side = tuple(rows(kk, planes, r, SIDE)
-                     for kk in keys[3:4 if latent else 5])
+        side = tuple(rows(kk, planes, r, SIDE, v=bool(i))
+                     for i, kk in enumerate(keys[3:4 if latent else 5]))
         # the rung form once, the kernel under every plan
         runs = [(form + label, fn, plan)
                 for label, plan in plans
                 for form, fn in forms(bs, mb, window, args.small,
-                                      latent).items()
+                                      latent, flat).items()
                 if form == "kernel" or not label]
         for case, n_live, lo, hi in cases:
             lens = np.zeros(r, np.int64)
@@ -229,7 +256,8 @@ def main():
             bt.reshape(-1)[:] = rng.permutation(r * mb) % (nb - 1)
             call = (q, *pool, jnp.asarray(bt), jnp.asarray(lens, jnp.int32),
                     jnp.asarray(lens > 0), *side, jnp.int32(3))
-            gb = planes * int(lens.sum()) * hkv * hd * 2 * len(pool) / 1e9
+            gb = planes * int(lens.sum()) * hkv * 2 * (
+                sum(hd) if flat else hd * len(pool)) / 1e9
             ref = None
             for form, fn, plan in runs:
                 use_plan(plan)

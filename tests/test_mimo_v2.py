@@ -45,12 +45,17 @@ def cfg32(**kw):
                                               attn_backend="xla", **kw)
 
 
-@pytest.fixture(scope="module")
-def params():
+# value heads of whole lanes: what lets a decode chunk's full layers read
+# the pool by the paged kernel (transformer._pool_kernel), interpreted
+# here; K rows stay 2 x 24 -> 128 columns, V rows become 2 x 128 = 256
+KERNEL = dict(v_head_dim=128, pool_kernel="pallas_interpret")
+
+
+def _params(cfg):
     """Seeded random weights; the norms' scales too, or a norm left out
     would go unseen behind ones (sinks and the router's bias are drawn
     by init_params)."""
-    p = init_params(cfg32(), jax.random.PRNGKey(0), dtype=jnp.float32)
+    p = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
     keys = iter(jax.random.split(jax.random.PRNGKey(1), 16))
 
     def jitter(a):
@@ -60,6 +65,26 @@ def params():
             p[stack][name]["scale"] = jitter(p[stack][name]["scale"])
     p["final_norm"]["scale"] = jitter(p["final_norm"]["scale"])
     return p
+
+
+@pytest.fixture(scope="module")
+def params():
+    return _params(cfg32())
+
+
+@pytest.fixture(scope="module")
+def kernel_params():
+    return _params(cfg32(**KERNEL))
+
+
+@pytest.fixture(params=["gather", "kernel"])
+def model(request, params):
+    """(cfg, params) a hand-driven pool is run with: the toy model, whose
+    full layers gather the pool in XLA, and the same with value heads of
+    128, whose full layers read it by the paged kernel (interpreted)."""
+    if request.param == "gather":
+        return cfg32(), params
+    return cfg32(**KERNEL), request.getfixturevalue("kernel_params")
 
 
 def tokens(n, seed=0):
@@ -329,15 +354,15 @@ class Sim:
 
 
 def test_slots_that_join_at_different_times_match_the_reference(
-        params, monkeypatch):
+        model, monkeypatch):
     """Two prompts in one wave (a padded tail bucket: 9 and 14 of 16,
     both past the window), decode, a third joins in a wave with a padded
     row while the others are mid-way, chunks of 4 through side buffers,
     ring and pool, every slot over a ring and blocks that held something
     else before. The pool is read by the in-loop gather under the
-    ladder's switch, as the chip reads it."""
+    ladder's switch, or by the paged kernel, as the chip reads it."""
     monkeypatch.setattr(transformer, "_PREGATHER_MAX_BYTES", 0)
-    sim = Sim(cfg32(), params, spoil=True)
+    sim = Sim(*model, spoil=True)
     sim.start([(0, tokens(9, 1)), (1, tokens(14, 2))], 16, 2)
     sim.decode(4, [4, 4, 0])
     sim.start([(2, tokens(6, 3))], 8, 2)          # one real row of two
@@ -349,10 +374,11 @@ def test_slots_that_join_at_different_times_match_the_reference(
         assert sim.check(1, **CONTROLS[control]) > 10 * TOL, control
 
 
-def test_a_context_that_wraps_the_ring_twice(params):
+def test_a_context_that_wraps_the_ring_twice(model):
     """Ring 8: a prompt of 13 wraps it once at admission, 12 decoded
-    positions wrap it again and more; the pre-gathered pool."""
-    sim = Sim(cfg32(), params)
+    positions wrap it again and more; the pre-gathered pool (or the
+    kernel's walk)."""
+    sim = Sim(*model)
     assert sim.paged.ring_k.shape[2] == 8
     sim.start([(1, tokens(13, 5))], 16, 2)
     for _ in range(3):
@@ -360,11 +386,13 @@ def test_a_context_that_wraps_the_ring_twice(params):
     assert sim.check(1) < TOL
 
 
-def test_a_decode_chunk_of_8_across_the_windows_edge(params):
+def test_a_decode_chunk_of_8_across_the_windows_edge(model):
     """A prompt of 5 (inside the window), then one chunk of 8 passes:
     the window's edge falls inside the chunk, its first passes see ring
-    rows the later ones must not, side buffers and ring in one softmax."""
-    sim = Sim(cfg32(), params, spoil=True)
+    rows the later ones must not, side buffers and ring in one softmax
+    (and the full layers' eight passes of side rows beside a pool context
+    of less than a page and a half)."""
+    sim = Sim(*model, spoil=True)
     sim.start([(0, tokens(5, 6))], 8, 2)
     sim.decode(8, [8, 0, 0])
     sim.decode(8, [8, 0, 0])
@@ -372,12 +400,12 @@ def test_a_decode_chunk_of_8_across_the_windows_edge(params):
     assert sim.check(0, sliding_window=7) > 10 * TOL
 
 
-def test_a_prompt_in_chunks_goes_on_from_its_slots_ring(params):
+def test_a_prompt_in_chunks_goes_on_from_its_slots_ring(model):
     """A prompt of 27 in chunks of 8 (the last one 3 of a bucket of 8):
     a later chunk's windowed layers see the ring the earlier ones left,
     across every chunk boundary and the ring's wrap; its full layers
     gather the earlier chunks' blocks."""
-    sim = Sim(cfg32(), params, spoil=True)
+    sim = Sim(*model, spoil=True)
     prompt = tokens(27, 7)
     for pre in (0, 8, 16, 24):
         last = sim.admit([(2, prompt[pre:pre + 8], pre)], 8, 2)
@@ -387,16 +415,16 @@ def test_a_prompt_in_chunks_goes_on_from_its_slots_ring(params):
     assert sim.check(2) < TOL
 
 
-def test_a_reused_slot_sees_nothing_of_its_last_tenant(params):
+def test_a_reused_slot_sees_nothing_of_its_last_tenant(model):
     """A slot's second tenant (shorter than the ring: most rows still
     hold the first one's positions) reads what a fresh slot reads, bit
     for bit."""
-    sim = Sim(cfg32(), params)
+    sim = Sim(*model)
     sim.start([(0, tokens(14, 8))], 16, 2)
     sim.decode(4, [4, 0, 0])
     sim.start([(0, tokens(5, 9))], 8, 2)
     sim.decode(4, [4, 0, 0])
-    fresh = Sim(cfg32(), params)
+    fresh = Sim(*model)
     fresh.start([(0, tokens(5, 9))], 8, 2)
     fresh.decode(4, [4, 0, 0])
     assert sim.check(0) < TOL
@@ -418,11 +446,11 @@ def test_padded_rows_and_padded_positions_change_no_live_ring(params):
     assert not np.array_equal(after[:, 1, :5], before[:, 1, :5])
 
 
-def test_a_slot_that_ends_inside_a_chunk_stops_writing_its_ring(params):
+def test_a_slot_that_ends_inside_a_chunk_stops_writing_its_ring(model):
     """Budget 2 in a chunk of 4: the slot's ring takes two rows, its
     later passes' rows go to the dummy row, and the slots beside it are
     what they would be alone."""
-    sim = Sim(cfg32(), params)
+    sim = Sim(*model)
     sim.start([(0, tokens(9, 11)), (1, tokens(6, 12))], 16, 2)
     before = np.asarray(sim.paged.ring_v)
     sim.decode(4, [2, 4, 0])
@@ -513,6 +541,56 @@ def test_the_batcher_serves_what_the_reference_computes(monkeypatch):
     assert counters["batcher_moe_experts_held"] \
         == 32 * counters["batcher_moe_layer_passes"]
     assert counters.get("prefix_hits", 0) == 0
+
+
+def test_the_batcher_counts_the_passes_its_full_layers_read_by_the_kernel(
+        monkeypatch):
+    """With value heads of whole lanes and the batcher's pin interpreted
+    (a one-device TPU pins "pallas") every decode pass reads the full
+    layers' pool by the paged kernel: ``batcher_pool_kernel_passes`` is
+    the weight passes, the span says so, the pool's extent a pass is the
+    longest live context in whole blocks and not the table's 64, and the
+    tokens are the reference's. Where the pin is a mesh's ("xla") the
+    count stays 0 and the in-loop gather serves the same tokens; value
+    heads of the toy's 16 columns (served off the kernel in
+    tests/test_pallas_parity.py), planes out of the compute dtype (an
+    int8 pool; the batcher refuses one by name) or a sink on the full
+    layers are turned away by transformer._pool_kernel."""
+    from distributed_llm_inferencing_tpu.utils import trace
+    monkeypatch.setattr(transformer, "_PREGATHER_MAX_BYTES", 0)
+    prompts = [tokens(n, 60 + n) for n in (5, 13)]
+    mesh_pin = batcher_mod._expert_backend(2, "tpu")   # "xla"
+
+    def passes(cfg, pin):
+        monkeypatch.setattr(batcher_mod, "_expert_backend",
+                            lambda *a, **k: pin)
+        b, reqs = serve(cfg, prompts, new=9, slots=2)
+        counters = b.metrics.snapshot()["counters"]
+        span = [s_ for s_ in trace.get_tracer().spans()
+                if s_.name == "batcher.decode_chunk"][-1]
+        assert span.attrs["pool_kernel"] == int(b.pool_kernel)
+        if pin != mesh_pin:
+            served_right(b, reqs, 9)
+        return ([r.tokens for r in reqs],
+                counters["batcher_pool_kernel_passes"],
+                counters["batcher_weight_passes"],
+                counters["batcher_decode_pool_positions"])
+    wide = cfg32(v_head_dim=128)
+    toks, kernel, weight, positions = passes(wide, "pallas_interpret")
+    assert kernel == weight > 0
+    # contexts reach 13 + 9: six blocks of 4 at most, of the table's 16
+    assert 0 < positions <= 24 * weight
+    assert passes(wide, mesh_pin)[:2] == (toks, 0)
+    cfg = wide.replace(pool_kernel="pallas_interpret")
+    paged = init_paged_cache(cfg, 9, BS, dtype=jnp.float32, slots=2)
+    assert transformer._pool_kernel(cfg, paged) == "pallas_interpret"
+    assert transformer._pool_kernel(
+        cfg32(pool_kernel="pallas_interpret"),
+        init_paged_cache(cfg32(), 9, BS, dtype=jnp.float32, slots=2)) is None
+    assert transformer._pool_kernel(cfg, paged._replace(
+        k=paged.k.astype(jnp.bfloat16))) is None
+    assert transformer._pool_kernel(
+        cfg.replace(attn_sinks=True), paged) is None
 
 
 def test_the_batcher_serves_a_held_share():

@@ -97,7 +97,11 @@ def test_flash_prefill_odd_shapes(B, S, H, Hkv, hd):
 # rows of a chunk of 1 and of 8 passes. Then heads that fill half or a
 # quarter of a tile's sublanes (4: falcon-h1's 20 query heads over them, a
 # group that is no power of two, and trinity's 32; 2), the 4 with every
-# chunk size's side rows (4 rows at a chunk of one: half a tile)
+# chunk size's side rows (4 rows at a chunk of one: half a tile). Last,
+# flat rows (``hd`` a pair: a K head's width and a V head's; ``hkv`` the
+# K/V heads side by side in a position's one row of each plane): 2 heads
+# of 192 / 128 -> rows of 384 / 256 columns, and mimo-v2.5's 4 -> 768 /
+# 512 under 64 query heads
 _PAGED_SHAPES = [
     (g, hkv, hd, window, side_rows)
     for g, hkv, hd, window in [
@@ -108,7 +112,9 @@ _PAGED_SHAPES = [
 ] + [(5, 4, 128, window, side_rows) for window in (None, 9)
      for side_rows in (1, 2, 4, 8)
 ] + [(8, 4, 128, None, 1), (8, 4, 128, 9, 2), (8, 4, 128, None, 4),
-     (8, 4, 128, 9, 8), (3, 2, 128, None, 1), (3, 2, 128, 9, 8)]
+     (8, 4, 128, 9, 8), (3, 2, 128, None, 1), (3, 2, 128, 9, 8)
+] + [(g, hkv, (192, 128), None, side_rows)
+     for g, hkv in ((2, 2), (16, 4)) for side_rows in (1, 8)]
 
 
 @pytest.mark.parametrize("g,hkv,hd,window,side_rows", _PAGED_SHAPES)
@@ -126,37 +132,51 @@ def test_paged_attend_matches_two_segment_attend(monkeypatch, dtype, g, hkv,
     several items and ends on a short one, in steps of both widths.
     With one K/V head the plane is a latent pool's: its rows are K and V
     at once (handed in as both), zeros past the query's width, and the
-    reference is attend with the rows' own columns as K and V."""
+    reference is attend with the rows' own columns as K and V. Flat rows
+    (a model with layer kinds): K and V planes of one row a position,
+    its heads side by side and V's narrower than K's, the query
+    zero-expanded to K's row as the decode chunk expands it, a context
+    of less than a page; the reference is attend over the same rows
+    viewed as heads."""
+    from distributed_llm_inferencing_tpu.models.transformer import (
+        _flat_rows_q)
     from distributed_llm_inferencing_tpu.ops import attention
     from distributed_llm_inferencing_tpu.ops.pallas import paged_attention
-    from distributed_llm_inferencing_tpu.ops.paged_kvcache import gather_seq
+    from distributed_llm_inferencing_tpu.ops.paged_kvcache import (
+        gather_seq, head_rows)
     dt = jnp.dtype(dtype)
     rng = np.random.default_rng(g * 100 + hkv + side_rows)
+    flat = isinstance(hd, tuple)
     shared = hkv == 1
     planes, nb, bs, mb = (2, 20, 4, 4) if shared else (3, 48, 4, 7)
     h = g * hkv
-    qw = hd - 24 if shared else hd
-    lens = np.asarray([0, 13, 8, mb * bs, 5, 17][:4 if shared else 6],
-                      np.int32)
+    if flat:   # the planes hold one "head": the row
+        (hd, vd), heads, hkv = hd, hkv, 1
+    qw = hd - 24 if shared else vd if flat else hd
+    lens = np.asarray([0, 13, 3 if flat else 8, mb * bs, 5,
+                       17][:4 if shared else 6], np.int32)
     live = np.asarray([0, 1, 1, 1, 0, 1][:len(lens)], bool)
     if shared:     # the empty slot first, then a dead one that holds rows
         lens, live = lens[[0, 2, 1, 3]], np.asarray([1, 0, 1, 1], bool)
     r = len(lens)
 
-    def rows(*lead):
+    def rows(*lead, v=False):
+        if flat:
+            return _rand(rng, *lead, 1, heads * (vd if v else hd)).astype(dt)
         x = _rand(rng, *lead, hkv, hd)
         return (x * (jnp.arange(hd) < qw)).astype(dt)
     k_planes = rows(planes, nb, bs)
-    v_planes = k_planes if shared else rows(planes, nb, bs)
+    v_planes = k_planes if shared else rows(planes, nb, bs, v=True)
     bt = jnp.asarray(rng.permutation(np.arange(1, nb))[:r * mb]
                      .reshape(r, mb).astype(np.int32))
     side_k = rows(r, side_rows)
-    side_v = side_k if shared else rows(r, side_rows)
-    q = _rand(rng, r, 1, h, qw).astype(dt)
+    side_v = side_k if shared else rows(r, side_rows, v=True)
+    q = _rand(rng, r, 1, h, hd if flat else qw).astype(dt)
     cl = jnp.asarray(lens)
     # (pages a tail step, a step, an item) = (1, 2, 4)
     n_planes = 1 if shared else 2
-    page = n_planes * bs * hkv * hd * dt.itemsize
+    page = bs * dt.itemsize * (heads * (hd + vd) if flat
+                               else n_planes * hkv * hd)
     monkeypatch.setattr(paged_attention, "_TAIL_BYTES", page)
     monkeypatch.setattr(paged_attention, "_STEP_BYTES", 2 * page)
     monkeypatch.setattr(paged_attention, "_ITEM_BYTES", 4 * page)
@@ -166,7 +186,7 @@ def test_paged_attend_matches_two_segment_attend(monkeypatch, dtype, g, hkv,
     # an item's 4 pages started and awaited a page a loop iteration, or
     # (a shared plane, 4 and 16 heads) in groups of 3 + 1 and in bulk
     monkeypatch.setattr(paged_attention, "_LOOP_PAGES",
-                        2 if shared or hkv in (4, 16) else 4)
+                        2 if shared or flat or hkv in (4, 16) else 4)
     monkeypatch.setattr(paged_attention, "_GROUP", 3)
     items = sum(
         -(-(-(-n // bs) - (max(n - window + 1, 0) // bs if window else 0))
@@ -177,17 +197,21 @@ def test_paged_attend_matches_two_segment_attend(monkeypatch, dtype, g, hkv,
         # operations each compile, a second of every case)
         walk = paged_attention.pool_walk(
             cl, jnp.asarray(live), k_planes, mb, sliding_window=window,
-            n_planes=n_planes)
+            n_planes=n_planes, v_planes=v_planes)
         out = paged_attention.paged_attend(
-            q, k_planes, v_planes, plane, bt, cl, cl + t, walk,
+            _flat_rows_q(q, heads, k_planes) if flat else q, k_planes,
+            v_planes, plane, bt, cl, cl + t, walk,
             (side_k, side_v, t), sliding_window=window, scale=0.11,
-            interpret=True)
+            v_head_dim=vd if flat else None, interpret=True)
         pool_pos = jnp.broadcast_to(jnp.arange(mb * bs), (r, mb * bs))
         side_pos = cl[:, None] + jnp.arange(side_rows)[None, :]
+
+        def seg(x, w):   # a segment's rows as attend takes them
+            return head_rows(x, heads, w) if flat else x[..., :qw]
         ref = attention.attend(
-            q, (gather_seq(k_planes, bt, plane)[..., :qw],
-                side_k[..., :qw]),
-            (gather_seq(v_planes, bt, plane)[..., :qw], side_v[..., :qw]),
+            q, (seg(gather_seq(k_planes, bt, plane), hd), seg(side_k, hd)),
+            (seg(gather_seq(v_planes, bt, plane), vd if flat else hd),
+             seg(side_v, vd if flat else hd)),
             (cl + t)[:, None], (pool_pos, side_pos),
             (pool_pos < cl[:, None],
              jnp.broadcast_to(jnp.arange(side_rows) <= t, (r, side_rows))),
@@ -243,6 +267,8 @@ def test_paged_attend_supported_shapes():
     for hkv in (1, 2, 4, 8, 16):
         assert supported(hkv, 128, jnp.bfloat16)
     assert supported(4, 256, jnp.float32) and supported(1, 640, jnp.bfloat16)
+    # mimo-v2.5's flat rows: one row a position in each plane
+    assert supported(1, 768, jnp.bfloat16) and supported(1, 512, jnp.bfloat16)
     assert not supported(3, 128, jnp.bfloat16)
     assert not supported(12, 128, jnp.bfloat16)
     assert not supported(4, 64, jnp.bfloat16)
@@ -267,6 +293,10 @@ _KERNEL_HEADS = dict(num_heads=8, num_kv_heads=8, head_dim=128)
     ("tiny-falcon-h1", dict(num_heads=20, num_kv_heads=4, head_dim=128),
      True),
     ("tiny-falcon-h1", {}, False),             # 2 heads of 24: not its shape
+    # mimo-v2's full layers: flat rows, 2 x 24 -> 128 columns of K and 2
+    # value heads of 128 -> 256 of V; the windowed layers keep their ring
+    ("tiny-mimo-v2", dict(v_head_dim=128), True),
+    ("tiny-mimo-v2", {}, False),               # values of 16: no whole lanes
 ])
 def test_batcher_pool_kernel_where_the_shape_allows(monkeypatch, model,
                                                     shape, kernel):
