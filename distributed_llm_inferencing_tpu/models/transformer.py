@@ -526,12 +526,16 @@ def embed(params, cfg: ModelConfig, tokens, q_positions):
 
 
 @jax.named_scope("lm_head")
-def unembed(params, cfg: ModelConfig, x):
+def unembed(params, cfg: ModelConfig, x, as_computed: bool = False):
     """Final norm + logits head, f32. Shared with parallel/pipeline.py.
 
     Post-LN models (opt-350m) have no final norm — each block already
     normalized its residual output; the embed projection (if any) maps
     back to the embedding dim before the tied head.
+
+    ``as_computed``: the logits in the dtype the head computed them in
+    (``x``'s), for ops/sampling.sample_batch, whose search walks the
+    bits they have; the float32 copy holds the same values.
     """
     if not cfg.post_norm:
         x = norm(x, params["final_norm"], cfg.norm_type, cfg.norm_eps)
@@ -553,7 +557,7 @@ def unembed(params, cfg: ModelConfig, x):
         logits = logits * cfg.logit_scale
     if cfg.logit_softcap is not None:
         logits = jnp.tanh(logits / cfg.logit_softcap) * cfg.logit_softcap
-    return logits.astype(jnp.float32)
+    return logits if as_computed else logits.astype(jnp.float32)
 
 
 def _qk_normalize(t, p, cfg: ModelConfig):
@@ -1974,7 +1978,7 @@ def decode_chunk_with_logits(params, cfg: ModelConfig, k: int, tokens, paged,
             make_layer, (x, side), params, cfg,
             (jnp.asarray(cfg.cache_index, jnp.int32) if kinds
              else jnp.arange(L, dtype=jnp.int32),))
-        logits = unembed(params, cfg, x2)[:, 0]
+        logits = unembed(params, cfg, x2, as_computed=True)[:, 0]
         with jax.named_scope("sample"):
             nxt = sample_batch(logits, seeds, steps0 + t, temps, tks, tps,
                                ds)
@@ -1985,8 +1989,8 @@ def decode_chunk_with_logits(params, cfg: ModelConfig, k: int, tokens, paged,
         # a pass nobody is alive in (the chunk outran every budget)
         # counts for nothing
         moe = jnp.sum(moe, axis=0) * jnp.any(alive)
-        return (nxt, side, new_cl, new_alive), (nxt, emit, alive, moe,
-                                                logits)
+        return (nxt, side, new_cl, new_alive), (
+            nxt, emit, alive, moe, logits.astype(jnp.float32))
 
     (_, side, _, _), (toks, emits, wrote, moe, logits) = jax.lax.scan(
         body, (tokens, side0, context_lens, budget > 0),
@@ -2257,7 +2261,7 @@ def paged_speculative_chunk(params, cfg: ModelConfig, k: int, gamma: int,
 
 def _kinds_prefill_tail(params, cfg: ModelConfig, tokens, tail_len,
                         tail_blocks, prefix_blocks, prefix_len, paged,
-                        slots):
+                        slots, logits_as_computed=False):
     """paged_prefill_tail for a model with layer kinds (cfg.swa,
     MiMo-V2): two caches for two kinds of layer. A full layer gathers
     its cached prefix (a chunked prompt's earlier chunks: such a model
@@ -2366,12 +2370,13 @@ def _kinds_prefill_tail(params, cfg: ModelConfig, tokens, tail_len,
     last_x = jnp.take_along_axis(
         x, jnp.maximum(tail_len - 1, 0)[:, None, None].astype(jnp.int32),
         axis=1)
-    return unembed(params, cfg, last_x)[:, 0], paged
+    return unembed(params, cfg, last_x, logits_as_computed)[:, 0], paged
 
 
 def paged_prefill_tail(params, cfg: ModelConfig, tokens, tail_len,
                        tail_blocks, prefix_blocks, prefix_len, paged,
-                       lora_ids=None, slots=None):
+                       lora_ids=None, slots=None,
+                       logits_as_computed: bool = False):
     """Prefill a WAVE of prompt tails into paged blocks, each attending its
     own cached prefix.
 
@@ -2399,7 +2404,9 @@ def paged_prefill_tail(params, cfg: ModelConfig, tokens, tail_len,
     tail_blocks: [B, T // bs] int32 (padding rows all-dummy; legacy
     unbatched [T // bs] accepted when B == 1);
     prefix_blocks: [B, PB] (dummy-padded); prefix_len: [B].
-    Returns (last-token logits [B, V] f32, new paged).
+    Returns (last-token logits [B, V] f32, new paged);
+    ``logits_as_computed``: in the head's own dtype, for the sampler
+    (``unembed``).
 
     A model with state layers (cfg.ssm) takes ``slots`` [B]: the serving
     slot whose state row each wave row continues (the dummy row, the
@@ -2416,7 +2423,7 @@ def paged_prefill_tail(params, cfg: ModelConfig, tokens, tail_len,
     if cfg.swa is not None:
         return _kinds_prefill_tail(params, cfg, tokens, tail_len,
                                    tail_blocks, prefix_blocks, prefix_len,
-                                   paged, slots)
+                                   paged, slots, logits_as_computed)
     b, t = tokens.shape
     if tail_blocks.ndim == 1:
         tail_blocks = tail_blocks[None]
@@ -2536,5 +2543,5 @@ def paged_prefill_tail(params, cfg: ModelConfig, tokens, tail_len,
     last_x = jnp.take_along_axis(
         x, jnp.maximum(tail_len - 1, 0)[:, None, None].astype(jnp.int32),
         axis=1)                                         # [B, 1, D]
-    last = unembed(params, cfg, last_x)[:, 0]           # [B, V]
+    last = unembed(params, cfg, last_x, logits_as_computed)[:, 0]  # [B, V]
     return last, paged

@@ -1232,7 +1232,7 @@ class ContinuousBatcher:
                 else:
                     last, paged = transformer.paged_prefill_tail(
                         p, cfg, toks, tl, tb, pfb, pfl, paged,
-                        lora_ids=aids, **kw)
+                        lora_ids=aids, logits_as_computed=True, **kw)
                 with jax.named_scope("sample"):
                     first = sample_batch(last, seeds, steps, temps, tks,
                                          tps, ds.astype(bool))

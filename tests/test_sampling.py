@@ -36,9 +36,9 @@ def _draw(logits, seeds, steps, temps, tks, tps, ds):
         jnp.asarray(tps, jnp.float32), jnp.asarray(ds, bool)))
 
 
-def _draw_many(logits, seed, steps, temp, tk, tp):
+def _draw_many(logits, seed, steps, temp, tk, tp, dtype="float32"):
     """Vectorized multi-step draws for distribution tests (one compile)."""
-    logits = jnp.asarray(logits, jnp.float32)
+    logits = jnp.asarray(logits, jnp.dtype(dtype))
 
     @jax.jit
     def go(steps):
@@ -122,11 +122,16 @@ def test_prefix_path_matches_full_distribution():
 # ---- the full tier: thresholds by search, held to a sort ---------------
 
 V = 2000
-ROW_KINDS = ("flat", "peaked", "bf16_ties", "neg_inf")
+ROW_KINDS = ("flat", "peaked", "bf16_ties", "neg_inf", "edges")
 MARGIN = 1e-5      # float32 summation error around top_p (docstring, 1.)
+# the dtype the logits are handed over in: float32 (a 32-bit search, 16
+# steps) or bfloat16 as a bf16 head computes them (16 bits, 8 steps)
+DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}
 
 
-def _rows(kind, r, v, seed=0):
+def _rows(kind, r, v, seed=0, dtype="float32"):
+    """[r, v] float32 logits; for ``bfloat16`` every value is one a bf16
+    head can give (handed over as bf16 by ``_as``)."""
     rng = np.random.default_rng([seed, ROW_KINDS.index(kind)])
     x = rng.normal(size=(r, v))
     if kind == "flat":          # what random weights give: a wide nucleus
@@ -135,9 +140,21 @@ def _rows(kind, r, v, seed=0):
         x *= 4.0
     elif kind == "bf16_ties":   # a bf16 head's logits: many equal values
         x = np.asarray(jnp.asarray(x * 2, jnp.bfloat16).astype(jnp.float32))
-    else:                       # banned tokens
+    elif kind == "neg_inf":     # banned tokens
         x[:, ::3] = -np.inf
+    else:                       # -0.0 beside 0.0, banned tokens, a row of
+        x[:, ::5] = -0.0        # one value, a row of one value and bans
+        x[:, 1::5] = 0.0
+        x[:, 2::7] = -np.inf
+        x[-1] = 1.25
+        x[-2] = np.where(np.arange(v) % 2, -np.inf, -3.0)
+    if dtype == "bfloat16":
+        x = np.asarray(jnp.asarray(x, jnp.bfloat16).astype(jnp.float32))
     return x.astype(np.float32)
+
+
+def _as(x, dtype):
+    return jnp.asarray(x, DTYPES[dtype])
 
 
 def _oracle(x, k, p):
@@ -171,27 +188,35 @@ FORMS = {"search": jax.jit(sampling.nucleus_thresholds),
          "sort": jax.jit(_thresholds_by_sort)}
 
 
-def _kept(x, k, p, form):
+def _kept(x, k, p, form, dtype="float32"):
+    """x: float32 values, handed to the form as ``dtype`` (the sort form
+    always takes float32). The cuts come back as float32."""
     r, v = x.shape
     kth, thresh = FORMS[form](
-        jnp.asarray(x), jnp.full((r,), v if k <= 0 else min(k, v), jnp.int32),
+        _as(x, dtype if form == "search" else "float32"),
+        jnp.full((r,), v if k <= 0 else min(k, v), jnp.int32),
         jnp.full((r,), p, jnp.float32))
-    cut = np.maximum(np.asarray(kth), np.asarray(thresh))
-    return x >= cut[:, None], np.asarray(kth), np.asarray(thresh)
+    kth, thresh = (np.asarray(c.astype(jnp.float32)) for c in (kth, thresh))
+    return x >= np.maximum(kth, thresh)[:, None], kth, thresh
 
 
+@pytest.mark.parametrize("dtype", list(DTYPES))
 @pytest.mark.parametrize("form", list(FORMS))
-@pytest.mark.parametrize("p", [0.1, 0.9, 1.0])
+@pytest.mark.parametrize("p", [0.0, 0.1, 0.9, 1.0])
 @pytest.mark.parametrize("k", [0, 1, 129, 500, V])
 @pytest.mark.parametrize("kind", ROW_KINDS)
-def test_kept_set_matches_float64_oracle(kind, k, p, form):
-    x = _rows(kind, 8, V)
-    kept, kth, thresh = _kept(x, k, p, form)
+def test_kept_set_matches_float64_oracle(kind, k, p, form, dtype):
+    x = _rows(kind, 8, V, dtype=dtype)
+    kept, kth, thresh = _kept(x, k, p, form, dtype)
     for r in range(x.shape[0]):
         want, sure = _oracle(x[r], k, p)
+        if p == 0.0:        # the top token stays (and what ties with it)
+            want = x[r] == x[r].max()
         np.testing.assert_array_equal(kept[r][sure], want[sure])
-        # (at p = 1.0 a long tail lies within the margin of 1.0)
-        assert kept[r].any() and (p == 1.0 or sure.mean() > 0.99)
+        # (at p = 1.0 a long tail lies within the margin of 1.0; a row of
+        # one value lies on the boundary whole)
+        assert kept[r].any() and (p == 1.0 or kind == "edges"
+                                  or sure.mean() > 0.99)
         # the cut is a value of the row
         assert max(kth[r], thresh[r]) in x[r]
 
@@ -215,26 +240,30 @@ def _sorted_full_draw(logits, seeds, steps, temps, top_ks, top_ps):
         lambda kk, l: jax.random.categorical(kk, l))(keys, masked)
 
 
+@pytest.mark.parametrize("dtype", list(DTYPES))
 @pytest.mark.parametrize("block, r, v", [
     (0, 64, 8192), (1, 64, 8192), (2, 64, 8192), (3, 64, 8192),
     (4, 16, 32000), (5, 16, 32000),      # a pass of the mistral cells
 ])
-def test_tokens_equal_the_sorted_form_at_the_cells_settings(block, r, v):
+def test_tokens_equal_the_sorted_form_at_the_cells_settings(block, r, v,
+                                                            dtype):
     """Random rows in blocks of 64 (a decode pass of the kanana cell) and
     of 16 x 32,000 (one of the mistral cells, which sorted until PR 36)
     at the benchmark's settings, temperature 0.7, top-p 0.9, top-k off:
     the drawn token is the sort-based form's, bit for bit, in every row
     the oracle is sure of (a flat row's tokens weigh about 1e-4 each, so
     in a quarter of such rows some token's mass lies within the margin
-    of 0.9; even there the tokens rarely differ)."""
+    of 0.9; even there the tokens rarely differ). bfloat16: the logits
+    go to the sampler as a bf16 head gives them (the 16-bit search), the
+    sorted form takes their float32 copy."""
     kind = ROW_KINDS[block % 3]
-    x = _rows(kind, r, v, seed=10 + block)
+    x = _rows(kind, r, v, seed=10 + block, dtype=dtype)
     seeds = jnp.arange(r, dtype=jnp.int32) + 1000 * block
     steps = jnp.full((r,), 17 + block, jnp.int32)
     temps = jnp.full((r,), 0.7, jnp.float32)
     tks = jnp.zeros((r,), jnp.int32)
     tps = jnp.full((r,), 0.9, jnp.float32)
-    got = np.asarray(_jit_sample(jnp.asarray(x), seeds, steps, temps, tks,
+    got = np.asarray(_jit_sample(_as(x, dtype), seeds, steps, temps, tks,
                                  tps, jnp.ones((r,), bool)))
     want = np.asarray(jax.jit(_sorted_full_draw)(x, seeds, steps, temps,
                                                  tks, tps))
@@ -253,8 +282,8 @@ def _primitives(jaxpr):
 
 
 # rows x vocabulary of a decode pass in the benchmark's cells
-CELL_SIZES = [(16, 32000), (64, 128256), (64, 200192)]
-CELL_IDS = ["mistral", "kanana", "trinity"]
+CELL_SIZES = [(16, 32000), (64, 128256), (64, 200192), (64, 261120)]
+CELL_IDS = ["mistral", "kanana", "trinity", "falcon-h1"]
 
 
 @pytest.mark.parametrize("name", ["sample_batch", "sample", "warp_logits"])
@@ -280,20 +309,58 @@ def test_no_path_sorts_the_vocabulary(name, rows, vocab):
     assert prims & {"while", "scan"}                  # the search's loop
 
 
+def _loops(jaxpr):
+    """(trip count, body's primitives) of every fori_loop in a jaxpr: a
+    ``scan`` where the trip count is static, as the search's is."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn.params["length"], set(
+                _primitives(eqn.params["jaxpr"].jaxpr))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _loops(sub)
+
+
+@pytest.mark.parametrize("dtype,trips", [
+    ("float32", 16), ("bfloat16", 8), ("float16", 8)])
+def test_the_search_runs_a_step_for_every_two_bits_the_logits_have(dtype,
+                                                                   trips):
+    """The search's loops (top-k's and the nucleus's, in the full tier's
+    branch and in the both-tiers branch) run 32 / 2 steps for float32
+    logits and 16 / 2 for a 16-bit head's, a compare-select-reduce each;
+    nothing in the sampler sorts either."""
+    rows, vocab = 64, 128256
+    i = jax.ShapeDtypeStruct((rows,), jnp.int32)
+    f = jax.ShapeDtypeStruct((rows,), jnp.float32)
+    jaxpr = jax.make_jaxpr(sample_batch)(
+        jax.ShapeDtypeStruct((rows, vocab), jnp.dtype(dtype)), i, i, f, i,
+        f, jax.ShapeDtypeStruct((rows,), jnp.bool_))
+    loops = [(n, prims) for n, prims in _loops(jaxpr.jaxpr)
+             if "reduce_sum" in prims]
+    assert [n for n, _ in loops] == [trips] * 4, loops
+    assert all({"ge", "select_n"} <= prims for _, prims in loops)
+    assert "sort" not in set(_primitives(jaxpr.jaxpr))
+
+
+@pytest.mark.parametrize("dtype", list(DTYPES))
 @pytest.mark.parametrize("kind", ["flat", "bf16_ties"])
 @pytest.mark.parametrize("rows,vocab", CELL_SIZES, ids=CELL_IDS)
-def test_thresholds_equal_the_sort_at_the_cells_sizes(rows, vocab, kind):
+def test_thresholds_equal_the_sort_at_the_cells_sizes(rows, vocab, kind,
+                                                      dtype):
     """The search against the reference sort at each cell's rows x
     vocabulary and settings (temperature 0.7, top-p 0.9, top-k off, and
     top-k 500 beside it): ``kth`` is an order statistic and equal bit
     for bit; the two nucleus cuts keep the same tokens wherever the
     float64 oracle is sure (docstring, 1.: within float32 summation
-    error of ``top_p`` the boundary token may fall on either side)."""
+    error of ``top_p`` the boundary token may fall on either side).
+    bfloat16: the scaled logits rounded to bf16 and handed to the search
+    as bf16, to the sort as their float32 copy."""
     x = _rows(kind, rows, vocab, seed=7) / np.float32(0.7)
+    if dtype == "bfloat16":
+        x = np.asarray(_as(x, dtype).astype(jnp.float32))
     for k in (0, 500):
         kept = {}
         for form in FORMS:
-            kept[form], kth, _ = _kept(x, k, 0.9, form)
+            kept[form], kth, _ = _kept(x, k, 0.9, form, dtype)
             kept[form + "_kth"] = kth
         if k:
             np.testing.assert_array_equal(kept["search_kth"],
@@ -330,8 +397,9 @@ def test_full_tier_row_independent_of_chunk_mates(mate):
         assert base[0] == mixed[0], (step, base, mixed)
 
 
+@pytest.mark.parametrize("dtype", list(DTYPES))
 @pytest.mark.parametrize("form", list(FORMS))
-def test_tie_across_the_kth_place_small(form):
+def test_tie_across_the_kth_place_small(form, dtype):
     """Docstring, 2.: a run of equal logits across the k-th place is in
     the top-k set whole, and the nucleus is normalised over that set.
     k = 3 over [4, 3, 2, 2, 2, 1, 0]: the set is the first five tokens;
@@ -339,21 +407,162 @@ def test_tie_across_the_kth_place_small(form):
     (over three sorted positions, as before PR 28, it would be 0.91, and
     only 4 and 3 would)."""
     x = np.array([[4, 3, 2, 2, 2, 1, 0]], np.float32)
-    kept, kth, thresh = _kept(x, 3, 0.85, form)
+    kept, kth, thresh = _kept(x, 3, 0.85, form, dtype)
     assert kth[0] == 2.0 and thresh[0] == 2.0
     np.testing.assert_array_equal(np.flatnonzero(kept[0]), [0, 1, 2, 3, 4])
-    kept, _, thresh = _kept(x, 3, 0.7, form)  # 54.6 / 96.8 = 0.56 < 0.7
+    kept, _, thresh = _kept(x, 3, 0.7, form, dtype)  # 54.6 / 96.8 = 0.56
     assert thresh[0] == 3.0
     np.testing.assert_array_equal(np.flatnonzero(kept[0]), [0, 1])
 
 
-def test_tie_across_the_kth_place_is_drawn_from():
+@pytest.mark.parametrize("dtype", list(DTYPES))
+def test_tie_across_the_kth_place_is_drawn_from(dtype):
     """The same through sample_batch (PREFIX_K < k < V): with k = 130
     over 129 distinct logits then a run of five equal ones, draws land
     on the run's every token and nowhere below it."""
     v = 400
     logits = np.full((1, v), -5.0, np.float32)
-    logits[0, :129] = np.linspace(1.0, 0.5, 129)
+    logits[0, :129] = np.linspace(1.0, 0.5, 129)   # 2**-8 apart: bf16 too
     logits[0, 129:134] = 0.4
-    out = _draw_many(logits, seed=2, steps=3000, temp=1.0, tk=130, tp=1.0)
+    out = _draw_many(logits, seed=2, steps=3000, temp=1.0, tk=130, tp=1.0,
+                     dtype=dtype)
     assert out.max() == 133 and set(range(129, 134)) <= set(out.tolist())
+
+
+@pytest.mark.parametrize("temperature", ["none", "a_row"])
+@pytest.mark.parametrize("kind", ["flat", "bf16_ties", "edges"])
+@pytest.mark.parametrize("rows,vocab", CELL_SIZES, ids=CELL_IDS)
+def test_sixteen_bit_search_equals_the_32_bit_search_bit_for_bit(
+        rows, vocab, kind, temperature):
+    """For logits a bf16 head gives, the 16-bit search's ``(kth,
+    thresh)`` are bit for bit the 32-bit search's on the float32 copy;
+    under a temperature a row they are values of the logits that pass
+    through the row's division to exactly the cuts the 32-bit search
+    finds in the float32 ``scaled``. Top-k off, on and mixed in one
+    batch, ``top_p`` from 0 to 1; the kept sets are the same set, and so
+    are the sampled tokens."""
+    x = _rows(kind, rows, vocab, seed=5, dtype="bfloat16")
+    rng = np.random.default_rng(vocab)
+    temps = (jnp.ones((rows,), jnp.float32) if temperature == "none" else
+             jnp.asarray(rng.uniform(0.3, 1.5, rows), jnp.float32))
+    tps = jnp.asarray(rng.choice([0.0, 0.05, 0.5, 0.9, 0.99, 1.0], rows),
+                      jnp.float32)
+    bits = lambda a: np.asarray(a).view(np.uint32)
+    scaled = jnp.asarray(x) / temps[:, None]
+    for ks in ([vocab] * rows, [500] * rows,
+               list(rng.choice([1, 129, 500, vocab], rows))):
+        ks = jnp.asarray(ks, jnp.int32)
+        kth16, thresh16 = FORMS["search"](
+            _as(x, "bfloat16"), ks, tps,
+            None if temperature == "none" else scaled)
+        kth32, thresh32 = FORMS["search"](scaled, ks, tps)
+        assert kth16.dtype == thresh16.dtype == jnp.bfloat16
+        for c16, c32 in ((kth16, kth32), (thresh16, thresh32)):
+            np.testing.assert_array_equal(
+                bits(c16.astype(jnp.float32) / temps), bits(c32))
+        cut16 = np.asarray(jnp.maximum(kth16, thresh16).astype(jnp.float32))
+        cut32 = np.asarray(jnp.maximum(kth32, thresh32))
+        np.testing.assert_array_equal(x >= cut16[:, None],
+                                      np.asarray(scaled) >= cut32[:, None])
+    i = jnp.arange(rows, dtype=jnp.int32)
+    rest = (i, i + 3, temps, jnp.where(ks == vocab, 0, ks), tps,
+            jnp.ones((rows,), bool))
+    np.testing.assert_array_equal(_jit_sample(_as(x, "bfloat16"), *rest),
+                                  _jit_sample(jnp.asarray(x), *rest))
+
+
+@pytest.mark.parametrize("kind", ROW_KINDS)
+def test_float16_logits_take_the_same_16_bit_search(kind):
+    """A float16 head's logits go through the same key functions (the
+    sign bit flipped or every bit, 16 of them; the least key 0x03FF):
+    cuts and tokens are the float32 copy's, bit for bit."""
+    x = np.asarray(jnp.asarray(_rows(kind, 8, V, seed=9), jnp.float16)
+                   .astype(jnp.float32))
+    x16 = jnp.asarray(x, jnp.float16)
+    assert int(sampling._key_neg_inf(jnp.float16)) == 0x03FF
+    assert int(sampling._key_neg_inf(jnp.bfloat16)) == 0x007F
+    assert int(sampling._key_neg_inf(jnp.float32)) == 0x007FFFFF
+    np.testing.assert_array_equal(
+        sampling._keys_to_float(sampling._float_keys(x16), jnp.float16),
+        jnp.where(x16 == 0, 0, x16))
+    tps = jnp.asarray([0.0, 0.1, 0.5, 0.9, 0.9, 0.99, 1.0, 0.9], jnp.float32)
+    for ks in ([V] * 8, [1, 129, 500, V] * 2):
+        ks = jnp.asarray(ks, jnp.int32)
+        for c16, c32 in zip(FORMS["search"](x16, ks, tps),
+                            FORMS["search"](jnp.asarray(x), ks, tps)):
+            assert c16.dtype == jnp.float16
+            np.testing.assert_array_equal(
+                np.asarray(c16.astype(jnp.float32)).view(np.uint32),
+                np.asarray(c32).view(np.uint32))
+    i = jnp.arange(8, dtype=jnp.int32)
+    rest = (i, i, jnp.full((8,), 0.7, jnp.float32),
+            jnp.where(ks == V, 0, ks), tps, jnp.ones((8,), bool))
+    np.testing.assert_array_equal(_jit_sample(x16, *rest),
+                                  _jit_sample(jnp.asarray(x), *rest))
+
+
+# ---- the batcher's programs hand the sampler a bf16 head's logits --------
+
+def _parent_sample_batch(form):
+    """sample_batch as the batcher's programs had it before the 16-bit
+    search: the logits cast to float32 at the door, then the 32-bit
+    search (``search32``) or the descending sort (``sort``)."""
+    def parent(logits, seeds, steps, temps, top_ks, top_ps, do_sample):
+        logits = logits.astype(jnp.float32)
+        if form == "search32":
+            return sample_batch(logits, seeds, steps, temps, top_ks,
+                                top_ps, do_sample)
+        drawn = _sorted_full_draw(logits, seeds, steps, temps, top_ks,
+                                  top_ps)
+        return jnp.where(do_sample, drawn,
+                         jnp.argmax(logits, axis=-1)).astype(jnp.int32)
+    return parent
+
+
+@pytest.mark.parametrize("form", ["search32", "sort"])
+def test_batcher_bf16_tokens_are_the_parent_forms(form, monkeypatch):
+    """A tiny bf16 model behind the batcher at the cells' sampling
+    (temperature 0.7, top-k off, top-p 0.9; chunks of up to 8 passes):
+    the admit program's first token and every chunk's tokens, drawn with
+    the head's bf16 logits handed to the sampler as they are, are the
+    tokens of the same seeds with the logits cast to float32 first, by
+    the 32-bit search and by the sort."""
+    from distributed_llm_inferencing_tpu.models.params import init_params
+    from distributed_llm_inferencing_tpu.models.registry import get_config
+    from distributed_llm_inferencing_tpu.runtime import batcher
+
+    cfg = get_config("tiny-llama").replace(dtype="bfloat16",
+                                           attn_backend="xla")
+    params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.bfloat16)
+    seen = []
+
+    def serve():
+        b = batcher.ContinuousBatcher(cfg, params, num_blocks=64,
+                                      block_size=8, slots=4, max_seq=128,
+                                      decode_chunk_cap=8)
+        rng = np.random.default_rng(4)
+        reqs = [b.submit(rng.integers(0, cfg.vocab_size, 5 + 3 * i).tolist(),
+                         max_new_tokens=28, seed=40 + i,
+                         sampling=SamplingParams(temperature=0.7, top_k=0,
+                                                 top_p=0.9))
+                for i in range(3)]
+        for _ in range(200):
+            b.step()
+            if all(r.done.is_set() for r in reqs):
+                break
+        assert 8 in {key[0] for key in b._decode_fns}
+        return [r.wait() for r in reqs]
+
+    def spy(logits, *rest):
+        seen.append(logits.dtype)
+        return sample_batch(logits, *rest)
+
+    monkeypatch.setattr(sampling, "sample_batch", spy)
+    monkeypatch.setattr(batcher, "sample_batch", spy)
+    got = serve()
+    assert seen and set(seen) == {jnp.dtype(jnp.bfloat16)}
+    assert all(len(t) == 28 for t in got)
+    parent = _parent_sample_batch(form)
+    monkeypatch.setattr(sampling, "sample_batch", parent)
+    monkeypatch.setattr(batcher, "sample_batch", parent)
+    assert serve() == got
