@@ -5,16 +5,42 @@ host CPU devices exactly as SURVEY.md §4 prescribes. Both variables are
 set before jax is imported, which is all JAX needs.
 """
 
+import functools
 import os
+import signal
 import sys
+import threading
 
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
-    os.environ["XLA_FLAGS"] = (
-        flags + " --xla_force_host_platform_device_count=8").strip()
+    flags += " --xla_force_host_platform_device_count=8"
+# What the suite spends is XLA:CPU compiling programs it runs once or
+# twice at toy widths: two thirds of a cold case's CPU seconds were
+# LLVM's (49.9 s -> 30.4 for forty cases of tests/test_decode_gather.py,
+# a whole run 985 s -> 771, with a compile cache on both sides; CHANGES.md,
+# PR 48). Level 0 is LLVM's, not HLO's: the programs' operations, fusions
+# and numerics are what they were (every tolerance and every bit-for-bit
+# comparison of the suite holds), the described v5e compiles of
+# tests/test_tpu_compile.py are libtpu's and unmoved; what runs long on
+# the CPU (the sampler's cell-size cases) runs longer. A level the caller
+# sets stays.
+if "xla_backend_optimization_level" not in flags:
+    flags += " --xla_backend_optimization_level=0"
+os.environ["XLA_FLAGS"] = flags.strip()
 os.environ["JAX_PLATFORMS"] = "cpu"
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+# ---- no persistent compile cache of the suite's own ---------------------
+# Measured for PR 48 (CHANGES.md): the cache on for every process of a run,
+# in one directory its workers shared, took a whole run from 787 s to
+# 610-771, and a worker died in five of its seven runs, the last four
+# inside the cache's own ``executable.serialize()`` (put) or deserialize
+# (get) on XLA:CPU; without it no worker died at this level of LLVM. So a
+# process has the cache only from the case that reaches
+# utils/platform.pin_platform on, as before, or where the caller sets
+# ``JAX_COMPILATION_CACHE_DIR``; the processes of a jax.distributed slice
+# must not inherit that (tests/test_multihost.py::_slice_env: they hang).
 
 
 def tiny_gpt_oss_model(seed=60):
@@ -183,6 +209,61 @@ def served_as_under_auto(build, asked, env, monkeypatch):
     return b
 
 
+@functools.lru_cache(maxsize=None)
+def jitted(fn, static_argnums=(1,)):
+    """``fn(params, cfg, ...)`` of models/transformer.py behind one
+    ``jax.jit`` a process, the configuration static as the serving
+    programs have it. Called eagerly, such a function traces, lowers and
+    compiles its ``lax.scan`` over the layers anew at every call (0.8 s a
+    decode step at toy widths, compile cache or no): a loop of decode
+    steps was a file's minutes. Not for a case that patches what the
+    trace reads (``monkeypatch.setattr(transformer, ...)``): the first
+    trace is the one every later call gets."""
+    import jax
+    return jax.jit(fn, static_argnums=static_argnums)
+
+
+_PROGRAM_TABLES = {}
+
+
+def share_programs(b):
+    """Hand batcher ``b`` the admit and decode program tables of the
+    process's first batcher that traces the same programs, so that a
+    file's cases trace each once between them: the jitted closures of
+    ``_admit_jit`` / ``_decode_jit`` / ``_spec_jit`` close over the
+    pinned configuration, the block size, the reserved block and the
+    mesh, take the weights as an argument, and are keyed by every shape
+    (tail, prefix, wave; passes, slots, block-table columns). Returns
+    ``b``. Not for a case that patches what a trace reads
+    (``transformer._pool_ladder``, ``batcher.sample_batch``) or that
+    reads whether a call compiled (tests/test_timeline.py,
+    the adaptive controllers)."""
+    key = (b.cfg, b.block_size, b._dummy, b.mesh_spec)
+    b._prefill_fns, b._decode_fns = _PROGRAM_TABLES.setdefault(
+        key, (b._prefill_fns, b._decode_fns))
+    return b
+
+
+def shared_batcher(*args, **kw):
+    """``ContinuousBatcher(*args, **kw)`` over the process's shared
+    program tables (share_programs)."""
+    from distributed_llm_inferencing_tpu.runtime.batcher import (
+        ContinuousBatcher)
+    return share_programs(ContinuousBatcher(*args, **kw))
+
+
+def stop_worker(agent):
+    """A WorkerAgent's teardown: its HTTP service shut, then every loaded
+    model's batcher stopped and its loop joined. A scheduler thread still
+    dispatching XLA work while the next case compiles, or while the
+    interpreter is torn down, is a segmentation fault waiting for a
+    loaded box."""
+    agent.service.shutdown()
+    for lm in list(agent.models.values()):
+        if lm.batcher is not None:
+            lm.batcher.stop()
+
+
 # ---- lock-order watchdog gate (utils/locks.py) ------------------------
 # When the suite runs with DLI_LOCK_CHECK=1 (scripts/check.sh arms it
 # for the chaos suite), every runtime lock is instrumented and a
@@ -191,6 +272,50 @@ def served_as_under_auto(build, asked, env, monkeypatch):
 # watchdog behind themselves, so any report left at session end is real.
 
 import pytest  # noqa: E402
+
+
+# ---- a limit of its own for every test ---------------------------------
+# A hang or a wait costs one case TEST_LIMIT_S, not the run its clock.
+TEST_LIMIT_S = 120.0
+
+
+@pytest.fixture(autouse=True)
+def _limit_each_test(request):
+    if (not hasattr(signal, "setitimer")
+            or threading.current_thread() is not threading.main_thread()):
+        yield
+        return
+
+    def expired(signum, frame):
+        pytest.fail(f"{request.node.nodeid} passed its limit of "
+                    f"{TEST_LIMIT_S:g} s (tests/conftest.py)",
+                    pytrace=False)
+
+    handler_was = signal.signal(signal.SIGALRM, expired)
+    signal.setitimer(signal.ITIMER_REAL, TEST_LIMIT_S)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, handler_was)
+
+
+def pytest_configure(config):
+    config.addinivalue_line(
+        "markers", "slow: left out of tier-1 (-m 'not slow'); "
+        "scripts/check.sh runs them in steps of their own")
+
+
+def pytest_sessionfinish(session):
+    """Name the threads that outlived their tests: one still inside
+    XLA:CPU while the runtime is torn down is a segmentation fault at
+    exit, and the next casualty should be tied to a name."""
+    alive = sorted(t.name for t in threading.enumerate()
+                   if t is not threading.main_thread() and t.is_alive())
+    if alive:
+        who = os.environ.get("PYTEST_XDIST_WORKER", "main")
+        print(f"\n[{who}] threads alive at session end: {alive}",
+              file=sys.stderr)
 
 
 @pytest.fixture(scope="session", autouse=True)

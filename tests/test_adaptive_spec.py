@@ -25,7 +25,6 @@ from distributed_llm_inferencing_tpu.runtime.engine import InferenceEngine
 
 CFG = get_config("tiny-llama").replace(dtype="float32", attn_backend="xla")
 PARAMS = init_params(CFG, jax.random.PRNGKey(0), dtype=jnp.float32)
-RNG = np.random.default_rng(3)
 
 
 # ---- controller policy (pure, no jax) ---------------------------------
@@ -183,7 +182,8 @@ def test_repetitive_workload_keeps_drafting():
     a repeating loop a few tokens in — prompt-lookup's best case. The
     controller must ride out the (genuinely draft-hostile) first tokens
     without abandoning drafting (min_evidence), then keep it on."""
-    base = RNG.integers(0, CFG.vocab_size, 4).tolist()
+    rng = np.random.default_rng(3)
+    base = rng.integers(0, CFG.vocab_size, 4).tolist()
     prompts = [(base * 8)[:24] for _ in range(4)]
     b = _spec_batcher()
     reqs = [b.submit(p, max_new_tokens=64, sampling=SamplingParams.greedy())
@@ -208,8 +208,9 @@ def test_adversarial_workload_converges_to_plain():
     plain dispatches; wall-clock on a shared CI box is noise). Uncovered
     sampled rows draw the same token the plain chunk would, so output
     stays bit-identical to the plain batcher under matching seeds."""
+    rng = np.random.default_rng(3)
     sp = SamplingParams(temperature=1.0, top_k=0, top_p=1.0)
-    prompts = [RNG.integers(0, CFG.vocab_size, 24).tolist()
+    prompts = [rng.integers(0, CFG.vocab_size, 24).tolist()
                for _ in range(4)]
     b = _spec_batcher()
     reqs = [b.submit(p, max_new_tokens=48, sampling=sp, seed=100 + i)
@@ -234,6 +235,7 @@ def test_lockstep_plain_chunks_keep_follower_history_in_sync():
     per-chunk appends), or a row admitted while the controller sits in
     plain mode leaves a permanent hole in the follower's drafting
     history that the next spec probe's delta skips forever."""
+    rng = np.random.default_rng(3)
     import json
     mk = lambda: ContinuousBatcher(  # noqa: E731
         CFG, PARAMS, num_blocks=64, block_size=8, slots=2, max_seq=96,
@@ -248,8 +250,8 @@ def test_lockstep_plain_chunks_keep_follower_history_in_sync():
         return run()
 
     leader.program_hook = hook
-    prompts = [(RNG.integers(0, CFG.vocab_size, 3).tolist() * 7)[:20],
-               RNG.integers(0, CFG.vocab_size, 9).tolist()]
+    prompts = [(rng.integers(0, CFG.vocab_size, 3).tolist() * 7)[:20],
+               rng.integers(0, CFG.vocab_size, 9).tolist()]
     reqs = [leader.submit(p, max_new_tokens=10,
                           sampling=SamplingParams.greedy(), seed=31 + i)
             for i, p in enumerate(prompts)]
@@ -278,13 +280,14 @@ def test_engine_adaptive_spec_output_invariant(repetitive, monkeypatch):
     """The single-stream engine loop consults the same controller: output
     must equal plain greedy decode whether chunks ran drafted or plain
     (the adversarial arm exercises the mid-generation fallback path)."""
+    rng = np.random.default_rng(3)
     monkeypatch.setenv("DLI_SPEC_ADAPTIVE", "1")
     eng = InferenceEngine(CFG, PARAMS, max_seq=160)
     if repetitive:
-        base = RNG.integers(0, CFG.vocab_size, 4).tolist()
+        base = rng.integers(0, CFG.vocab_size, 4).tolist()
         prompt = (base * 8)[:24]
     else:
-        prompt = RNG.integers(0, CFG.vocab_size, 24).tolist()
+        prompt = rng.integers(0, CFG.vocab_size, 24).tolist()
     g = SamplingParams.greedy()
     plain = eng.generate([prompt], max_new_tokens=40, sampling=g).tokens[0]
     spec = eng.generate([prompt], max_new_tokens=40, sampling=g,

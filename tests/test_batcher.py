@@ -4,6 +4,8 @@ Oracle for token content is the dense-cache engine in greedy mode (dense ≡
 paged is pinned separately in tests/test_paged.py).
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -14,10 +16,11 @@ from distributed_llm_inferencing_tpu.models.registry import get_config
 from distributed_llm_inferencing_tpu.ops.sampling import SamplingParams
 from distributed_llm_inferencing_tpu.runtime.batcher import ContinuousBatcher
 from distributed_llm_inferencing_tpu.runtime.engine import InferenceEngine
+# one trace a program for all the file's cases
+from conftest import shared_batcher as Batcher
 
 CFG = get_config("tiny-llama").replace(dtype="float32", attn_backend="xla")
 PARAMS = init_params(CFG, jax.random.PRNGKey(0), dtype=jnp.float32)
-RNG = np.random.default_rng(0)
 
 
 def run_until_done(b, reqs, max_steps=400):
@@ -30,16 +33,22 @@ def run_until_done(b, reqs, max_steps=400):
         f"{[(r.done.is_set(), r.error, len(r.tokens)) for r in reqs]}")
 
 
+@functools.lru_cache(maxsize=None)
+def _engine():
+    return InferenceEngine(CFG, PARAMS, max_seq=128)
+
+
 def dense_greedy(prompt, n):
-    eng = InferenceEngine(CFG, PARAMS, max_seq=128)
+    eng = _engine()
     return eng.generate([prompt], max_new_tokens=n,
                         sampling=SamplingParams.greedy()).tokens[0]
 
 
 def test_single_request_matches_engine():
-    b = ContinuousBatcher(CFG, PARAMS, num_blocks=64, block_size=8,
-                          slots=4, max_seq=128)
-    prompt = RNG.integers(0, CFG.vocab_size, 13).tolist()
+    rng = np.random.default_rng(0)
+    b = Batcher(CFG, PARAMS, num_blocks=64, block_size=8,
+                slots=4, max_seq=128)
+    prompt = rng.integers(0, CFG.vocab_size, 13).tolist()
     r = b.submit(prompt, max_new_tokens=20, sampling=SamplingParams.greedy())
     run_until_done(b, [r])
     assert r.wait() == dense_greedy(prompt, 20)
@@ -48,13 +57,14 @@ def test_single_request_matches_engine():
 
 def test_concurrent_mixed_sampling():
     """Slots advance together; per-slot sampling params are independent."""
-    b = ContinuousBatcher(CFG, PARAMS, num_blocks=128, block_size=8,
-                          slots=4, max_seq=128)
-    greedy_prompt = RNG.integers(0, CFG.vocab_size, 9).tolist()
+    rng = np.random.default_rng(0)
+    b = Batcher(CFG, PARAMS, num_blocks=128, block_size=8,
+                slots=4, max_seq=128)
+    greedy_prompt = rng.integers(0, CFG.vocab_size, 9).tolist()
     reqs = [b.submit(greedy_prompt, max_new_tokens=15,
                      sampling=SamplingParams.greedy())]
     for i in range(5):   # more requests than slots -> queueing
-        p = RNG.integers(0, CFG.vocab_size, 5 + i).tolist()
+        p = rng.integers(0, CFG.vocab_size, 5 + i).tolist()
         reqs.append(b.submit(p, max_new_tokens=10 + i,
                              sampling=SamplingParams(temperature=0.7)))
     run_until_done(b, reqs)
@@ -68,15 +78,16 @@ def test_concurrent_mixed_sampling():
 
 
 def test_prefix_cache_reuse_across_requests():
-    b = ContinuousBatcher(CFG, PARAMS, num_blocks=64, block_size=8,
-                          slots=2, max_seq=128)
-    sys_prompt = RNG.integers(0, CFG.vocab_size, 24).tolist()  # 3 full blocks
-    p1 = sys_prompt + RNG.integers(0, CFG.vocab_size, 4).tolist()
+    rng = np.random.default_rng(0)
+    b = Batcher(CFG, PARAMS, num_blocks=64, block_size=8,
+                slots=2, max_seq=128)
+    sys_prompt = rng.integers(0, CFG.vocab_size, 24).tolist()  # 3 full blocks
+    p1 = sys_prompt + rng.integers(0, CFG.vocab_size, 4).tolist()
     r1 = b.submit(p1, max_new_tokens=5, sampling=SamplingParams.greedy())
     run_until_done(b, [r1])
     misses_before = b.pool.stats()["prefix_misses"]
 
-    p2 = sys_prompt + RNG.integers(0, CFG.vocab_size, 6).tolist()
+    p2 = sys_prompt + rng.integers(0, CFG.vocab_size, 6).tolist()
     r2 = b.submit(p2, max_new_tokens=5, sampling=SamplingParams.greedy())
     run_until_done(b, [r2])
     st = b.pool.stats()
@@ -86,9 +97,10 @@ def test_prefix_cache_reuse_across_requests():
 
 
 def test_identical_prompt_full_hit():
-    b = ContinuousBatcher(CFG, PARAMS, num_blocks=64, block_size=8,
-                          slots=2, max_seq=128)
-    prompt = RNG.integers(0, CFG.vocab_size, 17).tolist()
+    rng = np.random.default_rng(0)
+    b = Batcher(CFG, PARAMS, num_blocks=64, block_size=8,
+                slots=2, max_seq=128)
+    prompt = rng.integers(0, CFG.vocab_size, 17).tolist()
     r1 = b.submit(prompt, max_new_tokens=6, sampling=SamplingParams.greedy())
     run_until_done(b, [r1])
     r2 = b.submit(prompt, max_new_tokens=6, sampling=SamplingParams.greedy())
@@ -99,9 +111,10 @@ def test_identical_prompt_full_hit():
 def test_preemption_under_memory_pressure():
     """A pool too small for all requests still completes every request
     correctly via preempt-and-resume."""
-    b = ContinuousBatcher(CFG, PARAMS, num_blocks=10, block_size=8,
-                          slots=3, max_seq=80)
-    prompts = [RNG.integers(0, CFG.vocab_size, 12).tolist() for _ in range(3)]
+    rng = np.random.default_rng(0)
+    b = Batcher(CFG, PARAMS, num_blocks=10, block_size=8,
+                slots=3, max_seq=80)
+    prompts = [rng.integers(0, CFG.vocab_size, 12).tolist() for _ in range(3)]
     reqs = [b.submit(p, max_new_tokens=12, sampling=SamplingParams.greedy())
             for p in prompts]
     run_until_done(b, reqs)
@@ -111,9 +124,10 @@ def test_preemption_under_memory_pressure():
 
 
 def test_pool_exhausted_is_reported():
-    b = ContinuousBatcher(CFG, PARAMS, num_blocks=2, block_size=8,
-                          slots=2, max_seq=64)
-    r = b.submit(RNG.integers(0, CFG.vocab_size, 30).tolist(),
+    rng = np.random.default_rng(0)
+    b = Batcher(CFG, PARAMS, num_blocks=2, block_size=8,
+                slots=2, max_seq=64)
+    r = b.submit(rng.integers(0, CFG.vocab_size, 30).tolist(),
                  max_new_tokens=4)
     for _ in range(20):
         b.step()
@@ -125,9 +139,10 @@ def test_pool_exhausted_is_reported():
 
 
 def test_streaming_and_eos():
-    b = ContinuousBatcher(CFG, PARAMS, num_blocks=64, block_size=8,
-                          slots=2, max_seq=128)
-    prompt = RNG.integers(0, CFG.vocab_size, 11).tolist()
+    rng = np.random.default_rng(0)
+    b = Batcher(CFG, PARAMS, num_blocks=64, block_size=8,
+                slots=2, max_seq=128)
+    prompt = rng.integers(0, CFG.vocab_size, 11).tolist()
     full = dense_greedy(prompt, 10)
     # use the 4th generated token as "eos": generation must stop before it
     eos = full[3]
@@ -146,17 +161,18 @@ def test_streaming_and_eos():
 def test_seeded_sampling_reproducible_across_interleavings():
     """A request's sampled output depends only on (params, prompt, seed) —
     not on what else shares its decode steps."""
-    prompt = RNG.integers(0, CFG.vocab_size, 10).tolist()
+    rng = np.random.default_rng(0)
+    prompt = rng.integers(0, CFG.vocab_size, 10).tolist()
     sp = SamplingParams(temperature=0.9, top_k=40, top_p=0.9)
 
-    b1 = ContinuousBatcher(CFG, PARAMS, num_blocks=64, block_size=8,
-                           slots=4, max_seq=128)
+    b1 = Batcher(CFG, PARAMS, num_blocks=64, block_size=8,
+                 slots=4, max_seq=128)
     alone = b1.submit(prompt, max_new_tokens=12, sampling=sp, seed=1234)
     run_until_done(b1, [alone])
 
-    b2 = ContinuousBatcher(CFG, PARAMS, num_blocks=64, block_size=8,
-                           slots=4, max_seq=128)
-    noise = [b2.submit(RNG.integers(0, CFG.vocab_size, 6 + i).tolist(),
+    b2 = Batcher(CFG, PARAMS, num_blocks=64, block_size=8,
+                 slots=4, max_seq=128)
+    noise = [b2.submit(rng.integers(0, CFG.vocab_size, 6 + i).tolist(),
                        max_new_tokens=20, sampling=sp, seed=i)
              for i in range(3)]
     crowded = b2.submit(prompt, max_new_tokens=12, sampling=sp, seed=1234)
@@ -165,9 +181,10 @@ def test_seeded_sampling_reproducible_across_interleavings():
 
 
 def test_cancel_frees_slot():
-    b = ContinuousBatcher(CFG, PARAMS, num_blocks=64, block_size=8,
-                          slots=2, max_seq=128)
-    r = b.submit(RNG.integers(0, CFG.vocab_size, 8).tolist(),
+    rng = np.random.default_rng(0)
+    b = Batcher(CFG, PARAMS, num_blocks=64, block_size=8,
+                slots=2, max_seq=128)
+    r = b.submit(rng.integers(0, CFG.vocab_size, 8).tolist(),
                  max_new_tokens=100, sampling=SamplingParams.greedy())
     b.step()
     assert not r.done.is_set()
@@ -180,11 +197,12 @@ def test_cancel_frees_slot():
 
 
 def test_stop_drains_inflight_requests():
-    b = ContinuousBatcher(CFG, PARAMS, num_blocks=64, block_size=8,
-                          slots=1, max_seq=128)
-    active = b.submit(RNG.integers(0, CFG.vocab_size, 8).tolist(),
+    rng = np.random.default_rng(0)
+    b = Batcher(CFG, PARAMS, num_blocks=64, block_size=8,
+                slots=1, max_seq=128)
+    active = b.submit(rng.integers(0, CFG.vocab_size, 8).tolist(),
                       max_new_tokens=100)
-    queued = b.submit(RNG.integers(0, CFG.vocab_size, 8).tolist(),
+    queued = b.submit(rng.integers(0, CFG.vocab_size, 8).tolist(),
                       max_new_tokens=100)
     b.step()
     b.stop()   # no thread started; must still fail both requests
@@ -194,11 +212,12 @@ def test_stop_drains_inflight_requests():
 
 
 def test_background_thread_serving():
-    b = ContinuousBatcher(CFG, PARAMS, num_blocks=64, block_size=8,
-                          slots=4, max_seq=128)
+    rng = np.random.default_rng(0)
+    b = Batcher(CFG, PARAMS, num_blocks=64, block_size=8,
+                slots=4, max_seq=128)
     b.start()
     try:
-        prompt = RNG.integers(0, CFG.vocab_size, 8).tolist()
+        prompt = rng.integers(0, CFG.vocab_size, 8).tolist()
         reqs = [b.submit(prompt, max_new_tokens=8,
                          sampling=SamplingParams.greedy())
                 for _ in range(6)]
@@ -227,11 +246,12 @@ def test_chunked_decode_amortizes_dispatches():
     """K-token on-device chunks: a 40-token generation costs a handful of
     dispatched programs, not one per token (the round-2 batcher's 6.6x
     regression vs the engine)."""
-    b = ContinuousBatcher(CFG, PARAMS, num_blocks=128, block_size=8,
-                          slots=4, max_seq=128)
+    rng = np.random.default_rng(0)
+    b = Batcher(CFG, PARAMS, num_blocks=128, block_size=8,
+                slots=4, max_seq=128)
     counter = OpCounter()
     b.program_hook = counter
-    prompts = [RNG.integers(0, CFG.vocab_size, 12).tolist() for _ in range(4)]
+    prompts = [rng.integers(0, CFG.vocab_size, 12).tolist() for _ in range(4)]
     reqs = [b.submit(p, max_new_tokens=40, sampling=SamplingParams.greedy())
             for p in prompts]
     run_until_done(b, reqs)
@@ -248,11 +268,12 @@ def test_chunked_decode_amortizes_dispatches():
 def test_wave_admission_one_dispatch_for_burst():
     """A burst of same-bucket requests admits in one batched program with
     first-token sampling fused in (no separate sample dispatch)."""
-    b = ContinuousBatcher(CFG, PARAMS, num_blocks=128, block_size=8,
-                          slots=8, max_seq=128)
+    rng = np.random.default_rng(0)
+    b = Batcher(CFG, PARAMS, num_blocks=128, block_size=8,
+                slots=8, max_seq=128)
     counter = OpCounter()
     b.program_hook = counter
-    prompts = [RNG.integers(0, CFG.vocab_size, 9).tolist() for _ in range(6)]
+    prompts = [rng.integers(0, CFG.vocab_size, 9).tolist() for _ in range(6)]
     reqs = [b.submit(p, max_new_tokens=1, sampling=SamplingParams.greedy())
             for p in prompts]
     run_until_done(b, reqs)
@@ -265,9 +286,10 @@ def test_wave_admission_one_dispatch_for_burst():
 def test_eos_mid_chunk_stops_on_device():
     """Per-slot eos masks inside the chunk: tokens after the eos step are
     never emitted even though the program ran past it."""
-    b = ContinuousBatcher(CFG, PARAMS, num_blocks=64, block_size=8,
-                          slots=2, max_seq=128)
-    prompt = RNG.integers(0, CFG.vocab_size, 11).tolist()
+    rng = np.random.default_rng(0)
+    b = Batcher(CFG, PARAMS, num_blocks=64, block_size=8,
+                slots=2, max_seq=128)
+    prompt = rng.integers(0, CFG.vocab_size, 11).tolist()
     full = dense_greedy(prompt, 30)
     eos = full[10]   # eos lands mid-chunk (after the 32-chunk starts)
     first = full.index(eos)
@@ -281,9 +303,10 @@ def test_eos_mid_chunk_stops_on_device():
 def test_mixed_budgets_mid_chunk():
     """Slots with different max_new_tokens share chunks; budget masks stop
     each at its own limit."""
-    b = ContinuousBatcher(CFG, PARAMS, num_blocks=128, block_size=8,
-                          slots=4, max_seq=128)
-    prompts = [RNG.integers(0, CFG.vocab_size, 7 + i).tolist()
+    rng = np.random.default_rng(0)
+    b = Batcher(CFG, PARAMS, num_blocks=128, block_size=8,
+                slots=4, max_seq=128)
+    prompts = [rng.integers(0, CFG.vocab_size, 7 + i).tolist()
                for i in range(4)]
     wants = [3, 17, 33, 50]
     reqs = [b.submit(p, max_new_tokens=w, sampling=SamplingParams.greedy())
@@ -303,11 +326,12 @@ from distributed_llm_inferencing_tpu.parallel.mesh import MeshSpec  # noqa: E402
 
 
 def test_tp_sharded_batcher_matches_dense_engine():
+    rng = np.random.default_rng(0)
     spec = MeshSpec(tp=2)
-    b = ContinuousBatcher(CFG, PARAMS, num_blocks=64, block_size=8,
-                          slots=4, max_seq=128, mesh_spec=spec)
+    b = Batcher(CFG, PARAMS, num_blocks=64, block_size=8,
+                slots=4, max_seq=128, mesh_spec=spec)
     assert b.stats()["mesh"]["tp"] == 2
-    prompt = RNG.integers(0, CFG.vocab_size, 13).tolist()
+    prompt = rng.integers(0, CFG.vocab_size, 13).tolist()
     r = b.submit(prompt, max_new_tokens=16, sampling=SamplingParams.greedy())
     run_until_done(b, [r])
     eng = InferenceEngine(CFG, PARAMS, mesh_spec=spec, max_seq=128)
@@ -317,11 +341,12 @@ def test_tp_sharded_batcher_matches_dense_engine():
 
 
 def test_tp_sharded_batcher_concurrent_and_prefix_reuse():
+    rng = np.random.default_rng(0)
     spec = MeshSpec(tp=4)
-    b = ContinuousBatcher(CFG, PARAMS, num_blocks=64, block_size=8,
-                          slots=4, max_seq=128, mesh_spec=spec)
-    sys_prompt = RNG.integers(0, CFG.vocab_size, 16).tolist()  # 2 full blocks
-    prompts = [sys_prompt + RNG.integers(0, CFG.vocab_size, 3 + i).tolist()
+    b = Batcher(CFG, PARAMS, num_blocks=64, block_size=8,
+                slots=4, max_seq=128, mesh_spec=spec)
+    sys_prompt = rng.integers(0, CFG.vocab_size, 16).tolist()  # 2 full blocks
+    prompts = [sys_prompt + rng.integers(0, CFG.vocab_size, 3 + i).tolist()
                for i in range(4)]
     reqs = [b.submit(p, max_new_tokens=8, sampling=SamplingParams.greedy())
             for p in prompts]
@@ -332,6 +357,7 @@ def test_tp_sharded_batcher_concurrent_and_prefix_reuse():
 
 
 def test_ep_sharded_batcher_moe():
+    rng = np.random.default_rng(0)
     from distributed_llm_inferencing_tpu.models.registry import get_config
     from distributed_llm_inferencing_tpu.models.params import init_params
     import jax
@@ -339,9 +365,9 @@ def test_ep_sharded_batcher_moe():
                                              attn_backend="xla")
     params = init_params(cfg, jax.random.PRNGKey(1), dtype=jnp.float32)
     spec = MeshSpec(ep=2, tp=2)
-    b = ContinuousBatcher(cfg, params, num_blocks=64, block_size=8,
-                          slots=2, max_seq=128, mesh_spec=spec)
-    prompt = RNG.integers(0, cfg.vocab_size, 11).tolist()
+    b = Batcher(cfg, params, num_blocks=64, block_size=8,
+                slots=2, max_seq=128, mesh_spec=spec)
+    prompt = rng.integers(0, cfg.vocab_size, 11).tolist()
     r = b.submit(prompt, max_new_tokens=8, sampling=SamplingParams.greedy())
     run_until_done(b, [r])
     eng = InferenceEngine(cfg, params, max_seq=128)
@@ -356,8 +382,8 @@ def test_batcher_rejects_non_tensor_axes():
     # mode (tests/test_paged_pipeline.py)
     for spec in (MeshSpec(dp=2), MeshSpec(sp=2)):
         with pytest.raises(ValueError, match="tp/ep"):
-            ContinuousBatcher(CFG, PARAMS, num_blocks=16, block_size=8,
-                              slots=2, max_seq=64, mesh_spec=spec)
+            Batcher(CFG, PARAMS, num_blocks=16, block_size=8,
+                    slots=2, max_seq=64, mesh_spec=spec)
 
 
 # ---------------- chunked prefill ----------------
@@ -372,8 +398,8 @@ def test_chunked_prefill_matches_monolithic():
     prompt = rng.integers(0, 256, 50).tolist()   # 7 blocks @ bs 8
 
     def run(chunk):
-        b = ContinuousBatcher(cfg, num_blocks=64, block_size=8, slots=2,
-                              max_seq=128, seed=0, prefill_chunk=chunk)
+        b = Batcher(cfg, num_blocks=64, block_size=8, slots=2,
+                    max_seq=128, seed=0, prefill_chunk=chunk)
         r = b.submit(prompt, max_new_tokens=8,
                      sampling=SamplingParams.greedy())
         for _ in range(60):
@@ -397,8 +423,8 @@ def test_chunked_prefill_decode_interleaves():
     cfg = get_config("tiny-llama").replace(dtype="float32",
                                            attn_backend="xla")
     rng = np.random.default_rng(1)
-    b = ContinuousBatcher(cfg, num_blocks=64, block_size=8, slots=2,
-                          max_seq=128, seed=0, prefill_chunk=1)
+    b = Batcher(cfg, num_blocks=64, block_size=8, slots=2,
+                max_seq=128, seed=0, prefill_chunk=1)
     short = b.submit([1, 2, 3], max_new_tokens=100,
                      sampling=SamplingParams.greedy())
     b.step()                      # admit short; it starts decoding
@@ -429,8 +455,8 @@ def test_chunked_prefill_cancel_mid_admission():
     cfg = get_config("tiny-llama").replace(dtype="float32",
                                            attn_backend="xla")
     rng = np.random.default_rng(2)
-    b = ContinuousBatcher(cfg, num_blocks=64, block_size=8, slots=2,
-                          max_seq=128, seed=0, prefill_chunk=1)
+    b = Batcher(cfg, num_blocks=64, block_size=8, slots=2,
+                max_seq=128, seed=0, prefill_chunk=1)
     free0 = b.pool.free_count()
     r = b.submit(rng.integers(0, 256, 40).tolist(), max_new_tokens=4,
                  sampling=SamplingParams.greedy())
@@ -454,8 +480,8 @@ def test_chunked_prefill_progresses_with_all_slots_busy():
     cfg = get_config("tiny-llama").replace(dtype="float32",
                                            attn_backend="xla")
     rng = np.random.default_rng(3)
-    b = ContinuousBatcher(cfg, num_blocks=64, block_size=8, slots=1,
-                          max_seq=128, seed=0, prefill_chunk=1)
+    b = Batcher(cfg, num_blocks=64, block_size=8, slots=1,
+                max_seq=128, seed=0, prefill_chunk=1)
     hog = b.submit([1, 2, 3], max_new_tokens=120,
                    sampling=SamplingParams.greedy())
     b.step()                      # the only slot is now decoding
@@ -485,8 +511,8 @@ def test_sample_full_passes_counts_the_full_tier(mate, full):
     sampling row of the chunk cannot take sample_batch's prefix tier
     (top_k off or beyond PREFIX_K), and stays put for a k = 50 fleet:
     its ratio to batcher_weight_passes is the full tier's share."""
-    b = ContinuousBatcher(CFG, PARAMS, num_blocks=64, block_size=8,
-                          slots=4, max_seq=128)
+    b = Batcher(CFG, PARAMS, num_blocks=64, block_size=8,
+                slots=4, max_seq=128)
     reqs = [b.submit([3, 4, 5], max_new_tokens=12,
                      sampling=SamplingParams(top_k=50)),
             b.submit([6, 7, 8, 9], max_new_tokens=12, sampling=mate)]
@@ -497,14 +523,16 @@ def test_sample_full_passes_counts_the_full_tier(mate, full):
         c["batcher_weight_passes"] if full else 0)
 
 
-def _rung_batcher():
+def _rung_batcher(make=Batcher):
     # 16 blocks of 8 a slot: rungs of 16, 32, 48, 64, 96, 128 positions
-    return ContinuousBatcher(CFG, PARAMS, num_blocks=64, block_size=8,
-                             slots=2, max_seq=128, decode_chunk_cap=8)
+    return make(CFG, PARAMS, num_blocks=64, block_size=8, slots=2,
+                max_seq=128, decode_chunk_cap=8)
 
 
-RUNG_PROMPTS = [RNG.integers(0, CFG.vocab_size, 25).tolist(),
-                RNG.integers(0, CFG.vocab_size, 6).tolist()]
+RUNG_PROMPTS = [np.random.default_rng(25).integers(
+                    0, CFG.vocab_size, 25).tolist(),
+                np.random.default_rng(6).integers(
+                    0, CFG.vocab_size, 6).tolist()]
 
 
 def _serve_across_a_rung(b):
@@ -531,7 +559,9 @@ def test_context_crossing_a_rung_streams_the_full_extents_tokens(
     are the ones warmed; the tokens are those of the ladder patched to
     the full extent alone, and the dense engine's."""
     from distributed_llm_inferencing_tpu.models import transformer
-    b = _rung_batcher()
+    # (program tables of their own: one is warmed and its keys counted,
+    # the other traces under a patched ladder)
+    b = _rung_batcher(ContinuousBatcher)
     assert b.warm_decode_programs() == len(b.decode_chunks)
     keys = set(b._decode_fns)
     got = _serve_across_a_rung(b)
@@ -542,7 +572,7 @@ def test_context_crossing_a_rung_streams_the_full_extents_tokens(
 
     monkeypatch.setattr(transformer, "_pool_ladder",
                         lambda mb, scanned=True: (mb,))
-    full = _rung_batcher()
+    full = _rung_batcher(ContinuousBatcher)
     assert _serve_across_a_rung(full) == got
     c = full.metrics.snapshot()["counters"]
     assert c["batcher_decode_pool_positions"] \

@@ -35,6 +35,7 @@ from distributed_llm_inferencing_tpu.runtime.master import (
     FAILURE_STRIKES, MAX_ATTEMPTS, Master)
 from distributed_llm_inferencing_tpu.runtime.worker import WorkerAgent
 from distributed_llm_inferencing_tpu.utils.faults import FaultInjector
+from conftest import stop_worker
 
 
 def _url(port, path):
@@ -64,7 +65,7 @@ def worker():
     _load_tiny(port)
     _warm(port)
     yield agent, port
-    agent.service.shutdown()
+    stop_worker(agent)
 
 
 @pytest.fixture()
@@ -345,7 +346,7 @@ def test_worker_crash_fails_over_to_peer(worker, master):
         assert not n["is_active"]
         assert _node(mport, bid)["is_active"]
     finally:
-        agent_a.service.shutdown()
+        stop_worker(agent_a)
 
 
 # ---- graceful drain ---------------------------------------------------
@@ -406,7 +407,7 @@ def test_drain_finishes_inflight_and_rejects_new():
         assert _node(mport, nid)["strikes"] == 0
     finally:
         m.stop()
-        agent.service.shutdown()
+        stop_worker(agent)
 
 
 # ---- relayed worker responses (satellite: structured 502) ------------

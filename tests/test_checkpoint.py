@@ -17,6 +17,7 @@ import requests
 from distributed_llm_inferencing_tpu.models import checkpoint
 from distributed_llm_inferencing_tpu.models.params import init_params
 from distributed_llm_inferencing_tpu.models.registry import get_config
+from conftest import jitted, stop_worker
 
 
 def tree_equal(a, b):
@@ -93,7 +94,7 @@ def test_sharded_restore(tmp_path):
 
     def fwd(p):
         cache = init_cache(cfg, 2, 16, dtype=jnp.float32)
-        logits, _ = transformer.prefill(p, cfg, toks, lens, cache)
+        logits, _ = jitted(transformer.prefill)(p, cfg, toks, lens, cache)
         return logits
 
     with mesh:
@@ -132,7 +133,7 @@ def test_cli_convert_and_worker_load(tmp_path):
         assert resp.status_code == 200, resp.text
         assert len(resp.json()["tokens"]) == 4
     finally:
-        agent.service.shutdown()
+        stop_worker(agent)
 
 
 def test_generate_cli_loads_native_checkpoint(tmp_path, capsys):

@@ -28,6 +28,7 @@ import requests as rq
 from distributed_llm_inferencing_tpu.runtime import kvwire
 from distributed_llm_inferencing_tpu.runtime.master import Master
 from distributed_llm_inferencing_tpu.runtime.worker import WorkerAgent
+from conftest import stop_worker
 
 # ~100 byte-tokens: long enough for many full 8-token blocks, short
 # enough that "<mode> "-prefixed variants + 8 new tokens fit max_seq 128
@@ -217,7 +218,7 @@ def _counters(agent):
 def prefill_worker():
     agent, port = _mk_worker(role="prefill")
     yield agent, port
-    agent.service.shutdown()
+    stop_worker(agent)
 
 
 def test_health_reports_role_and_occupancy(prefill_worker):
@@ -321,7 +322,7 @@ def trio():
     cold = _mk_worker(role="mixed")
     yield src, dst, cold
     for a, _ in (src, dst, cold):
-        a.service.shutdown()
+        stop_worker(a)
 
 
 def test_transferred_decode_bitwise_identical(trio):
@@ -376,13 +377,13 @@ def test_peer_session_reuse_and_teardown():
         assert c["worker_peer_conns_created"] == 1
         assert c["worker_peer_conns_reused"] >= 1
         # dead peer: the fetch fails loudly and the session is purged
-        src.service.shutdown()
+        stop_worker(src)
         with pytest.raises(Exception):
             client.fetch(url, "tiny-llama", digs[:1])
         assert url not in client._sessions
     finally:
-        dst.service.shutdown()
-        src.service.shutdown()
+        stop_worker(dst)
+        stop_worker(src)
 
 
 def test_restore_from_peer_rejects_mismatched_pages():
@@ -513,8 +514,8 @@ def test_chaos_disagg_source_death_no_breaker_storm():
         assert mc["scheduler_disagg_transfer"] >= 1
     finally:
         m.stop()
-        src.service.shutdown()
-        dst.service.shutdown()
+        stop_worker(src)
+        stop_worker(dst)
 
 
 # ---- int8 wire tier + single-flight prefetch ----------------------------
@@ -548,8 +549,8 @@ def test_int8_worker_transfer_greedy_match_and_compression(
         assert st["dtype"] == "int8"
         assert st["logical_bytes"] > st["bytes"] * 3.5
     finally:
-        src.service.shutdown()
-        dst.service.shutdown()
+        stop_worker(src)
+        stop_worker(dst)
 
 
 def test_single_flight_prefetch_coalesces():

@@ -31,6 +31,7 @@ from distributed_llm_inferencing_tpu.runtime.batcher import ContinuousBatcher
 from distributed_llm_inferencing_tpu.runtime.master import Master
 from distributed_llm_inferencing_tpu.runtime.state import Store
 from distributed_llm_inferencing_tpu.runtime.worker import WorkerAgent
+from conftest import stop_worker
 
 
 def _url(port, path):
@@ -114,7 +115,7 @@ def batched_worker():
         "sampling": {"do_sample": False}}, timeout=300)
     assert r.status_code == 200, r.text
     yield agent, port
-    agent.service.shutdown()
+    stop_worker(agent)
 
 
 def _mk_master(**kw):

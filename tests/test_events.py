@@ -33,6 +33,7 @@ from distributed_llm_inferencing_tpu.runtime.master import (
 from distributed_llm_inferencing_tpu.runtime.state import Store
 from distributed_llm_inferencing_tpu.runtime.tsdb import TSDB
 from distributed_llm_inferencing_tpu.runtime.worker import WorkerAgent
+from conftest import stop_worker
 
 # char-level tiny-llama tokenizer + the workers' max_seq=128: the
 # prompt must stay under ~98 tokens with 30 new, while clearing the
@@ -570,19 +571,7 @@ def test_chaos_kill_decode_node_journal_reconstructs_recovery():
         assert jr["trace_id"], jr
     finally:
         m.stop()
+        # the batchers' scheduler threads too: the killed worker's keeps
+        # decoding for nobody otherwise
         for agent, _ in workers:
-            try:
-                agent.service.shutdown()
-            except Exception:
-                pass
-        # stop the batcher scheduler threads too (the killed worker's
-        # keeps decoding for nobody otherwise): a daemon thread still
-        # dispatching XLA work during interpreter teardown is the
-        # known-flaky exit crash this container shows at seed
-        for agent, _ in workers:
-            for lm in list(getattr(agent, "models", {}).values()):
-                if lm.batcher is not None:
-                    try:
-                        lm.batcher.stop()
-                    except Exception:
-                        pass
+            stop_worker(agent)

@@ -27,6 +27,7 @@ from distributed_llm_inferencing_tpu.ops.sampling import SamplingParams
 from distributed_llm_inferencing_tpu.runtime import batcher as batcher_mod
 from distributed_llm_inferencing_tpu.runtime.batcher import ContinuousBatcher
 from distributed_llm_inferencing_tpu.utils import trace
+from conftest import jitted
 
 BS = 4
 R = 3            # serving slots of the hand-driven pool; row R is the dummy
@@ -85,7 +86,7 @@ def err(got, ref):
 def dense_logits(cfg, params, toks, pad=0):
     cache = init_cache(cfg, 1, 64, dtype=jnp.float32)
     padded = np.concatenate([toks, np.zeros(pad, np.int32)])
-    logits, cache = transformer.prefill(
+    logits, cache = jitted(transformer.prefill)(
         params, cfg, jnp.asarray(padded[None]), jnp.asarray([len(toks)]),
         cache)
     return np.asarray(logits[0, :len(toks)], np.float32), cache
@@ -110,8 +111,8 @@ def test_forward_matches_the_reference(params, n, pad):
     # then one decode step through the dense cache and the state
     cache = cache._replace(lengths=jnp.asarray([n]))
     nxt = int(np.argmax(ref[-1]))
-    logits, _ = transformer.decode_step(params, cfg, jnp.asarray([[nxt]]),
-                                        cache)
+    logits, _ = jitted(transformer.decode_step)(
+        params, cfg, jnp.asarray([[nxt]]), cache)
     ref2 = ref_logits(cfg, params, np.append(toks, nxt))
     assert err(logits[0, 0], ref2[-1]) < TOL
 

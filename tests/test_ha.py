@@ -58,6 +58,7 @@ from distributed_llm_inferencing_tpu.runtime.worker import (
     MASTER_NONCE_HEADER, MASTER_TERM_HEADER, STALE_TERM_HEADER,
     WorkerAgent)
 from distributed_llm_inferencing_tpu.utils.metrics import Metrics
+from conftest import stop_worker
 
 REPO = Path(__file__).resolve().parents[1]
 
@@ -294,7 +295,7 @@ def test_worker_fences_stale_term_on_the_wire():
                        timeout=10).status_code == 200
         assert w.role == "decode" or True   # role flip above may apply
     finally:
-        w.service.shutdown()
+        stop_worker(w)
 
 
 def test_master_steps_down_and_writes_nothing_when_fenced():
@@ -681,4 +682,4 @@ def test_live_pair_replication_redirect_takeover():
         except Exception:
             pass
         standby.stop()
-        worker.service.shutdown()
+        stop_worker(worker)

@@ -27,6 +27,7 @@ from distributed_llm_inferencing_tpu.ops.sampling import SamplingParams
 from distributed_llm_inferencing_tpu.runtime.batcher import (
     ContinuousBatcher)
 from distributed_llm_inferencing_tpu.runtime.engine import InferenceEngine
+from conftest import jitted
 
 ROOT = Path(__file__).resolve().parents[1]
 BS, NB = 8, 64                      # block size, pool blocks (0 = dummy)
@@ -36,15 +37,39 @@ BS, NB = 8, 64                      # block size, pool blocks (0 = dummy)
 F32_TOL = 2e-4
 
 
-def _setup(dtype="float32", seed=0):
-    cfg = get_config("tiny-kanana").replace(
+def _cfg(dtype="float32"):
+    return get_config("tiny-kanana").replace(
         dtype=dtype, attn_backend="xla", mla_latent_cache=True)
+
+
+def _setup(dtype="float32", seed=0):
+    cfg = _cfg(dtype)
     params = init_params(cfg, jax.random.PRNGKey(seed),
                          dtype=jnp.dtype(dtype))
     return cfg, params, ref.arch_of(cfg)
 
 
-CFG, PARAMS, ARCH = _setup()
+CFG = _cfg()
+ARCH = ref.arch_of(CFG)
+PARAMS = None       # float32 weights, drawn by the first case that runs
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _params():
+    """Not at import: every worker imports every file to collect it, and
+    drawing 128 experts' weights took each 6 s before its first case."""
+    global PARAMS
+    _, PARAMS, _ = _setup()
+
+
+# One trace and one compile a (function, configuration, shapes) for the
+# whole file (conftest.jitted says why)
+_prefill_tail = jitted(transformer.paged_prefill_tail)
+_decode_step = jitted(transformer.paged_decode_step)
+_dense_prefill = jitted(transformer.prefill)
+_dense_decode_step = jitted(transformer.decode_step)
+_decode_chunk = jax.jit(transformer.paged_decode_chunk, static_argnums=(1, 2),
+                        static_argnames=("dummy_block",))
 
 
 def _err(got, want):
@@ -71,7 +96,7 @@ def _prefill(cfg, params, paged, rows):
         toks[i, :len(tail)] = tail
         tb[i, :len(blocks)] = blocks
         pfb[i, :len(pblocks)] = pblocks
-    return transformer.paged_prefill_tail(
+    return _prefill_tail(
         params, cfg, jnp.asarray(toks),
         jnp.asarray([len(r[0]) for r in rows], jnp.int32), jnp.asarray(tb),
         jnp.asarray(pfb), jnp.asarray([r[3] for r in rows], jnp.int32),
@@ -129,8 +154,8 @@ def test_forty_decode_steps_through_the_latent_pool_match_reference():
         (p[1], tables[1, :1].tolist(), [], 0)])
     first = np.asarray(jnp.argmax(logits, -1), np.int32)
     cl0 = np.asarray([21, 6], np.int32)
-    step = jax.jit(lambda t, pg, cl: transformer.paged_decode_step(
-        PARAMS, CFG, t, pg, jnp.asarray(tables), cl))
+    def step(t, pg, cl):
+        return _decode_step(PARAMS, CFG, t, pg, jnp.asarray(tables), cl)
     cur, cl, seqs, got, pg = first, cl0, [list(x) for x in p], [], paged
     for _ in range(40):
         for i in range(2):
@@ -148,7 +173,7 @@ def test_forty_decode_steps_through_the_latent_pool_match_reference():
     zeros, ones = np.zeros((r,), np.int32), np.ones((r,), np.float32)
     toks_all, cur, cl, pg, moe_sum = [], first, cl0, paged, 0
     for c in range(5):
-        toks, emits, moe, _, _, pg = transformer.paged_decode_chunk(
+        toks, emits, moe, _, _, pg = _decode_chunk(
             PARAMS, CFG, 8, jnp.asarray(cur), pg, jnp.asarray(tables),
             jnp.asarray(cl), jnp.asarray(zeros), jnp.asarray(zeros + 8 * c),
             jnp.asarray(ones), jnp.asarray(zeros), jnp.asarray(ones),
@@ -223,8 +248,8 @@ def test_latent_pool_matches_the_materialized_formulation():
     p = _prompts(6, (19,))[0]
     mat = CFG.replace(mla_latent_cache=False)
     cache = init_cache(mat, 1, 64, dtype=jnp.float32)
-    lg, cache = transformer.prefill(PARAMS, mat, jnp.asarray([p]),
-                                    jnp.asarray([19], jnp.int32), cache)
+    lg, cache = _dense_prefill(PARAMS, mat, jnp.asarray([p]),
+                               jnp.asarray([19], jnp.int32), cache)
     want, cur = [np.asarray(lg)[0, 18]], int(jnp.argmax(lg[0, 18]))
     paged = init_paged_cache(CFG, NB, BS)
     tables = np.zeros((1, 8), np.int32)
@@ -233,9 +258,9 @@ def test_latent_pool_matches_the_materialized_formulation():
                            [(p, tables[0, :3].tolist(), [], 0)])
     got = [np.asarray(lg_p)[0]]
     for i in range(12):
-        lg, cache = transformer.decode_step(
+        lg, cache = _dense_decode_step(
             PARAMS, mat, jnp.asarray([[cur]], jnp.int32), cache)
-        lg_p, paged = transformer.paged_decode_step(
+        lg_p, paged = _decode_step(
             PARAMS, CFG, jnp.asarray([cur], jnp.int32), paged,
             jnp.asarray(tables), jnp.asarray([19 + i], jnp.int32))
         want.append(np.asarray(lg)[0, 0])
@@ -289,7 +314,7 @@ def test_bf16_passes_the_reference_and_int8_weights_fail_it(seed):
         lg, paged = _prefill(c, prm, paged, [(p[:40], _blocks(1, 40), [], 0)])
         out = [np.asarray(lg)[0]]
         for i in range(40, 48):
-            lg, paged = transformer.paged_decode_step(
+            lg, paged = _decode_step(
                 prm, c, jnp.asarray([p[i]], jnp.int32), paged,
                 jnp.asarray(tables), jnp.asarray([i], jnp.int32))
             out.append(np.asarray(lg)[0])

@@ -19,15 +19,16 @@ from distributed_llm_inferencing_tpu.ops.kvcache import (
     dequant_kv, init_cache, quant_kv)
 from distributed_llm_inferencing_tpu.ops.paged_kvcache import init_paged_cache
 from distributed_llm_inferencing_tpu.ops.sampling import SamplingParams
+from conftest import jitted, shared_batcher as Batcher
 
 CFG = get_config("tiny-llama").replace(dtype="float32", attn_backend="xla")
 QCFG = CFG.replace(kv_quant="int8")
 PARAMS = init_params(CFG, jax.random.PRNGKey(0), dtype=jnp.float32)
-RNG = np.random.default_rng(0)
 
 
 def test_quant_roundtrip_error_bound():
-    x = jnp.asarray(RNG.normal(size=(4, 7, 2, 16)), jnp.float32)
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.normal(size=(4, 7, 2, 16)), jnp.float32)
     q, s = quant_kv(x)
     back = dequant_kv(q, s, jnp.float32)
     # symmetric int8: error <= scale/2 = max|x| per head / 254
@@ -50,21 +51,22 @@ def test_cache_memory_halves():
 
 
 def test_dense_prefill_decode_close_to_full_precision():
+    rng = np.random.default_rng(0)
     B, S = 2, 24
-    toks = jnp.asarray(RNG.integers(0, CFG.vocab_size, (B, S)), jnp.int32)
+    toks = jnp.asarray(rng.integers(0, CFG.vocab_size, (B, S)), jnp.int32)
     lens = jnp.asarray([S, S - 5], jnp.int32)
 
-    logits_f, cache_f = transformer.prefill(
+    logits_f, cache_f = jitted(transformer.prefill)(
         PARAMS, CFG, toks, lens, init_cache(CFG, B, 48, dtype=jnp.float32))
-    logits_q, cache_q = transformer.prefill(
+    logits_q, cache_q = jitted(transformer.prefill)(
         PARAMS, QCFG, toks, lens, init_cache(QCFG, B, 48))
     # prefill attends fresh K/V only -> logits should match tightly
     np.testing.assert_allclose(np.asarray(logits_q), np.asarray(logits_f),
                                atol=1e-4, rtol=1e-4)
 
     nxt = jnp.argmax(logits_f[:, -1], -1).astype(jnp.int32)[:, None]
-    d_f, _ = transformer.decode_step(PARAMS, CFG, nxt, cache_f)
-    d_q, _ = transformer.decode_step(PARAMS, QCFG, nxt, cache_q)
+    d_f, _ = jitted(transformer.decode_step)(PARAMS, CFG, nxt, cache_f)
+    d_q, _ = jitted(transformer.decode_step)(PARAMS, QCFG, nxt, cache_q)
     # decode reads the quantized cache -> relaxed tolerance
     f, q = np.asarray(d_f[:, 0]), np.asarray(d_q[:, 0])
     assert np.abs(q - f).max() < 0.15 * np.abs(f).max()
@@ -75,8 +77,9 @@ def test_dense_prefill_decode_close_to_full_precision():
 
 
 def test_engine_generates_with_kv_int8():
+    rng = np.random.default_rng(0)
     from distributed_llm_inferencing_tpu.runtime.engine import InferenceEngine
-    prompt = RNG.integers(0, CFG.vocab_size, 11).tolist()
+    prompt = rng.integers(0, CFG.vocab_size, 11).tolist()
     full = InferenceEngine(CFG, PARAMS, max_seq=64).generate(
         [prompt], max_new_tokens=12, sampling=SamplingParams.greedy())
     q = InferenceEngine(QCFG, PARAMS, max_seq=64).generate(
@@ -89,14 +92,13 @@ def test_engine_generates_with_kv_int8():
 
 
 def test_batcher_paged_kv_int8_end_to_end():
-    from distributed_llm_inferencing_tpu.runtime.batcher import (
-        ContinuousBatcher)
-    b = ContinuousBatcher(QCFG, PARAMS, num_blocks=64, block_size=8,
-                          slots=2, max_seq=64)
+    rng = np.random.default_rng(0)
+    b = Batcher(QCFG, PARAMS, num_blocks=64, block_size=8,
+                slots=2, max_seq=64)
     assert b.paged.quantized and b.paged.k.dtype == jnp.int8
-    sys_prompt = RNG.integers(0, CFG.vocab_size, 16).tolist()
-    prompts = [sys_prompt + RNG.integers(0, CFG.vocab_size, 3).tolist(),
-               sys_prompt + RNG.integers(0, CFG.vocab_size, 5).tolist()]
+    sys_prompt = rng.integers(0, CFG.vocab_size, 16).tolist()
+    prompts = [sys_prompt + rng.integers(0, CFG.vocab_size, 3).tolist(),
+               sys_prompt + rng.integers(0, CFG.vocab_size, 5).tolist()]
     reqs = [b.submit(p, max_new_tokens=10, sampling=SamplingParams.greedy())
             for p in prompts]
     for _ in range(60):
@@ -108,8 +110,8 @@ def test_batcher_paged_kv_int8_end_to_end():
     # prefix reuse works over the quantized pool too
     assert b.pool.stats()["prefix_hits"] >= 1
     # quantized-vs-full trajectories stay mostly aligned (greedy, tiny model)
-    fb = ContinuousBatcher(CFG, PARAMS, num_blocks=64, block_size=8,
-                           slots=2, max_seq=64)
+    fb = Batcher(CFG, PARAMS, num_blocks=64, block_size=8,
+                 slots=2, max_seq=64)
     fr = fb.submit(prompts[0], max_new_tokens=10,
                    sampling=SamplingParams.greedy())
     for _ in range(60):
@@ -124,8 +126,9 @@ def test_paged_decode_step_kv_int8_matches_dense():
     """Stepwise paged decode over an int8 pool vs the int8 DENSE cache:
     the same quantization scheme on both sides should land on the same
     greedy tokens for a short trajectory."""
+    rng = np.random.default_rng(0)
     paged = init_paged_cache(QCFG, 16, 8)
-    prompt = RNG.integers(0, CFG.vocab_size, 9).tolist()
+    prompt = rng.integers(0, CFG.vocab_size, 9).tolist()
     # paged admission via prefill tail (no prefix)
     toks = np.zeros((1, 16), np.int32)
     toks[0, :9] = prompt
@@ -149,13 +152,13 @@ def test_paged_decode_step_kv_int8_matches_dense():
         cl += 1
 
     cache = init_cache(QCFG, 1, 32)
-    logits, cache = transformer.prefill(
+    logits, cache = jitted(transformer.prefill)(
         PARAMS, QCFG, jnp.asarray([prompt], jnp.int32),
         jnp.asarray([9], jnp.int32), cache)
     cur = int(jnp.argmax(logits[0, 8]))
     out_dense = [cur]
     for _ in range(5):
-        logits, cache = transformer.decode_step(
+        logits, cache = jitted(transformer.decode_step)(
             PARAMS, QCFG, jnp.asarray([[cur]], jnp.int32), cache)
         cur = int(jnp.argmax(logits[0, 0]))
         out_dense.append(cur)
@@ -165,9 +168,10 @@ def test_paged_decode_step_kv_int8_matches_dense():
 def test_kv_int8_with_sequence_parallel_ring():
     """kv_quant composes with sp (ring prefill + flash-decoding combine):
     the ring decode path receives the dequantized cache view."""
+    rng = np.random.default_rng(0)
     from distributed_llm_inferencing_tpu.parallel.mesh import MeshSpec
     from distributed_llm_inferencing_tpu.runtime.engine import InferenceEngine
-    prompt = RNG.integers(0, CFG.vocab_size, 12).tolist()
+    prompt = rng.integers(0, CFG.vocab_size, 12).tolist()
     eng = InferenceEngine(QCFG, PARAMS, mesh_spec=MeshSpec(sp=2), max_seq=64)
     out = eng.generate([prompt], max_new_tokens=8,
                        sampling=SamplingParams.greedy())

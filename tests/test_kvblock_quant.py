@@ -37,6 +37,7 @@ from distributed_llm_inferencing_tpu.ops.sampling import SamplingParams
 from distributed_llm_inferencing_tpu.runtime.batcher import (
     ContinuousBatcher)
 from distributed_llm_inferencing_tpu.runtime.kvtier import HostKVArena
+from conftest import jitted
 
 BS = 8
 
@@ -199,7 +200,7 @@ def test_decode_logits_bounded_vs_native_restore(model):
     tokens = np.zeros((1, t), np.int32)
     tokens[0, :len(prompt)] = prompt
     paged = init_paged_cache(cfg, 16, BS, dtype=jnp.float32)
-    last, paged = transformer.paged_prefill_tail(
+    last, paged = jitted(transformer.paged_prefill_tail)(
         params, cfg, jnp.asarray(tokens),
         jnp.asarray([len(prompt)], jnp.int32),
         jnp.asarray(my_blocks, jnp.int32),
@@ -217,10 +218,10 @@ def test_decode_logits_bounded_vs_native_restore(model):
     block_tables[0, n_blocks] = 1 + n_blocks
     context_lens = np.asarray([len(prompt)], np.int32)
     toks = np.asarray([int(jnp.argmax(last[0]))], np.int32)
-    ln, _ = transformer.paged_decode_step(
+    ln, _ = jitted(transformer.paged_decode_step)(
         params, cfg, jnp.asarray(toks), paged,
         jnp.asarray(block_tables), jnp.asarray(context_lens))
-    lq, _ = transformer.paged_decode_step(
+    lq, _ = jitted(transformer.paged_decode_step)(
         params, cfg, jnp.asarray(toks), paged_q,
         jnp.asarray(block_tables), jnp.asarray(context_lens))
     err = float(jnp.max(jnp.abs(lq[0] - ln[0])))

@@ -23,11 +23,10 @@ from distributed_llm_inferencing_tpu.models.params import init_params
 from distributed_llm_inferencing_tpu.models.registry import get_config
 from distributed_llm_inferencing_tpu.ops.sampling import SamplingParams
 from distributed_llm_inferencing_tpu.runtime import kvtier
-from distributed_llm_inferencing_tpu.runtime.batcher import ContinuousBatcher
+from conftest import shared_batcher as Batcher
 
 CFG = get_config("tiny-llama").replace(dtype="float32", attn_backend="xla")
 PARAMS = init_params(CFG, jax.random.PRNGKey(0), dtype=jnp.float32)
-RNG = np.random.default_rng(7)
 
 
 def run_until_done(b, reqs, max_steps=400):
@@ -47,9 +46,9 @@ def run_one(b, prompt, n=8, sampling=None, seed=3):
 
 def make_batcher(kv_host_mb, num_blocks=24):
     # small pool: eviction pressure is the point
-    return ContinuousBatcher(CFG, PARAMS, num_blocks=num_blocks,
-                             block_size=8, slots=2, max_seq=128,
-                             kv_host_mb=kv_host_mb)
+    return Batcher(CFG, PARAMS, num_blocks=num_blocks,
+                   block_size=8, slots=2, max_seq=128,
+                   kv_host_mb=kv_host_mb)
 
 
 # ---- digests / arena units ---------------------------------------------
@@ -86,7 +85,8 @@ def test_arena_int8_lru_counts_stored_bytes():
     native arena — and the occupancy the arena-full routing guard
     (DLI_SCHED_ARENA_FULL) sees is the honest quantized budget, while
     logical_bytes still carries the full-precision equivalent."""
-    page = RNG.standard_normal((2, 8, 2, 4)).astype(np.float32)  # 512 B
+    rng = np.random.default_rng(7)
+    page = rng.standard_normal((2, 8, 2, 4)).astype(np.float32)  # 512 B
     native = kvtier.HostKVArena(capacity_bytes=4 * page.nbytes)
     int8 = kvtier.HostKVArena(capacity_bytes=4 * page.nbytes,
                               dtype="int8")
@@ -176,18 +176,19 @@ def cold_batcher():
     return make_batcher(kv_host_mb=0)
 
 
-def _evict_everything(b, n_prompts=6):
+def _evict_everything(b, rng, n_prompts=6):
     """Flood the small pool with distinct prompts so earlier radix
     prefixes evict (offloading to the arena when the tier is on)."""
     for _ in range(n_prompts):
-        run_one(b, RNG.integers(0, 256, 40).tolist(), n=4)
+        run_one(b, rng.integers(0, 256, 40).tolist(), n=4)
 
 
 def test_restore_bitwise_identical_greedy(tier_batcher, cold_batcher):
-    prompt = RNG.integers(0, 256, 40).tolist()
+    rng = np.random.default_rng(7)
+    prompt = rng.integers(0, 256, 40).tolist()
     cold = run_one(cold_batcher, prompt)
     assert run_one(tier_batcher, prompt) == cold
-    _evict_everything(tier_batcher)
+    _evict_everything(tier_batcher, rng)
     base = tier_batcher.metrics.snapshot()["counters"].get(
         "kvtier_restored_blocks", 0)
     again = run_one(tier_batcher, prompt)
@@ -199,12 +200,13 @@ def test_restore_bitwise_identical_greedy(tier_batcher, cold_batcher):
 
 
 def test_restore_bitwise_identical_sampled(tier_batcher, cold_batcher):
-    prompt = RNG.integers(0, 256, 40).tolist()
+    rng = np.random.default_rng(7)
+    prompt = rng.integers(0, 256, 40).tolist()
     sp = SamplingParams(temperature=0.9, top_k=7, top_p=0.95,
                         do_sample=True)
     cold = run_one(cold_batcher, prompt, sampling=sp, seed=11)
     assert run_one(tier_batcher, prompt, sampling=sp, seed=11) == cold
-    _evict_everything(tier_batcher)
+    _evict_everything(tier_batcher, rng)
     again = run_one(tier_batcher, prompt, sampling=sp, seed=11)
     assert again == cold
 
@@ -212,11 +214,12 @@ def test_restore_bitwise_identical_sampled(tier_batcher, cold_batcher):
 def test_restore_after_pool_rebuild_cold_radix(cold_batcher):
     """The arena outlives radix content entirely: a FRESH tier batcher
     that offloaded everything restores into an empty radix match."""
+    rng = np.random.default_rng(7)
     b = make_batcher(kv_host_mb=64, num_blocks=16)
-    prompt = RNG.integers(0, 256, 40).tolist()
+    prompt = rng.integers(0, 256, 40).tolist()
     cold = run_one(cold_batcher, prompt)
     first = run_one(b, prompt)
-    _evict_everything(b, n_prompts=4)
+    _evict_everything(b, rng, n_prompts=4)
     blocks, n = b.pool.match_prefix(prompt[:39])
     b.pool.release(blocks)
     assert n == 0, "radix should have evicted the prompt under pressure"
@@ -226,8 +229,9 @@ def test_restore_after_pool_rebuild_cold_radix(cold_batcher):
 # ---- same-wave duplicate prefix ----------------------------------------
 
 def test_same_wave_duplicate_prefix_hits_earlier_insert():
+    rng = np.random.default_rng(7)
     b = make_batcher(kv_host_mb=0, num_blocks=48)
-    shared = RNG.integers(0, 256, 32).tolist()
+    shared = rng.integers(0, 256, 32).tolist()
     r1 = b.submit(shared + [1, 2, 3], max_new_tokens=4,
                   sampling=SamplingParams.greedy())
     r2 = b.submit(shared + [7, 8, 9], max_new_tokens=4,
@@ -249,10 +253,11 @@ def test_cold_chunked_prefill_counts_zero_cached_tokens():
     re-matches its OWN earlier blocks on each resumption — that must not
     count as cached prefill (it would inflate the A/B's cached-fraction
     acceptance metric for traffic with no sharing at all)."""
-    b = ContinuousBatcher(CFG, PARAMS, num_blocks=24, block_size=8,
-                          slots=2, max_seq=128, kv_host_mb=0,
-                          prefill_chunk=4)    # 32-token chunks
-    run_one(b, RNG.integers(0, 256, 100).tolist(), n=4)
+    rng = np.random.default_rng(7)
+    b = Batcher(CFG, PARAMS, num_blocks=24, block_size=8,
+                slots=2, max_seq=128, kv_host_mb=0,
+                prefill_chunk=4)    # 32-token chunks
+    run_one(b, rng.integers(0, 256, 100).tolist(), n=4)
     c = b.metrics.snapshot()["counters"]
     assert c.get("prefill_uncached_tokens", 0) >= 100   # >= 3 passes ran
     assert c.get("prefill_cached_tokens", 0) == 0
@@ -261,6 +266,11 @@ def test_cold_chunked_prefill_counts_zero_cached_tokens():
 # ---- metrics exposition ------------------------------------------------
 
 def test_radix_and_kvtier_counters_reach_exposition(tier_batcher):
+    # traffic of its own that evicts and offloads: under --dist load the
+    # cases above may have run on another worker (alone, it found a pool
+    # nothing had been evicted from)
+    rng = np.random.default_rng(11)
+    _evict_everything(tier_batcher, rng)
     tier_batcher.step()    # epilogue syncs pool counters into metrics
     text = tier_batcher.metrics.prometheus()
     for name in ("dli_radix_prefix_hits_total",

@@ -21,11 +21,10 @@ from distributed_llm_inferencing_tpu.models.params import init_params
 from distributed_llm_inferencing_tpu.models.registry import get_config
 from distributed_llm_inferencing_tpu.ops import lora as lora_ops
 from distributed_llm_inferencing_tpu.ops.sampling import SamplingParams
-from distributed_llm_inferencing_tpu.runtime.batcher import ContinuousBatcher
+from conftest import shared_batcher as Batcher
 
 CFG = get_config("tiny-llama").replace(dtype="float32", attn_backend="xla")
 PARAMS = init_params(CFG, jax.random.PRNGKey(0), dtype=jnp.float32)
-RNG = np.random.default_rng(31)
 
 # scale ~0.8: strong enough that the rank-r delta flips greedy argmax on
 # the random-init tiny model (the checkpoint-realistic 0.05 default is a
@@ -40,7 +39,7 @@ def _mk(**kw):
     kw.setdefault("block_size", 8)
     kw.setdefault("slots", 4)
     kw.setdefault("max_seq", 96)
-    return ContinuousBatcher(CFG, PARAMS, **kw)
+    return Batcher(CFG, PARAMS, **kw)
 
 
 def _drain(b, reqs, limit=2000):
@@ -142,8 +141,8 @@ def test_adapter_equals_merged_dense_greedy():
                  for p in prompts]
     _drain(b, base_reqs)
 
-    merged = ContinuousBatcher(CFG, _merged_params(ad), num_blocks=128,
-                               block_size=8, slots=4, max_seq=96)
+    merged = Batcher(CFG, _merged_params(ad), num_blocks=128,
+                     block_size=8, slots=4, max_seq=96)
     mreqs = [merged.submit(p, max_new_tokens=8,
                            sampling=SamplingParams.greedy(), seed=50 + i)
              for i, p in enumerate(prompts)]

@@ -10,6 +10,7 @@ import requests
 
 from distributed_llm_inferencing_tpu.utils.metrics import (
     HIST_BUCKETS, Metrics, hist_quantile, parse_prometheus, sanitize_name)
+from conftest import stop_worker
 
 NAME = r"[a-zA-Z_:][a-zA-Z0-9_:]*"
 SAMPLE_RE = re.compile(
@@ -130,7 +131,7 @@ def test_worker_metrics_endpoint_parses_strict():
         assert "dli_requests_completed_total" in names
         assert "dli_inference_seconds_bucket" in names
     finally:
-        agent.service.shutdown()
+        stop_worker(agent)
 
 
 def test_master_cluster_metrics_aggregation():
@@ -166,7 +167,7 @@ def test_master_cluster_metrics_aggregation():
         assert "counters" in cm["master"]
     finally:
         m.stop()
-        agent.service.shutdown()
+        stop_worker(agent)
 
 
 def test_batcher_gauges_and_histograms_move():

@@ -40,9 +40,9 @@ import requests as rq
 from distributed_llm_inferencing_tpu.models.params import init_params
 from distributed_llm_inferencing_tpu.models.registry import get_config
 from distributed_llm_inferencing_tpu.ops.sampling import SamplingParams
-from distributed_llm_inferencing_tpu.runtime.batcher import ContinuousBatcher
 from distributed_llm_inferencing_tpu.runtime.master import Master
 from distributed_llm_inferencing_tpu.runtime.worker import WorkerAgent
+from conftest import shared_batcher as Batcher, stop_worker
 
 CFG = get_config("tiny-llama").replace(dtype="float32", attn_backend="xla")
 PARAMS = init_params(CFG, jax.random.PRNGKey(0), dtype=jnp.float32)
@@ -62,7 +62,7 @@ def _mk_batcher(**kw):
     # small decode chunks so a migration request lands mid-stream, not
     # after the whole budget ran inside one chunk
     kw.setdefault("decode_chunk_cap", 4)
-    return ContinuousBatcher(CFG, PARAMS, **kw)
+    return Batcher(CFG, PARAMS, **kw)
 
 
 def _wait_tokens(req, n, timeout=60):
@@ -231,7 +231,7 @@ def worker_pair():
     b = _mk_worker()
     yield a, b
     for agent, _ in (a, b):
-        agent.service.shutdown()
+        stop_worker(agent)
 
 
 def test_role_flip_endpoint(worker_pair):
@@ -386,11 +386,12 @@ def test_master_drain_migrates_inflight_live():
                 break
             time.sleep(0.002)
         assert node is not None and breq is not None
-        threading.Thread(
+        drainer = threading.Thread(
             target=lambda: rq.post(
                 f"http://127.0.0.1:{node['port']}/drain",
                 json={"timeout": 30}, timeout=60),
-            daemon=True).start()
+            daemon=True, name="test-drain")
+        drainer.start()
         st = _wait_req(base, rid)
         assert st["status"] == "completed", st
         assert st["result"] == ref["result"]
@@ -400,10 +401,12 @@ def test_master_drain_migrates_inflight_live():
         mc = m.metrics.snapshot()["counters"]
         assert mc["requests_migrated"] >= 1
         assert mc["rebalancer_migrations"] >= 1
+        drainer.join(timeout=60)
+        assert not drainer.is_alive()
     finally:
         m.stop()
         for agent, _ in workers:
-            agent.service.shutdown()
+            stop_worker(agent)
 
 
 def test_chaos_kill_worker_mid_stream_recovers_via_kv_fetch():
@@ -452,7 +455,7 @@ def test_chaos_kill_worker_mid_stream_recovers_via_kv_fetch():
         m.stop()
         for agent, _ in workers:
             try:
-                agent.service.shutdown()
+                stop_worker(agent)
             except Exception:
                 pass
 

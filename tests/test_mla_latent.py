@@ -22,19 +22,20 @@ from distributed_llm_inferencing_tpu.models.registry import get_config
 from distributed_llm_inferencing_tpu.ops.kvcache import init_cache
 from distributed_llm_inferencing_tpu.ops.sampling import SamplingParams
 from distributed_llm_inferencing_tpu.runtime.engine import InferenceEngine
+from conftest import jitted
 
 
 def _decode_logits(cfg, params, prompt, steps=6):
     """Prefill + greedy decode loop; returns stacked per-step logits."""
     B, S = prompt.shape
     cache = init_cache(cfg, B, 32, dtype=jnp.float32)
-    logits, cache = transformer.prefill(
+    logits, cache = jitted(transformer.prefill)(
         params, cfg, jnp.asarray(prompt), jnp.full((B,), S, jnp.int32),
         cache)
     outs = [np.asarray(logits)[:, S - 1]]
     cur = jnp.argmax(logits[:, S - 1], axis=-1).astype(jnp.int32)
     for _ in range(steps):
-        logits, cache = transformer.decode_step(
+        logits, cache = jitted(transformer.decode_step)(
             params, cfg, cur[:, None], cache)
         outs.append(np.asarray(logits)[:, 0])
         cur = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)

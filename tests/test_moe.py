@@ -22,8 +22,13 @@ from distributed_llm_inferencing_tpu.parallel.mesh import (
 
 BASE = get_config("tiny-mixtral").replace(dtype="float32",
                                           attn_backend="xla")
-PARAMS = init_params(BASE, jax.random.PRNGKey(0), dtype=jnp.float32)
-RNG = np.random.default_rng(0)
+PARAMS = None       # drawn by the first case that runs, not at import
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _params():
+    global PARAMS
+    PARAMS = init_params(BASE, jax.random.PRNGKey(0), dtype=jnp.float32)
 
 
 def _layer(cfg, seed=0, router=None, scale=0.1):
@@ -222,10 +227,11 @@ def _prefill_logits(cfg, params, tokens, mesh=None, spec=None):
 def test_ep_sharded_matches_unsharded():
     """Experts sharded over ep (and their widths over tp): GSPMD
     partitions the same formulation, no separate path."""
+    rng = np.random.default_rng(0)
     spec = MeshSpec(ep=2, tp=2)
     validate_spec(spec, BASE)
     tokens = jnp.asarray(
-        RNG.integers(0, BASE.vocab_size, (2, 24)), jnp.int32)
+        rng.integers(0, BASE.vocab_size, (2, 24)), jnp.int32)
     ref = _prefill_logits(BASE, PARAMS, tokens)
     got = _prefill_logits(BASE, PARAMS, tokens, create_mesh(spec), spec)
     np.testing.assert_allclose(got, ref, atol=2e-4, rtol=2e-4)
@@ -234,10 +240,11 @@ def test_ep_sharded_matches_unsharded():
 def test_int8_experts_prefill_over_32_tokens():
     """An int8 MoE prompt over 32 tokens used to raise (the capacity
     form's scale broadcast); it runs, and close to the float weights."""
+    rng = np.random.default_rng(0)
     cfg = BASE.replace(quant="int8")
     qparams = maybe_quantize(PARAMS, cfg)
     tokens = jnp.asarray(
-        RNG.integers(0, BASE.vocab_size, (1, 64)), jnp.int32)
+        rng.integers(0, BASE.vocab_size, (1, 64)), jnp.int32)
     got = _prefill_logits(cfg, qparams, tokens)
     ref = _prefill_logits(BASE, PARAMS, tokens)
     assert np.isfinite(got).all()

@@ -54,6 +54,19 @@ from distributed_llm_inferencing_tpu.utils.platform import \
     free_port as _free_port  # noqa: E402
 
 
+def _slice_env():
+    """A slice process's environment: its own platform and device count
+    (RUNNER sets them), and no persistent compile cache, whatever the
+    caller's ``JAX_COMPILATION_CACHE_DIR``: with one, the two
+    elastic-recovery cases hang past their limit and a second run over a
+    warm directory fails at start-up (measured for PR 48, when the whole
+    suite ran with one; not looked into further)."""
+    import os
+    return {k: v for k, v in os.environ.items()
+            if k not in ("XLA_FLAGS", "JAX_PLATFORMS",
+                         "JAX_COMPILATION_CACHE_DIR")}
+
+
 @pytest.fixture(scope="module")
 def slice2():
     import os
@@ -61,8 +74,7 @@ def slice2():
     coord = f"127.0.0.1:{_free_port()}"
     lport, fport = _free_port(), _free_port()
     script = RUNNER.format(repo=repo)
-    env = {k: v for k, v in os.environ.items()
-           if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
+    env = _slice_env()
     procs = [
         subprocess.Popen([sys.executable, "-c", script, "0", str(lport),
                           coord, f"127.0.0.1:{fport}"],
@@ -97,14 +109,23 @@ def slice2():
             p.kill()
 
 
-def test_lockstep_load_and_infer(slice2):
-    lport, fport = slice2
-    url = f"http://127.0.0.1:{lport}"
-    r = requests.post(url + "/load_model", json={
+@pytest.fixture(scope="module")
+def slice2_loaded(slice2):
+    """The slice with tiny-llama loaded at tp=2 through its leader. A
+    fixture, not the first test's doing: under ``--dist load`` the case
+    that streams may run on a worker that never ran the case that loads
+    (alone, it found no model)."""
+    lport, _ = slice2
+    r = requests.post(f"http://127.0.0.1:{lport}/load_model", json={
         "model_name": "tiny-llama", "allow_random_init": True,
         "dtype": "float32", "max_seq": 64, "mesh": {"tp": 2}}, timeout=300)
     assert r.status_code == 200, r.text
+    return slice2
 
+
+def test_lockstep_load_and_infer(slice2_loaded):
+    lport, fport = slice2_loaded
+    url = f"http://127.0.0.1:{lport}"
     prompt = np.random.default_rng(0).integers(0, 256, 9).tolist()
     r = requests.post(url + "/inference", json={
         "model_name": "tiny-llama", "prompt_tokens": prompt,
@@ -124,8 +145,8 @@ def test_lockstep_load_and_infer(slice2):
     assert r2.json()["tokens"] == got
 
 
-def test_lockstep_streaming(slice2):
-    lport, _ = slice2
+def test_lockstep_streaming(slice2_loaded):
+    lport, _ = slice2_loaded
     url = f"http://127.0.0.1:{lport}"
     prompt = [3, 1, 4, 1, 5]
     with requests.post(url + "/inference_stream", json={
@@ -159,8 +180,7 @@ def slice2_nodist():
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     lport, fport = _free_port(), _free_port()
     script = RUNNER.format(repo=repo)
-    env = {k: v for k, v in os.environ.items()
-           if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
+    env = _slice_env()
 
     def spawn(proc_id, port, followers=None):
         argv = [sys.executable, "-c", script, str(proc_id), str(port),
@@ -264,8 +284,7 @@ def slice2_dist_restartable():
     coord = f"127.0.0.1:{_free_port()}"
     lport, fport = _free_port(), _free_port()
     script = RUNNER.format(repo=repo)
-    env = {k: v for k, v in os.environ.items()
-           if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
+    env = _slice_env()
 
     def spawn(proc_id, port, coord_arg, followers=None):
         argv = [sys.executable, "-c", script, str(proc_id), str(port),

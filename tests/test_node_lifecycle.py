@@ -10,22 +10,38 @@ staying non-negative under concurrent failures.
 import threading
 import time
 
+import pytest
 import requests
 
 from distributed_llm_inferencing_tpu.runtime.master import (
     FAILURE_STRIKES, MAX_ATTEMPTS, Master)
 from distributed_llm_inferencing_tpu.runtime.state import Store
 from distributed_llm_inferencing_tpu.runtime.worker import WorkerAgent
+from conftest import stop_worker
 
 
 def _url(port, path):
     return f"http://127.0.0.1:{port}{path}"
 
 
+@pytest.fixture
+def quiet_master():
+    """``Master(...)`` that is never started: its store's write-behind
+    flusher is the one thread it has, closed and joined at teardown."""
+    made = []
+
+    def make(*args, **kw):
+        made.append(Master(*args, **kw))
+        return made[-1]
+    yield make
+    for m in made:
+        m.store.close()
+
+
 # ---- breaker state machine (no sockets) ------------------------------
 
-def test_strikes_accumulate_then_open_at_threshold():
-    m = Master(":memory:")           # no background threads started
+def test_strikes_accumulate_then_open_at_threshold(quiet_master):
+    m = quiet_master(":memory:")           # no background threads started
     nid = m.store.add_node("n1", "127.0.0.1", 1, is_active=True)
     node = m.store.get_node(nid)
     for i in range(FAILURE_STRIKES - 1):
@@ -40,8 +56,8 @@ def test_strikes_accumulate_then_open_at_threshold():
     assert m.metrics.snapshot()["counters"]["breaker_opened"] == 1
 
 
-def test_half_open_probe_failure_reopens_immediately():
-    m = Master(":memory:")
+def test_half_open_probe_failure_reopens_immediately(quiet_master):
+    m = quiet_master(":memory:")
     nid = m.store.add_node("n1", "127.0.0.1", 1, is_active=True)
     m.store.update_node(nid, breaker_state="half_open", is_active=1,
                         consecutive_failures=FAILURE_STRIKES)
@@ -50,8 +66,8 @@ def test_half_open_probe_failure_reopens_immediately():
     assert n["breaker_state"] == "open" and n["is_active"] == 0
 
 
-def test_success_closes_half_open_and_clears_strikes():
-    m = Master(":memory:")
+def test_success_closes_half_open_and_clears_strikes(quiet_master):
+    m = quiet_master(":memory:")
     nid = m.store.add_node("n1", "127.0.0.1", 1, is_active=True)
     m.store.update_node(nid, breaker_state="half_open", is_active=1,
                         consecutive_failures=FAILURE_STRIKES)
@@ -62,8 +78,8 @@ def test_success_closes_half_open_and_clears_strikes():
     assert m.metrics.snapshot()["counters"]["breaker_closed"] == 1
 
 
-def test_pick_node_skips_open_draining_and_limits_half_open():
-    m = Master(":memory:")
+def test_pick_node_skips_open_draining_and_limits_half_open(quiet_master):
+    m = quiet_master(":memory:")
     a = m.store.add_node("a", "127.0.0.1", 1, is_active=True)
     b = m.store.add_node("b", "127.0.0.1", 2, is_active=True)
     # open breaker on a -> only b schedulable
@@ -84,11 +100,11 @@ def test_pick_node_skips_open_draining_and_limits_half_open():
     assert m._pick_node(None, exclude={a, b}) is not None
 
 
-def test_timeout_retry_prefers_node_holding_the_generation():
+def test_timeout_retry_prefers_node_holding_the_generation(quiet_master):
     """A timeout requeue records the node and does not exclude it; the
     retry pins back to that node (its idempotency cache / in-flight
     join has the generation) instead of re-generating on a peer."""
-    m = Master(":memory:")
+    m = quiet_master(":memory:")
     a = m.store.add_node("a", "127.0.0.1", 1, is_active=True)
     b = m.store.add_node("b", "127.0.0.1", 2, is_active=True)
     rid = m.store.submit_request("x", "p", 3, {})
@@ -163,14 +179,14 @@ def test_dead_node_reactivates_via_health_probe():
     finally:
         m.stop()
         if revived is not None:
-            revived.service.shutdown()
-        agent.service.shutdown()
+            stop_worker(revived)
+        stop_worker(agent)
 
 
 # ---- in-flight accounting under concurrent failures ------------------
 
-def test_inflight_never_negative_under_concurrent_failures():
-    m = Master(":memory:", retry_backoff_base=0.01)
+def test_inflight_never_negative_under_concurrent_failures(quiet_master):
+    m = quiet_master(":memory:", retry_backoff_base=0.01)
     m.store.add_node("dead", "127.0.0.1", 1, is_active=True)  # refused port
     for _ in range(8):
         m.store.submit_request("x", "p", 3, {})

@@ -13,13 +13,15 @@ import jax.numpy as jnp
 
 from distributed_llm_inferencing_tpu.models import convert, transformer
 from distributed_llm_inferencing_tpu.ops.kvcache import init_cache
+from conftest import jitted
 
 
 def _logits_ours(cfg, params, tokens):
     B, S = tokens.shape
     cache = init_cache(cfg, B, S, dtype=jnp.float32)
     lengths = jnp.full((B,), S, jnp.int32)
-    logits, _ = transformer.prefill(params, cfg, jnp.asarray(tokens), lengths, cache)
+    logits, _ = jitted(transformer.prefill)(
+        params, cfg, jnp.asarray(tokens), lengths, cache)
     return np.asarray(logits)
 
 
@@ -132,13 +134,13 @@ def test_opt_350m_decode_matches_hf_generate():
             pad_token_id=0)[0, 6:].tolist()
 
     cache = init_cache(cfg, 1, 32, dtype=jnp.float32)
-    logits, cache = transformer.prefill(
+    logits, cache = jitted(transformer.prefill)(
         params, cfg, jnp.asarray(prompt.astype(np.int32)),
         jnp.asarray([6], jnp.int32), cache)
     cur = int(np.argmax(np.asarray(logits)[0, 5]))
     got = [cur]
     for _ in range(7):
-        logits, cache = transformer.decode_step(
+        logits, cache = jitted(transformer.decode_step)(
             params, cfg, jnp.asarray([[cur]], jnp.int32), cache)
         cur = int(np.argmax(np.asarray(logits)[0, 0]))
         got.append(cur)
@@ -180,7 +182,7 @@ def test_ragged_prefill_matches_unpadded():
     padded[1, :5] = b[0]
 
     cache = init_cache(cfg, 2, 16, dtype=jnp.float32)
-    logits, _ = transformer.prefill(
+    logits, _ = jitted(transformer.prefill)(
         params, cfg, jnp.asarray(padded), jnp.asarray([9, 5], jnp.int32), cache)
     sole_a = _logits_ours(cfg, params, a)
     sole_b = _logits_ours(cfg, params, b)
@@ -250,13 +252,13 @@ def test_gemma_decode_matches_hf_generate():
             pad_token_id=0)[0, 6:].tolist()
 
     cache = init_cache(cfg, 1, 32, dtype=jnp.float32)
-    logits, cache = transformer.prefill(
+    logits, cache = jitted(transformer.prefill)(
         params, cfg, jnp.asarray(prompt.astype(np.int32)),
         jnp.asarray([6], jnp.int32), cache)
     cur = int(np.argmax(np.asarray(logits)[0, 5]))
     got = [cur]
     for _ in range(7):
-        logits, cache = transformer.decode_step(
+        logits, cache = jitted(transformer.decode_step)(
             params, cfg, jnp.asarray([[cur]], jnp.int32), cache)
         cur = int(np.argmax(np.asarray(logits)[0, 0]))
         got.append(cur)
@@ -425,13 +427,13 @@ def test_phi_decode_matches_hf_generate():
             pad_token_id=0)[0, 6:].tolist()
 
     cache = init_cache(cfg, 1, 32, dtype=jnp.float32)
-    logits, cache = transformer.prefill(
+    logits, cache = jitted(transformer.prefill)(
         params, cfg, jnp.asarray(prompt.astype(np.int32)),
         jnp.asarray([6], jnp.int32), cache)
     cur = int(np.argmax(np.asarray(logits)[0, 5]))
     got = [cur]
     for _ in range(7):
-        logits, cache = transformer.decode_step(
+        logits, cache = jitted(transformer.decode_step)(
             params, cfg, jnp.asarray([[cur]], jnp.int32), cache)
         cur = int(np.argmax(np.asarray(logits)[0, 0]))
         got.append(cur)
@@ -490,13 +492,13 @@ def test_bloom_decode_matches_hf_generate():
             pad_token_id=0)[0, 6:].tolist()
 
     cache = init_cache(cfg, 1, 32, dtype=jnp.float32)
-    logits, cache = transformer.prefill(
+    logits, cache = jitted(transformer.prefill)(
         params, cfg, jnp.asarray(prompt.astype(np.int32)),
         jnp.asarray([6], jnp.int32), cache)
     cur = int(np.argmax(np.asarray(logits)[0, 5]))
     got = [cur]
     for _ in range(7):
-        logits, cache = transformer.decode_step(
+        logits, cache = jitted(transformer.decode_step)(
             params, cfg, jnp.asarray([[cur]], jnp.int32), cache)
         cur = int(np.argmax(np.asarray(logits)[0, 0]))
         got.append(cur)

@@ -22,6 +22,7 @@ from distributed_llm_inferencing_tpu.ops.sampling import SamplingParams
 from distributed_llm_inferencing_tpu.runtime import kvtier, kvwire
 from distributed_llm_inferencing_tpu.runtime.batcher import ContinuousBatcher
 from distributed_llm_inferencing_tpu.utils import trace
+from conftest import jitted
 
 BS = 4
 # float32 against float32 on the CPU: the two sum in another order. The
@@ -68,8 +69,8 @@ def err(got, ref):
 
 def dense_logits(cfg, params, toks):
     cache = init_cache(cfg, 1, 64, dtype=jnp.float32)
-    logits, cache = transformer.prefill(params, cfg, jnp.asarray(toks[None]),
-                                        jnp.asarray([len(toks)]), cache)
+    logits, cache = jitted(transformer.prefill)(
+        params, cfg, jnp.asarray(toks[None]), jnp.asarray([len(toks)]), cache)
     return np.asarray(logits[0], np.float32), cache
 
 
@@ -84,8 +85,8 @@ def test_forward_matches_the_reference(params):
     assert err(got, ref) < TOL
     # then one decode step through the dense cache's nine planes
     nxt = int(np.argmax(ref[-1]))
-    logits, _ = transformer.decode_step(params, cfg, jnp.asarray([[nxt]]),
-                                        cache)
+    logits, _ = jitted(transformer.decode_step)(
+        params, cfg, jnp.asarray([[nxt]]), cache)
     ref2 = ref_logits(cfg, params, np.append(toks, nxt))
     assert err(logits[0, 0], ref2[-1]) < TOL
 

@@ -16,6 +16,7 @@ from distributed_llm_inferencing_tpu.models.params import init_params
 from distributed_llm_inferencing_tpu.models.registry import get_config
 from distributed_llm_inferencing_tpu.ops.kvcache import init_cache
 from distributed_llm_inferencing_tpu.ops.paged_kvcache import init_paged_cache
+from conftest import jitted
 
 BS = 8  # block size for tests
 
@@ -31,14 +32,15 @@ def _dense_greedy(cfg, params, prompt, n_new):
     tokens = np.zeros((1, s0), np.int32)
     tokens[0, :len(prompt)] = prompt
     lengths = jnp.asarray([len(prompt)], jnp.int32)
-    logits, cache = transformer.prefill(params, cfg, jnp.asarray(tokens),
-                                        lengths, cache)
+    logits, cache = jitted(transformer.prefill)(
+        params, cfg, jnp.asarray(tokens), lengths, cache)
     last = logits[0, len(prompt) - 1]
     out, traj = [], [last]
     cur = jnp.argmax(last)[None]
     out.append(int(cur[0]))
     for _ in range(n_new - 1):
-        logits, cache = transformer.decode_step(params, cfg, cur[:, None], cache)
+        logits, cache = jitted(transformer.decode_step)(
+            params, cfg, cur[:, None], cache)
         traj.append(logits[0, 0])
         cur = jnp.argmax(logits[0, 0])[None]
         out.append(int(cur[0]))
@@ -57,7 +59,7 @@ def _paged_greedy(cfg, params, prompt, n_new, *, num_blocks=32, slots=4,
     tokens = np.zeros((1, t), np.int32)
     tokens[0, :len(prompt)] = prompt
 
-    last, paged = transformer.paged_prefill_tail(
+    last, paged = jitted(transformer.paged_prefill_tail)(
         params, cfg, jnp.asarray(tokens), jnp.asarray([len(prompt)], jnp.int32),
         jnp.asarray(my_blocks, jnp.int32),
         jnp.zeros((1, 1), jnp.int32), jnp.zeros((1,), jnp.int32), paged)
@@ -76,7 +78,7 @@ def _paged_greedy(cfg, params, prompt, n_new, *, num_blocks=32, slots=4,
     toks = np.zeros((slots,), np.int32)
     for _ in range(n_new - 1):
         toks[slot] = cur_tok
-        logits, paged = transformer.paged_decode_step(
+        logits, paged = jitted(transformer.paged_decode_step)(
             params, cfg, jnp.asarray(toks), paged,
             jnp.asarray(block_tables), jnp.asarray(context_lens))
         traj.append(logits[slot])
@@ -128,7 +130,7 @@ def test_prefix_reuse_matches_full_prefill():
     blocks_a = list(range(1, 1 + t_a // BS))
     toks_a = np.zeros((1, t_a), np.int32)
     toks_a[0, :len(prompt_a)] = prompt_a
-    last_a, paged = transformer.paged_prefill_tail(
+    last_a, paged = jitted(transformer.paged_prefill_tail)(
         params, cfg, jnp.asarray(toks_a),
         jnp.asarray([len(prompt_a)], jnp.int32),
         jnp.asarray(blocks_a, jnp.int32),
@@ -142,7 +144,7 @@ def test_prefix_reuse_matches_full_prefill():
     blocks_b = list(range(10, 10 + t_b // BS))
     toks_b = np.zeros((1, t_b), np.int32)
     toks_b[0, :tail_len] = prompt_b[len(shared):]
-    last_b, paged = transformer.paged_prefill_tail(
+    last_b, paged = jitted(transformer.paged_prefill_tail)(
         params, cfg, jnp.asarray(toks_b),
         jnp.asarray([tail_len], jnp.int32),
         jnp.asarray(blocks_b, jnp.int32),
@@ -154,7 +156,7 @@ def test_prefix_reuse_matches_full_prefill():
     t_full = -(-len(prompt_b) // BS) * BS
     toks_full = np.zeros((1, t_full), np.int32)
     toks_full[0, :len(prompt_b)] = prompt_b
-    last_full, _ = transformer.paged_prefill_tail(
+    last_full, _ = jitted(transformer.paged_prefill_tail)(
         params, cfg, jnp.asarray(toks_full),
         jnp.asarray([len(prompt_b)], jnp.int32),
         jnp.asarray(list(range(1, 1 + t_full // BS)), jnp.int32),

@@ -13,11 +13,9 @@ import numpy as np
 from distributed_llm_inferencing_tpu.models.registry import get_config
 from distributed_llm_inferencing_tpu.ops.sampling import SamplingParams
 from distributed_llm_inferencing_tpu.parallel.mesh import MeshSpec
-from distributed_llm_inferencing_tpu.runtime.batcher import ContinuousBatcher
+from conftest import shared_batcher as Batcher
 
 CFG = get_config("tiny-llama").replace(dtype="float32", attn_backend="xla")
-RNG = np.random.default_rng(0)
-
 
 def _run(b, reqs, steps=200):
     for _ in range(steps):
@@ -28,10 +26,11 @@ def _run(b, reqs, steps=200):
 
 
 def _submit_mixed(b):
-    base = RNG.integers(0, 256, 6).tolist()
+    rng = np.random.default_rng(0)
+    base = rng.integers(0, 256, 6).tolist()
     prompts = [(base * 4)[:20],
-               RNG.integers(0, 256, 9).tolist(),
-               RNG.integers(0, 256, 13).tolist()]
+               rng.integers(0, 256, 9).tolist(),
+               rng.integers(0, 256, 13).tolist()]
     return [
         b.submit(prompts[0], max_new_tokens=14,
                  sampling=SamplingParams.greedy(), seed=1),
@@ -46,15 +45,12 @@ def test_pp_batcher_matches_dense():
     """pp=2 batcher ≡ single-stage batcher: same tokens for greedy AND
     sampled requests (per-slot PRNG streams are data, so the pipelined
     program must reproduce them bit-for-bit)."""
-    global RNG
-    RNG = np.random.default_rng(0)
-    dense = ContinuousBatcher(CFG, num_blocks=96, block_size=8, slots=4,
-                              max_seq=64, seed=0)
+    dense = Batcher(CFG, num_blocks=96, block_size=8, slots=4,
+                    max_seq=64, seed=0)
     want = _run(dense, _submit_mixed(dense))
 
-    RNG = np.random.default_rng(0)
-    pp = ContinuousBatcher(CFG, num_blocks=96, block_size=8, slots=4,
-                           max_seq=64, seed=0, mesh_spec=MeshSpec(pp=2))
+    pp = Batcher(CFG, num_blocks=96, block_size=8, slots=4,
+                 max_seq=64, seed=0, mesh_spec=MeshSpec(pp=2))
     got = _run(pp, _submit_mixed(pp))
     assert got == want, (got, want)
 
@@ -62,13 +58,12 @@ def test_pp_batcher_matches_dense():
 def test_pp_batcher_eos_budget_and_inflight_admission():
     """Per-slot eos stops a pp-scheduled slot mid-chunk; freed slots
     admit queued requests mid-flight exactly like the dense batcher."""
-    global RNG
-    RNG = np.random.default_rng(7)
-    prompts = [RNG.integers(0, 256, n).tolist() for n in (8, 11, 9, 7, 12)]
+    rng = np.random.default_rng(7)
+    prompts = [rng.integers(0, 256, n).tolist() for n in (8, 11, 9, 7, 12)]
 
     def run(mesh_spec):
-        b = ContinuousBatcher(CFG, num_blocks=96, block_size=8, slots=2,
-                              max_seq=64, seed=0, mesh_spec=mesh_spec)
+        b = Batcher(CFG, num_blocks=96, block_size=8, slots=2,
+                    max_seq=64, seed=0, mesh_spec=mesh_spec)
         # more requests than slots: forces queueing + in-flight admission
         reqs = [b.submit(p, max_new_tokens=6 + i,
                          sampling=SamplingParams.greedy(), seed=10 + i)
@@ -80,14 +75,14 @@ def test_pp_batcher_eos_budget_and_inflight_admission():
     assert got == want, (got, want)
 
     # eos: derive it from a full run, then check truncation matches
-    b = ContinuousBatcher(CFG, num_blocks=96, block_size=8, slots=2,
-                          max_seq=64, seed=0, mesh_spec=MeshSpec(pp=2))
+    b = Batcher(CFG, num_blocks=96, block_size=8, slots=2,
+                max_seq=64, seed=0, mesh_spec=MeshSpec(pp=2))
     r_full = b.submit(prompts[0], max_new_tokens=10,
                       sampling=SamplingParams.greedy(), seed=10)
     full = _run(b, [r_full])[0]
     eos = full[4]
-    b2 = ContinuousBatcher(CFG, num_blocks=96, block_size=8, slots=2,
-                           max_seq=64, seed=0, mesh_spec=MeshSpec(pp=2))
+    b2 = Batcher(CFG, num_blocks=96, block_size=8, slots=2,
+                 max_seq=64, seed=0, mesh_spec=MeshSpec(pp=2))
     r_eos = b2.submit(prompts[0], max_new_tokens=10,
                       sampling=SamplingParams.greedy(), seed=10,
                       eos_token_id=eos)
@@ -101,15 +96,14 @@ def test_pp_batcher_prefix_reuse():
     """Radix prefix hits survive the pp pool layout: a second request
     sharing a long prompt prefix admits with a cached prefix (fewer
     fresh blocks) and still matches the dense batcher's tokens."""
-    global RNG
-    RNG = np.random.default_rng(3)
-    head = RNG.integers(0, 256, 24).tolist()
-    p1 = head + RNG.integers(0, 256, 4).tolist()
-    p2 = head + RNG.integers(0, 256, 5).tolist()
+    rng = np.random.default_rng(3)
+    head = rng.integers(0, 256, 24).tolist()
+    p1 = head + rng.integers(0, 256, 4).tolist()
+    p2 = head + rng.integers(0, 256, 5).tolist()
 
     def run(mesh_spec):
-        b = ContinuousBatcher(CFG, num_blocks=96, block_size=8, slots=2,
-                              max_seq=64, seed=0, mesh_spec=mesh_spec)
+        b = Batcher(CFG, num_blocks=96, block_size=8, slots=2,
+                    max_seq=64, seed=0, mesh_spec=mesh_spec)
         r1 = b.submit(p1, max_new_tokens=6,
                       sampling=SamplingParams.greedy(), seed=1)
         out1 = _run(b, [r1])[0]
@@ -133,7 +127,7 @@ def test_pp_batcher_lockstep_replay_evolves_identical_cache():
     import json
     import jax
 
-    mk = lambda: ContinuousBatcher(  # noqa: E731
+    mk = lambda: Batcher(  # noqa: E731
         CFG, num_blocks=64, block_size=8, slots=2, max_seq=64, seed=0,
         mesh_spec=MeshSpec(pp=2))
     leader, follower = mk(), mk()
@@ -143,10 +137,9 @@ def test_pp_batcher_lockstep_replay_evolves_identical_cache():
         return run()
 
     leader.program_hook = hook
-    global RNG
-    RNG = np.random.default_rng(5)
-    prompts = [RNG.integers(0, 256, 9).tolist(),
-               RNG.integers(0, 256, 12).tolist()]
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, 256, 9).tolist(),
+               rng.integers(0, 256, 12).tolist()]
     reqs = [leader.submit(p, max_new_tokens=8,
                           sampling=SamplingParams.greedy(), seed=20 + i)
             for i, p in enumerate(prompts)]
@@ -164,13 +157,12 @@ def test_pp_batcher_kv8_matches_dense_kv8():
     tokens exactly (same quantize-at-write / dequantize-at-read points,
     so the rounding is identical)."""
     kcfg = CFG.replace(kv_quant="int8")
-    global RNG
-    RNG = np.random.default_rng(11)
-    prompts = [RNG.integers(0, 256, n).tolist() for n in (9, 14)]
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(0, 256, n).tolist() for n in (9, 14)]
 
     def run(mesh_spec):
-        b = ContinuousBatcher(kcfg, num_blocks=96, block_size=8, slots=2,
-                              max_seq=64, seed=0, mesh_spec=mesh_spec)
+        b = Batcher(kcfg, num_blocks=96, block_size=8, slots=2,
+                    max_seq=64, seed=0, mesh_spec=mesh_spec)
         reqs = [b.submit(p, max_new_tokens=8,
                          sampling=SamplingParams.greedy(), seed=30 + i)
                 for i, p in enumerate(prompts)]
@@ -183,8 +175,8 @@ def test_pp_batcher_kv8_matches_dense_kv8():
 
 def test_pp_batcher_rejects_unsupported_combos():
     # slots round UP to a pp multiple
-    b = ContinuousBatcher(CFG, num_blocks=32, block_size=8, slots=3,
-                          max_seq=64, mesh_spec=MeshSpec(pp=2))
+    b = Batcher(CFG, num_blocks=32, block_size=8, slots=3,
+                max_seq=64, mesh_spec=MeshSpec(pp=2))
     assert b.slots == 4
 
 
@@ -284,14 +276,10 @@ def test_pp_batcher_speculative_matches_single_stage():
     """Batcher-level: speculative serving on a pp=2 mesh ≡ the
     single-stage speculative batcher for greedy AND sampled requests,
     across multiple chunks (pool commits included)."""
-    global RNG
-
     def run(mesh_spec):
-        global RNG
-        RNG = np.random.default_rng(0)
-        b = ContinuousBatcher(CFG, num_blocks=96, block_size=8, slots=4,
-                              max_seq=64, seed=0, mesh_spec=mesh_spec,
-                              speculative="ngram", spec_gamma=3)
+        b = Batcher(CFG, num_blocks=96, block_size=8, slots=4,
+                    max_seq=64, seed=0, mesh_spec=mesh_spec,
+                    speculative="ngram", spec_gamma=3)
         return _run(b, _submit_mixed(b))
 
     want = run(None)

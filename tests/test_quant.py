@@ -14,6 +14,7 @@ from distributed_llm_inferencing_tpu.ops.quant import (
     dequantize_weight, maybe_quantize, quantize_weight)
 from distributed_llm_inferencing_tpu.ops.sampling import SamplingParams
 from distributed_llm_inferencing_tpu.runtime.engine import InferenceEngine
+from conftest import jitted
 
 
 def test_quantize_roundtrip_error():
@@ -45,7 +46,7 @@ def test_quantized_logits_close(model):
 
     def fwd(cfg_, p):
         cache = init_cache(cfg_, 2, 16, dtype=jnp.float32)
-        logits, _ = transformer.prefill(p, cfg_, toks, lens, cache)
+        logits, _ = jitted(transformer.prefill)(p, cfg_, toks, lens, cache)
         return np.asarray(logits)
 
     full = fwd(cfg, params)
@@ -199,7 +200,7 @@ def test_int4_forward_matches_dequantized_weights(model):
 
     def fwd(cfg_, p):
         cache = init_cache(cfg_, 2, 16, dtype=jnp.float32)
-        logits, _ = transformer.prefill(p, cfg_, toks, lens, cache)
+        logits, _ = jitted(transformer.prefill)(p, cfg_, toks, lens, cache)
         return np.asarray(logits)
 
     quant = fwd(qcfg, qparams)
@@ -335,7 +336,7 @@ def test_embed_quant_forward_matches_dequantized_table(model):
 
     def fwd(cfg_, p):
         cache = init_cache(cfg_, 2, 16, dtype=jnp.float32)
-        logits, _ = transformer.prefill(p, cfg_, toks, lens, cache)
+        logits, _ = jitted(transformer.prefill)(p, cfg_, toks, lens, cache)
         return np.asarray(logits)
 
     np.testing.assert_allclose(fwd(qcfg, qparams), fwd(cfg, ref_params),

@@ -13,6 +13,7 @@ import requests
 
 from distributed_llm_inferencing_tpu.runtime.master import Master
 from distributed_llm_inferencing_tpu.runtime.worker import WorkerAgent
+from conftest import stop_worker
 
 
 @pytest.fixture(scope="module")
@@ -21,7 +22,7 @@ def worker():
     srv = agent.serve(host="127.0.0.1", port=0, background=True)
     port = srv.server_address[1]
     yield agent, port
-    agent.service.shutdown()
+    stop_worker(agent)
 
 
 @pytest.fixture()
@@ -124,7 +125,7 @@ def test_worker_auth():
                          headers={"Authorization": "Bearer sekrit"})
         assert r.status_code == 200
     finally:
-        agent.service.shutdown()
+        stop_worker(agent)
 
 
 # ---- master + worker end-to-end --------------------------------------
@@ -291,7 +292,7 @@ def test_ssh_setup_parity(worker):
             assert r.status_code == 501
             assert "paramiko" in r.json()["message"]
     finally:
-        agent.service.shutdown()
+        stop_worker(agent)
 
 
 def test_admin_cli(worker, master):
@@ -384,7 +385,7 @@ def test_master_cancel_frees_worker_slot(master):
             time.sleep(0.2)
         assert st["active"] == 0, st
     finally:
-        agent.service.shutdown()
+        stop_worker(agent)
 
 
 def test_dashboard_pages_surface_serving_internals(master):

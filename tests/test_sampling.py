@@ -12,6 +12,8 @@ descending sort it replaced, kept here as the reference
 sizes too.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -22,7 +24,6 @@ from distributed_llm_inferencing_tpu.ops.sampling import (
     PREFIX_K, SamplingParams, nucleus_mask_sorted, sample, sample_batch,
     warp_logits)
 
-RNG = np.random.default_rng(0)
 
 
 _jit_sample = jax.jit(sample_batch)
@@ -55,14 +56,16 @@ def _draw_many(logits, seed, steps, temp, tk, tp, dtype="float32"):
 
 
 def test_greedy_rows_are_argmax():
-    logits = RNG.normal(size=(4, 300))
+    rng = np.random.default_rng(0)
+    logits = rng.normal(size=(4, 300))
     out = _draw(logits, [1] * 4, [0] * 4, [0.8] * 4, [50] * 4, [0.95] * 4,
                 [False] * 4)
     np.testing.assert_array_equal(out, logits.argmax(-1))
 
 
 def test_sampled_tokens_respect_top_k():
-    logits = RNG.normal(size=(8, 500))
+    rng = np.random.default_rng(0)
+    logits = rng.normal(size=(8, 500))
     for step in range(20):
         out = _draw(logits, list(range(8)), [step] * 8, [1.0] * 8, [5] * 8,
                     [1.0] * 8, [True] * 8)
@@ -84,8 +87,9 @@ def test_row_draw_independent_of_chunk_mates():
     """A covered row (k <= PREFIX_K) must sample the SAME token whether its
     chunk-mates are covered (fast branch) or force the full-vocab branch —
     the scheduler's (params, prompt, seed) purity contract."""
+    rng = np.random.default_rng(0)
     v = PREFIX_K * 4
-    logits = RNG.normal(size=(2, v))
+    logits = rng.normal(size=(2, v))
     for step in range(10):
         fast = _draw(logits, [11, 12], [step] * 2, [0.9] * 2, [50, 50],
                      [0.95] * 2, [True] * 2)
@@ -157,13 +161,26 @@ def _as(x, dtype):
     return jnp.asarray(x, DTYPES[dtype])
 
 
-def _oracle(x, k, p):
+@functools.lru_cache(maxsize=None)
+def _input(kind, r, v, seed=0, dtype="float32"):
+    """``_rows`` and the float64 oracle's stable descending order of each
+    row, once for all the cases over one input (k, p and the form are
+    what differs between them)."""
+    x = _rows(kind, r, v, seed, dtype)
+    x.setflags(write=False)
+    return x, [np.argsort(-row.astype(np.float64), kind="stable")
+               for row in x]
+
+
+def _oracle(x, k, p, order=None):
     """(kept, sure) for one row in float64: the top-k set is {x >= kth},
     a token is kept iff the mass strictly above its value, over that set,
-    is below p; ``sure`` where that mass is further than MARGIN from p."""
+    is below p; ``sure`` where that mass is further than MARGIN from p.
+    ``order``: the row's stable descending order, where a caller has it."""
     x = x.astype(np.float64)
     v = x.shape[0]
-    order = np.argsort(-x, kind="stable")
+    if order is None:
+        order = np.argsort(-x, kind="stable")
     xs = x[order]
     kth = xs[(v if k <= 0 else min(k, v)) - 1]
     in_topk = x >= kth
@@ -206,10 +223,10 @@ def _kept(x, k, p, form, dtype="float32"):
 @pytest.mark.parametrize("k", [0, 1, 129, 500, V])
 @pytest.mark.parametrize("kind", ROW_KINDS)
 def test_kept_set_matches_float64_oracle(kind, k, p, form, dtype):
-    x = _rows(kind, 8, V, dtype=dtype)
+    x, orders = _input(kind, 8, V, dtype=dtype)
     kept, kth, thresh = _kept(x, k, p, form, dtype)
     for r in range(x.shape[0]):
-        want, sure = _oracle(x[r], k, p)
+        want, sure = _oracle(x[r], k, p, orders[r])
         if p == 0.0:        # the top token stays (and what ties with it)
             want = x[r] == x[r].max()
         np.testing.assert_array_equal(kept[r][sure], want[sure])
@@ -240,6 +257,9 @@ def _sorted_full_draw(logits, seeds, steps, temps, top_ks, top_ps):
         lambda kk, l: jax.random.categorical(kk, l))(keys, masked)
 
 
+_jit_sorted_full_draw = jax.jit(_sorted_full_draw)
+
+
 @pytest.mark.parametrize("dtype", list(DTYPES))
 @pytest.mark.parametrize("block, r, v", [
     (0, 64, 8192), (1, 64, 8192), (2, 64, 8192), (3, 64, 8192),
@@ -265,8 +285,8 @@ def test_tokens_equal_the_sorted_form_at_the_cells_settings(block, r, v,
     tps = jnp.full((r,), 0.9, jnp.float32)
     got = np.asarray(_jit_sample(_as(x, dtype), seeds, steps, temps, tks,
                                  tps, jnp.ones((r,), bool)))
-    want = np.asarray(jax.jit(_sorted_full_draw)(x, seeds, steps, temps,
-                                                 tks, tps))
+    want = np.asarray(_jit_sorted_full_draw(x, seeds, steps, temps, tks,
+                                            tps))
     scaled = x / np.float32(0.7)
     sure = np.array([_oracle(scaled[i], 0, 0.9)[1].all() for i in range(r)])
     assert sure.mean() > 0.6
@@ -284,6 +304,14 @@ def _primitives(jaxpr):
 # rows x vocabulary of a decode pass in the benchmark's cells
 CELL_SIZES = [(16, 32000), (64, 128256), (64, 200192), (64, 261120)]
 CELL_IDS = ["mistral", "kanana", "trinity", "falcon-h1"]
+
+
+def _cell_rows(rows, whole):
+    """What a cell-size case pins is the vocabulary (the bit patterns the
+    search walks at that many columns) and the dtype, not 64 rows of it:
+    8 rows (mistral's 16 stay), and the cell's own rows in the one case a
+    vocabulary that ``whole`` picks."""
+    return rows if whole or rows == 16 else 8
 
 
 @pytest.mark.parametrize("name", ["sample_batch", "sample", "warp_logits"])
@@ -353,7 +381,10 @@ def test_thresholds_equal_the_sort_at_the_cells_sizes(rows, vocab, kind,
     float64 oracle is sure (docstring, 1.: within float32 summation
     error of ``top_p`` the boundary token may fall on either side).
     bfloat16: the scaled logits rounded to bf16 and handed to the search
-    as bf16, to the sort as their float32 copy."""
+    as bf16, to the sort as their float32 copy. The cell's whole rows x
+    vocabulary in the bf16-ties bfloat16 case, what a cell's head hands
+    over; 8 rows of the cell's vocabulary in the others."""
+    rows = _cell_rows(rows, (kind, dtype) == ("bf16_ties", "bfloat16"))
     x = _rows(kind, rows, vocab, seed=7) / np.float32(0.7)
     if dtype == "bfloat16":
         x = np.asarray(_as(x, dtype).astype(jnp.float32))
@@ -440,7 +471,10 @@ def test_sixteen_bit_search_equals_the_32_bit_search_bit_for_bit(
     through the row's division to exactly the cuts the 32-bit search
     finds in the float32 ``scaled``. Top-k off, on and mixed in one
     batch, ``top_p`` from 0 to 1; the kept sets are the same set, and so
-    are the sampled tokens."""
+    are the sampled tokens. The cell's whole rows x vocabulary in the
+    bf16-ties case under a temperature a row; 8 rows of the cell's
+    vocabulary in the others."""
+    rows = _cell_rows(rows, (kind, temperature) == ("bf16_ties", "a_row"))
     x = _rows(kind, rows, vocab, seed=5, dtype="bfloat16")
     rng = np.random.default_rng(vocab)
     temps = (jnp.ones((rows,), jnp.float32) if temperature == "none" else

@@ -17,10 +17,10 @@ from distributed_llm_inferencing_tpu.models.registry import get_config
 from distributed_llm_inferencing_tpu.ops.sampling import SamplingParams
 from distributed_llm_inferencing_tpu.ops.speculative import propose_ngram
 from distributed_llm_inferencing_tpu.runtime.engine import InferenceEngine
+from conftest import shared_batcher as Batcher
 
 CFG = get_config("tiny-llama").replace(dtype="float32", attn_backend="xla")
 PARAMS = init_params(CFG, jax.random.PRNGKey(0), dtype=jnp.float32)
-RNG = np.random.default_rng(0)
 
 
 def test_propose_ngram():
@@ -40,7 +40,8 @@ def _engine():
 def test_greedy_speculative_matches_plain_repetitive():
     """Repetitive prompt = high draft acceptance; output must still be
     bit-identical to plain greedy decode."""
-    pattern = RNG.integers(0, CFG.vocab_size, 5).tolist()
+    rng = np.random.default_rng(0)
+    pattern = rng.integers(0, CFG.vocab_size, 5).tolist()
     prompt = (pattern * 4)[:18]
     eng = _engine()
     plain = eng.generate([prompt], max_new_tokens=24,
@@ -54,7 +55,8 @@ def test_greedy_speculative_matches_plain_repetitive():
 def test_greedy_speculative_matches_plain_random():
     """Random prompt = few/no draft hits; correctness must not depend on
     acceptance rate."""
-    prompt = RNG.integers(0, CFG.vocab_size, 13).tolist()
+    rng = np.random.default_rng(0)
+    prompt = rng.integers(0, CFG.vocab_size, 13).tolist()
     eng = _engine()
     plain = eng.generate([prompt], max_new_tokens=16,
                          sampling=SamplingParams.greedy())
@@ -83,7 +85,8 @@ def test_speculative_fewer_steps_on_acceptance():
 
 
 def test_speculative_eos_and_seeding():
-    prompt = RNG.integers(0, CFG.vocab_size, 9).tolist()
+    rng = np.random.default_rng(0)
+    prompt = rng.integers(0, CFG.vocab_size, 9).tolist()
     eng = _engine()
     full = eng.generate([prompt], max_new_tokens=12,
                         sampling=SamplingParams.greedy(),
@@ -110,7 +113,8 @@ def test_speculative_sampling_distribution_preserved():
     sharply peaked next-token distribution and an adversarial draft, the
     emitted first token's empirical frequencies must match plain decode's
     across seeds."""
-    prompt = (RNG.integers(0, CFG.vocab_size, 4).tolist() * 5)[:18]
+    rng = np.random.default_rng(0)
+    prompt = (rng.integers(0, CFG.vocab_size, 4).tolist() * 5)[:18]
     eng = _engine()
     sp = SamplingParams(temperature=1.2, top_k=8, top_p=0.95)
     plain_counts: dict = {}
@@ -323,8 +327,6 @@ def test_batcher_speculative_matches_plain():
     runs exact rejection sampling — right length, deterministic given its
     seed — and draft tokens were accepted on the repetitive prompts."""
     from distributed_llm_inferencing_tpu.ops.sampling import SamplingParams
-    from distributed_llm_inferencing_tpu.runtime.batcher import (
-        ContinuousBatcher)
     cfg = get_config("tiny-llama").replace(dtype="float32",
                                            attn_backend="xla")
     rng = np.random.default_rng(0)
@@ -333,7 +335,7 @@ def test_batcher_speculative_matches_plain():
     arb = rng.integers(0, 256, 9).tolist()
 
     def run(spec):
-        b = ContinuousBatcher(
+        b = Batcher(
             cfg, num_blocks=96, block_size=8, slots=3, max_seq=128, seed=0,
             speculative="ngram" if spec else None, spec_gamma=3)
         reqs = [
@@ -366,16 +368,14 @@ def test_batcher_speculative_sampled_accepts_drafts():
     a lone sampled request on a highly repetitive prompt
     accepts at least one draft token."""
     from distributed_llm_inferencing_tpu.ops.sampling import SamplingParams
-    from distributed_llm_inferencing_tpu.runtime.batcher import (
-        ContinuousBatcher)
     cfg = get_config("tiny-llama").replace(dtype="float32",
                                            attn_backend="xla")
     rng = np.random.default_rng(3)
     base = rng.integers(0, 256, 4).tolist()
     prompt = (base * 6)[:22]
-    b = ContinuousBatcher(cfg, num_blocks=64, block_size=8, slots=2,
-                          max_seq=128, seed=0, speculative="ngram",
-                          spec_gamma=3)
+    b = Batcher(cfg, num_blocks=64, block_size=8, slots=2,
+                max_seq=128, seed=0, speculative="ngram",
+                spec_gamma=3)
     # low temperature peaks the target distribution, so in-pattern drafts
     # carry high acceptance probability (the tiny random-init model's
     # sampled trajectories wander; near-greedy keeps them on-pattern)
@@ -396,15 +396,13 @@ def test_batcher_speculative_lockstep_hist_delta():
     the leader's history rows and cache evolution exactly."""
     import json
     from distributed_llm_inferencing_tpu.ops.sampling import SamplingParams
-    from distributed_llm_inferencing_tpu.runtime.batcher import (
-        ContinuousBatcher)
     cfg = get_config("tiny-llama").replace(dtype="float32",
                                            attn_backend="xla")
     rng = np.random.default_rng(2)
     base = rng.integers(0, 256, 5).tolist()
     prompts = [(base * 5)[:20], rng.integers(0, 256, 7).tolist()]
 
-    mk = lambda: ContinuousBatcher(  # noqa: E731
+    mk = lambda: Batcher(  # noqa: E731
         cfg, num_blocks=64, block_size=8, slots=2, max_seq=96, seed=0,
         speculative="ngram", spec_gamma=3)
     leader, follower = mk(), mk()
@@ -482,8 +480,6 @@ def test_batcher_speculative_sampling_distribution_preserved():
     plain-vs-plain sampling noise floor at the same sample size instead
     of a fixed constant."""
     from distributed_llm_inferencing_tpu.ops.sampling import SamplingParams
-    from distributed_llm_inferencing_tpu.runtime.batcher import (
-        ContinuousBatcher)
     cfg = get_config("tiny-llama").replace(dtype="float32",
                                            attn_backend="xla")
     rng = np.random.default_rng(0)
@@ -492,10 +488,10 @@ def test_batcher_speculative_sampling_distribution_preserved():
     n = 120
 
     def collect(spec, seed0):
-        b = ContinuousBatcher(cfg, num_blocks=256, block_size=8, slots=8,
-                              max_seq=64, seed=0,
-                              speculative="ngram" if spec else None,
-                              spec_gamma=2)
+        b = Batcher(cfg, num_blocks=256, block_size=8, slots=8,
+                    max_seq=64, seed=0,
+                    speculative="ngram" if spec else None,
+                    spec_gamma=2)
         reqs = [b.submit(prompt, max_new_tokens=3, sampling=sp,
                          seed=seed0 + s) for s in range(n)]
         for _ in range(600):
@@ -529,16 +525,14 @@ def test_batcher_speculative_eos_and_stream():
     """eos cuts a speculative run mid-chunk; streamed tokens match kept
     tokens in order."""
     from distributed_llm_inferencing_tpu.ops.sampling import SamplingParams
-    from distributed_llm_inferencing_tpu.runtime.batcher import (
-        ContinuousBatcher)
     cfg = get_config("tiny-llama").replace(dtype="float32",
                                            attn_backend="xla")
     rng = np.random.default_rng(1)
     base = rng.integers(0, 256, 5).tolist()
     prompt = (base * 4)[:18]
 
-    plain = ContinuousBatcher(cfg, num_blocks=64, block_size=8, slots=2,
-                              max_seq=128, seed=0)
+    plain = Batcher(cfg, num_blocks=64, block_size=8, slots=2,
+                    max_seq=128, seed=0)
     r0 = plain.submit(prompt, max_new_tokens=10,
                       sampling=SamplingParams.greedy())
     for _ in range(40):
@@ -549,9 +543,9 @@ def test_batcher_speculative_eos_and_stream():
     eos = full[4]
     want = full[:4] if eos not in full[:4] else None
 
-    b = ContinuousBatcher(cfg, num_blocks=64, block_size=8, slots=2,
-                          max_seq=128, seed=0, speculative="ngram",
-                          spec_gamma=3)
+    b = Batcher(cfg, num_blocks=64, block_size=8, slots=2,
+                max_seq=128, seed=0, speculative="ngram",
+                spec_gamma=3)
     seen = []
     r = b.submit(prompt, max_new_tokens=10,
                  sampling=SamplingParams.greedy(), eos_token_id=eos,

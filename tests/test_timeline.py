@@ -23,7 +23,6 @@ from distributed_llm_inferencing_tpu.utils import clock, trace
 from distributed_llm_inferencing_tpu.utils.profiler import (
     PhaseProfiler, step_phases)
 
-RNG = np.random.default_rng(7)
 GREEDY = SamplingParams.greedy()
 SCOPES = ("kv_gather", "attention", "kv_write", "mlp", "moe_route",
           "moe_experts", "lm_head", "sample")
@@ -36,8 +35,8 @@ def batcher(model="tiny-llama", **kw):
     return ContinuousBatcher(cfg, None, seed=0, **kw)
 
 
-def serve(b, lengths, new_tokens=6, max_steps=400, chunk_cap=0):
-    reqs = [b.submit(RNG.integers(3, b.cfg.vocab_size, n).tolist(),
+def serve(b, rng, lengths, new_tokens=6, max_steps=400, chunk_cap=0):
+    reqs = [b.submit(rng.integers(3, b.cfg.vocab_size, n).tolist(),
                      max_new_tokens=new_tokens, sampling=GREEDY)
             for n in lengths]
     for r in reqs:
@@ -53,9 +52,10 @@ def serve(b, lengths, new_tokens=6, max_steps=400, chunk_cap=0):
 
 @pytest.mark.parametrize("sample_every", [1, 3])
 def test_sampled_step_is_an_ordered_timeline(sample_every):
+    rng = np.random.default_rng(7)
     b = batcher()
     b.profiler = PhaseProfiler(enabled=True, sample_every=sample_every)
-    serve(b, [9, 20, 5])
+    serve(b, rng, [9, 20, 5])
     samples = b.profiler.samples()
     assert samples and any(
         name == "admit_run" for s in samples for name, *_ in s["spans"])
@@ -106,12 +106,13 @@ def test_sampled_step_is_an_ordered_timeline(sample_every):
 
 @pytest.mark.parametrize("enabled", [False, True])
 def test_clocks_run_whether_or_not_the_profiler_does(enabled):
+    rng = np.random.default_rng(7)
     b = batcher()
     b.profiler = PhaseProfiler(enabled=enabled)
     assert b.profiler.clocks() == {"steps": 0, "wall_s": 0.0,
                                    "between_s": 0.0, "phases": {},
                                    "nested": {}}
-    serve(b, [9, 20, 5], chunk_cap=2)
+    serve(b, rng, [9, 20, 5], chunk_cap=2)
     b.step()                            # an idle poll adds nothing
     c = b.profiler.clocks()
     assert c["steps"] >= 2 and c["wall_s"] > 0
@@ -152,17 +153,18 @@ PARTS = ("decode_chunk_ms", "decode_admit_run_ms", "decode_admit_host_ms",
 @pytest.mark.parametrize("model", ["tiny-llama", "tiny-mixtral"])
 @pytest.mark.parametrize("neighbour", [False, True])
 def test_a_request_accounts_for_its_decode_time(model, neighbour):
+    rng = np.random.default_rng(7)
     tr = trace.get_tracer()
     b = batcher(model)
-    serve(b, [9])                       # compile outside the account
+    serve(b, rng, [9])     # compile outside the account
     t_mark = time.time()
-    first = b.submit(RNG.integers(3, b.cfg.vocab_size, 9).tolist(),
+    first = b.submit(rng.integers(3, b.cfg.vocab_size, 9).tolist(),
                      max_new_tokens=30, sampling=GREEDY, eos_token_id=None)
     first.chunk_cap = 4
     b.step()
     assert len(first.tokens) >= 1 and not first.done.is_set()
     if neighbour:                       # admitted while `first` decodes
-        serve(b, [12], new_tokens=2)
+        serve(b, rng, [12], new_tokens=2)
     while not first.done.is_set():
         b.step()
     cost = first.cost
@@ -205,15 +207,16 @@ def host_events(trace_dir):
 
 @pytest.mark.parametrize("enabled", [True, False])
 def test_profiler_trace_holds_the_host_phases(tmp_path, enabled):
+    rng = np.random.default_rng(7)
     b = batcher()
     b.profiler = PhaseProfiler(enabled=enabled)
-    serve(b, [9, 12])                   # compile outside the trace
+    serve(b, rng, [9, 12])     # compile outside the trace
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     opts.host_tracer_level = 1
     jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
     try:
-        serve(b, [9, 12])
+        serve(b, rng, [9, 12])
     finally:
         jax.profiler.stop_trace()
     evs = host_events(tmp_path)
@@ -290,16 +293,17 @@ def test_programs_carry_the_scope_vocabulary(model, program):
 # ---- what an admission cost -------------------------------------------
 
 def test_admit_wave_attributes_add_up():
+    rng = np.random.default_rng(7)
     tr = trace.get_tracer()
     b = batcher()
-    warm = serve(b, [30])               # one slot decodes meanwhile ...
+    warm = serve(b, rng, [30])     # one slot decodes meanwhile ...
     assert warm[0].error is None
     t_mark = time.time()
     before = b.metrics.snapshot()["counters"]["prefill_uncached_tokens"]
-    long_ = b.submit(RNG.integers(3, b.cfg.vocab_size, 20).tolist(),
+    long_ = b.submit(rng.integers(3, b.cfg.vocab_size, 20).tolist(),
                      max_new_tokens=40, sampling=GREEDY)
     b.step()                            # ... when the next wave arrives
-    reqs = serve(b, [9, 17, 3]) + [long_]
+    reqs = serve(b, rng, [9, 17, 3]) + [long_]
     while not long_.done.is_set():
         b.step()
     after = b.metrics.snapshot()["counters"]["prefill_uncached_tokens"]
@@ -341,6 +345,7 @@ def stall_ms(b):
 
 @pytest.mark.parametrize("where", ["none", "program", "host"])
 def test_stall_counters(journal, where):
+    rng = np.random.default_rng(7)
     b = batcher()
     assert stall_ms(b) == (0, 0)
     calls = {"decode": 0}
@@ -357,7 +362,7 @@ def test_stall_counters(journal, where):
         if where == "host" and calls["decode"] == 12 and "slept" not in calls:
             calls["slept"] = True
             time.sleep(0.3)             # the host stands still, once
-    reqs = [b.submit(RNG.integers(3, b.cfg.vocab_size, 9).tolist(),
+    reqs = [b.submit(rng.integers(3, b.cfg.vocab_size, 9).tolist(),
                      max_new_tokens=100, sampling=GREEDY, eos_token_id=None,
                      stream_cb=slow_reader)]
     # chunks of one size only, so the running mean is of like with like
@@ -405,6 +410,7 @@ def _burn(stop):
 @pytest.mark.parametrize("cause", ["host_runtime_busy", "gc", "descheduled",
                                    "interpreter_held"])
 def test_a_stalled_call_says_what_went_on(journal, cause):
+    rng = np.random.default_rng(7)
     tr = trace.get_tracer()
     b = batcher()
     late = _LateClock()
@@ -440,7 +446,7 @@ def test_a_stalled_call_says_what_went_on(journal, cause):
     b.program_hook = hook
     t_mark = time.time()
     try:
-        req = b.submit(RNG.integers(3, b.cfg.vocab_size, 9).tolist(),
+        req = b.submit(rng.integers(3, b.cfg.vocab_size, 9).tolist(),
                        max_new_tokens=100, sampling=GREEDY, eos_token_id=None)
         req.chunk_cap = 4
         while not req.done.is_set():
@@ -534,6 +540,7 @@ def test_profile_summary_splits_idle_time_over_host_phases():
 
 
 def test_profile_summary_prints_the_account(tmp_path):
+    rng = np.random.default_rng(7)
     import importlib.util
     from pathlib import Path
     spec = importlib.util.spec_from_file_location(
@@ -542,7 +549,7 @@ def test_profile_summary_prints_the_account(tmp_path):
     ps = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(ps)
     b = batcher()
-    serve(b, [9, 12], chunk_cap=2)
+    serve(b, rng, [9, 12], chunk_cap=2)
     clocks = b.profiler.clocks()
     want = {**clocks["phases"], **clocks["nested"],
             "between": clocks["between_s"]}

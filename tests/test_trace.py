@@ -11,6 +11,7 @@ import requests
 
 from distributed_llm_inferencing_tpu.utils import trace
 from distributed_llm_inferencing_tpu.utils.trace import SpanCtx, Tracer
+from conftest import stop_worker
 
 
 # ---- span model -------------------------------------------------------
@@ -148,7 +149,7 @@ def cluster():
     msrv = m.service.serve("127.0.0.1", 0, background=True)
     yield (agent, wsrv.server_address[1], m, msrv.server_address[1])
     m.stop()
-    agent.service.shutdown()
+    stop_worker(agent)
 
 
 def _url(port, path):

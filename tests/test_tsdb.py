@@ -13,6 +13,7 @@ from distributed_llm_inferencing_tpu.runtime import tsdb
 from distributed_llm_inferencing_tpu.utils import trace as trace_mod
 from distributed_llm_inferencing_tpu.utils.metrics import parse_prometheus
 from distributed_llm_inferencing_tpu.utils.profiler import PhaseProfiler
+from conftest import stop_worker
 
 T0 = 1_700_000_000.0
 
@@ -387,4 +388,4 @@ def test_master_timeseries_and_cost_endpoint_live():
                    for e in tr["traceEvents"]), "no profiler trace spans"
     finally:
         m.stop()
-        agent.service.shutdown()
+        stop_worker(agent)

@@ -14,6 +14,7 @@ import pytest
 import requests
 
 from distributed_llm_inferencing_tpu.runtime.worker import WorkerAgent
+from conftest import stop_worker
 
 
 @pytest.fixture(scope="module")
@@ -28,7 +29,7 @@ def worker():
     }, timeout=300)
     assert r.status_code == 200, r.text
     yield agent, port
-    agent.service.shutdown()
+    stop_worker(agent)
 
 
 def _url(port, path):
@@ -214,7 +215,7 @@ def test_batched_with_tp_mesh():
         assert r.status_code == 400
         assert "tp/ep" in r.json()["message"]
     finally:
-        agent.service.shutdown()
+        stop_worker(agent)
 
 
 def test_timeout_and_cancel_free_slots():
@@ -283,4 +284,4 @@ def test_timeout_and_cancel_free_slots():
                           json={"request_tag": "nope"}, timeout=10)
         assert c.status_code == 404
     finally:
-        agent.service.shutdown()
+        stop_worker(agent)
