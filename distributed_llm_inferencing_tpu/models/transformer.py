@@ -1537,11 +1537,10 @@ def _pool_kernel(cfg: ModelConfig, paged):
     Pallas call) where ops/pallas/paged_attention.py computes this
     model's attention and reads this pool as it lies -- planes
     unquantized and in the compute dtype; K and V heads of whole lanes
-    that fill a tile's 8 sublanes or divide them (4 or 2 heads lie in
-    (4, 128) or (2, 128) tiles, two or four of which are one (8, 128)
-    tile's bytes; one head: MQA, or a latent pool's one plane of shared
-    rows, stored lane_width wide, which the kernel takes as K and V at
-    once), the query heads any multiple of them; no ALiBi, sinks or
+    that fill a tile's 8 sublanes (one head: MQA, or a latent pool's
+    one plane of shared rows, stored lane_width wide, which the kernel
+    takes as K and V at once; fewer than 8: such a pool holds flat
+    rows, below), the query heads any multiple of them; no ALiBi, sinks or
     softcap; a window that is None or one trace-time integer, and none
     over a latent pool -- else None: the in-loop gather as far as
     _pool_ladder's rung (_attend_pool_rung). The stack may be scanned (the kernel takes the
@@ -1549,16 +1548,19 @@ def _pool_kernel(cfg: ModelConfig, paged):
     constant handed in as an array: one lowering for all of them). A
     model with layer kinds (cfg.swa) is judged by its pool-backed (full)
     layers' own config (kind_cfg), its windowed layers' ring being no
-    part of the pool: the pool then holds flat rows (one row a position,
-    its K/V heads side by side, V's narrower than K's), which the kernel
-    takes where both widths and a value head are whole lanes (a query
-    head's context is then whole lanes of a V row). In
+    part of the pool. A pool of flat rows (ops/paged_kvcache.flat_pool:
+    one row a position, its K/V heads side by side; a model with layer
+    kinds' V rows narrower than its K rows) the kernel takes where both
+    widths and a value head are whole lanes (a query head's context is
+    then whole lanes of a V row). In
     the benchmark's cells the kernel serves mistral-7b, Ouro-2.6B,
     kanana (its latent MQA plane, 7 layers held one by one),
-    falcon-h1 (20 query heads over 4 K/V heads) and mimo-v2.5's two full
+    falcon-h1 (20 query heads over rows of 4 K/V heads: 512 columns)
+    and mimo-v2.5's two full
     layers (rows of 768 and 512 columns under 64 query heads); trinity
     (per-layer windows) keeps the XLA form, as do int8 pools, meshes,
     the speculative chunk and the CPU."""
+    from distributed_llm_inferencing_tpu.ops.paged_kvcache import flat_pool
     attn = cfg if cfg.swa is None else cfg.kind_cfg("full", 1)
     if (not cfg.pool_kernel.startswith("pallas") or paged.quantized
             or cfg.attn_windows is not None
@@ -1571,9 +1573,8 @@ def _pool_kernel(cfg: ModelConfig, paged):
     if not paged_attention.supported(paged.k.shape[3], paged.k.shape[4],
                                      paged.k.dtype):
         return None
-    if cfg.swa is not None and not (
-            paged.k.shape[3] == 1
-            and cfg.v_head_dim_effective % paged_attention.LANES == 0
+    if flat_pool(cfg, paged) and not (
+            cfg.v_head_dim_effective % paged_attention.LANES == 0
             and paged.v.shape[4] == cfg.num_kv_heads
             * cfg.v_head_dim_effective):
         return None
@@ -1603,20 +1604,23 @@ def _flat_rows_q(q, hkv: int, k_rows):
     each head's own values in its K/V head's columns, so that one
     contraction over a whole row is its scores (the other heads' columns
     meet zeros). [R, Sq, H, W]."""
-    from distributed_llm_inferencing_tpu.ops.paged_kvcache import fit_rows
     b, sq, h, hd = q.shape
     wide = jnp.einsum("bqhgd,hk->bqhgkd", q.reshape(b, sq, hkv, h // hkv, hd),
                       jnp.eye(hkv, dtype=q.dtype))
-    return fit_rows(wide.reshape(b, sq, h, hkv * hd), k_rows)
+    wide = wide.reshape(b, sq, h, hkv * hd).astype(k_rows.dtype)
+    pad = k_rows.shape[-1] - hkv * hd   # (a ring's rows: lane_width)
+    return jnp.pad(wide, [(0, 0)] * 3 + [(0, pad)]) if pad else wide
 
 
 def _attend_flat_rows(q, k, v, hkv: int, vd: int, *args, **kw):
     """ops/attention.attend over caches that store a position's K/V
-    heads side by side in ONE row (ops/paged_kvcache.flat_rows: a model
-    with layer kinds), read as they lie: the XLA form (a windowed
-    layer's ring in every program; the full layers' gathered pool rows
-    where _pool_kernel does not take the Pallas kernel, which reads the
-    same rows in the pool by the same expansion of q). ``k``, ``v``:
+    heads side by side in ONE row (ops/paged_kvcache.flat_rows: a
+    one-device pool of few K/V heads, a model with layer kinds' pool and
+    ring), read as they lie: the XLA form (a windowed layer's ring in
+    every program; gathered pool rows where _pool_kernel does not take
+    the Pallas kernel, which reads the same rows in the pool by the
+    same expansion of q: trinity-mini's per-layer windows, the CPU).
+    ``k``, ``v``:
     segments [R, S, 1, W] (W the plane's width: hkv heads' columns, then
     zeros). Each query head goes in zero-expanded to the row's width
     (_flat_rows_q), and of the context that comes back as wide as a V
@@ -1628,12 +1632,18 @@ def _attend_flat_rows(q, k, v, hkv: int, vd: int, *args, **kw):
     costs (0.61 + 0.32 s of an 8 s trace, a layer; PERF.md section 6,
     PR 45). q [R, Sq, H, hd] -> [R, Sq, H, vd]."""
     from distributed_llm_inferencing_tpu.ops.attention import attend
-    b, sq, h, hd = q.shape
-    ctx = attend(_flat_rows_q(q, hkv, k[0]), k, v, *args, scale=hd ** -0.5,
-                 **kw)
+    return _own_columns(
+        attend(_flat_rows_q(q, hkv, k[0]), k, v, *args,
+               scale=q.shape[-1] ** -0.5, **kw), hkv, vd)
+
+
+def _own_columns(ctx, hkv: int, vd: int):
+    """Of a context as wide as a flat V row, [R, Sq, H, W], each query
+    head's own K/V head's columns: [R, Sq, H, vd]."""
+    b, sq, h, _ = ctx.shape
     ctx = ctx[..., :hkv * vd].reshape(b, sq, hkv, h // hkv, hkv, vd)
     return jnp.einsum("bqhgkv,hk->bqhgv", ctx,
-                      jnp.eye(hkv, dtype=q.dtype)).reshape(b, sq, h, vd)
+                      jnp.eye(hkv, dtype=ctx.dtype)).reshape(b, sq, h, vd)
 
 
 def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
@@ -1680,9 +1690,9 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
     what makes the split exact. The pool's segment takes one of two
     forms (``_pool_kernel``, from what the trace can see). *The kernel*
     (a one-device TPU program, unquantized K and V planes whose heads
-    fill or divide a tile's 8 sublanes, a latent pool's one plane of
-    whole lanes, or the flat rows of a model with layer kinds' full
-    layers: mistral-7b, Ouro-2.6B, kanana, falcon-h1, mimo-v2.5):
+    fill a tile's 8 sublanes, a latent pool's one plane of whole lanes,
+    or flat rows, a position's few K/V heads side by side: mistral-7b,
+    Ouro-2.6B, kanana, falcon-h1, mimo-v2.5's full layers):
     ops/pallas/paged_attention.paged_attend reads each live slot's pages
     where the pool lies, by (layer, block-table entry), as far as that
     slot's own context, and keeps both segments' softmax inside the
@@ -1711,13 +1721,16 @@ def paged_decode_chunk(params, cfg: ModelConfig, k: int, tokens, paged,
     columns), the query is padded with zeros to match, and a page's rows
     are fetched once for the scores and the weighted sum.
 
-    A model with layer kinds (cfg.swa) keeps a side buffer a kind and
-    plane: its windowed layers read their slot's ring below the horizon
-    where it lies, in XLA (_attend_flat_rows), and its full layers the
-    pool, whose rows hold a position's heads side by side: by the kernel
-    (q zero-expanded to a K row, _flat_rows_q; a head's own columns of V
-    picked inside the call) or, where it is not taken, by the gather of
-    the whole block table and the same expansion.
+    A pool of flat rows (ops/paged_kvcache.flat_pool: a one-device pool
+    of fewer K/V heads than a tile's 8 sublanes, a model with layer
+    kinds' full layers) keeps side buffers as wide as its rows and is
+    read as it lies: by the kernel (q zero-expanded to a K row,
+    _flat_rows_q; a head's own columns of V picked inside the call) or,
+    where it is not taken, by the gather (a windowed layer's bounded
+    columns, the ladder's rung) and the same expansion
+    (_attend_flat_rows). A model with layer kinds (cfg.swa) keeps a side
+    buffer a kind and plane, and its windowed layers read their slot's
+    ring below the horizon where it lies, in XLA.
 
     tokens: [R] last emitted token per slot; steps0: [R] tokens emitted so
     far. Returns (toks [K, R] int32, emits [K, R] bool, moe int32 [5],
@@ -1755,8 +1768,8 @@ def decode_chunk_with_logits(params, cfg: ModelConfig, k: int, tokens, paged,
     second path (benchmarks/chip/compare_reference_loop.py)."""
     from distributed_llm_inferencing_tpu.ops.attention import attend
     from distributed_llm_inferencing_tpu.ops.paged_kvcache import (
-        PagedKVCache, flat_rows, kind_scope, ring_read, window_read,
-        write_rows)
+        PagedKVCache, flat_pool, flat_rows, kind_scope, ring_read,
+        window_read, write_rows)
     from distributed_llm_inferencing_tpu.ops.sampling import sample_batch
 
     r = tokens.shape[0]
@@ -1769,6 +1782,7 @@ def decode_chunk_with_logits(params, cfg: ModelConfig, k: int, tokens, paged,
     dt = jnp.dtype(cfg.dtype)             # compute dtype (pool may be int8)
     quantized = paged.quantized
     latent = cfg.mla_latent_cache         # one plane of shared rows, no V
+    flat = flat_pool(cfg, paged)          # a position's heads in one row
     n_planes = 1 if latent else 2
     cl0 = context_lens                    # pool horizon, fixed this chunk
     pool_pos = jnp.broadcast_to(jnp.arange(mb * bs, dtype=jnp.int32),
@@ -1798,18 +1812,20 @@ def decode_chunk_with_logits(params, cfg: ModelConfig, k: int, tokens, paged,
         (L, r, k, cfg.cache_kv_heads,
          paged.k.shape[-1] if kernel else cfg.cache_head_dim),
         dt),) * n_planes
+    vd = cfg.v_head_dim_effective
+
+    def sides(planes):   # rows as flat planes store them
+        return tuple(jnp.zeros((p.shape[0], r, k, 1, p.shape[-1]), dt)
+                     for p in planes)
+    if flat:
+        side0 = sides(paged.planes())
     if kinds:
         # a side buffer a kind and plane: the full layers' rows go to the
         # pool after the scan, the windowed layers' to the ring, which
         # holds what lies below the chunk's horizon (ring_read: fixed for
         # the chunk like the pool's), slot r in row r
         assert paged.ring_k.shape[1] == r + 1, (paged.ring_k.shape, r)
-        vd = cfg.v_head_dim_effective
-
-        def sides(planes):   # rows as the caches store them: flat
-            return tuple(jnp.zeros((p.shape[0], r, k, 1, p.shape[-1]), dt)
-                         for p in planes)
-        side0 = sides(paged.planes()) + sides((paged.ring_k, paged.ring_v))
+        side0 = side0 + sides((paged.ring_k, paged.ring_v))
         ring = paged.ring_k.shape[2]
         ring_pos, ring_valid = ring_read(ring, cl0)
     if cfg.ssm is not None:
@@ -1863,11 +1879,11 @@ def decode_chunk_with_logits(params, cfg: ModelConfig, k: int, tokens, paged,
                     # pool and side rows in one softmax inside the call,
                     # the planes taken where they lie at the layer's
                     # index; a latent pool's one plane as K and V alike;
-                    # a full layer's flat rows under q expanded to them,
-                    # a head's own columns of V picked inside the call
+                    # flat rows under q expanded to them, a head's own
+                    # columns of V picked inside the call
                     with jax.named_scope("attention"), \
                             kind_scope("attention_full" if kinds else None):
-                        if kinds:
+                        if flat:
                             q, scale = (_flat_rows_q(q, cfg.num_kv_heads,
                                                      rows[0]),
                                         q.shape[-1] ** -0.5)
@@ -1875,7 +1891,7 @@ def decode_chunk_with_logits(params, cfg: ModelConfig, k: int, tokens, paged,
                             q, pool[0], pool[-1], li, block_tables, cl0,
                             cl0 + t, walk, (rows[0], rows[-1], t),
                             sliding_window=seg_cfg.sliding_window,
-                            scale=scale, v_head_dim=vd if kinds else None,
+                            scale=scale, v_head_dim=vd if flat else None,
                             interpret=kernel == "pallas_interpret")
 
                 def attend_side(q, sd2, sliding_window=None, **kw):
@@ -1887,13 +1903,14 @@ def decode_chunk_with_logits(params, cfg: ModelConfig, k: int, tokens, paged,
                     def attend_pool(got, pos, valid):
                         if latent:   # the rows' own columns (lane_width)
                             got = (got[0][..., :cfg.cache_head_dim],)
-                        if kinds:   # a position's heads lie in one row
+                        if flat:   # a position's heads lie in one row
                             with jax.named_scope("attention"), \
                                     kind_scope(kind):
                                 return _attend_flat_rows(
                                     q, (got[0], sd2[0]), (got[1], sd2[1]),
                                     cfg.num_kv_heads, vd, q_pos,
-                                    (pos, side_pos), (valid, side_valid))
+                                    (pos, side_pos), (valid, side_valid),
+                                    sliding_window=sliding_window, **kw)
                         with jax.named_scope("attention"), kind_scope(kind):
                             # a latent pool's rows stand for K and for V
                             return attend(
@@ -1959,7 +1976,7 @@ def decode_chunk_with_logits(params, cfg: ModelConfig, k: int, tokens, paged,
                 def attend_write(q, kh, vh):
                     if seg_cfg.attn_kind == "swa":
                         return attend_ring(q, kh, vh)
-                    if kinds:
+                    if flat:
                         kh, vh = flat_rows(kh), flat_rows(vh)
                     sd2, rows = _write_side(sd[:n_planes], (kh, vh), t, li)
                     if kinds:
@@ -2095,7 +2112,7 @@ def paged_speculative_chunk(params, cfg: ModelConfig, k: int, gamma: int,
     """
     from distributed_llm_inferencing_tpu.ops.attention import attend
     from distributed_llm_inferencing_tpu.ops.paged_kvcache import (
-        PagedKVCache, write_rows)
+        PagedKVCache, flat_pool, head_rows, write_rows)
     from distributed_llm_inferencing_tpu.ops.speculative import (
         accept_rejection_batch, propose_ngram_device)
 
@@ -2113,6 +2130,7 @@ def paged_speculative_chunk(params, cfg: ModelConfig, k: int, gamma: int,
     E = k * g1                       # side-buffer entries per slot
     dt = jnp.dtype(cfg.dtype)
     quantized = paged.quantized
+    flat = flat_pool(cfg, paged)
     cl0 = context_lens
     H = history.shape[1]
 
@@ -2153,6 +2171,9 @@ def paged_speculative_chunk(params, cfg: ModelConfig, k: int, gamma: int,
                     sd2, (sk2, sv2) = _write_side(sd, (kh, vh), t * g1, li)
 
                     def attend_pool(got, pos, valid):
+                        if flat:   # side buffers keep the heads' axis
+                            got = tuple(head_rows(g_, *sk2.shape[-2:])
+                                        for g_ in got)
                         with jax.named_scope("attention"):
                             return attend(
                                 q, (got[0], sk2), (got[1], sv2), qp,
@@ -2419,7 +2440,8 @@ def paged_prefill_tail(params, cfg: ModelConfig, tokens, tail_len,
     them back in place, so no [L, B, ...] stack of states is ever held.
     """
     from distributed_llm_inferencing_tpu.ops.paged_kvcache import (
-        PagedKVCache, paged_attend_prefix, write_blocks)
+        PagedKVCache, flat_pool, flat_rows, paged_attend_prefix,
+        write_blocks)
     if cfg.swa is not None:
         return _kinds_prefill_tail(params, cfg, tokens, tail_len,
                                    tail_blocks, prefix_blocks, prefix_len,
@@ -2436,6 +2458,7 @@ def paged_prefill_tail(params, cfg: ModelConfig, tokens, tail_len,
     x = embed(params, cfg, tokens, q_pos)
 
     has_ssm = cfg.ssm is not None
+    flat = flat_pool(cfg, paged)   # (its planes' shapes stay: the loop's too)
     if has_ssm:
         from distributed_llm_inferencing_tpu.ops import ssm
         fresh = prefix_len == 0
@@ -2486,13 +2509,29 @@ def paged_prefill_tail(params, cfg: ModelConfig, tokens, tail_len,
 
             def attend_write(q, k, v):
                 win = _layer_window(seg_cfg, lp)
+                scale = None
+                if flat:
+                    # a position's heads in one row: the tail attends its
+                    # prefix's rows as they lie and its own alike, q
+                    # zero-expanded to a row (4x the products of the view
+                    # by heads, on a tail of 512; but with that view,
+                    # head_rows of the gathered prefix, trinity-mini's
+                    # wave of 2 rows over 256 prefix blocks halted a v5e
+                    # core in the windowed layers' bounded gather, `Core
+                    # halted unexpectedly`, as PR 38's did: PERF.md
+                    # section 6, PR 49), and hands its rows out flat
+                    hkv, hd, vd = k.shape[2], q.shape[-1], v.shape[-1]
+                    k, v = flat_rows(k), flat_rows(v)
+                    q, scale = _flat_rows_q(q, hkv, k), hd ** -0.5
                 attn = paged_attend_prefix(
                     q, k, v, paged.k, paged.v, prefix_blocks, prefix_len,
                     q_pos, tail_valid, sliding_window=win,
                     k_scale_layer=paged.k_scale, v_scale_layer=paged.v_scale,
                     alibi=_alibi(seg_cfg), softcap=seg_cfg.attn_softcap,
                     sinks=_sinks(seg_cfg, lp), kind=_layer_kind(cfg, win),
-                    layer=li)
+                    layer=li, scale=scale)
+                if flat:
+                    attn = _own_columns(attn, hkv, vd)
                 if not paged.quantized:
                     return attn, (k, v)
                 # store int8 + scales; the tail attended its own fresh

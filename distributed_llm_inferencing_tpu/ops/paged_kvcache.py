@@ -10,7 +10,17 @@ vLLM/PagedAttention:
   layer (L = cfg.cache_planes: a looped model, cfg.loop_steps > 1, has a
   plane a (step, layer) pair, step-major). Which blocks a sequence owns
   is *host-side* state, managed by the native C++ allocator
-  (native/src/block_pool.cc) with ref-counted radix prefix sharing. An
+  (native/src/block_pool.cc) with ref-counted radix prefix sharing. A
+  one-device pool of fewer K/V heads than a tile has sublanes (whole
+  lanes wide, unquantized: trinity-mini's and falcon-h1's 4 x 128)
+  stores a position's heads side by side in ONE row,
+  [L, NB, bs, 1, Hkv * hd] (heads_in_rows, flat_rows): the same bytes
+  in the same order, in (8, 128) tiles of (positions, columns) that a
+  wave's scatter and a chunk's gather take where they lie; writers hand
+  rows over by heads (fit_rows), readers take the rows as they lie
+  (transformer._attend_flat_rows, the paged kernel's flat-rows form) or
+  view them by heads (head_rows), and the host arena and the wire keep
+  a block by heads (runtime/batcher.py _host_pages). An
   MLA model's pool is latent (cfg.mla_latent_cache):
   ``k`` alone, [L, NB, bs, 1, lane_width(rd + r)], one shared row a
   token a layer (transformer._mla_latent_rows) in the first rd + r
@@ -71,12 +81,12 @@ is a cost of its own beside attention (``kv_gather`` in PERF.md section
 5). ops/pallas/paged_attention.py reads the pages where they lie
 instead, each slot as far as its own context: the decode chunks of a
 one-device TPU program take it where the pool's shape allows
-(models/transformer.py _pool_kernel: mistral-7b, Ouro-2.6B, falcon-h1
-(4 K/V heads: half a tile of heads a position), its latent plane's
-rows fetched once as K and V alike, kanana, and mimo-v2.5's full layers
-(flat_rows: rows of 768 columns of K and 512 of V) among the
-benchmark's cells; PERF.md section 6, PRs 40, 42, 43 and 46),
-everything else keeps the gather.
+(models/transformer.py _pool_kernel: mistral-7b, Ouro-2.6B, its latent
+plane's rows fetched once as K and V alike, kanana, and flat rows,
+falcon-h1's (4 K/V heads of 128: rows of 512) and mimo-v2.5's full
+layers' (rows of 768 columns of K and 512 of V) among the benchmark's
+cells; PERF.md section 6, PRs 40, 42, 43, 46 and 49), everything else
+keeps the gather.
 
 The reference framework has no counterpart at any level — its KV cache was
 implicit inside HF ``generate`` (SURVEY.md §2.4).
@@ -112,10 +122,13 @@ def lane_width(w: int) -> int:
 
 
 def fit_rows(rows, plane):
-    """``rows`` [..., w] in ``plane``'s dtype and at its row width: zeros
-    after a latent pool's rd + r columns (lane_width), nothing to do for
-    any other plane."""
+    """``rows`` [..., H, w] in ``plane``'s dtype, row form and row width:
+    a position's heads side by side where the plane stores them so
+    (flat_rows), zeros after a latent pool's rd + r columns
+    (lane_width), nothing to do for any other plane."""
     rows = rows.astype(plane.dtype)
+    if plane.shape[-2] == 1 and rows.shape[-2] != 1:
+        rows = flat_rows(rows)
     pad = plane.shape[-1] - rows.shape[-1]
     if pad == 0:
         return rows
@@ -124,8 +137,9 @@ def fit_rows(rows, plane):
 
 def flat_rows(rows):
     """[..., H, w] -> [..., 1, H * w]: a position's heads side by side
-    in one row, as the pool and ring of a model with layer kinds store
-    them (init_paged_cache)."""
+    in one row, as a one-device pool of fewer K/V heads than a tile has
+    sublanes and the pool and ring of a model with layer kinds store
+    them (init_paged_cache, heads_in_rows)."""
     return rows.reshape(*rows.shape[:-2], 1, -1)
 
 
@@ -135,8 +149,43 @@ def head_rows(flat, heads: int, w: int):
     return flat[..., 0, :heads * w].reshape(*flat.shape[:-2], heads, w)
 
 
+def heads_in_rows(cfg: ModelConfig, devices: int = 1) -> bool:
+    """Whether ``cfg``'s block pool over ``devices`` devices stores a
+    position's K/V heads side by side in ONE row, [L, NB, bs, 1, Hkv *
+    w] (flat_rows), and not as an axis, [L, NB, bs, Hkv, w]: the rule,
+    read from the pool's shape alone. An (8, 128) tile's second-minor
+    axis is the heads' where they are an axis, and fewer than 8 of them
+    leave it part empty: XLA stores such planes in (Hkv, 128) tiles and
+    re-tiles them whole, there and back, around every write of whole
+    blocks and ahead of every gather that attention reads in (8, 128)
+    tiles (four copies of a plane an admit program and two a decode
+    chunk at trinity-mini's 4 heads, four an admit program at
+    falcon-h1's; PERF.md section 6, PRs 38, 45 and 49). With the heads
+    side by side the tile's axes are (positions, columns), as a latent
+    pool's are, and the wave's scatter and the chunk's gather take the
+    planes where they lie. So: an unquantized pool (an int8 pool's
+    scale planes have a head axis and no width), no latent one (one
+    shared row already), heads of whole lanes (lane_width pads a row,
+    not a head), on one device (a mesh shards the head axis:
+    parallel/sharding.paged_cache_specs). (A model with layer kinds,
+    cfg.swa, keeps flat rows whatever its shapes: init_paged_cache.)"""
+    return (devices == 1 and cfg.kv_quant is None
+            and not cfg.mla_latent_cache and 1 < cfg.cache_kv_heads < 8
+            and cfg.cache_head_dim % LANES == 0)
+
+
+def flat_pool(cfg: ModelConfig, paged) -> bool:
+    """Whether ``paged``'s K and V planes hold flat rows (heads_in_rows;
+    the full layers' pool of a model with layer kinds), read from their
+    shape: one row a position where the model has several K/V heads and
+    no latent pool."""
+    return (not cfg.mla_latent_cache and paged.k.shape[3] == 1
+            and cfg.num_kv_heads > 1)
+
+
 class PagedKVCache(NamedTuple):
-    k: jax.Array   # [L, NB, bs, Hkv, hd] (model dtype, or int8)
+    k: jax.Array   # [L, NB, bs, Hkv, hd] (model dtype, or int8), or
+    #                [L, NB, bs, 1, Hkv * hd] (heads_in_rows)
     v: Optional[jax.Array] = None   # like k; None in a latent pool
     # per-token-per-head scales, present iff cfg.kv_quant == "int8"
     # (ops/kvcache.py quant_kv scheme): [L, NB, bs, Hkv] f32
@@ -246,10 +295,13 @@ def ring_take(t: int, tail_len, prefix_len, slots, dummy_row: int,
 
 
 def init_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
-                     dtype=None, slots: int = 0) -> PagedKVCache:
+                     dtype=None, slots: int = 0,
+                     devices: int = 1) -> PagedKVCache:
     """The block pool and, for a model with state layers (cfg.ssm), a
     state row and a conv row for each of ``slots`` serving slots and one
-    dummy row behind them."""
+    dummy row behind them. ``devices``: how many the pool is laid over
+    (the batcher's mesh); heads_in_rows says from it and the pool's
+    shape which form the K and V planes take."""
     dtype = dtype or jnp.dtype(cfg.dtype)
     if cfg.swa is not None:
         # the full layers' pool and the windowed layers' ring
@@ -270,7 +322,7 @@ def init_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
             raise ValueError("state layers keep an unquantized K and V pool")
         c = cfg.ssm
         pool = init_paged_cache(cfg.replace(ssm=None), num_blocks,
-                                block_size, dtype)
+                                block_size, dtype, devices=devices)
         return pool._replace(
             ssm=jnp.zeros((cfg.num_layers, slots + 1, c.n_heads, c.d_head,
                            c.d_state), jnp.float32),
@@ -287,6 +339,8 @@ def init_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
             v_scale=jnp.zeros(shape[:-1], jnp.float32))
     if cfg.kv_quant is not None:
         raise ValueError(f"unknown kv_quant mode {cfg.kv_quant!r}")
+    if heads_in_rows(cfg, devices):
+        shape = shape[:-2] + (1, shape[-2] * shape[-1])
     return PagedKVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
 
 
@@ -358,13 +412,14 @@ def write_blocks(plane, rows, block_ids, first_plane=None):
     paged_prefill_tail) ``rows`` are that step's layers alone and go to
     planes [first_plane, first_plane + L) of the [T * L, ...] stack.
 
-    Where the heads leave the tile's second-minor axis part empty
-    (trinity's 4 of 8) XLA re-tiles both planes for this window and
-    copies them back: four passes over a plane a wave that a scatter by
-    position (write_rows) would not make. It is not taken: with it the
-    wave's prefix gather reads the (4, 128)-tiled pool directly, and
-    that program (tail 512 over 256 prefix blocks, 2 rows) halted the
-    core on a v5e (PERF.md section 6, PR 38)."""
+    The scatter moves whole blocks of (positions, columns) tiles where
+    the plane stores a position's heads in one row (heads_in_rows) or
+    has 8 heads or more. A head axis of 4 half-fills the tile's
+    second-minor axis: XLA re-tiled both planes for this window and
+    copied them back, four passes over a plane a wave, and a scatter by
+    position (write_rows) in its place halted the core on a v5e
+    (PERF.md section 6, PRs 38 and 49): such pools are flat now, or
+    sharded over a mesh."""
     L, bs = rows.shape[0], plane.shape[2]
     b, t = rows.shape[1:3]
     ids = block_ids.reshape(-1)
@@ -450,7 +505,7 @@ def paged_attend_decode(q, cache_k_layer, cache_v_layer, block_tables,
     chunks read the pool in their own way (models/transformer.py
     _pool_kernel: the Pallas paged kernel over K and V planes whose
     heads fill a tile's sublanes or divide them, a latent pool's one
-    plane or a model with layer kinds' flat rows, where it was measured
+    plane or flat rows, where it was measured
     at 1.5-6.2 times the gather's speed,
     PERF.md section 5; the in-loop gather elsewhere).
 
@@ -462,9 +517,16 @@ def paged_attend_decode(q, cache_k_layer, cache_v_layer, block_tables,
     with jax.named_scope("kv_gather"):
         # a latent pool's rows are K and V at once, gathered once, and
         # as wide as q_eff in their first columns (lane_width)
-        k = gather_seq(cache_k_layer, block_tables)[..., :q.shape[-1]]
-        v = (k if cache_v_layer is cache_k_layer
-             else gather_seq(cache_v_layer, block_tables))
+        k = gather_seq(cache_k_layer, block_tables)
+        if cache_v_layer is cache_k_layer:
+            v = k = k[..., :q.shape[-1]]
+        else:
+            v = gather_seq(cache_v_layer, block_tables)
+            if k.shape[-1] != q.shape[-1]:
+                # a pool that stores a position's heads in one row
+                # (flat_rows)
+                k, v = (head_rows(g, g.shape[-1] // q.shape[-1],
+                                  q.shape[-1]) for g in (k, v))
         if k_scale_layer is not None:
             from distributed_llm_inferencing_tpu.ops.kvcache import (
                 dequant_kv)
@@ -488,7 +550,7 @@ def paged_attend_prefix(q, k_new, v_new, cache_k_layer, cache_v_layer,
                         k_scale_layer=None, v_scale_layer=None,
                         alibi=None, softcap: Optional[float] = None, sinks=None,
                         expand_rows=None, kind: Optional[str] = None,
-                        layer=None):
+                        layer=None, scale: Optional[float] = None):
     """Tail-prefill attention: fresh tail K/V plus a cached prefix.
 
     This is what makes prefix-cache hits save *compute*, not just memory:
@@ -516,6 +578,11 @@ def paged_attend_prefix(q, k_new, v_new, cache_k_layer, cache_v_layer,
     [L, NB, ...] ones and the prefix is gathered by (layer, block): the
     tail is never read back from the pool, so the caller may write it
     after the whole stack (transformer.paged_prefill_tail).
+
+    A pool of flat rows (heads_in_rows) is attended as it lies: ``q``
+    comes zero-expanded to a row, ``k_new`` / ``v_new`` flat as the
+    gathered prefix is, and ``scale`` is the head's own
+    (transformer._flat_rows_q, _own_columns).
     """
     b, t = q.shape[0], q.shape[1]
     bs = cache_k_layer.shape[1 if layer is None else 2]
@@ -537,7 +604,10 @@ def paged_attend_prefix(q, k_new, v_new, cache_k_layer, cache_v_layer,
     if expand_rows is not None:
         kp, vp = expand_rows(kp)
     elif kp.shape[-2:] != k_new.shape[-2:]:
-        # a pool that stores a position's heads in one row (flat_rows)
+        # a pool that stores a position's heads in one row (flat_rows),
+        # viewed by heads (a model with layer kinds: its K and V rows
+        # differ in width; transformer.paged_prefill_tail hands a flat
+        # pool's tail over flat, and no view is taken)
         kp = head_rows(kp, *k_new.shape[-2:])
         vp = head_rows(vp, *v_new.shape[-2:])
     if read is None:
@@ -550,4 +620,4 @@ def paged_attend_prefix(q, k_new, v_new, cache_k_layer, cache_v_layer,
                       (vp, v_new.astype(vp.dtype)), q_positions,
                       (prefix_pos, q_positions), (prefix_valid, tail_valid),
                       sliding_window=sliding_window, alibi=alibi,
-                      softcap=softcap, sinks=sinks)
+                      softcap=softcap, sinks=sinks, scale=scale)

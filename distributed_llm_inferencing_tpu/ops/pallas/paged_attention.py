@@ -21,16 +21,17 @@ lies and as far as each slot's own context:
   one a slot. The list is made once a chunk, from the lengths the pool
   holds for the whole chunk.
 - A page's rows are (position, kv head) pairs, 128 or 256 of them (64
-  at falcon-h1's 4 heads: an (8, 128) tile of rows is then two
+  at 4 heads as an axis: an (8, 128) tile of rows is then two
   positions' heads, where XLA stores (4, 128) tiles of one position's;
-  the bytes and their order are the same, ``supported``). One
+  the bytes and their order are the same, ``supported``; a one-device
+  batcher's pool of so few heads holds flat rows instead, below). One
   MXU product of all query heads against those rows as they lie,
   ``[H, hd] x [rows, hd]^T``, gives every (query head, kv head) pair; the
   pairs whose kv head is not the query head's own are masked to -inf, so
   their probabilities are exactly 0 and ``p @ V`` over the same rows is
   the grouped-query sum. No head is ever sliced out of a page (a strided
   sublane read) and G query heads share their kv head's rows at no cost,
-  G a power of two or not (falcon-h1: 20 query heads over 4).
+  G a power of two or not.
 - The mathematics is ops/attention.attend's: bf16 K and V as stored,
   float32 scores, one online softmax in float32, float32 probabilities
   times V in float32 (the probabilities go through the MXU as three bf16
@@ -48,12 +49,15 @@ lies and as far as each slot's own context:
   a call whose whole-block q, output and softmax state pass Mosaic's
   scoped VMEM (kanana's 64 slots x 32 heads x 640: 21 MiB) asks for
   what it needs and starts and finishes its slots in a loop.
-- Flat rows (a model with layer kinds, MiMo-V2: ops/paged_kvcache.py
-  ``flat_rows``) are the same walk again over K and V planes
+- Flat rows (ops/paged_kvcache.py ``flat_rows``: a one-device pool of
+  fewer K/V heads than a tile has sublanes, a model with layer kinds)
+  are the same walk again over K and V planes
   ``[L, NB, bs, 1, Wk]`` and ``[L, NB, bs, 1, Wv]`` whose one row a
   position holds its K/V heads side by side, a head a column offset,
-  and whose widths differ (mimo-v2.5: 4 heads of 192 and of 128, rows
-  of 768 and 512 columns, a page 24 KB + 16 KB). Pages, steps, items
+  and whose widths may differ (mimo-v2.5: 4 heads of 192 and of 128,
+  rows of 768 and 512 columns, a page 24 KB + 16 KB; falcon-h1: 4 of
+  128 and of 128, rows of 512 under 20 query heads, 5 a K/V head).
+  Pages, steps, items
   and the two buffers are sized from each plane's own row bytes. The
   query comes zero-expanded to a K row (each query head's values in its
   own K/V head's columns), so the one product ``[H, Wk] x [rows, Wk]^T``
@@ -513,11 +517,11 @@ def paged_attend(q, k_planes, v_planes, plane, block_tables, context_lens,
     columns past it are zeros and so are q's; the context comes back
     ``w`` wide. Pass ``scale``: the default is the row's width's.
 
-    Flat rows (``v_head_dim``: a model with layer kinds,
-    ops/paged_kvcache.flat_rows): k_planes [L, NB, bs, 1, Wk], v_planes
-    [L, NB, bs, 1, Wv], a position's K/V heads side by side in its one
-    row of each, ``Wv // v_head_dim`` of them, Wk and Wv whole lanes and
-    not the same; side_k and side_v as wide as their planes' rows. ``q``
+    Flat rows (``v_head_dim``; ops/paged_kvcache.flat_rows): k_planes
+    [L, NB, bs, 1, Wk], v_planes [L, NB, bs, 1, Wv], a position's K/V
+    heads side by side in its one row of each, ``Wv // v_head_dim`` of
+    them, Wk and Wv whole lanes, the same (falcon-h1) or not
+    (mimo-v2.5); side_k and side_v as wide as their planes' rows. ``q``
     [R, 1, H, Wk] comes zero-expanded (each query head's values in its
     own K/V head's columns, zeros in the others': one contraction over
     the row is then that head's scores), ``scale`` is the head's own,

@@ -58,7 +58,7 @@ from distributed_llm_inferencing_tpu.models.params import init_params
 from distributed_llm_inferencing_tpu.native import BlockPool
 from distributed_llm_inferencing_tpu.ops import kvblock_quant as kvq
 from distributed_llm_inferencing_tpu.ops.paged_kvcache import (
-    init_paged_cache, window_columns)
+    flat_pool, flat_rows, head_rows, init_paged_cache, window_columns)
 from distributed_llm_inferencing_tpu.ops.sampling import (
     PREFIX_K, SamplingParams, sample_batch)
 from distributed_llm_inferencing_tpu.parallel import sharding as shd
@@ -641,8 +641,18 @@ class ContinuousBatcher:
         # not read "0 free blocks" as exhaustion
         self.metrics.gauge("batcher_free_kv_blocks", self.pool.free_count())
         self.paged = jax.device_put(
-            init_paged_cache(cfg, num_blocks + 1, block_size, slots=slots),
+            init_paged_cache(cfg, num_blocks + 1, block_size, slots=slots,
+                             devices=self.mesh_spec.num_devices),
             shd.named(self.mesh, shd.paged_cache_specs(cfg, self.mesh_spec)))
+        # a one-device pool of few K/V heads stores a position's heads in
+        # one row (ops/paged_kvcache.heads_in_rows); the host arena, the
+        # wire and migration keep a block by heads, [L, bs, Hkv, w],
+        # whatever the device's form (_host_pages, _run_restore): an int8
+        # arena's scales stay a head's, and peers of either form
+        # exchange blocks. (A model with layer kinds has no arena.)
+        self._flat_heads = ((cfg.num_kv_heads, cfg.head_dim)
+                            if cfg.swa is None and flat_pool(cfg, self.paged)
+                            else None)
         # state layers: what a slot holds beside its blocks (0 for a
         # model without them), true prompt positions through the chunked
         # scan, and live slots x decode passes through the one-step update
@@ -1744,10 +1754,7 @@ class ContinuousBatcher:
         if not keep:
             return
         w0 = clock.now()
-        idx = np.asarray([ev[j][0] for j in keep], np.int32)
-        leaves = [lf for lf in self.paged if lf is not None]
-        with self.mesh:
-            pages = jax.device_get([lf[:, idx] for lf in leaves])
+        pages = self._host_pages([ev[j][0] for j in keep])
         stored = 0
         nbytes = 0
         for col, j in enumerate(keep):
@@ -1764,6 +1771,19 @@ class ContinuousBatcher:
         trace.get_tracer().record(
             "batcher.kv_offload", w0, clock.now(),
             attrs={"blocks": len(ev), "stored": stored})
+
+    def _host_pages(self, blocks):
+        """Blocks ``blocks`` of every paged-cache leaf, device to host
+        (a blocking sync), by heads: [L, n, bs, Hkv, w] a leaf, the
+        arena's and the wire's form (a flat pool's rows viewed so on
+        the host, where it is no copy; _run_restore is the way back)."""
+        idx = np.asarray(blocks, np.int32)
+        leaves = [lf for lf in self.paged if lf is not None]
+        with self.mesh:
+            pages = jax.device_get([lf[:, idx] for lf in leaves])
+        if self._flat_heads:
+            pages = [head_rows(pg, *self._flat_heads) for pg in pages]
+        return pages
 
     def _restore_jit(self, b: int, nleaves: int):
         """Scatter ``b`` restored blocks back into every paged-cache
@@ -1797,6 +1817,8 @@ class ContinuousBatcher:
             # one C-level stack per leaf, not a python copy per page —
             # this runs on the scheduler thread between decode chunks
             stacked = np.stack([pg[j] for pg in pages], axis=1)
+            if self._flat_heads:   # as the device stores them
+                stacked = flat_rows(stacked)
             if b == nb and stacked.dtype == lf.dtype:
                 vals.append(stacked)
                 continue
@@ -1976,7 +1998,9 @@ class ContinuousBatcher:
         if fetcher is None:
             return 0
         live = [lf for lf in self.paged if lf is not None]
-        expect = [((lf.shape[0],) + tuple(lf.shape[2:]), lf.dtype)
+        # (a flat pool's blocks travel by heads: _host_pages)
+        expect = [((lf.shape[0], lf.shape[2])
+                   + tuple(self._flat_heads or lf.shape[3:]), lf.dtype)
                   for lf in live]
         w0 = clock.now()
         blocks = bytes_in = 0
@@ -2133,10 +2157,7 @@ class ContinuousBatcher:
         if not keep:
             return
         w0 = clock.now()
-        idx = np.asarray([req._blocks[i] for i in keep], np.int32)
-        leaves = [lf for lf in self.paged if lf is not None]
-        with self.mesh:
-            pages = jax.device_get([lf[:, idx] for lf in leaves])
+        pages = self._host_pages([req._blocks[i] for i in keep])
         stored = 0
         for col, i in enumerate(keep):
             cols = [p[:, col] for p in pages]

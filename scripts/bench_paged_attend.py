@@ -19,7 +19,8 @@ query heads over 8 K/V heads, 1025 blocks, 128 block-table columns,
 window 4096; Ouro 8 slots x 16 heads, 321 blocks, 40 columns; falcon-h1
 at 6 layers (12 planes: K and V) 64 slots x 20 query heads over 4 K/V
 heads (a group of 5, half a tile of heads a position), 4097 blocks, 64
-columns; kanana 64 slots x 32 heads over ONE plane of shared rows 640
+columns, and the same as its one-device pool lies since PR 49, flat rows
+of 512 (``falcon-h1-34b-l6.flat``); kanana 64 slots x 32 heads over ONE plane of shared rows 640
 wide (a latent pool: K and V at once, the query 576 wide), 10,241
 blocks, 160 columns, its layers held one by one (the XLA form's ladder
 is the full extent alone); mimo-v2.5 at its cell's 2 full layers (2
@@ -141,7 +142,8 @@ def forms(bs, mb, window, interpret, latent=False, flat=None):
             if flat:
                 return pa.paged_attend(
                     _flat_rows_q(q, flat[0], k), k, v, plane, bt, cl,
-                    cl + t, walk, (sk, side_v[plane], t), scale=SCALE,
+                    cl + t, walk, (sk, side_v[plane], t),
+                    scale=q.shape[-1] ** -0.5,   # _attend_flat_rows'
                     v_head_dim=flat[1], interpret=interpret)
             return pa.paged_attend(
                 q, k, v, plane, bt, cl, cl + t, walk,
@@ -210,6 +212,9 @@ def main():
          [("full", 8, 640, 640), ("cot-sat", 8, 249, 576)]),
         ("falcon-h1-34b-l6", 6, 64, 20, 4, (128, 128), 4097, 64, None,
          [("full", 64, 1024, 1024), ("chat-sat", 64, 130, 1024)]),
+        # ... and as its one-device pool lies since PR 49: flat rows of 512
+        ("falcon-h1-34b-l6.flat", 6, 64, 20, 4, ((128, 128), 128), 4097, 64,
+         None, [("full", 64, 1024, 1024), ("chat-sat", 64, 130, 1024)]),
         ("kanana-2-30b-a3b-l7", 7, 64, 32, 1, (640, 576), 10241, 160, None,
          [("full", 64, 2560, 2560), ("reason-sat", 64, 65, 1600)]),
         ("mimo-v2.5-l7", 2, 64, 64, 4, ((192, 128), 192), 20481, 320, None,

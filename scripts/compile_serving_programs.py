@@ -31,7 +31,11 @@ with shapes from
 what ``compiled.memory_analysis()`` says each device must hold, the
 collectives in the program text, and every instruction that yields a
 pool-sized or plane-sized array (``pool_sized``: a program that reads
-the pool where it lies has one scatter a plane and nothing else). With ``TEXT_DIR=<dir>`` each program's
+the pool where it lies has one scatter a plane and nothing else, which
+since PR 49 holds for every target: trinity's and falcon-h1's pools of
+4 K/V heads lie as flat rows of 512, ops/paged_kvcache.heads_in_rows,
+and falcon-h1's programs list the state plane's update beside the
+scatters). With ``TEXT_DIR=<dir>`` each program's
 text goes there too, less what names source lines (each instruction's
 ``metadata={...}``, the tables of files, functions and stack frames at
 the top, and the call-site locations inside a Mosaic kernel's
@@ -261,8 +265,8 @@ def main(widths):
                                 mesh, P(*s.sharding.spec[1:]))),
                         params[name]) for _ in range(n)]
         paged = described(
-            jax.eval_shape(lambda: init_paged_cache(cfg, BLOCKS + 1, BLOCK,
-                                                    slots=SLOTS)),
+            jax.eval_shape(lambda: init_paged_cache(
+                cfg, BLOCKS + 1, BLOCK, slots=SLOTS, devices=mesh.size)),
             shd.paged_cache_specs(cfg, spec))
         replicated = NamedSharding(mesh, P())
 

@@ -194,6 +194,99 @@ def paged_prefill_tail_per_layer_write(params, cfg, tokens, tail_len,
     return tf.unembed(params, cfg, last_x)[:, 0], PagedKVCache(*cache_out)
 
 
+# ---- a one-device pool's flat rows against the heads' own axis ----------
+# ops/paged_kvcache.heads_in_rows: a one-device pool of fewer K/V heads
+# than a tile has sublanes stores a position's heads side by side in one
+# row; the same pool laid over a mesh keeps the heads as an axis. The
+# scenario below runs the serving programs over either and hands back
+# what they computed, the planes viewed by heads: tests hold the two
+# the same (tests/test_paged.py, test_trinity.py, test_falcon_h1.py).
+
+def flat_rows_scenario(params, cfg, devices, *, k=4, speculative=False):
+    """A wave's write (two prompts and a padding row), then a second wave
+    (a chunked prompt's next chunk over its own blocks and, where the
+    model matches prefixes, a tail over the first prompt's blocks: a
+    prefix hit), then a decode chunk of ``k`` passes (or a speculative
+    one) over the three slots, on a pool made for ``devices`` devices.
+    Returns (first wave's logits, second wave's, the chunk's outputs
+    less the pool, the K and V planes by heads less the reserved
+    block)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from distributed_llm_inferencing_tpu.models import transformer as tf
+    from distributed_llm_inferencing_tpu.ops.paged_kvcache import (
+        flat_pool, head_rows, init_paged_cache)
+    bs, mb, r = 8, 8, 3
+    paged = init_paged_cache(cfg, 40, bs, slots=r, devices=devices)
+    assert flat_pool(cfg, paged) == (devices == 1)
+    rng = np.random.default_rng(0)
+    state = cfg.slot_cache    # per-slot state: a wave row names its slot
+
+    def admit(tokens, tail_len, tail_blocks, prefix_blocks, prefix_len,
+              slots, paged):
+        fn = jax.jit(lambda *a: tf.paged_prefill_tail(
+            params, cfg, *a[:-1], slots=a[-1] if state else None))
+        return fn(*(jnp.asarray(x, jnp.int32) for x in (
+            tokens, tail_len, tail_blocks, prefix_blocks, prefix_len)),
+            paged, jnp.asarray(slots, jnp.int32))
+
+    toks = rng.integers(3, cfg.vocab_size, (3, 16))
+    logits1, paged = admit(toks, [16, 9, 1], [[1, 2], [3, 4], [0, 0]],
+                           [[0, 0]] * 3, [0, 0, 0], [0, 1, r], paged)
+    toks2 = rng.integers(3, cfg.vocab_size, (3, 8))
+    # slot 0's next chunk; slot 2 over slot 0's two blocks (a state
+    # model matches no prefix: a padding row in its place)
+    hit = not state
+    logits2, paged = admit(
+        toks2, [5, 1, 8 if hit else 1], [[5], [0], [6 if hit else 0]],
+        [[1, 2], [0, 0], [1, 2] if hit else [0, 0]],
+        [16, 0, 16 if hit else 0], [0, r, 2 if hit else r], paged)
+    tables = np.zeros((r, mb), np.int32)
+    tables[0, :4], tables[1, :3] = [1, 2, 5, 7], [3, 4, 8]
+    tables[2, :4] = [1, 2, 6, 9]
+    context = np.asarray([21, 9, 24 if hit else 0], np.int32)
+    budget = np.asarray([k, k, k if hit else 0], np.int32)
+    z = np.zeros((r,), np.int32)
+    last = np.asarray([5, 6, 7], np.int32)
+    rows = (np.ones((r,), np.float32), z, np.ones((r,), np.float32),
+            np.zeros((r,), bool))
+    if speculative:
+        hist = np.zeros((r, mb * bs + 1), np.int32)
+        for i in range(r):
+            hist[i, :context[i] + 1] = np.resize(
+                rng.integers(3, cfg.vocab_size, 4), context[i] + 1)
+        out = jax.jit(lambda pg: tf.paged_speculative_chunk(
+            params, cfg, k, 2, hist[np.arange(r), context], hist, pg,
+            tables, context, z, z, *rows, budget * 3, z - 1, 0))(paged)
+    else:
+        out = jax.jit(lambda pg: tf.decode_chunk_with_logits(
+            params, cfg, k, last, pg, tables, context, z, z, *rows, budget,
+            z - 1, 0))(paged)
+        out = out[:-2] + out[-1:] + out[-2:-1]     # the pool last
+    planes = out[-1].planes()[:2]
+    if flat_pool(cfg, out[-1]):
+        planes = [head_rows(p, cfg.num_kv_heads, cfg.head_dim)
+                  for p in planes]
+    return jax.device_get((logits1, logits2, out[:-1],
+                           [p[:, 1:] for p in planes]))
+
+
+def assert_flat_rows_serve_the_same(flat, by_heads, rtol=1e-4, atol=1e-5):
+    """Two flat_rows_scenario results: tokens, emits and counts exactly,
+    logits and planes to a float32's rounding. (The flat pool's tails
+    and chunks attend rows of all the heads' columns under q
+    zero-expanded to a row: the other heads' columns add exact zeros,
+    but XLA sums a longer row in another order.)"""
+    import jax
+    import numpy as np
+    for a, b in zip(jax.tree.leaves(flat), jax.tree.leaves(by_heads)):
+        if np.issubdtype(a.dtype, np.floating):
+            np.testing.assert_allclose(a, b, rtol=rtol, atol=atol)
+        else:
+            np.testing.assert_array_equal(a, b)
+
+
 def served_as_under_auto(build, asked, env, monkeypatch):
     """``attn_backend`` / ``DLI_ATTENTION`` name the dense cache's flash
     kernels (ops/attention.resolve_backend); the batcher pins "xla" and

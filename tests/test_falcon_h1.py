@@ -383,6 +383,29 @@ def test_a_prompt_in_chunks_goes_on_from_its_slots_state(params):
     sim.check(1)
 
 
+@pytest.mark.parametrize("form", ["in-loop", "kernel"])
+def test_flat_rows_serve_what_the_heads_axis_serves(form, monkeypatch):
+    """tiny-falcon-h1 with falcon-h1-34b's head shape at 2 K/V heads (10
+    query heads of 128: 5 a K/V head, no power of two), the state planes
+    beside the pool: a one-device pool stores a position's heads side by
+    side (rows of 256; ops/paged_kvcache.heads_in_rows), a mesh's keeps
+    them as an axis. conftest.flat_rows_scenario over both (a wave, a
+    chunked prompt's next chunk from its slot's state, a decode chunk)
+    by the in-loop gather and by the kernel, interpreted, which reads
+    the flat rows by p @ V a K/V head at a time (5 rows of p) where it
+    read the heads' own rows behind a mask: the same tokens, logits,
+    planes and states to a float32's rounding."""
+    from conftest import (
+        assert_flat_rows_serve_the_same, flat_rows_scenario)
+    monkeypatch.setattr(transformer, "_PREGATHER_MAX_BYTES", 0)
+    cfg = cfg32(num_heads=10, head_dim=128,
+                pool_kernel="pallas_interpret" if form == "kernel" else "xla")
+    p = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    flat, by_heads = (flat_rows_scenario(p, cfg, devices)
+                      for devices in (1, 4))
+    assert_flat_rows_serve_the_same(flat, by_heads)
+
+
 def test_the_step_kernel_is_the_numpy_form(params):
     """ops/pallas/ssm_step.py, interpreted, against the jax.numpy update
     at a state of whole tiles (d_state 128): a prompt, then chunks of 4

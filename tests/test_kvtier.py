@@ -226,6 +226,44 @@ def test_restore_after_pool_rebuild_cold_radix(cold_batcher):
     assert run_one(b, prompt) == cold == first
 
 
+@pytest.mark.parametrize("host_dtype", ["native", "int8"])
+def test_a_flat_pools_blocks_lie_in_the_arena_by_heads(host_dtype,
+                                                       monkeypatch):
+    """A one-device pool of 4 K/V heads of 128 stores a position's heads
+    in one row of 512 (ops/paged_kvcache.heads_in_rows); the arena, and
+    with it the wire and migration, keep a block by heads,
+    [L, bs, Hkv, w]: what a peer with the heads' axis (a mesh) sends and
+    takes, and what an int8 arena's per-(layer, head) scales are made
+    over. Evict, offload, restore: bitwise the cold run's tokens in
+    native mode; the int8 arena restores and its records hold a scale a
+    head."""
+    from distributed_llm_inferencing_tpu.ops import kvblock_quant as kvq
+    monkeypatch.setenv("DLI_KV_HOST_DTYPE", host_dtype)
+    cfg = CFG.replace(head_dim=128)
+    params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    kw = dict(num_blocks=24, block_size=8, slots=2, max_seq=128)
+    b = Batcher(cfg, params, kv_host_mb=64, **kw)
+    L = cfg.num_layers
+    assert b.paged.k.shape == (L, 25, 8, 1, 512)
+    rng = np.random.default_rng(7)
+    prompt = rng.integers(0, 256, 40).tolist()
+    cold = run_one(Batcher(cfg, params, kv_host_mb=0, **kw), prompt)
+    assert run_one(b, prompt) == cold
+    _evict_everything(b, rng)
+    digest = b.kvtier.block_digests(prompt[:8])[0]
+    stored = b.kvtier.arena.peek_stored(digest)
+    if host_dtype == "int8":
+        assert kvq.is_quantized_block(stored)
+        assert [e["scale"].shape for e in stored["pages"]] == [(L, 4)] * 2
+    assert [p.shape for p in b.kvtier.arena.peek_pages(digest)] \
+        == [(L, 8, 4, 128)] * 2
+    base = b.metrics.snapshot()["counters"].get("kvtier_restored_blocks", 0)
+    again = run_one(b, prompt)
+    assert b.metrics.snapshot()["counters"]["kvtier_restored_blocks"] > base
+    if host_dtype == "native":
+        assert again == cold
+
+
 # ---- same-wave duplicate prefix ----------------------------------------
 
 def test_same_wave_duplicate_prefix_hits_earlier_insert():

@@ -15,8 +15,10 @@ from distributed_llm_inferencing_tpu.models import transformer
 from distributed_llm_inferencing_tpu.models.params import init_params
 from distributed_llm_inferencing_tpu.models.registry import get_config
 from distributed_llm_inferencing_tpu.ops.kvcache import init_cache
-from distributed_llm_inferencing_tpu.ops.paged_kvcache import init_paged_cache
-from conftest import jitted
+from distributed_llm_inferencing_tpu.ops.paged_kvcache import (
+    flat_pool, heads_in_rows, init_paged_cache)
+from conftest import (
+    assert_flat_rows_serve_the_same, flat_rows_scenario, jitted)
 
 BS = 8  # block size for tests
 
@@ -164,3 +166,72 @@ def test_prefix_reuse_matches_full_prefill():
 
     np.testing.assert_allclose(np.asarray(last_b[0]), np.asarray(last_full[0]),
                                rtol=2e-4, atol=2e-4)
+
+
+# ---- which pools store a position's heads in one row (heads_in_rows) ----
+
+@pytest.mark.parametrize("model,kw,devices,row", [
+    # trinity-mini's and falcon-h1's 4 K/V heads of 128, and 2: flat
+    ("tiny-llama", dict(num_kv_heads=4, head_dim=128), 1, 512),
+    ("tiny-llama", dict(num_kv_heads=2, head_dim=128), 1, 256),
+    ("tiny-falcon-h1", dict(num_heads=20, num_kv_heads=4, head_dim=128), 1,
+     512),                                       # beside the state planes
+    # a tile's 8 sublanes filled, or more: the heads keep their axis
+    ("tiny-llama", dict(num_kv_heads=8, head_dim=128), 1, None),
+    ("tiny-ouro", dict(num_heads=16, num_kv_heads=16, head_dim=128), 1, None),
+    # heads that are no whole lanes; an int8 pool (its scale planes have
+    # a head axis and no width); a latent pool (one shared row already)
+    ("tiny-llama", {}, 1, None),
+    ("tiny-llama", dict(num_kv_heads=4, head_dim=128, kv_quant="int8"), 1,
+     None),
+    ("tiny-kanana", dict(mla_latent_cache=True), 1, None),
+    # a mesh shards the head axis (parallel/sharding.paged_cache_specs)
+    ("tiny-llama", dict(num_kv_heads=4, head_dim=128), 4, None),
+    ("tiny-llama", dict(num_kv_heads=2, head_dim=128), 2, None),
+    # one head (MQA): the same shape either way
+    ("tiny-llama", dict(num_kv_heads=1, head_dim=128), 1, None),
+])
+def test_which_pools_store_heads_side_by_side(model, kw, devices, row):
+    """The rule is read from the pool's shape (init_paged_cache), not
+    from a model's name: an unquantized one-device pool of fewer K/V
+    heads than a tile's 8 sublanes, each whole lanes wide, stores a
+    position's heads in ONE row; every other pool keeps
+    [L, NB, bs, Hkv, hd]. The bytes a token are the same either way."""
+    cfg = get_config(model).replace(**kw)
+    paged = init_paged_cache(cfg, 6, BS, slots=2, devices=devices)
+    by_heads = init_paged_cache(cfg, 6, BS, slots=2, devices=8)
+    assert heads_in_rows(cfg, devices) == flat_pool(cfg, paged) \
+        == (row is not None)
+    assert paged.bytes_per_token == by_heads.bytes_per_token
+    if row is None:
+        assert paged.k.shape == by_heads.k.shape
+        assert paged.k.shape[3:] == (
+            (1, paged.k.shape[4]) if cfg.mla_latent_cache
+            else (cfg.num_kv_heads, cfg.head_dim))
+    else:
+        assert paged.k.shape == paged.v.shape == (
+            cfg.num_layers, 6, BS, 1, row)
+    assert (paged.ssm is None) == (cfg.ssm is None)
+
+
+@pytest.mark.parametrize("form", ["pre-gathered", "in-loop", "kernel",
+                                  "speculative"])
+def test_flat_rows_serve_what_the_heads_axis_serves(form, monkeypatch):
+    """conftest.flat_rows_scenario over a pool of 4 K/V heads of 128 as
+    one device holds it (rows of 512) and as a mesh does (the heads an
+    axis): a wave's write, a prefix hit, a chunked prompt's next chunk,
+    then the decode chunk's read in each of its forms and its one write.
+    Tokens exactly, logits and every plane to a float32's rounding (each
+    query head zero-expanded to the row: the other heads' columns add
+    exact zeros, in a sum of another length)."""
+    cfg = _cfg("tiny-llama").replace(
+        head_dim=128,
+        pool_kernel="pallas_interpret" if form == "kernel" else "xla")
+    if form != "pre-gathered":
+        monkeypatch.setattr(transformer, "_PREGATHER_MAX_BYTES", 0)
+    params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    flat, by_heads = (
+        flat_rows_scenario(params, cfg, devices,
+                           speculative=form == "speculative")
+        for devices in (1, 4))
+    assert_flat_rows_serve_the_same(flat, by_heads)

@@ -100,8 +100,8 @@ def test_flash_prefill_odd_shapes(B, S, H, Hkv, hd):
 # chunk size's side rows (4 rows at a chunk of one: half a tile). Last,
 # flat rows (``hd`` a pair: a K head's width and a V head's; ``hkv`` the
 # K/V heads side by side in a position's one row of each plane): 2 heads
-# of 192 / 128 -> rows of 384 / 256 columns, and mimo-v2.5's 4 -> 768 /
-# 512 under 64 query heads
+# of 192 / 128 -> rows of 384 / 256 columns, mimo-v2.5's 4 -> 768 /
+# 512 under 64 query heads, and 4 of 128 / 128 under 20
 _PAGED_SHAPES = [
     (g, hkv, hd, window, side_rows)
     for g, hkv, hd, window in [
@@ -114,7 +114,12 @@ _PAGED_SHAPES = [
 ] + [(8, 4, 128, None, 1), (8, 4, 128, 9, 2), (8, 4, 128, None, 4),
      (8, 4, 128, 9, 8), (3, 2, 128, None, 1), (3, 2, 128, 9, 8)
 ] + [(g, hkv, (192, 128), None, side_rows)
-     for g, hkv in ((2, 2), (16, 4)) for side_rows in (1, 8)]
+     for g, hkv in ((2, 2), (16, 4)) for side_rows in (1, 8)
+     # ... and falcon-h1's as one device stores them since PR 49: K and V
+     # rows both 4 x 128 = 512 wide, 5 query heads a K/V head (p @ V a
+     # head at a time takes 5 rows of p), and a window
+] + [(5, 4, (128, 128), window, side_rows)
+     for window, side_rows in ((None, 1), (None, 8), (9, 8))]
 
 
 @pytest.mark.parametrize("g,hkv,hd,window,side_rows", _PAGED_SHAPES)

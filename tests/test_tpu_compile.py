@@ -145,38 +145,59 @@ def test_paged_attend_compiles_over_a_latent_plane(compile_on_chip,
 
 
 @pytest.mark.parametrize("side_rows", [1, 2, 4, 8])
+@pytest.mark.parametrize("rows", ["tiles", "flat"])
 def test_paged_attend_compiles_over_four_head_planes(compile_on_chip,
-                                                     side_rows):
+                                                     side_rows, rows):
     """ops/pallas/paged_attention.py at the falcon-h1 cell's decode
     shape: 64 slots of 20 query heads (2.5 tiles of sublanes, a group of
-    5) over K and V planes of 4 heads, `[6, 4097, 16, 4, 128]` each,
-    block tables of 64 columns, and the side rows of each of the cell's
-    chunk sizes (a chunk of one pass: 4 rows, half a tile). XLA lays
+    5) over K and V planes of 4 heads, block tables of 64 columns, and
+    the side rows of each of the cell's chunk sizes (a chunk of one
+    pass: 4 rows, half a tile, or one flat row).
+
+    ``tiles``: the heads an axis, `[6, 4097, 16, 4, 128]` (the cell's
+    pool until PR 49; a mesh's shard never reaches the kernel). XLA lays
     4-head planes out in (4, 128) tiles; two of them hold the bytes of
     one (8, 128) tile in the same order, so the flat view the kernel
     reads, `[6, 4097, 64, 128]`, must be a `bitcast` of the stored plane
-    and nothing plane-sized may be copied (PERF.md section 6, PR 43)."""
+    and nothing plane-sized may be copied (PERF.md section 6, PR 43).
+
+    ``flat``: a position's heads side by side, `[6, 4097, 16, 1, 512]`
+    (ops/paged_kvcache.heads_in_rows: the cell's pool since PR 49), q
+    zero-expanded to a row of 512, K and V rows the same width, `p @ V`
+    a K/V head's 5 query heads at a time: Mosaic takes the 5-row slices
+    of p, and no plane is copied."""
+    from distributed_llm_inferencing_tpu.models.transformer import (
+        _flat_rows_q)
     from distributed_llm_inferencing_tpu.ops.pallas import paged_attention
-    slots, heads, hkv, mb, planes = 64, 20, 4, 64, (6, 4097, BS, 4, HD)
-    assert paged_attention.supported(hkv, HD, BF16)
-    assert paged_attention._pages(BS, hkv, HD, 2, mb) == (16, 32, 32)
+    slots, heads, hkv, mb = 64, 20, 4, 64
+    flat = rows == "flat"
+    row = (1, hkv * HD) if flat else (hkv, HD)
+    planes = (6, 4097, BS) + row
+    assert paged_attention.supported(*row, BF16)
+    assert paged_attention._pages(BS, *row, 2, mb) == (16, 32, 32)
 
     def attend(q, k, v, bt, cl, side_k, side_v, plane, t):
-        walk = paged_attention.pool_walk(cl, cl > 0, k, mb)
+        walk = paged_attention.pool_walk(cl, cl > 0, k, mb, v_planes=v)
+        if flat:
+            return paged_attention.paged_attend(
+                _flat_rows_q(q, hkv, k), k, v, plane, bt, cl, cl + t, walk,
+                (side_k, side_v, t), scale=HD ** -0.5, v_head_dim=HD)
         return paged_attention.paged_attend(
             q, k, v, plane, bt, cl, cl + t, walk, (side_k, side_v, t))
 
     text = compile_on_chip(
         attend, ((slots, 1, heads, HD), BF16), (planes, BF16),
         (planes, BF16), ((slots, mb), jnp.int32), ((slots,), jnp.int32),
-        ((slots, side_rows, hkv, HD), BF16),
-        ((slots, side_rows, hkv, HD), BF16), ((), jnp.int32),
+        ((slots, side_rows) + row, BF16),
+        ((slots, side_rows) + row, BF16), ((), jnp.int32),
         ((), jnp.int32)).as_text()
-    flat = [ln for ln in text.splitlines()
-            if re.search(r"= bf16\[6,4097,64,128\]", ln)]
-    assert len(flat) == 2 and all(" bitcast(" in ln for ln in flat), flat
-    assert not re.findall(r"= bf16\[6,4097,16,4,128\]\S* "
-                          r"(?!parameter)\S+\(", text)
+    assert "paged_pool_attend" in text
+    if not flat:
+        view = [ln for ln in text.splitlines()
+                if re.search(r"= bf16\[6,4097,64,128\]", ln)]
+        assert len(view) == 2 and all(" bitcast(" in ln for ln in view), view
+    assert not re.findall(r"= bf16\[6,4097,16,(?:4,128|1,512)\]\S* "
+                          r"(?!parameter|bitcast)\S+\(", text)
 
 
 def test_ssm_state_step_writes_the_plane_in_place(compile_on_chip):
@@ -511,27 +532,27 @@ def _cells():
         "kanana": (KANANA.replace(num_layers=7, mla_latent_cache=True,
                                   **pins),
                    64, 16, 10240, 160, (128, 0, 1), (0, 0)),
-        # .../trinity-mini-l5.json. K and V of 4 heads arrive in
-        # (4, 128) tiles; the chunk's attention and the wave's write of
-        # whole blocks take (8, 128) tiles of (positions, width), so XLA
-        # re-tiles either plane once a chunk (the parent's ten per-layer
-        # copies were the same bytes) and, in a wave, there and back
-        # (1.55 ms a plane a copy on the chip; PERF.md section 6, PR
-        # 38). Storing the heads ahead of a block's positions would end
-        # both (PERF.md section 7).
+        # .../trinity-mini-l5.json. Its 4 K/V heads of 128 lie side by
+        # side in ONE row of 512 (ops/paged_kvcache.heads_in_rows): the
+        # planes arrive in (8, 128) tiles of (positions, columns), which
+        # the wave's write of whole blocks and the chunk's gather take
+        # where they lie. With the heads an axis they arrived in
+        # (4, 128) tiles and XLA re-tiled either plane once a chunk of 8
+        # and, in a wave, there and back: 1.007 GB a plane a copy, four
+        # an admit program, two a chunk, until PR 49
         "trinity": (get_config("trinity-mini").replace(
             num_layers=5, dense_prefix_layers=1,
             attn_windows=(2048,) * 4 + (None,),
             rope_layers=(1, 1, 1, 1, 0), **pins),
-            64, 16, 12288, 576, (512, 128, 1), (4, 2)),
+            64, 16, 12288, 576, (512, 128, 1), (0, 0)),
         # .../falcon-h1-34b-l6.json: 20 query heads over 4 K/V heads,
-        # the state planes of 65 rows beside the pool. Its admit
-        # programs keep trinity's four copies of a K or V plane (the
-        # wave's write of whole blocks); its decode chunk reads the
-        # (4, 128)-tiled planes by the kernel as they lie: two such
-        # tiles are one (8, 128) tile's bytes, the flat view a bitcast
+        # the state planes of 65 rows beside the pool. Flat rows of 512
+        # as trinity's (its admit programs kept trinity's four copies
+        # until PR 49); its decode chunk reads them by the kernel, q
+        # zero-expanded to a row, p @ V a K/V head's 5 query heads at a
+        # time
         "falcon-h1": (get_config("falcon-h1-34b").replace(
-            num_layers=6, **pins), 64, 16, 4096, 64, (512, 1, 8), (4, 0)),
+            num_layers=6, **pins), 64, 16, 4096, 64, (512, 1, 8), (0, 0)),
     }
 
 
@@ -570,12 +591,19 @@ def test_no_serving_program_copies_the_pool(compile_on_chip, model, program):
     `bf16[7,10241,16,1,640]`: no `bf16[64,2560,1,640]` (or 576) of
     gathered rows, no copy of the plane. Since PR 43 falcon-h1's chunk
     holds it, one call in the scanned layer body over planes of 4 K/V
-    heads, `bf16[6,4097,16,4,128]`, whose flat view is a bitcast: no
-    `bf16[64,768,4,128]` of a rung (the state planes are the carry's,
-    written in place by `ssm_state_step`). trinity's chunk and every
-    admit program have no such call: their traces are the parent's
-    (`transformer._pool_kernel` says None before anything else is
-    traced differently)."""
+    heads (the state planes are the carry's, written in place by
+    `ssm_state_step`): until PR 49 `bf16[6,4097,16,4,128]` in (4, 128)
+    tiles, whose flat view was a bitcast, since then flat rows,
+    `bf16[6,4097,16,1,512]` (ops/paged_kvcache.heads_in_rows), read
+    under q zero-expanded to a row: no `bf16[64,768,4,128]` or
+    `bf16[64,768,1,512]` of a rung. trinity's chunk and every admit
+    program have no such call.
+
+    Since PR 49 no cell's program keeps a copy of a plane: trinity's and
+    falcon-h1's 4-head planes were re-tiled whole around the wave's
+    write (four `copy` an admit program) and ahead of trinity's chunk
+    of 8 (two), 1.007 GB and 0.403 GB a copy; a position's heads side
+    by side in one row leave one `fusion(scatter)` a plane."""
     cfg, slots, bs, blocks, mb, (t, pb, wave), kept = _cells()[model]
     held = cfg.is_moe
     kept = kept[program != "admit"]
@@ -589,6 +617,7 @@ def test_no_serving_program_copies_the_pool(compile_on_chip, model, program):
                               donate=True)
         text = chunk.as_text()
     assert ("paged_pool_attend" in text) == pool_kernel
+    _, (pool, _) = _serving_shapes(cfg, bs, blocks)
     if pool_kernel:
         from distributed_llm_inferencing_tpu.models.transformer import (
             _pool_ladder)
@@ -598,13 +627,15 @@ def test_no_serving_program_copies_the_pool(compile_on_chip, model, program):
         rungs = "|".join(str(m * bs) for m in _pool_ladder(mb))
         heads, width = ((1, r"\d+") if cfg.mla_latent_cache
                         else (cfg.num_kv_heads, cfg.head_dim))
+        if model == "falcon-h1":   # a position's heads in one row
+            assert pool[0][0] == (6, 4097, 16, 1, 512), pool
+            heads, width = r"(?:1|4)", r"(?:128|512)"
         made = re.findall(rf"= (bf16\[{slots},(?:{rungs}),"
                           rf"{heads},{width}\])", text)
         assert not made, f"a rung of K or V is materialized: {made[:4]}"
         if model == "ouro":
             assert chunk.memory_analysis().temp_size_in_bytes \
                 < 1.15 * 2 ** 30
-    _, (pool, _) = _serving_shapes(cfg, bs, blocks)
     made = _pool_sized()(text, [jax.ShapeDtypeStruct(*p) for p in pool])
     writes = [m for m in made if m[1] in ("fusion(scatter)", "scatter")]
     assert len(writes) == len(pool), f"one write a plane: {made}"

@@ -317,6 +317,34 @@ def test_tail_prefill_equals_the_per_layer_write(params, prefix_len,
         assert (np.asarray(a[:, SPARE:]) != np.asarray(was[:, SPARE:])).any()
 
 
+@pytest.mark.parametrize("form", ["pre-gathered", "windowed-read"])
+def test_flat_rows_serve_what_the_heads_axis_serves(form, monkeypatch):
+    """tiny-afmoe with K/V heads of 128 (2 of them: rows of 256), its MoE
+    layers held one by one as the batcher holds them: a one-device pool
+    stores a position's heads side by side (ops/paged_kvcache.
+    heads_in_rows), a mesh's keeps them as an axis. conftest.
+    flat_rows_scenario over both: the wave's write, a tail over a prefix
+    hit through the windowed layers' bounded prefix read, a chunked
+    prompt's next chunk, then the decode chunk over the pre-gathered
+    table and over the in-loop gather with the windowed layers' bounded
+    read (contexts of 21 and 24 behind a window of 8), the full layer
+    without rotation, the gate on: the same tokens, logits and planes to
+    a float32's rounding."""
+    from conftest import (
+        assert_flat_rows_serve_the_same, flat_rows_scenario)
+    if form != "pre-gathered":
+        monkeypatch.setattr(transformer, "_PREGATHER_MAX_BYTES", 0)
+    cfg = cfg32().replace(head_dim=128)
+    held = held_one_by_one(
+        init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32))
+    flat, by_heads = (flat_rows_scenario(held, cfg, devices)
+                      for devices in (1, 4))
+    assert_flat_rows_serve_the_same(flat, by_heads)
+    # (pool positions, window positions): the bounded read was taken
+    assert (int(flat[2][3]), int(flat[2][4])) == (
+        (64, 64) if form == "pre-gathered" else (64, 16))
+
+
 def test_a_scanned_stack_keeps_the_traced_window(params):
     """Stacked layers of mixed windows under one scan keep the traced
     leaf; a segment of one window, and a layer on its own, get a
