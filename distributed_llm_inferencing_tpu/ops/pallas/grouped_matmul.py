@@ -34,6 +34,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from distributed_llm_inferencing_tpu.utils.profiler import pallas_call_site
+
 # one expert's weight block in VMEM; the pipeline holds two. Trinity's
 # [2048, 1024] bf16 gate block is exactly this.
 _BLOCK_BYTES = 4 * 2 ** 20
@@ -124,6 +126,7 @@ def grouped_matmul(rows, w, group_sizes, *, interpret: bool = False):
         jnp.logical_and(hit[None, :], rank[None, :] == ids[:, None]),
         ids[None, :], 0), axis=1)
     hit_ids = jnp.where(ids < n_hit, hit_ids, jnp.max(jnp.where(hit, ids, 0)))
+    pallas_call_site()   # utils/profiler.py: counted as traced
     out = pl.pallas_call(
         functools.partial(_kernel, tile=tile),
         out_shape=jax.ShapeDtypeStruct((mp, f), rows.dtype),

@@ -86,6 +86,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from distributed_llm_inferencing_tpu.utils.profiler import pallas_call_site
+
 NEG_INF = -1e30
 LANES = 128
 
@@ -602,6 +604,7 @@ def _paged_attend(q, k_planes, v_planes, plane, block_tables, context_lens,
 
     def i32(x):
         return jnp.asarray(x, jnp.int32)
+    pallas_call_site()   # utils/profiler.py: counted as traced
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(

@@ -37,6 +37,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from distributed_llm_inferencing_tpu.utils.profiler import pallas_call_site
+
 # Only the decode-shaped path belongs here: at prefill (many rows per
 # weight read) XLA's materialize-once strategy is the right one, and the
 # fallback in ops/quant.py handles it.
@@ -128,6 +130,7 @@ def _q4_pallas(x, p4, scale, interpret: bool):
     dout = p4.shape[-1]
     tile_o = _pick_tile(din)
     kernel = _biased_kernel if x.dtype == jnp.bfloat16 else _signed_kernel
+    pallas_call_site()   # utils/profiler.py: counted as traced
     return pl.pallas_call(
         kernel,
         grid=(pl.cdiv(dout, tile_o),),

@@ -33,6 +33,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from distributed_llm_inferencing_tpu.utils.profiler import pallas_call_site
+
 F32 = jnp.float32
 
 
@@ -76,6 +78,7 @@ def ssm_step(plane, layer, decay, dtx, b, c, interpret: bool = False):
                         lambda i, li: (li[0], i // g, i % g, 0, 0, 0))
     row = pl.BlockSpec((1, 1, n), lambda i, li: (i, 0, 0))
     col = pl.BlockSpec((1, p, k), lambda i, li: (i, 0, 0))
+    pallas_call_site()   # utils/profiler.py: counted as traced
     out, y = pl.pallas_call(
         functools.partial(_kernel, heads=k),
         out_shape=(jax.ShapeDtypeStruct(tiles.shape, F32),

@@ -71,7 +71,7 @@ from distributed_llm_inferencing_tpu.runtime import tsdb as tsdb_mod
 from distributed_llm_inferencing_tpu.utils import clock, locks, trace
 from distributed_llm_inferencing_tpu.utils.metrics import Metrics
 from distributed_llm_inferencing_tpu.utils.profiler import (
-    PhaseProfiler, call_deltas, call_readings)
+    PhaseProfiler, call_deltas, call_readings, mark_imported)
 
 log = logging.getLogger("dli.batcher")
 
@@ -311,8 +311,9 @@ class ContinuousBatcher:
     # a doubling is the device or its runtime standing still, not load.
     # What it took over that mean is what was lost, and is counted.
     STALL_PROGRAM_FACTOR = 2.0
-    # ... judged once this many chunks of that size were seen after the
-    # first, which compiles or reads the program from the cache
+    # ... judged once this many chunks of that size were seen (a
+    # program's first use, which compiles or reads the compile cache, is
+    # not one: the call's label says so, _note_program)
     STALL_MIN_CHUNKS = 4
     # A busy step's host part (wall outside program calls) is a few
     # milliseconds for 16 slots; 100 ms is over ten times that, and
@@ -350,6 +351,17 @@ class ContinuousBatcher:
     STALL_CAUSES = ("gc", "interpreter_held", "descheduled",
                     "host_runtime_busy", "device_or_runtime_wait",
                     "thread_blocked")
+    # A program's first use (utils/profiler.py, the program account):
+    # how many, what their trace, lowering, load (the compile cache's
+    # read and deserialize on a hit, XLA's compile on a miss) and first
+    # run took, and what the cache answered
+    PROGRAM_COUNTERS = ("batcher_programs_first_use",
+                        "batcher_program_trace_ms",
+                        "batcher_program_lower_ms",
+                        "batcher_program_load_ms",
+                        "batcher_program_first_run_ms",
+                        "batcher_program_cache_hits",
+                        "batcher_program_cache_misses")
 
     @staticmethod
     def _stall_cause(where: str, lost_ms: float, did: dict) -> str:
@@ -377,6 +389,11 @@ class ContinuousBatcher:
                  kv_digest_chunk: Optional[int] = None,
                  kv_fetcher=None,
                  metrics: Optional[Metrics] = None):
+        # this step loop's phase clocks, always on, its opt-in sampling
+        # profiler (utils/profiler.py; DLI_PROFILE=1 or worker POST
+        # /api/profile) and its program account, which the build below
+        # is the first entry of
+        self.profiler = PhaseProfiler.from_env()
         # shared with the worker's registry when serving (so /metrics
         # carries the scheduler's gauges/histograms); owned otherwise
         self.metrics = metrics or Metrics()
@@ -582,6 +599,10 @@ class ContinuousBatcher:
         self.metrics.inc("batcher_stall_host_ms", 0)
         for cause in self.STALL_CAUSES:   # ... and by cause (_stall_cause)
             self.metrics.inc(f"batcher_stall_cause_{cause}_ms", 0)
+        # programs first used (compiled, or read from the compile cache)
+        # and what that took (_report_first_use)
+        for name in self.PROGRAM_COUNTERS:
+            self.metrics.inc(name, 0)
         self._stall_lost_s = 0.0  # both sides' sum: a request reads it twice
         self._wave_count = 0      # admit programs run (`wave` on their spans)
         self._pool_positions = 0  # the last decode chunk's (_run_decode)
@@ -614,22 +635,28 @@ class ContinuousBatcher:
         # the replayed program's outputs on both sides)
         self._hist_synced = (np.zeros((slots,), np.int64)
                              if speculative else None)
-        if params is None:
-            params = init_params(cfg, jax.random.PRNGKey(seed))
-        else:
-            from distributed_llm_inferencing_tpu.ops.quant import (
-                maybe_quantize, maybe_quantize_embed)
-            params = maybe_quantize_embed(maybe_quantize(params, cfg), cfg)
-        with self.mesh:
-            self.params = shd.shard_params(params, self.mesh, cfg,
-                                           self.mesh_spec)
-            del params     # or the stacked leaves below live on in it
-            if cfg.is_moe and self.mesh_spec.pp == 1:
-                # (a model with layer kinds: each kind's MoE stack)
-                for name in ("layers", "layers_full"):
-                    if name in self.params:
-                        self.params[name] = _unstack_layers(
-                            self.params.pop(name))
+        with self.profiler.program("build", "weights") as built:
+            if params is None:
+                params = init_params(cfg, jax.random.PRNGKey(seed))
+            else:
+                from distributed_llm_inferencing_tpu.ops.quant import (
+                    maybe_quantize, maybe_quantize_embed)
+                params = maybe_quantize_embed(maybe_quantize(params, cfg),
+                                              cfg)
+            with self.mesh:
+                self.params = shd.shard_params(params, self.mesh, cfg,
+                                               self.mesh_spec)
+                del params     # or the stacked leaves below live on in it
+                if cfg.is_moe and self.mesh_spec.pp == 1:
+                    # (a model with layer kinds: each kind's MoE stack)
+                    for name in ("layers", "layers_full"):
+                        if name in self.params:
+                            self.params[name] = _unstack_layers(
+                                self.params.pop(name))
+            # (the host's wall: what the device still owes of the
+            # weights is not waited for, and lands in the first program's
+            # ``run_ms``)
+            built.attrs["bytes"] = _tree_bytes(self.params)
 
         # +1: block 0 is the reserved dummy every inactive table entry
         # points at, so it never carries real KV
@@ -640,10 +667,14 @@ class ContinuousBatcher:
         # exists — a scrape between construction and the first step must
         # not read "0 free blocks" as exhaustion
         self.metrics.gauge("batcher_free_kv_blocks", self.pool.free_count())
-        self.paged = jax.device_put(
-            init_paged_cache(cfg, num_blocks + 1, block_size, slots=slots,
-                             devices=self.mesh_spec.num_devices),
-            shd.named(self.mesh, shd.paged_cache_specs(cfg, self.mesh_spec)))
+        with self.profiler.program("build", "pool") as built:
+            self.paged = jax.device_put(
+                init_paged_cache(cfg, num_blocks + 1, block_size,
+                                 slots=slots,
+                                 devices=self.mesh_spec.num_devices),
+                shd.named(self.mesh,
+                          shd.paged_cache_specs(cfg, self.mesh_spec)))
+            built.attrs["bytes"] = _tree_bytes(self.paged)
         # a one-device pool of few K/V heads stores a position's heads in
         # one row (ops/paged_kvcache.heads_in_rows); the host arena, the
         # wire and migration keep a block by heads, [L, bs, Hkv, w],
@@ -765,10 +796,6 @@ class ContinuousBatcher:
         for name in ("lora_loads", "lora_evictions", "lora_load_failures",
                      "lora_requests"):
             self.metrics.inc(name, 0)
-        # this step loop's phase clocks, always on, and its opt-in
-        # sampling profiler (utils/profiler.py; DLI_PROFILE=1 or worker
-        # POST /api/profile)
-        self.profiler = PhaseProfiler.from_env()
         self.context_lens = np.zeros((slots,), np.int32)
         self.active: List[Optional[BatchRequest]] = [None] * slots
         self._admit_order: collections.deque = collections.deque()  # slot ids
@@ -795,6 +822,7 @@ class ContinuousBatcher:
         # tokens / per admission wave, not per token (round-2's per-token
         # mirror was the multi-host throughput ceiling).
         self.program_hook = None
+        self._record_build()
 
     @property
     def decode_chunks(self):
@@ -948,6 +976,7 @@ class ContinuousBatcher:
         if self._thread is None:
             self._thread = threading.Thread(target=self._loop, daemon=True,
                                             name="batcher")
+            self.profiler.set_serving(True)
             self._thread.start()
 
     def stop(self):
@@ -958,6 +987,7 @@ class ContinuousBatcher:
         if self._thread:
             self._thread.join(timeout=30)
             self._thread = None
+        self.profiler.set_serving(False)
         for slot in range(self.slots):
             req = self.active[slot]
             if req is not None:
@@ -1370,9 +1400,10 @@ class ContinuousBatcher:
                 fn = self._decode_jit(k, r, mb)
                 if hasattr(fn, "lower"):   # not yet AOT-compiled
                     ints = jax.ShapeDtypeStruct((r * (mb + 7),), jnp.int32)
-                    self._decode_fns[(k, r, mb, False)] = fn.lower(
-                        self.params, toks, ints, floats,
-                        paged_sds).compile()
+                    with self.profiler.program("chunk", k, aot=True):
+                        self._decode_fns[(k, r, mb, False)] = fn.lower(
+                            self.params, toks, ints, floats,
+                            paged_sds).compile()
                     n += 1
                 if not (self.speculative and self.spec_gamma >= 1):
                     continue
@@ -1382,11 +1413,13 @@ class ContinuousBatcher:
                 if hasattr(sfn, "lower"):
                     ints = jax.ShapeDtypeStruct(
                         (r * (mb + hh + 9),), jnp.int32)
-                    self._decode_fns[("spec", k_it, g, r, mb, hh,
-                                      False)] = \
-                        sfn.lower(self.params, ints, floats,
-                                  paged_sds).compile()
+                    with self.profiler.program("spec", (k_it, g), aot=True):
+                        self._decode_fns[("spec", k_it, g, r, mb, hh,
+                                          False)] = \
+                            sfn.lower(self.params, ints, floats,
+                                      paged_sds).compile()
                     n += 1
+        self._report_first_use()
         return n
 
     # ---- program launch (shared by the scheduler and lockstep replay) --
@@ -1413,8 +1446,9 @@ class ContinuousBatcher:
             [np.asarray(a["slots"], np.int32)] if "slots" in a else []))
         floats = np.stack([np.asarray(a["temps"], np.float32),
                            np.asarray(a["tps"], np.float32)])
+        key = (toks.shape[1], pfb.shape[1], b) + (("lora",) * use_lora)
         fn = self._admit_jit(toks.shape[1], pfb.shape[1], b, use_lora)
-        with self.mesh:
+        with self.mesh, self.profiler.program("admit", key):
             first, self.paged = fn(self._wave_params(use_lora),
                                    jnp.asarray(ints),
                                    jnp.asarray(floats), self.paged)
@@ -1441,14 +1475,21 @@ class ContinuousBatcher:
             [np.asarray(a["aids"], np.int32)] if use_lora else []))
         floats = np.stack([np.asarray(a["temps"], np.float32),
                            np.asarray(a["tps"], np.float32)])
-        fn = self._decode_jit(int(a["k"]), r, mb, use_lora)
+        k = int(a["k"])
+        key = (k, "lora") if use_lora else k
         # chunk names this host call's device run and its
-        # batcher.decode_chunk span exactly; k and slots describe it
-        stats = ({"k": int(a["k"]),
-                  "slots": int(np.count_nonzero(a["budget"])),
-                  "chunk": self._step_count + 1}
-                 if self.profiler.enabled else {})
-        with self.mesh:
+        # batcher.decode_chunk span exactly; k and slots describe it,
+        # first_use says the call is about to compile or is the first
+        # run of a program compiled ahead (the account says what it took)
+        stats = {}
+        if self.profiler.enabled:
+            stats = {"k": k, "slots": int(np.count_nonzero(a["budget"])),
+                     "chunk": self._step_count + 1}
+            if ((k, r, mb, use_lora) not in self._decode_fns
+                    or self.profiler.awaits_run("chunk", key)):
+                stats["first_use"] = 1
+        fn = self._decode_jit(k, r, mb, use_lora)
+        with self.mesh, self.profiler.program("chunk", key):
             with self.profiler.phase("dispatch", **stats):
                 toks, emits, moe, pool_positions, self.paged = fn(
                     self._wave_params(use_lora),
@@ -1567,10 +1608,11 @@ class ContinuousBatcher:
                            np.asarray(a["tps"], np.float32)])
         fn = self._spec_jit(int(a["k"]), int(a["gamma"]), r, mb,
                             hist.shape[1], use_lora)
+        key = (int(a["k"]), int(a["gamma"])) + (("lora",) * use_lora)
         # draft+verify run fused in one device program; the profiler
         # attributes the whole dispatch+sync to the verify phase (the
         # host-side drafting state prep is tagged spec_draft by the step)
-        with self.mesh:
+        with self.mesh, self.profiler.program("spec", key):
             with self.profiler.phase("spec_verify"):
                 toks, keeps, eos_seen, self.paged = fn(
                     self._wave_params(use_lora), jnp.asarray(ints),
@@ -1583,19 +1625,19 @@ class ContinuousBatcher:
         (``call_readings()``, taken at its start): its wall, read once
         here, is program time of this step, and a decode chunk (``k``
         passes) is judged against the running mean of earlier chunks of
-        its kind and size. Admit waves only add their wall: their sizes
-        differ too widely for a mean to say anything. Returns the call's
-        (start, end) in epoch seconds, for its histogram and span."""
+        its kind and size, unless it was the program's first use (the
+        call's label saw it compile, or run for the first time: seconds
+        that are no pass's, which the program account holds). Admit
+        waves only add their wall: their sizes differ too widely for a
+        mean to say anything. Returns the call's (start, end) in epoch
+        seconds, for its histogram and span."""
         t0, t1 = before[0], time.perf_counter()
         wall_s = t1 - t0
         span = self.profiler.epoch(t0), self.profiler.epoch(t1)
         self._step_program_s += wall_s
-        if not k:
+        if not k or self.profiler.first_use:
             return span
-        st = self._pass_mean.get((kind, k))
-        if st is None:   # the first of its size compiles, or reads the cache
-            self._pass_mean[(kind, k)] = [0.0, 0]
-            return span
+        st = self._pass_mean.setdefault((kind, k), [0.0, 0])
         mean, n = st
         per_pass = wall_s / k
         lost = wall_s - mean * k
@@ -1612,6 +1654,50 @@ class ContinuousBatcher:
             per_pass = self.STALL_PROGRAM_FACTOR * mean
         st[:] = mean + (per_pass - mean) / min(n + 1, 64), n + 1
         return span
+
+    def _report_first_use(self, parent=None):
+        """Journal the programs the labelled calls since the last report
+        used for the first time (utils/profiler.py: the program account
+        holds their rows already): the counters, one
+        ``batcher.program_first_use`` span over each call that compiled,
+        under the step's own span where it has one (``parent``), and,
+        while the scheduler thread serves, a ``program-first-use`` event
+        and a warning: a request waited for a compilation."""
+        for row, run_ms, compiled in self.profiler.take_unreported():
+            m = self.metrics
+            m.inc("batcher_program_first_run_ms", run_ms)
+            if not compiled:    # the first run of a program compiled ahead
+                continue
+            m.inc("batcher_programs_first_use")
+            for name in ("trace", "lower", "load"):
+                m.inc(f"batcher_program_{name}_ms", row[f"{name}_ms"])
+            m.inc("batcher_program_cache_hits", row["cache_hits"])
+            m.inc("batcher_program_cache_misses", row["cache_misses"])
+            attrs = {f: row[f] for f in events.PROGRAM_FIRST_USE_FIELDS}
+            trace.get_tracer().record(
+                "batcher.program_first_use", row["start"], row["end"],
+                parent=parent, attrs=attrs)
+            if row["serving"]:
+                log.warning("program first used while serving: %s",
+                            json.dumps(attrs))
+                events.emit("program-first-use", **attrs)
+
+    def _record_build(self):
+        """Close the constructor's account and leave its spans:
+        ``batcher.build`` over the whole of it, ``batcher.build.weights``
+        and ``batcher.build.pool`` under it."""
+        build = self.profiler.built()
+        tracer = trace.get_tracer()
+        whole = tracer.record(
+            "batcher.build", build["start"], build["end"],
+            attrs={"model": self.cfg.name, "wall_ms": build["wall_ms"],
+                   **{f"eager_{k}": v for k, v in build["eager"].items()}})
+        for name in ("weights", "pool"):
+            part = build[name]
+            tracer.record(f"batcher.build.{name}", part["start"],
+                          part["end"], parent=whole,
+                          attrs={k: v for k, v in part.items()
+                                 if k not in ("start", "end")})
 
     def _stall(self, where: str, lost_s: float, k: int, slots: int,
                before: tuple, span: Tuple[float, float], grew: str):
@@ -1692,6 +1778,7 @@ class ContinuousBatcher:
                                       np.asarray(args["cl"], np.int32))
         else:
             raise ValueError(f"unknown batcher program kind {kind!r}")
+        self._report_first_use()
 
     # ---- scheduling ---------------------------------------------------
 
@@ -2560,10 +2647,15 @@ class ContinuousBatcher:
             # preemption re-admissions keep the original stamp)
             if m["req"].admitted_at is None:
                 m["req"].admitted_at = w0
-        # wave names this call's device run and its span exactly
-        with self.profiler.phase("admit_run", rows=b, tail_bucket=t,
-                                 prefix_bucket=pb, tokens=tokens,
-                                 wave=self._wave_count):
+        # wave names this call's device run and its span exactly;
+        # first_use says the program is about to be compiled
+        stats = {}
+        if self.profiler.enabled:
+            stats = dict(rows=b, tail_bucket=t, prefix_bucket=pb,
+                         tokens=tokens, wave=self._wave_count)
+            if (t, pb, b, "aids" in admit_args) not in self._prefill_fns:
+                stats["first_use"] = 1
+        with self.profiler.phase("admit_run", **stats):
             if self.program_hook is not None:
                 first = self.program_hook(
                     "admit", admit_args, lambda: self._run_admit(admit_args))
@@ -2584,6 +2676,7 @@ class ContinuousBatcher:
                    "kv_ring_bytes_per_slot": self._ring_bytes_per_slot,
                    **self._gathered_prefix(b, pb),
                    "bounded": int(self._wave_cut == (t, pb))})
+        self._report_first_use(wave)
         with self.profiler.phase("admit_post"):
             for j, m in enumerate(members):
                 if m["req"]._wave_span is None:
@@ -3204,7 +3297,7 @@ class ContinuousBatcher:
         self._step_count += 1
         w0, w1 = self._note_program(before, "decode", k, len(active))
         self.metrics.observe("batcher_decode_chunk", w1 - w0)
-        trace.get_tracer().record(
+        chunk = trace.get_tracer().record(
             "batcher.decode_chunk", w0, w1,
             attrs={"chunk": self._step_count, "k": k, "slots": len(active),
                    "kv_bytes_per_token": self.paged.bytes_per_token,
@@ -3215,6 +3308,7 @@ class ContinuousBatcher:
                    "pool_positions": self._pool_positions,
                    "window_positions": self._window_positions,
                    "pool_kernel": int(self.pool_kernel)})
+        self._report_first_use(chunk)
         # drafting history stays current even when the adaptive controller
         # runs plain chunks in a speculative batcher — pure function of
         # program outputs, so lockstep followers mirror it in replay()
@@ -3427,11 +3521,12 @@ class ContinuousBatcher:
         m.inc("spec_wave_dispatches")
         m.observe("batcher_decode_chunk", w1 - w0)
         self._note_program(before, f"spec{g_max}", k_it, len(active))
-        trace.get_tracer().record(
+        chunk = trace.get_tracer().record(
             "batcher.spec_wave_chunk", w0, w1,
             attrs={"chunk": self._step_count, "k": k_it,
                    "gamma_max": g_max, "slots": len(active),
                    "drafting": len(drafting), "riding": len(riding)})
+        self._report_first_use(chunk)
         self._apply_spec_hist(toks, keeps,
                               np.asarray(decode_args["cl"], np.int32))
 
@@ -3488,6 +3583,11 @@ class ContinuousBatcher:
                 self._work.clear()
 
 
+def _tree_bytes(tree) -> int:
+    """Bytes of the arrays of ``tree``, as placed."""
+    return int(sum(a.nbytes for a in jax.tree.leaves(tree)))
+
+
 def _unstack_layers(layers: dict) -> list:
     """[L, ...]-stacked MoE layers -> a list of per-layer trees, which the
     layer loops run unrolled (transformer.scan_layer_stack). The grouped
@@ -3519,3 +3619,6 @@ def _expert_backend(num_devices: int = 1, platform: str = "") -> str:
     one-device TPU program, which GSPMD need not partition."""
     on_tpu = (platform or jax.default_backend()) == "tpu"
     return "pallas" if on_tpu and num_devices == 1 else "xla"
+
+
+mark_imported()   # the serving code is imported: one clock read

@@ -61,6 +61,12 @@ class EventType(NamedTuple):
     #                           may emit a subset when inputs are absent)
 
 
+# what a program's first use leaves behind (utils/profiler.py, the
+# program account): the event's fields and the span's attributes
+PROGRAM_FIRST_USE_FIELDS = (
+    "kind", "key", "fun_name", "trace_ms", "lower_ms", "load_ms", "cache",
+    "cache_read_ms", "run_ms", "pallas_call_sites", "serving")
+
 EVENT_TYPES = (
     # ---- fleet membership / health -----------------------------------
     EventType(
@@ -275,6 +281,26 @@ EVENT_TYPES = (
          "thread_cpu_ms", "process_cpu_ms", "invol_switches",
          "major_faults", "gc_ms", "heartbeat_late_ms", "memory",
          "cause")),
+    EventType(
+        "program-first-use", "warning",
+        "A request waited for a compilation: the batcher's scheduler "
+        "thread called a program it had not used yet (a new tail, "
+        "prefix or wave bucket, a chunk size no warm-up reached), "
+        "and JAX traced, lowered and compiled it, or read it from the "
+        "compile cache, inside the step. `kind` (`admit`, `chunk`, "
+        "`spec`) and `key` (tail x prefix blocks x rows; passes) name "
+        "the program, `fun_name` is JAX's; `trace_ms`, `lower_ms` and "
+        "`load_ms` are what `jax.monitoring`'s events covered (`load_ms` "
+        "the backend's: the cache's read and deserialize when `cache` is "
+        "`hit`, of which `cache_read_ms` the read, XLA's compile when "
+        "`miss`, `off` with no cache asked), `run_ms` the rest of the "
+        "call (arguments, first run, the sync), `pallas_call_sites` the "
+        "kernels traced, `serving` always true here. The same record is "
+        "a `batcher.program_first_use` span under the step's "
+        "`batcher.admit_wave` / `batcher.decode_chunk` (left for set-up's "
+        "first uses too), a row of `GET /api/profile`'s `programs`, and "
+        "is added to `dli_batcher_program_*`.",
+        PROGRAM_FIRST_USE_FIELDS),
     # ---- multi-LoRA adapter serving (models/lora.py) ------------------
     EventType(
         "adapter-loaded", "info",

@@ -505,10 +505,13 @@ class WorkerAgent:
             self.models[name] = lm
         self.metrics.inc("models_loaded")
         log.info("loaded %s from %s in %.1fs", name, source, clock.now() - t0)
-        return 200, {"status": "success",
-                     "message": f"model {name} loaded",
-                     "load_time_s": clock.now() - t0,
-                     "stats": stats}
+        out = {"status": "success", "message": f"model {name} loaded",
+               "load_time_s": clock.now() - t0, "stats": stats}
+        if lm.batcher is not None:
+            # where the load's seconds went (utils/profiler.py, the
+            # program account): the build, and the programs used so far
+            out["programs"] = lm.batcher.profiler.programs()
+        return 200, out
 
     def load_model(self, body, _request=None):
         # lease-fenced like every state-changing RPC: a revived stale

@@ -72,6 +72,19 @@ spent in the cycle collector) taken at each call's start, and
 ``call_deltas()``, their deltas with the worst lateness of the process's
 **heartbeat** over the call (one daemon thread a process, asleep 50 ms
 at a time: if it woke late, no thread of this process ran).
+
+And the **program account**: what each program's first use cost, in the
+compiler's own words. One set of ``jax.monitoring`` listeners a process
+(``programs()``, started like ``vitals()``) hears every trace, lowering
+and backend compile (the cache's read on a hit) with its ``[start,
+end]`` on ``time.time()``, on the thread that compiles and only when
+something compiles. ``PhaseProfiler.program(kind, key)`` labels the
+thread for one call; a labelled call that compiled leaves one row
+(``trace_ms``, ``lower_ms``, ``load_ms``, ``run_ms``, ``cache``), the
+constructor's ``build`` labels the weights' and the pool's share, and
+what compiles on a thread with no label is summed under ``eager`` by
+the program's name. ``summary()["programs"]`` holds the account, as
+many rows as there are programs (docs/observability.md).
 """
 
 from __future__ import annotations
@@ -249,6 +262,231 @@ def call_deltas(before: tuple) -> dict:
     }
 
 
+# ---- what a program's first use cost ---------------------------------
+
+# jax.monitoring's names (jax/_src/dispatch.py, compiler.py): the three
+# time spans of one compilation, in the order they nest where they do
+# (a kernel's own trace inside its lowering), and the cache's events
+_SPAN_EVENTS = {"/jax/core/compile/jaxpr_trace_duration": 0,
+                "/jax/core/compile/jaxpr_to_mlir_module_duration": 1,
+                "/jax/core/compile/backend_compile_duration": 2}
+_CACHE_READ = "/jax/compilation_cache/cache_retrieval_time_sec"
+# hits, misses (JAX counts a miss where it writes the entry: one that
+# compiled faster than the cache's minimum is neither)
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": 0,
+                 "/jax/compilation_cache/cache_misses": 1}
+# the account is as large as the number of programs, and no larger
+MAX_PROGRAM_ROWS = 512
+MAX_EAGER_NAMES = 128
+
+_THREAD = threading.local()     # .call: the labelled call in flight
+
+
+def _union_s(*span_lists) -> float:
+    """Seconds covered by the ``(start, end)`` spans of all the lists."""
+    total, upto = 0.0, float("-inf")
+    for start, end in sorted(sp for spans in span_lists for sp in spans):
+        if end > upto:
+            total += end - max(start, upto)
+            upto = end
+    return total
+
+
+def _process_start() -> Optional[float]:
+    """When the OS started this process, in epoch seconds (its start in
+    clock ticks since boot against the uptime now); None off Linux."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            up = float(f.read().split()[0])
+        return time.time() - up + ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return None
+
+
+_IMPORTED_AT: Optional[float] = None
+
+
+def mark_imported():
+    """Note that the package's serving code is imported (the last line of
+    ``runtime/batcher.py``, which pulls in JAX and the models): one clock
+    read, the first time."""
+    global _IMPORTED_AT
+    if _IMPORTED_AT is None:
+        _IMPORTED_AT = time.time()
+
+
+def pallas_call_site():
+    """Count one Pallas call site into the labelled call this thread is
+    tracing, if any: the ``ops/pallas`` entry points call it as they are
+    traced (nothing of it is in a program)."""
+    call = getattr(_THREAD, "call", None)
+    if call is not None:
+        call.pallas += 1
+
+
+def _compiled_ms(spans) -> Dict[str, float]:
+    """``trace_ms``, ``lower_ms``, ``load_ms`` of one call's event spans
+    (trace, lower, load): the union of each kind, so that an inner
+    ``jit``'s trace is counted once, and what nests inside another kind
+    (a kernel traced while it is lowered) counted for the outer one
+    alone: the three add up to the time some event covered."""
+    trace, lower, load = spans
+    in_load = _union_s(load)
+    in_lower = _union_s(lower, load)
+    return {"trace_ms": round((_union_s(trace, lower, load) - in_lower)
+                              * 1e3, 3),
+            "lower_ms": round((in_lower - in_load) * 1e3, 3),
+            "load_ms": round(in_load * 1e3, 3)}
+
+
+class _Programs:
+    """One a process: the ``jax.monitoring`` listeners. An event belongs
+    to the labelled call of its thread (``_THREAD.call``); on a thread
+    with none it is summed under ``eager`` by the program's name, apart
+    for the time before and after a batcher's scheduler thread started
+    (sums, not unions: an eager program's inner trace counts twice)."""
+
+    def __init__(self):
+        import jax.monitoring as monitoring
+        self._lock = threading.Lock()
+        self.started = _process_start()
+        self.serving = 0      # batchers whose scheduler thread runs
+        # [serving] -> {fun_name: [programs, trace s, lower s, load s]}
+        self._eager = ({}, {})
+        self._eager_cache = ([0, 0], [0, 0])
+        monitoring.register_event_time_span_listener(self._on_span)
+        monitoring.register_event_duration_secs_listener(self._on_duration)
+        monitoring.register_event_listener(self._on_event)
+
+    def _on_span(self, event, start, end, fun_name="", **_):
+        kind = _SPAN_EVENTS.get(event)
+        if kind is None:
+            return
+        call = getattr(_THREAD, "call", None)
+        if call is not None:
+            if call.spans is None:
+                call.spans = ([], [], [])
+            call.spans[kind].append((start, end))
+            if kind == 2 or not call.fun_name:
+                call.fun_name = str(fun_name)
+            return
+        with self._lock:
+            names = self._eager[bool(self.serving)]
+            name = str(fun_name)
+            if name not in names and len(names) >= MAX_EAGER_NAMES:
+                name = "(others)"
+            row = names.setdefault(name, [0, 0.0, 0.0, 0.0])
+            row[0] += kind == 2
+            row[1 + kind] += end - start
+
+    def _on_duration(self, event, duration, **_):
+        if event == _CACHE_READ:
+            call = getattr(_THREAD, "call", None)
+            if call is not None:
+                call.cache_read_s += duration
+
+    def _on_event(self, event, **_):
+        kind = _CACHE_EVENTS.get(event)
+        if kind is None:
+            return
+        call = getattr(_THREAD, "call", None)
+        if call is not None:
+            call.cache[kind] += 1
+        else:
+            with self._lock:
+                self._eager_cache[bool(self.serving)][kind] += 1
+
+    def eager(self) -> dict:
+        """What compiled on threads with no label: ``setup`` (no
+        scheduler thread ran) and ``serving``, each its programs, their
+        seconds and the cache's answers, and ``by_name`` (milliseconds
+        of trace + lower + load a program name)."""
+        out = {}
+        with self._lock:
+            for key, names, cache in zip(("setup", "serving"), self._eager,
+                                         self._eager_cache):
+                sums = [sum(r[i] for r in names.values()) for i in range(4)]
+                out[key] = {
+                    "programs": sums[0],
+                    "trace_ms": round(sums[1] * 1e3, 3),
+                    "lower_ms": round(sums[2] * 1e3, 3),
+                    "load_ms": round(sums[3] * 1e3, 3),
+                    "cache_hits": cache[0], "cache_misses": cache[1],
+                    "by_name": {n: round(sum(r[1:]) * 1e3, 3)
+                                for n, r in sorted(names.items())}}
+        return out
+
+
+_PROGRAMS: Optional[_Programs] = None
+
+
+def programs() -> _Programs:
+    """The process's one :class:`_Programs`, started on first use (a
+    batcher's construction), never at import."""
+    global _PROGRAMS
+    if _PROGRAMS is None:
+        with _VITALS_LOCK:
+            if _PROGRAMS is None:
+                _PROGRAMS = _Programs()
+    return _PROGRAMS
+
+
+class _Call:
+    """One labelled call: the thread's events are its own from enter to
+    exit (an inner label takes over and hands back). Until an event
+    comes it holds nothing, and a call that saw none records nothing."""
+    __slots__ = ("prof", "kind", "key", "aot", "outer", "t0", "t1", "spans",
+                 "cache", "cache_read_s", "fun_name", "pallas", "attrs")
+
+    def __init__(self, prof: "PhaseProfiler", kind: str, key, aot: bool):
+        self.prof, self.kind, self.key, self.aot = prof, kind, key, aot
+        self.spans = None
+        self.cache = [0, 0]
+        self.cache_read_s = 0.0
+        self.fun_name = ""
+        self.pallas = 0
+        self.attrs: dict = {}
+
+    def __enter__(self):
+        self.outer = getattr(_THREAD, "call", None)
+        _THREAD.call = self
+        self.t0 = time.time()
+        return self
+
+    def __exit__(self, *exc):
+        self.t1 = time.time()
+        _THREAD.call = self.outer
+        if self.spans is not None and not (self.spans[1] or self.spans[2]):
+            self.spans = None    # a trace found again: nothing compiled
+        prof = self.prof
+        if (self.spans is not None or self.kind == "build"
+                or prof._await_run):
+            prof._close_call(self)
+        return False
+
+    def account(self) -> dict:
+        """The call's row: what its events covered, by kind, the cache's
+        answer, and under ``run_ms`` the rest of its wall."""
+        spans = self.spans or ((), (), ())
+        ms = _compiled_ms(spans)
+        hits, misses = self.cache
+        wall_ms = (self.t1 - self.t0) * 1e3
+        import jax
+        cache = ("hit" if hits and hits >= len(spans[2]) else
+                 "miss" if jax.config.jax_compilation_cache_dir else "off")
+        key = list(self.key) if isinstance(self.key, tuple) else self.key
+        return {"kind": self.kind, "key": key,
+                "fun_name": self.fun_name, **ms,
+                "cache": cache, "cache_hits": hits, "cache_misses": misses,
+                "cache_read_ms": round(self.cache_read_s * 1e3, 3),
+                "run_ms": round(max(0.0, wall_ms - sum(ms.values())), 3),
+                "wall_ms": round(wall_ms, 3),
+                "pallas_call_sites": self.pallas,
+                "start": self.t0, "end": self.t1}
+
+
 def step_phases(rec: dict) -> Dict[str, float]:
     """Seconds per top-level bracket of one recorded step (inclusive of
     what each nests), with the uncovered remainder under ``other`` so
@@ -301,6 +539,20 @@ class PhaseProfiler:
         self._shift = clock.now() - self._t0    # perf_counter -> epoch
         self.last_step: Dict[str, float] = {}   # the last busy step's clocks
         vitals()
+        # the program account (module docstring): a row a program first
+        # used, the constructor's build, and what the batcher has yet to
+        # journal of them (``take_unreported``)
+        self._account = programs()
+        self._rows: List[dict] = []
+        self._rows_dropped = 0
+        self._build: Dict[str, dict] = {}
+        # a batcher makes its profiler first: the build starts here
+        self._build_began = time.time()
+        self._eager_began = self._account.eager()["setup"]
+        self._built_at: Optional[float] = None
+        self._await_run: Dict[tuple, dict] = {}   # compiled, not yet run
+        self._unreported: List[tuple] = []
+        self.serving = False      # the scheduler thread was started
         self._step_n = 0          # steps seen while enabled (sampling clock)
         self._sampled = 0         # steps actually recorded
 
@@ -408,6 +660,127 @@ class PhaseProfiler:
             return _NOOP
         return _Phase(self, name, stats)
 
+    # ---- the program account -----------------------------------------
+
+    def program(self, kind: str, key, aot: bool = False) -> _Call:
+        """Label this thread for one program call (``kind``: admit,
+        chunk, spec or build; ``key`` the program's own): what compiles inside
+        is the call's. ``aot``: the call compiles and does not run (the
+        first labelled call of the same key is then its first run)."""
+        return _Call(self, kind, key, aot)
+
+    def _close_call(self, call: _Call):
+        where = (call.kind, call.key)
+        if call.kind == "build":
+            row = call.account()
+            for name in ("kind", "key", "fun_name", "cache", "run_ms",
+                         "pallas_call_sites"):
+                del row[name]
+            row.update(programs=len((call.spans or ((), (), ()))[2]),
+                       **call.attrs)
+            with self._lock:
+                self._build[str(call.key)] = row
+            return
+        if call.spans is None:
+            row = self._await_run.pop(where, None)
+            if row is not None:     # a program compiled ahead: its first run
+                run_ms = round((call.t1 - call.t0) * 1e3, 3)
+                with self._lock:
+                    row["run_ms"] = round(row["run_ms"] + run_ms, 3)
+                self._unreported.append((row, run_ms, False))
+            return
+        row = call.account()
+        row["serving"] = self.serving
+        if call.aot:
+            row["aot"] = True
+            self._await_run[where] = row
+        else:
+            self._await_run.pop(where, None)
+        with self._lock:
+            if len(self._rows) < MAX_PROGRAM_ROWS:
+                self._rows.append(row)
+            else:
+                self._rows_dropped += 1
+        self._unreported.append((row, row["run_ms"], True))
+
+    def awaits_run(self, kind: str, key) -> bool:
+        """Whether ``program(kind, key)`` was compiled ahead and has not
+        run yet."""
+        return (kind, key) in self._await_run
+
+    @property
+    def first_use(self) -> bool:
+        """Whether a labelled call since the last ``take_unreported``
+        was a program's first use."""
+        return bool(self._unreported)
+
+    def take_unreported(self):
+        """``(row, run ms to count, compiled)`` of the labelled calls
+        since the last take that were a program's first use: one that
+        compiled (its whole row), or the first run of one compiled ahead
+        (``compiled`` false: its wall, added to the row it has)."""
+        if not self._unreported:
+            return ()
+        out, self._unreported = self._unreported, []
+        return out
+
+    def set_serving(self, serving: bool):
+        """The scheduler thread starts or stops: rows (and the process's
+        unlabelled programs) from here on are ``serving``'s."""
+        if serving != self.serving:
+            self.serving = serving
+            with self._account._lock:
+                self._account.serving += 1 if serving else -1
+
+    def built(self) -> dict:
+        """Close the constructor's account: its wall since this profiler
+        was made and what compiled on no label meanwhile. Returns
+        ``programs()["build"]``."""
+        self._built_at = time.time()
+        start, was = self._build_began, self._eager_began
+        now = self._account.eager()["setup"]
+        eager = {k: round(now[k] - was[k], 3) for k in now if k != "by_name"}
+        with self._lock:
+            self._build = {"start": start, "end": self._built_at,
+                           "wall_ms": round((self._built_at - start) * 1e3, 3),
+                           **self._build, "eager": eager}
+            return dict(self._build)
+
+    def programs(self) -> dict:
+        """The program account (JSON-safe, as large as the number of
+        programs): ``process`` (seconds from the process's start, as the
+        OS has it, to the serving code's import done, to this batcher's
+        constructor begun (between the two: the backend's start, the
+        caller's own work) and to the batcher built), ``build`` (the
+        constructor: ``weights``, ``pool``, and ``eager``, what
+        compiled outside both), ``eager`` (programs of
+        threads with no label, the whole process's), ``rows`` (one a
+        program first used) and their ``totals`` by ``serving``."""
+        with self._lock:
+            rows = [dict(r) for r in self._rows]
+            build = dict(self._build)
+            dropped = self._rows_dropped
+        began = self._account.started
+
+        def since_start(t):
+            return round(t - began, 3) if began and t else None
+        fields = ("trace_ms", "lower_ms", "load_ms", "run_ms",
+                  "cache_read_ms", "cache_hits", "cache_misses",
+                  "pallas_call_sites")
+        totals = {key: dict.fromkeys(("programs",) + fields, 0)
+                  for key in ("setup", "serving")}
+        for r in rows:
+            t = totals["serving" if r["serving"] else "setup"]
+            t["programs"] += 1
+            for f in fields:
+                t[f] = round(t[f] + r[f], 3)
+        return {
+            "process": {"imported_s": since_start(_IMPORTED_AT),
+                        "build_began_s": since_start(build.get("start")),
+                        "built_s": since_start(self._built_at)},
+            "build": build, "eager": self._account.eager(),
+            "rows": rows, "rows_dropped": dropped, "totals": totals}
+
     def step_clocks(self) -> Dict[str, float]:
         """Seconds per bracket name of the open step's closed brackets
         (the scheduler thread's own view; not a copy)."""
@@ -492,6 +865,7 @@ class PhaseProfiler:
             "enabled": self.enabled,
             "sample_every": self.sample_every,
             "clocks": self.clocks(),
+            "programs": self.programs(),
             "steps_sampled": len(samples),
             "steps_seen": self._step_n,
             "wall_s": round(wall, 6),

@@ -25,7 +25,13 @@ TensorBoard, and set the scheduler's own account of its time beside them:
    batcher was built); with a trace, each bracket's clock beside the
    device's idle time under it: the host's two views in one place. The
    clocks are all of the window or the process's life, the trace a few
-   seconds of it: compare the shares, not the seconds.
+   seconds of it: compare the shares, not the seconds. Where the file
+   holds the program account too (`summary()["programs"]`: a `GET
+   /api/profile` answer, a `/load_model` answer), what the start's
+   seconds went to is printed after them: the build, each program's
+   first use by trace, lowering, load and first run, and the programs
+   of no label. A formatter of what the program returned, no reader of
+   its own (docs/observability.md, "The program account").
 
 `<trace_dir>` is what `POST /profile/start` or `jax.profiler.start_trace`
 wrote (its newest `plugins/profile/*/*.xplane.pb`), or the file itself.
@@ -378,13 +384,81 @@ def summarize(path: str) -> dict:
 NESTED = ("admit_prep", "admit_run", "admit_post")
 
 
+def _last_line(path: str) -> dict:
+    with open(path) as f:
+        return json.loads(f.read().strip().splitlines()[-1])
+
+
+def read_programs(path: str):
+    """The first program account (a `programs` that holds `rows`) in the
+    JSON file's last line, at any depth; None if it holds none."""
+    todo = [_last_line(path)]
+    while todo:
+        doc = todo.pop(0)
+        found = doc.get("programs")
+        if isinstance(found, dict) and "rows" in found:
+            return found
+        todo.extend(v for v in doc.values() if isinstance(v, dict))
+    return None
+
+
+def render_programs(acct: dict) -> str:
+    """The program account as text: when the process had imported and
+    built, the build by part, a line a program first used, the sums by
+    `serving`, and the unlabelled programs' names by cost."""
+    proc = acct["process"]
+    lines = ["program account: serving code imported "
+             f"{proc['imported_s']} s after the process began, batcher "
+             f"built at {proc['built_s']} s"]
+    build = acct["build"]
+    lines.append(f"  build {build.get('wall_ms', 0) / 1e3:9.3f} s")
+    head = (f"    {'':<22} {'wall':>9} {'trace':>9} {'lower':>9} "
+            f"{'load':>9} {'run':>9}  ms")
+
+    def cells(row):
+        return " ".join(f"{row.get(k, 0):9.1f}" if k in row else " " * 9
+                        for k in ("wall_ms", "trace_ms", "lower_ms",
+                                  "load_ms", "run_ms"))
+    lines.append(head)
+    for part in ("weights", "pool", "eager"):
+        row = build.get(part)
+        if row:
+            lines.append(f"    {part:<22} {cells(row)}  "
+                         f"{row.get('programs', 0)} programs"
+                         + (f", {row['bytes'] / 2 ** 30:.3f} GiB"
+                            if "bytes" in row else ""))
+    for row in acct["rows"]:
+        key = row["key"]
+        name = f"{row['kind']} " + ("x".join(map(str, key))
+                                    if isinstance(key, list) else str(key))
+        lines.append(
+            f"    {name:<22} {cells(row)}  cache {row['cache']}"
+            + (f", {row['pallas_call_sites']} kernels"
+               if row["pallas_call_sites"] else "")
+            + (", compiled ahead" if row.get("aot") else "")
+            + (", SERVING" if row["serving"] else ""))
+    for when, t in acct["totals"].items():
+        lines.append(f"    {when + ' total':<22} {cells(t)}  "
+                     f"{t['programs']} programs, cache "
+                     f"{t['cache_hits']} hits {t['cache_misses']} misses")
+    for when, e in acct["eager"].items():
+        lines.append(f"    {'no label, ' + when:<22} {cells(e)}  "
+                     f"{e['programs']} programs, cache "
+                     f"{e['cache_hits']} hits {e['cache_misses']} misses")
+        for name, ms in sorted(e["by_name"].items(),
+                               key=lambda kv: -kv[1])[:8]:
+            lines.append(f"      {name:<36} {ms:9.1f} ms")
+    if acct.get("rows_dropped"):
+        lines.append(f"    ({acct['rows_dropped']} rows past the bound)")
+    return "\n".join(lines)
+
+
 def read_account(path: str) -> dict:
     """{phase: seconds} of the scheduler's clocks from a JSON file (its
     last line, if it has several): a benchmark's result line, a worker's
     or master's `GET /api/profile` answer (the first profiler found), or
     a `summary()` itself."""
-    with open(path) as f:
-        doc = json.loads(f.read().strip().splitlines()[-1])
+    doc = _last_line(path)
     prefix, suffix = "batcher_clock_", "_ms"
     if "counters" in doc:
         return {k[len(prefix):-len(suffix)]: v * 1e-3
@@ -458,7 +532,8 @@ def main(argv=None) -> int:
     ap.add_argument("trace_dir", nargs="?")
     ap.add_argument("--account", metavar="FILE",
                     help="a result line or a GET /api/profile answer: "
-                    "print the scheduler's phase clocks too")
+                    "print the scheduler's phase clocks too, and the "
+                    "program account where the file holds one")
     ap.add_argument("--json", action="store_true",
                     help="print the summary as one JSON object")
     args = ap.parse_args(argv)
@@ -467,6 +542,7 @@ def main(argv=None) -> int:
     summary = summarize(args.trace_dir) if args.trace_dir else {}
     if args.account:
         summary["clocks"] = read_account(args.account)
+        summary["programs"] = read_programs(args.account)
     if args.json:
         print(json.dumps(summary))
         return 0
@@ -476,6 +552,8 @@ def main(argv=None) -> int:
         idle = (summary["devices"][0]["idle_by_phase"]
                 if summary.get("devices") else None)
         print(render_account(summary["clocks"], idle))
+        if summary["programs"]:
+            print(render_programs(summary["programs"]))
     return 0
 
 
